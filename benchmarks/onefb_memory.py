@@ -54,15 +54,11 @@ def temp_bytes(schedule: str, m: int) -> int:
 
 
 def main() -> None:
+    # compile-only memory analysis on 8 virtual CPU devices
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", 8)
-    except RuntimeError:
-        pass
 
     rows = []
     for m in (1, 4, 16, 64):

@@ -26,21 +26,6 @@ import argparse
 import sys
 
 
-def _bootstrap_devices(n: int) -> None:
-    """Virtual-CPU backend, same dance as __graft_entry__/tests: must run
-    before the first jax operation; keep whatever exists if backends are
-    already up (in-process callers)."""
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        from simple_distributed_machine_learning_tpu.parallel.compat import (
-            set_host_device_count,
-        )
-        set_host_device_count(n)
-    except RuntimeError:
-        pass
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m simple_distributed_machine_learning_tpu.analysis",
@@ -129,7 +114,12 @@ def main(argv=None) -> int:
                8 if (args.fixtures or args.fixture is not None) else 0,
                args.dryrun or 0)
     if need:
-        _bootstrap_devices(need)
+        # the analyzer traces on virtual CPU devices (no FLOPs run); imported
+        # here so --serve-protocol stays jax-free
+        from simple_distributed_machine_learning_tpu.parallel.compat import (
+            virtual_cpu_devices,
+        )
+        virtual_cpu_devices(need)
     ok = True
     protocol_violated = False
 

@@ -373,7 +373,8 @@ class BoundsWalker:
         if prim in ("broadcast_in_dim", "reshape", "transpose", "squeeze",
                     "rev", "slice", "copy", "stop_gradient",
                     "reduce_max", "reduce_min", "sort", "expand_dims",
-                    "reduce_precision", "real", "optimization_barrier"):
+                    "reduce_precision", "real", "optimization_barrier",
+                    "pvary"):       # the vma varying cast: value-identity
             if prim == "sort":
                 return [env.read(v) for v in eqn.invars][:n] or [a] * n
             return [a] * n
@@ -454,7 +455,7 @@ class BoundsWalker:
             )
             return kernels.check_pallas_call(self, eqn, ins, env)
 
-        if prim == "pjit" and len(ins) == 2:
+        if prim == "jit" and len(ins) == 2:
             # jnp's floor_divide/remainder lower to div/rem plus a
             # sign-correction select whose predicate is only RELATIONALLY
             # decidable (sign(d) != sign(c) AND rem != 0 share d) — plain

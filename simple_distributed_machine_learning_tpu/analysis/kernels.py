@@ -99,13 +99,9 @@ def _grid(gm) -> tuple[int, ...]:
 
 
 def _dimension_semantics(eqn, n_axes: int) -> tuple[str, ...]:
-    cp = eqn.params.get("compiler_params") or {}
-    if not isinstance(cp, dict):
-        cp = getattr(cp, "__dict__", {}) or {}
-    mosaic = cp.get("mosaic") or {}
-    if not isinstance(mosaic, dict):
-        mosaic = getattr(mosaic, "__dict__", {}) or {}
-    sem = mosaic.get("dimension_semantics")
+    # a mapping {"mosaic_tpu": pltpu.CompilerParams(...)}
+    mosaic = dict(eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    sem = getattr(mosaic, "dimension_semantics", None)
     if not sem:
         return ("arbitrary",) * n_axes
     sem = tuple(str(s) for s in sem)
@@ -129,17 +125,13 @@ def _bm_parts(bm):
     if bm is None:
         return None
     raw = getattr(bm, "block_shape", None)
-    asd = getattr(bm, "array_shape_dtype", None)
+    asd = getattr(bm, "array_aval", None)
     if raw is None or asd is None:
         return None
     shape = tuple(int(s) for s in asd.shape)
-    block = []
-    for d, b in enumerate(raw):
-        try:
-            block.append(int(b))
-        except (TypeError, ValueError):
-            # Mapped/None entry: the dim is carried whole (squeezed)
-            block.append(1)
+    # entries are pallas block dims: Blocked/Element carry ``block_size``;
+    # Squeezed (a mapped/None dim) carries the dim whole, one row at a time
+    block = [int(getattr(b, "block_size", 1)) for b in raw]
     return tuple(block), shape, np.dtype(asd.dtype)
 
 

@@ -6,7 +6,7 @@ carrying three pieces of state:
 
 - a **replication environment** inside each ``shard_map``: for every value,
   the set of mesh axes it may VARY over (differ across devices). Inputs seed
-  from ``in_names``; ``axis_index`` introduces variance; ``psum``/
+  from ``in_specs``; ``axis_index`` introduces variance; ``psum``/
   ``all_gather`` over an axis remove it (every device then holds the same
   value); ``ppermute`` preserves it; control flow joins it (a ``switch`` on a
   stage index makes every branch output stage-varying). This is a static
@@ -50,6 +50,7 @@ from simple_distributed_machine_learning_tpu.analysis.trace import (
     is_low_precision,
     norm_axes,
     open_jaxpr,
+    prim_name,
     source_line,
     subjaxprs,
 )
@@ -87,24 +88,26 @@ def _mesh_axes_of(eqn, active_mesh) -> dict[str, int]:
     """Manual (non-auto) axes of a shard_map eqn, cross-checked against the
     launch mesh when one was passed to ``analyze``."""
     mesh = eqn.params.get("mesh", None)
-    auto = eqn.params.get("auto", None) or frozenset()
+    manual = eqn.params.get("manual_axes", None)
     axes: dict[str, int] = {}
     shape = getattr(mesh, "shape", None)
     if shape:
         for name, size in dict(shape).items():
-            if name not in auto:
+            if manual is None or name in manual:
                 axes[name] = int(size)
     if not axes and active_mesh is not None:
         axes = {n: int(s) for n, s in dict(active_mesh.shape).items()}
     return axes
 
 
-def _names_to_axes(names: Any) -> frozenset:
-    """A shard_map in_names/out_names entry ({dim: (axis, ...)}) as the flat
-    set of mesh axes it maps."""
+def _spec_axes(spec: Any) -> frozenset:
+    """A shard_map in_specs/out_specs entry (a ``PartitionSpec``: one of
+    ``None`` / axis name / tuple of names per dim) as the flat set of mesh
+    axes it maps."""
     out = set()
-    for v in dict(names or {}).values():
-        out.update(norm_axes(v))
+    for entry in tuple(spec or ()):
+        if entry is not None:
+            out.update(norm_axes(entry))
     return frozenset(out)
 
 
@@ -148,7 +151,7 @@ class Walker:
         caller's ``analysis.spec(..., vary=('data',))`` contract): a buffer
         whose shape is replicated but whose CONTENT each device holds a
         different shard of — exactly a ZeRO opt-state shard in the
-        check_rep=False era, which no ``in_names`` can express. The
+        check_vma=False case, which no ``in_specs`` can express. The
         variance threads through call-like eqns into every shard_map's
         replication inference, where a consume-without-gather surfaces as a
         missing reduction (re-tagged ``sharded-state`` by run_rules).
@@ -165,7 +168,7 @@ class Walker:
             return [vary.get(id(v), EMPTY) for v in atoms]
 
         for eqn in jaxpr.eqns:
-            prim = eqn.primitive.name
+            prim = prim_name(eqn)
             for invar in eqn.invars:
                 key = id(invar)
                 if key in donated:
@@ -199,7 +202,7 @@ class Walker:
                 for key, _, sub in subjaxprs(eqn):
                     self._path.append(
                         f"pjit:{eqn.params.get('name', key)}"
-                        if prim == "pjit"
+                        if prim == "jit"
                         else f"scan[x{trips}]" if prim == "scan" else prim)
                     self._trips *= trips
                     try:
@@ -242,7 +245,7 @@ class Walker:
                     if union:
                         for var in eqn.outvars:
                             vary[id(var)] = union
-            if prim == "pjit":
+            if prim == "jit":
                 don = eqn.params.get("donated_invars") or ()
                 site = self._where(eqn)
                 seen_at: dict[int, bool] = {}   # id(var) -> any donated
@@ -281,15 +284,10 @@ class Walker:
         axes = _mesh_axes_of(eqn, self.active_mesh)
         ctx = _MeshCtx(axes)
         inner = open_jaxpr(eqn.params["jaxpr"])
-        in_names = eqn.params.get("in_names")
-        out_names = eqn.params.get("out_names")
-        if in_names is None:            # new-jax spelling: in_specs PartitionSpec
-            in_vmas = [EMPTY for _ in inner.invars]
-        else:
-            in_vmas = [_names_to_axes(n) for n in in_names]
+        in_vmas = [_spec_axes(sp) for sp in eqn.params["in_specs"]]
         if incoming:
             # declared content-variance (ZeRO shards in replicated-shape
-            # buffers) joins whatever in_names already map
+            # buffers) joins whatever in_specs already map
             in_vmas = [v | inc for v, inc in
                        zip(in_vmas, incoming + [EMPTY] * len(in_vmas))]
         # cross-check the traced mesh against the launch mesh
@@ -305,10 +303,9 @@ class Walker:
                         hint="rebuild the step for the launch mesh (axis "
                              "sizes are baked in at trace time)")
         out_vmas = self._visit_vma(inner, in_vmas, ctx)
-        if out_names is None:
-            return
-        for i, (names, vma) in enumerate(zip(out_names, out_vmas)):
-            claimed = _names_to_axes(names)
+        for i, (spec, vma) in enumerate(zip(eqn.params["out_specs"],
+                                            out_vmas)):
+            claimed = _spec_axes(spec)
             missing = sorted(
                 ax for ax in vma - claimed
                 if ctx.size(ax) is not None and ctx.size(ax) > 1)
@@ -341,7 +338,7 @@ class Walker:
         return [self._read(env, v) for v in jaxpr.outvars]
 
     def _eqn_vma(self, eqn, env, ctx) -> list:
-        prim = eqn.primitive.name
+        prim = prim_name(eqn)
         in_vmas = [self._read(env, v) for v in eqn.invars]
         union = frozenset().union(*in_vmas) if in_vmas else EMPTY
         n_out = len(eqn.outvars)
@@ -352,6 +349,10 @@ class Walker:
             axes = eqn_axes(eqn)
             self._check_axes(eqn, axes, ctx)
             return [frozenset(axes)]
+        if prim == "pvary":
+            # pvary: the vma checker's varying cast — no wire traffic, the
+            # value is simply typed (and from here on treated as) varying
+            return [union | frozenset(eqn_axes(eqn))] * n_out
         if prim == "cond":
             return self._cond_vma(eqn, in_vmas, ctx)
         if prim == "scan":
@@ -364,7 +365,7 @@ class Walker:
         # matches, else fall back to the union rule
         for key, _, sub in subjaxprs(eqn):
             if len(sub.invars) == len(eqn.invars):
-                self._path.append(prim if prim != "pjit"
+                self._path.append(prim if prim != "jit"
                                   else f"pjit:{eqn.params.get('name', '')}")
                 try:
                     outs = self._visit_vma(sub, in_vmas, ctx)
@@ -375,7 +376,7 @@ class Walker:
         return [union] * n_out
 
     def _collective_vma(self, eqn, in_vmas, union, ctx) -> list:
-        prim = eqn.primitive.name
+        prim = prim_name(eqn)
         axes = eqn_axes(eqn)
         self._check_axes(eqn, axes, ctx)
         self._check_dtype(eqn, prim)
@@ -576,14 +577,14 @@ class Walker:
             hit = [taint[id(v)] for v in eqn.invars if id(v) in taint]
             if not hit:
                 continue
-            via_add = any(hit) or eqn.primitive.name in add_like
+            via_add = any(hit) or prim_name(eqn) in add_like
             for ov in eqn.outvars:
                 taint[id(ov)] = taint.get(id(ov), False) or via_add
             # recurse one level into call-like bodies cheaply: treat any
             # sub-jaxpr containing an add as an add on this path
             if not via_add:
                 for _, _, sub in subjaxprs(eqn):
-                    if any(e.primitive.name in add_like for e in sub.eqns):
+                    if any(prim_name(e) in add_like for e in sub.eqns):
                         for ov in eqn.outvars:
                             taint[id(ov)] = True
                         break
@@ -663,7 +664,7 @@ class Walker:
         encoded structurally so differing lengths differ)."""
         sig = []
         for eqn in open_jaxpr(jaxpr).eqns:
-            prim = eqn.primitive.name
+            prim = prim_name(eqn)
             if prim in RENDEZVOUS_PRIMS:
                 perm = eqn.params.get("perm")
                 sig.append((prim, eqn_axes(eqn),

@@ -2,11 +2,9 @@
 
 The analyzer never runs the step — it traces it to a ``ClosedJaxpr``
 (:func:`trace_to_jaxpr`) and walks equations, recursing through every
-sub-jaxpr a primitive carries (``scan``/``cond``/``switch`` bodies, ``pjit``
+sub-jaxpr a primitive carries (``scan``/``cond``/``switch`` bodies, ``jit``
 and ``custom_vjp`` call jaxprs, ``shard_map`` inner jaxprs, ``remat``
-thunks). Everything here is version-tolerant over the jaxpr surface the
-repo supports (jax 0.4.x through the 0.9 vma era): param keys are probed,
-never assumed.
+thunks). Param keys are probed, never assumed.
 """
 
 from __future__ import annotations
@@ -14,15 +12,7 @@ from __future__ import annotations
 from typing import Any, Iterator
 
 import jax
-from jax import core as jax_core
-
-try:                                     # moved in newer jax
-    from jax.extend import core as jex_core
-    _JAXPR_TYPES = (jax_core.Jaxpr, jex_core.Jaxpr)
-    _CLOSED_TYPES = (jax_core.ClosedJaxpr, jex_core.ClosedJaxpr)
-except Exception:                         # pragma: no cover - old jax only
-    _JAXPR_TYPES = (jax_core.Jaxpr,)
-    _CLOSED_TYPES = (jax_core.ClosedJaxpr,)
+from jax.extend import core as jex_core
 
 # collectives the lint passes care about, by primitive name
 COLLECTIVE_PRIMS = frozenset({
@@ -32,13 +22,27 @@ COLLECTIVE_PRIMS = frozenset({
 # collectives that are a cross-device rendezvous (axis_index is free)
 RENDEZVOUS_PRIMS = COLLECTIVE_PRIMS - {"axis_index"}
 
+# under the vma type system the reducing collectives trace under their
+# ``*_invariant`` names (same wire traffic, invariant-typed result); the
+# rules speak the classic names
+_CANONICAL_PRIM = {
+    "psum_invariant": "psum",
+    "all_gather_invariant": "all_gather",
+}
+
+
+def prim_name(eqn) -> str:
+    """The equation's primitive under the name the rules are written in."""
+    name = eqn.primitive.name
+    return _CANONICAL_PRIM.get(name, name)
+
 
 def is_jaxpr(x: Any) -> bool:
-    return isinstance(x, _JAXPR_TYPES)
+    return isinstance(x, jex_core.Jaxpr)
 
 
 def is_closed(x: Any) -> bool:
-    return isinstance(x, _CLOSED_TYPES)
+    return isinstance(x, jex_core.ClosedJaxpr)
 
 
 def open_jaxpr(x: Any):

@@ -1,9 +1,9 @@
 """ctypes bindings for the native C++ data loader (``native/data_loader.cpp``).
 
 Builds ``libsdml_data.so`` on demand with ``make`` (g++ is in the image;
-pybind11 is not, hence the plain C ABI + ctypes). Everything here degrades
-gracefully: if the toolchain or .so is unavailable, callers fall back to the
-pure-NumPy paths in ``mnist.py``.
+pybind11 is not, hence the plain C ABI + ctypes). If the toolchain or .so is
+unavailable, callers fall back to the pure-NumPy paths in ``mnist.py`` and
+one line on stderr says so.
 """
 
 from __future__ import annotations
@@ -12,6 +12,7 @@ import ctypes
 import fcntl
 import os
 import subprocess
+import sys
 from typing import Iterator
 
 import numpy as np
@@ -21,6 +22,12 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
 _SO_PATH = os.path.join(_NATIVE_DIR, "libsdml_data.so")
 
 _lib = None  # None = not attempted; False = attempted and unavailable
+
+
+def _warn(why: str) -> None:
+    """The fallback is allowed, never silent."""
+    sys.stderr.write(f"native_loader: {why}; using the pure-NumPy data "
+                     f"path\n")
 
 
 def _load() -> ctypes.CDLL | None:
@@ -37,13 +44,16 @@ def _load() -> ctypes.CDLL | None:
             fcntl.flock(lockf, fcntl.LOCK_EX)
             subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
                            capture_output=True, timeout=120)
-    except Exception:
+    except Exception as e:  # noqa: BLE001 - any build failure = fallback
         if not os.path.exists(_SO_PATH):
+            _warn(f"`make -C {_NATIVE_DIR}` failed ({type(e).__name__}: "
+                  f"{str(e)[:200]}) and no built library exists")
             _lib = False
             return None
     try:
         lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+    except OSError as e:
+        _warn(f"cannot load {_SO_PATH} ({e})")
         _lib = False
         return None
     lib.idx_read.argtypes = [ctypes.c_char_p,

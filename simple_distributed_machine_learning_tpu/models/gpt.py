@@ -506,16 +506,19 @@ def make_gpt_stages(key: jax.Array, cfg: GPTConfig = GPTConfig(),
             shards = tuple(_slice_expert_shard(params, e, cfg)
                            for e in range(cfg.n_expert_parallel))
             stages.append(Stage(apply=apply, params=shards[0],
-                                in_shape=in_shape, expert_shards=shards))
+                                in_shape=in_shape, expert_shards=shards,
+                                token_input=first))
         elif cfg.n_tensor_parallel > 1:
             # slice the SAME dense init per model shard (Megatron layout):
             # the TP pipeline matches the dense build to float tolerance
             shards = tuple(_slice_tp_stage(params, m, cfg.n_tensor_parallel)
                            for m in range(cfg.n_tensor_parallel))
             stages.append(Stage(apply=apply, params=shards[0],
-                                in_shape=in_shape, shards=shards))
+                                in_shape=in_shape, shards=shards,
+                                token_input=first))
         else:
-            stages.append(Stage(apply=apply, params=params, in_shape=in_shape))
+            stages.append(Stage(apply=apply, params=params, in_shape=in_shape,
+                                token_input=first))
 
     # the wire carries only INTER-stage activations ([t_loc, d_model] blocks
     # and the stage-0 token ids); the last stage's [t_loc, vocab] log-probs

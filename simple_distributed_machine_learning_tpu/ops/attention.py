@@ -19,9 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from simple_distributed_machine_learning_tpu.parallel.compat import (
-    axis_size as _axis_size,
-)
 from jax.sharding import PartitionSpec as P
 
 SEQ_AXIS = "seq"
@@ -112,7 +109,7 @@ def ring_attention(params: dict, x: jax.Array, n_heads: int,
     gathered sequence to float tolerance (see tests/test_attention.py).
     """
     h = n_heads
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     q = _split_heads(x @ params["wq"], h)
     k = _split_heads(x @ params["wk"], h)

@@ -53,7 +53,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from simple_distributed_machine_learning_tpu.ops.flash_attention import (
-    _HAS_PLTPU,
     _LANES,
     NEG_INF,
     _compiler_params,
@@ -174,9 +173,6 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     - ``"auto"`` (default) — ``natural`` when ``dh`` is a lane multiple or
       in interpret mode (no tiling there), else ``packed``.
     """
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("paged_attention needs jax.experimental.pallas."
-                           "tpu (interpret mode covers non-TPU backends)")
     S, H, K, dh = q.shape
     NB = tables.shape[1]
     bs = int(block_size)

@@ -1,24 +1,13 @@
-"""JAX version compatibility for the shard_map engine.
+"""The vma helpers the shard_map engine shares (jax 0.9: ``jax.shard_map``,
+``lax.pcast``, ``jax.typeof(...).vma``).
 
-The framework targets modern JAX (the ``check_vma`` era: ``jax.shard_map``,
-``lax.pcast``, ``jax.typeof(...).vma``), but the collective core — ppermute
-rings, psum, custom_vjp — predates all of that. This module pins the three
-seams where the APIs diverged so the engine also runs on the 0.4.x series
-(where ``shard_map`` still lives in ``jax.experimental`` and there is no vma
-type system at all):
-
-- :func:`shard_map` — dispatches to ``jax.shard_map`` when present; otherwise
-  to ``jax.experimental.shard_map.shard_map`` with ``check_rep=False`` (the
-  old replication checker cannot type the engine's ppermute/switch machinery;
-  values are bit-identical across the axes the out_specs drop, so taking
-  shard 0 is exact).
-- :func:`pvary_to` — the vma-anchor cast (``lax.pcast(..., to="varying")``).
-  On versions without a vma system there is nothing to anchor: identity.
-- :func:`vma_of` — the value's varying-manual-axes set, ``frozenset()`` when
-  the concept does not exist.
-- :func:`set_host_device_count` — ``jax_num_cpu_devices`` config where it
-  exists, silently relying on ``--xla_force_host_platform_device_count``
-  (which the callers also set) where it does not.
+- :func:`shard_map` — ``jax.shard_map`` with ``check_vma=None`` meaning "jax's
+  default" (vma checking on).
+- :func:`vma_of` — the value's varying-manual-axes set.
+- :func:`pvary_to` — the vma-anchor cast (``lax.pcast(..., to="varying")``)
+  over exactly the axes the value does not already vary over.
+- :func:`ct_like` — types a custom-vjp backward output to its primal.
+- :func:`virtual_cpu_devices` — ``jax_num_cpu_devices`` for the CPU dry runs.
 """
 
 from __future__ import annotations
@@ -26,72 +15,50 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:  # pragma: no cover - exercised only on old jax
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
-else:
-    _OLD_SHARD_MAP = None
-
-HAS_VMA = hasattr(lax, "pcast") and hasattr(jax, "typeof")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across the experimental→stable API move.
-
-    ``check_vma=None`` means "the caller's default" (vma checking on, where
-    the concept exists). Old jax always runs with ``check_rep=False``: its
-    rep checker predates the vma algebra the engine's anchors target.
-    """
-    if _NEW_SHARD_MAP is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-    return _OLD_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """``jax.shard_map``; ``check_vma=None`` leaves jax's default (on)."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def vma_of(x) -> frozenset:
-    """The axes ``x`` is varying over (empty where vma does not exist)."""
-    if not HAS_VMA:
-        return frozenset()
+    """The manual axes ``x`` is varying over."""
     return getattr(jax.typeof(x), "vma", frozenset()) or frozenset()
 
 
 def pvary_to(x: jax.Array, axes: tuple[str, ...]) -> jax.Array:
     """pcast ``x`` to varying over exactly the axes of ``axes`` it does not
     already vary over (pcast rejects mixed already/not-yet-varying sets).
-    Identity on jax versions without the vma system.
-
-    The cast only exists to satisfy the vma checker — it is the identity
-    value-wise — so in contexts where no checker is active and pcast itself
-    objects (e.g. tracing under ``check_vma=False``, where the anchor is
-    unnecessary anyway), the value passes through unchanged.
-    """
-    if not HAS_VMA:
-        return x
+    Value-wise the identity; a typing error from pcast propagates."""
     missing = tuple(a for a in axes if a not in vma_of(x))
     if not missing:
         return x
+    return lax.pcast(x, missing, to="varying")
+
+
+def ct_like(ct: jax.Array, primal: jax.Array) -> jax.Array:
+    """Type a custom-vjp cotangent to its primal's varying axes: psum over
+    the axes only the cotangent varies over (the reduction autodiff itself
+    inserts when an invariant value met varying ones, e.g. the data-parallel
+    gradient all-reduce of a replicated weight), pcast up the rest."""
+    want = vma_of(primal)
+    extra = tuple(sorted(vma_of(ct) - want))
+    if extra:
+        ct = lax.psum(ct, extra)
+    return pvary_to(ct, tuple(sorted(want)))
+
+
+def virtual_cpu_devices(n: int) -> None:
+    """For the dry runs that are CPU runs by definition (``dryrun_multichip``,
+    the analyzer CLI): ``n`` virtual CPU devices, asked for before the
+    backend starts — the one place the package names a platform through the
+    API. A backend that is already up is kept only if it is the CPU one with
+    enough devices; any other (a live TPU backend included) is an error."""
     try:
-        return lax.pcast(x, missing, to="varying")
-    except (ValueError, TypeError, NotImplementedError):
-        return x
-
-
-def axis_size(axis: str) -> int:
-    """``lax.axis_size`` where it exists; ``lax.psum(1, axis)`` (which
-    constant-folds to the static size through the axis env) elsewhere."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
-def set_host_device_count(n: int) -> None:
-    """Force ``n`` virtual CPU devices through the live config (the env-var
-    route is latched too early when a sitecustomize imports jax first)."""
-    try:
+        jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        # pre-jax_num_cpu_devices: the XLA_FLAGS route the callers also set
-        # (--xla_force_host_platform_device_count) is the only mechanism
-        pass
+    except RuntimeError:
+        if jax.default_backend() != "cpu" or len(jax.devices()) < n:
+            raise

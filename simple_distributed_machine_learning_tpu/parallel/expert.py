@@ -34,9 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from simple_distributed_machine_learning_tpu.parallel.compat import (
-    axis_size as _axis_size,
-)
 
 from simple_distributed_machine_learning_tpu.ops.layers import linear_init
 
@@ -178,7 +175,7 @@ def moe_apply_ep(params: dict, x: jax.Array, k: int = 2,
     )
 
     check_overlap(overlap)
-    D = _axis_size(axis)
+    D = lax.axis_size(axis)
     T, _ = x.shape
     E = n_experts_of(params)                             # global expert count
     capacity = default_capacity(T, E, k) if capacity is None else capacity

@@ -119,7 +119,6 @@ def build_1f1b_fn(pipe, deterministic: bool) -> Callable:
     n_data = pipe.n_data
     from simple_distributed_machine_learning_tpu.ops.losses import nll_loss
     from simple_distributed_machine_learning_tpu.parallel.compat import (
-        HAS_VMA,
         pvary_to as _pvary_to,
         shard_map as _shard_map,
         vma_of as _vma_of,
@@ -216,7 +215,7 @@ def build_1f1b_fn(pipe, deterministic: bool) -> Callable:
                         lambda a: grad_sync(a, EXPERT_AXIS), p)
                 if compute_dtype is not None:
                     p = jax.tree.map(lambda a: a.astype(compute_dtype), p)
-                    x = x.astype(compute_dtype)
+                    x = pipe.stages[s].cast_input(x, compute_dtype)
                 y = applies[s](p, x, k, deterministic)
                 aux = jnp.float32(0.0)
                 if isinstance(y, tuple):
@@ -296,20 +295,8 @@ def build_1f1b_fn(pipe, deterministic: bool) -> Callable:
                 # it: for sharded stages that assembles the PARTIALS (the
                 # real cotangent, no correction); for replicated stages it
                 # summed n IDENTICAL full cotangents — rescale per axis.
-                if tp_on and not model_sharded[s] and HAS_VMA:
-                    # (vma jax only: pre-vma pullbacks never inserted the
-                    # implicit psum this divides back out — each slot's
-                    # cotangent is already the single true copy there)
+                if tp_on and not model_sharded[s]:
                     d_x = d_x / n_model
-                if tp_on and model_sharded[s] and not HAS_VMA:
-                    # pre-vma jax: without the wire's model-invariance
-                    # typing, a sharded stage's pullback hands every slot
-                    # exactly n_model x GPipe's gradient on EVERY param leaf
-                    # (sharded weights and grad_sync'd bias alike — measured
-                    # uniform), while its input cotangent d_x comes out at
-                    # the correct scale. Rescale params only;
-                    # tests/test_onefb.py pins bit-exact parity vs GPipe.
-                    d_params = jax.tree.map(lambda a: a / n_model, d_params)
                 # vma-aware autodiff semantics: ``params`` is data-INVARIANT
                 # (the buffer is replicated over the data axis), so the
                 # pullback's d_params must be too — jax inserts the implicit
@@ -395,17 +382,6 @@ def build_1f1b_fn(pipe, deterministic: bool) -> Callable:
             for i, a in enumerate(init0))
         carry, _ = lax.scan(step, init, jnp.arange(T))
         _, _, _, grad_acc, num_acc, aux_acc = carry
-        if not HAS_VMA:
-            # pre-vma jax: params were never TYPED data-/seq-invariant, so
-            # the pullback's implicit gradient psum over those axes (the
-            # comment at make_bwd_branch) did not happen — each device holds
-            # only its own data (seq) shard's gradient while the out_spec
-            # claims data-invariance. Insert the DP all-reduce explicitly.
-            # Found by analysis/ (rule unreduced-gradient.missing-reduce);
-            # pinned by test_onefb's dp>1 parity cases on old jax.
-            grad_acc = lax.psum(grad_acc, DATA_AXIS)
-            if seq_on:
-                grad_acc = lax.psum(grad_acc, SEQ_AXIS)
 
         # loss value (reporting): identical reduction to the GPipe engine
         num = lax.psum(lax.psum(num_acc, STAGE_AXIS), DATA_AXIS)

@@ -55,7 +55,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from simple_distributed_machine_learning_tpu.parallel.compat import (
-    axis_size as _axis_size,
+    ct_like as _ct_like,
+    pvary_to as _pvary_to,
 )
 from simple_distributed_machine_learning_tpu.utils.profiler import (
     annotate_scope,
@@ -96,7 +97,7 @@ def _put_row_chunk(buf: jax.Array, chunk: jax.Array, c, n: int) -> jax.Array:
 
 def _ring_all_gather_impl(x, axis, perm_fn=_fwd_perm, tag="ring_all_gather"):
     """[n, ...] shard -> [mp*n, ...] gathered along axis 0, via mp-1 hops."""
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     if mp == 1:
         return x
     n = x.shape[0]
@@ -124,7 +125,7 @@ def _ring_reduce_scatter_impl(x, axis, perm_fn=_fwd_perm,
     ``c+1`` and visits ``c+2, …, c`` — a fixed summation order per chunk, so
     a following all-gather yields bit-identical replicas.
     """
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     if mp == 1:
         return x
     if x.shape[0] % mp:
@@ -204,14 +205,16 @@ def _ring_psum_impl(x, axis, perm_fn=_fwd_perm, tag="ring_psum"):
     as a reduce-scatter ring + all-gather ring over column chunks.
 
     Falls back to one ``lax.psum`` when the last axis does not divide by the
-    ring size (the chunks must be equal for static shapes).
+    ring size (the chunks must be equal for static shapes) — cast back up to
+    axis-varying, the type the ring's ppermutes give, so that the result and
+    its cotangent are typed the same on both routes.
     """
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     if mp == 1:
         return x
     d = x.shape[-1]
     if d % mp:
-        return lax.psum(x, axis)
+        return _pvary_to(lax.psum(x, axis), (axis,))
     # chunk the LAST axis (the matmul output features): move it leading so
     # the row-chunk ring helpers apply, then restore
     xt = jnp.moveaxis(x, -1, 0)
@@ -256,7 +259,7 @@ def _allgather_matmul_impl(x, w, axis, perm_fn=_fwd_perm,
                            tag="allgather_matmul"):
     """y = allgather(x) @ w, chunk-at-a-time: multiply the held activation
     chunk while the next one rides the ring."""
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     if mp == 1:
         return x @ w
     n = x.shape[0]
@@ -282,7 +285,7 @@ def _matmul_reducescatter_impl(x, w, axis, perm_fn=_fwd_perm,
                                tag="matmul_reducescatter"):
     """y = reduce_scatter(x @ w): each row-chunk's partial product computes
     while the accumulator for the previous chunk rides the ring."""
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     if mp == 1:
         return x @ w
     if x.shape[0] % mp:
@@ -310,7 +313,7 @@ def _gatherT_matmul_impl(x, dy, axis, n_rows, perm_fn=_fwd_perm,
     """dw = allgather(x)^T @ dy without materializing the gather: circulate
     the ``x`` chunks and accumulate ``x_c^T @ dy[rows c]`` per hop. ``dy``
     is local ``[mp*n_rows, k]``; ``x`` is this device's ``[n_rows, d]``."""
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     if mp == 1:
         return x.T @ dy
     i = lax.axis_index(axis)
@@ -362,7 +365,7 @@ def _allgather_matmul_bwd(axis, res, dy):
     # dw = allgather(x)^T @ dy, re-circulating x chunk-by-chunk
     dw = _gatherT_matmul_impl(x, dy, axis, x.shape[0], perm_fn=_bwd_perm,
                               tag="allgather_matmul_bwd_dw")
-    return dx, dw
+    return _ct_like(dx, x), _ct_like(dw, w)
 
 
 allgather_matmul.defvjp(_allgather_matmul_fwd, _allgather_matmul_bwd)
@@ -394,7 +397,7 @@ def _matmul_reducescatter_fwd(x, w, axis):
 
 def _matmul_reducescatter_bwd(axis, res, dy):
     x, w = res
-    mp = _axis_size(axis)
+    mp = lax.axis_size(axis)
     n = x.shape[0] // mp
     # d(x@w) = allgather(dy) (each device's dy is the cotangent of its row
     # chunk of the summed product): dx = allgather_matmul(dy, w^T)
@@ -414,7 +417,7 @@ def _matmul_reducescatter_bwd(axis, res, dy):
             acc = acc + _row_chunk(x, c, n).T @ chunk
         if s + 1 < mp:
             chunk = nxt
-    return dx, acc
+    return _ct_like(dx, x), _ct_like(acc, w)
 
 
 matmul_reducescatter.defvjp(_matmul_reducescatter_fwd,

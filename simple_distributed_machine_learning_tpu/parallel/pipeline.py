@@ -92,12 +92,24 @@ class Stage:
     auxiliary loss (e.g. the MoE load-balancing term, already scaled by its
     weight) that the engine adds to the objective (summed over stages,
     averaged over microbatches/data shards).
+
+    ``token_input``: ``x`` carries integer token ids as float32 (exact up to
+    2**24). Under mixed precision the engine hands them to ``apply`` UNCAST:
+    bfloat16 keeps 8 significant bits, so a cast rounds every id above 256
+    (8191 becomes 8192 — out of the vocabulary, which ``jnp.take`` fills
+    with NaN: the ``final_loss: NaN`` of every bf16 GPT row with a real
+    vocabulary).
     """
     apply: Callable[[Any, jax.Array, jax.Array, bool], jax.Array]
     params: Any
     in_shape: tuple[int, ...]
     shards: tuple | None = None
     expert_shards: tuple | None = None
+    token_input: bool = False
+
+    def cast_input(self, x: jax.Array, compute_dtype) -> jax.Array:
+        """``x`` in the mixed-precision compute dtype, unless it is ids."""
+        return x if self.token_input else x.astype(compute_dtype)
 
 
 class Pipeline:
@@ -480,7 +492,7 @@ class Pipeline:
                     if compute_dtype is not None:
                         params = jax.tree.map(
                             lambda a: a.astype(compute_dtype), params)
-                        x = x.astype(compute_dtype)
+                        x = self.stages[s].cast_input(x, compute_dtype)
                     y = applies[s](params, x, k, deterministic)
                     aux = jnp.float32(0.0)
                     if isinstance(y, tuple):
@@ -865,7 +877,7 @@ class Pipeline:
         if self.compute_dtype is not None:
             params = jax.tree.map(
                 lambda a: a.astype(self.compute_dtype), params)
-            xs = xs.astype(self.compute_dtype)
+            xs = stage.cast_input(xs, self.compute_dtype)
         k = jax.random.fold_in(
             jax.random.fold_in(jax.random.fold_in(key, 0), 0), 0)
         out = stage.apply(params, xs, k, deterministic)

@@ -25,9 +25,6 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-from simple_distributed_machine_learning_tpu.parallel.compat import (
-    axis_size as _axis_size,
-)
 
 from simple_distributed_machine_learning_tpu.ops.attention import (
     SEQ_AXIS,
@@ -51,7 +48,7 @@ def ulysses_attention(params: dict, x: jax.Array, n_heads: int,
     crossing devices — causality is exact); the reverse ``all_to_all``
     restores sequence sharding for the output projection.
     """
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     if n_heads % s:
         raise ValueError(f"{n_heads} heads not divisible by axis size {s}")
     b, t_loc, d = x.shape

@@ -23,6 +23,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from simple_distributed_machine_learning_tpu.ops.layers import linear_init
+from simple_distributed_machine_learning_tpu.parallel.compat import (
+    ct_like,
+    pvary_to,
+)
 from simple_distributed_machine_learning_tpu.parallel.mesh import MODEL_AXIS
 
 
@@ -86,14 +90,19 @@ def _grad_sync_fwd(x, axis, overlap):
 
 
 def _grad_sync_bwd(axis, overlap, _, ct):
+    # the primal is the per-device (axis-varying) storage leaf and the
+    # output IS the primal, so ct carries the primal's vma; psum types its
+    # result axis-invariant, so cast it back up to the primal's type
     if overlap == "ring":
         from simple_distributed_machine_learning_tpu.parallel.overlap import (
             _bwd_perm,
             _ring_psum_impl,
         )
-        return (_ring_psum_impl(ct, axis, perm_fn=_bwd_perm,
-                                tag="grad_sync_ring"),)
-    return (lax.psum(ct, axis),)
+        red = _ring_psum_impl(ct, axis, perm_fn=_bwd_perm,
+                              tag="grad_sync_ring")
+    else:
+        red = lax.psum(ct, axis)
+    return (ct_like(red, ct),)
 
 
 grad_sync.defvjp(_grad_sync_fwd, _grad_sync_bwd)
@@ -124,9 +133,6 @@ def tp_pair_apply(params: dict, x: jax.Array, activation=jax.nn.relu,
     z = h @ params["w2"]["w"]
     bias = lax.pmean(grad_sync(params["w2"]["b"], axis, overlap), axis)
     if overlap == "ring":
-        from simple_distributed_machine_learning_tpu.parallel.compat import (
-            pvary_to,
-        )
         from simple_distributed_machine_learning_tpu.parallel.overlap import (
             ring_psum,
         )

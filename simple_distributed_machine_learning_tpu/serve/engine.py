@@ -694,7 +694,7 @@ class InferenceEngine:
         full budget refund, same release path as retirement — and the
         handle lands in ``SHED`` with ``finish_reason = reason``. The
         supervisor's deadline/overload shedding calls this; metrics
-        accounting is the CALLER's job (it knows the reason taxonomy)."""
+        accounting is the CALLER's job (it knows the set of reasons)."""
         r = self.requests[rid]
         if r.state not in (QUEUED, ACTIVE):
             raise ValueError(

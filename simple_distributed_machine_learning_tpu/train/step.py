@@ -150,7 +150,7 @@ def make_scanned_train_step(pipe: Pipeline, opt: Optimizer, unroll: int = 1,
                 if pipe.compute_dtype is not None:
                     pp = jax.tree.map(
                         lambda a: a.astype(pipe.compute_dtype), pp)
-                    xs = xs.astype(pipe.compute_dtype)
+                    xs = stage.cast_input(xs, pipe.compute_dtype)
                 out = stage.apply(pp, xs, kk, False)
                 import jax.numpy as jnp
                 aux = jnp.float32(0.0)

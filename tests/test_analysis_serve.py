@@ -110,7 +110,7 @@ def test_trace_recursion_reaches_serve_primitives(stages):
                 is_leaf=lambda a: hasattr(a, "sds"))
             prims |= all_primitives(trace_to_jaxpr(prog.fn, *plain))
     assert {"scatter", "gather", "dynamic_update_slice", "dynamic_slice",
-            "scan", "pjit", "argmax", "concatenate", "iota"} <= prims
+            "scan", "jit", "argmax", "concatenate", "iota"} <= prims
 
 
 def test_hbm_table_present_and_ranked(stages):

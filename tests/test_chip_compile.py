@@ -1,0 +1,147 @@
+"""The Pallas kernels of the main path, compiled by the TPU compiler for a
+described (not attached) ``v5e:2x2`` chip at the widths the chip smoke runs.
+
+Nothing executes: a pass says Mosaic accepts the kernel at that shape, dtype
+and layout — what interpret mode (every other kernel test here) cannot say.
+The whole train step and serve tick programs are compiled the same way by a
+scratch script before a chip call; they are too slow to keep as tests.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library, and every xdist worker imports this
+file. ``_interpret()`` asks ``jax.default_backend()`` and would answer
+"interpret" on the CPU the suite runs on, so the test steers it (monkeypatch).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from simple_distributed_machine_learning_tpu.ops import (
+    flash_attention as fa,
+    paged_attention as pa,
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described-topology compile can be written to the persistent cache
+    # but never read back without a chip: keep the cache off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Make the kernels take their on-chip branch (compiled, not interpret).
+    ``paged_attention`` imported the function by name, so both are set."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# -- paged attention: the serve tick's kernel -------------------------------
+
+H = 16          # chip_smoke's model: 16 heads
+SLOTS = 8
+SEQ = 512
+
+_PAGED = ([(dh, bs, 1, pool)
+           for dh in (64, 128) for bs in (4, 16, 128)
+           for pool in ("float32", "bfloat16", "int8")]
+          # the speculative verify width on the deployment block size
+          + [(dh, 16, 4, pool)
+             for dh in (64, 128) for pool in ("float32", "bfloat16", "int8")])
+
+
+@pytest.mark.parametrize("dh,bs,k,pool", _PAGED)
+def test_paged_attention_compiles_for_v5e(one_chip, mosaic, dh, bs, k, pool):
+    """The shapes and dtypes ``models/gpt.py::_paged_attend`` passes: f32
+    queries (serving computes in f32), one layer's pool ``[n_blocks+1, H,
+    bs, dh]`` in the cache dtype, and for a quantized pool the ``QuantKV``
+    scale planes ``[n_blocks+1, H, bs]`` in f32."""
+    nb = SEQ // bs
+    n_phys = SLOTS * nb + 1
+    quant = pool == "int8"
+    shapes = [((SLOTS, H, k, dh), jnp.float32),
+              ((n_phys, H, bs, dh), jnp.dtype(pool)),
+              ((n_phys, H, bs, dh), jnp.dtype(pool)),
+              ((SLOTS, nb), jnp.int32),
+              ((SLOTS, k), jnp.int32)]
+    if quant:
+        shapes += [((n_phys, H, bs), jnp.float32)] * 2
+
+    def fn(q, kc, vc, tables, qpos, *scales):
+        kw = dict(kscale=scales[0], vscale=scales[1]) if quant else {}
+        return pa.paged_attention(q, kc, vc, tables, qpos, block_size=bs,
+                                  **kw)
+
+    # "auto" resolves to the packed layout (pool transposed so positions
+    # take the lanes) below a lane multiple, to the natural one at 128
+    jaxpr = str(jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in shapes]))
+    assert ("transpose" in jaxpr) == (dh % 128 != 0)
+    _compile(fn, one_chip, *shapes)
+
+
+def test_paged_attention_compiles_with_bf16_queries(one_chip, mosaic):
+    """A bf16 query against a bf16 pool (what a bf16-compute serve build
+    would pass): the kernel widens both in VMEM."""
+    bs, dh = 16, 64
+    nb = SEQ // bs
+    shapes = [((SLOTS, H, 1, dh), jnp.bfloat16),
+              ((SLOTS * nb + 1, H, bs, dh), jnp.bfloat16),
+              ((SLOTS * nb + 1, H, bs, dh), jnp.bfloat16),
+              ((SLOTS, nb), jnp.int32), ((SLOTS, 1), jnp.int32)]
+    _compile(lambda q, kc, vc, t, p: pa.paged_attention(
+        q, kc, vc, t, p, block_size=bs), one_chip, *shapes)
+
+
+# -- flash attention: the train step's kernel -------------------------------
+
+_FLASH = [((8, 16, 512, 64), "bfloat16"),      # chip_smoke's train step
+          ((8, 16, 512, 64), "float32"),
+          ((2, 8, 2048, 128), "bfloat16"),
+          ((2, 8, 2048, 128), "float32")]
+
+
+@pytest.mark.parametrize("shape,dtype", _FLASH)
+def test_flash_attention_forward_compiles_for_v5e(one_chip, mosaic, shape,
+                                                  dtype):
+    _compile(fa.flash_attention, one_chip, *[(shape, jnp.dtype(dtype))] * 3)
+
+
+@pytest.mark.parametrize("shape,dtype", _FLASH)
+def test_flash_attention_gradient_compiles_for_v5e(one_chip, mosaic, shape,
+                                                   dtype):
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v).astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+             *[(shape, jnp.dtype(dtype))] * 3)

@@ -21,7 +21,6 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
 )
 from simple_distributed_machine_learning_tpu.models.mlp import make_mlp_stages
 from simple_distributed_machine_learning_tpu.ops.losses import nll_loss
-from simple_distributed_machine_learning_tpu.parallel.compat import HAS_VMA
 from simple_distributed_machine_learning_tpu.parallel.mesh import make_mesh
 from simple_distributed_machine_learning_tpu.parallel.pipeline import Pipeline
 
@@ -63,10 +62,6 @@ def test_eval_metrics_gpt_pp_dp_weighted():
     _check(pipe, buf, x, y, jax.random.key(3), mask)
 
 
-@pytest.mark.skipif(
-    not HAS_VMA,
-    reason="branch-divergent ppermute rings deadlock on old jax's XLA:CPU "
-           "collective-permute rendezvous")
 def test_eval_metrics_gpt_seq_parallel():
     cfg = GPTConfig(vocab=32, seq_len=16, d_model=32, n_heads=2, n_layers=2,
                     attn_impl="ring", n_seq=2)

@@ -248,3 +248,17 @@ def test_monitor_kills_stopped_parent():
             pass
         parent.wait()
         mon.stdin.close()
+
+
+def test_monitor_module_imports_no_jax():
+    """The monitor child shares its parent's environment (no scrubbing), so
+    what keeps it off the chip the parent holds is that its whole import
+    chain — package ``__init__``, ``utils``, ``resilience.faults`` — never
+    imports jax."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; "
+         "import simple_distributed_machine_learning_tpu.utils.failure; "
+         "sys.exit(1 if 'jax' in sys.modules else 0)"],
+        cwd=REPO, capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr[-2000:]

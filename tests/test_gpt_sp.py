@@ -17,16 +17,6 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
     make_gpt_stages,
 )
-from simple_distributed_machine_learning_tpu.parallel.compat import HAS_VMA
-
-# ring attention's ppermutes sit inside the engine's per-stage lax.switch
-# branches; old jax's XLA:CPU collective-permute rendezvous is global across
-# devices, so branch-divergent rings deadlock there instead of failing (on
-# TPU, and on modern jax's partitioned lowering, the permutes are
-# independent). Skip rather than hang the suite.
-ring_in_pipeline = pytest.param("ring", marks=pytest.mark.skipif(
-    not HAS_VMA, reason="branch-divergent ppermute rings deadlock on old "
-                        "jax's XLA:CPU collective-permute rendezvous"))
 from simple_distributed_machine_learning_tpu.parallel.mesh import make_mesh
 from simple_distributed_machine_learning_tpu.parallel.pipeline import Pipeline
 from simple_distributed_machine_learning_tpu.train.optimizer import sgd
@@ -55,7 +45,7 @@ def _sp_pipe(attn, n_micro=2):
     return Pipeline(stages, mesh, wd, od, n_microbatches=n_micro)
 
 
-@pytest.mark.parametrize("attn", [ring_in_pipeline, "ulysses"])
+@pytest.mark.parametrize("attn", ["ring", "ulysses"])
 def test_gpt_sp_loss_and_logits_match_dense(attn):
     x, y = _data(jax.random.key(1), 4)
     key = jax.random.key(2)
@@ -71,7 +61,7 @@ def test_gpt_sp_loss_and_logits_match_dense(attn):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("attn", [ring_in_pipeline, "ulysses"])
+@pytest.mark.parametrize("attn", ["ring", "ulysses"])
 def test_gpt_sp_sgd_trajectory_matches_dense(attn):
     """Two SGD(momentum) steps: the seq-sharded engine's gradients (through
     ppermute stage hops AND the attention collective) must reproduce the

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from simple_distributed_machine_learning_tpu.models.mlp import make_mlp_stages
 from simple_distributed_machine_learning_tpu.parallel.mesh import make_mesh
@@ -87,3 +88,46 @@ def test_bf16_scanned_fast_path():
     assert buf.dtype == jnp.float32
     assert np.isfinite(np.asarray(losses)).all()
     assert float(losses[-1]) < float(losses[0]) + 0.5
+
+
+@pytest.mark.parametrize("path", ["fused", "gpipe", "1f1b", "scanned"])
+def test_bf16_gpt_keeps_token_ids_exact(path):
+    """Token ids ride into the first GPT stage as float32 and must reach its
+    embedding lookup EXACT under bf16 compute, on every engine path. A bf16
+    cast keeps 8 significant bits: it rounds every id above 256 and sends
+    8191 to 8192 — out of the vocabulary, which ``jnp.take`` fills with NaN
+    (the ``final_loss: NaN`` of the bf16 GPT bench rows). So: the top ids of
+    a real-sized vocabulary give a finite loss that tracks f32 compute."""
+    from simple_distributed_machine_learning_tpu.models.gpt import (
+        GPTConfig,
+        make_gpt_stages,
+    )
+    from simple_distributed_machine_learning_tpu.train.optimizer import sgd
+
+    cfg = GPTConfig(vocab=8192, seq_len=8, d_model=16, n_heads=2, n_layers=2)
+    n_stages = 1 if path in ("fused", "scanned") else 2
+    stages, wd, od = make_gpt_stages(jax.random.key(0), cfg, n_stages)
+    assert [s.token_input for s in stages] == [True] + [False] * (n_stages - 1)
+    ids = jnp.array([[8191, 8190, 8177, 4097, 1025, 513, 257, 3]] * 4)
+    x, y = ids.astype(jnp.float32), jnp.roll(ids, -1, axis=1)
+    mesh = make_mesh(n_stages=n_stages, n_data=1)
+
+    def loss(dtype):
+        pipe = Pipeline(stages, mesh, wd, od, n_microbatches=2,
+                        compute_dtype=dtype,
+                        schedule="1f1b" if path == "1f1b" else "gpipe")
+        buf = pipe.init_params()
+        if path == "scanned":
+            opt = sgd(0.1)
+            step = make_scanned_train_step(pipe, opt)
+            _, _, losses = step(buf, opt.init(buf), x[None], y[None],
+                                jax.random.key(1))
+            return float(losses[0])
+        l, g = jax.jit(lambda b: pipe.loss_and_grads(
+            b, x, y, jax.random.key(1), deterministic=True))(buf)
+        assert bool(jnp.isfinite(g).all())
+        return float(l)
+
+    l32, l16 = loss(None), loss(jnp.bfloat16)
+    assert np.isfinite(l16)
+    np.testing.assert_allclose(l16, l32, rtol=3e-2)

@@ -227,31 +227,11 @@ def test_1f1b_seq_parallel_matches_gpipe(attn):
     import subprocess
     import sys
 
-    from simple_distributed_machine_learning_tpu.parallel.compat import (
-        HAS_VMA,
-    )
-
-    if attn == "ring" and not HAS_VMA:
-        # ring attention's ppermutes live inside the stage switch: on old
-        # jax's XLA:CPU the global collective-permute rendezvous deadlocks
-        # under the branch-skewed execution (the documented CPU caveat —
-        # statically flagged by analysis/ as ppermute-deadlock.ring-in-branch
-        # and pinned by tests/test_analysis.py); the subprocess would hang
-        # to its timeout. Ulysses (all_to_all) remains the old-jax gate.
-        pytest.skip("old jax: branch-divergent ppermute rings deadlock "
-                    "XLA:CPU (analysis/ flags this shape statically)")
-
     code = f"""
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-jax.config.update("jax_platforms", "cpu")
-# version-tolerant 8-virtual-device setup: jax_num_cpu_devices where it
-# exists, the XLA_FLAGS route (set above) everywhere else — same shim as
-# tests/conftest.py (a bare config.update AttributeErrors on old jax)
-from simple_distributed_machine_learning_tpu.parallel.compat import set_host_device_count
-set_host_device_count(8)
 from simple_distributed_machine_learning_tpu.models.gpt import GPTConfig, make_gpt_stages
 from simple_distributed_machine_learning_tpu.parallel.mesh import make_mesh
 from simple_distributed_machine_learning_tpu.parallel.pipeline import Pipeline

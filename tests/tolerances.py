@@ -7,28 +7,14 @@ STORAGE dtype, not of the individual test. Pinning it here ends the
 per-test magic-number drift that left one bf16 comparison strict enough
 to flake on backends whose accumulation order differs (the PR-15
 known-env failure: bf16 beam decode flipping a near-tie ordering).
+
+``attn_tol`` lives in the package (``utils/tolerances.py``) so that
+``chip_smoke.py`` holds the chip to the same pins; it is re-exported here.
 """
 
-import jax.numpy as jnp
-
-
-def attn_tol(dtype) -> tuple[float, float]:
-    """``(rtol, atol)`` for outputs computed through K/V stored as
-    ``dtype``. f32 allows accumulation-order ulps only; bf16 allows its
-    ~3-decimal-bit rounding through one attention round trip; quantized
-    dtypes allow their per-row amax/qmax quantization step."""
-    d = jnp.dtype(dtype)
-    if d == jnp.dtype(jnp.float32):
-        return (1e-5, 1e-5)
-    if d == jnp.dtype(jnp.float16):
-        return (2e-3, 2e-3)
-    if d == jnp.dtype(jnp.bfloat16):
-        return (5e-2, 5e-2)
-    if d == jnp.dtype(jnp.int8):
-        return (6e-2, 6e-2)
-    if d.name.startswith("float8"):
-        return (1.5e-1, 1.5e-1)
-    raise ValueError(f"no pinned attention tolerance for dtype {d.name}")
+from simple_distributed_machine_learning_tpu.utils.tolerances import (  # noqa: F401
+    attn_tol,
+)
 
 
 def near_tie_token_mismatch_budget() -> float:
