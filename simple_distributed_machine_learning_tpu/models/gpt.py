@@ -850,9 +850,10 @@ def _tp_adapter_layers(bank, aid, tp):
     return at
 
 
-def _tp_jit(body, mesh, n_buf_in, n_rest_in, n_buf_out, n_rest_out,
+def _tp_jit(name, body, mesh, n_buf_in, n_rest_in, n_buf_out, n_rest_out,
             donate=(1, 2)):
-    """``jit(shard_map(body))`` with the serving specs: params as the
+    """``jit(shard_map(body))`` with the serving specs, the program called
+    ``name`` (what a device trace shows it as): params as the
     ``(stacked blocks, replicated embed/head)`` pair, ``n_buf_in`` K/V pool
     buffers sharded on their HEAD axis (dim 2 in both layouts), everything
     else replicated. The pool buffers are donated exactly as in the
@@ -870,6 +871,7 @@ def _tp_jit(body, mesh, n_buf_in, n_rest_in, n_buf_out, n_rest_out,
                 + (P(),) * n_rest_in)
     out_specs = (cache,) * n_buf_out + (P(),) * n_rest_out
     fn = _shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn.__name__ = name
     return functools.partial(jax.jit, donate_argnums=donate)(fn)
 
 
@@ -1377,16 +1379,16 @@ def _build_slot_prefill_tp(cfg, mesh, adapters=False):
                        temperature, top_k, top_p,
                        _tp_adapter_layers(bank, aid, tp))
 
-        return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=8, n_buf_out=2,
-                       n_rest_out=2)
+        return _tp_jit("prefill_dense_tp", body, mesh,
+                       n_buf_in=2, n_rest_in=8, n_buf_out=2, n_rest_out=2)
 
     def body(params, kc, vc, prompt, slot, key_data, temperature,
              top_k, top_p):
         return run(params, kc, vc, prompt, slot, key_data, temperature,
                    top_k, top_p)
 
-    return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=6, n_buf_out=2,
-                   n_rest_out=2)
+    return _tp_jit("prefill_dense_tp", body, mesh,
+                   n_buf_in=2, n_rest_in=6, n_buf_out=2, n_rest_out=2)
 
 
 def _dense_block_step_slots(bp, h, li, kc, vc, pos, n_heads,
@@ -1485,19 +1487,20 @@ def _build_slot_decode(H, adapters=False):
 
     if adapters:
         @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, kc, vc, toks, pos, key_data, temps, top_ks,
-                 top_ps, bank, aids):
+        def step_dense_decode(params, kc, vc, toks, pos, key_data, temps,
+                              top_ks, top_ps, bank, aids):
             return run(params, kc, vc, toks, pos, key_data, temps,
                        top_ks, top_ps, _adapter_layers(bank, aids))
 
-        return step
+        return step_dense_decode
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step(params, kc, vc, toks, pos, key_data, temps, top_ks, top_ps):
+    def step_dense_decode(params, kc, vc, toks, pos, key_data, temps,
+                          top_ks, top_ps):
         return run(params, kc, vc, toks, pos, key_data, temps, top_ks,
                    top_ps)
 
-    return step
+    return step_dense_decode
 
 
 def _build_slot_decode_tp(cfg, mesh, adapters=False):
@@ -1521,15 +1524,15 @@ def _build_slot_decode_tp(cfg, mesh, adapters=False):
             return run(params, kc, vc, toks, pos, key_data, temps,
                        top_ks, top_ps, _tp_adapter_layers(bank, aids, tp))
 
-        return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=8, n_buf_out=2,
-                       n_rest_out=2)
+        return _tp_jit("step_dense_decode_tp", body, mesh,
+                       n_buf_in=2, n_rest_in=8, n_buf_out=2, n_rest_out=2)
 
     def body(params, kc, vc, toks, pos, key_data, temps, top_ks, top_ps):
         return run(params, kc, vc, toks, pos, key_data, temps, top_ks,
                    top_ps)
 
-    return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=6, n_buf_out=2,
-                   n_rest_out=2)
+    return _tp_jit("step_dense_decode_tp", body, mesh,
+                   n_buf_in=2, n_rest_in=6, n_buf_out=2, n_rest_out=2)
 
 
 def _validate_paged_build(stages, cfg: GPTConfig, max_len: int,
@@ -1700,21 +1703,21 @@ def _build_paged_prefill_chunk(H, bs, dh, adapters=False):
 
     if adapters:
         @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def chunk(params, kc, vc, tokens, p0, table, key_data,
-                  temperature, top_k, top_p, bank, aid):
+        def chunk_paged_prefill(params, kc, vc, tokens, p0, table, key_data,
+                                temperature, top_k, top_p, bank, aid):
             return run(params, kc, vc, tokens, p0, table, key_data,
                        temperature, top_k, top_p,
                        _adapter_layers(bank, aid))
 
-        return chunk
+        return chunk_paged_prefill
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def chunk(params, kc, vc, tokens, p0, table, key_data, temperature,
-              top_k, top_p):
+    def chunk_paged_prefill(params, kc, vc, tokens, p0, table, key_data,
+                            temperature, top_k, top_p):
         return run(params, kc, vc, tokens, p0, table, key_data,
                    temperature, top_k, top_p)
 
-    return chunk
+    return chunk_paged_prefill
 
 
 def _build_paged_prefill_chunk_tp(cfg, bs, dh, mesh, adapters=False):
@@ -1739,16 +1742,16 @@ def _build_paged_prefill_chunk_tp(cfg, bs, dh, mesh, adapters=False):
                        temperature, top_k, top_p,
                        _tp_adapter_layers(bank, aid, tp))
 
-        return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=9, n_buf_out=2,
-                       n_rest_out=2)
+        return _tp_jit("chunk_paged_prefill_tp", body, mesh,
+                       n_buf_in=2, n_rest_in=9, n_buf_out=2, n_rest_out=2)
 
     def body(params, kc, vc, tokens, p0, table, key_data, temperature,
              top_k, top_p):
         return run(params, kc, vc, tokens, p0, table, key_data,
                    temperature, top_k, top_p)
 
-    return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=7, n_buf_out=2,
-                   n_rest_out=2)
+    return _tp_jit("chunk_paged_prefill_tp", body, mesh,
+                   n_buf_in=2, n_rest_in=7, n_buf_out=2, n_rest_out=2)
 
 
 def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
@@ -1851,20 +1854,20 @@ def _build_paged_decode_step(H, bs, dh, kernel="dense", adapters=False):
 
     if adapters:
         @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, kc, vc, toks, pos, tables, key_data, temps,
-                 top_ks, top_ps, bank, aids):
+        def step_paged_decode(params, kc, vc, toks, pos, tables, key_data,
+                              temps, top_ks, top_ps, bank, aids):
             return run(params, kc, vc, toks, pos, tables, key_data,
                        temps, top_ks, top_ps, _adapter_layers(bank, aids))
 
-        return step
+        return step_paged_decode
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step(params, kc, vc, toks, pos, tables, key_data, temps, top_ks,
-             top_ps):
+    def step_paged_decode(params, kc, vc, toks, pos, tables, key_data,
+                          temps, top_ks, top_ps):
         return run(params, kc, vc, toks, pos, tables, key_data, temps,
                    top_ks, top_ps)
 
-    return step
+    return step_paged_decode
 
 
 def _build_paged_decode_step_tp(cfg, bs, dh, mesh, kernel="dense",
@@ -1891,16 +1894,16 @@ def _build_paged_decode_step_tp(cfg, bs, dh, mesh, kernel="dense",
                        temps, top_ks, top_ps,
                        _tp_adapter_layers(bank, aids, tp))
 
-        return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=9, n_buf_out=2,
-                       n_rest_out=2)
+        return _tp_jit("step_paged_decode_tp", body, mesh,
+                       n_buf_in=2, n_rest_in=9, n_buf_out=2, n_rest_out=2)
 
     def body(params, kc, vc, toks, pos, tables, key_data, temps, top_ks,
              top_ps):
         return run(params, kc, vc, toks, pos, tables, key_data, temps,
                    top_ks, top_ps)
 
-    return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=7, n_buf_out=2,
-                   n_rest_out=2)
+    return _tp_jit("step_paged_decode_tp", body, mesh,
+                   n_buf_in=2, n_rest_in=7, n_buf_out=2, n_rest_out=2)
 
 
 def make_paged_block_copy():
@@ -2277,16 +2280,16 @@ def _build_slot_verify_tp(cfg, K, ml, mesh, adapters=False):
                        valid_n, key_data, temps, top_ks, top_ps,
                        _tp_adapter_layers(bank, aids, tp))
 
-        return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=11, n_buf_out=2,
-                       n_rest_out=3)
+        return _tp_jit("verify_dense_tp", body, mesh,
+                       n_buf_in=2, n_rest_in=11, n_buf_out=2, n_rest_out=3)
 
     def body(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
              key_data, temps, top_ks, top_ps):
         return run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
                    key_data, temps, top_ks, top_ps)
 
-    return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=9, n_buf_out=2,
-                   n_rest_out=3)
+    return _tp_jit("verify_dense_tp", body, mesh,
+                   n_buf_in=2, n_rest_in=9, n_buf_out=2, n_rest_out=3)
 
 
 def _paged_verify_fwd(blocks, embed, head, kc, vc, xs, qpos, wphys, woff,
@@ -2436,16 +2439,16 @@ def _build_paged_verify_step_tp(cfg, K, ml, bs, dh, mesh, kernel="dense",
                        valid_n, tables, key_data, temps, top_ks, top_ps,
                        _tp_adapter_layers(bank, aids, tp))
 
-        return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=12, n_buf_out=2,
-                       n_rest_out=3)
+        return _tp_jit("verify_paged_tp", body, mesh,
+                       n_buf_in=2, n_rest_in=12, n_buf_out=2, n_rest_out=3)
 
     def body(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
              tables, key_data, temps, top_ks, top_ps):
         return run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
                    tables, key_data, temps, top_ks, top_ps)
 
-    return _tp_jit(body, mesh, n_buf_in=2, n_rest_in=10, n_buf_out=2,
-                   n_rest_out=3)
+    return _tp_jit("verify_paged_tp", body, mesh,
+                   n_buf_in=2, n_rest_in=10, n_buf_out=2, n_rest_out=3)
 
 
 def _check_spec_tick_build(cfg: GPTConfig, draft_cfg: GPTConfig,

@@ -222,6 +222,7 @@ def _flash_fwd_call(q, k, v, block_q: int, block_k: int):
         ],
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(qp, kp, vp)
     o = o.reshape(b, h, tq, dp)[:, :, :t, :dh]
     l = l.reshape(b, h, tq)[:, :, :t]
@@ -408,6 +409,7 @@ def _flash_bwd(block_q, block_k, res, do):
         scratch_shapes=[pltpu.VMEM((block_q, dp_), jnp.float32)],
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name="flash_attention_dq",
     )(qp, kp, vp, dop, mp, linvp, dlp)
 
     # dkv grid: (bh, k-block, q-block) — index maps select by the axis kind.
@@ -442,6 +444,7 @@ def _flash_bwd(block_q, block_k, res, do):
         ],
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name="flash_attention_dkv",
     )(kp, vp, qp, dop, mp, linvp, dlp)
 
     dq = dq.reshape(b, h, tq, dp_)[:, :, :t, :dh]

@@ -249,6 +249,7 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
         # slots are independent; the k-block axis carries scratch state
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), qpos.astype(jnp.int32), *operands)
     return out[..., :dh]
 

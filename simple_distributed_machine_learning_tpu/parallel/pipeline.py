@@ -57,6 +57,7 @@ from simple_distributed_machine_learning_tpu.parallel.staging import (
     wire_decode,
     wire_encode,
 )
+from simple_distributed_machine_learning_tpu.telemetry import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,26 +217,39 @@ class Pipeline:
                     per_shard.extend(s.expert_shards)
                 else:
                     per_shard.extend([mt] * self.n_expert)
-        flat, metas_all = pack_stage_params(per_shard)
-        import numpy as np
-        # keep the master copy on the HOST: device_put of an on-device array
-        # with a matching sharding ALIASES it, and a later donated train step
-        # would delete the alias — init_params() must survive any number of
-        # donating steps
-        self._buf0 = np.asarray(jax.device_get(flat.reshape(
-            self.n_stages, self.n_model, self.n_expert, -1)))
-        # shard 0's layout stands for the stage (shards are shape-identical)
-        stride = self.n_model * self.n_expert
-        self.metas = metas_all[::stride]
-        for s, stage in enumerate(self.stages):
-            if stage.shards is not None or stage.expert_shards is not None:
-                m0 = metas_all[s * stride]
-                for m in metas_all[s * stride:(s + 1) * stride]:
-                    if m.shapes != m0.shapes:
-                        raise ValueError(
-                            f"stage {s}: model/expert shards have differing "
-                            f"leaf shapes — sharded params must split evenly")
-        self._validate_boundaries()
+        # set-up by phase (telemetry/tracing.py): the eager pack on the
+        # first device, the row's trip to the host, the rest of the build
+        with tracing.span("pipeline.init") as init:
+            with tracing.span("pipeline.pack"):
+                flat, metas_all = pack_stage_params(per_shard)
+                # the pack is dispatched eagerly: wait here, or its time
+                # reads as the transfer's
+                jax.block_until_ready(flat)
+            import numpy as np
+            # keep the master copy on the HOST: device_put of an on-device
+            # array with a matching sharding ALIASES it, and a later donated
+            # train step would delete the alias — init_params() must survive
+            # any number of donating steps
+            nbytes = int(flat.nbytes)
+            init.set(bytes=nbytes)
+            with tracing.span("pipeline.to_host", bytes=nbytes):
+                self._buf0 = np.asarray(jax.device_get(flat.reshape(
+                    self.n_stages, self.n_model, self.n_expert, -1)))
+            # shard 0's layout stands for the stage (shards are
+            # shape-identical)
+            stride = self.n_model * self.n_expert
+            self.metas = metas_all[::stride]
+            for s, stage in enumerate(self.stages):
+                if (stage.shards is not None
+                        or stage.expert_shards is not None):
+                    m0 = metas_all[s * stride]
+                    for m in metas_all[s * stride:(s + 1) * stride]:
+                        if m.shapes != m0.shapes:
+                            raise ValueError(
+                                f"stage {s}: model/expert shards have "
+                                f"differing leaf shapes — sharded params "
+                                f"must split evenly")
+            self._validate_boundaries()
 
     def _validate_boundaries(self) -> None:
         """Shape-check every stage hop at build time (via eval_shape — no FLOPs).

@@ -62,6 +62,7 @@ from simple_distributed_machine_learning_tpu.serve.slots import (
     KVCachePool,
     PagedKVPool,
 )
+from simple_distributed_machine_learning_tpu.telemetry import tracing
 
 # sampling-param sentinels (models/gpt.py::_sample_dyn): 0 disables top-k,
 # anything > 1 disables top-p
@@ -438,52 +439,57 @@ class InferenceEngine:
         engine is the no-deadline baseline)."""
         import jax
 
-        # fault-injection site: a crash while the request is being accepted
-        # (journaled by the supervisor but never admitted — the recovery
-        # corner serve/supervisor.py re-admits from the journal alone)
-        maybe_fire("serve.admit", step=self._next_rid)
-        prompt = np.asarray(prompt, np.int32)
-        validate_request(prompt, max_new_tokens, temperature, top_k, top_p,
-                         self.cfg.vocab, self.max_len)
-        for name, v in (("ttft_deadline_s", ttft_deadline_s),
-                        ("deadline_s", deadline_s)):
-            if v is not None and v <= 0:
-                raise ValueError(f"{name} must be > 0, got {v}")
-        self._check_adapter(adapter)
-        rid = self._next_rid
-        self._next_rid += 1
-        seed = rid if seed is None else seed
-        r = Request(rid=rid, prompt=prompt, max_new_tokens=max_new_tokens,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    eos_id=eos_id, seed=seed, on_token=on_token,
-                    cls=cls, priority=priority,
-                    ttft_deadline_s=ttft_deadline_s, deadline_s=deadline_s,
-                    adapter=adapter)
-        if self._adapters is not None:
-            # the version-qualified prefix-cache namespace (refreshed at
-            # the admission gate — the probe and the decode must agree on
-            # the adapter VERSION or a hot-swap could reuse stale K/V)
-            r._prefix_ns = self._adapters.namespace_of(adapter)
-        # the request's independent key stream — the SAME key a solo
-        # make_cached_decoder call would be handed, so streams align
-        r.key_data = np.asarray(jax.random.key_data(jax.random.key(seed)))
-        if self.speculative:
-            # the draft's own stream, derived but disjoint (fold_in), so
-            # sampled proposals never consume the target's splits — greedy
-            # consumes neither, which is what keeps greedy speculative
-            # decode bit-exact vs solo
-            r.draft_key_data = np.asarray(jax.random.key_data(
-                jax.random.fold_in(jax.random.key(seed), 1)))
-        r.submit_time = (self._clock() if arrival_time is None
-                         else arrival_time)
-        self._now = max(self._now, r.submit_time)
-        self.requests[rid] = r
-        self.scheduler.enqueue(r)
-        if self.metrics is not None:
-            self.metrics.on_submit()
-        if self.trace is not None:
-            self.trace.on_submit(r, r.submit_time)
-        return r
+        with tracing.span("engine.submit") as sp:
+            # fault-injection site: a crash while the request is being
+            # accepted (journaled by the supervisor but never admitted — the
+            # recovery corner serve/supervisor.py re-admits from the journal
+            # alone)
+            maybe_fire("serve.admit", step=self._next_rid)
+            prompt = np.asarray(prompt, np.int32)
+            validate_request(prompt, max_new_tokens, temperature, top_k,
+                             top_p, self.cfg.vocab, self.max_len)
+            for name, v in (("ttft_deadline_s", ttft_deadline_s),
+                            ("deadline_s", deadline_s)):
+                if v is not None and v <= 0:
+                    raise ValueError(f"{name} must be > 0, got {v}")
+            self._check_adapter(adapter)
+            rid = self._next_rid
+            self._next_rid += 1
+            sp.set(rid=rid)
+            seed = rid if seed is None else seed
+            r = Request(rid=rid, prompt=prompt,
+                        max_new_tokens=max_new_tokens,
+                        temperature=temperature, top_k=top_k, top_p=top_p,
+                        eos_id=eos_id, seed=seed, on_token=on_token,
+                        cls=cls, priority=priority,
+                        ttft_deadline_s=ttft_deadline_s,
+                        deadline_s=deadline_s, adapter=adapter)
+            if self._adapters is not None:
+                # the version-qualified prefix-cache namespace (refreshed at
+                # the admission gate — the probe and the decode must agree on
+                # the adapter VERSION or a hot-swap could reuse stale K/V)
+                r._prefix_ns = self._adapters.namespace_of(adapter)
+            # the request's independent key stream — the SAME key a solo
+            # make_cached_decoder call would be handed, so streams align
+            r.key_data = np.asarray(
+                jax.random.key_data(jax.random.key(seed)))
+            if self.speculative:
+                # the draft's own stream, derived but disjoint (fold_in),
+                # so sampled proposals never consume the target's splits —
+                # greedy consumes neither, which is what keeps greedy
+                # speculative decode bit-exact vs solo
+                r.draft_key_data = np.asarray(jax.random.key_data(
+                    jax.random.fold_in(jax.random.key(seed), 1)))
+            r.submit_time = (self._clock() if arrival_time is None
+                             else arrival_time)
+            self._now = max(self._now, r.submit_time)
+            self.requests[rid] = r
+            self.scheduler.enqueue(r)
+            if self.metrics is not None:
+                self.metrics.on_submit()
+            if self.trace is not None:
+                self.trace.on_submit(r, r.submit_time)
+            return r
 
     # -- adapter plumbing --------------------------------------------------
 
@@ -566,56 +572,75 @@ class InferenceEngine:
         """
         if not self.busy:
             return 0
+        # the tick by phase (telemetry/tracing.py): every stretch of the
+        # tick lies in one child span, so that host time has a cause
+        with tracing.span("engine.tick", tick=self._tick_count + 1) as sp:
+            return self._tick(sp)
+
+    def _tick(self, sp) -> int:
+        """The body of a busy :meth:`step` under its ``engine.tick`` span
+        ``sp``, which is told at the end what the tick did."""
         # fault-injection site (resilience/faults.py): slow-tick stalls the
         # tick (a degraded device), wedged-device raises DeviceWedged —
         # no-op without an installed plan
         maybe_fire("serve.tick", step=self._tick_count)
         self._tick_count += 1
+        chunk = 0
         if self.kv_layout == "dense":
-            emitted = self._admit_dense()
+            with tracing.span("engine.admit") as admit:
+                emitted, boarded = self._admit_dense()
+                admit.set(boarded=boarded)
             # occupancy the batched decode actually RUNS at — sampled before
             # same-tick retirement so short requests cannot bias it low
             decode_active = self.pool.n_active
             emitted += (self._spec_tick(self.pool.active_slots())
                         if self.speculative else self._decode_tick_dense())
         else:
-            # host-tier upload progress FIRST: blocks completing this tick
-            # register before admission probes the prefix registry, so a
-            # request blocked on its own prefetch boards this very tick
-            self.pool.advance_transfers()
-            if self.trace is not None and getattr(self.pool, "_inflight",
-                                                  None):
-                # trace the upload gate: a queued request held back by its
-                # own in-flight prefetch gets ONE ``gate`` row per episode
-                # (attribution's queue-vs-prefetch split). Stamped with
-                # the most recent clock read, like paged admission — and
-                # only probed while uploads are actually in flight, so
-                # the common path pays one attribute test
-                for r in self.scheduler.queue:
-                    if (r.rid not in self._gated
-                            and self.pool.prefetch_blocked(r)):
-                        self._gated.add(r.rid)
-                        self.trace.on_gate(r, self._now)
-            self._admit_paged()
+            with tracing.span("engine.admit") as admit:
+                # host-tier upload progress FIRST: blocks completing this
+                # tick register before admission probes the prefix registry,
+                # so a request blocked on its own prefetch boards this very
+                # tick
+                self.pool.advance_transfers()
+                if self.trace is not None and getattr(self.pool, "_inflight",
+                                                      None):
+                    # trace the upload gate: a queued request held back by
+                    # its own in-flight prefetch gets ONE ``gate`` row per
+                    # episode (attribution's queue-vs-prefetch split).
+                    # Stamped with the most recent clock read, like paged
+                    # admission — and only probed while uploads are actually
+                    # in flight, so the common path pays one attribute test
+                    for r in self.scheduler.queue:
+                        if (r.rid not in self._gated
+                                and self.pool.prefetch_blocked(r)):
+                            self._gated.add(r.rid)
+                            self.trace.on_gate(r, self._now)
+                admit.set(boarded=self._admit_paged())
+            chunk = int(bool(self._prefilling))
             emitted = self._prefill_tick()
             decoding = self._decoding_slots()
             decode_active = len(decoding)
             emitted += (self._spec_tick(decoding) if self.speculative
                         else self._decode_tick_paged(decoding))
-        if self.metrics is not None:
-            live, predicted = self.kv_drift()
-            self.metrics.on_tick(
-                self.scheduler.queue_depth, self.pool.n_active,
-                self.pool.n_slots, decode_active=decode_active,
-                block_stats=(self.pool.stats()
-                             if self.kv_layout == "paged" else None),
-                tp=self.tp, spec_k=self.spec_k,
-                kv_predicted=predicted, kv_drift=live - predicted,
-                attn_kernel=self.attn_kernel,
-                adapter_stats=(self._adapters.stats()
-                               if self._adapters is not None else None))
-        if self.flight is not None:
-            self.flight.snap(self, self._tick_count, emitted)
+        if self.metrics is not None or self.flight is not None:
+            with tracing.span("engine.bookkeeping"):
+                if self.metrics is not None:
+                    live, predicted = self.kv_drift()
+                    self.metrics.on_tick(
+                        self.scheduler.queue_depth, self.pool.n_active,
+                        self.pool.n_slots, decode_active=decode_active,
+                        block_stats=(self.pool.stats()
+                                     if self.kv_layout == "paged" else None),
+                        tp=self.tp, spec_k=self.spec_k,
+                        kv_predicted=predicted, kv_drift=live - predicted,
+                        attn_kernel=self.attn_kernel,
+                        adapter_stats=(self._adapters.stats()
+                                       if self._adapters is not None
+                                       else None))
+                if self.flight is not None:
+                    self.flight.snap(self, self._tick_count, emitted)
+        sp.set(chunk=chunk, decoding=decode_active, emitted=emitted,
+               queue=self.scheduler.queue_depth)
         return emitted
 
     def kv_drift(self) -> tuple[int, int]:
@@ -790,9 +815,12 @@ class InferenceEngine:
 
     # -- dense tick internals ---------------------------------------------
 
-    def _admit_dense(self) -> int:
-        emitted = 0
+    def _admit_dense(self) -> tuple[int, int]:
+        """Board and prefill (one shot each) the requests the scheduler
+        admits; returns ``(tokens emitted, requests boarded)``."""
+        emitted = boarded = 0
         for r in self.scheduler.admit():
+            boarded += 1
             seq = r.resume_seq       # == r.prompt unless resuming preempted
             t0 = int(seq.shape[0])
             kc, vc, tok, kd = self._prefill(
@@ -841,37 +869,42 @@ class InferenceEngine:
                 self._finish(r, reason, now)
             else:
                 self.pool.seat(r.slot, t0, tok)
-        return emitted
+        return emitted, boarded
 
     def _decode_tick_dense(self) -> int:
         active = self.pool.active_slots()
         if not active:
             return 0
-        kd, temps, top_ks, top_ps = self._sampling_inputs(active)
-        kc, vc, toks, kd2 = self._decode(
-            self.params, self.pool.kc, self.pool.vc,
-            self.pool.last_token.copy(), self.pool.positions.copy(),
-            kd, temps, top_ks, top_ps,
-            *self._bank_args(self._adapter_inputs(active)))
-        self.pool.kc, self.pool.vc = kc, vc
+        with tracing.span("engine.decode.prepare"):
+            kd, temps, top_ks, top_ps = self._sampling_inputs(active)
+            args = (self.pool.last_token.copy(), self.pool.positions.copy(),
+                    kd, temps, top_ks, top_ps,
+                    *self._bank_args(self._adapter_inputs(active)))
+        with tracing.span("engine.decode.dispatch"):
+            kc, vc, toks, kd2 = self._decode(
+                self.params, self.pool.kc, self.pool.vc, *args)
+            self.pool.kc, self.pool.vc = kc, vc
         return self._emit_decoded(active, toks, kd2)
 
     # -- paged tick internals ---------------------------------------------
 
-    def _admit_paged(self) -> None:
+    def _admit_paged(self) -> int:
         """Board waiting requests. The scheduler's admit loop already bound
         each sequence to its slot (prefix matched, shared blocks
         referenced, worst-case budget reserved — ``PagedKVPool.bind_seq``)
         and parked the first position to compute in ``r.prefill_pos``. No
         model FLOPs here — prefill happens chunk by chunk in
-        :meth:`_prefill_tick`."""
+        :meth:`_prefill_tick`. Returns how many boarded."""
+        boarded = 0
         for r in self.scheduler.admit():
+            boarded += 1
             self._prefilling.append(r.rid)
             self._gated.discard(r.rid)
             if self.trace is not None:
                 # boarding performs no clock read; stamped with the most
                 # recent one (at most a tick stale, see serve/tracing.py)
                 self.trace.on_admit(r, self._now, r.slot)
+        return boarded
 
     def _prefill_tick(self) -> int:
         """At most ONE prefill chunk per tick — the scheduler's budget that
@@ -887,18 +920,31 @@ class InferenceEngine:
         p0 = r.prefill_pos
         c = (plen - p0 if self.prefill_chunk is None
              else min(self.prefill_chunk, plen - p0))
-        t_start = self._now = self._clock()
-        self._ensure_writable_range(r.slot, p0, c)
-        kc, vc, tok, kd = self._chunk_prefill(
-            self.params, self.pool.kc, self.pool.vc,
-            seq[None, p0:p0 + c], np.int32(p0),
-            self.pool.device_table(r.slot), r.key_data,
-            np.float32(r.temperature),
-            np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
-            np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
-            *self._bank_args(np.int32(getattr(r, "_adapter_row", 0))))
-        self.pool.kc, self.pool.vc = kc, vc
-        tok = int(np.asarray(tok))     # host sync: honest chunk timing
+        with tracing.span("engine.prefill.prepare", rid=r.rid, p0=p0, n=c):
+            t_start = self._now = self._clock()
+            self._ensure_writable_range(r.slot, p0, c)
+            args = (
+                seq[None, p0:p0 + c], np.int32(p0),
+                self.pool.device_table(r.slot), r.key_data,
+                np.float32(r.temperature),
+                np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
+                np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
+                *self._bank_args(np.int32(getattr(r, "_adapter_row", 0))))
+        with tracing.span("engine.prefill.dispatch", rid=r.rid):
+            kc, vc, tok, kd = self._chunk_prefill(
+                self.params, self.pool.kc, self.pool.vc, *args)
+            self.pool.kc, self.pool.vc = kc, vc
+        with tracing.span("engine.prefill.wait", rid=r.rid):
+            tok = int(np.asarray(tok))     # host sync: honest chunk timing
+        with tracing.span("engine.prefill.emit", rid=r.rid):
+            return self._prefill_emit(r, seq, p0, c, t_start, tok, kd)
+
+    def _prefill_emit(self, r: Request, seq, p0: int, c: int,
+                      t_start: float, tok: int, kd) -> int:
+        """Host-side tail of a prefill chunk: account it and, after the
+        final chunk, publish the prefix, emit the first token and seat the
+        request for decode (or finish it)."""
+        plen = int(seq.shape[0])
         now = self._now = self._clock()
         if self.metrics is not None:
             self.metrics.on_prefill_chunk((now - t_start) * 1e3)
@@ -959,25 +1005,28 @@ class InferenceEngine:
         if not active:
             return 0
         S = self.pool.n_slots
-        kd, temps, top_ks, top_ps = self._sampling_inputs(active)
-        # non-decoding slots: position 0 + all-trash table, so their
-        # garbage write lands in the trash block no table references
-        pos = np.zeros(S, np.int32)
-        toks = np.zeros(S, np.int32)
-        tables = np.full((S, self.pool.blocks_per_seq), PagedKVPool.TRASH,
-                         np.int32)
-        for s in active:
-            # on-demand block allocation as this position advances (and
-            # copy-on-write if the write block is still shared)
-            self._ensure_writable_range(s, int(self.pool.positions[s]), 1)
-            tables[s] = self.pool.device_table(s)
-            pos[s] = self.pool.positions[s]
-            toks[s] = self.pool.last_token[s]
-        kc, vc, toks2, kd2 = self._decode(
-            self.params, self.pool.kc, self.pool.vc,
-            toks, pos, tables, kd, temps, top_ks, top_ps,
-            *self._bank_args(self._adapter_inputs(active)))
-        self.pool.kc, self.pool.vc = kc, vc
+        with tracing.span("engine.decode.prepare"):
+            kd, temps, top_ks, top_ps = self._sampling_inputs(active)
+            # non-decoding slots: position 0 + all-trash table, so their
+            # garbage write lands in the trash block no table references
+            pos = np.zeros(S, np.int32)
+            toks = np.zeros(S, np.int32)
+            tables = np.full((S, self.pool.blocks_per_seq),
+                             PagedKVPool.TRASH, np.int32)
+            for s in active:
+                # on-demand block allocation as this position advances (and
+                # copy-on-write if the write block is still shared)
+                self._ensure_writable_range(s, int(self.pool.positions[s]),
+                                            1)
+                tables[s] = self.pool.device_table(s)
+                pos[s] = self.pool.positions[s]
+                toks[s] = self.pool.last_token[s]
+            bank_args = self._bank_args(self._adapter_inputs(active))
+        with tracing.span("engine.decode.dispatch"):
+            kc, vc, toks2, kd2 = self._decode(
+                self.params, self.pool.kc, self.pool.vc,
+                toks, pos, tables, kd, temps, top_ks, top_ps, *bank_args)
+            self.pool.kc, self.pool.vc = kc, vc
         return self._emit_decoded(active, toks2, kd2)
 
     def _ensure_writable_range(self, slot: int, p0: int, n: int) -> None:
@@ -1018,58 +1067,60 @@ class InferenceEngine:
         if not active:
             return 0
         S, K = self.pool.n_slots, self.spec_k
-        kd, temps, top_ks, top_ps = self._sampling_inputs(active)
-        toks = np.zeros(S, np.int32)
-        pos = np.zeros(S, np.int32)
-        valid = np.zeros(S, np.int32)
-        dkd = np.zeros((S, 2), np.uint32)
-        for s in active:
-            r = self.requests[self.pool.occupant(s)]
-            toks[s] = self.pool.last_token[s]
-            pos[s] = self.pool.positions[s]
-            # the per-slot clamp: never speculate past the remaining token
-            # budget, so every real K/V write stays inside the slot's
-            # reservation (non-decoding slots keep valid 0 -> all-trash)
-            valid[s] = min(K, r.max_new_tokens - len(r.tokens))
-            dkd[s] = r.draft_key_data
-        tables = None
-        if self.kv_layout == "paged":
-            tables = np.full((S, self.pool.blocks_per_seq), PagedKVPool.TRASH,
-                             np.int32)
+        with tracing.span("engine.decode.prepare"):
+            kd, temps, top_ks, top_ps = self._sampling_inputs(active)
+            toks = np.zeros(S, np.int32)
+            pos = np.zeros(S, np.int32)
+            valid = np.zeros(S, np.int32)
+            dkd = np.zeros((S, 2), np.uint32)
             for s in active:
-                self._ensure_writable_range(s, int(pos[s]), int(valid[s]))
-                tables[s] = self.pool.device_table(s)
-        # adapters ride the VERIFY side only: the draft proposes as the
-        # base model (a wrong proposal costs acceptance rate, never
-        # correctness — the adapted verify rows decide every emission)
-        bank_args = self._bank_args(self._adapter_inputs(active))
-        if self._spec_fused is not None:
-            args = (toks, pos, valid) + (() if tables is None
-                                         else (tables,))
-            dkc, dvc, kc, vc, otoks, nacc, kd2, dkd2 = self._spec_fused(
-                self._draft_params, self._dkc, self._dvc, self.params,
-                self.pool.kc, self.pool.vc, *args, dkd, kd, temps,
-                top_ks, top_ps, *bank_args)
-        else:
-            dkc, dvc, drafts, qrows, dkd2 = self._propose(
-                self._draft_params, self._dkc, self._dvc, toks, pos, dkd,
-                temps, top_ks, top_ps)
-            # the propose outputs flow into verify VERBATIM, still on
-            # device; verify itself consumes only the first K-1 proposals
-            # (the K-th exists to keep the draft cache ahead; models/gpt.py
-            # section comment)
-            if tables is not None:
-                kc, vc, otoks, nacc, kd2 = self._verify(
-                    self.params, self.pool.kc, self.pool.vc, toks, pos,
-                    drafts, qrows, valid, tables, kd, temps, top_ks,
-                    top_ps, *bank_args)
+                r = self.requests[self.pool.occupant(s)]
+                toks[s] = self.pool.last_token[s]
+                pos[s] = self.pool.positions[s]
+                # the per-slot clamp: never speculate past the remaining token
+                # budget, so every real K/V write stays inside the slot's
+                # reservation (non-decoding slots keep valid 0 -> all-trash)
+                valid[s] = min(K, r.max_new_tokens - len(r.tokens))
+                dkd[s] = r.draft_key_data
+            tables = None
+            if self.kv_layout == "paged":
+                tables = np.full((S, self.pool.blocks_per_seq),
+                                 PagedKVPool.TRASH, np.int32)
+                for s in active:
+                    self._ensure_writable_range(s, int(pos[s]), int(valid[s]))
+                    tables[s] = self.pool.device_table(s)
+            # adapters ride the VERIFY side only: the draft proposes as the
+            # base model (a wrong proposal costs acceptance rate, never
+            # correctness — the adapted verify rows decide every emission)
+            bank_args = self._bank_args(self._adapter_inputs(active))
+        with tracing.span("engine.decode.dispatch"):
+            if self._spec_fused is not None:
+                args = (toks, pos, valid) + (() if tables is None
+                                             else (tables,))
+                dkc, dvc, kc, vc, otoks, nacc, kd2, dkd2 = self._spec_fused(
+                    self._draft_params, self._dkc, self._dvc, self.params,
+                    self.pool.kc, self.pool.vc, *args, dkd, kd, temps,
+                    top_ks, top_ps, *bank_args)
             else:
-                kc, vc, otoks, nacc, kd2 = self._verify(
-                    self.params, self.pool.kc, self.pool.vc, toks, pos,
-                    drafts, qrows, valid, kd, temps, top_ks, top_ps,
-                    *bank_args)
-        self._dkc, self._dvc = dkc, dvc
-        self.pool.kc, self.pool.vc = kc, vc
+                dkc, dvc, drafts, qrows, dkd2 = self._propose(
+                    self._draft_params, self._dkc, self._dvc, toks, pos, dkd,
+                    temps, top_ks, top_ps)
+                # the propose outputs flow into verify VERBATIM, still on
+                # device; verify itself consumes only the first K-1 proposals
+                # (the K-th exists to keep the draft cache ahead; models/gpt.py
+                # section comment)
+                if tables is not None:
+                    kc, vc, otoks, nacc, kd2 = self._verify(
+                        self.params, self.pool.kc, self.pool.vc, toks, pos,
+                        drafts, qrows, valid, tables, kd, temps, top_ks,
+                        top_ps, *bank_args)
+                else:
+                    kc, vc, otoks, nacc, kd2 = self._verify(
+                        self.params, self.pool.kc, self.pool.vc, toks, pos,
+                        drafts, qrows, valid, kd, temps, top_ks, top_ps,
+                        *bank_args)
+            self._dkc, self._dvc = dkc, dvc
+            self.pool.kc, self.pool.vc = kc, vc
         return self._emit_spec(active, otoks, nacc, kd2, dkd2, valid)
 
     def _emit_spec(self, active: list[int], otoks, nacc, kd2, dkd2,
@@ -1079,48 +1130,50 @@ class InferenceEngine:
         already written but gets overwritten before it can be attended),
         advance positions by the count actually emitted, and feed the
         proposed/accepted counters."""
-        otoks = np.asarray(otoks)                # host sync: tick endpoint
-        nacc = np.asarray(nacc)
-        kd2 = np.asarray(kd2)
-        dkd2 = np.asarray(dkd2)
-        now = self._now = self._clock()
-        emitted = proposed = accepted = 0
-        for s in active:
-            r = self.requests[self.pool.occupant(s)]
-            r.key_data = kd2[s]
-            r.draft_key_data = dkd2[s]
-            m = int(nacc[s])                     # >= 1: valid[s] >= 1
-            n_emit = 0
-            finish = None
-            for tok in otoks[s, :m]:
-                n_emit += 1
-                r.emit(int(tok))
-                finish = r.finished_by(int(tok))
+        with tracing.span("engine.decode.wait"):
+            otoks = np.asarray(otoks)            # host sync: tick endpoint
+            nacc = np.asarray(nacc)
+            kd2 = np.asarray(kd2)
+            dkd2 = np.asarray(dkd2)
+        with tracing.span("engine.decode.emit"):
+            now = self._now = self._clock()
+            emitted = proposed = accepted = 0
+            for s in active:
+                r = self.requests[self.pool.occupant(s)]
+                r.key_data = kd2[s]
+                r.draft_key_data = dkd2[s]
+                m = int(nacc[s])                     # >= 1: valid[s] >= 1
+                n_emit = 0
+                finish = None
+                for tok in otoks[s, :m]:
+                    n_emit += 1
+                    r.emit(int(tok))
+                    finish = r.finished_by(int(tok))
+                    if finish is not None:
+                        break
+                dt = now - self._last_emit[r.rid]
+                if self.metrics is not None:
+                    # the tick emitted n_emit tokens in one dt window: spread
+                    # the interval so the TPOT mean stays the true cadence
+                    for _ in range(n_emit):
+                        self.metrics.on_token(dt / n_emit, cls=r.cls)
+                self._last_emit[r.rid] = now
+                emitted += n_emit
+                slot_proposed = max(int(valid[s]) - 1, 0)
+                slot_accepted = max(n_emit - 1, 0)
+                proposed += slot_proposed
+                accepted += slot_accepted
+                if self.trace is not None:
+                    self.trace.on_tick_tokens(r, now, n_emit,
+                                              proposed=slot_proposed,
+                                              accepted=slot_accepted)
                 if finish is not None:
-                    break
-            dt = now - self._last_emit[r.rid]
-            if self.metrics is not None:
-                # the tick emitted n_emit tokens in one dt window: spread
-                # the interval so the TPOT mean stays the true cadence
-                for _ in range(n_emit):
-                    self.metrics.on_token(dt / n_emit, cls=r.cls)
-            self._last_emit[r.rid] = now
-            emitted += n_emit
-            slot_proposed = max(int(valid[s]) - 1, 0)
-            slot_accepted = max(n_emit - 1, 0)
-            proposed += slot_proposed
-            accepted += slot_accepted
-            if self.trace is not None:
-                self.trace.on_tick_tokens(r, now, n_emit,
-                                          proposed=slot_proposed,
-                                          accepted=slot_accepted)
-            if finish is not None:
-                self._finish(r, finish, now)
-            else:
-                self.pool.positions[s] += n_emit
-                self.pool.last_token[s] = r.tokens[-1]
-        if self.metrics is not None and proposed:
-            self.metrics.on_spec(proposed, accepted)
+                    self._finish(r, finish, now)
+                else:
+                    self.pool.positions[s] += n_emit
+                    self.pool.last_token[s] = r.tokens[-1]
+            if self.metrics is not None and proposed:
+                self.metrics.on_spec(proposed, accepted)
         return emitted
 
     # -- shared tick tails -------------------------------------------------
@@ -1140,27 +1193,29 @@ class InferenceEngine:
         return kd, temps, top_ks, top_ps
 
     def _emit_decoded(self, active: list[int], toks, kd2) -> int:
-        toks = np.asarray(toks)                  # host sync: tick endpoint
-        kd2 = np.asarray(kd2)
-        now = self._now = self._clock()
-        emitted = 0
-        for s in active:
-            r = self.requests[self.pool.occupant(s)]
-            tok = int(toks[s])
-            r.key_data = kd2[s]
-            r.emit(tok)
-            emitted += 1
-            if self.metrics is not None:
-                self.metrics.on_token(now - self._last_emit[r.rid],
-                                      cls=r.cls)
-            if self.trace is not None:
-                self.trace.on_tick_tokens(r, now, 1)
-            self._last_emit[r.rid] = now
-            reason = r.finished_by(tok)
-            if reason is not None:
-                self._finish(r, reason, now)
-            else:
-                self.pool.advance(s, tok)
+        with tracing.span("engine.decode.wait"):
+            toks = np.asarray(toks)              # host sync: tick endpoint
+            kd2 = np.asarray(kd2)
+        with tracing.span("engine.decode.emit"):
+            now = self._now = self._clock()
+            emitted = 0
+            for s in active:
+                r = self.requests[self.pool.occupant(s)]
+                tok = int(toks[s])
+                r.key_data = kd2[s]
+                r.emit(tok)
+                emitted += 1
+                if self.metrics is not None:
+                    self.metrics.on_token(now - self._last_emit[r.rid],
+                                          cls=r.cls)
+                if self.trace is not None:
+                    self.trace.on_tick_tokens(r, now, 1)
+                self._last_emit[r.rid] = now
+                reason = r.finished_by(tok)
+                if reason is not None:
+                    self._finish(r, reason, now)
+                else:
+                    self.pool.advance(s, tok)
         return emitted
 
     def _finish(self, r: Request, reason: str, now: float) -> None:

@@ -9,9 +9,12 @@ harness thread through. Module map:
 - ``timer.py`` — :class:`StepTimer`: fenced timing windows with the
   compile-vs-steady split, p50/p95/max step latency, examples/sec and
   tokens/sec (+ opt-in ``jax.stages`` compiled cost stats);
-- ``tracing.py`` — :class:`Tracer`: host spans with wall-clock durations,
-  exported as Chrome-trace JSON (inspectable without XProf; doubles onto the
-  XProf timeline via ``utils/profiler.annotate`` when capturing);
+- ``tracing.py`` — the process's span recorder (:func:`tracing.span`,
+  :func:`tracing.current`, :class:`Tracer`): the hot paths' host spans on
+  ``perf_counter_ns`` in a bounded ring, with JAX's compiles and the
+  collector's pauses beside them, exported as Chrome-trace JSON (inspectable
+  without XProf; every span also enters ``jax.profiler.TraceAnnotation``,
+  so it lies on the XProf timeline when capturing);
 - ``memory.py`` — ``jax.live_arrays()`` byte totals + per-device
   ``memory_stats()`` sampling;
 - ``ici.py`` — static expected collective bytes/step, read-only reuse of

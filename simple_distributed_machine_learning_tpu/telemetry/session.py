@@ -33,7 +33,7 @@ from simple_distributed_machine_learning_tpu.telemetry.registry import (
     append_jsonl,
 )
 from simple_distributed_machine_learning_tpu.telemetry.timer import StepTimer
-from simple_distributed_machine_learning_tpu.telemetry.tracing import Tracer
+from simple_distributed_machine_learning_tpu.telemetry import tracing
 
 METRICS_FILE = "metrics.jsonl"
 TRACE_FILE = "trace.json"
@@ -50,7 +50,10 @@ class Telemetry:
         self.outdir = outdir
         self.every = int(every)
         self.registry = MetricsRegistry()
-        self.tracer = Tracer(process_name=process_name)
+        # the process's recorder from here on: the spans of the trainer's
+        # and the serve engine's hot paths land in this run's trace.json
+        self.tracer = tracing.Tracer(process_name=process_name)
+        tracing.install(self.tracer)
         self.timer = StepTimer(registry=self.registry)
         self._steps_seen = 0
         self._mark = time.perf_counter()
@@ -71,12 +74,6 @@ class Telemetry:
             return jax.process_index() == 0
         except Exception:  # noqa: BLE001 - before distributed init
             return True
-
-    # -- spans -------------------------------------------------------------
-
-    def span(self, name: str, **attrs):
-        """Host span: on the Chrome trace, and on XProf when capturing."""
-        return self.tracer.span(name, **attrs)
 
     # -- step sampling -----------------------------------------------------
 

@@ -36,7 +36,7 @@ def make_train_step(pipe: Pipeline, opt: Optimizer,
     import jax.numpy as jnp
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def step(buf, opt_state, x, targets, key, weights=None):
+    def step_train(buf, opt_state, x, targets, key, weights=None):
         # Pipeline.loss_and_grads: GPipe via value_and_grad of the loss-only
         # engine (no [batch, *out_shape] accumulator rides the scan), or the
         # hand-scheduled 1F1B interleave when the pipeline was built with
@@ -51,7 +51,7 @@ def make_train_step(pipe: Pipeline, opt: Optimizer,
             return buf2, opt_state2, loss, gnorm
         return buf2, opt_state2, loss
 
-    return step
+    return step_train
 
 
 def make_scanned_train_step(pipe: Pipeline, opt: Optimizer, unroll: int = 1,
@@ -90,7 +90,7 @@ def make_scanned_train_step(pipe: Pipeline, opt: Optimizer, unroll: int = 1,
     from simple_distributed_machine_learning_tpu.ops.losses import nll_loss
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def step(buf, opt_state, xs, targets, key):
+    def step_train_scanned(buf, opt_state, xs, targets, key):
         import jax.numpy as jnp
 
         def scan_batches(body, init):
@@ -189,7 +189,7 @@ def make_scanned_train_step(pipe: Pipeline, opt: Optimizer, unroll: int = 1,
         (buf2, opt2, _), losses = scan_batches(body, (buf, opt_state, 0))
         return buf2, opt2, losses
 
-    return step
+    return step_train_scanned
 
 
 def make_eval_step(pipe: Pipeline):
@@ -212,7 +212,7 @@ def make_eval_step(pipe: Pipeline):
     import jax.numpy as jnp
 
     @jax.jit
-    def step(buf, x, targets, key, n_valid):
+    def step_eval(buf, x, targets, key, n_valid):
         # per-sample 0/1 validity mask; eval_metrics broadcasts it over any
         # token axes (LM targets [B, T])
         mask = (jnp.arange(x.shape[0]) < n_valid).astype(jnp.float32)
@@ -220,4 +220,4 @@ def make_eval_step(pipe: Pipeline):
                                                  weights=mask)
         return sum_loss, correct          # correct is exact int32
 
-    return step
+    return step_eval

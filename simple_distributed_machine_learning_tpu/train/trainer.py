@@ -15,7 +15,6 @@ prints (``is_main``).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import sys
 
@@ -33,6 +32,7 @@ from simple_distributed_machine_learning_tpu.resilience.faults import (
     check as faults_check,
     maybe_fire,
 )
+from simple_distributed_machine_learning_tpu.telemetry import tracing
 from simple_distributed_machine_learning_tpu.train.optimizer import (
     Optimizer,
     sgd,
@@ -484,8 +484,7 @@ class Trainer:
                 if b.n_valid < len(b.x):
                     w = (np.arange(len(b.x)) < b.n_valid).astype(np.float32)
                 bx = self._apply_numeric_faults(b.x, step)
-                with (tele.span("feed") if tele is not None
-                      else contextlib.nullcontext()):
+                with tracing.span("feed"):
                     x, y, w = self._feed(bx, b.y, w)
                 if (tele is not None and first
                         and epoch == self.start_epoch):
@@ -503,8 +502,7 @@ class Trainer:
                         abstractify(w) if w is not None else None,
                         mesh=self.pipe.mesh)
                 gnorm = None
-                with (tele.span("step") if tele is not None
-                      else contextlib.nullcontext()):
+                with tracing.span("step"):
                     if sent is not None:
                         self.buf, self.opt_state, loss, gnorm = \
                             self._train_step(self.buf, self.opt_state,
@@ -572,15 +570,13 @@ class Trainer:
 
     def evaluate(self) -> tuple[float, int]:
         cfg = self.config
-        tele = self.telemetry
         total_loss = 0.0
         correct = 0
         # prediction units: samples for classifiers (y: [N]), tokens for
         # language models (y: [N, T]) — y.size covers both
         n = int(self.test_ds.y.size)
         for b in batches(self.test_ds, cfg.batch_size, pad_last=True):
-            with (tele.span("eval") if tele is not None
-                  else contextlib.nullcontext()):
+            with tracing.span("eval"):
                 x, y, _ = self._feed(b.x, b.y, None)
                 sl, c = self._eval_step(self.buf, x, y, self._key,
                                         np.int32(b.n_valid))

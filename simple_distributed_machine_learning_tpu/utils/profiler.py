@@ -64,19 +64,10 @@ def trace(logdir: str = "/tmp/sdml_trace", enabled: bool = True):
                 pass  # already stopped / never fully started: nothing leaks
 
 
-def annotate(name: str):
-    """Named region that shows up on the trace timeline.
-
-    Host-side: annotates the wall-clock interval of the Python block (dispatch,
-    blocking reads). For regions INSIDE a jitted program use
-    :func:`annotate_scope` — a TraceAnnotation entered at trace time would
-    label the tracing, not the execution.
-    """
-    return jax.profiler.TraceAnnotation(name)
-
-
 def annotate_scope(name: str):
-    """Named region for ops inside a compiled program.
+    """Named region for ops inside a compiled program (host intervals are
+    ``telemetry/tracing.py``'s spans: a ``TraceAnnotation`` entered at trace
+    time would label the tracing, not the execution).
 
     ``jax.named_scope`` prefixes the HLO metadata of every op traced under it,
     which XProf surfaces as a grouped region on the device timeline — the

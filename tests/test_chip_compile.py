@@ -12,7 +12,9 @@ file. ``_interpret()`` asks ``jax.default_backend()`` and would answer
 "interpret" on the CPU the suite runs on, so the test steers it (monkeypatch).
 """
 
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,11 +61,19 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(pa, "_interpret", lambda: False)
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernels):
+    """Compile ``fn`` and return the HLO lines of its Mosaic kernels, which
+    must be the ones called ``kernels`` (the ``name=`` of each Pallas call:
+    what a device trace shows the kernel as)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    return compiled
+    lines = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    for name in kernels:
+        assert any(name in ln.split(" = ")[0] for ln in lines), (
+            name, [ln.split(" = ")[0] for ln in lines])
+    assert len(lines) == len(kernels)
+    return lines
 
 
 # -- paged attention: the serve tick's kernel -------------------------------
@@ -107,7 +117,14 @@ def test_paged_attention_compiles_for_v5e(one_chip, mosaic, dh, bs, k, pool):
     jaxpr = str(jax.make_jaxpr(fn)(
         *[jax.ShapeDtypeStruct(s, d) for s, d in shapes]))
     assert ("transpose" in jaxpr) == (dh % 128 != 0)
-    _compile(fn, one_chip, *shapes)
+    (line,) = _compile(fn, one_chip, *shapes, kernels=["paged_attention"])
+    # the benchmark finds the kernel's events by this pattern
+    # (bench_cells/traffic/serve-closed.json); a name leaves it in the line
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "bench_cells", "traffic",
+                           "serve-closed.json")) as f:
+        pattern = json.load(f)["kernels"]["paged_attention"]
+    assert re.search(pattern, line)
 
 
 def test_paged_attention_compiles_with_bf16_queries(one_chip, mosaic):
@@ -120,7 +137,8 @@ def test_paged_attention_compiles_with_bf16_queries(one_chip, mosaic):
               ((SLOTS * nb + 1, H, bs, dh), jnp.bfloat16),
               ((SLOTS, nb), jnp.int32), ((SLOTS, 1), jnp.int32)]
     _compile(lambda q, kc, vc, t, p: pa.paged_attention(
-        q, kc, vc, t, p, block_size=bs), one_chip, *shapes)
+        q, kc, vc, t, p, block_size=bs), one_chip, *shapes,
+        kernels=["paged_attention"])
 
 
 # -- flash attention: the train step's kernel -------------------------------
@@ -134,7 +152,8 @@ _FLASH = [((8, 16, 512, 64), "bfloat16"),      # chip_smoke's train step
 @pytest.mark.parametrize("shape,dtype", _FLASH)
 def test_flash_attention_forward_compiles_for_v5e(one_chip, mosaic, shape,
                                                   dtype):
-    _compile(fa.flash_attention, one_chip, *[(shape, jnp.dtype(dtype))] * 3)
+    _compile(fa.flash_attention, one_chip, *[(shape, jnp.dtype(dtype))] * 3,
+             kernels=["flash_attention_fwd"])
 
 
 @pytest.mark.parametrize("shape,dtype", _FLASH)
@@ -144,4 +163,6 @@ def test_flash_attention_gradient_compiles_for_v5e(one_chip, mosaic, shape,
         return jnp.sum(fa.flash_attention(q, k, v).astype(jnp.float32))
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-             *[(shape, jnp.dtype(dtype))] * 3)
+             *[(shape, jnp.dtype(dtype))] * 3,
+             kernels=["flash_attention_fwd", "flash_attention_dq",
+                      "flash_attention_dkv"])
