@@ -839,9 +839,10 @@ class Pipeline:
 
     def _trivial_mesh(self) -> bool:
         """Degenerate single-device mesh: the pipeline IS the fused model.
-        Skip the shard_map engine — its packed-row unpack/repack costs ~10x
-        the model itself at this scale (grad of the slice/concat machinery),
-        with nothing to overlap on one device."""
+        Skip the shard_map engine: its scan, switch and wire codec have
+        nothing to schedule or overlap on one device. Both paths
+        differentiate through the same ``unpack_stage_params`` (one split,
+        whose transpose is one concatenate of the leaf cotangents)."""
         return (self.n_stages == 1 and self.n_data == 1 and self.n_model == 1
                 and self.n_seq == 1 and self.n_expert == 1
                 and self.stages[0].shards is None

@@ -85,13 +85,18 @@ def pack_stage_params(stage_params: Sequence[Any]) -> tuple[jax.Array, list[Stag
 
 
 def unpack_stage_params(row: jax.Array, meta: StageMeta) -> Any:
-    """Rebuild one stage's param pytree from its packed row (pure reshapes —
-    XLA fuses these away; there is no runtime copy on TPU)."""
-    leaves = []
-    offset = 0
-    for shape, size in zip(meta.shapes, meta.sizes):
-        leaves.append(jnp.reshape(row[offset:offset + size], shape))
-        offset += size
+    """Rebuild one stage's param pytree from its packed row.
+
+    ONE ``lax.split`` cuts the row into the leaves (and the zero padding,
+    dropped), so that its transpose is ONE ``concatenate`` of the leaf
+    cotangents: the gradient with respect to the row writes every element
+    once. Do not cut it with a slice per leaf: each slice transposes to a
+    ``pad`` to the row's width, added to the others, O(leaves x row) — on
+    gpt2-medium's 294 leaves that was more than half the train step
+    (PERF.md, Findings, PR 27)."""
+    pad = row.shape[0] - meta.total
+    pieces = jax.lax.split(row, meta.sizes + ((pad,) if pad else ()))
+    leaves = [jnp.reshape(p, shape) for p, shape in zip(pieces, meta.shapes)]
     return jax.tree.unflatten(meta.treedef, leaves)
 
 

@@ -108,11 +108,12 @@ def make_scanned_train_step(pipe: Pipeline, opt: Optimizer, unroll: int = 1,
             return jax.lax.scan(body_pool, init, jnp.arange(pool_steps),
                                 unroll=unroll)
 
-        # On the degenerate single-device mesh, differentiating through the
-        # packed [1, 1, P] buffer costs ~10x the model itself per scan
-        # iteration (the slice/concat machinery's autodiff). Unpack params and
-        # any buffer-shaped optimizer state to pytrees ONCE per window, scan
-        # on pytrees, repack at the end. Buffer-shaped state leaves (SGD
+        # On the degenerate single-device mesh, unpack params and any
+        # buffer-shaped optimizer state to pytrees ONCE per window, scan on
+        # pytrees, repack at the end: no iteration then cuts the packed
+        # [1, 1, P] row or forms its gradient (per step that is one split
+        # forward and one concatenate of the leaf cotangents backward, a
+        # pass over the row each). Buffer-shaped state leaves (SGD
         # momentum, AdamW m/v) are unpacked alongside the params; scalar
         # leaves (step counters, carried bias-correction powers) pass through
         # unchanged — excluding them from this path sent every

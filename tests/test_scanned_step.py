@@ -118,12 +118,15 @@ def test_adamw_rides_unpacked_fast_path():
     assert ratio < 1.6, f"AdamW window bytes {ratio:.2f}x SGD - packed-path?"
 
     # absolute anchor: a state shape the gate CANNOT unpack (a (2,)-vector
-    # counter) forces the packed engine; the real AdamW must compile to
-    # meaningfully less LIVE TEMP memory than that (bytes-accessed barely
-    # separates at MLP scale, temp separates ~2x at [128,512,256,64]). If a
-    # regression knocked every optimizer off the fast path, the adamw/sgd
+    # counter) forces the packed engine, whose scan carries the packed
+    # [1, 1, 1, P] buffer; the real AdamW's scan must carry leaves only. If
+    # a regression knocked every optimizer off the fast path, the adamw/sgd
     # ratio above would still pass (packed-vs-packed) but this anchor
-    # catches it.
+    # catches it. Structure, not cost: with the unpack one lax.split the
+    # two paths no longer separate by bytes accessed or live temp.
+    from simple_distributed_machine_learning_tpu.analysis.trace import (
+        subjaxprs,
+    )
     from simple_distributed_machine_learning_tpu.train.optimizer import (
         Optimizer,
         adamw as _adamw,
@@ -144,23 +147,25 @@ def test_adamw_rides_unpacked_fast_path():
 
         return Optimizer(init, update)
 
-    big, bwd, bod = make_mlp_stages(jax.random.key(5), [128, 512, 256, 64], 1)
-    bpipe = Pipeline(big, make_mesh(n_stages=1, n_data=1), bwd, bod,
-                     n_microbatches=1)
-    bxs = jax.random.normal(key, (8, 16, 128))
-    bts = jax.random.randint(key, (8, 16), 0, 64)
+    def scans(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for _key, _i, sub in subjaxprs(eqn):
+                yield from scans(sub)
 
-    def window_temp(opt):
-        buf = bpipe.init_params()
+    def window_scan_carries_buffer(opt):
+        buf = pipe.init_params()
         st = opt.init(buf)
-        step = make_scanned_train_step(bpipe, opt)
-        compiled = step.lower(buf, st, bxs, bts, key).compile()
-        return compiled.memory_analysis().temp_size_in_bytes
+        step = make_scanned_train_step(pipe, opt)
+        window = max(scans(jax.make_jaxpr(step)(buf, st, xs, ts, key).jaxpr),
+                     key=lambda e: len(e.invars))
+        return any(v.aval.shape == buf.shape for v in window.invars)
 
-    temp_ratio = window_temp(adamw(1e-3)) / window_temp(packed_adamw(1e-3))
-    assert temp_ratio < 0.7, (
-        f"AdamW live temp {temp_ratio:.2f}x the forced-packed engine - "
-        f"did the fast-path gate regress for every optimizer?")
+    assert window_scan_carries_buffer(packed_adamw(1e-3))
+    assert not window_scan_carries_buffer(adamw(1e-3)), (
+        "AdamW's window scans over the packed buffer - did the fast-path "
+        "gate regress for every optimizer?")
 
 
 def test_scanned_clip_single_device_matches_loop():
