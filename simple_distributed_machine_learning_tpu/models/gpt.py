@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +106,10 @@ class GPTConfig:
     #            attention output projection; same losses to float tolerance
     overlap: str = "none"
 
+    # the serving engine's question of any model config: per-slot state
+    # beside the K/V pool (models/jamba.py has it)
+    recurrent_state = False
+
     def __post_init__(self):
         if self.attn_impl not in ("dense", "flash", "ring", "ulysses"):
             raise ValueError(
@@ -169,6 +173,23 @@ class GPTConfig:
                     "n_tensor_parallel > 1 with n_seq > 1 is not supported "
                     "(the wire's token sharding and the TP row scatter "
                     "would both claim the token axis)")
+
+    def paged_serving(self, stages, max_len: int, block_size: int,
+                      cache_dtype=None, mesh=None, kernel: str = "dense",
+                      adapters: bool = False) -> "PagedServing":
+        """The engine's model interface (:class:`PagedServing`): every
+        block is an attention layer with ``n_heads`` K/V heads, and a slot
+        has no state beside its blocks."""
+        return PagedServing(
+            kv_layers=sum(len(s.params["blocks"]) for s in stages),
+            kv_heads=self.n_heads, head_dim=self.d_model // self.n_heads,
+            state_shapes=(),
+            chunk_prefill=make_paged_prefill_chunk(
+                stages, self, max_len, block_size, cache_dtype, mesh=mesh,
+                adapters=adapters),
+            decode=make_paged_decode_step(
+                stages, self, max_len, block_size, cache_dtype, mesh=mesh,
+                kernel=kernel, adapters=adapters))
 
 
 def _block_init(key: jax.Array, cfg: GPTConfig) -> dict:
@@ -909,6 +930,57 @@ def _memo_build(key: tuple, build):
     if fn is None:
         fn = _DECODE_BUILD_CACHE[key] = build()
     return fn
+
+
+class PagedServing(NamedTuple):
+    """What a model hands ``serve/engine.py`` for the paged layout
+    (``cfg.paged_serving(stages, max_len, block_size, cache_dtype, mesh=,
+    kernel=, adapters=)``): the cache's layout and the two programs.
+
+    The pool holds ``kv_layers x kv_heads x head_dim`` K/V rows a position
+    (the CACHE's head count: a grouped-query model's is smaller than its
+    query heads'). ``state_shapes`` is a pytree of per-slot
+    ``jax.ShapeDtypeStruct``: recurrent buffers the pool keeps beside the
+    blocks, one ``[n_slots, *shape]`` array per leaf; empty for an
+    attention-only model. With it empty the programs are ``chunk_prefill(
+    params, kc, vc, tokens [1, c], p0, table, key_data, temperature, top_k,
+    top_p) -> (kc, vc, token, key_data)`` and ``decode(params, kc, vc, toks,
+    pos, tables, key_data, temps, top_ks, top_ps) -> (kc, vc, tokens,
+    key_data)``; with state they take ``state`` after ``vc`` (donated like
+    the pool) and return it after ``vc``, the chunk takes ``slot`` after
+    ``table`` and the decode ``live [S]`` after ``tables``.
+
+    ``pack_chunk`` / ``pack_decode``: where given, the engine hands them a
+    program's host-side arguments (everything after the buffers) and calls
+    the program with what they return instead. Every numpy argument of a
+    call is a transfer of its own, about 0.13 ms each on a v5e's host (my
+    chip run, PR 28); a model may take them as one array.
+
+    ``ahead``: the programs keep every slot's newest token and sampling key
+    on the device, in the LAST pair of ``state_shapes`` (``([] int32, [2]
+    uint32)`` a slot). The decode reads its ``toks`` and ``key_data`` there
+    (the host's copies it is handed are not looked at) and writes the live
+    slots' new ones back; the chunk takes ``seat`` after ``slot``:
+    :data:`SEAT_NONE` (a mid-prompt chunk: the slot's pair stays),
+    :data:`SEAT_SAMPLE` (seat its own sample and advanced key) or a token
+    (seat that token and the key it was handed: a resumed request's).
+    Nothing a decode needs then waits for the host to read the last one,
+    and the engine dispatches a tick's decode before it reads the previous
+    tick's tokens (``serve/engine.py::_tick_ahead``)."""
+    kv_layers: int
+    kv_heads: int
+    head_dim: int
+    state_shapes: tuple
+    chunk_prefill: Callable
+    decode: Callable
+    pack_chunk: Callable | None = None
+    pack_decode: Callable | None = None
+    ahead: bool = False
+
+
+# a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)
+SEAT_NONE = -2
+SEAT_SAMPLE = -1
 
 
 def _dense_block_prefill(bp, h, li, kc, vc, prompt_len, n_heads):

@@ -146,3 +146,27 @@ def dropout2d(key: jax.Array, x: jax.Array, rate: float = 0.5,
     n, _, _, c = x.shape
     mask = jax.random.bernoulli(key, keep, (n, 1, 1, c))
     return jnp.where(mask, x / keep, 0.0)
+
+
+def rms_norm(weight: jax.Array, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """RMSNorm over the trailing feature axis, computed in float32:
+    ``weight * x / sqrt(mean(x**2) + eps)`` (no mean subtraction, no bias)."""
+    x = x.astype(jnp.float32)
+    return (x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+            * weight.astype(jnp.float32))
+
+
+def matmul_acc32(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` with both operands in the WEIGHT's dtype and a float32
+    accumulator: bfloat16 weights are read as stored (half the HBM bytes of
+    an upcast copy), float32 weights give the plain float32 product."""
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def gated_mlp(params: dict, x: jax.Array) -> jax.Array:
+    """SwiGLU feed-forward, no biases: ``down(silu(gate x) * (up x))`` with
+    ``params = {'gate': [d, ff], 'up': [d, ff], 'down': [ff, d]}``."""
+    mid = jax.nn.silu(matmul_acc32(x, params["gate"])) * matmul_acc32(
+        x, params["up"])
+    return matmul_acc32(mid, params["down"])

@@ -153,6 +153,13 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     quantized pool pass ``kscale``/``vscale`` [n_blocks+1, H, bs] — the
     per-row f32 dequant scales — and int8/fp8 ``kc``/``vc``.
 
+    Grouped-query attention: the pool may hold FEWER heads than ``q``
+    (``kc``/``vc``: [n_blocks+1, KVH, bs, dh] with ``KVH`` dividing ``H``;
+    multi-query is ``KVH = 1``). The ``H / KVH`` query heads of a group then
+    ride their one K/V block stream as more query rows of it (each with its
+    own position), so a block is read once for the whole group and the pool
+    never holds a repeated head.
+
     Returns f32 [S, H, K, dh]: exactly what the dense-math path's masked
     softmax-attention einsum pair produces over the gathered span, with
     rows past each query's position masked out (trash-table entries
@@ -173,11 +180,23 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     - ``"auto"`` (default) — ``natural`` when ``dh`` is a lane multiple or
       in interpret mode (no tiling there), else ``packed``.
     """
-    S, H, K, dh = q.shape
+    S, n_q_heads, n_q, dh = q.shape
     NB = tables.shape[1]
     bs = int(block_size)
     if kc.shape[-2] != bs:
         raise ValueError(f"kc block axis {kc.shape[-2]} != block_size {bs}")
+    H = kc.shape[1]
+    if n_q_heads % H:
+        raise ValueError(f"the pool's {H} K/V heads do not divide the "
+                         f"{n_q_heads} query heads")
+    group = n_q_heads // H
+    if group > 1:
+        # head h = kv * group + g: the group's queries become rows
+        # g * n_q + k of K/V head kv; the last row still holds the newest
+        # position, which is all the kernel asks of their order
+        q = q.reshape(S, H, group * n_q, dh)
+        qpos = jnp.tile(qpos, (1, group))
+    K = group * n_q
     quant = kscale is not None
     if quant != (vscale is not None):
         raise ValueError("pass both kscale and vscale, or neither")
@@ -251,7 +270,7 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
         interpret=interpret,
         name="paged_attention",
     )(tables.astype(jnp.int32), qpos.astype(jnp.int32), *operands)
-    return out[..., :dh]
+    return out[..., :dh].reshape(S, n_q_heads, n_q, dh)
 
 
 def paged_flash_decode(q: jax.Array, kc: jax.Array, vc: jax.Array,

@@ -1,7 +1,12 @@
 """The continuous-batching inference engine: submit / step / drain.
 
-:class:`InferenceEngine` is the serving API over the slot-wise model ops
-(``models/gpt.py``), the KV-cache pool and the FCFS scheduler:
+:class:`InferenceEngine` is the serving API over a model's slot-wise ops,
+the KV-cache pool and the FCFS scheduler. The paged layout takes the
+cache's shape and its two compiled programs from the model
+(``cfg.paged_serving(...)`` -> ``models/gpt.py::PagedServing``): GPT
+(``models/gpt.py``) is attention in every block and nothing else; a model
+with state-space layers (``models/jamba.py``) also has a recurrent buffer
+per slot, which rides beside the K/V pool through both programs.
 
 - ``submit(prompt, ...) -> Request`` enqueues one sequence with its own
   sampling params and seeded key stream, and returns the live handle
@@ -28,7 +33,13 @@ Two KV-cache layouts (``kv_layout``):
 Device state is exactly the pool's K/V buffers; everything else (positions,
 last tokens, block tables, key streams, request lifecycle) is host-side
 numpy assembled into each tick's inputs — the scheduler stays plain Python
-while every FLOP runs inside the compiled programs.
+while every FLOP runs inside the compiled programs. A model whose programs
+keep every slot's newest token and key on the device as well
+(``PagedServing.ahead``) gets the tick of :meth:`InferenceEngine._tick_ahead`:
+decode, then the chunk, and the NEXT tick's decode launched before this
+tick's tokens are read, so the device works through the host's share of
+the tick. A request's tokens are the same; the slot a chunk seats decodes
+from the tick after.
 
 Correctness anchor: a request's tokens are bit-exact vs decoding it alone
 via ``make_cached_decoder`` with the same seed (tests/test_serve.py) —
@@ -39,6 +50,7 @@ chunk boundaries cannot change anyone's output.
 from __future__ import annotations
 
 import collections
+import functools
 import time
 
 import numpy as np
@@ -88,8 +100,67 @@ class DrainTimeout(RuntimeError):
         self.unfinished = unfinished
 
 
+@functools.cache
+def _host_device():
+    """The host's own CPU device, or ``None`` where JAX is held to another
+    platform."""
+    import jax
+
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        return None
+
+
+def _seed_key_data(seed: int, fold: int | None = None) -> np.ndarray:
+    """``jax.random.key(seed)``'s key data (folded with ``fold``, where
+    given), made on the host's CPU: on the accelerator the few integer
+    operations would queue behind the tick in flight, and ``submit`` would
+    wait a whole decode for two words (my chip run, PR 28: 1.7 ms of
+    device idle a request)."""
+    import jax
+
+    with jax.default_device(_host_device()):
+        key = jax.random.key(seed)
+        if fold is not None:
+            key = jax.random.fold_in(key, fold)
+        return np.asarray(jax.random.key_data(key))
+
+
+def _refuse_for_recurrent_state(*, kv_layout, host_cache_blocks,
+                                draft_stages, lint) -> None:
+    """A model with per-slot recurrent state serves through the paged pool
+    and nothing that was built for K/V blocks alone: each such mechanism is
+    refused by name rather than half-done. (``mesh``, ``adapters`` and a
+    quantized ``cache_dtype`` reach the model's own ``paged_serving``, which
+    refuses them in the same words.)"""
+    why = {
+        "kv_layout='dense'": (
+            kv_layout == "dense",
+            "the dense slot-row pool and its whole-prompt prefill are "
+            "GPT's; recurrent state lives in the paged pool"),
+        "host_cache_blocks": (
+            bool(host_cache_blocks),
+            "the host offload tier demotes prefix BLOCKS, and a block "
+            "without the recurrent state that goes with it is no prefix"),
+        "draft_stages (speculative decoding)": (
+            draft_stages is not None,
+            "a rejected draft token cannot be taken back out of the "
+            "recurrent state without a snapshot of it"),
+        "lint=True": (
+            bool(lint),
+            "the analyzer's program registry (analysis/programs.py) builds "
+            "GPT's programs"),
+    }
+    for name, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{name} is not available with a model that has recurrent "
+                f"state: {reason}")
+
+
 class InferenceEngine:
-    """Continuous-batching serving over a dense single-device GPT build.
+    """Continuous-batching serving over a single-device model build.
 
     ``stages``/``cfg``: a ``make_gpt_stages`` build (dense-MLP, unsharded —
     the ``make_cached_decoder`` restrictions). ``params`` overrides the
@@ -148,6 +219,18 @@ class InferenceEngine:
     rows at tick boundaries; the paged prefix cache is namespaced per
     adapter so tenants can never share K/V computed under a different
     model.
+
+    Recurrent state: a ``cfg`` with ``recurrent_state`` (a hybrid of
+    state-space and attention layers) serves through the same paged pool,
+    chunked prefill and ``attn_kernel`` — its per-slot state buffers live
+    in the pool beside the blocks. It binds with no prefix match and
+    registers none; preemption, journal recovery and fleet handoff
+    recompute ``resume_seq`` from position 0, which rebuilds the state.
+    What is built for K/V blocks alone is REFUSED at construction with
+    such a model, by name: ``kv_layout="dense"``, ``host_cache_blocks``,
+    ``draft_stages`` (speculation), ``adapters``, ``mesh`` (tensor
+    parallelism), ``lint=True`` (the analyzer's registry builds GPT's
+    programs) and a quantized ``cache_dtype``.
     """
 
     def __init__(self, stages, cfg, *, params=None, n_slots: int = 4,
@@ -162,18 +245,6 @@ class InferenceEngine:
                  mesh=None, draft_stages=None, draft_cfg=None,
                  spec_k: int = 0, trace=None, flight=None,
                  adapters=None) -> None:
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            make_paged_block_copy,
-            make_paged_decode_step,
-            make_paged_prefill_chunk,
-            make_paged_spec_tick,
-            make_paged_verify_step,
-            make_slot_decode_step,
-            make_slot_prefill,
-            make_slot_propose,
-            make_slot_spec_tick,
-            make_slot_verify_step,
-        )
         if kv_layout not in ("paged", "dense"):
             raise ValueError(
                 f"kv_layout must be 'paged' or 'dense', got {kv_layout!r}")
@@ -218,6 +289,10 @@ class InferenceEngine:
                 f"AdapterStore has {adapters.n_rows} bank rows but this "
                 f"engine needs n_slots + 1 = {n_slots + 1} (base row + one "
                 f"per slot — the never-refuse sizing)")
+        if cfg.recurrent_state:
+            _refuse_for_recurrent_state(
+                kv_layout=kv_layout, host_cache_blocks=host_cache_blocks,
+                draft_stages=draft_stages, lint=lint)
         self._adapters = adapters
         self.cfg = cfg
         self.stages = stages       # kept for the analyzer's program registry
@@ -233,29 +308,54 @@ class InferenceEngine:
         self.speculative = draft_stages is not None
         self.draft_stages = draft_stages   # for the analyzer's registry
         self.draft_cfg = draft_cfg
-        n_layers = sum(len(p["blocks"]) for p in self.params)
-        head_dim = cfg.d_model // cfg.n_heads
         adp = adapters is not None
         if kv_layout == "paged":
-            self.pool = PagedKVPool(n_layers, n_slots, cfg.n_heads,
-                                    self.max_len, head_dim, cache_dtype,
+            # the model's cache layout and its two programs (models/gpt.py
+            # ::PagedServing); everything below the pool is the model's
+            serving = cfg.paged_serving(
+                stages, self.max_len, block_size, cache_dtype, mesh=mesh,
+                kernel=attn_kernel, adapters=adp)
+            n_layers = serving.kv_layers
+            self.pool = PagedKVPool(n_layers, n_slots, serving.kv_heads,
+                                    self.max_len, serving.head_dim,
+                                    cache_dtype,
                                     block_size=block_size, n_blocks=n_blocks,
                                     tp=self.tp,
                                     host_cache_blocks=host_cache_blocks,
-                                    prefetch_ticks=prefetch_ticks)
-            self._chunk_prefill = make_paged_prefill_chunk(
-                stages, cfg, self.max_len, block_size, cache_dtype,
-                mesh=mesh, adapters=adp)
-            self._decode = make_paged_decode_step(
-                stages, cfg, self.max_len, block_size, cache_dtype,
-                mesh=mesh, kernel=attn_kernel, adapters=adp)
+                                    prefetch_ticks=prefetch_ticks,
+                                    state_shapes=serving.state_shapes)
+            self._chunk_prefill = serving.chunk_prefill
+            self._decode = serving.decode
+            self._pack_chunk = serving.pack_chunk
+            self._pack_decode = serving.pack_decode
+            # the model's programs keep the newest tokens on the device
+            # (PagedServing.ahead): the tick is _tick_ahead's, and _ahead
+            # the decode it has dispatched for the next one
+            self._dispatch_ahead = serving.ahead
+            self._ahead = None
+            from simple_distributed_machine_learning_tpu.models.gpt import (
+                SEAT_NONE,
+                SEAT_SAMPLE,
+                make_paged_block_copy,
+            )
             self._copy_block = make_paged_block_copy()
+            self._seat_none, self._seat_sample = SEAT_NONE, SEAT_SAMPLE
             if self.speculative:
+                from simple_distributed_machine_learning_tpu.models.gpt import (  # noqa: E501
+                    make_paged_verify_step,
+                )
                 self._verify = make_paged_verify_step(
                     stages, cfg, self.max_len, block_size, spec_k,
                     cache_dtype, mesh=mesh, kernel=attn_kernel,
                     adapters=adp)
         else:
+            from simple_distributed_machine_learning_tpu.models.gpt import (
+                make_slot_decode_step,
+                make_slot_prefill,
+                make_slot_verify_step,
+            )
+            n_layers = sum(len(p["blocks"]) for p in self.params)
+            head_dim = cfg.d_model // cfg.n_heads
             self.pool = KVCachePool(n_layers, n_slots, cfg.n_heads,
                                     self.max_len, head_dim, cache_dtype,
                                     tp=self.tp)
@@ -280,6 +380,10 @@ class InferenceEngine:
             # only (acceptance always re-scores on the target)
             from simple_distributed_machine_learning_tpu.models.gpt import (
                 _is_quantized_dtype,
+                make_paged_spec_tick,
+                make_slot_prefill,
+                make_slot_propose,
+                make_slot_spec_tick,
             )
             self._draft_cache_dtype = (None if _is_quantized_dtype(
                 cache_dtype) else cache_dtype)
@@ -437,8 +541,6 @@ class InferenceEngine:
         ``ttft_deadline_s``/``deadline_s`` are stored on the handle; the
         serve SUPERVISOR enforces them at tick boundaries (an unsupervised
         engine is the no-deadline baseline)."""
-        import jax
-
         with tracing.span("engine.submit") as sp:
             # fault-injection site: a crash while the request is being
             # accepted (journaled by the supervisor but never admitted — the
@@ -471,15 +573,13 @@ class InferenceEngine:
                 r._prefix_ns = self._adapters.namespace_of(adapter)
             # the request's independent key stream — the SAME key a solo
             # make_cached_decoder call would be handed, so streams align
-            r.key_data = np.asarray(
-                jax.random.key_data(jax.random.key(seed)))
+            r.key_data = _seed_key_data(seed)
             if self.speculative:
                 # the draft's own stream, derived but disjoint (fold_in),
                 # so sampled proposals never consume the target's splits —
                 # greedy consumes neither, which is what keeps greedy
                 # speculative decode bit-exact vs solo
-                r.draft_key_data = np.asarray(jax.random.key_data(
-                    jax.random.fold_in(jax.random.key(seed), 1)))
+                r.draft_key_data = _seed_key_data(seed, fold=1)
             r.submit_time = (self._clock() if arrival_time is None
                              else arrival_time)
             self._now = max(self._now, r.submit_time)
@@ -568,7 +668,9 @@ class InferenceEngine:
         Dense tick: admit (whole-prompt prefill each) -> batched decode ->
         retire. Paged tick: admit (board slots, match prefixes, reserve
         blocks) -> ONE prefill chunk of the oldest prefilling request ->
-        batched block-gather decode over the DECODING slots -> retire.
+        batched block-gather decode over the DECODING slots -> retire;
+        the decode comes first where the model's programs keep the newest
+        tokens on the device (:meth:`_tick_ahead`).
         """
         if not self.busy:
             return 0
@@ -589,7 +691,7 @@ class InferenceEngine:
         if self.kv_layout == "dense":
             with tracing.span("engine.admit") as admit:
                 emitted, boarded = self._admit_dense()
-                admit.set(boarded=boarded)
+                admit.set(boarded=boarded, prefix_declined=0)
             # occupancy the batched decode actually RUNS at — sampled before
             # same-tick retirement so short requests cannot bias it low
             decode_active = self.pool.n_active
@@ -615,13 +717,19 @@ class InferenceEngine:
                                 and self.pool.prefetch_blocked(r)):
                             self._gated.add(r.rid)
                             self.trace.on_gate(r, self._now)
-                admit.set(boarded=self._admit_paged())
+                declined = self.pool.prefix_declined_total
+                admit.set(boarded=self._admit_paged(),
+                          prefix_declined=(self.pool.prefix_declined_total
+                                           - declined))
             chunk = int(bool(self._prefilling))
-            emitted = self._prefill_tick()
-            decoding = self._decoding_slots()
-            decode_active = len(decoding)
-            emitted += (self._spec_tick(decoding) if self.speculative
-                        else self._decode_tick_paged(decoding))
+            if self._dispatch_ahead:
+                emitted, decode_active = self._tick_ahead()
+            else:
+                emitted = self._prefill_tick()
+                decoding = self._decoding_slots()
+                decode_active = len(decoding)
+                emitted += (self._spec_tick(decoding) if self.speculative
+                            else self._decode_tick_paged(decoding))
         if self.metrics is not None or self.flight is not None:
             with tracing.span("engine.bookkeeping"):
                 if self.metrics is not None:
@@ -640,8 +748,20 @@ class InferenceEngine:
                 if self.flight is not None:
                     self.flight.snap(self, self._tick_count, emitted)
         sp.set(chunk=chunk, decoding=decode_active, emitted=emitted,
-               queue=self.scheduler.queue_depth)
+               queue=self.scheduler.queue_depth,
+               state_slots=self._state_slots(),
+               kv_blocks=(self.pool.blocks_in_use
+                          if self.kv_layout == "paged" else 0))
         return emitted
+
+    def _state_slots(self) -> int:
+        """Slots whose recurrent state is live: decoding, or past their
+        first prefill chunk (a slot bound and still waiting for its chunk
+        turn holds nothing of its own yet). 0 for a model without."""
+        if not self.pool.recurrent:
+            return 0
+        return sum(1 for s in self.pool.active_slots()
+                   if self.requests[self.pool.occupant(s)].prefill_pos != 0)
 
     def kv_drift(self) -> tuple[int, int]:
         """``(live, predicted)`` resident K/V bytes: the pool's
@@ -652,6 +772,20 @@ class InferenceEngine:
         prefix sharing, ≤ 0 with it (sharing only shrinks the truth), and
         > 0 only if the pool leaks blocks the model says no live sequence
         can be pinning."""
+        rows = []
+        if self.kv_layout == "paged":
+            for s in self.pool.active_slots():
+                r = self.requests[self.pool.occupant(s)]
+                n = (r.prefill_pos if r.prefill_pos is not None
+                     else int(self.pool.positions[s]))
+                if n > 0:
+                    rows.append(n)
+        if self.pool.recurrent:
+            # the analyzer's model is GPT's (one head count, every layer
+            # attends); here the pool's own block bytes make the prediction
+            return (self.pool.bytes_resident(),
+                    sum(self.pool.blocks_for(n) for n in rows)
+                    * self.pool.bytes_per_block)
         if self._predict is None:
             from simple_distributed_machine_learning_tpu.analysis.programs import (  # noqa: E501
                 engine_spec,
@@ -661,14 +795,6 @@ class InferenceEngine:
             # the drift check can never describe a different deployment
             self._predict = (engine_spec(self), predict_kv_bytes_resident)
         sspec, predict = self._predict
-        rows = []
-        if self.kv_layout == "paged":
-            for s in self.pool.active_slots():
-                r = self.requests[self.pool.occupant(s)]
-                n = (r.prefill_pos if r.prefill_pos is not None
-                     else int(self.pool.positions[s]))
-                if n > 0:
-                    rows.append(n)
         return (self.pool.bytes_resident(),
                 predict(sspec, rows, n_layers=self._n_layers))
 
@@ -763,8 +889,6 @@ class InferenceEngine:
         newest token, so the continued decode is bit-exact vs the
         uninterrupted run. Callers re-admit in rid order to preserve FCFS
         arrival order across the restart."""
-        import jax
-
         if request.rid in self.requests:
             raise ValueError(f"request {request.rid} already lives in this "
                              f"engine — restore() is for rebuilt engines")
@@ -783,11 +907,9 @@ class InferenceEngine:
         request._prefix_probe = None   # probed against THIS pool's registry
         if request.key_data is None:
             # never emitted a token: the stream starts where submit's would
-            request.key_data = np.asarray(
-                jax.random.key_data(jax.random.key(request.seed)))
+            request.key_data = _seed_key_data(request.seed)
         if self.speculative and request.draft_key_data is None:
-            request.draft_key_data = np.asarray(jax.random.key_data(
-                jax.random.fold_in(jax.random.key(request.seed), 1)))
+            request.draft_key_data = _seed_key_data(request.seed, fold=1)
         self.requests[request.rid] = request
         self._next_rid = max(self._next_rid, request.rid + 1)
         self.scheduler.enqueue(request)
@@ -912,8 +1034,15 @@ class InferenceEngine:
         oldest still-prefilling request (FCFS, matching admission order);
         the final chunk samples the request's first token (TTFT endpoint)
         and registers its prompt blocks for future prefix sharing."""
+        chunk = self._prefill_dispatch()
+        return 0 if chunk is None else self._prefill_finish(chunk)
+
+    def _prefill_dispatch(self):
+        """Launch the tick's prefill chunk, if a request is prefilling:
+        what :meth:`_prefill_finish` needs to read it back and account
+        it, else ``None``."""
         if not self._prefilling:
-            return 0
+            return None
         r = self.requests[self._prefilling[0]]
         seq = r.resume_seq           # == r.prompt unless resuming preempted
         plen = int(seq.shape[0])
@@ -923,17 +1052,30 @@ class InferenceEngine:
         with tracing.span("engine.prefill.prepare", rid=r.rid, p0=p0, n=c):
             t_start = self._now = self._clock()
             self._ensure_writable_range(r.slot, p0, c)
+            seat = ()
+            if self._dispatch_ahead:
+                # what the chunk leaves as the slot's newest token on the
+                # device: nothing mid-prompt, its own sample, or a resumed
+                # request's stored one (as _prefill_emit seats the host's)
+                seat = (np.int32(
+                    self._seat_none if p0 + c < plen else
+                    r.tokens[-1] if r.tokens else self._seat_sample),)
             args = (
                 seq[None, p0:p0 + c], np.int32(p0),
-                self.pool.device_table(r.slot), r.key_data,
-                np.float32(r.temperature),
+                self.pool.device_table(r.slot),
+                # a model with recurrent state is told whose rows these are
+                *((np.int32(r.slot),) if self.pool.recurrent else ()), *seat,
+                r.key_data, np.float32(r.temperature),
                 np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
                 np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
                 *self._bank_args(np.int32(getattr(r, "_adapter_row", 0))))
         with tracing.span("engine.prefill.dispatch", rid=r.rid):
-            kc, vc, tok, kd = self._chunk_prefill(
-                self.params, self.pool.kc, self.pool.vc, *args)
-            self.pool.kc, self.pool.vc = kc, vc
+            tok, kd = self._run_paged(self._chunk_prefill,
+                                      self._pack_chunk, *args)
+        return r, seq, p0, c, t_start, tok, kd
+
+    def _prefill_finish(self, chunk) -> int:
+        r, seq, p0, c, t_start, tok, kd = chunk
         with tracing.span("engine.prefill.wait", rid=r.rid):
             tok = int(np.asarray(tok))     # host sync: honest chunk timing
         with tracing.span("engine.prefill.emit", rid=r.rid):
@@ -995,6 +1137,26 @@ class InferenceEngine:
             self.pool.seat(r.slot, plen, tok)
         return 1
 
+    def _run_paged(self, program, pack, *args):
+        """Call one of the model's two paged programs (its host-side
+        arguments through the model's ``pack``, where it has one) and take
+        the donated pool buffers back — and the recurrent state buffers
+        that ride beside them, where the model has any."""
+        pool = self.pool
+        if pack is not None:
+            args = pack(*args)      # the model takes them as one transfer
+        if pool.recurrent:
+            pool.kc, pool.vc, pool.state, tok, kd = program(
+                self.params, pool.kc, pool.vc, pool.state, *args)
+        else:
+            pool.kc, pool.vc, tok, kd = program(
+                self.params, pool.kc, pool.vc, *args)
+        # start the read-back now: the copies are queued behind the program,
+        # and the ``*.wait`` that follows finds the bytes on the host
+        tok.copy_to_host_async()
+        kd.copy_to_host_async()
+        return tok, kd
+
     def _decoding_slots(self) -> list[int]:
         """Occupied slots whose request finished prefilling — the batched
         decode's participants this tick (still-prefilling slots sit out)."""
@@ -1004,7 +1166,16 @@ class InferenceEngine:
     def _decode_tick_paged(self, active: list[int]) -> int:
         if not active:
             return 0
+        return self._emit_decoded(*self._decode_dispatch(
+            [(s, int(self.pool.positions[s])) for s in active]))
+
+    def _decode_dispatch(self, seats: list[tuple[int, int]]):
+        """Launch one decode over ``seats``, ``(slot, position)`` of every
+        slot that takes part: ``(slots, tokens, key_data)`` as
+        :meth:`_emit_decoded` takes them, the last two still on the
+        device."""
         S = self.pool.n_slots
+        active = [s for s, _ in seats]
         with tracing.span("engine.decode.prepare"):
             kd, temps, top_ks, top_ps = self._sampling_inputs(active)
             # non-decoding slots: position 0 + all-trash table, so their
@@ -1013,21 +1184,81 @@ class InferenceEngine:
             toks = np.zeros(S, np.int32)
             tables = np.full((S, self.pool.blocks_per_seq),
                              PagedKVPool.TRASH, np.int32)
-            for s in active:
+            for s, p in seats:
                 # on-demand block allocation as this position advances (and
                 # copy-on-write if the write block is still shared)
-                self._ensure_writable_range(s, int(self.pool.positions[s]),
-                                            1)
+                self._ensure_writable_range(s, p, 1)
                 tables[s] = self.pool.device_table(s)
-                pos[s] = self.pool.positions[s]
+                pos[s] = p
                 toks[s] = self.pool.last_token[s]
             bank_args = self._bank_args(self._adapter_inputs(active))
+            live = ()
+            if self.pool.recurrent:
+                # the slots whose recurrent state this tick advances; the
+                # others' (mid-prefill, free) must come back unchanged
+                live = (np.zeros(S, bool),)
+                live[0][active] = True
         with tracing.span("engine.decode.dispatch"):
-            kc, vc, toks2, kd2 = self._decode(
-                self.params, self.pool.kc, self.pool.vc,
-                toks, pos, tables, kd, temps, top_ks, top_ps, *bank_args)
-            self.pool.kc, self.pool.vc = kc, vc
-        return self._emit_decoded(active, toks2, kd2)
+            toks2, kd2 = self._run_paged(
+                self._decode, self._pack_decode, toks, pos, tables, *live,
+                kd, temps, top_ks, top_ps, *bank_args)
+        return active, toks2, kd2
+
+    def _tick_ahead(self) -> tuple[int, int]:
+        """The paged tick of a model whose programs keep the newest tokens
+        on the device (``PagedServing.ahead``): the decode FIRST, then the
+        prefill chunk, whose slot decodes from the next tick on. In that
+        order the next tick's decode needs nothing this tick has yet to
+        read (its tokens are on the device, and who takes part follows
+        from lengths the host knows), so it is dispatched before this
+        tick's tokens are waited for and the device runs it while the host
+        emits, admits and prepares. A request that can end on a token
+        (``eos_id``) makes the next tick's slots unknowable: that tick
+        dispatches its own decode, in the same order. Returns the tokens
+        emitted and the slots that decoded."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            seats = [(s, int(self.pool.positions[s]))
+                     for s in self._decoding_slots()]
+            dec = self._decode_dispatch(seats) if seats else None
+        else:
+            # a request preempted or cancelled since the dispatch has left
+            # its slot: its token is dropped (a resumed one is sampled
+            # again from the key the host kept)
+            rids, (slots, toks, kd) = ahead
+            held = [s for s, rid in zip(slots, rids)
+                    if self.pool.occupant(s) == rid
+                    and self.requests[rid].prefill_pos is None]
+            dec = (held, toks, kd) if held else None
+        chunk = self._prefill_dispatch()
+        seats = self._seats_ahead(dec[0] if dec else (), chunk)
+        if seats:
+            self._ahead = ([self.pool.occupant(s) for s, _ in seats],
+                           self._decode_dispatch(seats))
+        emitted = self._emit_decoded(*dec) if dec else 0
+        if chunk is not None:
+            emitted += self._prefill_finish(chunk)
+        return emitted, len(dec[0]) if dec else 0
+
+    def _seats_ahead(self, decoding, chunk) -> list[tuple[int, int]] | None:
+        """``(slot, position)`` of the NEXT tick's decode while this
+        tick's (over ``decoding``) and its ``chunk`` are in flight, or
+        ``None`` where a token not yet read could end a request."""
+        seats = []
+        for s in decoding:
+            r = self.requests[self.pool.occupant(s)]
+            if len(r.tokens) + 1 >= r.max_new_tokens:
+                continue            # the token in flight is its last
+            if r.eos_id is not None:
+                return None
+            seats.append((s, int(self.pool.positions[s]) + 1))
+        if chunk is not None:
+            r, seq, p0, c = chunk[:4]
+            if p0 + c == len(seq) and (r.tokens or r.max_new_tokens > 1):
+                if r.eos_id is not None and not r.tokens:
+                    return None
+                seats.append((r.slot, len(seq)))
+        return sorted(seats)
 
     def _ensure_writable_range(self, slot: int, p0: int, n: int) -> None:
         """Allocate/copy-on-write every block covering positions

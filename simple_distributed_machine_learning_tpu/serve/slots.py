@@ -21,6 +21,21 @@ Two layouts, one slot discipline:
   of a ``max_len`` row, so the same bytes sustain strictly more concurrent
   requests (the ``bench.py --serve`` fixed-memory comparison).
 
+Recurrent state (paged, ``state_shapes``): a model with state-space layers
+(``models/jamba.py``) keeps, beside the K/V blocks of its few attention
+layers, a fixed-size recurrent buffer per slot and layer — no blocks, no
+length, nothing to share. :class:`PagedKVPool` holds those buffers
+(``pool.state``, one ``[n_slots, ...]`` array per leaf, indexed by slot)
+under the same slot discipline. Such state summarises a whole prefix in
+place, so a pool that has it matches and registers NO prompt prefix (a
+shared block would come without the state that belongs to it): every bind
+starts at position 0, where the model's prefill zeroes the slot's rows —
+a bound slot never sees its last occupant's state. Requests that WOULD
+have matched are counted (``prefix_declined_total``). A model may keep
+every slot's newest token and sampling key among these buffers too
+(``models/gpt.py::PagedServing.ahead``); ``last_token`` here then trails
+the device by the tick in flight.
+
 Both pools share the invariant-guarded slot free list: acquiring an occupied
 slot or releasing a free one raises instead of silently corrupting a
 neighbor's cache, and the paged pool extends the discipline to blocks — no
@@ -69,6 +84,8 @@ import numpy as np
 def kv_block_bytes(n_layers: int, n_heads: int, block_size: int,
                    head_dim: int, cache_dtype=None) -> int:
     """Bytes one physical K/V block pins across every layer (K and V).
+    ``n_layers`` / ``n_heads`` are the CACHE's: the layers that attend and
+    their K/V heads (a grouped-query model's query heads are more).
 
     The ONE copy of the formula: :class:`PagedKVPool` sizes its
     ``bytes_per_block`` (and therefore the ``serve_kv_bytes_resident``
@@ -161,6 +178,10 @@ class _SlotPoolBase:
         self.last_token = np.zeros(n_slots, np.int32)
         self._occupant: list[int | None] = [None] * n_slots
         self._free: list[int] = list(range(n_slots))[::-1]   # pop() -> slot 0
+        # a pool without recurrent state buffers (PagedKVPool may have them)
+        self.recurrent = False
+        self.state = ()
+        self.prefix_declined_total = 0
 
     # -- occupancy accounting ---------------------------------------------
 
@@ -351,9 +372,20 @@ class PagedKVPool(_SlotPoolBase):
                  max_len: int, head_dim: int, cache_dtype=None,
                  block_size: int = 16, n_blocks: int | None = None,
                  tp: int = 1, host_cache_blocks: int = 0,
-                 prefetch_ticks: int = 1) -> None:
+                 prefetch_ticks: int = 1, state_shapes=()) -> None:
         super().__init__(n_slots, max_len)
         self.tp = _check_tp(n_heads, tp)
+        import jax
+
+        # per-slot recurrent buffers beside the blocks (module docstring,
+        # "Recurrent state"): one array per leaf, not one over the layers,
+        # so a layer's update never copies its neighbours
+        leaves = jax.tree.leaves(state_shapes)
+        self.recurrent = bool(leaves)
+        if self.recurrent and (host_cache_blocks or self.tp > 1):
+            raise ValueError(
+                "recurrent state has no host offload tier and no "
+                "tensor-parallel placement (host_cache_blocks / tp)")
         if host_cache_blocks < 0:
             raise ValueError(
                 f"host_cache_blocks must be >= 0, got {host_cache_blocks}")
@@ -405,6 +437,17 @@ class PagedKVPool(_SlotPoolBase):
         else:
             self.kc = jnp.zeros(shape, cd)
             self.vc = jnp.zeros(shape, cd)
+        self.state = jax.tree.map(
+            lambda sd: jnp.zeros((n_slots, *sd.shape), sd.dtype),
+            state_shapes)
+        self.state_bytes_per_slot = sum(
+            math.prod(sd.shape) * jnp.dtype(sd.dtype).itemsize
+            for sd in leaves)
+        # first-block keys of prompts this pool would have registered,
+        # newest last and no more of them than blocks: what
+        # ``prefix_declined_total`` is counted against
+        self._would_be: collections.OrderedDict[bytes, None] = (
+            collections.OrderedDict())
         # PER-SHARD bytes (heads split tp ways by the TP serving programs):
         # the gauge tracks what one chip actually pins, which is the number
         # TP sharding exists to shrink — and what the analyzer's
@@ -561,6 +604,9 @@ class PagedKVPool(_SlotPoolBase):
                 f"reservation — the previous sequence was never ended")
         prompt = np.asarray(prompt)
         self._slot_ns[slot] = ns
+        if self.recurrent and self._first_block_key(ns, prompt) \
+                in self._would_be:
+            self.prefix_declined_total += 1
         shared_len, chain = self._probe_prefix(prompt, ns)
         for block, _fill in chain:
             self._ref_block(block)
@@ -732,6 +778,8 @@ class PagedKVPool(_SlotPoolBase):
         ``ns`` (capped at ``prompt_len - 1`` so at least one position is
         always recomputed). Returns ``(shared_len, [(block, fill), ...])``
         without mutating."""
+        if self.recurrent:
+            return 0, []        # a block without its state is no prefix
         prompt = np.asarray(prompt, np.int32)
         cap = int(prompt.shape[0]) - 1
         bs = self.block_size
@@ -756,6 +804,12 @@ class PagedKVPool(_SlotPoolBase):
             j += 1
         return shared, chain
 
+    def _first_block_key(self, ns: bytes, prompt) -> bytes | None:
+        """The registry key a prompt's first FULL block would have had."""
+        if len(prompt) < self.block_size:
+            return None
+        return ns + np.asarray(prompt[:self.block_size], np.int32).tobytes()
+
     def register_prefix(self, slot: int, prompt: np.ndarray) -> None:
         """Publish ``slot``'s freshly prefilled prompt blocks to the
         registry: one key per full block boundary plus the partial tail, so
@@ -767,6 +821,16 @@ class PagedKVPool(_SlotPoolBase):
         prompt = np.asarray(prompt, np.int32)
         ns = self._slot_ns[slot]
         bs = self.block_size
+        if self.recurrent:
+            # nothing is published; only remembered, to count the matches
+            # this pool has to decline
+            key = self._first_block_key(ns, prompt)
+            if key is not None:
+                self._would_be[key] = None
+                self._would_be.move_to_end(key)
+                if len(self._would_be) > self.n_blocks:
+                    self._would_be.popitem(last=False)
+            return
         table = self.tables[slot]
         plen = int(prompt.shape[0])
         for j in range(self.blocks_for(plen)):
@@ -1016,6 +1080,12 @@ class PagedKVPool(_SlotPoolBase):
             "cow_copies_total": self.cow_copies_total,
             "evictions_total": self.evictions_total,
         }
+        if self.recurrent:
+            s.update({
+                "state_bytes_resident":
+                    self.n_active * self.state_bytes_per_slot,
+                "prefix_declined_total": self.prefix_declined_total,
+            })
         if self.host_cache_blocks:
             s.update({
                 "host_blocks": len(self._host),

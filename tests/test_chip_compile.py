@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from simple_distributed_machine_learning_tpu.ops import (
     flash_attention as fa,
     paged_attention as pa,
+    selective_scan as ss,
 )
 
 
@@ -59,6 +60,7 @@ def mosaic(monkeypatch):
     ``paged_attention`` imported the function by name, so both are set."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
 
 
 def _compile(fn, one_chip, *shapes, kernels):
@@ -139,6 +141,52 @@ def test_paged_attention_compiles_with_bf16_queries(one_chip, mosaic):
     _compile(lambda q, kc, vc, t, p: pa.paged_attention(
         q, kc, vc, t, p, block_size=bs), one_chip, *shapes,
         kernels=["paged_attention"])
+
+
+def _kernel_pattern(mix: str, kernel: str) -> str:
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "bench_cells", "traffic", mix + ".json")) as f:
+        return json.load(f)["kernels"][kernel]
+
+
+def test_multi_query_paged_attention_compiles_at_the_hybrid_cells_shape(
+        one_chip, mosaic):
+    """``jamba2-3b.serve-reason-closed``: 128 slots, 20 query heads riding
+    the pool's ONE K/V head of 128, bfloat16 blocks of 16 (the 20 heads are
+    20 query rows of the head's block stream)."""
+    slots, heads, dh, bs, nb = 128, 20, 128, 16, 64
+    shapes = [((slots, heads, 1, dh), jnp.float32),
+              ((8193, 1, bs, dh), jnp.bfloat16),
+              ((8193, 1, bs, dh), jnp.bfloat16),
+              ((slots, nb), jnp.int32), ((slots, 1), jnp.int32)]
+    (line,) = _compile(lambda q, kc, vc, t, p: pa.paged_attention(
+        q, kc, vc, t, p, block_size=bs), one_chip, *shapes,
+        kernels=["paged_attention"])
+    assert re.search(_kernel_pattern("serve-reason-closed",
+                                     "paged_attention"), line.strip())
+    assert not re.search(_kernel_pattern("serve-reason-closed",
+                                         "selective_scan"), line.strip())
+
+
+# -- selective scan: the hybrid serve programs' kernel ------------------------
+
+
+@pytest.mark.parametrize("n,n_tok", [(128, 1), (1, 256), (1, 64), (1, 192),
+                                     (3, 7)])
+def test_selective_scan_compiles_for_v5e(one_chip, mosaic, n, n_tok):
+    """The published widths (``d_inner`` 5120, 16 states) at the shapes the
+    hybrid cell runs: the decode tick over 128 slots, the prefill chunks of
+    its prompt lengths; and a ragged walk."""
+    di, n_state, f32 = 5120, 16, jnp.float32
+    wide, narrow = ((n, n_tok, di), f32), ((n, n_tok, n_state), f32)
+    (line,) = _compile(
+        ss.selective_scan, one_chip, wide, wide, wide, narrow, narrow,
+        ((n_state, di), f32), ((di,), f32), ((n, n_state, di), f32),
+        kernels=["selective_scan"])
+    assert re.search(_kernel_pattern("serve-reason-closed",
+                                     "selective_scan"), line.strip())
+    assert not re.search(_kernel_pattern("serve-reason-closed",
+                                         "paged_attention"), line.strip())
 
 
 # -- flash attention: the train step's kernel -------------------------------

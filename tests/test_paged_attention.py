@@ -531,3 +531,38 @@ def test_kernel_hbm_mismatch_is_flagged():
     # exact agreement is silent
     good = [HBMCost("kernel.kv_stream", "paged_decode", want)]
     assert not _reconcile_kernel_hbm(good, model, sspec)
+
+
+# -- grouped-query attention: fewer K/V heads in the pool than query heads ----
+
+
+@pytest.mark.parametrize("n_q_heads,n_kv_heads,K", [(4, 1, 1), (6, 2, 1),
+                                                    (4, 1, 3)])
+def test_grouped_query_pool_matches_dense_over_a_repeated_head(
+        n_q_heads, n_kv_heads, K):
+    """The pool holds ``n_kv_heads``; the kernel must give what dense
+    attention gives over a pool in which every K/V head is repeated for
+    the query heads of its group (head ``h`` reads K/V head ``h //
+    group``). Same tolerance as the multi-head test: identical K/V, only
+    the accumulation order differs."""
+    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(5), H=n_kv_heads)
+    q = jax.random.normal(kq, (3, n_q_heads, K, 16))
+    qpos = np.minimum(pos[:, None] + np.arange(K)[None, :],
+                      6 * 4 - 1).astype(np.int32)
+    out = jax.jit(lambda *a: paged_attention(*a, block_size=4))(
+        q, kc, vc, jnp.asarray(tables), jnp.asarray(qpos))
+    group = n_q_heads // n_kv_heads
+    ref = _dense_paged_reference(q, jnp.repeat(kc, group, axis=1),
+                                 jnp.repeat(vc, group, axis=1), tables, qpos)
+    rtol, atol = attn_tol(jnp.float32)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=rtol, atol=atol)
+
+
+def test_query_heads_must_be_a_multiple_of_the_pools_heads():
+    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(6), H=2)
+    q = jax.random.normal(kq, (3, 3, 1, 16))
+    with pytest.raises(ValueError, match="do not divide"):
+        paged_attention(q, kc, vc, jnp.asarray(tables),
+                        jnp.asarray(pos[:, None]), block_size=4)
