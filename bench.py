@@ -962,7 +962,8 @@ def _measure_paged_vs_dense(stages, cfg, slots: int, n_requests: int,
         peak, done, tps = _burst(engine, burst)
         out.append({
             "config": label, "n_slots": kw["n_slots"],
-            "kv_bytes": int(engine.pool.kc.nbytes + engine.pool.vc.nbytes),
+            "kv_bytes": sum(int(a.nbytes) for a in jax.tree.leaves(
+                (engine.pool.kc, engine.pool.vc))),
             "n_requests": len(burst), "completed": done,
             "max_concurrent": peak, "tokens_per_sec": tps, **dev,
         })

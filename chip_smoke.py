@@ -281,10 +281,12 @@ def serve_run(stages, cfg, params, prompts, *, attn_kernel: str,
     done = [h for h in handles if len(h.tokens) == SERVE_NEW]
     check(len(done) == len(prompts),
           f"serve[{label}]: {len(done)}/{len(prompts)} requests completed")
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves((eng.pool.kc,
+                                                        eng.pool.vc)))
     print(f"serve[{label}]: {len(done)}/{len(prompts)} requests completed, "
           f"{ticks} ticks, {wall:.2f} s wall incl. compiles (first tick "
           f"{first_tick:.2f} s), kv drift 0 bytes, pool "
-          f"{eng.pool.kc.nbytes + eng.pool.vc.nbytes} bytes")
+          f"{pool_bytes} bytes")
     return eng, [list(h.tokens) for h in handles]
 
 
@@ -299,17 +301,18 @@ def lowered_decode_tick(eng) -> str:
     return tick.fn.lower(*args).as_text()
 
 
-def gathered_rows(cache, tables) -> jax.Array:
+def gathered_rows(cache, tables, n_heads: int) -> jax.Array:
     """Layer 0's rows under every slot's table, ``[S, H, NB * bs, dh]`` f32:
     a plain gather (dequantized for a :class:`QuantKV` pool), written here so
     the reference shares no code with the paths it checks."""
-    if isinstance(cache, QuantKV):
-        rows = (cache.data[0][tables].astype(jnp.float32)
-                * cache.scale[0][tables][..., None])
-    else:
-        rows = cache[0][tables].astype(jnp.float32)
-    S, NB, H, bs, dh = rows.shape
-    return jnp.moveaxis(rows, 2, 1).reshape(S, H, NB * bs, dh)
+    layer = cache[0]                 # [n_blocks+1, bs, H*dh]: heads in a row
+    quant = isinstance(layer, QuantKV)
+    rows = (layer.data if quant else layer)[tables].astype(jnp.float32)
+    S, NB, bs, _ = rows.shape
+    rows = rows.reshape(S, NB * bs, n_heads, -1)
+    if quant:
+        rows = rows * layer.scale[tables].reshape(S, NB * bs, n_heads, 1)
+    return jnp.moveaxis(rows, 2, 1)
 
 
 def attention_parity(eng, cache_dtype, seed: int, label: str) -> None:
@@ -326,21 +329,22 @@ def attention_parity(eng, cache_dtype, seed: int, label: str) -> None:
     S, NB, bs = pool.n_slots, pool.blocks_per_seq, SERVE_BLOCK
     H, dh = eng.cfg.n_heads, eng.cfg.d_model // eng.cfg.n_heads
     rng = np.random.default_rng(seed + 11)
-    n_phys = jax.tree.leaves(pool.kc)[0].shape[1]
+    n_phys = pool.n_blocks + 1
     # physical blocks 1.. (0 is the trash block) hold what the run wrote
     tables = jnp.asarray(rng.integers(1, n_phys, size=(S, NB)), jnp.int32)
     pos = jnp.asarray(rng.integers(bs, NB * bs, size=(S,)), jnp.int32)
     q = jnp.asarray(rng.standard_normal((S, H, 1, dh)), jnp.float32)
 
     def both(kc, vc):
-        if isinstance(kc, QuantKV):
-            fused = paged_attention(q, kc.data[0], vc.data[0], tables,
+        if isinstance(kc[0], QuantKV):
+            fused = paged_attention(q, kc[0].data, vc[0].data, tables,
                                     pos[:, None], block_size=bs,
-                                    kscale=kc.scale[0], vscale=vc.scale[0])
+                                    kscale=kc[0].scale, vscale=vc[0].scale)
         else:
             fused = paged_attention(q, kc[0], vc[0], tables, pos[:, None],
                                     block_size=bs)
-        krow, vrow = gathered_rows(kc, tables), gathered_rows(vc, tables)
+        krow = gathered_rows(kc, tables, H)
+        vrow = gathered_rows(vc, tables, H)
         live = (jnp.arange(NB * bs)[None, None, None, :]
                 <= pos[:, None, None, None])
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, krow) / np.sqrt(dh)

@@ -229,7 +229,7 @@ def oob_block_table() -> Report:
     params = [jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), s.params)
         for s in stages]
-    kc = jax.ShapeDtypeStruct((1, n_blocks + 1, 2, bs, 4), np.float32)
+    kc = (jax.ShapeDtypeStruct((n_blocks + 1, bs, 2 * 4), np.float32),)
     return analyze(
         step, params, kc, kc,
         spec((S,), np.int32, 0, cfg.vocab - 1),
@@ -275,7 +275,7 @@ def _cow_tick_report(threaded: bool, name: str) -> Report:
     params = [jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), s.params)
         for s in stages]
-    kc = jax.ShapeDtypeStruct((1, n_blocks + 1, 2, bs, 4), np.float32)
+    kc = (jax.ShapeDtypeStruct((n_blocks + 1, bs, 2 * 4), np.float32),)
     return analyze(
         tick, params, kc, kc,
         spec((1, 3), np.int32, 0, cfg.vocab - 1),
@@ -364,12 +364,13 @@ def clean_gather_before_use() -> Report:
 
 # -- kernel-*: seeded Pallas kernel defects (analysis/kernels.py) ----------
 
-def _paged_kernel_report(table_hi_slack: int, layout: str,
+def _paged_kernel_report(table_hi_slack: int, H: int,
                          dh: int, bs: int, name: str) -> Report:
-    """Trace the REAL fused paged-attention kernel on synthetic shapes with
-    a block-table contract reaching ``n_blocks + table_hi_slack`` — slack 0
-    is the slots.py invariant (clean), slack 1 is a table that can point
-    one block past the pool (kernel-oob)."""
+    """Trace the REAL fused paged-attention kernel on synthetic shapes
+    (``H`` heads of ``dh`` in a pool row) with a block-table contract
+    reaching ``n_blocks + table_hi_slack`` — slack 0 is the slots.py
+    invariant (clean), slack 1 is a table that can point one block past
+    the pool (kernel-oob)."""
     import jax
     import numpy as np
 
@@ -380,14 +381,13 @@ def _paged_kernel_report(table_hi_slack: int, layout: str,
     from simple_distributed_machine_learning_tpu.ops.paged_attention import (
         paged_attention,
     )
-    S, H, K, NB, n_blocks = 2, 2, 1, 3, 5
+    S, K, NB, n_blocks = 2, 1, 3, 5
 
     def attend(q, kc, vc, tables, qpos):
-        return paged_attention(q, kc, vc, tables, qpos, block_size=bs,
-                               _layout=layout)
+        return paged_attention(q, kc, vc, tables, qpos, block_size=bs)
 
     q = jax.ShapeDtypeStruct((S, H, K, dh), np.float32)
-    kv = jax.ShapeDtypeStruct((n_blocks + 1, H, bs, dh), np.float32)
+    kv = jax.ShapeDtypeStruct((n_blocks + 1, bs, H * dh), np.float32)
     return analyze(
         attend, q, kv, kv,
         spec((S, NB), np.int32, 0, n_blocks + table_hi_slack),
@@ -399,30 +399,31 @@ def kernel_oob_index_map() -> Report:
     """The fused kernel's K/V index map fed a block-table contract that can
     reach one past the pool: the BlockSpec would stream a window outside
     the backing buffer."""
-    return _paged_kernel_report(1, "natural", dh=8, bs=4,
+    return _paged_kernel_report(1, H=2, dh=8, bs=4,
                                 name="fixture:kernel_oob_index_map")
 
 
 def kernel_clean_paged() -> Report:
     """The same kernel under the slots.py table invariant — every index
     map proves in bounds (must be fully clean)."""
-    return _paged_kernel_report(0, "natural", dh=8, bs=4,
+    return _paged_kernel_report(0, H=2, dh=8, bs=4,
                                 name="fixture:kernel_clean_paged")
 
 
 def kernel_bad_tile() -> Report:
-    """The pre-fix small-head-dim layout at a TPU-realistic block size:
-    dh=4 in the 128-lane slot pads every K/V block 32x (the ROADMAP #2
-    hazard the 'packed' layout fixes)."""
-    return _paged_kernel_report(0, "natural", dh=4, bs=128,
+    """A pool row too narrow for the lanes at a TPU-realistic block size:
+    ONE head of dh=4 in the 128-lane slot pads every K/V block 32x (the
+    ROADMAP #2 small-head-dim hazard)."""
+    return _paged_kernel_report(0, H=1, dh=4, bs=128,
                                 name="fixture:kernel_bad_tile")
 
 
-def kernel_packed_tile() -> Report:
-    """The fixed layout for the same shapes: block positions in the lane
-    slot, the small head dim padded <= 2x into sublanes (must be clean)."""
-    return _paged_kernel_report(0, "packed", dh=4, bs=128,
-                                name="fixture:kernel_packed_tile")
+def kernel_rows_in_lanes_tile() -> Report:
+    """The pool's layout at the same head dim and block size: all 32 heads
+    of a position side by side fill the 128 lanes, nothing padded (must be
+    clean)."""
+    return _paged_kernel_report(0, H=32, dh=4, bs=128,
+                                name="fixture:kernel_rows_in_lanes_tile")
 
 
 def _grid_kernel_report(racing: bool, scratch_dtype, name: str) -> Report:
@@ -637,9 +638,9 @@ FIXTURES: dict[str, Fixture] = {f.name: f for f in [
     Fixture("kernel_clean_grid", "", False,
             "the grid kernel with its output indexed by the parallel axis",
             kernel_clean_grid),
-    Fixture("kernel_packed_tile", "", False,
-            "the small-head-dim kernel in the fixed 'packed' layout",
-            kernel_packed_tile),
+    Fixture("kernel_rows_in_lanes_tile", "", False,
+            "the small-head-dim kernel over rows that fill the lanes",
+            kernel_rows_in_lanes_tile),
     Fixture("kernel_f32_accumulator", "", False,
             "the grid kernel with its scratch accumulator in f32",
             kernel_f32_accumulator),

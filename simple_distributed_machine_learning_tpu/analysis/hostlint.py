@@ -70,6 +70,7 @@ DECODE_BUILDER_NAMES = (
     "make_paged_prefill_chunk",
     "make_paged_decode_step",
     "make_paged_block_copy",
+    "make_paged_block_write",
     "make_adapter_bank_update",
     "make_slot_propose",
     "make_slot_verify_step",

@@ -376,8 +376,9 @@ def check_pallas_call(walker, eqn, ins, env):
                         where=src,
                         hint="swap the trailing block dims (pack the "
                              "small dim into sublanes, the long one into "
-                             "lanes) — ops/paged_attention.py's 'packed' "
-                             "layout is the reference fix"))
+                             "lanes), or widen the row: the paged pool "
+                             "keeps all heads of a position in one row "
+                             "(serve/slots.py::PagedKVPool)"))
 
     # (4) dtype lint: sub-f32 floating scratch accumulators
     body = eqn.params.get("jaxpr")

@@ -223,10 +223,11 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
 
 
-def _cache_sds(shape, cache_dtype):
-    """Abstract pool buffer for ``shape`` under ``cache_dtype``: a plain
-    struct, or the QuantKV (data + per-row scale plane) pytree a
-    quantized pool actually threads through every tick program."""
+def _cache_sds(n_layers, n_phys, bs, n_heads, head_dim, cache_dtype):
+    """Abstract paged pool: one buffer a layer, ``[n_phys, bs, H*dh]``, as
+    a plain struct or the QuantKV (data + per-row scale plane) pytree a
+    quantized pool actually threads through every tick program
+    (``serve/slots.py::PagedKVPool``)."""
     import numpy as np
 
     from simple_distributed_machine_learning_tpu.models.gpt import (
@@ -234,10 +235,10 @@ def _cache_sds(shape, cache_dtype):
         _cache_dtype,
         _is_quantized_dtype,
     )
+    layer = _sds((n_phys, bs, n_heads * head_dim), _cache_dtype(cache_dtype))
     if _is_quantized_dtype(cache_dtype):
-        return QuantKV(_sds(shape, _cache_dtype(cache_dtype)),
-                       _sds(shape[:-1], np.float32))
-    return _sds(shape, _cache_dtype(cache_dtype))
+        layer = QuantKV(layer, _sds((n_phys, bs, n_heads), np.float32))
+    return (layer,) * n_layers
 
 
 def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
@@ -493,7 +494,7 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
         return programs, findings
 
     # paged layout
-    kc = _cache_sds((L, n_blocks + 1, H, bs, dh), sspec.cache_dtype)
+    kc = _cache_sds(L, n_blocks + 1, bs, H, dh, sspec.cache_dtype)
     kernel = sspec.attn_kernel
     tables = spec((S, NB), np.int32, 0, n_blocks)
     table1 = spec((NB,), np.int32, 0, n_blocks)
@@ -1109,9 +1110,9 @@ def engine_spec(engine, prompt_lens: tuple | None = None) -> ServeSpec:
         block_size=pool.block_size if paged else 16,
         n_blocks=pool.n_blocks if paged else None,
         prefill_chunk=engine.prefill_chunk,
-        # pool.kc.dtype covers QuantKV too (its dtype property is the
-        # narrow storage dtype, which round-trips through _cache_dtype)
-        cache_dtype=pool.kc.dtype, prompt_lens=prompt_lens,
+        # the storage dtype (a quantized pool's is its narrow one, which
+        # round-trips through _cache_dtype)
+        cache_dtype=pool.cache_dtype, prompt_lens=prompt_lens,
         spec_k=engine.spec_k if engine.speculative else 0,
         draft_cfg=engine.draft_cfg,
         attn_kernel=engine.attn_kernel,

@@ -694,13 +694,14 @@ def _is_quantized_dtype(cache_dtype) -> bool:
 
 
 class QuantKV(NamedTuple):
-    """One quantized K or V pool buffer: narrow-dtype block ``data``
-    (``[L, n_blocks+1, H, bs, dh]``) plus the per-row f32 dequant
-    ``scale`` plane (``[L, n_blocks+1, H, bs]`` — one scale per written
-    position per head, so incremental decode writes never re-quantize a
-    block's existing rows). A NamedTuple so jax treats the pair as ONE
-    pytree buffer: jit donation, device_put sharding and tree_map'd block
-    copies all flow through unchanged engine/pool code."""
+    """One layer's quantized K or V pool buffer: narrow-dtype block
+    ``data`` (``[n_blocks+1, bs, H*dh]``, a position's heads side by side
+    like the plain buffer's) plus the per-row f32 dequant ``scale`` plane
+    (``[n_blocks+1, bs, H]`` — one scale per written position per head, so
+    incremental decode writes never re-quantize a block's existing rows).
+    A NamedTuple so jax treats the pair as ONE pytree buffer: jit
+    donation, device_put sharding and tree_map'd block copies all flow
+    through unchanged engine/pool code."""
     data: jax.Array
     scale: jax.Array
 
@@ -876,9 +877,12 @@ def _tp_jit(name, body, mesh, n_buf_in, n_rest_in, n_buf_out, n_rest_out,
     """``jit(shard_map(body))`` with the serving specs, the program called
     ``name`` (what a device trace shows it as): params as the
     ``(stacked blocks, replicated embed/head)`` pair, ``n_buf_in`` K/V pool
-    buffers sharded on their HEAD axis (dim 2 in both layouts), everything
-    else replicated. The pool buffers are donated exactly as in the
-    single-device builders."""
+    buffers sharded on their HEAD axis (dim 2 in both layouts: the dense
+    slot pool's ``[L, S, H, max_len, dh]`` and every leaf of the paged
+    pool's per-layer ``[n_blocks+1, bs, H*dh]``, whose lanes hold a shard's
+    heads contiguously; one spec is the prefix of either pytree),
+    everything else replicated. The pool buffers are donated exactly as in
+    the single-device builders."""
     from jax.sharding import PartitionSpec as P
 
     from simple_distributed_machine_learning_tpu.parallel.compat import (
@@ -1619,64 +1623,68 @@ def _validate_paged_build(stages, cfg: GPTConfig, max_len: int,
         raise ValueError(f"{caller} needs block_size >= 1, got {block_size}")
 
 
-def _gather_paged_rows(cache_l: jax.Array, table: jax.Array) -> jax.Array:
-    """Assemble a sequence's contiguous K or V row from the paged pool.
-
-    ``cache_l``: one layer's blocks ``[n_blocks, H, bs, dh]``; ``table``:
-    logical->physical block ids, ``[NB]`` (one sequence) or ``[S, NB]``
-    (one per slot). Returns ``[..., H, NB*bs, dh]`` with position ``p``
-    of the sequence at flattened row index ``p`` — EXACTLY the dense
-    layout's row order, so the attention math downstream is unchanged and
-    the trailing garbage rows (trash-block entries past the allocated
-    span) are removed by the same position mask that already hides
-    not-yet-written dense rows."""
-    rows = cache_l[table]                     # [..., NB, H, bs, dh]
-    rows = jnp.moveaxis(rows, -4, -3)         # [..., H, NB, bs, dh]
-    return rows.reshape(*rows.shape[:-3],
-                        rows.shape[-3] * rows.shape[-2], rows.shape[-1])
-
-
 def _paged_scatter(kc, li, phys, off, rows):
     """Land K/V ``rows`` (``[..., H, dh]``, aligned with the ``phys``/
-    ``off`` index arrays ``[...]``) at layer ``li`` of a paged pool buffer
-    — the ONE scatter every paged program uses. Plain buffers cast to the
-    storage dtype; :class:`QuantKV` buffers quantize each row and land its
+    ``off`` index arrays ``[...]``) in layer ``li``'s buffer of a paged
+    pool — the ONE scatter every paged program uses. ``kc`` is the pool's
+    tuple of per-layer buffers ``[n_blocks+1, bs, H*dh]``: a position's
+    heads lie side by side in one row, the two indexed axes lead and are
+    adjacent, so the write is one contiguous row a position and XLA keeps
+    it in place on the donated buffer. Plain buffers cast to the storage
+    dtype; :class:`QuantKV` buffers quantize each head's row and land its
     scale in the matching plane, so a quantized pool never holds a
     half-updated (data, scale) pair."""
-    if isinstance(kc, QuantKV):
-        qd, sc = _quantize_rows(rows, kc.data.dtype)
-        return QuantKV(kc.data.at[li, phys, :, off, :].set(qd),
-                       kc.scale.at[li, phys, :, off].set(sc))
-    return kc.at[li, phys, :, off, :].set(rows.astype(kc.dtype))
+    buf = kc[li]
+    if isinstance(buf, QuantKV):
+        qd, sc = _quantize_rows(rows, buf.data.dtype)
+        new = QuantKV(
+            buf.data.at[phys, off].set(qd.reshape(*qd.shape[:-2], -1)),
+            buf.scale.at[phys, off].set(sc))
+    else:
+        new = buf.at[phys, off].set(
+            rows.reshape(*rows.shape[:-2], -1).astype(buf.dtype))
+    return kc[:li] + (new,) + kc[li + 1:]
 
 
-def _paged_gather(kc, li, table):
-    """Layer ``li``'s gathered sequence rows (``[..., H, span, dh]``) for
-    the dense-math attention path; :class:`QuantKV` buffers dequantize
+def _paged_gather(kc, li, table, n_heads):
+    """Layer ``li``'s K or V rows of a sequence, assembled from the paged
+    pool for the dense-math attention path. ``table``: logical->physical
+    block ids, ``[NB]`` (one sequence) or ``[S, NB]`` (one per slot);
+    ``n_heads``: the heads in a pool row. Returns ``[..., H, NB*bs, dh]``
+    with position ``p`` of the sequence at flattened row index ``p`` —
+    EXACTLY the dense layout's row order, so the attention math downstream
+    is unchanged and the trailing garbage rows (trash-block entries past
+    the allocated span) are removed by the same position mask that already
+    hides not-yet-written dense rows. :class:`QuantKV` buffers dequantize
     (``data * scale``, f32) so the downstream einsums see ordinary rows."""
-    if isinstance(kc, QuantKV):
-        rows = _gather_paged_rows(kc.data[li], table).astype(jnp.float32)
-        sc = kc.scale[li][table]              # [..., NB, H, bs]
-        sc = jnp.moveaxis(sc, -3, -2)         # [..., H, NB, bs]
-        sc = sc.reshape(*sc.shape[:-2], sc.shape[-2] * sc.shape[-1])
-        return rows * sc[..., None]
-    return _gather_paged_rows(kc[li], table)
+    buf = kc[li]
+    quant = isinstance(buf, QuantKV)
+    rows = (buf.data if quant else buf)[table]    # [..., NB, bs, H*dh]
+    lead = rows.shape[:-3]
+    span = rows.shape[-3] * rows.shape[-2]
+    rows = rows.reshape(*lead, span, n_heads, -1)
+    if quant:
+        sc = buf.scale[table].reshape(*lead, span, n_heads)
+        rows = rows.astype(jnp.float32) * sc[..., None]
+    return jnp.moveaxis(rows, -3, -2)             # [..., H, span, dh]
 
 
 def _paged_attend(kc, vc, li, q, tables, qpos, bs):
     """The FUSED attention path: one Pallas pass over layer ``li``'s
     physical blocks (gather + mask + online-softmax attention, dequant
     fused for :class:`QuantKV` pools) — see ``ops/paged_attention.py``.
-    ``q``: [S, H, K, dh]; ``qpos``: [S, K]. Returns f32 [S, H, K, dh],
-    exactly the dense-math path's masked attention output."""
+    The layer's buffer goes over whole, as the pool holds it. ``q``:
+    [S, H, K, dh]; ``qpos``: [S, K]. Returns f32 [S, H, K, dh], exactly
+    the dense-math path's masked attention output."""
     from simple_distributed_machine_learning_tpu.ops.paged_attention import (
         paged_attention,
     )
-    if isinstance(kc, QuantKV):
-        return paged_attention(q, kc.data[li], vc.data[li], tables, qpos,
-                               block_size=bs, kscale=kc.scale[li],
-                               vscale=vc.scale[li])
-    return paged_attention(q, kc[li], vc[li], tables, qpos, block_size=bs)
+    k, v = kc[li], vc[li]
+    if isinstance(k, QuantKV):
+        return paged_attention(q, k.data, v.data, tables, qpos,
+                               block_size=bs, kscale=k.scale,
+                               vscale=v.scale)
+    return paged_attention(q, k, v, tables, qpos, block_size=bs)
 
 
 def _check_attn_kernel(kernel: str, caller: str) -> str:
@@ -1717,8 +1725,9 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     path's parity is dtype-conditional (the decode tick round-trips the
     cache in BOTH paths, so it is exempt).
 
-    ``kc``/``vc`` (``[L, n_blocks+1, H, block_size, dh]``) are donated —
-    the engine always threads the returned buffers back into the pool.
+    ``kc``/``vc`` (one ``[n_blocks+1, block_size, H*dh]`` buffer a layer,
+    ``serve/slots.py::PagedKVPool``) are donated, every leaf — the engine
+    always threads the returned buffers back into the pool.
     ``adapters=True`` appends the traced ``(bank, aid)`` multi-tenant
     args (:func:`make_slot_prefill`'s adapter notes apply).
     """
@@ -1753,8 +1762,8 @@ def _paged_chunk_fwd(blocks, embed, head, kc, vc, tokens, p0, table, H, bs,
                               None if ab_at is None else ab_at(li))
         kc = _paged_scatter(kc, li, phys, off, k_[0].swapaxes(0, 1))
         vc = _paged_scatter(vc, li, phys, off, v[0].swapaxes(0, 1))
-        krow = _paged_gather(kc, li, table)       # [H, span, dh]
-        vrow = _paged_gather(vc, li, table)
+        krow = _paged_gather(kc, li, table, H)    # [H, span, dh]
+        vrow = _paged_gather(vc, li, table, H)
         scores = jnp.einsum("bhqd,hkd->bhqk", q, krow) / math.sqrt(dh)
         scores = jnp.where(live, scores, -jnp.inf)
         a = jnp.einsum("bhqk,hkd->bhqd",
@@ -1836,7 +1845,7 @@ def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
 
     The block-gather twin of :func:`make_slot_decode_step`: ONE batched
     token step over all slots, but each slot's K/V row is assembled from
-    its block table (:func:`_gather_paged_rows`) instead of a dense pool
+    its block table (:func:`_paged_gather`) instead of a dense pool
     row, and its new K/V lands via a per-slot scatter into physical block
     ``tables[s, pos // bs]`` at offset ``pos % bs``. Values for live
     positions are bit-identical to the dense layout's (same numbers,
@@ -1902,8 +1911,8 @@ def _paged_decode_fwd(blocks, embed, head, kc, vc, toks, pos, tables, H, bs,
         if kernel == "fused":
             a = _paged_attend(kc, vc, li, q, tables, pos[:, None], bs)
         else:
-            krow = _paged_gather(kc, li, tables)          # [S,H,span,dh]
-            vrow = _paged_gather(vc, li, tables)
+            krow = _paged_gather(kc, li, tables, H)       # [S,H,span,dh]
+            vrow = _paged_gather(vc, li, tables, H)
             scores = (jnp.einsum("bhqd,bhkd->bhqk", q, krow)
                       / math.sqrt(dh))
             scores = jnp.where(live, scores, -jnp.inf)
@@ -1984,22 +1993,42 @@ def make_paged_block_copy():
     divergent write. Buffers are donated so XLA updates the pool in place
     instead of materializing a second pool; ``dst``/``src`` are traced
     scalars so one compiled program serves every copy. Tree-mapped over
-    the buffer leaves, so a quantized pool's :class:`QuantKV` pair (block
-    data AND its scale plane, both with the physical-block axis at dim 1)
-    copies atomically — a CoW that moved rows without their scales would
-    silently rescale the destination block."""
+    the buffer leaves (every layer's, the physical-block axis leading), so
+    a quantized pool's :class:`QuantKV` pair (block data AND its scale
+    plane) copies atomically — a CoW that moved rows without their scales
+    would silently rescale the destination block."""
     def build():
         @functools.partial(jax.jit, donate_argnums=(0, 1))
         def copy(kc, vc, dst, src):
             def one(buf):
-                blk = jax.lax.dynamic_slice_in_dim(buf, src, 1, 1)
-                return jax.lax.dynamic_update_slice_in_dim(buf, blk, dst, 1)
+                blk = jax.lax.dynamic_slice_in_dim(buf, src, 1, 0)
+                return jax.lax.dynamic_update_slice_in_dim(buf, blk, dst, 0)
 
             return jax.tree.map(one, kc), jax.tree.map(one, vc)
 
         return copy
 
     return _memo_build(("paged_block_copy",), build)
+
+
+def make_paged_block_write():
+    """The host tier's upload: ``write(kc, vc, dst, hk, hv) -> (kc, vc)``
+    lands one block's host rows (``hk``/``hv``: the pool's pytrees less
+    the block axis, what ``PagedKVPool._block_to_host`` took) at physical
+    block ``dst`` of every layer. Donated and jitted like the copy above:
+    an eager ``.at[].set`` copies a whole buffer for every promoted block."""
+    def build():
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        def write(kc, vc, dst, hk, hv):
+            def one(buf, rows):
+                return jax.lax.dynamic_update_slice_in_dim(
+                    buf, rows[None], dst, 0)
+
+            return jax.tree.map(one, kc, hk), jax.tree.map(one, vc, hv)
+
+        return write
+
+    return _memo_build(("paged_block_write",), build)
 
 
 def make_adapter_bank_update():
@@ -2387,8 +2416,8 @@ def _paged_verify_fwd(blocks, embed, head, kc, vc, xs, qpos, wphys, woff,
         if kernel == "fused":
             a = _paged_attend(kc, vc, li, q, tables, qpos, bs)
         else:
-            krow = _paged_gather(kc, li, tables)             # [S,H,span,dh]
-            vrow = _paged_gather(vc, li, tables)
+            krow = _paged_gather(kc, li, tables, H)          # [S,H,span,dh]
+            vrow = _paged_gather(vc, li, tables, H)
             scores = (jnp.einsum("bhqd,bhkd->bhqk", q, krow)
                       / math.sqrt(dh))
             scores = jnp.where(live, scores, -jnp.inf)
@@ -2662,6 +2691,7 @@ DECODE_BUILDERS = {
     "make_paged_prefill_chunk": make_paged_prefill_chunk,
     "make_paged_decode_step": make_paged_decode_step,
     "make_paged_block_copy": make_paged_block_copy,
+    "make_paged_block_write": make_paged_block_write,
     "make_adapter_bank_update": make_adapter_bank_update,
     "make_slot_propose": make_slot_propose,
     "make_slot_verify_step": make_slot_verify_step,

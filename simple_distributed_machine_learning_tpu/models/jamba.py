@@ -472,8 +472,10 @@ def _hybrid_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
             kc = _paged_scatter(kc, ai, phys, off, k[0])
             vc = _paged_scatter(vc, ai, phys, off, v[0])
             # [KV, span, dh] -> [1, span, KV, dh]
-            krow = jnp.swapaxes(_paged_gather(kc, ai, table), 0, 1)[None]
-            vrow = jnp.swapaxes(_paged_gather(vc, ai, table), 0, 1)[None]
+            krow = jnp.swapaxes(
+                _paged_gather(kc, ai, table, cfg.n_kv_heads), 0, 1)[None]
+            vrow = jnp.swapaxes(
+                _paged_gather(vc, ai, table, cfg.n_kv_heads), 0, 1)[None]
             h = h + matmul_acc32(
                 _grouped_attention(q, krow, vrow, seen, cfg),
                 bp["attn"]["wo"])
@@ -546,8 +548,10 @@ def _hybrid_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
                 a = jnp.swapaxes(a, 1, 2).reshape(a.shape[0], 1, -1)
             else:
                 # [S, KV, span, dh] -> [S, span, KV, dh]
-                krow = jnp.swapaxes(_paged_gather(kc, ai, tables), 1, 2)
-                vrow = jnp.swapaxes(_paged_gather(vc, ai, tables), 1, 2)
+                krow = jnp.swapaxes(
+                    _paged_gather(kc, ai, tables, cfg.n_kv_heads), 1, 2)
+                vrow = jnp.swapaxes(
+                    _paged_gather(vc, ai, tables, cfg.n_kv_heads), 1, 2)
                 a = _grouped_attention(q, krow, vrow, seen, cfg)
             h = h + matmul_acc32(a, bp["attn"]["wo"])
             ai += 1

@@ -500,9 +500,11 @@ class InferenceEngine:
         from simple_distributed_machine_learning_tpu.parallel.mesh import (
             MODEL_AXIS,
         )
-        # the head axis is dim 2 in every pool leaf — block data AND (for
-        # quantized pools) the QuantKV scale planes — so one spec places
-        # the whole pytree per-shard
+        # the head axis is dim 2 in every pool leaf — the dense pool's
+        # [L, S, H, max_len, dh], a paged layer's [n_blocks+1, bs, H*dh]
+        # (a shard's heads are contiguous lanes) AND (for quantized pools)
+        # its QuantKV scale plane — so one spec places the whole pytree
+        # per-shard
         cache_sh = NamedSharding(mesh, P(None, None, MODEL_AXIS))
         self.pool.kc = jax.tree.map(
             lambda leaf: jax.device_put(leaf, cache_sh), self.pool.kc)

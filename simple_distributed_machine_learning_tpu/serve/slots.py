@@ -10,10 +10,14 @@ Two layouts, one slot discipline:
   (``bench.py --serve``) and for engines built with ``kv_layout="dense"``.
 
 - :class:`PagedKVPool` — the block-table paged layout (vLLM-style): a global
-  pool of fixed-size K/V *blocks* (``[L, n_blocks+1, H, block_size, dh]``;
-  physical block 0 is the trash block inactive slots write into), a
-  per-slot block table mapping logical block ``j`` (positions
-  ``[j*bs, (j+1)*bs)``) to a physical block, on-demand allocation as
+  pool of fixed-size K/V *blocks*, one buffer per layer
+  (``[n_blocks+1, block_size, H*dh]``: a position's heads side by side in
+  one row, the layout the programs scatter, gather and attend in, so a
+  tick writes its rows in place on the donated buffers and hands the
+  attention kernel a layer's buffer untouched; physical block 0 is the
+  trash block inactive slots write into), a per-slot block table mapping
+  logical block ``j`` (positions ``[j*bs, (j+1)*bs)``) to a physical
+  block, on-demand allocation as
   positions advance, and copy-on-write prefix sharing: requests with a
   common prompt prefix reference the same physical blocks until they
   diverge, and the first write into a shared block copies it first.
@@ -423,20 +427,23 @@ class PagedKVPool(_SlotPoolBase):
         cd = _cache_dtype(cache_dtype)
         self.cache_dtype = cd
         self.quantized = _is_quantized_dtype(cache_dtype)
-        # +1: physical block 0 is the trash block, never allocated
-        shape = (n_layers, n_blocks + 1, n_heads, block_size, head_dim)
-        if self.quantized:
+        # +1: physical block 0 is the trash block, never allocated. One
+        # buffer a layer, a position's heads in one row: a layer's write
+        # touches no other layer, and no program re-lays a buffer out
+        shape = (n_blocks + 1, block_size, n_heads * head_dim)
+
+        def layer():
+            if not self.quantized:
+                return jnp.zeros(shape, cd)
             # narrow block data + per-(position, head) f32 scale planes as
-            # ONE pytree buffer per cache (models/gpt.py::QuantKV): every
+            # ONE pytree buffer per layer (models/gpt.py::QuantKV): every
             # compiled step, the CoW copy, donation and TP placement
             # thread the pair together
-            self.kc = QuantKV(jnp.zeros(shape, cd),
-                              jnp.zeros(shape[:-1], jnp.float32))
-            self.vc = QuantKV(jnp.zeros(shape, cd),
-                              jnp.zeros(shape[:-1], jnp.float32))
-        else:
-            self.kc = jnp.zeros(shape, cd)
-            self.vc = jnp.zeros(shape, cd)
+            return QuantKV(jnp.zeros(shape, cd),
+                           jnp.zeros((*shape[:2], n_heads), jnp.float32))
+
+        self.kc = tuple(layer() for _ in range(n_layers))
+        self.vc = tuple(layer() for _ in range(n_layers))
         self.state = jax.tree.map(
             lambda sd: jnp.zeros((n_slots, *sd.shape), sd.dtype),
             state_shapes)
@@ -504,6 +511,11 @@ class PagedKVPool(_SlotPoolBase):
         self.host_prefetch_hits_total = 0
         self.host_prefetch_misses_total = 0
         self.host_transfer_bytes_total = 0
+        if host_cache_blocks:
+            from simple_distributed_machine_learning_tpu.models.gpt import (
+                make_paged_block_write,
+            )
+            self._write_block = make_paged_block_write()
 
     # -- capacity ----------------------------------------------------------
 
@@ -863,7 +875,7 @@ class PagedKVPool(_SlotPoolBase):
         """One physical block's rows as a host (numpy) pytree — a QuantKV
         cache's narrow data and f32 scale planes travel together."""
         import jax
-        return jax.tree.map(lambda a: np.asarray(a[:, block]), cache)
+        return jax.tree.map(lambda a: np.asarray(a[block]), cache)
 
     def _demote(self, block: int) -> None:
         """Copy an evicted cached block's rows (and its registered prefix
@@ -1029,7 +1041,6 @@ class PagedKVPool(_SlotPoolBase):
         block goes straight back to the free list."""
         if not self._inflight:
             return
-        import jax
         done = [t for t in self._inflight if t["ticks_left"] <= 1]
         for t in self._inflight:
             t["ticks_left"] -= 1
@@ -1041,10 +1052,8 @@ class PagedKVPool(_SlotPoolBase):
                 if key in self._prefix:
                     self._free_blocks.append(block)
                     continue
-                self.kc = jax.tree.map(
-                    lambda d, h: d.at[:, block].set(h), self.kc, hk)
-                self.vc = jax.tree.map(
-                    lambda d, h: d.at[:, block].set(h), self.vc, hv)
+                self.kc, self.vc = self._write_block(
+                    self.kc, self.vc, np.int32(block), hk, hv)
                 self._prefix[key] = (block, fill)
                 self._cached.setdefault(block, set()).add(key)
                 self._lru[block] = None        # cached ref-0, reclaimable

@@ -108,7 +108,7 @@ def test_clean_fixtures_pass():
     for name in ("clean_grad_sync", "clean_pipeline_step",
                  "clean_cow_tick", "clean_gather_before_use",
                  "kernel_clean_paged", "kernel_clean_grid",
-                 "kernel_packed_tile", "kernel_f32_accumulator"):
+                 "kernel_rows_in_lanes_tile", "kernel_f32_accumulator"):
         report = FIXTURES[name].build()
         assert report.ok(fail_on="warning"), report.format()
 

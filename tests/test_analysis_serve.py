@@ -149,9 +149,8 @@ def _paged_decode_args(stages, tables_hi, pos_hi, S=2, ml=16, bs=4):
     params = [jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), s.params)
         for s in stages]
-    kc = jax.ShapeDtypeStruct(
-        (CFG.n_layers, nb + 1, CFG.n_heads, bs,
-         CFG.d_model // CFG.n_heads), np.float32)
+    kc = (jax.ShapeDtypeStruct((nb + 1, bs, CFG.d_model),
+                               np.float32),) * CFG.n_layers
     return (params, kc, kc,
             spec((S,), np.int32, 0, CFG.vocab - 1),
             spec((S,), np.int32, 0, pos_hi),
@@ -271,7 +270,8 @@ def test_real_builders_are_memoized(stages):
         elif name == "make_cached_decoder":
             def build():
                 return make(stages, CFG, 4, 4)
-        elif name in ("make_paged_block_copy", "make_adapter_bank_update"):
+        elif name in ("make_paged_block_copy", "make_paged_block_write",
+                      "make_adapter_bank_update"):
             build = make
         elif "paged" in name:
             def build():

@@ -444,7 +444,9 @@ def test_tick_and_admit_spans_carry_the_new_counts(stages):
     assert ticks[-1].attrs["state_slots"] == 0      # retired
     assert sum(a.attrs["prefix_declined"] for a in admits) == 1
     assert eng.pool.shared_prefix_len(same) == 0
-    assert eng.pool.kc.shape[:3] == (2, eng.pool.n_blocks + 1, 1)
+    # one buffer per attention layer, a position's ONE K/V head its row
+    assert [b.shape for b in eng.pool.kc] == [
+        (eng.pool.n_blocks + 1, BS, CFG.head_dim)] * 2
 
 
 # -- what is refused ----------------------------------------------------------

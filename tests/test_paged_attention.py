@@ -32,11 +32,13 @@ from tolerances import attn_tol
 from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
     QuantKV,
+    _paged_scatter,
     _quantize_rows,
     make_gpt_stages,
     make_paged_block_copy,
 )
 from simple_distributed_machine_learning_tpu.ops.paged_attention import (
+    _attend_blocks,
     paged_attention,
     paged_flash_decode,
 )
@@ -48,11 +50,27 @@ from simple_distributed_machine_learning_tpu.serve.slots import (
 )
 
 CFG = GPTConfig(vocab=64, seq_len=32, d_model=32, n_heads=2, n_layers=2)
+# several heads of the deployed head dim in one pool row (4 x 64 lanes)
+CFG_DH64 = GPTConfig(vocab=64, seq_len=32, d_model=256, n_heads=4,
+                     n_layers=2)
 
 
 @pytest.fixture(scope="module")
 def stages():
     return make_gpt_stages(jax.random.key(0), CFG, 1)[0]
+
+
+@pytest.fixture(scope="module")
+def stages_dh64():
+    return make_gpt_stages(jax.random.key(0), CFG_DH64, 1)[0]
+
+
+def _rows(kc):
+    """A head-major layer ``[n, H, bs, dh]`` (what the dense reference
+    here reads) as the pool holds it: ``[n, bs, H*dh]``, a position's
+    heads side by side. Scale planes ``[n, H, bs]`` -> ``[n, bs, H]``."""
+    kc = jnp.moveaxis(kc, 1, 2)
+    return kc.reshape(*kc.shape[:2], -1) if kc.ndim == 4 else kc
 
 
 def _dense_paged_reference(q, kc, vc, tables, qpos):
@@ -85,7 +103,7 @@ def _toy_pool(key, S=3, H=2, dh=16, bs=4, NB=6, NBtot=12):
     tables[0, :4] = [2, 5, 7, 8]
     tables[1, :2] = [1, 3]
     tables[2, :1] = [9]
-    pos = np.array([10, 4, 0], np.int32)
+    pos = np.array([10, 4, 0], np.int32) * (bs // 4)
     return kq, kc, vc, tables, pos
 
 
@@ -93,43 +111,51 @@ def test_paged_flash_decode_matches_dense_gather():
     kq, kc, vc, tables, pos = _toy_pool(jax.random.key(0))
     q = jax.random.normal(kq, (3, 2, 1, 16))
     out = jax.jit(lambda *a: paged_flash_decode(*a, block_size=4))(
-        q, kc, vc, jnp.asarray(tables), jnp.asarray(pos))
+        q, _rows(kc), _rows(vc), jnp.asarray(tables), jnp.asarray(pos))
     ref = _dense_paged_reference(q, kc, vc, tables, pos[:, None])
     rtol, atol = attn_tol(jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=rtol, atol=atol)
 
 
-def test_paged_attention_verify_variant_matches_dense_gather():
-    """The K-token variant: per-query masks at qpos = pos + j."""
-    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(1))
+@pytest.mark.parametrize("bs,NB", [(4, 6), (8, 4), (8, 6)])
+def test_paged_attention_verify_variant_matches_dense_gather(bs, NB):
+    """The K-token variant: per-query masks at qpos = pos + j. Blocks of 8
+    are whole sublane tiles, so a grid cell attends a span of 4 (``NB`` 4)
+    or 2 (``NB`` 6) of them at once; a span's blocks past a slot's newest
+    position are fetched as its last live block and masked."""
+    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(1), bs=bs, NB=NB)
     K = 4
     q = jax.random.normal(kq, (3, 2, K, 16))
     qpos = np.minimum(pos[:, None] + np.arange(K)[None, :],
-                      6 * 4 - 1).astype(np.int32)
-    out = jax.jit(lambda *a: paged_attention(*a, block_size=4))(
-        q, kc, vc, jnp.asarray(tables), jnp.asarray(qpos))
+                      NB * bs - 1).astype(np.int32)
+    out = jax.jit(lambda *a: paged_attention(*a, block_size=bs))(
+        q, _rows(kc), _rows(vc), jnp.asarray(tables), jnp.asarray(qpos))
     ref = _dense_paged_reference(q, kc, vc, tables, qpos)
     rtol, atol = attn_tol(jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=rtol, atol=atol)
 
 
-def test_paged_attention_fused_dequant_matches_dequantized_rows():
+@pytest.mark.parametrize("H", [2, 1])
+def test_paged_attention_fused_dequant_matches_dequantized_rows(H):
     """int8 blocks + per-row scales through the kernel == dense attention
     over the EXPLICITLY dequantized rows — same effective K/V, so the
     comparison is tight (accumulation order only), proving dequantize is
-    fused faithfully rather than approximated."""
-    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(2))
+    fused faithfully rather than approximated. Two heads in a row take the
+    head-major call (a row's heads carry different scales), one head the
+    pool as it lies."""
+    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(2), H=H)
     K = 2
-    q = jax.random.normal(kq, (3, 2, K, 16))
+    q = jax.random.normal(kq, (3, H, K, 16))
     qpos = np.minimum(pos[:, None] + np.arange(K)[None, :],
                       23).astype(np.int32)
     kd, ks = _quantize_rows(kc, jnp.int8)
     vd, vs = _quantize_rows(vc, jnp.int8)
     out = jax.jit(lambda *a: paged_attention(
         *a[:5], block_size=4, kscale=a[5], vscale=a[6]))(
-        q, kd, vd, jnp.asarray(tables), jnp.asarray(qpos), ks, vs)
+        q, _rows(kd), _rows(vd), jnp.asarray(tables), jnp.asarray(qpos),
+        _rows(ks), _rows(vs))
     deq_k = kd.astype(jnp.float32) * ks[..., None]
     deq_v = vd.astype(jnp.float32) * vs[..., None]
     ref = _dense_paged_reference(q, deq_k, deq_v, tables, qpos)
@@ -172,8 +198,13 @@ def test_kv_block_bytes_accounts_scale_planes():
     # the pool's bytes_per_block uses the same formula (scales included)
     pool = PagedKVPool(L, 2, H, 16, dh, cache_dtype="int8", block_size=bs)
     assert pool.bytes_per_block == i8
-    assert isinstance(pool.kc, QuantKV)
-    assert pool.kc.nbytes == (pool.kc.data.nbytes + pool.kc.scale.nbytes)
+    # one QuantKV a layer: data rows [n_blocks+1, bs, H*dh], a scale per
+    # (position, head); what the pool allocates is what a block is billed
+    assert len(pool.kc) == L and isinstance(pool.kc[0], QuantKV)
+    assert pool.kc[0].data.shape == (pool.n_blocks + 1, bs, H * dh)
+    assert pool.kc[0].scale.shape == (pool.n_blocks + 1, bs, H)
+    assert (sum(c.nbytes for c in pool.kc + pool.vc)
+            == (pool.n_blocks + 1) * i8)
     # fixed-byte sizing: the int8 budget funds strictly more blocks
     budget = 10 * bf16
     assert (n_blocks_for_bytes(budget, L, H, bs, dh, "int8")
@@ -200,8 +231,9 @@ def test_engine_knob_validation(stages):
                         cache_dtype="int8")
 
 
-def _drain_tokens(stages, cfg, prompts, max_new=8, **kw):
-    engine = InferenceEngine(stages, cfg, n_slots=3, block_size=4, **kw)
+def _drain_tokens(stages, cfg, prompts, max_new=8, block_size=4, **kw):
+    engine = InferenceEngine(stages, cfg, n_slots=3, block_size=block_size,
+                             **kw)
     handles = [engine.submit(p, max_new_tokens=max_new, seed=100 + i)
                for i, p in enumerate(prompts)]
     engine.drain()
@@ -214,26 +246,36 @@ def _prompts(n=4, seed=0):
             for t in (5, 9, 13, 7)[:n]]
 
 
-@pytest.mark.parametrize("cache_dtype", [None, "bfloat16", "int8"])
-def test_engine_greedy_fused_bit_exact_vs_dense_path(stages, cache_dtype):
+@pytest.mark.parametrize("model,cache_dtype", [
+    ("dh16", None), ("dh16", "bfloat16"), ("dh16", "int8"),
+    ("dh64", None), ("dh64", "bfloat16"), ("dh64", "int8")])
+def test_engine_greedy_fused_bit_exact_vs_dense_path(stages, stages_dh64,
+                                                     model, cache_dtype):
     """THE acceptance anchor: greedy decode through attn_kernel='fused'
     emits the exact token stream of the gather-then-dense path — per
     storage dtype (f32/bf16 bit-exact vs their own dense path; the int8
-    pool vs ITS dense path, quantization identical on both sides)."""
+    pool vs ITS dense path, quantization identical on both sides), and
+    with four heads of 64 in one pool row (and blocks of 8, which the
+    kernel attends four to a grid cell) as with two of 16 (blocks of 4,
+    one to a cell)."""
+    st, cfg, bs = ((stages, CFG, 4) if model == "dh16"
+                   else (stages_dh64, CFG_DH64, 8))
     prompts = _prompts()
-    _, dense = _drain_tokens(stages, CFG, prompts, cache_dtype=cache_dtype)
-    _, fused = _drain_tokens(stages, CFG, prompts, cache_dtype=cache_dtype,
-                             attn_kernel="fused")
+    _, dense = _drain_tokens(st, cfg, prompts, block_size=bs,
+                             cache_dtype=cache_dtype)
+    _, fused = _drain_tokens(st, cfg, prompts, block_size=bs,
+                             cache_dtype=cache_dtype, attn_kernel="fused")
     assert dense == fused
 
 
-def test_engine_speculative_fused_bit_exact(stages):
+@pytest.mark.parametrize("spec_k", [3, 4])
+def test_engine_speculative_fused_bit_exact(stages, spec_k):
     """The K-token verify variant through the engine: fused speculative
     greedy streams equal the dense-path speculative ones AND the plain
     decode's (the existing spec-decode bit-exactness contract composes
     with the kernel)."""
     prompts = _prompts()
-    kw = dict(draft_stages=stages, draft_cfg=CFG, spec_k=3)
+    kw = dict(draft_stages=stages, draft_cfg=CFG, spec_k=spec_k)
     _, plain = _drain_tokens(stages, CFG, prompts)
     _, sp_dense = _drain_tokens(stages, CFG, prompts, **kw)
     _, sp_fused = _drain_tokens(stages, CFG, prompts,
@@ -293,22 +335,48 @@ def test_quantized_pool_prefix_sharing_cow_refcounts(stages):
         cache_dtype="int8")
 
 
-def test_quantized_block_copy_moves_scale_planes():
-    """The CoW device op must copy a QuantKV block's data AND its scale
-    plane — rows without their scales decode to a different value."""
+@pytest.mark.parametrize("quant", [True, False])
+def test_block_copy_then_divergent_write_on_the_per_layer_pool(quant):
+    """The CoW device op on the pool's per-layer buffers: the copy moves a
+    block's rows in every layer (a QuantKV block's data AND its scale
+    plane — rows without their scales decode to a different value), and
+    the sharer's divergent write then lands in the copy alone."""
     L, H, bs, dh, NB = 2, 2, 4, 8, 3
-    data = jnp.arange(L * (NB + 1) * H * bs * dh,
-                      dtype=jnp.float32).reshape(L, NB + 1, H, bs, dh)
-    qd, sc = _quantize_rows(data, jnp.int8)
+    data = jnp.arange(L * (NB + 1) * bs * H * dh,
+                      dtype=jnp.float32).reshape(L, NB + 1, bs, H, dh)
+
+    def pool(offset):
+        rows = data + offset
+        if not quant:
+            return tuple(r.reshape(NB + 1, bs, H * dh) for r in rows)
+        qd, sc = _quantize_rows(rows, jnp.int8)
+        return tuple(QuantKV(d.reshape(NB + 1, bs, H * dh), s_)
+                     for d, s_ in zip(qd, sc))
+
+    def host(cache):
+        return jax.tree.map(np.asarray, cache)
+
     # the copy op DONATES its buffers: snapshot host copies first
-    qd_np, sc_np = np.asarray(qd), np.asarray(sc)
-    kc = QuantKV(qd, sc)
-    vc = QuantKV(qd + 0, sc + 0.0)
-    copy = make_paged_block_copy()
-    kc2, vc2 = copy(kc, vc, jnp.int32(1), jnp.int32(3))
-    np.testing.assert_array_equal(np.asarray(kc2.data[:, 1]), qd_np[:, 3])
-    np.testing.assert_array_equal(np.asarray(kc2.scale[:, 1]), sc_np[:, 3])
-    np.testing.assert_array_equal(np.asarray(vc2.scale[:, 2]), sc_np[:, 2])
+    kc, vc = pool(0.0), pool(1.0)
+    k0, v0 = host(kc), host(vc)
+    kc, vc = make_paged_block_copy()(kc, vc, jnp.int32(1), jnp.int32(3))
+    for got, was in ((host(kc), k0), (host(vc), v0)):
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(was)):
+            np.testing.assert_array_equal(g[1], w[3])      # dst = src
+            np.testing.assert_array_equal(g[[0, 2, 3]], w[[0, 2, 3]])
+    # the divergent write: layer 0, block 1, offset 2
+    new = jnp.full((1, H, dh), -7.0)
+    k1 = host(kc)
+    kc = jax.jit(lambda c: _paged_scatter(
+        c, 0, jnp.array([1]), jnp.array([2]), new))(kc)
+    k2 = host(kc)
+    for layer, (g, w) in enumerate(zip(k2, k1)):
+        for gl, wl in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            changed = np.argwhere((gl != wl).reshape(NB + 1, bs, -1).any(-1))
+            assert changed.tolist() == ([[1, 2]] if layer == 0 else [])
+    row = k2[0].data[1, 2] * np.repeat(k2[0].scale[1, 2], dh) \
+        if quant else k2[0][1, 2]
+    np.testing.assert_allclose(row, -7.0, rtol=1e-6)
 
 
 def test_tp2_quantized_pool_token_parity(stages):
@@ -430,46 +498,50 @@ def test_fp8_cache_roundtrip_and_engine(stages):
     assert dense == fused
 
 
-# ---- ISSUE 16: the packed small-head-dim layout + kernel-derived HBM ----
+# ---- the pool as it lies against the head-major call; kernel-derived HBM ----
+
+def _head_major_call(q, kc, vc, tables, qpos, ks=None, vs=None):
+    """The kernel called with every head a stream of its own over a
+    head-major pool ``[n, H, bs, dh]``: what the wrapper did before the
+    pool held a position's heads in one row."""
+    return _attend_blocks(q, kc, vc, jnp.asarray(tables), jnp.asarray(qpos),
+                          4, 1.0 / math.sqrt(q.shape[-1]), ks, vs)
+
 
 @pytest.mark.parametrize("dh", [4, 8, 16])
-def test_packed_layout_matches_natural(dh):
-    """The 'packed' layout (K/V transposed so block positions take the
-    lane slot — the ROADMAP #2 small-head-dim fix) is numerically
-    identical to the natural layout: the zero-padded head rows contribute
-    nothing to either dot."""
+def test_rows_in_lanes_call_matches_head_major_call(dh):
+    """One stream whose row is all the heads, each head's query in its own
+    lanes of a zeroed row and its output that lane block of its row, is
+    the head-major call: the zeros add exactly."""
     key, kc, vc, tables, pos = _toy_pool(jax.random.key(3), dh=dh)
     S, H = tables.shape[0], kc.shape[1]
     q = jax.random.normal(key, (S, H, 2, dh))
     qpos = np.stack([np.maximum(pos - 1, 0), pos], axis=1).astype(np.int32)
-    nat = paged_attention(q, kc, vc, tables, qpos, block_size=4,
-                          _layout="natural")
-    pak = paged_attention(q, kc, vc, tables, qpos, block_size=4,
-                          _layout="packed")
-    np.testing.assert_allclose(np.asarray(pak), np.asarray(nat),
+    nat = _head_major_call(q, kc, vc, tables, qpos)
+    row = paged_attention(q, _rows(kc), _rows(vc), tables, qpos,
+                          block_size=4)
+    np.testing.assert_allclose(np.asarray(row), np.asarray(nat),
                                rtol=1e-6, atol=1e-6)
 
 
-def test_packed_layout_matches_natural_quantized():
+def test_quantized_rows_pool_matches_head_major_call():
     key, kc, vc, tables, pos = _toy_pool(jax.random.key(4), dh=4)
     kq, ks = _quantize_rows(kc, jnp.int8)
     vq, vs = _quantize_rows(vc, jnp.int8)
     S, H = tables.shape[0], kc.shape[1]
     q = jax.random.normal(key, (S, H, 1, 4))
-    nat = paged_attention(q, kq, vq, tables, pos[:, None], block_size=4,
-                          kscale=ks, vscale=vs, _layout="natural")
-    pak = paged_attention(q, kq, vq, tables, pos[:, None], block_size=4,
-                          kscale=ks, vscale=vs, _layout="packed")
-    np.testing.assert_allclose(np.asarray(pak), np.asarray(nat),
+    nat = _head_major_call(q, kq, vq, tables, pos[:, None], ks, vs)
+    row = paged_attention(q, _rows(kq), _rows(vq), tables, pos[:, None],
+                          block_size=4, kscale=_rows(ks), vscale=_rows(vs))
+    np.testing.assert_allclose(np.asarray(row), np.asarray(nat),
                                rtol=1e-6, atol=1e-6)
 
 
-def test_paged_attention_rejects_unknown_layout():
+def test_paged_attention_rejects_a_head_major_pool():
     key, kc, vc, tables, pos = _toy_pool(jax.random.key(5))
     q = jax.random.normal(key, (3, 2, 1, 16))
-    with pytest.raises(ValueError, match="_layout"):
-        paged_attention(q, kc, vc, tables, pos[:, None], block_size=4,
-                        _layout="sideways")
+    with pytest.raises(ValueError, match="KVH\\*dh"):
+        paged_attention(q, kc, vc, tables, pos[:, None], block_size=4)
 
 
 @pytest.mark.parametrize("cache_dtype", [None, "int8"])
@@ -536,21 +608,24 @@ def test_kernel_hbm_mismatch_is_flagged():
 # -- grouped-query attention: fewer K/V heads in the pool than query heads ----
 
 
-@pytest.mark.parametrize("n_q_heads,n_kv_heads,K", [(4, 1, 1), (6, 2, 1),
-                                                    (4, 1, 3)])
+@pytest.mark.parametrize("n_q_heads,n_kv_heads,K,dh", [
+    (4, 1, 1, 16), (6, 2, 1, 16), (4, 1, 3, 16),
+    # the verify width over several heads of 64 in a row, grouped and not
+    (8, 2, 4, 64), (4, 4, 4, 64)])
 def test_grouped_query_pool_matches_dense_over_a_repeated_head(
-        n_q_heads, n_kv_heads, K):
+        n_q_heads, n_kv_heads, K, dh):
     """The pool holds ``n_kv_heads``; the kernel must give what dense
     attention gives over a pool in which every K/V head is repeated for
     the query heads of its group (head ``h`` reads K/V head ``h //
     group``). Same tolerance as the multi-head test: identical K/V, only
     the accumulation order differs."""
-    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(5), H=n_kv_heads)
-    q = jax.random.normal(kq, (3, n_q_heads, K, 16))
+    kq, kc, vc, tables, pos = _toy_pool(jax.random.key(5), H=n_kv_heads,
+                                        dh=dh)
+    q = jax.random.normal(kq, (3, n_q_heads, K, dh))
     qpos = np.minimum(pos[:, None] + np.arange(K)[None, :],
                       6 * 4 - 1).astype(np.int32)
     out = jax.jit(lambda *a: paged_attention(*a, block_size=4))(
-        q, kc, vc, jnp.asarray(tables), jnp.asarray(qpos))
+        q, _rows(kc), _rows(vc), jnp.asarray(tables), jnp.asarray(qpos))
     group = n_q_heads // n_kv_heads
     ref = _dense_paged_reference(q, jnp.repeat(kc, group, axis=1),
                                  jnp.repeat(vc, group, axis=1), tables, qpos)
@@ -564,5 +639,5 @@ def test_query_heads_must_be_a_multiple_of_the_pools_heads():
     kq, kc, vc, tables, pos = _toy_pool(jax.random.key(6), H=2)
     q = jax.random.normal(kq, (3, 3, 1, 16))
     with pytest.raises(ValueError, match="do not divide"):
-        paged_attention(q, kc, vc, jnp.asarray(tables),
+        paged_attention(q, _rows(kc), _rows(vc), jnp.asarray(tables),
                         jnp.asarray(pos[:, None]), block_size=4)
