@@ -643,11 +643,9 @@ def measure_serving(rates: tuple = (2.0, 8.0, 32.0), n_requests: int = 24,
     throughput, TTFT/TPOT p50/p95 and mean slot occupancy — TTFT includes
     genuine queue wait once the offered load exceeds slot capacity.
 
-    With ``compare=True`` two paged-vs-dense comparisons ride along
-    (:func:`_measure_paged_vs_dense`): max sustainable concurrency at
-    fixed KV-cache bytes, and p95 decode-tick latency under a long-prompt
-    arrival (chunked vs monolithic prefill) — the two wins the paged pool
-    exists for.
+    With ``compare=True`` the comparison rows ride along (speculative vs
+    plain, fused vs gather kernel, quantized pool, availability, fleet,
+    disaggregation, host offload, adapters, observability overhead).
 
     Engines are warmed (every prefill bucket + the decode tick compiled)
     before the trace runs, so latency columns measure serving, not XLA
@@ -682,11 +680,11 @@ def measure_serving(rates: tuple = (2.0, 8.0, 32.0), n_requests: int = 24,
     stages, _, _ = make_gpt_stages(jax.random.key(0), cfg, n_stages=1)
     if lint:
         # --serve --lint: preflight the EXACT serving programs this sweep
-        # is about to time — the paged sweep engines (including the 1-slot
-        # sequential baseline) AND, with compare=True, the paged-vs-dense
-        # comparison engines, whose n_slots/n_blocks/prefill_chunk are
-        # traced shapes and contract bounds, i.e. DIFFERENT compiled
-        # programs — abort before any compile/timing work on ERROR findings
+        # is about to time — the sweep engines (including the 1-slot
+        # sequential baseline) AND, with compare=True, the comparison
+        # engines, whose n_slots/prefill_chunk are traced shapes and
+        # contract bounds, i.e. DIFFERENT compiled programs — abort before
+        # any compile/timing work on ERROR findings
         from simple_distributed_machine_learning_tpu.analysis.programs import (
             ServeSpec,
             lint_serve,
@@ -694,41 +692,32 @@ def measure_serving(rates: tuple = (2.0, 8.0, 32.0), n_requests: int = 24,
         sspecs = [
             # the sweep rows and the 1-slot sequential baseline (n_slots is
             # a traced shape: different compiled programs)
-            ServeSpec(cfg, n_slots=slots, kv_layout="paged",
+            ServeSpec(cfg, n_slots=slots,
                       block_size=block_size, prompt_lens=prompt_lens,
                       attn_kernel=attn_kernel),
-            ServeSpec(cfg, n_slots=1, kv_layout="paged",
+            ServeSpec(cfg, n_slots=1,
                       block_size=block_size, prompt_lens=prompt_lens,
                       attn_kernel=attn_kernel),
             # the kernel-comparison engines (both attention paths) and the
             # int8 pool the quantized fixed-mem rows build — each a
             # distinct compiled program family
-            ServeSpec(cfg, n_slots=slots, kv_layout="paged",
+            ServeSpec(cfg, n_slots=slots,
                       block_size=block_size, prompt_lens=prompt_lens,
                       attn_kernel="fused"),
-            ServeSpec(cfg, n_slots=slots, kv_layout="paged",
+            ServeSpec(cfg, n_slots=slots,
                       block_size=block_size, prompt_lens=prompt_lens,
                       cache_dtype="int8"),
             # the speculative comparison engines (draft == target): the
             # propose scan, the batched verify and the fused tick are
             # DIFFERENT compiled programs from the plain sweep's
-            ServeSpec(cfg, n_slots=min(slots, 4), kv_layout="paged",
+            ServeSpec(cfg, n_slots=min(slots, 4),
                       block_size=block_size, prompt_lens=prompt_lens,
                       spec_k=SPEC_BENCH_K, draft_cfg=cfg)]
         if compare:
-            geo = _compare_geometries(cfg, slots=slots, max_new=max_new,
-                                      prompt_lens=prompt_lens,
-                                      block_size=block_size)
-            for _label, kw in geo["fixed_mem"]:
-                sspecs.append(ServeSpec(cfg, prompt_lens=prompt_lens, **kw))
-            lp_lens = (min(prompt_lens), geo["long_len"])
-            for _label, kw in geo["longprompt"]:
-                sspecs.append(ServeSpec(cfg, prompt_lens=lp_lens, **kw))
             # the availability row's supervised engine (chunked prefill =
             # block_size bounds its recovery-retrace shapes) — a distinct
             # compiled geometry, so it preflights too
             sspecs.append(ServeSpec(cfg, n_slots=min(slots, 4),
-                                    kv_layout="paged",
                                     block_size=block_size,
                                     prefill_chunk=block_size,
                                     prompt_lens=prompt_lens))
@@ -744,8 +733,7 @@ def measure_serving(rates: tuple = (2.0, 8.0, 32.0), n_requests: int = 24,
                 raise SystemExit("bench --serve: serve-program preflight "
                                  "found ERROR findings")
         print(f"bench --serve: lint preflight clean "
-              f"({len(seen)} deployments"
-              + (", paged + dense" if compare else ", paged") + ")")
+              f"({len(seen)} deployments)")
 
     def run(rate, n_slots, label):
         engine = InferenceEngine(stages, cfg, n_slots=n_slots,
@@ -778,11 +766,6 @@ def measure_serving(rates: tuple = (2.0, 8.0, 32.0), n_requests: int = 24,
     rows = [run(max(rates), 1, "gpt_serve_sequential")]
     rows += [run(r, slots, "gpt_serve") for r in rates]
     if compare:
-        rows += _measure_paged_vs_dense(stages, cfg, slots=slots,
-                                        n_requests=n_requests,
-                                        max_new=max_new,
-                                        prompt_lens=prompt_lens,
-                                        block_size=block_size)
         rows += _measure_spec_vs_plain(stages, cfg, slots=min(slots, 4),
                                        n_requests=n_requests,
                                        max_new=max_new,
@@ -847,39 +830,6 @@ def measure_serving(rates: tuple = (2.0, 8.0, 32.0), n_requests: int = 24,
     return rows
 
 
-def _compare_geometries(cfg, slots: int, max_new: int, prompt_lens: tuple,
-                        block_size: int) -> dict:
-    """Engine-constructor kwargs for the paged-vs-dense comparison rows.
-
-    Shared by ``--serve --lint`` (which must preflight the exact programs
-    the comparison compiles — these geometries differ from the sweep
-    engines in n_slots/n_blocks/prefill_chunk, all traced shapes) and
-    :func:`_measure_paged_vs_dense` (which builds engines from them)."""
-    mem_slots = max(2, slots // 4)          # the dense pool being matched
-    bps = -(-cfg.seq_len // block_size)     # blocks per max_len sequence
-    n_blocks = mem_slots * bps              # same bytes as the dense rows
-    rows_per_req = max(prompt_lens) + max_new - 1
-    blocks_per_req = -(-rows_per_req // block_size)
-    paged_slots = min(32, max(mem_slots + 1, n_blocks // blocks_per_req))
-    n_short = max(2, slots // 2)
-    return {
-        "fixed_mem": (
-            ("gpt_serve_dense_fixed_mem",
-             dict(n_slots=mem_slots, kv_layout="dense")),
-            ("gpt_serve_paged_fixed_mem",
-             dict(n_slots=paged_slots, kv_layout="paged",
-                  block_size=block_size, n_blocks=n_blocks))),
-        "longprompt": (
-            ("gpt_serve_dense_longprompt",
-             dict(n_slots=n_short + 1, kv_layout="dense")),
-            ("gpt_serve_paged_chunked_longprompt",
-             dict(n_slots=n_short + 1, kv_layout="paged",
-                  block_size=block_size, prefill_chunk=block_size))),
-        "long_len": cfg.seq_len - max_new,
-        "n_short": n_short,
-    }
-
-
 def _drain_burst(engine, specs):
     """Submit everything at t=0 and drive to empty — the one burst-drain
     helper every comparison row family measures with. Returns
@@ -897,111 +847,6 @@ def _drain_burst(engine, specs):
     wall = _time.perf_counter() - t0
     done = sum(1 for h in handles if h.state == "done")
     return handles, ticks, toks, peak, done, wall
-
-
-def _measure_paged_vs_dense(stages, cfg, slots: int, n_requests: int,
-                            max_new: int, prompt_lens: tuple,
-                            block_size: int,
-                            parts: tuple = ("fixed_mem", "longprompt"),
-                            ) -> list[dict]:
-    """The two paged-pool claims, measured head to head (ROADMAP item #1):
-
-    1. *Fixed KV memory, max sustainable concurrency* — a dense pool of
-       ``mem_slots`` rows vs a paged pool of the SAME bytes
-       (``mem_slots * blocks_per_seq`` blocks) given slots to spare. A
-       burst workload arrives all at once; the peak number of
-       simultaneously active requests is recorded. Dense caps at
-       ``mem_slots`` (a row is reserved at ``max_len`` whether used or
-       not); paged admits until actual blocks run out, so with requests
-       shorter than ``max_len`` it sustains strictly more.
-
-    2. *Prefill stall, p95 tick latency* — short requests decode steadily
-       while one LONG prompt arrives mid-flight. Dense/monolithic runs the
-       whole prompt inside one tick (every co-resident stalls for it);
-       paged/chunked spreads it over ``block_size``-token chunks, so the
-       worst decode tick shrinks. Per-tick wall latency is measured around
-       ``engine.step()`` after the long submit.
-    """
-    import time as _time
-
-    import jax
-    import numpy as np
-
-    from simple_distributed_machine_learning_tpu.serve import (
-        InferenceEngine,
-    )
-
-    rng = np.random.default_rng(7)
-    dev = {"device_kind": jax.devices()[0].device_kind,
-           "backend": jax.default_backend()}
-
-    def _burst(engine, specs):
-        """(peak concurrent active, completed, tokens/sec) of a burst."""
-        _h, _ticks, toks, peak, done, wall = _drain_burst(engine, specs)
-        return peak, done, round(toks / wall, 1)
-
-    def _spec(t0, i):
-        return dict(prompt=rng.integers(0, cfg.vocab, t0).astype(np.int32),
-                    max_new_tokens=max_new, seed=1000 + i)
-
-    # -- 1. fixed-memory concurrency --------------------------------------
-    out = []
-    geo = _compare_geometries(cfg, slots=slots, max_new=max_new,
-                              prompt_lens=prompt_lens, block_size=block_size)
-    paged_slots = geo["fixed_mem"][1][1]["n_slots"]
-    burst = [_spec(prompt_lens[i % len(prompt_lens)], i)
-             for i in range(max(n_requests, 2 * paged_slots))]
-    for label, kw in geo["fixed_mem"]:
-        if "fixed_mem" not in parts:
-            break
-        engine = InferenceEngine(stages, cfg, **kw)
-        warm = [_spec(t0, 500) for t0 in prompt_lens]
-        for sp in warm:
-            engine.submit(**{**sp, "max_new_tokens": 2})
-        engine.drain()
-        peak, done, tps = _burst(engine, burst)
-        out.append({
-            "config": label, "n_slots": kw["n_slots"],
-            "kv_bytes": sum(int(a.nbytes) for a in jax.tree.leaves(
-                (engine.pool.kc, engine.pool.vc))),
-            "n_requests": len(burst), "completed": done,
-            "max_concurrent": peak, "tokens_per_sec": tps, **dev,
-        })
-
-    # -- 2. long-prompt prefill stall -------------------------------------
-    # the stress case: a prompt near the sequence budget, so the monolithic
-    # prefill tick dwarfs a decode tick
-    long_len = geo["long_len"]
-    n_short = geo["n_short"]
-    for label, kw in geo["longprompt"]:
-        if "longprompt" not in parts:
-            break
-        engine = InferenceEngine(stages, cfg, **kw)
-        # warm the exact compiled shapes: short prefill, long prefill
-        # (its chunk lengths), the decode tick
-        engine.submit(**{**_spec(min(prompt_lens), 600),
-                         "max_new_tokens": 2})
-        engine.submit(**{**_spec(long_len, 601), "max_new_tokens": 2})
-        engine.drain()
-        for i in range(n_short):
-            engine.submit(**_spec(min(prompt_lens), 700 + i))
-        for _ in range(3):                    # steady decode underway
-            engine.step()
-        engine.submit(**{**_spec(long_len, 800), "max_new_tokens": max_new})
-        tick_ms = []
-        while engine.busy:
-            t0 = _time.perf_counter()
-            engine.step()
-            tick_ms.append((_time.perf_counter() - t0) * 1e3)
-        out.append({
-            "config": label, "n_slots": kw["n_slots"],
-            "long_prompt_len": long_len, "n_short": n_short,
-            "tick_ms_p50": round(float(np.percentile(tick_ms, 50)), 3),
-            "tick_ms_p95": round(float(np.percentile(tick_ms, 95)), 3),
-            "tick_ms_max": round(max(tick_ms), 3),
-            "n_ticks": len(tick_ms), **dev,
-        })
-    return out
 
 
 def _measure_kernel_and_quant(stages, cfg, slots: int, n_requests: int,
@@ -1189,7 +1034,7 @@ def _measure_spec_vs_plain(stages, cfg, slots: int, n_requests: int,
     )
 
     def run(spec: bool) -> dict:
-        kw = dict(kv_layout="paged", block_size=block_size)
+        kw = dict(block_size=block_size)
         if spec:
             kw.update(draft_stages=stages, draft_cfg=cfg, spec_k=spec_k)
         engine = InferenceEngine(stages, cfg, n_slots=slots, **kw)
@@ -1275,7 +1120,7 @@ def _measure_availability(stages, cfg, slots: int, n_requests: int,
         sup = ServeSupervisor(
             # chunked prefill bounds the recovery re-prefill to chunk-sized
             # compiled shapes (the engine.preempt compile-cost note)
-            engine_factory(stages, cfg, n_slots=slots, kv_layout="paged",
+            engine_factory(stages, cfg, n_slots=slots,
                            block_size=block_size, prefill_chunk=block_size,
                            metrics=metrics),
             os.path.join(tmpdir.name, "journal.jsonl"), metrics=metrics,
@@ -1354,7 +1199,7 @@ def _measure_fleet_availability(stages, cfg, n_requests: int, max_new: int,
     tmpdir = tempfile.TemporaryDirectory(prefix="sdml-bench-fleet-")
     try:
         fleet = ServeFleet(
-            engine_factory(stages, cfg, n_slots=slots, kv_layout="paged",
+            engine_factory(stages, cfg, n_slots=slots,
                            block_size=block_size, prefill_chunk=block_size,
                            metrics=metrics),
             tmpdir.name, n_replicas=replicas, metrics=metrics,
@@ -1427,7 +1272,7 @@ def _measure_disaggregation(stages, cfg, n_requests: int, max_new: int,
         try:
             fleet = ServeFleet(
                 engine_factory(stages, cfg, n_slots=slots,
-                               kv_layout="paged", block_size=block_size,
+                               block_size=block_size,
                                prefill_chunk=block_size, metrics=metrics),
                 tmpdir.name, n_replicas=replicas,
                 prefill_replicas=n_prefill, metrics=metrics)
@@ -1503,7 +1348,7 @@ def _measure_host_offload(stages, cfg, n_requests: int,
         try:
             fleet = ServeFleet(
                 engine_factory(stages, cfg, n_slots=slots,
-                               kv_layout="paged", block_size=bs,
+                               block_size=bs,
                                n_blocks=n_blocks, max_len=max_len,
                                prefill_chunk=bs,
                                host_cache_blocks=host_blocks,
@@ -1701,7 +1546,7 @@ def _measure_slo_overhead(stages, cfg, slots: int, n_requests: int,
         tmpdir = tempfile.TemporaryDirectory(prefix="sdml-bench-slo-")
         try:
             sup = ServeSupervisor(
-                engine_factory(stages, cfg, n_slots=slots, kv_layout="paged",
+                engine_factory(stages, cfg, n_slots=slots,
                                block_size=block_size,
                                prefill_chunk=block_size, metrics=metrics),
                 os.path.join(tmpdir.name, "journal.jsonl"),

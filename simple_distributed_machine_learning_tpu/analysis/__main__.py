@@ -35,10 +35,10 @@ def main(argv=None) -> int:
                         "an N-virtual-device mesh")
     p.add_argument("--serve", action="store_true",
                    help="lint the serving-program registry (cached decoder "
-                        "+ slot/paged prefill, decode, CoW copy and the "
-                        "composite tick) over the paged layout at two "
-                        "block/chunk shapes, the dense layout, the "
-                        "speculative pair and the serve supervisor's "
+                        "+ paged prefill chunk, decode, CoW copy and the "
+                        "composite tick) at two block/chunk shapes, under "
+                        "the fused kernel, with adapters, the speculative "
+                        "programs and the serve supervisor's "
                         "degraded-fallback layout")
     p.add_argument("--serve-kernel", action="store_true",
                    help="kernel-only preflight over the same registry "

@@ -66,16 +66,13 @@ from simple_distributed_machine_learning_tpu.analysis.report import (
 DECODE_BUILDER_NAMES = (
     "make_cached_decoder",
     "make_slot_prefill",
-    "make_slot_decode_step",
     "make_paged_prefill_chunk",
     "make_paged_decode_step",
     "make_paged_block_copy",
     "make_paged_block_write",
     "make_adapter_bank_update",
     "make_slot_propose",
-    "make_slot_verify_step",
     "make_paged_verify_step",
-    "make_slot_spec_tick",
     "make_paged_spec_tick",
 )
 

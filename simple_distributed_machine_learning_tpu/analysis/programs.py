@@ -1,14 +1,13 @@
 """The compiled-program registry: one call lints everything a launch runs.
 
 PR 3's preflight covered the train/eval step; since the serving subsystem
-landed, the riskiest compiled code is the DECODE path — five programs
-(``models/gpt.py``: ``make_slot_prefill``/``make_slot_decode_step`` for the
-dense layout, ``make_paged_prefill_chunk``/``make_paged_decode_step``/
-``make_paged_block_copy`` for the paged one, plus ``make_cached_decoder``,
-the solo-parity anchor) whose failure modes are silent: an out-of-range
-block-table index scatters K/V into another request's blocks, a CoW copy
-reads a buffer the prefill already donated, a per-prompt-length retrace
-explodes the trace cache under real traffic. This module enumerates those
+landed, the riskiest compiled code is the DECODE path — the paged pool's
+programs (``models/gpt.py``: ``make_paged_prefill_chunk``/
+``make_paged_decode_step``/``make_paged_block_copy``, plus
+``make_cached_decoder``, the solo-parity anchor) whose failure modes are
+silent: an out-of-range block-table index scatters K/V into another
+request's blocks, a CoW copy reads a buffer the prefill already donated, a
+per-prompt-length retrace explodes the trace cache under real traffic. This module enumerates those
 entry points with ABSTRACT-ARG BUILDERS — each argument carries the value
 contract the host side (``serve/slots.py``) maintains, declared via
 ``analysis.spec`` — so ``lint_serve`` traces and lints the exact programs a
@@ -40,14 +39,14 @@ Since ISSUE 9 the registry also covers sharded + speculative serving: with
 program is rebuilt as its exact ``shard_map`` twin — head-sharded pool,
 packed Megatron weights — and the mesh-axis + scatter-bounds rules walk
 the sharded block gathers; with ``spec_k >= 2`` (pass the draft build) the
-draft propose scan, the batched verify step and a composite speculative
-tick join the registry, and the HBM model reports PER-SHARD bytes plus the
-verify/propose streams.
+draft's prefill and propose scan (over its own slot rows), the batched
+verify step and a composite speculative tick join the registry, and the
+HBM model reports PER-SHARD bytes plus the verify/propose streams.
 
 Entry points::
 
-    spec = ServeSpec(cfg, n_slots=4, kv_layout="paged", block_size=16,
-                     prefill_chunk=8, prompt_lens=(4, 8, 12))
+    spec = ServeSpec(cfg, n_slots=4, block_size=16, prefill_chunk=8,
+                     prompt_lens=(4, 8, 12))
     report = lint_serve(stages, spec)         # one Report, all programs
     report = lint_engine(engine)              # a live engine's exact knobs
 
@@ -95,9 +94,8 @@ class ServeSpec:
     cfg: Any
     n_slots: int = 4
     max_len: int | None = None          # None -> cfg.seq_len
-    kv_layout: str = "paged"
     block_size: int = 16
-    n_blocks: int | None = None         # None -> dense-equivalent capacity
+    n_blocks: int | None = None         # None -> every slot reaches max_len
     prefill_chunk: int | None = None
     cache_dtype: Any = None
     prompt_lens: tuple | None = None
@@ -108,7 +106,7 @@ class ServeSpec:
     # paged-attention kernel's single pass) — the HBM model's per-tick
     # rows and the registry's built programs both key off it
     attn_kernel: str = "dense"
-    # the host-RAM offload tier (paged only — serve/slots.py): evicted
+    # the host-RAM offload tier (serve/slots.py): evicted
     # prefix blocks demote to a host-side LRU of this many blocks instead
     # of dying; 0 disables the tier (and the host rows of the HBM model).
     # prefetch_ticks is the async host->HBM upload latency in engine ticks
@@ -206,9 +204,8 @@ def _retrace_finding(name: str, axis: str, sspec: ServeSpec) -> list[Finding]:
                  f"trace cache grows without limit)"),
         where=name,
         hint="bucket prompt lengths (ServeSpec.prompt_lens / the "
-             "simulator's buckets) or serve the paged layout with a "
-             "prefill_chunk, which bounds prefill shapes to the chunk "
-             "size")]
+             "simulator's buckets) or serve with a prefill_chunk, which "
+             "bounds the target's prefill shapes to the chunk size")]
 
 
 # -- abstract-arg builders -------------------------------------------------
@@ -250,18 +247,15 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
     With ``sspec.tp > 1`` pass the live ``mesh`` — the registry then
     builds the EXACT shard_map programs a TP engine runs (head-sharded
     pool, packed Megatron weights). With ``sspec.spec_k >= 2`` pass the
-    ``draft_stages`` build — the draft propose scan, the batched verify
-    and a composite speculative tick join the registry."""
+    ``draft_stages`` build — the draft's prefill and propose scan, the
+    batched verify and a composite speculative tick join the registry."""
     import numpy as np
 
     from simple_distributed_machine_learning_tpu.models.gpt import (
-        _cache_dtype,
         make_cached_decoder,
         make_paged_block_copy,
         make_paged_decode_step,
         make_paged_prefill_chunk,
-        make_slot_decode_step,
-        make_slot_prefill,
         pack_tp_serve_params,
     )
 
@@ -273,7 +267,6 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
     L = cfg.n_layers
     NB = sspec.blocks_per_seq
     n_blocks = sspec.nb
-    cd = _cache_dtype(sspec.cache_dtype)
     dense_params = [s.params for s in stages]
     if sspec.tp > 1:
         # the TP serving layout: stacked Megatron block slices + replicated
@@ -296,11 +289,11 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
 
     # the cached decoder: the solo-parity anchor every served request is
     # bit-exact against — linted at one representative bucket (always the
-    # dense single-device build, whatever the serving layout/tp)
+    # dense single-device build, whatever the serving tp)
     t0 = int(min(sspec.prompt_lens)) if sspec.prompt_lens else min(4, ml - 1)
     t0 = max(1, min(t0, ml - 1))
     n_new = ml - t0
-    # the solo anchor decodes dense rows: a quantized serving dtype
+    # the solo anchor decodes contiguous rows: a quantized serving dtype
     # widens to f32 there (quantized pools are judged against it at
     # pinned tolerance, not bit-exactness)
     from simple_distributed_machine_learning_tpu.models.gpt import (
@@ -352,148 +345,6 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
             "adapter_bank_update", make_adapter_bank_update(),
             (bank, spec((), np.int32, 0, N - 1), row_a)))
 
-    def _spec_draft_programs():
-        """The draft propose scan + its abstract pool (dense slot layout
-        whatever the target layout — the engine's draft discipline)."""
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            _cache_dtype,
-            _is_quantized_dtype,
-            make_slot_propose,
-        )
-        dcfg = sspec.draft_cfg
-        dL = sum(len(p["blocks"]) for p in (s.params for s in draft_stages))
-        # dense draft rows: a quantized TARGET dtype falls back to f32 for
-        # the draft (the engine's rule — trace the program it actually runs)
-        draft_cd = (None if _is_quantized_dtype(sspec.cache_dtype)
-                    else sspec.cache_dtype)
-        dkc = _sds((dL, S, dcfg.n_heads, ml,
-                    dcfg.d_model // dcfg.n_heads), _cache_dtype(draft_cd))
-        propose = make_slot_propose(draft_stages, dcfg, ml, K, draft_cd)
-        memo = check_builder_memo(
-            "make_slot_propose",
-            lambda: make_slot_propose(draft_stages, dcfg, ml, K, draft_cd))
-        dparams = abstractify([s.params for s in draft_stages])
-        propose_args = (dparams, dkc, dkc, toks, pos, kdS, f32S, top_ks,
-                        f32S)
-        return propose, propose_args, memo
-
-    if sspec.kv_layout == "dense":
-        kc = _sds((L, S, H, ml, dh), cd)
-        prefill = make_slot_prefill(stages, cfg, ml, sspec.cache_dtype,
-                                    mesh=mesh)
-        decode = make_slot_decode_step(stages, cfg, ml, sspec.cache_dtype,
-                                       mesh=mesh)
-        findings += check_builder_memo(
-            "make_slot_prefill",
-            lambda: make_slot_prefill(stages, cfg, ml, sspec.cache_dtype,
-                                      mesh=mesh))
-        findings += check_builder_memo(
-            "make_slot_decode_step",
-            lambda: make_slot_decode_step(stages, cfg, ml,
-                                          sspec.cache_dtype, mesh=mesh))
-        findings += _retrace_finding("make_slot_prefill", "prompt length",
-                                     sspec)
-        t0p = t0
-        prefill_args = (params, kc, kc, spec((1, t0p), np.int32, 0, V - 1),
-                        spec((), np.int32, 0, S - 1), kd1, f32, top_k1, f32)
-        decode_args = (params, kc, kc, toks, pos, kdS, f32S, top_ks, f32S)
-        programs.append(Program("slot_prefill", prefill, prefill_args))
-        programs.append(Program("slot_decode", decode, decode_args))
-
-        if sspec.adapters_on:
-            findings += check_builder_memo(
-                "make_slot_prefill[adapters]",
-                lambda: make_slot_prefill(stages, cfg, ml,
-                                          sspec.cache_dtype, mesh=mesh,
-                                          adapters=True))
-            findings += check_builder_memo(
-                "make_slot_decode_step[adapters]",
-                lambda: make_slot_decode_step(stages, cfg, ml,
-                                              sspec.cache_dtype,
-                                              mesh=mesh, adapters=True))
-            programs.append(Program(
-                "slot_prefill_adapter",
-                make_slot_prefill(stages, cfg, ml, sspec.cache_dtype,
-                                  mesh=mesh, adapters=True),
-                prefill_args + (bank, aid1)))
-            programs.append(Program(
-                "slot_decode_adapter",
-                make_slot_decode_step(stages, cfg, ml, sspec.cache_dtype,
-                                      mesh=mesh, adapters=True),
-                decode_args + (bank, aids)))
-
-        # the composite tick: prefill -> decode with the pool buffers
-        # THREADED the way engine.step does — donated-buffer flow across
-        # the program boundary is what the donation rules walk here
-        def dense_tick(params, kc, vc, prompt, slot, kd_1, t1, k1, p1,
-                       toks, pos, kds, temps, tks, tps):
-            kc, vc, tok, kd_1 = prefill(params, kc, vc, prompt, slot, kd_1,
-                                        t1, k1, p1)
-            kc, vc, toks2, kds2 = decode(params, kc, vc, toks, pos, kds,
-                                         temps, tks, tps)
-            return kc, vc, tok, toks2, kds2
-
-        programs.append(Program(
-            "dense_tick", dense_tick,
-            prefill_args[:1] + (kc, kc) + prefill_args[3:]
-            + decode_args[3:]))
-
-        if speculative:
-            from simple_distributed_machine_learning_tpu.models.gpt import (
-                make_slot_verify_step,
-            )
-            propose, propose_args, memo = _spec_draft_programs()
-            findings += memo
-            verify = make_slot_verify_step(stages, cfg, ml, K,
-                                           sspec.cache_dtype, mesh=mesh)
-            findings += check_builder_memo(
-                "make_slot_verify_step",
-                lambda: make_slot_verify_step(stages, cfg, ml, K,
-                                              sspec.cache_dtype,
-                                              mesh=mesh))
-            verify_args = (params, kc, kc, toks, pos, drafts_a, qrows_a,
-                           valid_n, kdS, f32S, top_ks, f32S)
-            programs.append(Program("slot_propose", propose, propose_args))
-            programs.append(Program("slot_verify", verify, verify_args))
-
-            # the composite speculative tick: propose (draft pool) ->
-            # verify (target pool), proposals flowing between on device.
-            # Single-device targets execute this as the engine's FUSED
-            # make_slot_spec_tick program — lint exactly that build; a TP
-            # engine dispatches the two halves separately, so the closure
-            # composition below IS its tick
-            if sspec.tp == 1:
-                from simple_distributed_machine_learning_tpu.models.gpt import (  # noqa: E501
-                    make_slot_spec_tick,
-                )
-                dcfg = sspec.draft_cfg
-                dense_spec_tick = make_slot_spec_tick(
-                    stages, cfg, draft_stages, dcfg, ml, K,
-                    sspec.cache_dtype)
-                findings += check_builder_memo(
-                    "make_slot_spec_tick",
-                    lambda: make_slot_spec_tick(stages, cfg, draft_stages,
-                                                dcfg, ml, K,
-                                                sspec.cache_dtype))
-            else:
-                def dense_spec_tick(dparams, dkc, dvc, params, kc, vc,
-                                    toks, pos, valid, dkds, kds, temps,
-                                    tks, tps):
-                    dkc, dvc, drafts, qrows, dkds2 = propose(
-                        dparams, dkc, dvc, toks, pos, dkds, temps, tks,
-                        tps)
-                    kc, vc, toks2, n_acc, kds2 = verify(
-                        params, kc, vc, toks, pos, drafts, qrows, valid,
-                        kds, temps, tks, tps)
-                    return dkc, dvc, kc, vc, toks2, n_acc, kds2, dkds2
-
-            programs.append(Program(
-                "dense_spec_tick", dense_spec_tick,
-                propose_args[:3] + (params, kc, kc, toks, pos, valid_n,
-                                    kdS, kdS, f32S, top_ks, f32S)))
-        return programs, findings
-
-    # paged layout
     kc = _cache_sds(L, n_blocks + 1, bs, H, dh, sspec.cache_dtype)
     kernel = sspec.attn_kernel
     tables = spec((S, NB), np.int32, 0, n_blocks)
@@ -575,10 +426,39 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
 
     if speculative:
         from simple_distributed_machine_learning_tpu.models.gpt import (
+            _cache_dtype,
+            _is_quantized_dtype,
             make_paged_verify_step,
+            make_slot_prefill,
+            make_slot_propose,
         )
-        propose, propose_args, memo = _spec_draft_programs()
-        findings += memo
+        # the draft's programs over its own slot rows (one max_len row a
+        # slot; a quantized TARGET dtype falls back to f32 for the draft:
+        # the engine's rule — trace the programs it actually runs)
+        dcfg = sspec.draft_cfg
+        dL = sum(len(p["blocks"]) for p in (s.params for s in draft_stages))
+        draft_cd = (None if _is_quantized_dtype(sspec.cache_dtype)
+                    else sspec.cache_dtype)
+        dkc = _sds((dL, S, dcfg.n_heads, ml,
+                    dcfg.d_model // dcfg.n_heads), _cache_dtype(draft_cd))
+        dparams = abstractify([s.params for s in draft_stages])
+        draft_prefill = make_slot_prefill(draft_stages, dcfg, ml, draft_cd)
+        findings += check_builder_memo(
+            "make_slot_prefill",
+            lambda: make_slot_prefill(draft_stages, dcfg, ml, draft_cd))
+        # the draft prefills a request's whole sequence at once
+        findings += _retrace_finding("make_slot_prefill", "prompt length",
+                                     sspec)
+        programs.append(Program(
+            "draft_prefill", draft_prefill,
+            (dparams, dkc, dkc, spec((1, t0), np.int32, 0, V - 1),
+             spec((), np.int32, 0, S - 1), kd1, f32, top_k1, f32)))
+        propose = make_slot_propose(draft_stages, dcfg, ml, K, draft_cd)
+        findings += check_builder_memo(
+            "make_slot_propose",
+            lambda: make_slot_propose(draft_stages, dcfg, ml, K, draft_cd))
+        propose_args = (dparams, dkc, dkc, toks, pos, kdS, f32S, top_ks,
+                        f32S)
         verify = make_paged_verify_step(stages, cfg, ml, bs, K,
                                         sspec.cache_dtype, mesh=mesh,
                                         kernel=kernel)
@@ -592,14 +472,15 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
         programs.append(Program("paged_propose", propose, propose_args))
         programs.append(Program("paged_verify", verify, verify_args))
 
-        # single-device targets run the engine's FUSED make_paged_spec_tick
-        # build; a TP engine dispatches the two halves separately (see the
-        # dense branch's note)
+        # the composite speculative tick: propose (draft rows) -> verify
+        # (target pool), proposals flowing between on device. Single-device
+        # targets execute this as the engine's FUSED make_paged_spec_tick
+        # program — lint exactly that build; a TP engine dispatches the two
+        # halves separately, so the closure composition below IS its tick
         if sspec.tp == 1:
             from simple_distributed_machine_learning_tpu.models.gpt import (
                 make_paged_spec_tick,
             )
-            dcfg = sspec.draft_cfg
             paged_spec_tick = make_paged_spec_tick(
                 stages, cfg, draft_stages, dcfg, ml, bs, K,
                 sspec.cache_dtype, kernel=kernel)
@@ -639,27 +520,21 @@ def degraded_spec(sspec: ServeSpec) -> ServeSpec:
     """The serve supervisor's degraded-fallback deployment for ``sspec`` —
     the SAME transform ``serve/supervisor.py::engine_factory`` applies when
     rebuilding past ``degrade_after`` restarts: speculation off, tensor
-    parallelism off, dense slot rows.  Kept here as one function so the
-    registry sweep (:func:`default_registry_reports`) lints the exact
-    layout a chaos-stressed supervisor will rebuild into — a fallback that
-    only exists on the worst day must be proven clean on every PR."""
+    parallelism off, the fused kernel, a quantized cache and the host tier
+    off; everything else (the paged pool's block size, block count and
+    prefill chunk, the adapter bank: tenants keep serving on the worst day)
+    kept. One function, so the registry sweep
+    (:func:`default_registry_reports`) lints the exact layout a
+    chaos-stressed supervisor will rebuild into — a fallback that only
+    exists on the worst day must be proven clean on every PR."""
     from simple_distributed_machine_learning_tpu.models.gpt import (
         _is_quantized_dtype,
     )
-    return ServeSpec(cfg_dense(sspec.cfg), n_slots=sspec.n_slots,
-                     max_len=sspec.max_len, kv_layout="dense",
-                     # quantized blocks and the fused kernel are paged
-                     # features: the dense fallback widens to f32 and
-                     # dense-math attention (engine_factory's rule)
-                     cache_dtype=(None
-                                  if _is_quantized_dtype(sspec.cache_dtype)
-                                  else sspec.cache_dtype),
-                     prompt_lens=sspec.prompt_lens,
-                     # the adapter bank SURVIVES degraded rebuilds —
-                     # engine_factory's _adapter_kw applies to both
-                     # branches (tenants keep serving on the worst day)
-                     n_adapters=sspec.n_adapters,
-                     adapter_rank=sspec.adapter_rank)
+    return dataclasses.replace(
+        sspec, cfg=cfg_dense(sspec.cfg), spec_k=0, draft_cfg=None,
+        attn_kernel="dense", host_cache_blocks=0, prefetch_ticks=1,
+        cache_dtype=(None if _is_quantized_dtype(sspec.cache_dtype)
+                     else sspec.cache_dtype))
 
 
 # -- the HBM-bytes-per-tick model ------------------------------------------
@@ -692,85 +567,70 @@ def hbm_tick_costs(sspec: ServeSpec, n_layers: int | None = None
     fused = sspec.attn_kernel == "fused"
     out: list[HBMCost] = []
     K = int(sspec.spec_k)
-    if sspec.kv_layout == "paged":
-        span = sspec.blocks_per_seq * sspec.block_size
+    span = sspec.blocks_per_seq * sspec.block_size
+    out.append(HBMCost(
+        "decode.kv_gather", "paged_decode", S * L * span * row,
+        note=f"{S} slots x {L} layers x {span}-row table span{shard}"
+             + (" — the fused kernel's single pass" if fused else "")))
+    if not fused:
+        # gather-then-dense materializes the gathered span and the
+        # attention einsums read it back: a SECOND full pass of
+        # resident K/V per tick — exactly what kernel='fused'
+        # (ops/paged_attention.py) eliminates
         out.append(HBMCost(
-            "decode.kv_gather", "paged_decode", S * L * span * row,
-            note=f"{S} slots x {L} layers x {span}-row table span{shard}"
-                 + (" — the fused kernel's single pass" if fused else "")))
+            "decode.kv_attn_reread", "paged_decode",
+            S * L * span * row,
+            note=f"dense-math path rereads the materialized "
+                 f"span{shard}; eliminated by kernel='fused'"))
+    out.append(HBMCost(
+        "decode.kv_scatter", "paged_decode", S * L * row,
+        note=f"one position per slot per layer{shard}"))
+    c = sspec.resolved_chunk
+    out.append(HBMCost(
+        "prefill.kv_scatter", "paged_prefill_chunk", c * L * row,
+        note=f"{c}-token chunk{shard}"))
+    out.append(HBMCost(
+        "prefill.kv_gather", "paged_prefill_chunk", L * span * row,
+        note=f"the chunk attends the gathered table span{shard}"))
+    out.append(HBMCost(
+        "cow.block_copy", "paged_block_copy",
+        L * sspec.block_size * row,
+        note=f"per copy-on-write divergence, all layers{shard}"))
+    if sspec.host_cache_blocks:
+        # the host offload tier's transfer-bandwidth bill: one whole
+        # block (all layers, K+V, plus quantized scale planes — it IS
+        # the pool's bytes_per_block) crosses the HBM<->host boundary
+        # per demotion and per prefetch promotion. The pool's
+        # host_transfer_bytes_total counter advances by exactly this
+        # per move — predict_transfer_bytes reconciles it to zero
+        # drift (tests/test_disagg.py)
+        blk = kv_block_bytes(L, H // tp, sspec.block_size, dh,
+                             sspec.cache_dtype)
+        out.append(HBMCost(
+            "offload.demote_copy", "host_offload", blk,
+            note=f"per HBM->host demotion: the evicted block, all "
+                 f"layers{shard} — an eviction that would otherwise "
+                 f"discard the prefix"))
+        out.append(HBMCost(
+            "offload.prefetch_upload", "host_offload", blk,
+            note=f"per host->HBM promotion: one async-prefetched "
+                 f"block, all layers{shard}, spread over "
+                 f"{sspec.prefetch_ticks} tick(s)"))
+    if K >= 2:
+        out.append(HBMCost(
+            "verify.kv_scatter", "paged_verify", S * L * K * row,
+            note=f"{K} speculated positions per slot per layer{shard}"))
+        out.append(HBMCost(
+            "verify.kv_gather", "paged_verify", S * L * span * row,
+            note=f"the verify queries attend the table span{shard}"
+                 + (" — the fused kernel's single pass" if fused
+                    else "")))
         if not fused:
-            # gather-then-dense materializes the gathered span and the
-            # attention einsums read it back: a SECOND full pass of
-            # resident K/V per tick — exactly what kernel='fused'
-            # (ops/paged_attention.py) eliminates
             out.append(HBMCost(
-                "decode.kv_attn_reread", "paged_decode",
+                "verify.kv_attn_reread", "paged_verify",
                 S * L * span * row,
                 note=f"dense-math path rereads the materialized "
                      f"span{shard}; eliminated by kernel='fused'"))
-        out.append(HBMCost(
-            "decode.kv_scatter", "paged_decode", S * L * row,
-            note=f"one position per slot per layer{shard}"))
-        c = sspec.resolved_chunk
-        out.append(HBMCost(
-            "prefill.kv_scatter", "paged_prefill_chunk", c * L * row,
-            note=f"{c}-token chunk{shard}"))
-        out.append(HBMCost(
-            "prefill.kv_gather", "paged_prefill_chunk", L * span * row,
-            note=f"the chunk attends the gathered table span{shard}"))
-        out.append(HBMCost(
-            "cow.block_copy", "paged_block_copy",
-            L * sspec.block_size * row,
-            note=f"per copy-on-write divergence, all layers{shard}"))
-        if sspec.host_cache_blocks:
-            # the host offload tier's transfer-bandwidth bill: one whole
-            # block (all layers, K+V, plus quantized scale planes — it IS
-            # the pool's bytes_per_block) crosses the HBM<->host boundary
-            # per demotion and per prefetch promotion. The pool's
-            # host_transfer_bytes_total counter advances by exactly this
-            # per move — predict_transfer_bytes reconciles it to zero
-            # drift (tests/test_disagg.py)
-            blk = kv_block_bytes(L, H // tp, sspec.block_size, dh,
-                                 sspec.cache_dtype)
-            out.append(HBMCost(
-                "offload.demote_copy", "host_offload", blk,
-                note=f"per HBM->host demotion: the evicted block, all "
-                     f"layers{shard} — an eviction that would otherwise "
-                     f"discard the prefix"))
-            out.append(HBMCost(
-                "offload.prefetch_upload", "host_offload", blk,
-                note=f"per host->HBM promotion: one async-prefetched "
-                     f"block, all layers{shard}, spread over "
-                     f"{sspec.prefetch_ticks} tick(s)"))
-        if K >= 2:
-            out.append(HBMCost(
-                "verify.kv_scatter", "paged_verify", S * L * K * row,
-                note=f"{K} speculated positions per slot per layer{shard}"))
-            out.append(HBMCost(
-                "verify.kv_gather", "paged_verify", S * L * span * row,
-                note=f"the verify queries attend the table span{shard}"
-                     + (" — the fused kernel's single pass" if fused
-                        else "")))
-            if not fused:
-                out.append(HBMCost(
-                    "verify.kv_attn_reread", "paged_verify",
-                    S * L * span * row,
-                    note=f"dense-math path rereads the materialized "
-                         f"span{shard}; eliminated by kernel='fused'"))
-    else:
-        out.append(HBMCost(
-            "decode.kv_read", "slot_decode", S * L * ml * row,
-            note=f"{S} rows x {L} layers x max_len={ml}{shard}"))
-        out.append(HBMCost(
-            "decode.kv_scatter", "slot_decode", S * L * row,
-            note=f"one position per slot per layer{shard}"))
-        if K >= 2:
-            out.append(HBMCost(
-                "verify.kv_scatter", "slot_verify", S * L * K * row,
-                note=f"{K} speculated positions per slot per layer{shard}"))
-            out.append(HBMCost(
-                "verify.kv_read", "slot_verify", S * L * ml * row,
-                note=f"the verify queries read the full rows{shard}"))
     if sspec.adapters_on:
         # the adapter bank's per-tick traffic: each slot gathers its
         # tenant's whole A/B row (4 planes x L layers, f32) per decode
@@ -782,16 +642,13 @@ def hbm_tick_costs(sspec: ServeSpec, n_layers: int | None = None
         # aq/av gathers replicate — billed at the replicated full row.
         from simple_distributed_machine_learning_tpu.models import lora
         row_b = lora.bank_bytes(1, L, cfg.d_model, sspec.adapter_rank)
-        paged = sspec.kv_layout == "paged"
         out.append(HBMCost(
-            "decode.adapter_gather",
-            "paged_decode" if paged else "slot_decode", S * row_b,
+            "decode.adapter_gather", "paged_decode", S * row_b,
             note=f"{S} slots x one bank row ({L} layers, 4 low-rank "
                  f"planes, rank {sspec.adapter_rank}) — row 0 (base) "
                  f"gathers the same bytes of zeros"))
         out.append(HBMCost(
-            "prefill.adapter_gather",
-            "paged_prefill_chunk" if paged else "slot_prefill", row_b,
+            "prefill.adapter_gather", "paged_prefill_chunk", row_b,
             note="the boarding request's one bank row"))
         out.append(HBMCost(
             "adapter.bank_upload", "adapter_bank_update", row_b,
@@ -802,7 +659,7 @@ def hbm_tick_costs(sspec: ServeSpec, n_layers: int | None = None
             _is_quantized_dtype,
         )
         dcfg = sspec.draft_cfg
-        # the draft pool is dense slot rows; a quantized TARGET dtype
+        # the draft keeps one max_len row a slot; a quantized TARGET dtype
         # falls back to f32 for the draft (the engine's rule)
         draft_cd = (None if _is_quantized_dtype(sspec.cache_dtype)
                     else sspec.cache_dtype)
@@ -829,21 +686,14 @@ def predict_kv_bytes_resident(sspec: ServeSpec, rows_per_seq,
     what makes the runtime KV-drift gauge (``serve_kv_drift_bytes`` =
     live − predicted) a leak detector: 0 without sharing, ≤ 0 with it,
     and > 0 only if the pool pins blocks the model says it cannot need.
-    Dense layout: ``rows_per_seq`` is ignored — the dense pool pins every
-    row up front, so the prediction is the full allocation. PER SHARD
-    under TP — the pool's gauge reports per-chip bytes (heads split ``tp``
-    ways), and this model must agree with it EXACTLY
+    PER SHARD under TP — the pool's gauge reports per-chip bytes (heads
+    split ``tp`` ways), and this model must agree with it EXACTLY
     (tests/test_analysis_serve.py)."""
     from simple_distributed_machine_learning_tpu.serve.slots import (
         kv_block_bytes,
     )
     cfg = sspec.cfg
     L = n_layers if n_layers is not None else cfg.n_layers
-    if sspec.kv_layout == "dense":
-        per_row = kv_block_bytes(L, cfg.n_heads // sspec.tp, sspec.ml,
-                                 cfg.d_model // cfg.n_heads,
-                                 sspec.cache_dtype)
-        return per_row * sspec.n_slots
     per_block = kv_block_bytes(L, cfg.n_heads // sspec.tp, sspec.block_size,
                                cfg.d_model // cfg.n_heads,
                                sspec.cache_dtype)
@@ -945,11 +795,9 @@ def lint_serve(stages, sspec: ServeSpec, name: str | None = None,
     programs, policy = build_registry(stages, sspec, mesh=mesh,
                                       draft_stages=draft_stages)
     n_layers = sum(len(p["blocks"]) for p in (s.params for s in stages))
-    label = name or (f"serve[{sspec.kv_layout} slots={sspec.n_slots} "
-                     f"max_len={sspec.ml}"
-                     + (f" block={sspec.block_size}"
-                        f" chunk={sspec.prefill_chunk}"
-                        if sspec.kv_layout == "paged" else "")
+    label = name or (f"serve[slots={sspec.n_slots} max_len={sspec.ml}"
+                     f" block={sspec.block_size}"
+                     f" chunk={sspec.prefill_chunk}"
                      + (" kernel=fused" if sspec.attn_kernel == "fused"
                         else "")
                      + (f" cache={jnp_dtype_name(sspec.cache_dtype)}"
@@ -1034,8 +882,9 @@ def _reconcile_kernel_hbm(kernel_rows: list[HBMCost],
 
 def default_registry_reports() -> list[Report]:
     """The CI lint gate's serve-program sweep: one tiny GPT build linted
-    over the paged layout at two block/chunk shapes plus the dense layout,
-    all with the simulator's prompt buckets declared — every report must
+    at two block/chunk shapes, under the fused kernel over a quantized
+    pool, with adapters, speculative and as the degraded fallback, all
+    with the simulator's prompt buckets declared — every report must
     be ERROR-free for the gate to pass (``--serve`` in the analysis
     CLI)."""
     import jax
@@ -1050,38 +899,32 @@ def default_registry_reports() -> list[Report]:
     draft_cfg = _dc.replace(cfg, n_layers=1)
     draft_stages, _, _ = make_gpt_stages(jax.random.key(1), draft_cfg, 1)
     buckets = (4, 8, 12)
-    # the speculative paged layout runs the FUSED verify kernel (the
+    # the speculative deployment runs the FUSED verify kernel (the
     # K-token variant of paged attention) so the registry sweep lints —
     # and HBM-reconciles — both fused tick shapes, not just K=1 decode
-    spec_paged = ServeSpec(cfg, n_slots=4, kv_layout="paged", block_size=4,
-                           prefill_chunk=3, prompt_lens=buckets, spec_k=4,
+    spec_paged = ServeSpec(cfg, n_slots=4, block_size=4, prefill_chunk=3,
+                           prompt_lens=buckets, spec_k=4,
                            draft_cfg=draft_cfg, attn_kernel="fused")
     specs = [
-        ServeSpec(cfg, n_slots=4, kv_layout="paged", block_size=4,
-                  prefill_chunk=3, prompt_lens=buckets),
-        ServeSpec(cfg, n_slots=4, kv_layout="paged", block_size=8,
-                  prefill_chunk=None, prompt_lens=buckets),
+        ServeSpec(cfg, n_slots=4, block_size=4, prefill_chunk=3,
+                  prompt_lens=buckets),
+        ServeSpec(cfg, n_slots=4, block_size=8, prefill_chunk=None,
+                  prompt_lens=buckets),
         # the fused Pallas paged-attention kernel over an int8-quantized
         # pool (interpret mode off-TPU): the serving hot path's kernel
         # variant is linted exactly like the dense-math programs
-        ServeSpec(cfg, n_slots=4, kv_layout="paged", block_size=4,
-                  prefill_chunk=3, prompt_lens=buckets,
-                  cache_dtype="int8", attn_kernel="fused"),
-        ServeSpec(cfg, n_slots=4, kv_layout="dense", prompt_lens=buckets),
+        ServeSpec(cfg, n_slots=4, block_size=4, prefill_chunk=3,
+                  prompt_lens=buckets, cache_dtype="int8",
+                  attn_kernel="fused"),
         # the multi-tenant adapter layouts (ISSUE 20): every decode-path
         # program's adapters=True twin plus the bank-row upload program,
         # bank sized by the engine's n_slots + 1 rule
-        ServeSpec(cfg, n_slots=4, kv_layout="paged", block_size=4,
-                  prefill_chunk=3, prompt_lens=buckets, n_adapters=5,
-                  adapter_rank=2),
-        ServeSpec(cfg, n_slots=4, kv_layout="dense", prompt_lens=buckets,
-                  n_adapters=5, adapter_rank=2),
-        # the speculative pair (draft propose + batched verify + composite
-        # tick) on both layouts — TP deployments need a live multi-device
-        # mesh, so the CLI/tests cover those where devices exist
+        ServeSpec(cfg, n_slots=4, block_size=4, prefill_chunk=3,
+                  prompt_lens=buckets, n_adapters=5, adapter_rank=2),
+        # the speculative programs (draft prefill + propose, batched verify,
+        # composite tick) — TP deployments need a live multi-device mesh,
+        # so the CLI/tests cover those where devices exist
         spec_paged,
-        ServeSpec(cfg, n_slots=4, kv_layout="dense", prompt_lens=buckets,
-                  spec_k=4, draft_cfg=draft_cfg),
     ]
     reports = [lint_serve(stages, s, draft_stages=(draft_stages
                                                    if s.spec_k else None))
@@ -1092,23 +935,21 @@ def default_registry_reports() -> list[Report]:
     # shows the fallback was proven, not assumed
     reports.append(lint_serve(
         stages, degraded_spec(spec_paged),
-        name=f"serve[degraded fallback of paged spec_k={spec_paged.spec_k}"
-             f": dense slots={spec_paged.n_slots} tp=1 spec_k=0]"))
+        name=f"serve[degraded fallback of spec_k={spec_paged.spec_k} "
+             f"kernel=fused: slots={spec_paged.n_slots} "
+             f"block={spec_paged.block_size} tp=1 spec_k=0]"))
     return reports
 
 
 def engine_spec(engine, prompt_lens: tuple | None = None) -> ServeSpec:
     """The :class:`ServeSpec` of a LIVE engine — the one engine->spec
-    mapping (layout, block geometry, chunk size, cache dtype, spec/draft
+    mapping (block geometry, chunk size, cache dtype, spec/draft
     shape) shared by the lint preflight and the runtime KV-drift gauge,
     so the two can never describe different deployments."""
     pool = engine.pool
-    paged = engine.kv_layout == "paged"
     return ServeSpec(
         cfg=engine.cfg, n_slots=pool.n_slots, max_len=engine.max_len,
-        kv_layout=engine.kv_layout,
-        block_size=pool.block_size if paged else 16,
-        n_blocks=pool.n_blocks if paged else None,
+        block_size=pool.block_size, n_blocks=pool.n_blocks,
         prefill_chunk=engine.prefill_chunk,
         # the storage dtype (a quantized pool's is its narrow one, which
         # round-trips through _cache_dtype)
@@ -1116,8 +957,8 @@ def engine_spec(engine, prompt_lens: tuple | None = None) -> ServeSpec:
         spec_k=engine.spec_k if engine.speculative else 0,
         draft_cfg=engine.draft_cfg,
         attn_kernel=engine.attn_kernel,
-        host_cache_blocks=getattr(pool, "host_cache_blocks", 0),
-        prefetch_ticks=getattr(pool, "prefetch_ticks", 1),
+        host_cache_blocks=pool.host_cache_blocks,
+        prefetch_ticks=pool.prefetch_ticks,
         n_adapters=(0 if getattr(engine, "_adapters", None) is None
                     else engine._adapters.n_rows),
         adapter_rank=(0 if getattr(engine, "_adapters", None) is None
@@ -1126,7 +967,7 @@ def engine_spec(engine, prompt_lens: tuple | None = None) -> ServeSpec:
 
 def lint_engine(engine, prompt_lens: tuple | None = None) -> Report:
     """Preflight a live :class:`~..serve.engine.InferenceEngine`'s EXACT
-    programs — same layout, block geometry, chunk size and cache dtype the
+    programs — same block geometry, chunk size and cache dtype the
     engine constructed (``InferenceEngine(lint=True)`` calls this at
     construction)."""
     return lint_serve(engine.stages, engine_spec(engine, prompt_lens),

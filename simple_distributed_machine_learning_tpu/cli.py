@@ -973,7 +973,7 @@ def _run_serve(args, n_stages: int, key) -> None:
         buckets = tuple(args.serve_shared_prefix + p
                         for p in GPT_SERVE_PROMPTS)
         report = lint_serve(stages, ServeSpec(
-            serve_cfg, n_slots=args.serve_slots, kv_layout="paged",
+            serve_cfg, n_slots=args.serve_slots,
             block_size=args.serve_block_size,
             prefill_chunk=(args.serve_prefill_chunk or None),
             prompt_lens=buckets, spec_k=args.serve_spec_k,

@@ -15,8 +15,6 @@ from simple_distributed_machine_learning_tpu.models.gpt import (  # noqa: F401
     make_cached_decoder,
     make_decoder,
     make_gpt_stages,
-    make_slot_decode_step,
-    make_slot_prefill,
 )
 from simple_distributed_machine_learning_tpu.models.lenet import (  # noqa: F401
     make_lenet_stages,

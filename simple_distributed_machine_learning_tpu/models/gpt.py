@@ -672,8 +672,9 @@ def _cache_dtype(cache_dtype):
     a :class:`QuantKV` pytree instead of a bare array — with quantize
     fused into every scatter and dequantize into every gather/kernel
     (:func:`_quantize_rows` / :func:`_paged_gather`). Quantization is a
-    PAGED-pool feature: dense slot pools and the solo cached decoder are
-    the parity anchors and reject it (:func:`_check_cache_quantization`)."""
+    PAGED-pool feature: the speculative draft's slot rows and the solo
+    cached decoder (the parity anchor) carry no scale planes and reject it
+    (:func:`_check_cache_quantization`)."""
     return jnp.float32 if cache_dtype is None else jnp.dtype(cache_dtype)
 
 
@@ -739,10 +740,11 @@ def _quantize_rows(rows: jax.Array, dtype) -> tuple[jax.Array, jax.Array]:
 
 def _check_cache_quantization(cache_dtype, caller: str,
                               paged: bool) -> None:
-    """Quantized caches are paged-pool-only (the dense layouts are the
-    bit-exactness anchors the quantized pool's pinned tolerance is judged
-    against); unknown narrow dtypes fail loudly here instead of as a
-    shape error mid-trace."""
+    """Quantized caches are paged-pool-only (the solo cached decoder is the
+    bit-exactness anchor the quantized pool's pinned tolerance is judged
+    against, and the draft's slot rows have no scale planes); unknown
+    narrow dtypes fail loudly here instead of as a shape error
+    mid-trace."""
     if cache_dtype is None:
         return
     name = jnp.dtype(cache_dtype).name
@@ -754,7 +756,7 @@ def _check_cache_quantization(cache_dtype, caller: str,
         raise ValueError(
             f"{caller}: quantized cache_dtype={name} is a paged-pool "
             f"feature (per-block scales live beside physical blocks); "
-            f"dense slot layouts are the parity anchors — use f32/bf16")
+            f"the cached decoder and the draft's slot rows take f32/bf16")
 
 
 # -- tensor-parallel serving ------------------------------------------------
@@ -877,12 +879,11 @@ def _tp_jit(name, body, mesh, n_buf_in, n_rest_in, n_buf_out, n_rest_out,
     """``jit(shard_map(body))`` with the serving specs, the program called
     ``name`` (what a device trace shows it as): params as the
     ``(stacked blocks, replicated embed/head)`` pair, ``n_buf_in`` K/V pool
-    buffers sharded on their HEAD axis (dim 2 in both layouts: the dense
-    slot pool's ``[L, S, H, max_len, dh]`` and every leaf of the paged
+    buffers sharded on their HEAD axis (dim 2 of every leaf of the paged
     pool's per-layer ``[n_blocks+1, bs, H*dh]``, whose lanes hold a shard's
-    heads contiguously; one spec is the prefix of either pytree),
-    everything else replicated. The pool buffers are donated exactly as in
-    the single-device builders."""
+    heads contiguously, and of a quantized pool's scale plane; one spec is
+    the prefix of the pytree), everything else replicated. The pool buffers
+    are donated exactly as in the single-device builders."""
     from jax.sharding import PartitionSpec as P
 
     from simple_distributed_machine_learning_tpu.parallel.compat import (
@@ -1311,12 +1312,12 @@ def _build_cached_decoder(total, prompt_len, n_new, H, dh, cd,
 
 def _validate_slot_build(stages, cfg: GPTConfig, max_len: int,
                          caller: str, cache_dtype=None) -> None:
-    """Shared validation for the serving slot ops: single-device dense-MLP
+    """Shared validation for the serving ops: single-device dense-MLP
     builds only (the :func:`make_cached_decoder` restrictions — MoE routing
     capacity is a full-sequence quantity; sharded stage trees are per-shard
     slices, not the whole model), ``max_len`` within the position
-    table, and no quantized cache dtype (dense slot rows are the parity
-    anchors; the paged validator re-allows quantization)."""
+    table, and no quantized cache dtype (the draft's slot rows carry no
+    scale planes; the paged validator re-allows quantization)."""
     _check_cache_quantization(cache_dtype, caller, paged=False)
     if cfg.n_experts > 0:
         raise ValueError(
@@ -1337,152 +1338,87 @@ def _validate_slot_build(stages, cfg: GPTConfig, max_len: int,
     _check_embed_matches(stages, cfg)
 
 
-def make_slot_prefill(stages, cfg: GPTConfig, max_len: int,
-                      cache_dtype=None, mesh=None,
-                      adapters: bool = False):
-    """Serving prefill-into-slot: ``prefill(params, kc, vc, prompt [1, T0],
-    slot, key_data, temperature, top_k, top_p) -> (kc, vc, token,
-    key_data)``.
+# -- the speculative draft's programs ----------------------------------------
+#
+# The served TARGET model's K/V lives in the paged pool and nowhere else. The
+# speculative DRAFT model keeps one ``max_len`` row per slot, ``[L, n_slots,
+# H, max_len, dh]`` (``InferenceEngine._init_draft_pool``): it is small by
+# design, so paging it buys nothing, and a row past a slot's newest position
+# is overwritten before it can be attended. ``make_slot_prefill`` fills a
+# slot's row from a prompt and ``make_slot_propose`` (speculative section
+# below) decodes ``spec_k`` tokens over every row; both are single-device and
+# run the base model.
 
-    ``adapters=True`` builds the multi-tenant variant: two TRACED args
-    append to the signature — the stacked adapter ``bank`` pytree and the
-    request's bank-row index ``aid`` — and every block's q/v projection
-    adds the gathered low-rank delta (:func:`_dense_qkv`). One static
-    BOOL in the memo key: bank contents, row count and rank are all data,
-    so adapter registration/hot-swap never retraces and any adapter mix
-    shares this one program.
+
+def _refuse_tp_draft(cfg: GPTConfig, caller: str) -> None:
+    if cfg.n_tensor_parallel > 1:
+        raise ValueError(
+            f"{caller} runs the draft model single-device "
+            f"(replicated under a TP target): build the draft with "
+            f"n_tensor_parallel=1")
+
+
+def make_slot_prefill(stages, cfg: GPTConfig, max_len: int,
+                      cache_dtype=None):
+    """The draft's prefill-into-slot: ``prefill(params, kc, vc, prompt
+    [1, T0], slot, key_data, temperature, top_k, top_p) -> (kc, vc, token,
+    key_data)``.
 
     Runs ONE request's prompt through every block (batch 1, exactly the
     solo decoder's prefill shapes and math — shared :func:`_dense_qkv` /
     ``causal_attention_core`` / :func:`_dense_attn_tail`), writes each
-    layer's K/V rows into pool row ``slot`` at positions ``[0, T0)``, and
-    samples the first output token with the request's own params and key
-    stream (:func:`_sample_dyn`'s sentinels: ``top_k=0`` / ``top_p=2.0``
-    disable). Retraces per distinct prompt length (the prompt shape is
-    static — real serving buckets prompt lengths the same way); the decode
-    tick stays one program regardless.
+    layer's K/V rows into row ``slot`` at positions ``[0, T0)``, and
+    samples a token with the given params and key (:func:`_sample_dyn`'s
+    sentinels: ``top_k=0`` / ``top_p=2.0`` disable; the engine discards
+    both, only the cache write matters to a draft). Retraces per distinct
+    prompt length (the prompt shape is static).
 
-    ``kc``/``vc``: the pool buffers, ``[L, n_slots, H, max_len, dh]`` in
-    the :func:`_cache_dtype` storage dtype (bf16 halves pool memory). They
-    are DONATED — the engine always threads the returned buffers back into
-    the pool, and donation lets XLA update the slot row in place instead of
-    copying the whole pool per call.
-
-    With ``cfg.n_tensor_parallel > 1`` (pass the ``mesh``): the same math
-    inside ``shard_map`` — QKV on the local ``H/tp`` heads, K/V landing in
-    this shard's slice of the head-sharded pool, the attention/MLP reduces
-    of :func:`_tp_attn_tail` — with ``params`` in the
-    :func:`pack_tp_serve_params` layout.
+    ``kc``/``vc``: the draft's buffers, ``[L, n_slots, H, max_len, dh]`` in
+    the :func:`_cache_dtype` storage dtype. They are DONATED — the engine
+    threads the returned buffers back, and donation lets XLA update the
+    slot row in place instead of copying the buffer per call. Single-device
+    like :func:`make_slot_propose`: a tensor-parallel ``cfg`` is refused.
     """
     _validate_slot_build(stages, cfg, max_len, "make_slot_prefill",
                          cache_dtype)
-    mesh = _validate_tp_serve(cfg, mesh, "make_slot_prefill")
+    _refuse_tp_draft(cfg, "make_slot_prefill")
     H = cfg.n_heads
-    key_ = ("slot_prefill", cfg, max_len, mesh, adapters)
-    if cfg.n_tensor_parallel > 1:
-        return _memo_build(key_, lambda: _build_slot_prefill_tp(cfg, mesh,
-                                                                adapters))
-    return _memo_build(key_, lambda: _build_slot_prefill(H, adapters))
+    return _memo_build(("slot_prefill", cfg, max_len),
+                       lambda: _build_slot_prefill(H))
 
 
-def _slot_prefill_fwd(blocks, embed, head, kc, vc, prompt, slot, H, tail,
-                      ab_at=None):
-    """One request's whole-prompt prefill into pool row ``slot`` — the one
-    copy of the math, shared by the single-device and TP builds (``H`` is
-    the LOCAL head count; ``tail`` closes each block; ``ab_at`` is the
-    optional per-layer adapter lookup of :func:`_adapter_layers`)."""
-    t0 = prompt.shape[1]
-    ids = prompt.astype(jnp.int32)
-    h = embedding_lookup(embed["tok"], ids) + embed["pos"][:t0]
-    for li, bp in enumerate(blocks):
-        q, k_, v = _dense_qkv(bp, h, H,               # [1, H, T0, dh]
-                              None if ab_at is None else ab_at(li))
-        kc = jax.lax.dynamic_update_slice(
-            kc, k_.astype(kc.dtype)[None], (li, slot, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(
-            vc, v.astype(vc.dtype)[None], (li, slot, 0, 0, 0))
-        h = tail(bp, h, causal_attention_core(q, k_, v))
-    return kc, vc, _head_logprobs(head, h[:, -1])[0]  # row: [V]
-
-
-def _build_slot_prefill(H, adapters=False):
-    def run(params, kc, vc, prompt, slot, key_data, temperature, top_k,
-            top_p, ab_at=None):
-        embed, blocks, head = _merged_stage_trees(params)
-        kc, vc, row = _slot_prefill_fwd(blocks, embed, head, kc, vc,
-                                        prompt, slot, H, _dense_attn_tail,
-                                        ab_at)
-        tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
-        return kc, vc, tok, kd
-
-    if adapters:
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def prefill(params, kc, vc, prompt, slot, key_data, temperature,
-                    top_k, top_p, bank, aid):
-            return run(params, kc, vc, prompt, slot, key_data,
-                       temperature, top_k, top_p,
-                       _adapter_layers(bank, aid))
-
-        return prefill
-
+def _build_slot_prefill(H):
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def prefill(params, kc, vc, prompt, slot, key_data, temperature,
                 top_k, top_p):
-        return run(params, kc, vc, prompt, slot, key_data, temperature,
-                   top_k, top_p)
+        embed, blocks, head = _merged_stage_trees(params)
+        t0 = prompt.shape[1]
+        ids = prompt.astype(jnp.int32)
+        h = embedding_lookup(embed["tok"], ids) + embed["pos"][:t0]
+        for li, bp in enumerate(blocks):
+            q, k_, v = _dense_qkv(bp, h, H)               # [1, H, T0, dh]
+            kc = jax.lax.dynamic_update_slice(
+                kc, k_.astype(kc.dtype)[None], (li, slot, 0, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                vc, v.astype(vc.dtype)[None], (li, slot, 0, 0, 0))
+            h = _dense_attn_tail(bp, h, causal_attention_core(q, k_, v))
+        row = _head_logprobs(head, h[:, -1])[0]           # [V]
+        tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
+        return kc, vc, tok, kd
 
     return prefill
 
 
-def _build_slot_prefill_tp(cfg, mesh, adapters=False):
-    tp = cfg.n_tensor_parallel
-    tail = functools.partial(_tp_attn_tail, overlap=cfg.overlap)
-    H_loc = cfg.n_heads // tp
-
-    def run(params, kc, vc, prompt, slot, key_data, temperature,
-            top_k, top_p, ab_at=None):
-        blocks, embed, head = _tp_local_trees(params)
-        kc, vc, row = _slot_prefill_fwd(blocks, embed, head, kc, vc,
-                                        prompt, slot, H_loc, tail, ab_at)
-        row = _close_rows(row)
-        tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
-        return kc, vc, tok, kd
-
-    if adapters:
-        def body(params, kc, vc, prompt, slot, key_data, temperature,
-                 top_k, top_p, bank, aid):
-            return run(params, kc, vc, prompt, slot, key_data,
-                       temperature, top_k, top_p,
-                       _tp_adapter_layers(bank, aid, tp))
-
-        return _tp_jit("prefill_dense_tp", body, mesh,
-                       n_buf_in=2, n_rest_in=8, n_buf_out=2, n_rest_out=2)
-
-    def body(params, kc, vc, prompt, slot, key_data, temperature,
-             top_k, top_p):
-        return run(params, kc, vc, prompt, slot, key_data, temperature,
-                   top_k, top_p)
-
-    return _tp_jit("prefill_dense_tp", body, mesh,
-                   n_buf_in=2, n_rest_in=6, n_buf_out=2, n_rest_out=2)
-
-
-def _dense_block_step_slots(bp, h, li, kc, vc, pos, n_heads,
-                            tail=_dense_attn_tail, ab=None):
-    """One block on one token per SLOT (``h``: [S, 1, d]) against pool
-    cache row ``li``; each slot writes its new K/V at its OWN position
-    (``pos``: [S]) and attends ``[0, pos]``. Per-slot math is exactly
-    :func:`_dense_block_step`'s (same scale expression, same einsums, same
-    masked-row softmax), and every slot's output depends only on its own
-    cache row — the bit-exactness anchor continuous batching rests on.
-    ``n_heads`` is the LOCAL head count and ``tail`` closes the block (the
-    TP build passes ``H/tp`` and :func:`_tp_attn_tail`); ``ab`` is this
-    layer's optional batched adapter factors (:func:`_dense_qkv`)."""
-    q, knew, vnew = _dense_qkv(bp, h, n_heads, ab)        # [S, H, 1, dh]
-    # scale from the PROJECTED head dim (q's trailing axis), never from
-    # h.shape[-1] // n_heads: under TP the local head count shrinks but the
-    # per-head dim does not, and a local-count-derived scale silently
-    # rescales attention (the causal_attention_core convention)
+def _dense_block_step_slots(bp, h, li, kc, vc, pos, n_heads):
+    """One block of the DRAFT on one token per SLOT (``h``: [S, 1, d])
+    against its cache row ``li``; each slot writes its new K/V at its OWN
+    position (``pos``: [S]) and attends ``[0, pos]``. Per-slot math is
+    exactly :func:`_dense_block_step`'s (same scale expression, same
+    einsums, same masked-row softmax), and every slot's output depends only
+    on its own cache row."""
+    q, knew, vnew = _dense_qkv(bp, h, n_heads)            # [S, H, 1, dh]
+    # scale from the PROJECTED head dim (q's trailing axis): the
+    # causal_attention_core convention
     dh = q.shape[-1]
 
     def upd(cache, new, p):
@@ -1498,117 +1434,17 @@ def _dense_block_step_slots(bp, h, li, kc, vc, pos, n_heads,
     scores = jnp.where(live, scores, -jnp.inf)
     a = jnp.einsum("bhqk,bhkd->bhqd",
                    jax.nn.softmax(scores, axis=-1), vci)
-    return tail(bp, h, a), kc, vc
+    return _dense_attn_tail(bp, h, a), kc, vc
 
 
-def make_slot_decode_step(stages, cfg: GPTConfig, max_len: int,
-                          cache_dtype=None, mesh=None,
-                          adapters: bool = False):
-    """Serving decode tick: ``step(params, kc, vc, toks [S], pos [S],
-    key_data [S, 2], temps [S], top_ks [S], top_ps [S]) -> (kc, vc,
-    next_toks [S], next_key_data [S, 2])``.
-
-    ONE batched token step over ALL ``n_slots`` slots — static shapes, so a
-    single compiled program serves every tick regardless of occupancy.
-    Each slot consumes its carried token at its own position, lands its K/V
-    row via a per-slot scatter, attends its masked cache row, and samples
-    with its own params and key stream (``vmap`` of :func:`_sample_dyn` —
-    loop semantics, per-slot draws equal the unbatched calls). Inactive
-    slots compute garbage that the engine discards host-side; their stale
-    cache writes are invisible by construction (see ``serve/slots.py``).
-    ``kc``/``vc`` are donated (same contract as :func:`make_slot_prefill`):
-    one in-place pool update per tick, not a pool-sized copy per token.
-
-    With ``cfg.n_tensor_parallel > 1`` (pass the ``mesh``): the shard_map
-    twin over the head-sharded pool (:func:`make_slot_prefill`'s TP notes
-    apply). ``adapters=True`` appends the traced ``(bank, aids [S])``
-    multi-tenant args — each slot gathers its OWN adapter's low-rank
-    factors by index, so one program serves any adapter mix per tick
-    (:func:`make_slot_prefill`'s adapter notes apply).
-    """
-    _validate_slot_build(stages, cfg, max_len, "make_slot_decode_step",
-                         cache_dtype)
-    mesh = _validate_tp_serve(cfg, mesh, "make_slot_decode_step")
-    H = cfg.n_heads
-    key_ = ("slot_decode", cfg, max_len, mesh, adapters)
-    if cfg.n_tensor_parallel > 1:
-        return _memo_build(key_, lambda: _build_slot_decode_tp(cfg, mesh,
-                                                               adapters))
-    return _memo_build(key_, lambda: _build_slot_decode(H, adapters))
-
-
-def _slot_decode_fwd(blocks, embed, head, kc, vc, toks, pos, H, tail,
-                     ab_at=None):
-    """The batched one-token-per-slot step's forward — shared by the
-    single-device and TP builds and by the speculative draft proposer
-    (which always runs base-model: the draft never takes ``ab_at``)."""
+def _slot_decode_fwd(blocks, embed, head, kc, vc, toks, pos, H):
+    """One step of the draft proposer's scan (:func:`make_slot_propose`):
+    one token per slot through every block over the draft's slot rows."""
     pe = jnp.take(embed["pos"], pos, axis=0)[:, None]      # [S, 1, d]
     h = embedding_lookup(embed["tok"], toks[:, None]) + pe
     for li, bp in enumerate(blocks):
-        h, kc, vc = _dense_block_step_slots(
-            bp, h, li, kc, vc, pos, H, tail,
-            None if ab_at is None else ab_at(li))
+        h, kc, vc = _dense_block_step_slots(bp, h, li, kc, vc, pos, H)
     return kc, vc, _head_logprobs(head, h[:, 0])           # rows: [S, V]
-
-
-def _build_slot_decode(H, adapters=False):
-    def run(params, kc, vc, toks, pos, key_data, temps, top_ks, top_ps,
-            ab_at=None):
-        embed, blocks, head = _merged_stage_trees(params)
-        kc, vc, rows = _slot_decode_fwd(blocks, embed, head, kc, vc, toks,
-                                        pos, H, _dense_attn_tail, ab_at)
-        toks2, kd2 = jax.vmap(_sample_dyn)(rows, key_data, temps,
-                                           top_ks, top_ps)
-        return kc, vc, toks2, kd2
-
-    if adapters:
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step_dense_decode(params, kc, vc, toks, pos, key_data, temps,
-                              top_ks, top_ps, bank, aids):
-            return run(params, kc, vc, toks, pos, key_data, temps,
-                       top_ks, top_ps, _adapter_layers(bank, aids))
-
-        return step_dense_decode
-
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step_dense_decode(params, kc, vc, toks, pos, key_data, temps,
-                          top_ks, top_ps):
-        return run(params, kc, vc, toks, pos, key_data, temps, top_ks,
-                   top_ps)
-
-    return step_dense_decode
-
-
-def _build_slot_decode_tp(cfg, mesh, adapters=False):
-    tp = cfg.n_tensor_parallel
-    tail = functools.partial(_tp_attn_tail, overlap=cfg.overlap)
-    H_loc = cfg.n_heads // tp
-
-    def run(params, kc, vc, toks, pos, key_data, temps, top_ks, top_ps,
-            ab_at=None):
-        blocks, embed, head = _tp_local_trees(params)
-        kc, vc, rows = _slot_decode_fwd(blocks, embed, head, kc, vc, toks,
-                                        pos, H_loc, tail, ab_at)
-        rows = _close_rows(rows)
-        toks2, kd2 = jax.vmap(_sample_dyn)(rows, key_data, temps,
-                                           top_ks, top_ps)
-        return kc, vc, toks2, kd2
-
-    if adapters:
-        def body(params, kc, vc, toks, pos, key_data, temps, top_ks,
-                 top_ps, bank, aids):
-            return run(params, kc, vc, toks, pos, key_data, temps,
-                       top_ks, top_ps, _tp_adapter_layers(bank, aids, tp))
-
-        return _tp_jit("step_dense_decode_tp", body, mesh,
-                       n_buf_in=2, n_rest_in=8, n_buf_out=2, n_rest_out=2)
-
-    def body(params, kc, vc, toks, pos, key_data, temps, top_ks, top_ps):
-        return run(params, kc, vc, toks, pos, key_data, temps, top_ks,
-                   top_ps)
-
-    return _tp_jit("step_dense_decode_tp", body, mesh,
-                   n_buf_in=2, n_rest_in=6, n_buf_out=2, n_rest_out=2)
 
 
 def _validate_paged_build(stages, cfg: GPTConfig, max_len: int,
@@ -1652,10 +1488,11 @@ def _paged_gather(kc, li, table, n_heads):
     block ids, ``[NB]`` (one sequence) or ``[S, NB]`` (one per slot);
     ``n_heads``: the heads in a pool row. Returns ``[..., H, NB*bs, dh]``
     with position ``p`` of the sequence at flattened row index ``p`` —
-    EXACTLY the dense layout's row order, so the attention math downstream
-    is unchanged and the trailing garbage rows (trash-block entries past
-    the allocated span) are removed by the same position mask that already
-    hides not-yet-written dense rows. :class:`QuantKV` buffers dequantize
+    EXACTLY a contiguous cache row's order (the cached decoder's), so the
+    attention math downstream is unchanged and the trailing garbage rows
+    (trash-block entries past the allocated span) are removed by the same
+    position mask that hides not-yet-written rows. :class:`QuantKV`
+    buffers dequantize
     (``data * scale``, f32) so the downstream einsums see ordinary rows."""
     buf = kc[li]
     quant = isinstance(buf, QuantKV)
@@ -1717,8 +1554,7 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     the request's key stream advances exactly once, at the same point as
     its solo decode).
 
-    Retraces per distinct chunk length (like :func:`make_slot_prefill`
-    retraces per prompt length). Bit-exactness vs the solo
+    Retraces per distinct chunk length. Bit-exactness vs the solo
     ``make_cached_decoder`` holds for f32 caches: the chunk reads earlier
     K/V back out of the cache, so a bf16 cache rounds where the solo
     monolithic prefill attends fresh f32 K/V — the one place the paged
@@ -1727,9 +1563,22 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
 
     ``kc``/``vc`` (one ``[n_blocks+1, block_size, H*dh]`` buffer a layer,
     ``serve/slots.py::PagedKVPool``) are donated, every leaf — the engine
-    always threads the returned buffers back into the pool.
-    ``adapters=True`` appends the traced ``(bank, aid)`` multi-tenant
-    args (:func:`make_slot_prefill`'s adapter notes apply).
+    always threads the returned buffers back into the pool, and donation
+    lets XLA write the rows in place.
+
+    ``adapters=True`` builds the multi-tenant variant: two TRACED args
+    append to the signature — the stacked adapter ``bank`` pytree and the
+    request's bank-row index ``aid`` — and every block's q/v projection
+    adds the gathered low-rank delta (:func:`_dense_qkv`). One static
+    BOOL in the memo key: bank contents, row count and rank are all data,
+    so adapter registration/hot-swap never retraces and any adapter mix
+    shares this one program.
+
+    With ``cfg.n_tensor_parallel > 1`` (pass the ``mesh``): the same math
+    inside ``shard_map`` — QKV on the local ``H/tp`` heads, K/V landing in
+    this shard's lanes of the head-sharded pool, the attention/MLP reduces
+    of :func:`_tp_attn_tail` — with ``params`` in the
+    :func:`pack_tp_serve_params` layout.
     """
     _validate_paged_build(stages, cfg, max_len, block_size,
                           "make_paged_prefill_chunk", cache_dtype)
@@ -1843,25 +1692,30 @@ def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
     tables [S, NB], key_data [S, 2], temps [S], top_ks [S], top_ps [S]) ->
     (kc, vc, next_toks [S], next_key_data [S, 2])``.
 
-    The block-gather twin of :func:`make_slot_decode_step`: ONE batched
-    token step over all slots, but each slot's K/V row is assembled from
-    its block table (:func:`_paged_gather`) instead of a dense pool
-    row, and its new K/V lands via a per-slot scatter into physical block
-    ``tables[s, pos // bs]`` at offset ``pos % bs``. Values for live
-    positions are bit-identical to the dense layout's (same numbers,
-    different storage), the mask removes everything else, so the PR-5
-    bit-exactness anchor carries over unchanged.
+    ONE batched token step over ALL ``n_slots`` slots — static shapes, so
+    a single compiled program serves every tick regardless of occupancy.
+    Each slot consumes its carried token at its own position, lands its new
+    K/V via a per-slot scatter into physical block ``tables[s, pos // bs]``
+    at offset ``pos % bs``, attends the row assembled from its block table
+    (:func:`_paged_gather`) masked to ``<= pos``, and samples with its own
+    params and key stream (``vmap`` of :func:`_sample_dyn` — loop
+    semantics, per-slot draws equal the unbatched calls). Values for live
+    positions are the cached decoder's (same numbers, different storage)
+    and the mask removes everything else: the bit-exactness anchor
+    continuous batching rests on.
 
-    The dense pool's stale-write safety argument does NOT carry over: a
-    non-decoding slot's table entries may alias blocks reused by a live
+    A non-decoding slot's table entries may alias blocks reused by a live
     request, so the ENGINE routes those slots' tick inputs to the trash
     block (``pos = 0``, all-trash table) — their garbage K/V lands where
-    no real table points. ``kc``/``vc`` are donated (one in-place pool
-    update per tick).
+    no real table points, and the engine discards their tokens host-side.
+    ``kc``/``vc`` are donated (one in-place pool update per tick).
 
     With ``cfg.n_tensor_parallel > 1`` (pass the ``mesh``): the shard_map
-    twin over the head-sharded block pool (:func:`make_slot_prefill`'s TP
-    notes apply — block tables and positions stay replicated host inputs).
+    twin over the head-sharded block pool
+    (:func:`make_paged_prefill_chunk`'s TP and adapter notes apply — block
+    tables and positions stay replicated host inputs; ``adapters=True``
+    appends ``(bank, aids [S])``, each slot gathering its OWN adapter's
+    factors by index).
 
     ``kernel="fused"`` swaps the gather-then-dense attention for the
     single-pass Pallas paged-attention kernel (flash-decode layout,
@@ -2076,10 +1930,11 @@ def make_adapter_bank_update():
 # Rejected-tail K/V: verify writes all K positions before it knows how
 # many survive. In-budget positions land in the slot's own rows/blocks and
 # are overwritten by the next tick before they can be attended (the same
-# trailing-write argument the slot pools rest on); positions beyond the
-# slot's remaining token budget (j >= valid_n) are routed to a trash sink —
-# the dense layout's never-live row max_len-1, the paged pool's trash
-# block 0 — so they cannot land past the reservation or in a neighbour.
+# trailing-write argument the draft's slot rows rest on); positions beyond
+# the slot's remaining token budget (j >= valid_n) are routed to a trash
+# sink — the paged pool's trash block 0 for the target, the never-live row
+# max_len-1 for the draft — so they cannot land past the reservation or in
+# a neighbour.
 #
 # Sampled modes (temperature > 0) use standard residual-rejection
 # sampling: accept draft token d with probability min(1, p(d)/q(d)) on the
@@ -2198,7 +2053,7 @@ def make_slot_propose(stages, cfg: GPTConfig, max_len: int, spec_k: int,
     key_data [S, 2], temps [S], top_ks [S], top_ps [S]) -> (kc, vc,
     drafts [S, K], draft_rows [S, K, V], key_data [S, 2])``.
 
-    ``spec_k`` sequential draft decode steps over the draft's DENSE slot
+    ``spec_k`` sequential draft decode steps over the draft's slot-row
     pool, fused into ONE compiled ``lax.scan`` — one dispatch proposes the
     whole tick's draft tokens (plus their raw log-prob rows, which the
     sampled verify's rejection test needs). Step j consumes the carried
@@ -2212,11 +2067,7 @@ def make_slot_propose(stages, cfg: GPTConfig, max_len: int, spec_k: int,
     _validate_slot_build(stages, cfg, max_len, "make_slot_propose",
                          cache_dtype)
     _check_spec_k(spec_k, "make_slot_propose")
-    if cfg.n_tensor_parallel > 1:
-        raise ValueError(
-            "make_slot_propose runs the draft model single-device "
-            "(replicated under a TP target): build the draft with "
-            "n_tensor_parallel=1")
+    _refuse_tp_draft(cfg, "make_slot_propose")
     H = cfg.n_heads
     key_ = ("slot_propose", cfg, max_len, spec_k)
     return _memo_build(key_, lambda: _build_slot_propose(H, spec_k,
@@ -2233,7 +2084,7 @@ def _build_slot_propose(H, K, ml):
             kc, vc, tok, kd = carry
             p = jnp.minimum(pos + j, ml - 1)
             kc, vc, rows = _slot_decode_fwd(blocks, embed, head, kc, vc,
-                                            tok, p, H, _dense_attn_tail)
+                                            tok, p, H)
             nxt, kd = jax.vmap(_sample_dyn)(rows, kd, temps, top_ks,
                                             top_ps)
             return (kc, vc, nxt, kd), (nxt, rows)
@@ -2244,153 +2095,6 @@ def _build_slot_propose(H, K, ml):
                 jnp.moveaxis(rows, 0, 1), kd2)
 
     return propose
-
-
-def _slot_verify_fwd(blocks, embed, head, kc, vc, xs, qpos, wpos, H, tail,
-                     ab_at=None):
-    """K-tokens-per-slot verify forward over the dense slot pool (``xs``:
-    [S, K] input tokens, ``qpos``: [S, K] query positions, ``wpos``:
-    [S, K] K/V write positions — ``qpos`` in budget, the never-live trash
-    row past it). Per-position math is exactly the decode tick's (same
-    projections, same masked-row softmax), which is what extends the PR-5
-    bit-exactness anchor to speculative verify."""
-    S, K = xs.shape
-    pe = jnp.take(embed["pos"], qpos.reshape(-1),
-                  axis=0).reshape(S, K, -1)
-    h = embedding_lookup(embed["tok"], xs) + pe              # [S, K, d]
-    ml = kc.shape[-2]
-    live = (jnp.arange(ml)[None, None, None, :]
-            <= qpos[:, None, :, None])                       # [S,1,K,ml]
-    for li, bp in enumerate(blocks):
-        q, knew, vnew = _dense_qkv(                          # [S, H, K, dh]
-            bp, h, H, None if ab_at is None else ab_at(li))
-        dh = q.shape[-1]          # the projected head dim (TP-safe scale)
-
-        def upd(cache, new, wp):
-            return cache.at[:, wp, :].set(new)               # [H, ml, dh]
-
-        kci = jax.vmap(upd)(kc[li], knew.astype(kc.dtype), wpos)
-        vci = jax.vmap(upd)(vc[li], vnew.astype(vc.dtype), wpos)
-        kc = kc.at[li].set(kci)
-        vc = vc.at[li].set(vci)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kci) / math.sqrt(dh)
-        scores = jnp.where(live, scores, -jnp.inf)
-        a = jnp.einsum("bhqk,bhkd->bhqd",
-                       jax.nn.softmax(scores, axis=-1), vci)
-        h = tail(bp, h, a)
-    return kc, vc, _head_logprobs(head, h)                   # [S, K, V]
-
-
-def make_slot_verify_step(stages, cfg: GPTConfig, max_len: int, spec_k: int,
-                          cache_dtype=None, mesh=None,
-                          adapters: bool = False):
-    """Target verify tick (dense layout): ``verify(params, kc, vc,
-    toks [S], pos [S], drafts [S, K], draft_rows [S, K, V],
-    valid_n [S], key_data [S, 2], temps [S], top_ks [S], top_ps [S]) ->
-    (kc, vc, toks [S, K], n_acc [S], key_data [S, 2])``.
-
-    ONE batched forward scores all ``spec_k`` positions of every slot
-    (inputs ``[t0, d0, .., d_{K-2}]`` at positions ``pos .. pos+K-1``) and
-    runs :func:`_spec_accept` per slot; ``valid_n`` is the slot's clamp
-    ``min(spec_k, remaining token budget)`` (0 for non-decoding slots),
-    bounding both emission and which positions write real K/V (the rest go
-    to the trash row). ``kc``/``vc`` are donated.
-
-    With ``cfg.n_tensor_parallel > 1`` (pass the ``mesh``): the shard_map
-    twin — head-sharded QKV/O over the head-sharded pool, rows re-closed
-    across the model axis before acceptance, so every shard accepts the
-    same prefix."""
-    _validate_slot_build(stages, cfg, max_len, "make_slot_verify_step",
-                         cache_dtype)
-    _check_spec_k(spec_k, "make_slot_verify_step")
-    mesh = _validate_tp_serve(cfg, mesh, "make_slot_verify_step")
-    H = cfg.n_heads
-    key_ = ("slot_verify", cfg, max_len, spec_k, mesh, adapters)
-    if cfg.n_tensor_parallel > 1:
-        return _memo_build(key_, lambda: _build_slot_verify_tp(
-            cfg, spec_k, max_len, mesh, adapters))
-    return _memo_build(key_, lambda: _build_slot_verify(H, spec_k,
-                                                        max_len, adapters))
-
-
-def _verify_positions(pos, valid_n, K, ml):
-    """Query/write position plan shared by the dense verify builds:
-    queries at ``pos + j`` (clamped in-table), writes routed to the
-    never-live trash row ``ml - 1`` once past the slot's budget."""
-    j = jnp.arange(K)[None, :]
-    qpos = jnp.minimum(pos[:, None] + j, ml - 1)
-    wpos = jnp.where(j < valid_n[:, None], qpos, ml - 1)
-    return qpos, wpos
-
-
-def _build_slot_verify(H, K, ml, adapters=False):
-    def run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-            key_data, temps, top_ks, top_ps, ab_at=None):
-        embed, blocks, head = _merged_stage_trees(params)
-        xs = jnp.concatenate([toks[:, None], drafts[:, :-1]], axis=1)
-        qpos, wpos = _verify_positions(pos, valid_n, K, ml)
-        kc, vc, rows = _slot_verify_fwd(blocks, embed, head, kc, vc, xs,
-                                        qpos, wpos, H, _dense_attn_tail,
-                                        ab_at)
-        toks2, n_acc, kd2 = _spec_accept_rows(
-            rows, drafts, draft_rows, valid_n, key_data, temps, top_ks,
-            top_ps)
-        return kc, vc, toks2, n_acc, kd2
-
-    if adapters:
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def verify(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-                   key_data, temps, top_ks, top_ps, bank, aids):
-            return run(params, kc, vc, toks, pos, drafts, draft_rows,
-                       valid_n, key_data, temps, top_ks, top_ps,
-                       _adapter_layers(bank, aids))
-
-        return verify
-
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def verify(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-               key_data, temps, top_ks, top_ps):
-        return run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-                   key_data, temps, top_ks, top_ps)
-
-    return verify
-
-
-def _build_slot_verify_tp(cfg, K, ml, mesh, adapters=False):
-    tp = cfg.n_tensor_parallel
-    tail = functools.partial(_tp_attn_tail, overlap=cfg.overlap)
-    H_loc = cfg.n_heads // tp
-
-    def run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-            key_data, temps, top_ks, top_ps, ab_at=None):
-        blocks, embed, head = _tp_local_trees(params)
-        xs = jnp.concatenate([toks[:, None], drafts[:, :-1]], axis=1)
-        qpos, wpos = _verify_positions(pos, valid_n, K, ml)
-        kc, vc, rows = _slot_verify_fwd(blocks, embed, head, kc, vc, xs,
-                                        qpos, wpos, H_loc, tail, ab_at)
-        rows = _close_rows(rows)
-        toks2, n_acc, kd2 = _spec_accept_rows(
-            rows, drafts, draft_rows, valid_n, key_data, temps, top_ks,
-            top_ps)
-        return kc, vc, toks2, n_acc, kd2
-
-    if adapters:
-        def body(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-                 key_data, temps, top_ks, top_ps, bank, aids):
-            return run(params, kc, vc, toks, pos, drafts, draft_rows,
-                       valid_n, key_data, temps, top_ks, top_ps,
-                       _tp_adapter_layers(bank, aids, tp))
-
-        return _tp_jit("verify_dense_tp", body, mesh,
-                       n_buf_in=2, n_rest_in=11, n_buf_out=2, n_rest_out=3)
-
-    def body(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-             key_data, temps, top_ks, top_ps):
-        return run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
-                   key_data, temps, top_ks, top_ps)
-
-    return _tp_jit("verify_dense_tp", body, mesh,
-                   n_buf_in=2, n_rest_in=9, n_buf_out=2, n_rest_out=3)
 
 
 def _paged_verify_fwd(blocks, embed, head, kc, vc, xs, qpos, wphys, woff,
@@ -2431,20 +2135,27 @@ def make_paged_verify_step(stages, cfg: GPTConfig, max_len: int,
                            block_size: int, spec_k: int, cache_dtype=None,
                            mesh=None, kernel: str = "dense",
                            adapters: bool = False):
-    """Target verify tick (paged layout): ``verify(params, kc, vc,
+    """Target verify tick: ``verify(params, kc, vc,
     toks [S], pos [S], drafts [S, K], draft_rows [S, K, V],
     valid_n [S], tables [S, NB], key_data [S, 2], temps [S], top_ks [S],
     top_ps [S]) -> (kc, vc, toks [S, K], n_acc [S], key_data [S, 2])``.
 
-    The block-gather twin of :func:`make_slot_verify_step`: per-position
+    ONE batched forward scores all ``spec_k`` positions of every slot
+    (inputs ``[t0, d0, .., d_{K-2}]`` at positions ``pos .. pos+K-1``) and
+    runs :func:`_spec_accept_rows`; ``valid_n`` is the slot's clamp
+    ``min(spec_k, remaining token budget)`` (0 for non-decoding slots),
+    bounding both emission and which positions write real K/V. Per-position
     physical blocks come from the slot's table (``tables[s, (pos+j)//bs]``
     at offset ``(pos+j) % bs``), with positions past ``valid_n`` routed to
     the pool's trash block 0 — a rejected tail (or a non-decoding slot)
     can neither overrun the slot's reservation nor touch a neighbour's
     blocks. The engine must have ``ensure_writable``'d positions
     ``pos .. pos+valid_n-1`` first (same contract as the decode tick).
-    ``kc``/``vc`` are donated. TP: :func:`make_slot_verify_step`'s notes
-    apply. ``kernel="fused"`` runs the K-token variant of the Pallas
+    ``kc``/``vc`` are donated. With ``cfg.n_tensor_parallel > 1`` (pass the
+    ``mesh``): the shard_map twin — head-sharded QKV/O over the
+    head-sharded pool, rows re-closed across the model axis before
+    acceptance, so every shard accepts the same prefix.
+    ``kernel="fused"`` runs the K-token variant of the Pallas
     paged-attention kernel instead of gather-then-dense (same greedy
     bit-exactness contract as :func:`make_paged_decode_step`)."""
     _validate_paged_build(stages, cfg, max_len, block_size,
@@ -2552,91 +2263,40 @@ def _build_paged_verify_step_tp(cfg, K, ml, bs, dh, mesh, kernel="dense",
                    n_buf_in=2, n_rest_in=10, n_buf_out=2, n_rest_out=3)
 
 
-def _check_spec_tick_build(cfg: GPTConfig, draft_cfg: GPTConfig,
-                           caller: str) -> None:
-    if cfg.n_tensor_parallel > 1:
-        raise ValueError(
-            f"{caller} fuses the single-device tick only — a TP target "
-            f"runs propose and verify as separate dispatches (the verify "
-            f"is a shard_map program; see InferenceEngine)")
-    if draft_cfg.vocab != cfg.vocab:
-        raise ValueError(
-            f"{caller}: draft vocab {draft_cfg.vocab} != target vocab "
-            f"{cfg.vocab}")
-
-
-def make_slot_spec_tick(stages, cfg: GPTConfig, draft_stages,
-                        draft_cfg: GPTConfig, max_len: int, spec_k: int,
-                        cache_dtype=None, adapters: bool = False):
-    """The FUSED speculative tick (dense layout, single-device targets):
-    ``tick(dparams, dkc, dvc, params, kc, vc, toks [S], pos [S],
-    valid_n [S], draft_key_data [S, 2], key_data [S, 2], temps [S],
-    top_ks [S], top_ps [S]) -> (dkc, dvc, kc, vc, toks [S, K],
-    n_acc [S], key_data, draft_key_data)``.
+def make_paged_spec_tick(stages, cfg: GPTConfig, draft_stages,
+                         draft_cfg: GPTConfig, max_len: int,
+                         block_size: int, spec_k: int, cache_dtype=None,
+                         kernel: str = "dense", adapters: bool = False):
+    """The FUSED speculative tick (single-device targets): ``tick(dparams,
+    dkc, dvc, params, kc, vc, toks [S], pos [S], valid_n [S],
+    tables [S, NB], draft_key_data [S, 2], key_data [S, 2], temps [S],
+    top_ks [S], top_ps [S]) -> (dkc, dvc, kc, vc, toks [S, K], n_acc [S],
+    key_data, draft_key_data)``.
 
     One compiled program runs the draft propose scan AND the batched
     target verify — ONE dispatch per speculative tick instead of two, and
     the ``[S, K, V]`` draft log-prob rows never materialize as a program
-    output (they flow straight into the acceptance test inside the fused
-    program). Exactly :func:`make_slot_propose` composed with
-    :func:`make_slot_verify_step`, so the greedy bit-exactness contract
-    carries over unchanged. All four pool buffers are donated.
+    output (they flow straight into the acceptance test). Exactly
+    :func:`make_slot_propose` over the draft's slot rows composed with
+    :func:`make_paged_verify_step` over the target's paged pool
+    (``kernel="fused"`` routes it through the Pallas paged-attention
+    kernel), so the greedy bit-exactness contract carries over unchanged.
+    All four buffers are donated.
 
     With ``adapters=True`` the tick takes trailing ``(bank, aids)`` and
     forwards them to the VERIFY side only: the draft proposer stays the
     base model (a wrong proposal only costs acceptance rate, never
     correctness — verify's adapted rows decide every emitted token)."""
-    _check_spec_tick_build(cfg, draft_cfg, "make_slot_spec_tick")
-    propose = make_slot_propose(draft_stages, draft_cfg, max_len, spec_k,
-                                cache_dtype)
-    verify = make_slot_verify_step(stages, cfg, max_len, spec_k,
-                                   cache_dtype, adapters=adapters)
-
-    def build():
-        def run(dparams, dkc, dvc, params, kc, vc, toks, pos, valid_n,
-                dkd, kd, temps, top_ks, top_ps, extra=()):
-            dkc, dvc, drafts, qrows, dkd2 = propose(
-                dparams, dkc, dvc, toks, pos, dkd, temps, top_ks, top_ps)
-            kc, vc, otoks, nacc, kd2 = verify(
-                params, kc, vc, toks, pos, drafts, qrows, valid_n, kd,
-                temps, top_ks, top_ps, *extra)
-            return dkc, dvc, kc, vc, otoks, nacc, kd2, dkd2
-
-        if adapters:
-            @functools.partial(jax.jit, donate_argnums=(1, 2, 4, 5))
-            def tick(dparams, dkc, dvc, params, kc, vc, toks, pos,
-                     valid_n, dkd, kd, temps, top_ks, top_ps, bank, aids):
-                return run(dparams, dkc, dvc, params, kc, vc, toks, pos,
-                           valid_n, dkd, kd, temps, top_ks, top_ps,
-                           (bank, aids))
-
-            return tick
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 4, 5))
-        def tick(dparams, dkc, dvc, params, kc, vc, toks, pos, valid_n,
-                 dkd, kd, temps, top_ks, top_ps):
-            return run(dparams, dkc, dvc, params, kc, vc, toks, pos,
-                       valid_n, dkd, kd, temps, top_ks, top_ps)
-
-        return tick
-
-    return _memo_build(("slot_spec_tick", cfg, draft_cfg, max_len, spec_k,
-                        adapters), build)
-
-
-def make_paged_spec_tick(stages, cfg: GPTConfig, draft_stages,
-                         draft_cfg: GPTConfig, max_len: int,
-                         block_size: int, spec_k: int, cache_dtype=None,
-                         kernel: str = "dense", adapters: bool = False):
-    """Paged twin of :func:`make_slot_spec_tick`: ``tick(dparams, dkc,
-    dvc, params, kc, vc, toks, pos, valid_n, tables [S, NB], dkd, kd,
-    temps, top_ks, top_ps) -> (dkc, dvc, kc, vc, toks [S, K], n_acc [S],
-    key_data, draft_key_data)`` — the draft pool stays the dense slot
-    layout (the engine's draft discipline), the target side is the
-    block-gather :func:`make_paged_verify_step` (``kernel="fused"``
-    routes it through the Pallas paged-attention kernel)."""
-    _check_spec_tick_build(cfg, draft_cfg, "make_paged_spec_tick")
-    # the draft pool is dense slot rows: a quantized TARGET dtype falls
+    if cfg.n_tensor_parallel > 1:
+        raise ValueError(
+            "make_paged_spec_tick fuses the single-device tick only — a TP "
+            "target runs propose and verify as separate dispatches (the "
+            "verify is a shard_map program; see InferenceEngine)")
+    if draft_cfg.vocab != cfg.vocab:
+        raise ValueError(
+            f"make_paged_spec_tick: draft vocab {draft_cfg.vocab} != target "
+            f"vocab {cfg.vocab}")
+    # the draft's slot rows carry no scales: a quantized TARGET dtype falls
     # back to f32 for the draft (the engine builds its draft buffers with
     # the same rule)
     draft_cd = None if _is_quantized_dtype(cache_dtype) else cache_dtype
@@ -2687,16 +2347,13 @@ def make_paged_spec_tick(stages, cfg: GPTConfig, draft_stages,
 DECODE_BUILDERS = {
     "make_cached_decoder": make_cached_decoder,
     "make_slot_prefill": make_slot_prefill,
-    "make_slot_decode_step": make_slot_decode_step,
     "make_paged_prefill_chunk": make_paged_prefill_chunk,
     "make_paged_decode_step": make_paged_decode_step,
     "make_paged_block_copy": make_paged_block_copy,
     "make_paged_block_write": make_paged_block_write,
     "make_adapter_bank_update": make_adapter_bank_update,
     "make_slot_propose": make_slot_propose,
-    "make_slot_verify_step": make_slot_verify_step,
     "make_paged_verify_step": make_paged_verify_step,
-    "make_slot_spec_tick": make_slot_spec_tick,
     "make_paged_spec_tick": make_paged_spec_tick,
 }
 

@@ -8,13 +8,12 @@ latency accounting (PAPERS.md: "Fine-Tuning and Serving Gemma on Cloud TPU").
 This package is that layer, on top of the existing KV-cache model ops,
 checkpoint restore, and the telemetry registry:
 
-- :mod:`.slots` — the KV-cache pools: :class:`KVCachePool` (dense
-  ``n_slots`` static-shape rows, the PR-5 baseline) and
-  :class:`PagedKVPool` (block-table paged pool with refcounted blocks,
-  prefix sharing via a registered-prompt registry, copy-on-write before
-  divergent writes, and reservation-backed on-demand allocation — the
-  layout that makes concurrency a function of actual tokens resident, not
-  worst-case rows), both on the invariant-guarded free-list discipline;
+- :mod:`.slots` — the KV-cache pool: :class:`PagedKVPool` (block-table
+  paged pool with refcounted blocks, prefix sharing via a
+  registered-prompt registry, copy-on-write before divergent writes, and
+  reservation-backed on-demand allocation — the layout that makes
+  concurrency a function of actual tokens resident, not worst-case rows)
+  on the invariant-guarded free-list discipline;
 - :mod:`.request` — the request object: prompt, per-request sampling params
   (greedy / top-k / top-p with an independent seeded key stream),
   ``max_new_tokens`` / EOS termination, and latency timestamps;
@@ -25,7 +24,7 @@ checkpoint restore, and the telemetry registry:
   ``step()`` (one tick: admit, at most one prefill CHUNK, then ONE batched
   decode program regardless of occupancy — chunked prefill keeps a long
   prompt from freezing in-flight decodes), ``drain()``, streaming
-  per-token callbacks; ``kv_layout="paged"|"dense"`` picks the pool;
+  per-token callbacks;
 - :mod:`.simulator` — open-loop traffic simulator: seeded Poisson arrivals
   at a configurable rate driving the engine (``cli.py --serve-sim``);
 - :mod:`.metrics` — serving telemetry on the PR-4 ``MetricsRegistry``:
@@ -106,7 +105,6 @@ from simple_distributed_machine_learning_tpu.serve.simulator import (  # noqa: F
     simulate,
 )
 from simple_distributed_machine_learning_tpu.serve.slots import (  # noqa: F401
-    KVCachePool,
     PagedKVPool,
 )
 from simple_distributed_machine_learning_tpu.serve.supervisor import (  # noqa: F401

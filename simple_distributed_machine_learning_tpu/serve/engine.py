@@ -1,12 +1,13 @@
 """The continuous-batching inference engine: submit / step / drain.
 
-:class:`InferenceEngine` is the serving API over a model's slot-wise ops,
-the KV-cache pool and the FCFS scheduler. The paged layout takes the
+:class:`InferenceEngine` is the serving API over a model's serving
+programs, the paged KV-cache pool and the FCFS scheduler. It takes the
 cache's shape and its two compiled programs from the model
-(``cfg.paged_serving(...)`` -> ``models/gpt.py::PagedServing``): GPT
-(``models/gpt.py``) is attention in every block and nothing else; a model
-with state-space layers (``models/jamba.py``) also has a recurrent buffer
-per slot, which rides beside the K/V pool through both programs.
+(``cfg.paged_serving(...)`` -> ``models/gpt.py::PagedServing``) and nowhere
+else: GPT (``models/gpt.py``) is attention in every block and nothing
+else; a model with state-space layers (``models/jamba.py``) also has a
+recurrent buffer per slot, which rides beside the K/V pool through both
+programs.
 
 - ``submit(prompt, ...) -> Request`` enqueues one sequence with its own
   sampling params and seeded key stream, and returns the live handle
@@ -14,21 +15,17 @@ per slot, which rides beside the K/V pool through both programs.
 - ``step()`` is one *tick*; ``drain()`` ticks until queue and slots are
   empty.
 
-Two KV-cache layouts (``kv_layout``):
-
-- ``"paged"`` (default) — block-table paged pool (``serve/slots.py::
-  PagedKVPool``) with prefix sharing, copy-on-write and CHUNKED prefill:
-  each tick runs at most one prefill chunk (``prefill_chunk`` prompt
-  positions of the oldest still-prefilling request) and then ONE batched
-  block-gather decode step over every decoding slot — a long prompt no
-  longer freezes in-flight requests, and admission is gated on free
-  BLOCKS (the request's worst-case footprint after prefix sharing), not
-  free rows. Non-decoding slots' tick writes are routed to the pool's
-  trash block (see the stale-write note in ``serve/slots.py``).
-- ``"dense"`` — the PR-5 slot-row pool: admission prefills the whole
-  prompt in one shot (``make_slot_prefill``) and every occupied slot
-  decodes each tick. Kept as the measured baseline of
-  ``bench.py --serve``'s paged-vs-dense comparison.
+One KV-cache layout: the block-table paged pool (``serve/slots.py::
+PagedKVPool``) with prefix sharing, copy-on-write and CHUNKED prefill.
+Each tick runs at most one prefill chunk (``prefill_chunk`` prompt
+positions of the oldest still-prefilling request) and then ONE batched
+block-gather decode step over every decoding slot — a long prompt does not
+freeze in-flight requests, and admission is gated on free BLOCKS (the
+request's worst-case footprint after prefix sharing), not free rows.
+Non-decoding slots' tick writes are routed to the pool's trash block (see
+the stale-write note in ``serve/slots.py``). Only a speculative DRAFT
+model keeps one contiguous row per slot (:meth:`InferenceEngine.
+_init_draft_pool`).
 
 Device state is exactly the pool's K/V buffers; everything else (positions,
 last tokens, block tables, key streams, request lifecycle) is host-side
@@ -70,10 +67,7 @@ from simple_distributed_machine_learning_tpu.serve.request import (
 from simple_distributed_machine_learning_tpu.serve.scheduler import (
     FCFSScheduler,
 )
-from simple_distributed_machine_learning_tpu.serve.slots import (
-    KVCachePool,
-    PagedKVPool,
-)
+from simple_distributed_machine_learning_tpu.serve.slots import PagedKVPool
 from simple_distributed_machine_learning_tpu.telemetry import tracing
 
 # sampling-param sentinels (models/gpt.py::_sample_dyn): 0 disables top-k,
@@ -127,18 +121,14 @@ def _seed_key_data(seed: int, fold: int | None = None) -> np.ndarray:
         return np.asarray(jax.random.key_data(key))
 
 
-def _refuse_for_recurrent_state(*, kv_layout, host_cache_blocks,
-                                draft_stages, lint) -> None:
+def _refuse_for_recurrent_state(*, host_cache_blocks, draft_stages,
+                                lint) -> None:
     """A model with per-slot recurrent state serves through the paged pool
     and nothing that was built for K/V blocks alone: each such mechanism is
     refused by name rather than half-done. (``mesh``, ``adapters`` and a
     quantized ``cache_dtype`` reach the model's own ``paged_serving``, which
     refuses them in the same words.)"""
     why = {
-        "kv_layout='dense'": (
-            kv_layout == "dense",
-            "the dense slot-row pool and its whole-prompt prefill are "
-            "GPT's; recurrent state lives in the paged pool"),
         "host_cache_blocks": (
             bool(host_cache_blocks),
             "the host offload tier demotes prefix BLOCKS, and a block "
@@ -169,10 +159,10 @@ class InferenceEngine:
     budget (defaults to ``cfg.seq_len``); ``cache_dtype`` is the pool's
     storage dtype (bf16 halves pool memory, the ``_cache_dtype`` rule).
 
-    Paged knobs (``kv_layout="paged"``): ``block_size`` positions per K/V
-    block; ``n_blocks`` pool capacity (default: the dense pool's capacity,
-    ``n_slots * ceil(max_len/block_size)`` — shrink it to serve more slots
-    than the memory could densely back); ``prefill_chunk`` prompt positions
+    Pool knobs: ``block_size`` positions per K/V block; ``n_blocks`` pool
+    capacity (default ``n_slots * ceil(max_len/block_size)``: every slot
+    can reach ``max_len`` — shrink it to serve more slots than the memory
+    could back at full length); ``prefill_chunk`` prompt positions
     per prefill chunk (``None`` = the whole remaining prompt in one chunk);
     ``attn_kernel`` the decode/verify attention path — ``"dense"``
     (gather-then-dense, the parity anchor) or ``"fused"`` (the Pallas
@@ -182,12 +172,11 @@ class InferenceEngine:
     fp8 where the jnp build has it) stores paged blocks narrow with
     per-row f32 scales (``models/gpt.py::QuantKV``) — roughly 3.6x more
     resident requests per byte than f32 at pinned-tolerance logits, with
-    dequantize fused into both attention paths; paged-only (dense layouts
-    reject it). ``host_cache_blocks > 0`` enables the LRU host-RAM
-    offload tier (evicted prefix blocks demote to host; a router affinity
-    hit on a host-resident prefix starts an async upload landing after
-    ``prefetch_ticks`` ticks — ``serve/slots.py`` "Host offload tier");
-    paged-only as well.
+    dequantize fused into both attention paths. ``host_cache_blocks > 0``
+    enables the LRU host-RAM offload tier (evicted prefix blocks demote to
+    host; a router affinity hit on a host-resident prefix starts an async
+    upload landing after ``prefetch_ticks`` ticks — ``serve/slots.py``
+    "Host offload tier").
 
     Tensor parallelism: build ``cfg`` with ``n_tensor_parallel = tp > 1``
     (the stages stay the UNSHARDED dense build) and pass a ``mesh`` whose
@@ -204,8 +193,8 @@ class InferenceEngine:
     batched target verify instead of a one-token decode, emitting 1..
     ``spec_k`` tokens per slot; greedy requests stay bit-exact vs their
     solo decode (the models/gpt.py speculative-section contract). The
-    draft keeps its own dense slot-pool K/V buffers and per-request key
-    stream regardless of the target layout.
+    draft keeps its own K/V buffers, one contiguous row per slot, and its
+    own per-request key stream.
 
     Multi-tenant adapters: pass ``adapters`` (a
     :class:`~.adapters.AdapterStore` built for this engine's ``n_slots``)
@@ -227,7 +216,7 @@ class InferenceEngine:
     registers none; preemption, journal recovery and fleet handoff
     recompute ``resume_seq`` from position 0, which rebuilds the state.
     What is built for K/V blocks alone is REFUSED at construction with
-    such a model, by name: ``kv_layout="dense"``, ``host_cache_blocks``,
+    such a model, by name: ``host_cache_blocks``,
     ``draft_stages`` (speculation), ``adapters``, ``mesh`` (tensor
     parallelism), ``lint=True`` (the analyzer's registry builds GPT's
     programs) and a quantized ``cache_dtype``.
@@ -235,7 +224,7 @@ class InferenceEngine:
 
     def __init__(self, stages, cfg, *, params=None, n_slots: int = 4,
                  max_len: int | None = None, cache_dtype=None,
-                 kv_layout: str = "paged", block_size: int = 16,
+                 block_size: int = 16,
                  n_blocks: int | None = None, prefill_chunk: int | None = None,
                  host_cache_blocks: int = 0, prefetch_ticks: int = 1,
                  attn_kernel: str = "dense",
@@ -245,33 +234,15 @@ class InferenceEngine:
                  mesh=None, draft_stages=None, draft_cfg=None,
                  spec_k: int = 0, trace=None, flight=None,
                  adapters=None) -> None:
-        if kv_layout not in ("paged", "dense"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'dense', got {kv_layout!r}")
         if attn_kernel not in ("dense", "fused"):
             raise ValueError(
                 f"attn_kernel must be 'dense' (gather-then-dense "
                 f"attention) or 'fused' (the Pallas paged-attention "
                 f"kernel), got {attn_kernel!r}")
-        if attn_kernel == "fused" and kv_layout != "paged":
-            raise ValueError(
-                "attn_kernel='fused' is the paged pool's kernel (block-"
-                "table gather fused with attention); the dense layout has "
-                "no block tables — use kv_layout='paged'")
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1 (or None for whole-prompt "
                 f"chunks), got {prefill_chunk}")
-        if kv_layout == "dense" and (prefill_chunk is not None
-                                     or n_blocks is not None):
-            raise ValueError(
-                "prefill_chunk/n_blocks are paged-pool knobs; the dense "
-                "layout prefills whole prompts into fixed rows")
-        if kv_layout == "dense" and host_cache_blocks:
-            raise ValueError(
-                "host_cache_blocks is a paged-pool knob (the host offload "
-                "tier demotes evicted prefix BLOCKS); the dense layout has "
-                "no blocks to demote — use kv_layout='paged'")
         if (draft_stages is None) != (draft_cfg is None):
             raise ValueError(
                 "speculative decoding needs BOTH draft_stages and "
@@ -291,12 +262,11 @@ class InferenceEngine:
                 f"per slot — the never-refuse sizing)")
         if cfg.recurrent_state:
             _refuse_for_recurrent_state(
-                kv_layout=kv_layout, host_cache_blocks=host_cache_blocks,
+                host_cache_blocks=host_cache_blocks,
                 draft_stages=draft_stages, lint=lint)
         self._adapters = adapters
         self.cfg = cfg
         self.stages = stages       # kept for the analyzer's program registry
-        self.kv_layout = kv_layout
         self.attn_kernel = attn_kernel
         self.prefill_chunk = prefill_chunk
         self.params = (params if params is not None
@@ -309,81 +279,50 @@ class InferenceEngine:
         self.draft_stages = draft_stages   # for the analyzer's registry
         self.draft_cfg = draft_cfg
         adp = adapters is not None
-        if kv_layout == "paged":
-            # the model's cache layout and its two programs (models/gpt.py
-            # ::PagedServing); everything below the pool is the model's
-            serving = cfg.paged_serving(
-                stages, self.max_len, block_size, cache_dtype, mesh=mesh,
-                kernel=attn_kernel, adapters=adp)
-            n_layers = serving.kv_layers
-            self.pool = PagedKVPool(n_layers, n_slots, serving.kv_heads,
-                                    self.max_len, serving.head_dim,
-                                    cache_dtype,
-                                    block_size=block_size, n_blocks=n_blocks,
-                                    tp=self.tp,
-                                    host_cache_blocks=host_cache_blocks,
-                                    prefetch_ticks=prefetch_ticks,
-                                    state_shapes=serving.state_shapes)
-            self._chunk_prefill = serving.chunk_prefill
-            self._decode = serving.decode
-            self._pack_chunk = serving.pack_chunk
-            self._pack_decode = serving.pack_decode
-            # the model's programs keep the newest tokens on the device
-            # (PagedServing.ahead): the tick is _tick_ahead's, and _ahead
-            # the decode it has dispatched for the next one
-            self._dispatch_ahead = serving.ahead
-            self._ahead = None
-            from simple_distributed_machine_learning_tpu.models.gpt import (
-                SEAT_NONE,
-                SEAT_SAMPLE,
-                make_paged_block_copy,
-            )
-            self._copy_block = make_paged_block_copy()
-            self._seat_none, self._seat_sample = SEAT_NONE, SEAT_SAMPLE
-            if self.speculative:
-                from simple_distributed_machine_learning_tpu.models.gpt import (  # noqa: E501
-                    make_paged_verify_step,
-                )
-                self._verify = make_paged_verify_step(
-                    stages, cfg, self.max_len, block_size, spec_k,
-                    cache_dtype, mesh=mesh, kernel=attn_kernel,
-                    adapters=adp)
-        else:
-            from simple_distributed_machine_learning_tpu.models.gpt import (
-                make_slot_decode_step,
-                make_slot_prefill,
-                make_slot_verify_step,
-            )
-            n_layers = sum(len(p["blocks"]) for p in self.params)
-            head_dim = cfg.d_model // cfg.n_heads
-            self.pool = KVCachePool(n_layers, n_slots, cfg.n_heads,
-                                    self.max_len, head_dim, cache_dtype,
-                                    tp=self.tp)
-            self._prefill = make_slot_prefill(stages, cfg, self.max_len,
-                                              cache_dtype, mesh=mesh,
-                                              adapters=adp)
-            self._decode = make_slot_decode_step(stages, cfg, self.max_len,
-                                                 cache_dtype, mesh=mesh,
-                                                 adapters=adp)
-            if self.speculative:
-                self._verify = make_slot_verify_step(
-                    stages, cfg, self.max_len, spec_k, cache_dtype,
-                    mesh=mesh, adapters=adp)
+        # the model's cache layout and its two programs (models/gpt.py
+        # ::PagedServing); everything below the pool is the model's
+        serving = cfg.paged_serving(
+            stages, self.max_len, block_size, cache_dtype, mesh=mesh,
+            kernel=attn_kernel, adapters=adp)
+        self._n_layers = serving.kv_layers
+        self.pool = PagedKVPool(self._n_layers, n_slots, serving.kv_heads,
+                                self.max_len, serving.head_dim, cache_dtype,
+                                block_size=block_size, n_blocks=n_blocks,
+                                tp=self.tp,
+                                host_cache_blocks=host_cache_blocks,
+                                prefetch_ticks=prefetch_ticks,
+                                state_shapes=serving.state_shapes)
+        self._chunk_prefill = serving.chunk_prefill
+        self._decode = serving.decode
+        self._pack_chunk = serving.pack_chunk
+        self._pack_decode = serving.pack_decode
+        # the model's programs keep the newest tokens on the device
+        # (PagedServing.ahead): the tick is _tick_ahead's, and _ahead
+        # the decode it has dispatched for the next one
+        self._dispatch_ahead = serving.ahead
+        self._ahead = None
+        from simple_distributed_machine_learning_tpu.models.gpt import (
+            SEAT_NONE,
+            SEAT_SAMPLE,
+            make_paged_block_copy,
+        )
+        self._copy_block = make_paged_block_copy()
+        self._seat_none, self._seat_sample = SEAT_NONE, SEAT_SAMPLE
         if self.speculative:
             if draft_cfg.vocab != cfg.vocab:
                 raise ValueError(
                     f"draft vocab {draft_cfg.vocab} != target vocab "
                     f"{cfg.vocab} — the draft proposes target token ids")
-            # the draft pool is dense slot rows (no per-block scales), so a
+            # the draft keeps one row per slot (no per-block scales), so a
             # quantized TARGET dtype falls back to f32 for the draft — the
             # draft cache is small by design, and its rows feed proposals
             # only (acceptance always re-scores on the target)
             from simple_distributed_machine_learning_tpu.models.gpt import (
                 _is_quantized_dtype,
                 make_paged_spec_tick,
+                make_paged_verify_step,
                 make_slot_prefill,
                 make_slot_propose,
-                make_slot_spec_tick,
             )
             self._draft_cache_dtype = (None if _is_quantized_dtype(
                 cache_dtype) else cache_dtype)
@@ -396,19 +335,18 @@ class InferenceEngine:
             if self.tp == 1:
                 # single-device targets run the FUSED tick: one dispatch
                 # per speculative tick, draft rows never leave the device
-                self._spec_fused = (
-                    make_paged_spec_tick(stages, cfg, draft_stages,
-                                         draft_cfg, self.max_len,
-                                         block_size, spec_k, cache_dtype,
-                                         kernel=attn_kernel, adapters=adp)
-                    if kv_layout == "paged" else
-                    make_slot_spec_tick(stages, cfg, draft_stages,
-                                        draft_cfg, self.max_len, spec_k,
-                                        cache_dtype, adapters=adp))
+                self._spec_fused = make_paged_spec_tick(
+                    stages, cfg, draft_stages, draft_cfg, self.max_len,
+                    block_size, spec_k, cache_dtype, kernel=attn_kernel,
+                    adapters=adp)
             else:
                 # a TP target verifies in a shard_map program while the
                 # draft stays replicated single-device — two dispatches
                 self._spec_fused = None
+                self._verify = make_paged_verify_step(
+                    stages, cfg, self.max_len, block_size, spec_k,
+                    cache_dtype, mesh=mesh, kernel=attn_kernel,
+                    adapters=adp)
             self._draft_params = [s.params for s in draft_stages]
             self._init_draft_pool(n_slots)
         if self.tp > 1:
@@ -445,7 +383,6 @@ class InferenceEngine:
         # perturb virtual-clock scenario numbers
         self.trace = trace
         self.flight = flight
-        self._n_layers = n_layers
         self._predict = None     # lazy (ServeSpec, predict_fn) for kv drift
         self._clock = clock
         # the engine's most recent clock reading — what trace events with
@@ -457,7 +394,7 @@ class InferenceEngine:
         self._tick_count = 0
         self.requests: dict[int, Request] = {}
         # rids admitted but not yet fully prefilled, admission order (the
-        # chunked-prefill work queue; always empty in dense layout)
+        # chunked-prefill work queue)
         self._prefilling: collections.deque[int] = collections.deque()
         # per-request last-emit timestamps for TPOT accounting
         self._last_emit: dict[int, float] = {}
@@ -466,10 +403,11 @@ class InferenceEngine:
         self._gated: set[int] = set()
 
     def _init_draft_pool(self, n_slots: int) -> None:
-        """The draft model's K/V buffers: ALWAYS the dense slot layout
-        (one ``max_len`` row per slot), whatever the target layout — the
-        draft is small by design, so paging it buys nothing, and the dense
-        trailing-write argument keeps its rejected-tail rows safe."""
+        """The draft model's K/V buffers: one ``max_len`` row per slot —
+        the draft is small by design, so paging it buys nothing, and a
+        rejected tail's rows are overwritten before they can be attended
+        (the trailing-write argument, models/gpt.py's speculative
+        section)."""
         import jax.numpy as jnp
 
         from simple_distributed_machine_learning_tpu.models.gpt import (
@@ -500,11 +438,10 @@ class InferenceEngine:
         from simple_distributed_machine_learning_tpu.parallel.mesh import (
             MODEL_AXIS,
         )
-        # the head axis is dim 2 in every pool leaf — the dense pool's
-        # [L, S, H, max_len, dh], a paged layer's [n_blocks+1, bs, H*dh]
-        # (a shard's heads are contiguous lanes) AND (for quantized pools)
-        # its QuantKV scale plane — so one spec places the whole pytree
-        # per-shard
+        # the head axis is dim 2 in every pool leaf — a layer's
+        # [n_blocks+1, bs, H*dh] (a shard's heads are contiguous lanes) AND
+        # (for quantized pools) its QuantKV scale plane — so one spec
+        # places the whole pytree per-shard
         cache_sh = NamedSharding(mesh, P(None, None, MODEL_AXIS))
         self.pool.kc = jax.tree.map(
             lambda leaf: jax.device_put(leaf, cache_sh), self.pool.kc)
@@ -667,12 +604,11 @@ class InferenceEngine:
         returning 0 when idle — idle ticks touch no metrics, so a polling
         loop cannot drag the occupancy histogram toward zero.
 
-        Dense tick: admit (whole-prompt prefill each) -> batched decode ->
-        retire. Paged tick: admit (board slots, match prefixes, reserve
-        blocks) -> ONE prefill chunk of the oldest prefilling request ->
-        batched block-gather decode over the DECODING slots -> retire;
-        the decode comes first where the model's programs keep the newest
-        tokens on the device (:meth:`_tick_ahead`).
+        A tick: admit (board slots, match prefixes, reserve blocks) -> ONE
+        prefill chunk of the oldest prefilling request -> batched
+        block-gather decode over the DECODING slots -> retire; the decode
+        comes first where the model's programs keep the newest tokens on
+        the device (:meth:`_tick_ahead`).
         """
         if not self.busy:
             return 0
@@ -689,49 +625,40 @@ class InferenceEngine:
         # no-op without an installed plan
         maybe_fire("serve.tick", step=self._tick_count)
         self._tick_count += 1
-        chunk = 0
-        if self.kv_layout == "dense":
-            with tracing.span("engine.admit") as admit:
-                emitted, boarded = self._admit_dense()
-                admit.set(boarded=boarded, prefix_declined=0)
-            # occupancy the batched decode actually RUNS at — sampled before
-            # same-tick retirement so short requests cannot bias it low
-            decode_active = self.pool.n_active
-            emitted += (self._spec_tick(self.pool.active_slots())
-                        if self.speculative else self._decode_tick_dense())
+        with tracing.span("engine.admit") as admit:
+            # host-tier upload progress FIRST: blocks completing this
+            # tick register before admission probes the prefix registry,
+            # so a request blocked on its own prefetch boards this very
+            # tick
+            self.pool.advance_transfers()
+            if self.trace is not None and self.pool._inflight:
+                # trace the upload gate: a queued request held back by
+                # its own in-flight prefetch gets ONE ``gate`` row per
+                # episode (attribution's queue-vs-prefetch split).
+                # Stamped with the most recent clock read, like
+                # admission — and only probed while uploads are actually
+                # in flight, so the common path pays one attribute test
+                for r in self.scheduler.queue:
+                    if (r.rid not in self._gated
+                            and self.pool.prefetch_blocked(r)):
+                        self._gated.add(r.rid)
+                        self.trace.on_gate(r, self._now)
+            declined = self.pool.prefix_declined_total
+            admit.set(boarded=self._admit(),
+                      prefix_declined=(self.pool.prefix_declined_total
+                                       - declined))
+        chunk = int(bool(self._prefilling))
+        if self._dispatch_ahead:
+            emitted, decode_active = self._tick_ahead()
         else:
-            with tracing.span("engine.admit") as admit:
-                # host-tier upload progress FIRST: blocks completing this
-                # tick register before admission probes the prefix registry,
-                # so a request blocked on its own prefetch boards this very
-                # tick
-                self.pool.advance_transfers()
-                if self.trace is not None and getattr(self.pool, "_inflight",
-                                                      None):
-                    # trace the upload gate: a queued request held back by
-                    # its own in-flight prefetch gets ONE ``gate`` row per
-                    # episode (attribution's queue-vs-prefetch split).
-                    # Stamped with the most recent clock read, like paged
-                    # admission — and only probed while uploads are actually
-                    # in flight, so the common path pays one attribute test
-                    for r in self.scheduler.queue:
-                        if (r.rid not in self._gated
-                                and self.pool.prefetch_blocked(r)):
-                            self._gated.add(r.rid)
-                            self.trace.on_gate(r, self._now)
-                declined = self.pool.prefix_declined_total
-                admit.set(boarded=self._admit_paged(),
-                          prefix_declined=(self.pool.prefix_declined_total
-                                           - declined))
-            chunk = int(bool(self._prefilling))
-            if self._dispatch_ahead:
-                emitted, decode_active = self._tick_ahead()
-            else:
-                emitted = self._prefill_tick()
-                decoding = self._decoding_slots()
-                decode_active = len(decoding)
-                emitted += (self._spec_tick(decoding) if self.speculative
-                            else self._decode_tick_paged(decoding))
+            emitted = self._prefill_tick()
+            # occupancy the batched decode actually RUNS at — sampled
+            # before same-tick retirement so short requests cannot bias it
+            # low
+            decoding = self._decoding_slots()
+            decode_active = len(decoding)
+            emitted += (self._spec_tick(decoding) if self.speculative
+                        else self._decode_tick(decoding))
         if self.metrics is not None or self.flight is not None:
             with tracing.span("engine.bookkeeping"):
                 if self.metrics is not None:
@@ -739,8 +666,7 @@ class InferenceEngine:
                     self.metrics.on_tick(
                         self.scheduler.queue_depth, self.pool.n_active,
                         self.pool.n_slots, decode_active=decode_active,
-                        block_stats=(self.pool.stats()
-                                     if self.kv_layout == "paged" else None),
+                        block_stats=self.pool.stats(),
                         tp=self.tp, spec_k=self.spec_k,
                         kv_predicted=predicted, kv_drift=live - predicted,
                         attn_kernel=self.attn_kernel,
@@ -752,8 +678,7 @@ class InferenceEngine:
         sp.set(chunk=chunk, decoding=decode_active, emitted=emitted,
                queue=self.scheduler.queue_depth,
                state_slots=self._state_slots(),
-               kv_blocks=(self.pool.blocks_in_use
-                          if self.kv_layout == "paged" else 0))
+               kv_blocks=self.pool.blocks_in_use)
         return emitted
 
     def _state_slots(self) -> int:
@@ -775,13 +700,12 @@ class InferenceEngine:
         > 0 only if the pool leaks blocks the model says no live sequence
         can be pinning."""
         rows = []
-        if self.kv_layout == "paged":
-            for s in self.pool.active_slots():
-                r = self.requests[self.pool.occupant(s)]
-                n = (r.prefill_pos if r.prefill_pos is not None
-                     else int(self.pool.positions[s]))
-                if n > 0:
-                    rows.append(n)
+        for s in self.pool.active_slots():
+            r = self.requests[self.pool.occupant(s)]
+            n = (r.prefill_pos if r.prefill_pos is not None
+                 else int(self.pool.positions[s]))
+            if n > 0:
+                rows.append(n)
         if self.pool.recurrent:
             # the analyzer's model is GPT's (one head count, every layer
             # attends); here the pool's own block bytes make the prediction
@@ -809,11 +733,10 @@ class InferenceEngine:
         and reseats on the stored last token with the key stream untouched,
         so the continued decode is bit-exact vs an unpreempted run.
 
-        Compile-cost note: the dense layout (and a paged engine with
-        ``prefill_chunk=None``) prefills whole sequences, retracing per
-        distinct length — every distinct preemption point is a fresh XLA
-        compile. Preemption-heavy serving should run the default paged
-        layout WITH a ``prefill_chunk``, which bounds prefill shapes to
+        Compile-cost note: an engine with ``prefill_chunk=None`` prefills
+        whole sequences, retracing per distinct length — every distinct
+        preemption point is a fresh XLA compile. Preemption-heavy serving
+        should set a ``prefill_chunk``, which bounds prefill shapes to
         chunk sizes the engine has already compiled."""
         r = self.requests[rid]
         if r.state != ACTIVE or r.slot is None:
@@ -842,8 +765,8 @@ class InferenceEngine:
 
     def cancel(self, rid: int, reason: str = "cancelled") -> Request:
         """Remove a live request NOW with a structured rejection: a queued
-        request leaves the queue, an active one frees its slot and (paged)
-        decrefs its table blocks and returns its unused reservation — the
+        request leaves the queue, an active one frees its slot, decrefs
+        its table blocks and returns its unused reservation — the
         full budget refund, same release path as retirement — and the
         handle lands in ``SHED`` with ``finish_reason = reason``. The
         supervisor's deadline/overload shedding calls this; metrics
@@ -937,82 +860,9 @@ class InferenceEngine:
             ticks += 1
         return [r for r in self.requests.values() if r.state == DONE]
 
-    # -- dense tick internals ---------------------------------------------
+    # -- tick internals ---------------------------------------------------
 
-    def _admit_dense(self) -> tuple[int, int]:
-        """Board and prefill (one shot each) the requests the scheduler
-        admits; returns ``(tokens emitted, requests boarded)``."""
-        emitted = boarded = 0
-        for r in self.scheduler.admit():
-            boarded += 1
-            seq = r.resume_seq       # == r.prompt unless resuming preempted
-            t0 = int(seq.shape[0])
-            kc, vc, tok, kd = self._prefill(
-                self.params, self.pool.kc, self.pool.vc,
-                seq[None, :], np.int32(r.slot), r.key_data,
-                np.float32(r.temperature),
-                np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
-                np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
-                *self._bank_args(np.int32(getattr(r, "_adapter_row", 0))))
-            self.pool.kc, self.pool.vc = kc, vc
-            if self.speculative:
-                self._draft_prefill_slot(r, seq)
-            if r.tokens:
-                # resuming after preemption: the prefill only rebuilt K/V;
-                # its sampled token AND advanced key are discarded (the key
-                # stream already consumed this split before the preemption)
-                # and decode restarts from the stored newest token. The
-                # TPOT base resets to NOW deliberately: the stall is
-                # preemption wait, tracked by the preemption counters (and
-                # the request-level tpot_s mean), not decode cadence — one
-                # giant sample would distort the per-class cadence
-                # histogram the SLO gate reads
-                self.pool.seat(r.slot, t0, r.tokens[-1])
-                now = self._now = self._clock()
-                self._last_emit[r.rid] = now
-                if self.trace is not None:
-                    self.trace.on_admit(r, now, r.slot)
-                    self.trace.on_resume(r, now)
-                continue
-            tok = int(np.asarray(tok))           # host sync: TTFT endpoint
-            r.key_data = np.asarray(kd)
-            now = self._now = self._clock()
-            r.first_token_time = now
-            self._last_emit[r.rid] = now
-            r.emit(tok)
-            emitted += 1
-            if self.metrics is not None:
-                self.metrics.on_first_token(r.ttft_s, cls=r.cls)
-            if self.trace is not None:
-                # dense admission prefills in one shot: boarding and the
-                # TTFT endpoint share this tick's single clock read
-                self.trace.on_admit(r, now, r.slot)
-                self.trace.on_first_token(r, now)
-            reason = r.finished_by(tok)
-            if reason is not None:
-                self._finish(r, reason, now)
-            else:
-                self.pool.seat(r.slot, t0, tok)
-        return emitted, boarded
-
-    def _decode_tick_dense(self) -> int:
-        active = self.pool.active_slots()
-        if not active:
-            return 0
-        with tracing.span("engine.decode.prepare"):
-            kd, temps, top_ks, top_ps = self._sampling_inputs(active)
-            args = (self.pool.last_token.copy(), self.pool.positions.copy(),
-                    kd, temps, top_ks, top_ps,
-                    *self._bank_args(self._adapter_inputs(active)))
-        with tracing.span("engine.decode.dispatch"):
-            kc, vc, toks, kd2 = self._decode(
-                self.params, self.pool.kc, self.pool.vc, *args)
-            self.pool.kc, self.pool.vc = kc, vc
-        return self._emit_decoded(active, toks, kd2)
-
-    # -- paged tick internals ---------------------------------------------
-
-    def _admit_paged(self) -> int:
+    def _admit(self) -> int:
         """Board waiting requests. The scheduler's admit loop already bound
         each sequence to its slot (prefix matched, shared blocks
         referenced, worst-case budget reserved — ``PagedKVPool.bind_seq``)
@@ -1117,8 +967,11 @@ class InferenceEngine:
             # its sample and advanced key are discarded like a mid-prompt
             # chunk's (the stream already consumed this split before the
             # preemption) and decode restarts from the stored newest token.
-            # TPOT base resets to NOW deliberately (see the dense twin):
-            # preemption wait is not decode cadence
+            # TPOT base resets to NOW deliberately: the stall is preemption
+            # wait, tracked by the preemption counters (and the
+            # request-level tpot_s mean), not decode cadence — one giant
+            # sample would distort the per-class cadence histogram the SLO
+            # gate reads
             self.pool.seat(r.slot, plen, r.tokens[-1])
             self._last_emit[r.rid] = now
             if self.trace is not None:
@@ -1165,7 +1018,7 @@ class InferenceEngine:
         return [s for s in self.pool.active_slots()
                 if self.requests[self.pool.occupant(s)].prefill_pos is None]
 
-    def _decode_tick_paged(self, active: list[int]) -> int:
+    def _decode_tick(self, active: list[int]) -> int:
         if not active:
             return 0
         return self._emit_decoded(*self._decode_dispatch(
@@ -1292,7 +1145,7 @@ class InferenceEngine:
         propose scan (``spec_k`` fused draft steps) then the batched
         target verify, emitting 1..``spec_k`` tokens per slot. On a
         single-device target both halves run as ONE fused compiled
-        program (``make_*_spec_tick``: one dispatch per tick, the draft's
+        program (``make_paged_spec_tick``: one dispatch per tick, the draft's
         ``[S, K, V]`` log-prob rows never leave the device); a TP target
         runs them as two dispatches (the verify is a shard_map program,
         the draft stays replicated), proposals flowing between on device
@@ -1315,25 +1168,21 @@ class InferenceEngine:
                 # reservation (non-decoding slots keep valid 0 -> all-trash)
                 valid[s] = min(K, r.max_new_tokens - len(r.tokens))
                 dkd[s] = r.draft_key_data
-            tables = None
-            if self.kv_layout == "paged":
-                tables = np.full((S, self.pool.blocks_per_seq),
-                                 PagedKVPool.TRASH, np.int32)
-                for s in active:
-                    self._ensure_writable_range(s, int(pos[s]), int(valid[s]))
-                    tables[s] = self.pool.device_table(s)
+            tables = np.full((S, self.pool.blocks_per_seq),
+                             PagedKVPool.TRASH, np.int32)
+            for s in active:
+                self._ensure_writable_range(s, int(pos[s]), int(valid[s]))
+                tables[s] = self.pool.device_table(s)
             # adapters ride the VERIFY side only: the draft proposes as the
             # base model (a wrong proposal costs acceptance rate, never
             # correctness — the adapted verify rows decide every emission)
             bank_args = self._bank_args(self._adapter_inputs(active))
         with tracing.span("engine.decode.dispatch"):
             if self._spec_fused is not None:
-                args = (toks, pos, valid) + (() if tables is None
-                                             else (tables,))
                 dkc, dvc, kc, vc, otoks, nacc, kd2, dkd2 = self._spec_fused(
                     self._draft_params, self._dkc, self._dvc, self.params,
-                    self.pool.kc, self.pool.vc, *args, dkd, kd, temps,
-                    top_ks, top_ps, *bank_args)
+                    self.pool.kc, self.pool.vc, toks, pos, valid, tables,
+                    dkd, kd, temps, top_ks, top_ps, *bank_args)
             else:
                 dkc, dvc, drafts, qrows, dkd2 = self._propose(
                     self._draft_params, self._dkc, self._dvc, toks, pos, dkd,
@@ -1342,16 +1191,10 @@ class InferenceEngine:
                 # device; verify itself consumes only the first K-1 proposals
                 # (the K-th exists to keep the draft cache ahead; models/gpt.py
                 # section comment)
-                if tables is not None:
-                    kc, vc, otoks, nacc, kd2 = self._verify(
-                        self.params, self.pool.kc, self.pool.vc, toks, pos,
-                        drafts, qrows, valid, tables, kd, temps, top_ks,
-                        top_ps, *bank_args)
-                else:
-                    kc, vc, otoks, nacc, kd2 = self._verify(
-                        self.params, self.pool.kc, self.pool.vc, toks, pos,
-                        drafts, qrows, valid, kd, temps, top_ks, top_ps,
-                        *bank_args)
+                kc, vc, otoks, nacc, kd2 = self._verify(
+                    self.params, self.pool.kc, self.pool.vc, toks, pos,
+                    drafts, qrows, valid, tables, kd, temps, top_ks,
+                    top_ps, *bank_args)
             self._dkc, self._dvc = dkc, dvc
             self.pool.kc, self.pool.vc = kc, vc
         return self._emit_spec(active, otoks, nacc, kd2, dkd2, valid)
@@ -1457,9 +1300,9 @@ class InferenceEngine:
         if self.trace is not None:
             self.trace.on_finish(r, now, reason)
         if r.state == ACTIVE:
-            # scheduler.retire unbinds the sequence (paged: decref table
-            # blocks — registered ones stay reclaimable — and return the
-            # unused reservation) before the slot frees
+            # scheduler.retire unbinds the sequence (decref table blocks —
+            # registered ones stay reclaimable — and return the unused
+            # reservation) before the slot frees
             self.scheduler.retire(r, reason)
         self._adapter_release(r)
         if self.metrics is not None:

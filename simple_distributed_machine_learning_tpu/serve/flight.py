@@ -68,9 +68,8 @@ class FlightRecorder:
             "slots_active": int(engine.pool.n_active),
             "slots_total": int(engine.pool.n_slots),
             "prefill_backlog": len(engine._prefilling),
+            "blocks": engine.pool.stats(),
         }
-        if engine.kv_layout == "paged":
-            row["blocks"] = engine.pool.stats()
         row.update(extra)
         self.record(row)
         return row

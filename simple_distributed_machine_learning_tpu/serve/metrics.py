@@ -15,14 +15,14 @@ The serving-standard latency split, as registry instruments:
   (gauge) — lifetime request/token counters and aggregate throughput over
   the wall-clock window from first submit to last token.
 
-Paged-pool instruments (populated only by ``kv_layout="paged"`` engines —
-the engine hands the pool's stats to :meth:`ServeMetrics.on_tick`):
+Block-pool instruments (the engine hands the pool's stats to
+:meth:`ServeMetrics.on_tick`):
 
 - ``serve_blocks_in_use`` / ``serve_blocks_free`` / ``serve_blocks_cached``
   / ``serve_blocks_total`` (gauges) — block-pool occupancy: live working
   set, allocatable headroom, reclaimable prefix cache;
 - ``serve_kv_bytes_resident`` (gauge) — bytes of K/V live requests
-  actually pin (the number the paged layout shrinks vs dense rows);
+  actually pin (blocks referenced, not ``max_len`` rows);
 - ``serve_prefix_hit_blocks_total`` / ``serve_cow_copies_total`` /
   ``serve_block_evictions_total`` (counters) — prefix-share hits at
   admission, copy-on-write block copies, LRU cache evictions;
@@ -226,14 +226,14 @@ class ServeMetrics:
         self.completed = r.counter("serve_requests_completed_total")
         self.tokens = r.counter("serve_tokens_generated_total")
         self.tokens_per_sec = r.gauge("serve_tokens_per_sec")
-        # paged block-pool instruments (stay at zero under a dense engine;
-        # summary() includes their block only once block stats arrive)
+        # block-pool instruments (summary() includes their block only once
+        # block stats arrive)
         self.blocks_total = r.gauge("serve_blocks_total")
         self.blocks_in_use = r.gauge("serve_blocks_in_use")
         self.blocks_free = r.gauge("serve_blocks_free")
         self.blocks_cached = r.gauge("serve_blocks_cached")
         self.kv_bytes_resident = r.gauge("serve_kv_bytes_resident")
-        # model-drift gauges (both layouts; fed per tick by the engine)
+        # model-drift gauges (fed per tick by the engine)
         self.kv_bytes_predicted = r.gauge("serve_kv_bytes_predicted")
         self.kv_drift_bytes = r.gauge("serve_kv_drift_bytes")
         self._drift_seen = False
@@ -483,8 +483,7 @@ class ServeMetrics:
                                   labels={"class": adapter}).inc()
 
     def on_prefill_chunk(self, chunk_ms: float) -> None:
-        """One prefill chunk's wall latency (paged engines; the dense
-        layout's monolithic prefill is inside TTFT instead)."""
+        """One prefill chunk's wall latency."""
         self.prefill_chunk_ms.observe(chunk_ms)
 
     def on_spec(self, proposed: int, accepted: int) -> None:

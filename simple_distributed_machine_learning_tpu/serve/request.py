@@ -68,9 +68,8 @@ class Request:
     # -- lifecycle (engine-owned) -----------------------------------------
     state: str = QUEUED
     slot: int | None = None
-    # paged chunked prefill: next prompt position to compute while the
-    # request is admitted but not yet decoding (None once seated — and
-    # always None in the dense layout's whole-prompt prefill)
+    # chunked prefill: next prompt position to compute while the request
+    # is admitted but not yet decoding (None once seated)
     prefill_pos: int | None = None
     tokens: list[int] = dataclasses.field(default_factory=list)
     key_data: np.ndarray | None = None  # live PRNG key data (uint32 [2])

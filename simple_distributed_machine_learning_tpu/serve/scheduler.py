@@ -6,13 +6,13 @@ no preemption — which keeps TTFT fairness trivial to reason about and makes
 the scheduler invariants sharp enough to pin in tests:
 
 - a request is admitted the first tick the POOL accepts it
-  (``pool.can_admit``: a free slot for the dense layout; a free slot AND
-  the block budget after prefix sharing for the paged one), never before a
+  (``pool.can_admit``: a free slot AND the block budget after prefix
+  sharing), never before a
   request that arrived earlier (queue order IS arrival order — the
   head-of-line request is probed, so a big request is never starved by
   smaller ones slipping past it);
 - admission BINDS the sequence to its slot inside the loop
-  (``pool.bind_seq``: the paged pool matches/references shared prefix
+  (``pool.bind_seq``: the pool matches/references shared prefix
   blocks and reserves the worst-case budget), so a burst cannot admit
   past the pool's actual capacity;
 - retirement (EOS sampled, or ``max_new_tokens`` reached) unbinds and
@@ -44,13 +44,13 @@ from simple_distributed_machine_learning_tpu.serve.request import (
     QUEUED,
     Request,
 )
-from simple_distributed_machine_learning_tpu.serve.slots import KVCachePool
+from simple_distributed_machine_learning_tpu.serve.slots import PagedKVPool
 
 
 class FCFSScheduler:
-    """First-come-first-served admission over a :class:`KVCachePool`."""
+    """First-come-first-served admission over a :class:`PagedKVPool`."""
 
-    def __init__(self, pool: KVCachePool) -> None:
+    def __init__(self, pool: PagedKVPool) -> None:
         self.pool = pool
         self.queue: collections.deque[Request] = collections.deque()
         # the engine this scheduler serves (attach()): policies that evict
@@ -83,10 +83,9 @@ class FCFSScheduler:
         engine prefills each one.
 
         Admission is gated on the POOL's judgment (``pool.can_admit``), not
-        just a free slot: the dense pool's answer is "a slot is free" (the
-        row IS the whole budget), the paged pool's is "a slot is free AND
-        enough blocks remain for this request's worst-case footprint after
-        prefix sharing". The gate runs on the request :meth:`pick` actually
+        just a free slot: "a slot is free AND enough blocks remain for
+        this request's worst-case footprint after prefix sharing". The
+        gate runs on the request :meth:`pick` actually
         RETURNS (not a peeked head), so a subclass policy reordering the
         queue is still budget-checked; a picked request that doesn't fit
         goes back to the front and admission stops — head-of-line blocking,

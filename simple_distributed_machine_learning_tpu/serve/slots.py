@@ -1,31 +1,23 @@
-"""KV-cache pools: the serving engine's one device-resident state.
+"""The paged KV-cache pool: the serving engine's one device-resident state.
 
-Two layouts, one slot discipline:
+:class:`PagedKVPool` is the block-table paged layout (vLLM-style). A *slot*
+is the home of one in-flight sequence: its position counter, its pending
+token and its block table. K/V lives in a global pool of fixed-size
+*blocks*, one buffer per layer (``[n_blocks+1, block_size, H*dh]``: a
+position's heads side by side in one row, the layout the programs scatter,
+gather and attend in, so a tick writes its rows in place on the donated
+buffers and hands the attention kernel a layer's buffer untouched; physical
+block 0 is the trash block inactive slots write into). A per-slot block
+table maps logical block ``j`` (positions ``[j*bs, (j+1)*bs)``) to a
+physical block, blocks are allocated on demand as positions advance, and
+prefixes are shared copy-on-write: requests with a common prompt prefix
+reference the same physical blocks until they diverge, and the first write
+into a shared block copies it first. A sequence's memory footprint is
+``ceil(rows/block_size)`` blocks, not a ``max_len`` row reserved whether the
+sequence uses it or not, so concurrency is a function of the tokens
+actually resident.
 
-- :class:`KVCachePool` — the dense PR-5 layout: a *slot* is one row of every
-  layer's K/V cache (``[L, n_slots, H, max_len, dh]``), the static-shape home
-  of one in-flight sequence. Memory is reserved at ``max_len`` per slot
-  whether the sequence uses it or not, so HBM — not compute — caps
-  concurrency. Kept as the paged pool's comparison baseline
-  (``bench.py --serve``) and for engines built with ``kv_layout="dense"``.
-
-- :class:`PagedKVPool` — the block-table paged layout (vLLM-style): a global
-  pool of fixed-size K/V *blocks*, one buffer per layer
-  (``[n_blocks+1, block_size, H*dh]``: a position's heads side by side in
-  one row, the layout the programs scatter, gather and attend in, so a
-  tick writes its rows in place on the donated buffers and hands the
-  attention kernel a layer's buffer untouched; physical block 0 is the
-  trash block inactive slots write into), a per-slot block table mapping
-  logical block ``j`` (positions ``[j*bs, (j+1)*bs)``) to a physical
-  block, on-demand allocation as
-  positions advance, and copy-on-write prefix sharing: requests with a
-  common prompt prefix reference the same physical blocks until they
-  diverge, and the first write into a shared block copies it first.
-  A sequence's memory footprint is ``ceil(rows/block_size)`` blocks instead
-  of a ``max_len`` row, so the same bytes sustain strictly more concurrent
-  requests (the ``bench.py --serve`` fixed-memory comparison).
-
-Recurrent state (paged, ``state_shapes``): a model with state-space layers
+Recurrent state (``state_shapes``): a model with state-space layers
 (``models/jamba.py``) keeps, beside the K/V blocks of its few attention
 layers, a fixed-size recurrent buffer per slot and layer — no blocks, no
 length, nothing to share. :class:`PagedKVPool` holds those buffers
@@ -40,27 +32,20 @@ every slot's newest token and sampling key among these buffers too
 (``models/gpt.py::PagedServing.ahead``); ``last_token`` here then trails
 the device by the tick in flight.
 
-Both pools share the invariant-guarded slot free list: acquiring an occupied
-slot or releasing a free one raises instead of silently corrupting a
-neighbor's cache, and the paged pool extends the discipline to blocks — no
-double allocation, no double free, no write into a block another sequence
-still references (the scheduler invariants pinned in tests/test_serve.py).
+The slot free list is invariant-guarded: acquiring an occupied slot or
+releasing a free one raises instead of silently corrupting a neighbor's
+cache, and the same discipline covers blocks — no double allocation, no
+double free, no write into a block another sequence still references (the
+scheduler invariants pinned in tests/test_serve.py).
 
-Stale-write safety (dense): an idle slot keeps its stale position, and the
-batched decode step keeps writing garbage K/V there while the slot is
-unoccupied. That is safe by construction — a row at cache index ``p`` only
-ever becomes visible to attention at the tick that FIRST reaches position
-``p``, and that same tick overwrites index ``p`` with the real K/V before
-attending; prefill likewise overwrites ``[0, prompt_len)`` on admission.
+Stale-write safety: the batched decode step writes K/V for EVERY slot,
+occupied or not, and a retired slot's stale block-table entries may point
+at physical blocks REUSED by a live request, so a garbage write there
+would corrupt a neighbor. The engine therefore routes every non-decoding
+slot's tick write to the trash block (``PagedKVPool.TRASH``, position 0),
+which no block table ever references.
 
-Stale-write safety (paged): the dense argument breaks under paging — a
-retired slot's stale block-table entries may point at physical blocks
-REUSED by a live request, so a garbage write there would corrupt a
-neighbor. The engine therefore routes every non-decoding slot's tick write
-to the trash block (``PagedKVPool.TRASH``, position 0), which no block
-table ever references.
-
-Host offload tier (paged, ``host_cache_blocks > 0``): LRU eviction of a
+Host offload tier (``host_cache_blocks > 0``): LRU eviction of a
 cached prefix block demotes its rows to host RAM instead of discarding
 them, growing the effective prefix cache past HBM. The router probes the
 host registry too (:meth:`PagedKVPool.host_prefix_len`), and an affinity
@@ -164,133 +149,6 @@ def _ns_of(request) -> bytes:
     return adapter_namespace(adapter)
 
 
-class _SlotPoolBase:
-    """Slot occupancy accounting shared by both layouts: the free-slot list
-    with invariant guards, and the per-slot decode state (position counters
-    and last-token values — tiny host arrays fed into every compiled tick;
-    the authoritative copy lives here, not on device)."""
-
-    def __init__(self, n_slots: int, max_len: int) -> None:
-        if n_slots < 1:
-            raise ValueError(f"n_slots must be >= 1, got {n_slots}")
-        if max_len < 2:
-            raise ValueError(f"max_len must be >= 2 (a prompt token plus a "
-                             f"generated one), got {max_len}")
-        self.n_slots = n_slots
-        self.max_len = max_len
-        self.positions = np.zeros(n_slots, np.int32)
-        self.last_token = np.zeros(n_slots, np.int32)
-        self._occupant: list[int | None] = [None] * n_slots
-        self._free: list[int] = list(range(n_slots))[::-1]   # pop() -> slot 0
-        # a pool without recurrent state buffers (PagedKVPool may have them)
-        self.recurrent = False
-        self.state = ()
-        self.prefix_declined_total = 0
-
-    # -- occupancy accounting ---------------------------------------------
-
-    @property
-    def n_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def n_active(self) -> int:
-        return self.n_slots - len(self._free)
-
-    def active_slots(self) -> list[int]:
-        return [s for s, r in enumerate(self._occupant) if r is not None]
-
-    def occupant(self, slot: int) -> int | None:
-        return self._occupant[slot]
-
-    def acquire(self, rid: int) -> int:
-        """Claim a free slot for request ``rid``; raises when full or on a
-        double-occupancy attempt (the invariant, not a best-effort)."""
-        if not self._free:
-            raise RuntimeError("slot acquire on a full pool — the scheduler "
-                               "must check can_admit first")
-        slot = self._free.pop()
-        if self._occupant[slot] is not None:     # pragma: no cover - guard
-            raise RuntimeError(
-                f"slot {slot} already occupied by request "
-                f"{self._occupant[slot]} — free-list corruption")
-        self._occupant[slot] = rid
-        return slot
-
-    def release(self, slot: int) -> None:
-        if self._occupant[slot] is None:
-            raise RuntimeError(f"release of already-free slot {slot}")
-        self._occupant[slot] = None
-        self._free.append(slot)
-
-    # -- per-slot decode state --------------------------------------------
-
-    def seat(self, slot: int, prompt_len: int, first_token: int) -> None:
-        """Post-prefill seating: the slot's next write position is
-        ``prompt_len`` (the first generated token's position) and its
-        pending input token is the freshly sampled one."""
-        if not 0 < prompt_len < self.max_len:
-            raise ValueError(f"prompt_len {prompt_len} outside (0, "
-                             f"{self.max_len})")
-        self.positions[slot] = prompt_len
-        self.last_token[slot] = int(first_token)
-
-    def advance(self, slot: int, next_token: int) -> None:
-        self.positions[slot] += 1
-        self.last_token[slot] = int(next_token)
-
-    # -- layout hooks (scheduler-driven) -----------------------------------
-
-    def bind_seq(self, request) -> int | None:
-        """Attach an admitted request's sequence state to its slot. The
-        dense layout has none (the row IS the state): returns ``None``.
-        The paged override matches/reserves blocks and returns the first
-        prompt position prefill must compute. MUST run inside the
-        admission loop, immediately after the slot acquire — the next
-        head-of-line ``can_admit`` probe has to see this request's
-        reservation, or a burst admits past the pool's capacity."""
-        return None
-
-    def unbind_seq(self, slot: int) -> None:
-        """Release the slot's sequence state at retirement (before the slot
-        itself frees). Dense layout: nothing to do."""
-
-    # -- routing affinity (FleetRouter's signal) -----------------------------
-
-    def shared_prefix_len(self, prompt, ns: bytes = b"") -> int:
-        """Prompt positions this pool could serve from already-registered
-        prefix blocks — the fleet router's affinity signal
-        (``serve/router.py``); ``ns`` scopes the probe to one adapter's
-        key space. The dense layout shares nothing: 0."""
-        return 0
-
-    def host_prefix_len(self, prompt, ns: bytes = b"") -> int:
-        """Prompt positions resident in this pool's HOST offload tier — the
-        router's second affinity signal (an affinity hit here starts the
-        async prefetch upload). Pools without a host tier: 0."""
-        return 0
-
-    def prefetch_blocked(self, request) -> bool:
-        """True while an in-flight host->HBM upload covers a prefix of
-        ``request``'s bind sequence — the one ``can_admit`` failure that
-        preemption can NEVER fix (the PriorityScheduler must not evict
-        work for it; the request boards when the upload lands). Pools
-        without a host tier: never."""
-        return False
-
-    # -- preemption feasibility (PriorityScheduler's precheck) --------------
-
-    def admit_shortfall(self, request) -> int:
-        """Sequence-budget units ``request`` is short of admission (beyond
-        a free slot). Dense layout: the row is the whole budget — 0."""
-        return 0
-
-    def freeable_blocks(self, slot: int) -> int:
-        """Budget guaranteed back if ``slot``'s sequence ends now. Dense
-        layout: nothing beyond the slot itself — 0."""
-        return 0
-
-
 def _check_tp(n_heads: int, tp: int) -> int:
     """Pool-side TP validation: the K/V head axis is what the serving
     shard_map splits, so ``tp`` must divide ``n_heads``. Byte accounting
@@ -303,47 +161,13 @@ def _check_tp(n_heads: int, tp: int) -> int:
     return tp
 
 
-class KVCachePool(_SlotPoolBase):
-    """Dense fixed-capacity slot pool; see module docstring."""
-
-    def __init__(self, n_layers: int, n_slots: int, n_heads: int,
-                 max_len: int, head_dim: int, cache_dtype=None,
-                 tp: int = 1) -> None:
-        super().__init__(n_slots, max_len)
-        import jax.numpy as jnp
-
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            _cache_dtype,
-            _check_cache_quantization,
-        )
-        _check_cache_quantization(cache_dtype, "KVCachePool", paged=False)
-        self.tp = _check_tp(n_heads, tp)
-        shape = (n_layers, n_slots, n_heads, max_len, head_dim)
-        cd = _cache_dtype(cache_dtype)
-        self.cache_dtype = cd
-        self.kc = jnp.zeros(shape, cd)
-        self.vc = jnp.zeros(shape, cd)
-        # PER-SHARD bytes, like the paged pool's bytes_per_block: one row
-        # is a max_len-sized "block", and every row is pinned up front —
-        # occupancy never changes what a dense pool holds resident
-        self._bytes_total = kv_block_bytes(n_layers, n_heads // self.tp,
-                                           max_len, head_dim, cd) * n_slots
-
-    def bytes_resident(self) -> int:
-        """The dense pool's resident K/V bytes: the full allocation,
-        regardless of occupancy (the paged layout exists to shrink exactly
-        this). The KV-drift gauge checks it against the analyzer's dense
-        prediction — equality is a geometry/bookkeeping invariant."""
-        return self._bytes_total
-
-    def can_admit(self, request) -> bool:
-        """Dense admission gate: one free slot IS the whole budget (the row
-        reserves ``max_len`` positions up front)."""
-        return self.n_free > 0
-
-
-class PagedKVPool(_SlotPoolBase):
+class PagedKVPool:
     """Block-table paged K/V pool with prefix sharing; see module docstring.
+
+    Slot accounting: the free-slot list with invariant guards, and the
+    per-slot decode state (position counters and last-token values — tiny
+    host arrays fed into every compiled tick; the authoritative copy lives
+    here, not on device).
 
     Block lifecycle: a physical block is *free* (on the free list), *live*
     (``ref > 0`` request references), or *cached* (``ref == 0`` but holding
@@ -377,7 +201,18 @@ class PagedKVPool(_SlotPoolBase):
                  block_size: int = 16, n_blocks: int | None = None,
                  tp: int = 1, host_cache_blocks: int = 0,
                  prefetch_ticks: int = 1, state_shapes=()) -> None:
-        super().__init__(n_slots, max_len)
+        if n_slots < 1:
+            raise ValueError(f"n_slots must be >= 1, got {n_slots}")
+        if max_len < 2:
+            raise ValueError(f"max_len must be >= 2 (a prompt token plus a "
+                             f"generated one), got {max_len}")
+        self.n_slots = n_slots
+        self.max_len = max_len
+        self.positions = np.zeros(n_slots, np.int32)
+        self.last_token = np.zeros(n_slots, np.int32)
+        self._occupant: list[int | None] = [None] * n_slots
+        self._free: list[int] = list(range(n_slots))[::-1]   # pop() -> slot 0
+        self.prefix_declined_total = 0
         self.tp = _check_tp(n_heads, tp)
         import jax
 
@@ -407,7 +242,7 @@ class PagedKVPool(_SlotPoolBase):
         self.block_size = block_size
         self.blocks_per_seq = math.ceil(max_len / block_size)
         if n_blocks is None:
-            # default: the dense pool's capacity in blocks (same worst case)
+            # default: every slot can reach max_len
             n_blocks = n_slots * self.blocks_per_seq
         if n_blocks < self.blocks_per_seq:
             raise ValueError(
@@ -517,6 +352,58 @@ class PagedKVPool(_SlotPoolBase):
             )
             self._write_block = make_paged_block_write()
 
+    # -- occupancy accounting ---------------------------------------------
+
+    @property
+    def n_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def n_active(self) -> int:
+        return self.n_slots - len(self._free)
+
+    def active_slots(self) -> list[int]:
+        return [s for s, r in enumerate(self._occupant) if r is not None]
+
+    def occupant(self, slot: int) -> int | None:
+        return self._occupant[slot]
+
+    def acquire(self, rid: int) -> int:
+        """Claim a free slot for request ``rid``; raises when full or on a
+        double-occupancy attempt (the invariant, not a best-effort)."""
+        if not self._free:
+            raise RuntimeError("slot acquire on a full pool — the scheduler "
+                               "must check can_admit first")
+        slot = self._free.pop()
+        if self._occupant[slot] is not None:     # pragma: no cover - guard
+            raise RuntimeError(
+                f"slot {slot} already occupied by request "
+                f"{self._occupant[slot]} — free-list corruption")
+        self._occupant[slot] = rid
+        return slot
+
+    def release(self, slot: int) -> None:
+        if self._occupant[slot] is None:
+            raise RuntimeError(f"release of already-free slot {slot}")
+        self._occupant[slot] = None
+        self._free.append(slot)
+
+    # -- per-slot decode state --------------------------------------------
+
+    def seat(self, slot: int, prompt_len: int, first_token: int) -> None:
+        """Post-prefill seating: the slot's next write position is
+        ``prompt_len`` (the first generated token's position) and its
+        pending input token is the freshly sampled one."""
+        if not 0 < prompt_len < self.max_len:
+            raise ValueError(f"prompt_len {prompt_len} outside (0, "
+                             f"{self.max_len})")
+        self.positions[slot] = prompt_len
+        self.last_token[slot] = int(first_token)
+
+    def advance(self, slot: int, next_token: int) -> None:
+        self.positions[slot] += 1
+        self.last_token[slot] = int(next_token)
+
     # -- capacity ----------------------------------------------------------
 
     @property
@@ -551,7 +438,7 @@ class PagedKVPool(_SlotPoolBase):
     # -- admission ---------------------------------------------------------
 
     def can_admit(self, request) -> bool:
-        """Paged admission gate: a free slot AND enough blocks for this
+        """The admission gate: a free slot AND enough blocks for this
         request's worst-case budget after prefix sharing (shared FULL blocks
         are never written, so they cost nothing; a shared partial tail still
         budgets one block for its copy-on-write).
@@ -637,7 +524,13 @@ class PagedKVPool(_SlotPoolBase):
         self.prefix_hit_blocks_total += len(chain)
         return shared_len
 
-    def bind_seq(self, request) -> int | None:
+    def bind_seq(self, request) -> int:
+        """Attach an admitted request's sequence to its slot
+        (:meth:`begin_seq`): the first prompt position prefill must
+        compute. MUST run inside the admission loop, immediately after the
+        slot acquire — the next head-of-line ``can_admit`` probe has to see
+        this request's reservation, or a burst admits past the pool's
+        capacity."""
         # resume_seq/resume_max_new: identical to prompt/max_new_tokens for
         # fresh requests; after a preemption they cover the already-emitted
         # tokens whose K/V re-admission must recompute (serve/request.py)
@@ -645,6 +538,8 @@ class PagedKVPool(_SlotPoolBase):
                               _bind_budget_of(request), ns=_ns_of(request))
 
     def unbind_seq(self, slot: int) -> None:
+        """Release the slot's sequence state at retirement (before the slot
+        itself frees)."""
         self.end_seq(slot)
 
     def end_seq(self, slot: int) -> None:
@@ -761,11 +656,11 @@ class PagedKVPool(_SlotPoolBase):
     # -- prefix registry ---------------------------------------------------
 
     def shared_prefix_len(self, prompt, ns: bytes = b"") -> int:
-        """The paged affinity signal: longest registered prefix of
-        ``prompt`` (in positions) this pool already holds in namespace
-        ``ns``. A pure probe — no referencing, no memo, no registry
-        mutation — so the router may ask every replica without perturbing
-        any pool."""
+        """The fleet router's affinity signal (``serve/router.py``): longest
+        registered prefix of ``prompt`` (in positions) this pool already
+        holds in namespace ``ns``. A pure probe — no referencing, no memo,
+        no registry mutation — so the router may ask every replica without
+        perturbing any pool."""
         return self._probe_prefix(np.asarray(prompt, np.int32), ns)[0]
 
     def _probe_cached(self, request) -> tuple[int, list[tuple[int, int]]]:
@@ -916,10 +811,11 @@ class PagedKVPool(_SlotPoolBase):
             del self._host[hid]
 
     def host_prefix_len(self, prompt, ns: bytes = b"") -> int:
-        """The host-tier affinity signal: longest host-resident prefix of
-        ``prompt`` (in positions) under the ``ns`` adapter namespace. A
-        pure probe, like :meth:`shared_prefix_len` — the router may ask
-        freely."""
+        """The router's second affinity signal (a hit here starts the async
+        prefetch upload): longest host-resident prefix of ``prompt`` (in
+        positions) under the ``ns`` adapter namespace; 0 without a host
+        tier. A pure probe, like :meth:`shared_prefix_len` — the router
+        may ask freely."""
         return self._probe_host(np.asarray(prompt, np.int32), ns)[0]
 
     def _probe_host(self, prompt: np.ndarray, ns: bytes = b""
@@ -1019,6 +915,10 @@ class PagedKVPool(_SlotPoolBase):
         return True
 
     def prefetch_blocked(self, request) -> bool:
+        """True while an in-flight host->HBM upload covers a prefix of
+        ``request``'s bind sequence — the one ``can_admit`` failure that
+        preemption can NEVER fix (the PriorityScheduler must not evict
+        work for it; the request boards when the upload lands)."""
         if not self._inflight:
             return False
         seq_b = _ns_of(request) + np.asarray(

@@ -23,8 +23,9 @@ a crash during recovery, included).  ``max_restarts`` bounds the loop
 (:class:`~..resilience.supervisor.RestartBudgetExceeded`), and
 ``degrade_after`` restarts flips later rebuilds to the DEGRADED layout —
 :func:`engine_factory`'s rule: speculation off, tensor parallelism off,
-dense slot rows (the same transform ``analysis.programs.degraded_spec``
-keeps lint-clean in the program registry).
+the fused kernel, a quantised cache and the host tier off, the paged pool
+kept (the same transform ``analysis.programs.degraded_spec`` keeps
+lint-clean in the program registry).
 
 **Deadlines.**  ``submit(..., ttft_deadline_s=, deadline_s=)`` (or the
 supervisor-wide defaults) bound time-to-first-token and total latency.
@@ -156,19 +157,23 @@ def engine_factory(stages, cfg, *, metrics=None, clock=time.monotonic,
                    **kw):
     """The standard ``factory(degraded) -> InferenceEngine`` closure.
 
-    Non-degraded builds get the full deployment (paged knobs, TP mesh,
+    Non-degraded builds get the full deployment (pool knobs, TP mesh,
     speculative draft) exactly as passed; ``degraded=True`` applies the
-    fallback rule — ``spec_k → 0``, ``tp → 1``, dense slot rows — the
-    layout ``analysis.programs.degraded_spec`` mirrors so the program
+    fallback rule "drop speed features, never tenants": ``spec_k → 0``,
+    ``tp → 1``, the gather-then-dense attention in place of the fused
+    kernel, a quantised ``cache_dtype`` widened to f32, the host tier and
+    its prefetch off — and the paged pool KEPT (``block_size``,
+    ``n_blocks``, ``prefill_chunk`` as passed), so the fallback builds for
+    every model the engine serves, one with recurrent state included.
+    ``analysis.programs.degraded_spec`` mirrors the rule so the program
     registry proves the fallback lint-clean before any crash needs it.
     The fallback stays bit-exact for everything except *sampled* requests
-    that were being served speculatively (dense vs paged vs plain-decode
-    streams all equal the solo decode; sampled speculative streams are
+    that were being served speculatively (plain-decode streams equal the
+    solo decode whatever the kernel; sampled speculative streams are
     deterministic but consume the key streams differently).
 
     ``adapter_rank > 0`` turns on multi-tenant LoRA serving: every build
-    (degraded ones included — the fallback drops layout/speed features,
-    never tenants) gets a FRESH :class:`~.adapters.AdapterStore` over one
+    (degraded ones included) gets a FRESH :class:`~.adapters.AdapterStore` over one
     SHARED ``adapter_host`` dict, so registered adapters survive crash
     rebuilds while device residency honestly resets with the engine.
 
@@ -203,19 +208,16 @@ def engine_factory(stages, cfg, *, metrics=None, clock=time.monotonic,
         if getattr(cfg, "n_tensor_parallel", 1) > 1:
             dcfg = dataclasses.replace(cfg, n_tensor_parallel=1)
         dkw = {k: v for k, v in kw.items()
-               if k not in ("block_size", "n_blocks", "prefill_chunk",
-                            "kv_layout", "attn_kernel",
-                            "host_cache_blocks", "prefetch_ticks")}
+               if k not in ("attn_kernel", "host_cache_blocks",
+                            "prefetch_ticks")}
         from simple_distributed_machine_learning_tpu.models.gpt import (
             _is_quantized_dtype,
         )
         if _is_quantized_dtype(dkw.get("cache_dtype")):
-            # quantized blocks (and the fused kernel dropped above) are
-            # paged-pool features; the dense fallback widens to f32 —
-            # same rule degraded_spec mirrors for the lint gate
+            # the fallback widens a quantized pool to f32 — same rule
+            # degraded_spec mirrors for the lint gate
             dkw["cache_dtype"] = None
-        return InferenceEngine(stages, dcfg, kv_layout="dense",
-                               metrics=metrics, clock=clock,
+        return InferenceEngine(stages, dcfg, metrics=metrics, clock=clock,
                                scheduler=scheduler,
                                **_adapter_kw(n_slots), **dkw)
 

@@ -2,7 +2,7 @@
 
 Four contracts pin the whole-program gate:
 
-1. the serving-program registry lints CLEAN on both KV layouts — and not
+1. the serving-program registry lints CLEAN — and not
    vacuously: the interval pass must PROVE every PROMISE_IN_BOUNDS gather
    (zero ``unproven-promise`` findings), and the trace recursion must reach
    every program (zero ``trace.failed``);
@@ -17,6 +17,8 @@ Four contracts pin the whole-program gate:
 Everything except the HBM cross-check is trace-only.
 """
 
+import ast
+import dataclasses
 import functools
 import os
 
@@ -62,19 +64,20 @@ def stages():
 
 def _specs():
     return [
-        ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="paged",
-                  block_size=4, prefill_chunk=3, prompt_lens=BUCKETS),
-        ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="paged",
-                  block_size=8, prefill_chunk=None, prompt_lens=BUCKETS),
-        ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="dense",
+        ServeSpec(CFG, n_slots=3, max_len=16, block_size=4, prefill_chunk=3,
                   prompt_lens=BUCKETS),
+        ServeSpec(CFG, n_slots=3, max_len=16, block_size=8,
+                  prefill_chunk=None, prompt_lens=BUCKETS),
+        ServeSpec(CFG, n_slots=3, max_len=16, block_size=4, prefill_chunk=3,
+                  prompt_lens=BUCKETS, attn_kernel="fused",
+                  cache_dtype="bfloat16"),
     ]
 
 
-# ---- 1. the registry lints clean on both layouts -------------------------
+# ---- 1. the registry lints clean at every pool shape ----------------------
 
 @pytest.mark.parametrize("i", range(3))
-def test_registry_clean_both_layouts(stages, i):
+def test_registry_clean_at_every_pool_shape(stages, i):
     report = lint_serve(stages, _specs()[i])
     assert report.ok(fail_on="warning"), report.format()
     # the clean pass is a PROOF, not silence: the paged gathers run in
@@ -85,15 +88,24 @@ def test_registry_clean_both_layouts(stages, i):
 
 
 def test_registry_covers_every_decode_builder(stages):
-    # the paged + dense registries together enumerate every memoized
-    # decode builder (plus the composite ticks)
+    # a plain, a speculative and an adapter deployment together enumerate
+    # every memoized decode builder the engine calls (plus the composite
+    # ticks); make_paged_block_write is the host tier's, built by the pool
+    draft_stages, dcfg = _draft()
     names = set()
-    for s in _specs():
-        programs, _ = build_registry(stages, s)
+    for s, draft in ((_specs()[0], None),
+                     (dataclasses.replace(_specs()[0], spec_k=4,
+                                          draft_cfg=dcfg), draft_stages),
+                     (dataclasses.replace(_specs()[0], n_adapters=4,
+                                          adapter_rank=2), None)):
+        programs, _ = build_registry(stages, s, draft_stages=draft)
         names.update(p.name for p in programs)
-    assert {"cached_decoder", "slot_prefill", "slot_decode",
-            "paged_prefill_chunk", "paged_decode", "paged_block_copy",
-            "dense_tick", "paged_tick"} <= names
+    assert {"cached_decoder", "paged_prefill_chunk", "paged_decode",
+            "paged_block_copy", "paged_tick", "draft_prefill",
+            "paged_propose", "paged_verify", "paged_spec_tick",
+            "adapter_bank_update", "paged_prefill_chunk_adapter",
+            "paged_decode_adapter"} == names
+    assert len(DECODE_BUILDERS) == 10
 
 
 def test_trace_recursion_reaches_serve_primitives(stages):
@@ -258,9 +270,7 @@ def test_real_builders_are_memoized(stages):
     # the speculative builders take the draft build (same tiny model here)
     extra = {
         "make_slot_propose": lambda m: m(stages, CFG, 16, 4),
-        "make_slot_verify_step": lambda m: m(stages, CFG, 16, 4),
         "make_paged_verify_step": lambda m: m(stages, CFG, 16, 4, 4),
-        "make_slot_spec_tick": lambda m: m(stages, CFG, stages, CFG, 16, 4),
         "make_paged_spec_tick": lambda m: m(stages, CFG, stages, CFG, 16,
                                             4, 4),
     }
@@ -283,19 +293,21 @@ def test_real_builders_are_memoized(stages):
 
 
 def test_unbounded_retrace_flagged_bounded_clean(stages):
-    unbounded = ServeSpec(CFG, n_slots=2, max_len=16, kv_layout="dense")
+    # no chunk: the final (= whole-prompt) chunk's length is the trace key
+    unbounded = ServeSpec(CFG, n_slots=2, max_len=16, block_size=4,
+                          prefill_chunk=None)
     report = lint_serve(stages, unbounded)
-    assert any(f.rule == "retrace-explosion.unbounded-trace-key"
-               for f in report.findings), report.format()
+    assert "make_paged_prefill_chunk" in [
+        f.where for f in report.findings
+        if f.rule == "retrace-explosion.unbounded-trace-key"], report.format()
     assert report.ok()                      # WARNING-level: gates don't trip
-    bounded = ServeSpec(CFG, n_slots=2, max_len=16, kv_layout="dense",
-                        prompt_lens=BUCKETS)
+    bounded = dataclasses.replace(unbounded, prompt_lens=BUCKETS)
     assert lint_serve(stages, bounded).ok(fail_on="warning")
-    # paged: a prefill_chunk bounds the SERVING shapes even with no
+    # a prefill_chunk bounds the SERVING shapes even with no
     # buckets — the only remaining warning is the cached (solo-parity)
     # decoder, whose per-(prompt, n_new) retrace is caller-owned
-    chunked = ServeSpec(CFG, n_slots=2, max_len=16, kv_layout="paged",
-                        block_size=4, prefill_chunk=4)
+    chunked = ServeSpec(CFG, n_slots=2, max_len=16, block_size=4,
+                        prefill_chunk=4)
     report = lint_serve(stages, chunked)
     assert report.ok()
     unbounded_rules = [f for f in report.findings
@@ -416,7 +428,7 @@ def test_predicted_resident_bytes_match_gauge(stages, block_size, n_reqs,
         prompt = rng.integers(0, CFG.vocab, plen).astype(np.int32)
         prompt[0] = i
         handles.append(engine.submit(prompt, max_new_tokens=6, seed=i))
-    sspec = ServeSpec(CFG, n_slots=n_reqs, max_len=ml, kv_layout="paged",
+    sspec = ServeSpec(CFG, n_slots=n_reqs, max_len=ml,
                       block_size=block_size)
     for _ in range(n_reqs + 2):      # prefills (one per tick) + decodes
         engine.step()
@@ -444,7 +456,6 @@ def test_predicted_resident_bytes_match_gauge(stages, block_size, n_reqs,
 # ---- sharded + speculative registry (ISSUE 9) ----------------------------
 
 def _draft():
-    import dataclasses
     dcfg = dataclasses.replace(CFG, n_layers=1)
     return make_gpt_stages(jax.random.key(1), dcfg, 1)[0], dcfg
 
@@ -454,11 +465,11 @@ def test_registry_clean_speculative_both_layouts(stages):
     tick join the registry and lint clean — the proof, not silence, rule
     of contract 1 extends to every speculative program."""
     draft_stages, dcfg = _draft()
-    for s in (ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="paged",
-                        block_size=4, prefill_chunk=3, prompt_lens=BUCKETS,
-                        spec_k=4, draft_cfg=dcfg),
-              ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="dense",
-                        prompt_lens=BUCKETS, spec_k=4, draft_cfg=dcfg)):
+    base = ServeSpec(CFG, n_slots=3, max_len=16, block_size=4,
+                     prefill_chunk=3, prompt_lens=BUCKETS, spec_k=4,
+                     draft_cfg=dcfg)
+    for s in (base, dataclasses.replace(base, attn_kernel="fused",
+                                        cache_dtype="int8")):
         report = lint_serve(stages, s, draft_stages=draft_stages)
         assert report.ok(fail_on="warning"), report.format()
         rules = {f.rule for f in report.findings}
@@ -466,15 +477,20 @@ def test_registry_clean_speculative_both_layouts(stages):
         assert "scatter-bounds.unproven-promise" not in rules
         programs, _ = build_registry(stages, s, draft_stages=draft_stages)
         names = {p.name for p in programs}
-        want = ({"paged_propose", "paged_verify", "paged_spec_tick"}
-                if s.kv_layout == "paged"
-                else {"slot_propose", "slot_verify", "dense_spec_tick"})
-        assert want <= names, names
+        assert {"draft_prefill", "paged_propose", "paged_verify",
+                "paged_spec_tick"} <= names, names
+    # the draft prefills a whole sequence at once: with no buckets declared
+    # that trace key is flagged like the cached decoder's
+    report = lint_serve(stages, dataclasses.replace(base, prompt_lens=None),
+                        draft_stages=draft_stages)
+    assert "make_slot_prefill" in [
+        f.where for f in report.findings
+        if f.rule == "retrace-explosion.unbounded-trace-key"]
 
 
 def test_lint_serve_requires_the_draft_build():
     _, dcfg = _draft()
-    s = ServeSpec(CFG, n_slots=2, max_len=16, kv_layout="dense",
+    s = ServeSpec(CFG, n_slots=2, max_len=16, block_size=4,
                   prompt_lens=BUCKETS, spec_k=4, draft_cfg=dcfg)
     with pytest.raises(ValueError, match="draft_stages"):
         lint_serve(None, s)
@@ -483,41 +499,33 @@ def test_lint_serve_requires_the_draft_build():
 def test_registry_clean_tp2(stages):
     """TP-sharded serving programs on a live 2-device model mesh: the
     mesh-axis and scatter-bounds rules walk the sharded block gathers of
-    the exact shard_map twins the TP engine runs — clean on both layouts,
-    and TP without the mesh is refused."""
-    import dataclasses
-
+    the exact shard_map twins the TP engine runs — clean under both
+    attention kernels, and TP without the mesh is refused."""
     from simple_distributed_machine_learning_tpu.parallel.mesh import (
         make_mesh,
     )
     cfg2 = dataclasses.replace(CFG, n_tensor_parallel=2)
     mesh = make_mesh(n_stages=1, n_data=1, n_model=2)
-    for s in (ServeSpec(cfg2, n_slots=3, max_len=16, kv_layout="paged",
-                        block_size=4, prefill_chunk=3,
-                        prompt_lens=BUCKETS),
-              ServeSpec(cfg2, n_slots=3, max_len=16, kv_layout="dense",
-                        prompt_lens=BUCKETS)):
+    base = ServeSpec(cfg2, n_slots=3, max_len=16, block_size=4,
+                     prefill_chunk=3, prompt_lens=BUCKETS)
+    for s in (base, dataclasses.replace(base, attn_kernel="fused")):
         report = lint_serve(stages, s, mesh=mesh)
         assert report.ok(fail_on="warning"), report.format()
         assert "trace.failed" not in {f.rule for f in report.findings}
     with pytest.raises(ValueError, match="mesh"):
-        lint_serve(stages, ServeSpec(cfg2, n_slots=3, max_len=16,
-                                     kv_layout="dense",
-                                     prompt_lens=BUCKETS))
+        lint_serve(stages, base)
 
 
 def test_hbm_per_shard_bytes(stages):
     """Under TP the HBM model reports PER-SHARD bytes: every K/V stream
     row halves at tp=2, the resident-bytes prediction halves, and the
     prediction still equals a live tp-declared pool's gauge exactly."""
-    import dataclasses
-
     from simple_distributed_machine_learning_tpu.serve.slots import (
         PagedKVPool,
     )
     cfg2 = dataclasses.replace(CFG, n_tensor_parallel=2)
-    s1 = ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="paged",
-                   block_size=4, prefill_chunk=3)
+    s1 = ServeSpec(CFG, n_slots=3, max_len=16, block_size=4,
+                   prefill_chunk=3)
     s2 = dataclasses.replace(s1, cfg=cfg2)
     c1 = {h.op: h.bytes_per_tick for h in hbm_tick_costs(s1)}
     c2 = {h.op: h.bytes_per_tick for h in hbm_tick_costs(s2)}
@@ -643,31 +651,36 @@ def test_scatter_variant_primitives_checked():
 
 def test_degraded_spec_matches_engine_factory_rule(stages):
     """``degraded_spec`` and ``serve/supervisor.py::engine_factory`` must
-    apply the SAME fallback transform (spec off, tp 1, dense rows) — the
+    apply the SAME fallback transform (spec off, tp 1, the kernel, the
+    quantised cache and the host tier off, the pool kept) — the
     registry's degraded entry is only a proof if it describes the engine a
     chaos-stressed supervisor actually rebuilds."""
-    import dataclasses as _dc
-
     from simple_distributed_machine_learning_tpu.analysis.programs import (
         degraded_spec,
+        engine_spec,
     )
     from simple_distributed_machine_learning_tpu.serve.supervisor import (
         engine_factory,
     )
 
-    full = ServeSpec(CFG, n_slots=3, max_len=16, kv_layout="paged",
-                     block_size=4, prefill_chunk=3, prompt_lens=BUCKETS,
-                     spec_k=4, draft_cfg=_dc.replace(CFG, n_layers=1))
+    draft_stages, draft_cfg = _draft()
+    full = ServeSpec(CFG, n_slots=3, max_len=16, block_size=4, n_blocks=9,
+                     prefill_chunk=3, prompt_lens=BUCKETS, spec_k=4,
+                     draft_cfg=draft_cfg, attn_kernel="fused",
+                     cache_dtype="int8", host_cache_blocks=4,
+                     prefetch_ticks=2)
     d = degraded_spec(full)
-    assert d.kv_layout == "dense" and d.spec_k == 0 and d.tp == 1
-    assert d.n_slots == full.n_slots and d.ml == full.ml
-    draft_cfg = _dc.replace(CFG, n_layers=1)
-    draft_stages = make_gpt_stages(jax.random.key(1), draft_cfg, 1)[0]
     eng = engine_factory(stages, CFG, n_slots=3, max_len=16, block_size=4,
-                         prefill_chunk=3, draft_stages=draft_stages,
+                         n_blocks=9, prefill_chunk=3, attn_kernel="fused",
+                         cache_dtype="int8", host_cache_blocks=4,
+                         prefetch_ticks=2, draft_stages=draft_stages,
                          draft_cfg=draft_cfg, spec_k=4)(True)
-    assert eng.kv_layout == "dense" and not eng.speculative
-    assert eng.tp == 1 and eng.pool.n_slots == 3
+    assert not eng.speculative and eng.tp == 1 and eng.pool.n_slots == 3
+    # field for field: the spec the analyzer derives is the engine's own
+    assert dataclasses.replace(
+        engine_spec(eng, BUCKETS), cache_dtype=None) == dataclasses.replace(
+            d, cache_dtype=None)
+    assert d.cache_dtype is None and eng.pool.cache_dtype == np.float32
     # and the degraded ENGINE's own lint (the exact programs it built)
     # is clean: zero trace.failed, zero unproven-promise
     report = lint_engine(eng, prompt_lens=BUCKETS)
@@ -675,6 +688,55 @@ def test_degraded_spec_matches_engine_factory_rule(stages):
     rules = {f.rule for f in report.findings}
     assert "trace.failed" not in rules
     assert "scatter-bounds.unproven-promise" not in rules
+
+
+def test_degraded_spec_of_a_fused_paged_spec_lints_clean(stages):
+    """The fallback of a plain (recurrent-free) deployment on the fused
+    kernel: the kernel is dropped, the pool's geometry is kept, and the
+    registry proves that layout clean — no kernel rows left to reconcile."""
+    from simple_distributed_machine_learning_tpu.analysis.programs import (
+        degraded_spec,
+    )
+    full = ServeSpec(CFG, n_slots=3, max_len=16, block_size=4, n_blocks=10,
+                     prefill_chunk=3, prompt_lens=BUCKETS,
+                     attn_kernel="fused", cache_dtype="bfloat16")
+    d = degraded_spec(full)
+    assert d == dataclasses.replace(full, attn_kernel="dense")
+    report = lint_serve(stages, d)
+    assert report.ok(fail_on="warning"), report.format()
+    rules = {f.rule for f in report.findings}
+    assert "trace.failed" not in rules
+    assert "scatter-bounds.unproven-promise" not in rules
+    assert not [h for h in report.hbm if h.op == "kernel.kv_stream"]
+    assert "decode.kv_attn_reread" in {h.op for h in report.hbm}
+
+
+def test_engine_imports_only_what_speculation_and_cow_need():
+    """``serve/engine.py`` reaches a target model's programs through
+    ``cfg.paged_serving()`` alone. What it still imports from
+    ``models/gpt.py`` by name is pinned here: the seat constants and the
+    block copy (copy-on-write), the speculative verify / tick / draft
+    builders, the TP weight packing and two dtype helpers. A decode or
+    prefill builder for the target cannot come back unnoticed."""
+    import simple_distributed_machine_learning_tpu.serve.engine as engine
+    with open(engine.__file__) as f:
+        tree = ast.parse(f.read())
+    names, modules = set(), set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and ".models" in (
+                node.module or ""):
+            modules.add(node.module.rsplit(".", 1)[1])
+            names |= {a.name for a in node.names}
+    assert modules == {"gpt"}
+    assert names == {
+        "SEAT_NONE", "SEAT_SAMPLE", "make_paged_block_copy",
+        "make_paged_verify_step", "make_paged_spec_tick",
+        "make_slot_prefill", "make_slot_propose",
+        "pack_tp_serve_params", "_cache_dtype", "_is_quantized_dtype"}
+    builders = names & set(DECODE_BUILDERS)
+    assert builders == {"make_paged_block_copy", "make_paged_verify_step",
+                        "make_paged_spec_tick", "make_slot_prefill",
+                        "make_slot_propose"}
 
 
 def test_default_registry_includes_clean_degraded_entry():

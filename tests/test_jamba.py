@@ -453,7 +453,6 @@ def test_tick_and_admit_spans_carry_the_new_counts(stages):
 
 
 @pytest.mark.parametrize("kw,name", [
-    ({"kv_layout": "dense"}, "kv_layout='dense'"),
     ({"host_cache_blocks": 4}, "host_cache_blocks"),
     ({"draft_stages": "d", "draft_cfg": "c", "spec_k": 2}, "draft_stages"),
     ({"adapters": type("Store", (), {"n_rows": 3})()}, "adapters"),

@@ -211,24 +211,34 @@ def test_kv_block_bytes_accounts_scale_planes():
             > n_blocks_for_bytes(budget, L, H, bs, dh, "bfloat16"))
 
 
-def test_quantized_cache_is_paged_only():
-    from simple_distributed_machine_learning_tpu.serve.slots import (
-        KVCachePool,
+def test_quantized_cache_is_paged_only(stages):
+    """What keeps contiguous rows (the speculative draft's programs, the
+    solo cached decoder) has no scale planes and refuses a quantized
+    dtype; the paged pool takes it."""
+    from simple_distributed_machine_learning_tpu.models.gpt import (
+        make_cached_decoder,
+        make_slot_prefill,
+        make_slot_propose,
     )
 
-    with pytest.raises(ValueError, match="paged"):
-        KVCachePool(2, 2, 2, 16, 16, cache_dtype="int8")
+    for build in (lambda: make_slot_prefill(stages, CFG, 16, "int8"),
+                  lambda: make_slot_propose(stages, CFG, 16, 4, "int8"),
+                  lambda: make_cached_decoder(stages, CFG, 4, 4,
+                                              cache_dtype="int8")):
+        with pytest.raises(ValueError, match="paged-pool feature"):
+            build()
+    PagedKVPool(2, 2, 2, 16, 16, cache_dtype="int8")
 
 
 def test_engine_knob_validation(stages):
     with pytest.raises(ValueError, match="attn_kernel"):
         InferenceEngine(stages, CFG, attn_kernel="magic")
-    with pytest.raises(ValueError, match="paged"):
-        InferenceEngine(stages, CFG, kv_layout="dense",
-                        attn_kernel="fused")
-    with pytest.raises(ValueError, match="paged"):
-        InferenceEngine(stages, CFG, kv_layout="dense",
-                        cache_dtype="int8")
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        InferenceEngine(stages, CFG, prefill_chunk=0)
+    with pytest.raises(ValueError, match="n_blocks"):
+        InferenceEngine(stages, CFG, max_len=16, block_size=4, n_blocks=3)
+    with pytest.raises(ValueError, match="host_cache_blocks"):
+        InferenceEngine(stages, CFG, host_cache_blocks=-1)
 
 
 def _drain_tokens(stages, cfg, prompts, max_new=8, block_size=4, **kw):
@@ -439,7 +449,7 @@ def test_hbm_model_matches_kernel_single_pass(stages):
     )
 
     def costs(**kw):
-        s = ServeSpec(CFG, n_slots=4, kv_layout="paged", block_size=4,
+        s = ServeSpec(CFG, n_slots=4, block_size=4,
                       **kw)
         return {h.op: h.bytes_per_tick for h in hbm_tick_costs(s)}
 
@@ -556,7 +566,7 @@ def test_kernel_hbm_rows_reconcile_with_tick_model(stages, cache_dtype):
         hbm_tick_costs,
         lint_serve,
     )
-    sspec = ServeSpec(CFG, n_slots=2, kv_layout="paged", block_size=4,
+    sspec = ServeSpec(CFG, n_slots=2, block_size=4,
                       cache_dtype=cache_dtype, attn_kernel="fused",
                       prompt_lens=(4,))
     report = lint_serve(stages, sspec)
@@ -589,7 +599,7 @@ def test_kernel_hbm_mismatch_is_flagged():
     from simple_distributed_machine_learning_tpu.analysis.report import (
         HBMCost,
     )
-    sspec = ServeSpec(CFG, n_slots=2, kv_layout="paged", block_size=4,
+    sspec = ServeSpec(CFG, n_slots=2, block_size=4,
                       attn_kernel="fused")
     model = hbm_tick_costs(sspec)
     want = next(h.bytes_per_tick for h in model

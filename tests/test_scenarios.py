@@ -181,28 +181,6 @@ def test_preemption_parity_paged():
                                       err_msg=f"request {h.rid}")
 
 
-def test_preemption_parity_dense_layout():
-    """Same pin on the dense slot-row layout (whole-prompt re-prefill with
-    the sample discarded)."""
-    stages, params = _model()
-    eng = InferenceEngine(stages, CFG, n_slots=2,
-                          scheduler=PriorityScheduler, kv_layout="dense")
-    b1 = eng.submit(_prompt(6, 1), max_new_tokens=12, seed=11, cls="batch")
-    b2 = eng.submit(_prompt(8, 2), max_new_tokens=12, seed=12, cls="batch")
-    for _ in range(4):
-        eng.step()
-    it = eng.submit(_prompt(4, 3), max_new_tokens=5, seed=13,
-                    cls="interactive", priority=2)
-    eng.drain()
-    assert b1.n_preempted + b2.n_preempted >= 1
-    for h, (p, n, s) in [(b1, (_prompt(6, 1), 12, 11)),
-                         (b2, (_prompt(8, 2), 12, 12)),
-                         (it, (_prompt(4, 3), 5, 13))]:
-        np.testing.assert_array_equal(np.asarray(h.tokens),
-                                      _solo(stages, params, p, n, s),
-                                      err_msg=f"request {h.rid}")
-
-
 def test_priority_never_preempts_equal_or_higher():
     stages, _ = _model()
     eng = InferenceEngine(stages, CFG, n_slots=1,

@@ -6,8 +6,8 @@ vs decoding it alone through ``models.make_cached_decoder``, across mixed
 prompt lengths, mid-flight admissions, EOS early exits, and every sampling
 mode — and since the paged pool landed, ALSO across block-table storage,
 chunked prefill boundaries, shared prefixes and copy-on-write divergence
-(the default engine is paged, so every parity test above exercises it; the
-dense layout keeps its own parity pin). Plus the scheduler invariants (no
+(the engine has one pool, the paged one, so every parity test exercises
+it). Plus the scheduler invariants (no
 double occupancy/allocation, admission blocks on block exhaustion and
 resumes, every request completes, freed slots reuse next tick, queues drain
 above capacity), the serving metrics incl. the block-pool gauges, the
@@ -28,7 +28,7 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
     make_cached_decoder,
     make_gpt_stages,
-    make_slot_decode_step,
+    make_paged_decode_step,
     make_slot_prefill,
 )
 from simple_distributed_machine_learning_tpu.serve import (
@@ -44,7 +44,7 @@ from simple_distributed_machine_learning_tpu.serve.request import (
     validate_request,
 )
 from simple_distributed_machine_learning_tpu.serve.slots import (
-    KVCachePool,
+    PagedKVPool,
     PagedKVPool,
 )
 
@@ -204,7 +204,7 @@ def test_freed_slot_reusable_next_tick():
 
 
 def test_pool_guards():
-    pool = KVCachePool(2, 2, 2, 8, 4)
+    pool = PagedKVPool(2, 2, 2, 8, 4)
     a = pool.acquire(0)
     b = pool.acquire(1)
     assert {a, b} == {0, 1}
@@ -234,7 +234,7 @@ def test_request_validation():
     with pytest.raises(ValueError, match="max_len"):
         make_slot_prefill(stages, CFG, CFG.seq_len + 1)
     with pytest.raises(ValueError, match="max_len"):
-        make_slot_decode_step(stages, CFG, 1)
+        make_paged_decode_step(stages, CFG, 1, 4)
     # engine-independent request plumbing
     validate_request(np.zeros(3, np.int32), 2, 0.0, None, None, 32, 16)
     r = Request(rid=0, prompt=np.zeros(3, np.int32), max_new_tokens=4)
@@ -278,27 +278,6 @@ def test_streaming_callback_order():
 
 # ---------------------------------------------------------------------------
 # paged pool: chunked prefill, prefix sharing, copy-on-write, exhaustion
-
-
-@pytest.mark.slow
-def test_dense_layout_parity():
-    """The dense layout stays available (the bench baseline) and stays
-    bit-exact — the default engine is now paged, so pin dense explicitly."""
-    stages, params = _model()
-    eng = InferenceEngine(stages, CFG, n_slots=2, kv_layout="dense")
-    r1 = eng.submit(_prompt(5, 101), max_new_tokens=6, seed=111)
-    r2 = eng.submit(_prompt(8, 102), max_new_tokens=5, seed=112,
-                    temperature=0.8, top_k=5)
-    eng.drain()
-    np.testing.assert_array_equal(
-        r1.tokens, _solo(stages, params, r1.prompt, 6, 111))
-    np.testing.assert_array_equal(
-        r2.tokens, _solo(stages, params, r2.prompt, 5, 112,
-                         temperature=0.8, top_k=5))
-    with pytest.raises(ValueError, match="paged-pool knobs"):
-        InferenceEngine(stages, CFG, kv_layout="dense", prefill_chunk=4)
-    with pytest.raises(ValueError, match="kv_layout"):
-        InferenceEngine(stages, CFG, kv_layout="rowful")
 
 
 def test_chunked_prefill_bitexact_across_chunk_sizes():
@@ -707,7 +686,7 @@ def test_bench_continuous_beats_sequential():
     existed = os.path.exists(artifact)
     # rate far above service capacity so the continuous batch actually
     # fills (at low offered load both engines are arrival-bound and tie);
-    # compare=False: the paged-vs-dense comparison has its own test
+    # compare=False: the comparison rows have their own tests
     rows = measure_serving(rates=(2000.0,), n_requests=12, slots=4,
                            max_new=12, cfg=CFG, prompt_lens=(4, 8),
                            compare=False)
@@ -721,72 +700,6 @@ def test_bench_continuous_beats_sequential():
             assert r[k] is not None and r[k] > 0, (k, r)
     # CPU smoke shapes never write the TPU sweep's artifact
     assert os.path.exists(artifact) == existed
-
-
-@pytest.mark.slow
-def test_bench_paged_sustains_more_concurrency_at_fixed_memory():
-    """The tentpole's memory claim, measured: at (near-)equal KV-cache
-    bytes the paged pool boards strictly more concurrent requests than the
-    dense slot pool — a dense row reserves max_len positions, a paged
-    sequence only its actual blocks. Structural, not timing-dependent: the
-    burst arrives all at once and concurrency is capped by memory."""
-    import jax as _jax
-
-    from bench import _measure_paged_vs_dense
-    from simple_distributed_machine_learning_tpu.models.gpt import (
-        make_gpt_stages as _mk,
-    )
-
-    stages = _mk(_jax.random.key(0), CFG, n_stages=1)[0]
-    # fixed_mem only: the longprompt stall rows are timing-based and get
-    # their own slow-marked test on a prefill-dominated shape
-    rows = _measure_paged_vs_dense(stages, CFG, slots=4, n_requests=12,
-                                   max_new=8, prompt_lens=(4, 8),
-                                   block_size=8, parts=("fixed_mem",))
-    dense = next(r for r in rows
-                 if r["config"] == "gpt_serve_dense_fixed_mem")
-    paged = next(r for r in rows
-                 if r["config"] == "gpt_serve_paged_fixed_mem")
-    assert dense["completed"] == dense["n_requests"]
-    assert paged["completed"] == paged["n_requests"]
-    # same usable block capacity (paged adds only the 1-block trash page)
-    assert paged["kv_bytes"] <= dense["kv_bytes"] * 1.2
-    assert paged["max_concurrent"] > dense["max_concurrent"], (paged, dense)
-
-
-@pytest.mark.slow
-def test_bench_chunked_prefill_cuts_stall_tick_latency():
-    """The tentpole's latency claim, measured on a prefill-dominated shape
-    (long prompt ~= seq budget): with chunked prefill the worst decode-tick
-    latency under a long-prompt arrival is lower than the monolithic
-    baseline's. Timing-based, so: a shape where the effect is ~2x, and
-    best-of-3 to ride out scheduler noise."""
-    import jax as _jax
-
-    from bench import _measure_paged_vs_dense
-    from simple_distributed_machine_learning_tpu.models.gpt import (
-        GPTConfig as _Cfg,
-        make_gpt_stages as _mk,
-    )
-
-    cfg = _Cfg(vocab=64, seq_len=192, d_model=64, n_heads=4, n_layers=2)
-    stages = _mk(_jax.random.key(0), cfg, n_stages=1)[0]
-    last = None
-    for _ in range(3):
-        rows = _measure_paged_vs_dense(stages, cfg, slots=4, n_requests=8,
-                                       max_new=8, prompt_lens=(4, 8),
-                                       block_size=16,
-                                       parts=("longprompt",))
-        mono = next(r for r in rows
-                    if r["config"] == "gpt_serve_dense_longprompt")
-        chunked = next(
-            r for r in rows
-            if r["config"] == "gpt_serve_paged_chunked_longprompt")
-        last = (chunked, mono)
-        if (chunked["tick_ms_max"] < mono["tick_ms_max"]
-                and chunked["tick_ms_p95"] < mono["tick_ms_p95"]):
-            return
-    raise AssertionError(f"chunked prefill never beat monolithic: {last}")
 
 
 # ---------------------------------------------------------------------------
@@ -810,20 +723,19 @@ def _draft_model():
     return _DRAFT_STAGES
 
 
-def _spec_engine(layout="paged", slots=3, spec_k=4, draft_stages=None,
-                 draft_cfg=None, **kw):
+def _spec_engine(slots=3, spec_k=4, draft_stages=None, draft_cfg=None,
+                 **kw):
     stages, _ = _model()
-    if layout == "paged":
-        kw.setdefault("block_size", 8)
+    kw.setdefault("block_size", 8)
     return InferenceEngine(
-        stages, CFG, n_slots=slots, kv_layout=layout,
+        stages, CFG, n_slots=slots,
         draft_stages=(_draft_model() if draft_stages is None
                       else draft_stages),
         draft_cfg=draft_cfg or DRAFT_CFG, spec_k=spec_k, **kw)
 
 
 def test_spec_greedy_bitexact_mixed_and_midflight():
-    """Greedy speculative decode, paged layout: mixed prompt lengths with
+    """Greedy speculative decode: mixed prompt lengths with
     queueing plus a mid-flight admission — every request's tokens equal
     its solo decode exactly (the acceptance rule's bit-exactness pin)."""
     stages, params = _model()
@@ -864,19 +776,6 @@ def test_spec_eos_early_exit_parity():
     np.testing.assert_array_equal(r.tokens, solo[:cut])
     np.testing.assert_array_equal(
         r2.tokens, _solo(stages, params, r2.prompt, 6, 75))
-
-
-@pytest.mark.slow
-def test_spec_dense_layout_parity():
-    """The dense slot pool serves the same speculative streams."""
-    stages, params = _model()
-    eng = _spec_engine(layout="dense", slots=2)
-    handles = [eng.submit(_prompt(n, 80 + n), max_new_tokens=7, seed=80 + n)
-               for n in (3, 7, 5)]
-    eng.drain()
-    for h in handles:
-        np.testing.assert_array_equal(
-            h.tokens, _solo(stages, params, h.prompt, 7, h.seed))
 
 
 @pytest.mark.slow
@@ -949,28 +848,27 @@ def test_spec_sampled_deterministic_per_seed():
     assert a2 == b2
 
 
-def _tp_engine(layout, tp, spec=False, **kw):
+def _tp_engine(tp, spec=False, **kw):
     from simple_distributed_machine_learning_tpu.parallel.mesh import (
         make_mesh,
     )
     stages, _ = _model()
     cfg = dataclasses.replace(CFG, n_tensor_parallel=tp)
     mesh = make_mesh(n_stages=1, n_data=1, n_model=tp) if tp > 1 else None
-    if layout == "paged":
-        kw.setdefault("block_size", 8)
+    kw.setdefault("block_size", 8)
     if spec:
         kw.update(draft_stages=_draft_model(), draft_cfg=DRAFT_CFG,
                   spec_k=4)
-    return InferenceEngine(stages, cfg, n_slots=2, kv_layout=layout,
-                           mesh=mesh, **kw)
+    return InferenceEngine(stages, cfg, n_slots=2, mesh=mesh, **kw)
 
 
-def test_tp2_matches_tp1_dense():
+def test_tp2_matches_tp1():
     """TP=2 serving on a 2-CPU-device model mesh reproduces the TP=1
-    stream token-for-token (dense layout): head-sharded QKV/O + the
-    collective-matmul MLP + the pmean row-closing are the same math."""
+    stream token-for-token: head-sharded QKV/O over the head-sharded pool
+    + the collective-matmul MLP + the pmean row-closing are the same
+    math."""
     stages, params = _model()
-    eng = _tp_engine("dense", 2)
+    eng = _tp_engine(2)
     assert eng.pool.tp == 2
     handles = [eng.submit(_prompt(n, 100 + n), max_new_tokens=6,
                           seed=100 + n) for n in (4, 7)]
@@ -982,7 +880,7 @@ def test_tp2_matches_tp1_dense():
 
 @pytest.mark.slow
 def test_tp2_matches_tp1_paged_and_gauge_per_shard():
-    """Paged TP=2 parity, plus the byte accounting: the pool's
+    """TP=2 parity mid-stream, plus the byte accounting: the pool's
     serve_kv_bytes_resident gauge reports PER-SHARD bytes and equals the
     analyzer's per-shard prediction exactly."""
     from simple_distributed_machine_learning_tpu.analysis.programs import (
@@ -990,7 +888,7 @@ def test_tp2_matches_tp1_paged_and_gauge_per_shard():
         predict_kv_bytes_resident,
     )
     stages, params = _model()
-    eng = _tp_engine("paged", 2)
+    eng = _tp_engine(2)
     handles = [eng.submit(_prompt(n, 110 + n), max_new_tokens=6,
                           seed=110 + n) for n in (4, 7)]
     for _ in range(4):
@@ -1002,7 +900,7 @@ def test_tp2_matches_tp1_paged_and_gauge_per_shard():
         rows.append(h.prefill_pos if h.prefill_pos is not None
                     else int(h.prompt.shape[0]) + len(h.tokens) - 1)
     sspec = ServeSpec(dataclasses.replace(CFG, n_tensor_parallel=2),
-                      n_slots=2, kv_layout="paged", block_size=8)
+                      n_slots=2, block_size=8)
     assert (predict_kv_bytes_resident(sspec, [r for r in rows if r > 0])
             == eng.pool.stats()["kv_bytes_resident"] > 0)
     eng.drain()
@@ -1016,7 +914,7 @@ def test_tp2_with_speculation_matches_solo():
     """Both tentpole axes at once: a TP=2 target verifying a replicated
     draft's proposals still reproduces the solo stream exactly."""
     stages, params = _model()
-    eng = _tp_engine("paged", 2, spec=True)
+    eng = _tp_engine(2, spec=True)
     handles = [eng.submit(_prompt(n, 120 + n), max_new_tokens=6,
                           seed=120 + n) for n in (3, 6)]
     eng.drain()
@@ -1054,6 +952,98 @@ def test_spec_and_tp_engine_validation():
         make_slot_propose(stages,
                           dataclasses.replace(CFG, n_tensor_parallel=2),
                           16, 4)
+
+
+def test_one_layout_no_dense_name_left():
+    """The engine has one KV layout and no knob for it: ``kv_layout`` is an
+    unknown keyword (a ``TypeError``, not accepted and ignored), and
+    neither package exports a name of the deleted slot-row layout."""
+    import inspect
+
+    import simple_distributed_machine_learning_tpu.models as models
+    import simple_distributed_machine_learning_tpu.serve as serve
+    from simple_distributed_machine_learning_tpu.models import gpt
+    from simple_distributed_machine_learning_tpu.serve import slots
+
+    stages, _ = _model()
+    for layout in ("paged", "dense"):
+        with pytest.raises(TypeError, match="kv_layout"):
+            InferenceEngine(stages, CFG, n_slots=2, kv_layout=layout)
+    assert "kv_layout" not in inspect.signature(
+        InferenceEngine.__init__).parameters
+    gone = {"KVCachePool", "make_slot_decode_step", "make_slot_verify_step",
+            "make_slot_spec_tick", "make_slot_prefill"}
+    for mod in (serve, models):
+        assert not gone & set(getattr(mod, "__all__", dir(mod))), mod
+    assert not (gone - {"make_slot_prefill"}) & set(dir(gpt))
+    # one pool class, with no base it shares with another
+    pools = [c for c in vars(slots).values() if inspect.isclass(c)]
+    assert pools == [PagedKVPool] and PagedKVPool.__bases__ == (object,)
+
+
+def test_draft_prefill_is_single_device_and_takes_no_target_options():
+    """``make_slot_prefill`` is the speculative DRAFT's program now: no
+    ``mesh=`` / ``adapters=`` (the served target's options), and a
+    tensor-parallel ``cfg`` refused in ``make_slot_propose``'s words."""
+    import inspect
+
+    from simple_distributed_machine_learning_tpu.models.gpt import (
+        make_slot_propose,
+    )
+    stages, _ = _model()
+    assert list(inspect.signature(make_slot_prefill).parameters) == [
+        "stages", "cfg", "max_len", "cache_dtype"]
+    for kw in ({"mesh": None}, {"adapters": False}):
+        with pytest.raises(TypeError):
+            make_slot_prefill(stages, CFG, 16, **kw)
+    tp_cfg = dataclasses.replace(CFG, n_tensor_parallel=2)
+    said = []
+    for make, args in ((make_slot_prefill, (16,)),
+                       (make_slot_propose, (16, 4))):
+        with pytest.raises(ValueError, match="single-device") as e:
+            make(stages, tp_cfg, *args)
+        said.append(str(e.value).replace(make.__name__, "<builder>"))
+    assert said[0] == said[1]
+    assert make_slot_prefill(stages, CFG, 16) is make_slot_prefill(
+        stages, CFG, 16)
+
+
+def test_draft_programs_greedy_tokens_match_the_cached_decoder():
+    """The draft's two programs over its slot rows (prefill a row, then
+    ``spec_k`` scanned decode steps) propose, greedy, exactly the tokens
+    ``make_cached_decoder`` decodes on the draft model, in the slot's own
+    row."""
+    from simple_distributed_machine_learning_tpu.models.gpt import (
+        make_slot_propose,
+    )
+    dstages = _draft_model()
+    dparams = [s.params for s in dstages]
+    S, ml, K, slot = 2, 24, 4, 1
+    prompt = _prompt(6, 77)
+    t0 = len(prompt)
+    dec = make_cached_decoder(dstages, DRAFT_CFG, t0, K + 1)
+    want = np.asarray(dec(dparams, prompt[None], jax.random.key(0)))[0, t0:]
+    shape = (DRAFT_CFG.n_layers, S, DRAFT_CFG.n_heads, ml,
+             DRAFT_CFG.d_model // DRAFT_CFG.n_heads)
+    kc, vc = jax.numpy.zeros(shape), jax.numpy.zeros(shape)
+    greedy = (np.float32(0.0), np.int32(0), np.float32(2.0))
+    kc, vc, tok, _ = make_slot_prefill(dstages, DRAFT_CFG, ml)(
+        dparams, kc, vc, prompt[None], np.int32(slot),
+        np.zeros(2, np.uint32), *greedy)
+    assert int(tok) == want[0]
+    toks = np.zeros(S, np.int32)
+    pos = np.zeros(S, np.int32)
+    toks[slot], pos[slot] = int(tok), t0
+    kd = np.zeros((S, 2), np.uint32)
+    kc, vc, drafts, rows, kd2 = make_slot_propose(dstages, DRAFT_CFG, ml, K)(
+        dparams, kc, vc, toks, pos, kd, np.zeros(S, np.float32),
+        np.zeros(S, np.int32), np.full(S, 2.0, np.float32))
+    np.testing.assert_array_equal(np.asarray(drafts)[slot], want[1:])
+    assert rows.shape == (S, K, DRAFT_CFG.vocab)
+    np.testing.assert_array_equal(np.asarray(kd2), kd)   # greedy: no draws
+    # the written rows are the slot's own: positions [0, t0 + K)
+    live = np.abs(np.asarray(kc)).sum(axis=(0, 2, 4))     # [S, ml]
+    assert (live[slot, :t0 + K] > 0).all() and not live[slot, t0 + K:].any()
 
 
 def test_bench_spec_beats_plain_2x():

@@ -3,7 +3,7 @@
 The load-bearing claims (ISSUE 10 acceptance):
 
 - **Bit-exact recovery** — an injected ``engine-crash`` mid-flight (mixed
-  prompt lengths, sampled + greedy, paged AND dense layouts, plus a crash
+  prompt lengths, sampled + greedy, plus a crash
   DURING recovery) rebuilds the engine and re-admits every in-flight
   request from the journal such that each request's full token stream
   equals the uninterrupted run's — which itself equals the solo
@@ -19,8 +19,9 @@ The load-bearing claims (ISSUE 10 acceptance):
   rates; sustained backlog enters the load-degraded best-effort lockout
   with hysteresis.
 - **Degraded rebuild** — past ``degrade_after`` restarts the engine is
-  rebuilt in the fallback layout (speculation off, TP off, dense rows) and
-  greedy streams stay bit-exact.
+  rebuilt in the fallback layout (speculation off, TP off, the fused
+  kernel, a quantised cache and the host tier off, the paged pool kept) and
+  greedy streams stay bit-exact — for a model with recurrent state too.
 """
 
 import dataclasses
@@ -102,9 +103,8 @@ def _supervisor(tmp_path, name="journal.jsonl", clock=None, metrics=None,
     stages, _ = _model()
     kw = dict(engine_kw or {})
     kw.setdefault("n_slots", 2)
-    if kw.get("kv_layout", "paged") == "paged":
-        kw.setdefault("block_size", 4)
-        kw.setdefault("prefill_chunk", 3)
+    kw.setdefault("block_size", 4)
+    kw.setdefault("prefill_chunk", 3)
     if clock is not None:
         kw["clock"] = clock
         sup_kw["clock"] = clock
@@ -194,18 +194,14 @@ def test_empty_journal_recovers_fresh_engine(tmp_path):
 # bit-exact crash recovery
 
 
-def _fixed_run(tmp_path, name, chaos, layout="paged"):
+def _fixed_run(tmp_path, name, chaos):
     """Mixed prompt lengths, greedy AND sampled, with queueing (2 slots,
     4 requests) — optionally under a chaos schedule.  Returns the
     supervisor, each request's final tokens in rid order, and the specs
     (for solo-decode comparison)."""
-    if layout == "paged":
-        kw = {"kv_layout": "paged", "block_size": 4, "prefill_chunk": 3}
-    else:
-        kw = {"kv_layout": "dense"}
     if chaos:
         faults.install(faults.FaultPlan.parse(chaos))
-    sup = _supervisor(tmp_path, name, engine_kw=kw)
+    sup = _supervisor(tmp_path, name)
     specs = [
         dict(prompt=_prompt(5, 1), max_new_tokens=8, seed=11),
         dict(prompt=_prompt(9, 2), max_new_tokens=6, seed=12,
@@ -223,7 +219,7 @@ def _fixed_run(tmp_path, name, chaos, layout="paged"):
 
 def test_crash_recovery_bitexact_paged(tmp_path):
     """THE acceptance pin: an engine crash mid-flight (mixed prompt
-    lengths, greedy + sampled, paged layout) recovers every in-flight
+    lengths, greedy + sampled) recovers every in-flight
     request from the journal with its FULL token stream equal to the
     uninterrupted run's — which equals each request's solo decode."""
     stages, params = _model()
@@ -249,18 +245,6 @@ def test_double_crash_recovery_bitexact(tmp_path):
     sup, crashed, _ = _fixed_run(tmp_path, "crash2.jsonl",
                                  "engine-crash@serve.tick,after=3,times=2")
     assert sup.restarts == 2
-    assert crashed == base
-
-
-@pytest.mark.slow
-def test_crash_recovery_bitexact_dense(tmp_path):
-    """Same pin on the dense slot-row layout (whole-prompt resume
-    prefill)."""
-    _, base, _ = _fixed_run(tmp_path, "based.jsonl", None, layout="dense")
-    sup, crashed, _ = _fixed_run(tmp_path, "crashd.jsonl",
-                                 "engine-crash@serve.tick=3",
-                                 layout="dense")
-    assert sup.restarts == 1
     assert crashed == base
 
 
@@ -336,10 +320,10 @@ def test_finished_but_unacked_request_not_redecoded(tmp_path):
     sup2.close()
 
 
-def test_degraded_rebuild_dense_and_bitexact(tmp_path):
+def test_degraded_rebuild_keeps_the_pool_and_is_bitexact(tmp_path):
     """Past ``degrade_after`` restarts the rebuild applies the fallback
-    rule — speculation off, dense rows — and greedy streams still equal
-    the full (speculative, paged) run's."""
+    rule — speculation off, tp 1, the paged pool kept — and greedy streams
+    still equal the full (speculative) run's."""
     stages, _ = _model()
     draft_cfg = dataclasses.replace(CFG, n_layers=1)
     draft_stages = make_gpt_stages(jax.random.key(9), draft_cfg, 1)[0]
@@ -364,7 +348,73 @@ def test_degraded_rebuild_dense_and_bitexact(tmp_path):
     sup, deg = run("dcrash.jsonl", "engine-crash@serve.tick=2",
                    degrade_after=1)
     assert sup.degraded and sup.state == "degraded"
-    assert sup.engine.kv_layout == "dense" and not sup.engine.speculative
+    eng = sup.engine
+    assert not eng.speculative and eng.spec_k == 0 and eng.tp == 1
+    assert eng.pool.block_size == 4 and eng.pool.n_blocks == 2 * 12
+    assert deg == base
+
+
+def test_degraded_factory_drops_speed_features_and_keeps_the_pool():
+    """``engine_factory(...)(degraded=True)``: the fused kernel, a quantised
+    cache, the host tier and its prefetch go; ``block_size``, ``n_blocks``
+    and ``prefill_chunk`` stay as passed (the full build keeps them all)."""
+    stages, _ = _model()
+    factory = engine_factory(
+        stages, CFG, n_slots=2, max_len=32, block_size=4, n_blocks=11,
+        prefill_chunk=3, attn_kernel="fused", cache_dtype="int8",
+        host_cache_blocks=5, prefetch_ticks=2)
+    full, deg = factory(False), factory(True)
+    for eng in (full, deg):
+        assert eng.pool.block_size == 4 and eng.pool.n_blocks == 11
+        assert eng.prefill_chunk == 3 and eng.max_len == 32
+        assert eng.pool.n_slots == 2
+    assert full.attn_kernel == "fused" and deg.attn_kernel == "dense"
+    assert full.pool.quantized and not deg.pool.quantized
+    assert deg.pool.cache_dtype == np.float32
+    assert full.pool.host_cache_blocks == 5 and full.pool.prefetch_ticks == 2
+    assert deg.pool.host_cache_blocks == 0 and deg.pool.prefetch_ticks == 1
+    # a bf16 pool is no speed feature: it stays
+    assert engine_factory(stages, CFG, cache_dtype="bfloat16")(
+        True).pool.cache_dtype == jax.numpy.bfloat16
+
+
+def test_degraded_rebuild_serves_a_model_with_recurrent_state(tmp_path):
+    """A supervised hybrid (state-space + attention) deployment with
+    ``degrade_after`` set: the degraded rebuild constructs (the fallback
+    keeps the paged pool, where recurrent state lives) and every request
+    finishes bit-exact with the uncrashed run."""
+    from simple_distributed_machine_learning_tpu.models.jamba import (
+        JambaConfig,
+        make_jamba_stages,
+    )
+    cfg = JambaConfig(vocab=97, seq_len=48, d_model=64, n_heads=4,
+                      n_kv_heads=1, d_ff=128, n_layers=4, attn_period=2,
+                      attn_offset=1, expand=4, dt_rank=8)
+    stages = make_jamba_stages(jax.random.key(0), cfg)[0]
+    assert cfg.recurrent_state
+
+    def run(name, chaos):
+        if chaos:
+            faults.install(faults.FaultPlan.parse(chaos))
+        sup = ServeSupervisor(
+            engine_factory(stages, cfg, n_slots=2, max_len=48,
+                           block_size=4, prefill_chunk=5,
+                           attn_kernel="fused"),
+            str(tmp_path / name), degrade_after=1, max_restarts=2)
+        rng = np.random.default_rng(7)
+        handles = [sup.submit(rng.integers(0, cfg.vocab, n).astype(np.int32),
+                              max_new_tokens=m, seed=70 + n)
+                   for n, m in ((5, 7), (9, 6), (3, 8))]
+        sup.drain()
+        sup.close()
+        faults.uninstall()
+        return sup, [list(h.tokens) for h in handles]
+
+    _, base = run("hbase.jsonl", None)
+    sup, deg = run("hcrash.jsonl", "engine-crash@serve.tick=3")
+    assert sup.restarts == 1 and sup.degraded
+    assert sup.engine.pool.recurrent and sup.engine.attn_kernel == "dense"
+    assert all(r.state == DONE for r in sup.requests.values())
     assert deg == base
 
 

@@ -331,17 +331,6 @@ def test_bookkeeping_span_only_with_metrics_or_flight_attached(stages,
         t.id for t in by["engine.tick"]}
 
 
-def test_dense_layout_ticks_carry_the_same_phases(stages, tracer):
-    eng = InferenceEngine(stages, CFG, n_slots=2, kv_layout="dense")
-    eng.submit(_prompt(5, 1), 3)
-    eng.drain()
-    by = _by_name(tracer)
-    assert {"engine.tick", "engine.admit", "engine.decode.dispatch",
-            "engine.decode.wait", "engine.decode.emit"} <= set(by)
-    assert by["engine.admit"][0].attrs["boarded"] == 1
-    assert all(t.attrs["chunk"] == 0 for t in by["engine.tick"])
-
-
 # -- the engine's own clock never sees the recorder ----------------------------
 
 TOY = GPTConfig(vocab=32, seq_len=48, d_model=32, n_heads=2, n_layers=2)
