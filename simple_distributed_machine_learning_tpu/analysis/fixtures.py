@@ -230,13 +230,14 @@ def oob_block_table() -> Report:
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), s.params)
         for s in stages]
     kc = (jax.ShapeDtypeStruct((n_blocks + 1, bs, 2 * 4), np.float32),)
+    state = ((spec((S,), np.int32, 0, cfg.vocab - 1),
+              jax.ShapeDtypeStruct((S, 2), np.uint32)),)
     return analyze(
-        step, params, kc, kc,
-        spec((S,), np.int32, 0, cfg.vocab - 1),
+        step, params, kc, kc, state,
         spec((S,), np.int32, 0, ml - 1),
         # BUG: entries may reach n_blocks + 1 — one past the last block
         spec((S, NB), np.int32, 0, n_blocks + 1),
-        jax.ShapeDtypeStruct((S, 2), np.uint32),
+        jax.ShapeDtypeStruct((S,), np.bool_),
         jax.ShapeDtypeStruct((S,), np.float32),
         spec((S,), np.int32, 0, cfg.vocab),
         jax.ShapeDtypeStruct((S,), np.float32),
@@ -252,6 +253,7 @@ def _cow_tick_report(threaded: bool, name: str) -> Report:
 
     from simple_distributed_machine_learning_tpu.analysis import spec
     from simple_distributed_machine_learning_tpu.models.gpt import (
+        SEAT_NONE,
         make_paged_block_copy,
         make_paged_prefill_chunk,
     )
@@ -260,9 +262,11 @@ def _cow_tick_report(threaded: bool, name: str) -> Report:
     chunk = make_paged_prefill_chunk(stages, cfg, ml, bs)
     copy = make_paged_block_copy()
 
-    def tick(params, kc, vc, tokens, p0, table, kd, t, k_, p_):
-        kc2, vc2, tok, _kd2 = chunk(params, kc, vc, tokens, p0, table, kd,
-                                    t, k_, p_)
+    def tick(params, kc, vc, state, tokens, p0, table, slot, seat, kd, t,
+             k_, p_):
+        kc2, vc2, _state2, tok, _kd2 = chunk(
+            params, kc, vc, state, tokens, p0, table, slot, seat, kd, t,
+            k_, p_)
         if threaded:
             kc3, vc3 = copy(kc2, vc2, jnp.int32(2), jnp.int32(1))
         else:
@@ -276,11 +280,16 @@ def _cow_tick_report(threaded: bool, name: str) -> Report:
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), s.params)
         for s in stages]
     kc = (jax.ShapeDtypeStruct((n_blocks + 1, bs, 2 * 4), np.float32),)
+    S = 2
+    state = ((spec((S,), np.int32, 0, cfg.vocab - 1),
+              jax.ShapeDtypeStruct((S, 2), np.uint32)),)
     return analyze(
-        tick, params, kc, kc,
+        tick, params, kc, kc, state,
         spec((1, 3), np.int32, 0, cfg.vocab - 1),
         spec((), np.int32, 0, ml - 4),
         spec((3,), np.int32, 0, n_blocks),
+        spec((), np.int32, 0, S - 1),
+        spec((), np.int32, SEAT_NONE, cfg.vocab - 1),
         jax.ShapeDtypeStruct((2,), np.uint32),
         jax.ShapeDtypeStruct((), np.float32),
         spec((), np.int32, 0, cfg.vocab),

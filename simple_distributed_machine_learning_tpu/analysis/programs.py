@@ -252,6 +252,7 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
     import numpy as np
 
     from simple_distributed_machine_learning_tpu.models.gpt import (
+        SEAT_NONE,
         make_cached_decoder,
         make_paged_block_copy,
         make_paged_decode_step,
@@ -371,10 +372,18 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
         findings += _retrace_finding("make_paged_prefill_chunk",
                                      "chunk (= whole-prompt) length", sspec)
 
-    chunk_args = (params, kc, kc, spec((1, c), np.int32, 0, V - 1),
-                  spec((), np.int32, 0, ml - 1 - c), table1, kd1, f32,
-                  top_k1, f32)
-    decode_args = (params, kc, kc, toks, pos, tables, kdS, f32S, top_ks,
+    # every slot's newest token and key, which both programs keep on the
+    # device beside the pool (PagedServing.ahead): the chunk seats its
+    # slot's (``seat``: SEAT_NONE, SEAT_SAMPLE or a token), the decode
+    # reads its inputs there and writes the ``live`` slots' back
+    state = ((toks, kdS),)
+    slot1 = spec((), np.int32, 0, S - 1)
+    seat1 = spec((), np.int32, SEAT_NONE, V - 1)
+    live = _sds((S,), np.bool_)
+    chunk_args = (params, kc, kc, state, spec((1, c), np.int32, 0, V - 1),
+                  spec((), np.int32, 0, ml - 1 - c), table1, slot1, seat1,
+                  kd1, f32, top_k1, f32)
+    decode_args = (params, kc, kc, state, pos, tables, live, f32S, top_ks,
                    f32S)
     copy_args = (kc, kc, spec((), np.int32, 1, n_blocks),
                  spec((), np.int32, 0, n_blocks))
@@ -406,23 +415,25 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
                                    adapters=True),
             decode_args + (bank, aids)))
 
-    # the composite tick: chunk -> CoW copy -> decode, pool buffers
-    # threaded exactly as engine.step/_ensure_writable_range thread them.
-    # A read of the pre-call buffer after any stage donated it is the
-    # cross-program read-after-donate the donation rules exist for.
-    def paged_tick(params, kc, vc, tokens, p0, table, kd_1, t1, k1, p1,
-                   dst, src, toks, pos, tables, kds, temps, tks, tps):
-        kc, vc, tok, kd_1 = chunk(params, kc, vc, tokens, p0, table, kd_1,
-                                  t1, k1, p1)
+    # the composite tick: chunk -> CoW copy -> decode, pool and state
+    # buffers threaded exactly as engine.step/_ensure_writable_range
+    # thread them. A read of the pre-call buffer after any stage donated
+    # it is the cross-program read-after-donate the donation rules exist
+    # for.
+    def paged_tick(params, kc, vc, state, tokens, p0, table, slot, seat,
+                   kd_1, t1, k1, p1, dst, src, pos, tables, live, temps,
+                   tks, tps):
+        kc, vc, state, tok, kd_1 = chunk(params, kc, vc, state, tokens, p0,
+                                         table, slot, seat, kd_1, t1, k1,
+                                         p1)
         kc, vc = copy(kc, vc, dst, src)
-        kc, vc, toks2, kds2 = decode(params, kc, vc, toks, pos, tables,
-                                     kds, temps, tks, tps)
-        return kc, vc, tok, toks2, kds2
+        kc, vc, state, toks2, kds2 = decode(params, kc, vc, state, pos,
+                                            tables, live, temps, tks, tps)
+        return kc, vc, state, tok, toks2, kds2
 
     programs.append(Program(
         "paged_tick", paged_tick,
-        chunk_args[:1] + (kc, kc) + chunk_args[3:] + copy_args[2:]
-        + decode_args[3:]))
+        chunk_args + copy_args[2:] + decode_args[4:]))
 
     if speculative:
         from simple_distributed_machine_learning_tpu.models.gpt import (

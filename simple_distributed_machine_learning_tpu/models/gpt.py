@@ -178,18 +178,20 @@ class GPTConfig:
                       cache_dtype=None, mesh=None, kernel: str = "dense",
                       adapters: bool = False) -> "PagedServing":
         """The engine's model interface (:class:`PagedServing`): every
-        block is an attention layer with ``n_heads`` K/V heads, and a slot
-        has no state beside its blocks."""
+        block is an attention layer with ``n_heads`` K/V heads, and beside
+        its blocks a slot has its newest token and sampling key and nothing
+        else (``ahead``: the programs feed them back on the device)."""
         return PagedServing(
             kv_layers=sum(len(s.params["blocks"]) for s in stages),
             kv_heads=self.n_heads, head_dim=self.d_model // self.n_heads,
-            state_shapes=(),
+            state_shapes=(NEWEST_PAIR,),
             chunk_prefill=make_paged_prefill_chunk(
                 stages, self, max_len, block_size, cache_dtype, mesh=mesh,
                 adapters=adapters),
             decode=make_paged_decode_step(
                 stages, self, max_len, block_size, cache_dtype, mesh=mesh,
-                kernel=kernel, adapters=adapters))
+                kernel=kernel, adapters=adapters),
+            pack_decode=_leave_host_pair_behind, ahead=True)
 
 
 def _block_init(key: jax.Array, cfg: GPTConfig) -> dict:
@@ -882,8 +884,9 @@ def _tp_jit(name, body, mesh, n_buf_in, n_rest_in, n_buf_out, n_rest_out,
     buffers sharded on their HEAD axis (dim 2 of every leaf of the paged
     pool's per-layer ``[n_blocks+1, bs, H*dh]``, whose lanes hold a shard's
     heads contiguously, and of a quantized pool's scale plane; one spec is
-    the prefix of the pytree), everything else replicated. The pool buffers
-    are donated exactly as in the single-device builders."""
+    the prefix of the pytree), everything else replicated (the per-slot
+    state pair of the chunk and decode programs too: it leads the rest on
+    both sides). ``donate`` as in the single-device builders."""
     from jax.sharding import PartitionSpec as P
 
     from simple_distributed_machine_learning_tpu.parallel.compat import (
@@ -945,26 +948,31 @@ class PagedServing(NamedTuple):
     The pool holds ``kv_layers x kv_heads x head_dim`` K/V rows a position
     (the CACHE's head count: a grouped-query model's is smaller than its
     query heads'). ``state_shapes`` is a pytree of per-slot
-    ``jax.ShapeDtypeStruct``: recurrent buffers the pool keeps beside the
-    blocks, one ``[n_slots, *shape]`` array per leaf; empty for an
-    attention-only model. With it empty the programs are ``chunk_prefill(
-    params, kc, vc, tokens [1, c], p0, table, key_data, temperature, top_k,
-    top_p) -> (kc, vc, token, key_data)`` and ``decode(params, kc, vc, toks,
-    pos, tables, key_data, temps, top_ks, top_ps) -> (kc, vc, tokens,
-    key_data)``; with state they take ``state`` after ``vc`` (donated like
-    the pool) and return it after ``vc``, the chunk takes ``slot`` after
-    ``table`` and the decode ``live [S]`` after ``tables``.
+    ``jax.ShapeDtypeStruct``: device buffers the pool keeps beside the
+    blocks, one ``[n_slots, *shape]`` array per leaf (a state-space
+    layer's recurrent pair, every slot's newest token and key). With it
+    empty the programs are ``chunk_prefill(params, kc, vc, tokens [1, c],
+    p0, table, key_data, temperature, top_k, top_p) -> (kc, vc, token,
+    key_data)`` and ``decode(params, kc, vc, toks, pos, tables, key_data,
+    temps, top_ks, top_ps) -> (kc, vc, tokens, key_data)``; with state they
+    take ``state`` after ``vc`` (donated like the pool) and return it after
+    ``vc``, the chunk takes ``slot`` after ``table`` and the decode
+    ``live [S]`` after ``tables``. Both of this package's models keep
+    state: what differs is whether any of it is RECURRENT
+    (``cfg.recurrent_state``: a summary of the whole prefix, which rules
+    out prefix sharing and more, ``serve/slots.py``).
 
     ``pack_chunk`` / ``pack_decode``: where given, the engine hands them a
     program's host-side arguments (everything after the buffers) and calls
     the program with what they return instead. Every numpy argument of a
     call is a transfer of its own, about 0.13 ms each on a v5e's host (my
-    chip run, PR 28); a model may take them as one array.
+    chip run, PR 28); a model may take them as one array, or leave behind
+    what its program does not read.
 
     ``ahead``: the programs keep every slot's newest token and sampling key
-    on the device, in the LAST pair of ``state_shapes`` (``([] int32, [2]
-    uint32)`` a slot). The decode reads its ``toks`` and ``key_data`` there
-    (the host's copies it is handed are not looked at) and writes the live
+    on the device, in the LAST pair of ``state_shapes``
+    (:data:`NEWEST_PAIR`). The decode reads its ``toks`` and ``key_data``
+    there (the host's copies are not looked at) and writes the live
     slots' new ones back; the chunk takes ``seat`` after ``slot``:
     :data:`SEAT_NONE` (a mid-prompt chunk: the slot's pair stays),
     :data:`SEAT_SAMPLE` (seat its own sample and advanced key) or a token
@@ -986,6 +994,44 @@ class PagedServing(NamedTuple):
 # a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)
 SEAT_NONE = -2
 SEAT_SAMPLE = -1
+# one slot's newest token and sampling key data (PagedServing, ``ahead``)
+NEWEST_PAIR = (jax.ShapeDtypeStruct((), jnp.int32),
+               jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+
+def _seat_newest(pair, slot, seat, tok, kd, key_data):
+    """The pair ``([S] int32, [S, 2] uint32)`` after a prefill chunk of
+    ``slot`` (``PagedServing.ahead``): left as it was (:data:`SEAT_NONE`),
+    or the slot's row set to the chunk's own sample ``tok`` and advanced
+    key ``kd`` (:data:`SEAT_SAMPLE`), or to the token ``seat`` and the key
+    the chunk was handed."""
+    newest, keys = pair
+    own = seat == SEAT_SAMPLE
+    # the clamp changes no value (a negative seat is a code and takes
+    # another branch): it lets the analyzer's bounds pass prove that what
+    # the next decode looks up is a token
+    newest = newest.at[slot].set(jnp.where(
+        seat == SEAT_NONE, newest[slot],
+        jnp.where(own, tok, jnp.maximum(seat, 0))))
+    keys = keys.at[slot].set(jnp.where(
+        seat == SEAT_NONE, keys[slot], jnp.where(own, kd, key_data)))
+    return newest, keys
+
+
+def _feed_newest(pair, live, toks2, kd2):
+    """The pair after a decode step: the ``live`` slots' rows are the
+    step's samples and keys, the others' as they were (a slot between its
+    chunk and its first decode must find what the chunk seated)."""
+    toks, key_data = pair
+    return (jnp.where(live, toks2, toks),
+            jnp.where(live[:, None], kd2, key_data))
+
+
+def _leave_host_pair_behind(toks, pos, tables, live, key_data, *rest):
+    """GPT's ``PagedServing.pack_decode``: the engine's host copies of the
+    tokens and keys stay behind, the decode reads the device's."""
+    del toks, key_data
+    return (pos, tables, live, *rest)
 
 
 def _dense_block_prefill(bp, h, li, kc, vc, prompt_len, n_heads):
@@ -1537,8 +1583,8 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
                              block_size: int, cache_dtype=None, mesh=None,
                              adapters: bool = False):
     """Chunked serving prefill into paged blocks: ``chunk(params, kc, vc,
-    tokens [1, c], p0, table [NB], key_data, temperature, top_k, top_p) ->
-    (kc, vc, token, key_data)``.
+    state, tokens [1, c], p0, table [NB], slot, seat, key_data, temperature,
+    top_k, top_p) -> (kc, vc, state, token, key_data)``.
 
     Runs ONE request's prompt positions ``[p0, p0+c)`` through every block
     (batch 1, the solo decoder's math via the shared :func:`_dense_qkv` /
@@ -1554,6 +1600,15 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     the request's key stream advances exactly once, at the same point as
     its solo decode).
 
+    ``state`` is ``((newest [S] int32, keys [S, 2] uint32),)``, every
+    slot's newest token and sampling key (:data:`NEWEST_PAIR`,
+    ``PagedServing.ahead``), and ``seat`` says what this chunk leaves there
+    for ``slot``: nothing (:data:`SEAT_NONE`, a mid-prompt chunk), its own
+    sample and advanced key (:data:`SEAT_SAMPLE`), or a resumed request's
+    stored token with the key handed in (:func:`_seat_newest`). The decode
+    step reads its inputs there, so the slot's first decode waits for no
+    host.
+
     Retraces per distinct chunk length. Bit-exactness vs the solo
     ``make_cached_decoder`` holds for f32 caches: the chunk reads earlier
     K/V back out of the cache, so a bf16 cache rounds where the solo
@@ -1562,9 +1617,9 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     cache in BOTH paths, so it is exempt).
 
     ``kc``/``vc`` (one ``[n_blocks+1, block_size, H*dh]`` buffer a layer,
-    ``serve/slots.py::PagedKVPool``) are donated, every leaf — the engine
-    always threads the returned buffers back into the pool, and donation
-    lets XLA write the rows in place.
+    ``serve/slots.py::PagedKVPool``) and ``state`` are donated, every
+    leaf — the engine always threads the returned buffers back into the
+    pool, and donation lets XLA write the rows in place.
 
     ``adapters=True`` builds the multi-tenant variant: two TRACED args
     append to the signature — the stacked adapter ``bank`` pytree and the
@@ -1578,7 +1633,7 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     inside ``shard_map`` — QKV on the local ``H/tp`` heads, K/V landing in
     this shard's lanes of the head-sharded pool, the attention/MLP reduces
     of :func:`_tp_attn_tail` — with ``params`` in the
-    :func:`pack_tp_serve_params` layout.
+    :func:`pack_tp_serve_params` layout and ``state`` replicated.
     """
     _validate_paged_build(stages, cfg, max_len, block_size,
                           "make_paged_prefill_chunk", cache_dtype)
@@ -1622,30 +1677,33 @@ def _paged_chunk_fwd(blocks, embed, head, kc, vc, tokens, p0, table, H, bs,
 
 
 def _build_paged_prefill_chunk(H, bs, dh, adapters=False):
-    def run(params, kc, vc, tokens, p0, table, key_data, temperature,
-            top_k, top_p, ab_at=None):
+    def run(params, kc, vc, state, tokens, p0, table, slot, seat, key_data,
+            temperature, top_k, top_p, ab_at=None):
         embed, blocks, head = _merged_stage_trees(params)
         kc, vc, row = _paged_chunk_fwd(blocks, embed, head, kc, vc,
                                        tokens, p0, table, H, bs, dh,
                                        _dense_attn_tail, ab_at)
         tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
-        return kc, vc, tok, kd
+        pair, = state
+        return (kc, vc, (_seat_newest(pair, slot, seat, tok, kd, key_data),),
+                tok, kd)
 
     if adapters:
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def chunk_paged_prefill(params, kc, vc, tokens, p0, table, key_data,
-                                temperature, top_k, top_p, bank, aid):
-            return run(params, kc, vc, tokens, p0, table, key_data,
-                       temperature, top_k, top_p,
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
+        def chunk_paged_prefill(params, kc, vc, state, tokens, p0, table,
+                                slot, seat, key_data, temperature, top_k,
+                                top_p, bank, aid):
+            return run(params, kc, vc, state, tokens, p0, table, slot, seat,
+                       key_data, temperature, top_k, top_p,
                        _adapter_layers(bank, aid))
 
         return chunk_paged_prefill
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def chunk_paged_prefill(params, kc, vc, tokens, p0, table, key_data,
-                            temperature, top_k, top_p):
-        return run(params, kc, vc, tokens, p0, table, key_data,
-                   temperature, top_k, top_p)
+    @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
+    def chunk_paged_prefill(params, kc, vc, state, tokens, p0, table, slot,
+                            seat, key_data, temperature, top_k, top_p):
+        return run(params, kc, vc, state, tokens, p0, table, slot, seat,
+                   key_data, temperature, top_k, top_p)
 
     return chunk_paged_prefill
 
@@ -1655,45 +1713,55 @@ def _build_paged_prefill_chunk_tp(cfg, bs, dh, mesh, adapters=False):
     tail = functools.partial(_tp_attn_tail, overlap=cfg.overlap)
     H_loc = cfg.n_heads // tp
 
-    def run(params, kc, vc, tokens, p0, table, key_data, temperature,
-            top_k, top_p, ab_at=None):
+    def run(params, kc, vc, state, tokens, p0, table, slot, seat, key_data,
+            temperature, top_k, top_p, ab_at=None):
         blocks, embed, head = _tp_local_trees(params)
         kc, vc, row = _paged_chunk_fwd(blocks, embed, head, kc, vc,
                                        tokens, p0, table, H_loc, bs, dh,
                                        tail, ab_at)
         row = _close_rows(row)
         tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
-        return kc, vc, tok, kd
+        pair, = state
+        return (kc, vc, (_seat_newest(pair, slot, seat, tok, kd, key_data),),
+                tok, kd)
 
     if adapters:
-        def body(params, kc, vc, tokens, p0, table, key_data, temperature,
-                 top_k, top_p, bank, aid):
-            return run(params, kc, vc, tokens, p0, table, key_data,
-                       temperature, top_k, top_p,
+        def body(params, kc, vc, state, tokens, p0, table, slot, seat,
+                 key_data, temperature, top_k, top_p, bank, aid):
+            return run(params, kc, vc, state, tokens, p0, table, slot, seat,
+                       key_data, temperature, top_k, top_p,
                        _tp_adapter_layers(bank, aid, tp))
 
-        return _tp_jit("chunk_paged_prefill_tp", body, mesh,
-                       n_buf_in=2, n_rest_in=9, n_buf_out=2, n_rest_out=2)
+        return _tp_jit("chunk_paged_prefill_tp", body, mesh, n_buf_in=2,
+                       n_rest_in=12, n_buf_out=2, n_rest_out=3,
+                       donate=(1, 2, 3))
 
-    def body(params, kc, vc, tokens, p0, table, key_data, temperature,
-             top_k, top_p):
-        return run(params, kc, vc, tokens, p0, table, key_data,
-                   temperature, top_k, top_p)
+    def body(params, kc, vc, state, tokens, p0, table, slot, seat, key_data,
+             temperature, top_k, top_p):
+        return run(params, kc, vc, state, tokens, p0, table, slot, seat,
+                   key_data, temperature, top_k, top_p)
 
-    return _tp_jit("chunk_paged_prefill_tp", body, mesh,
-                   n_buf_in=2, n_rest_in=7, n_buf_out=2, n_rest_out=2)
+    return _tp_jit("chunk_paged_prefill_tp", body, mesh, n_buf_in=2,
+                   n_rest_in=10, n_buf_out=2, n_rest_out=3,
+                   donate=(1, 2, 3))
 
 
 def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
                            block_size: int, cache_dtype=None, mesh=None,
                            kernel: str = "dense",
                            adapters: bool = False):
-    """Paged serving decode tick: ``step(params, kc, vc, toks [S], pos [S],
-    tables [S, NB], key_data [S, 2], temps [S], top_ks [S], top_ps [S]) ->
-    (kc, vc, next_toks [S], next_key_data [S, 2])``.
+    """Paged serving decode tick: ``step(params, kc, vc, state, pos [S],
+    tables [S, NB], live [S], temps [S], top_ks [S], top_ps [S]) -> (kc,
+    vc, state, next_toks [S], next_key_data [S, 2])``.
 
     ONE batched token step over ALL ``n_slots`` slots — static shapes, so
     a single compiled program serves every tick regardless of occupancy.
+    Every slot's input token and sampling key are ``state``'s pair
+    ``((newest [S] int32, keys [S, 2] uint32),)``, where the chunk that
+    finished the slot's prompt seated them and where the ``live`` slots'
+    new ones go back (:func:`_feed_newest`, ``PagedServing.ahead``): the
+    next step needs nothing from the host that this one computes, and the
+    engine launches it before it has read this one's tokens.
     Each slot consumes its carried token at its own position, lands its new
     K/V via a per-slot scatter into physical block ``tables[s, pos // bs]``
     at offset ``pos % bs``, attends the row assembled from its block table
@@ -1707,8 +1775,9 @@ def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
     A non-decoding slot's table entries may alias blocks reused by a live
     request, so the ENGINE routes those slots' tick inputs to the trash
     block (``pos = 0``, all-trash table) — their garbage K/V lands where
-    no real table points, and the engine discards their tokens host-side.
-    ``kc``/``vc`` are donated (one in-place pool update per tick).
+    no real table points, the engine discards their tokens host-side and
+    their pair stays as it was (``live`` is false for them).
+    ``kc``/``vc``/``state`` are donated (one in-place update per tick).
 
     With ``cfg.n_tensor_parallel > 1`` (pass the ``mesh``): the shard_map
     twin over the head-sharded block pool
@@ -1777,30 +1846,32 @@ def _paged_decode_fwd(blocks, embed, head, kc, vc, toks, pos, tables, H, bs,
 
 
 def _build_paged_decode_step(H, bs, dh, kernel="dense", adapters=False):
-    def run(params, kc, vc, toks, pos, tables, key_data, temps, top_ks,
-            top_ps, ab_at=None):
+    def run(params, kc, vc, state, pos, tables, live, temps, top_ks, top_ps,
+            ab_at=None):
+        pair, = state
+        toks, key_data = pair
         embed, blocks, head = _merged_stage_trees(params)
         kc, vc, rows = _paged_decode_fwd(blocks, embed, head, kc, vc, toks,
                                          pos, tables, H, bs, dh,
                                          _dense_attn_tail, kernel, ab_at)
         toks2, kd2 = jax.vmap(_sample_dyn)(rows, key_data, temps,
                                            top_ks, top_ps)
-        return kc, vc, toks2, kd2
+        return kc, vc, (_feed_newest(pair, live, toks2, kd2),), toks2, kd2
 
     if adapters:
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step_paged_decode(params, kc, vc, toks, pos, tables, key_data,
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
+        def step_paged_decode(params, kc, vc, state, pos, tables, live,
                               temps, top_ks, top_ps, bank, aids):
-            return run(params, kc, vc, toks, pos, tables, key_data,
-                       temps, top_ks, top_ps, _adapter_layers(bank, aids))
+            return run(params, kc, vc, state, pos, tables, live, temps,
+                       top_ks, top_ps, _adapter_layers(bank, aids))
 
         return step_paged_decode
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step_paged_decode(params, kc, vc, toks, pos, tables, key_data,
-                          temps, top_ks, top_ps):
-        return run(params, kc, vc, toks, pos, tables, key_data, temps,
-                   top_ks, top_ps)
+    @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
+    def step_paged_decode(params, kc, vc, state, pos, tables, live, temps,
+                          top_ks, top_ps):
+        return run(params, kc, vc, state, pos, tables, live, temps, top_ks,
+                   top_ps)
 
     return step_paged_decode
 
@@ -1811,8 +1882,10 @@ def _build_paged_decode_step_tp(cfg, bs, dh, mesh, kernel="dense",
     tail = functools.partial(_tp_attn_tail, overlap=cfg.overlap)
     H_loc = cfg.n_heads // tp
 
-    def run(params, kc, vc, toks, pos, tables, key_data, temps, top_ks,
-            top_ps, ab_at=None):
+    def run(params, kc, vc, state, pos, tables, live, temps, top_ks, top_ps,
+            ab_at=None):
+        pair, = state
+        toks, key_data = pair
         blocks, embed, head = _tp_local_trees(params)
         kc, vc, rows = _paged_decode_fwd(blocks, embed, head, kc, vc, toks,
                                          pos, tables, H_loc, bs, dh, tail,
@@ -1820,25 +1893,26 @@ def _build_paged_decode_step_tp(cfg, bs, dh, mesh, kernel="dense",
         rows = _close_rows(rows)
         toks2, kd2 = jax.vmap(_sample_dyn)(rows, key_data, temps,
                                            top_ks, top_ps)
-        return kc, vc, toks2, kd2
+        return kc, vc, (_feed_newest(pair, live, toks2, kd2),), toks2, kd2
 
     if adapters:
-        def body(params, kc, vc, toks, pos, tables, key_data, temps,
-                 top_ks, top_ps, bank, aids):
-            return run(params, kc, vc, toks, pos, tables, key_data,
-                       temps, top_ks, top_ps,
-                       _tp_adapter_layers(bank, aids, tp))
+        def body(params, kc, vc, state, pos, tables, live, temps, top_ks,
+                 top_ps, bank, aids):
+            return run(params, kc, vc, state, pos, tables, live, temps,
+                       top_ks, top_ps, _tp_adapter_layers(bank, aids, tp))
 
-        return _tp_jit("step_paged_decode_tp", body, mesh,
-                       n_buf_in=2, n_rest_in=9, n_buf_out=2, n_rest_out=2)
+        return _tp_jit("step_paged_decode_tp", body, mesh, n_buf_in=2,
+                       n_rest_in=9, n_buf_out=2, n_rest_out=3,
+                       donate=(1, 2, 3))
 
-    def body(params, kc, vc, toks, pos, tables, key_data, temps, top_ks,
+    def body(params, kc, vc, state, pos, tables, live, temps, top_ks,
              top_ps):
-        return run(params, kc, vc, toks, pos, tables, key_data, temps,
-                   top_ks, top_ps)
+        return run(params, kc, vc, state, pos, tables, live, temps, top_ks,
+                   top_ps)
 
-    return _tp_jit("step_paged_decode_tp", body, mesh,
-                   n_buf_in=2, n_rest_in=7, n_buf_out=2, n_rest_out=2)
+    return _tp_jit("step_paged_decode_tp", body, mesh, n_buf_in=2,
+                   n_rest_in=7, n_buf_out=2, n_rest_out=3,
+                   donate=(1, 2, 3))
 
 
 def make_paged_block_copy():

@@ -6,8 +6,10 @@ cache's shape and its two compiled programs from the model
 (``cfg.paged_serving(...)`` -> ``models/gpt.py::PagedServing``) and nowhere
 else: GPT (``models/gpt.py``) is attention in every block and nothing
 else; a model with state-space layers (``models/jamba.py``) also has a
-recurrent buffer per slot, which rides beside the K/V pool through both
-programs.
+recurrent buffer per slot. Either keeps per-slot buffers in the pool
+(``pool.state``: every slot's newest token and key, and the recurrent
+buffers where there are any), which ride beside the K/V blocks through
+both programs.
 
 - ``submit(prompt, ...) -> Request`` enqueues one sequence with its own
   sampling params and seeded key stream, and returns the live handle
@@ -27,16 +29,19 @@ the stale-write note in ``serve/slots.py``). Only a speculative DRAFT
 model keeps one contiguous row per slot (:meth:`InferenceEngine.
 _init_draft_pool`).
 
-Device state is exactly the pool's K/V buffers; everything else (positions,
-last tokens, block tables, key streams, request lifecycle) is host-side
-numpy assembled into each tick's inputs — the scheduler stays plain Python
-while every FLOP runs inside the compiled programs. A model whose programs
-keep every slot's newest token and key on the device as well
-(``PagedServing.ahead``) gets the tick of :meth:`InferenceEngine._tick_ahead`:
-decode, then the chunk, and the NEXT tick's decode launched before this
-tick's tokens are read, so the device works through the host's share of
-the tick. A request's tokens are the same; the slot a chunk seats decodes
-from the tick after.
+Device state is exactly the pool's buffers; everything else (positions,
+block tables, request lifecycle, and the host's copy of last tokens and
+key streams) is host-side numpy assembled into each tick's inputs — the
+scheduler stays plain Python while every FLOP runs inside the compiled
+programs. A model whose programs keep every slot's newest token and key on
+the device (``PagedServing.ahead``: both of this package's) gets the tick
+of :meth:`InferenceEngine._tick_ahead`: decode, then the chunk, and the
+NEXT tick's decode launched before this tick's tokens are read, so the
+device works through the host's share of the tick. A request's tokens are
+the same; the slot a chunk seats decodes from the tick after. Speculative
+decoding keeps the plain tick (chunk, then :meth:`InferenceEngine.
+_spec_tick`): its draft and its token budget need the host's tokens every
+tick.
 
 Correctness anchor: a request's tokens are bit-exact vs decoding it alone
 via ``make_cached_decoder`` with the same seed (tests/test_serve.py) —
@@ -291,15 +296,19 @@ class InferenceEngine:
                                 tp=self.tp,
                                 host_cache_blocks=host_cache_blocks,
                                 prefetch_ticks=prefetch_ticks,
-                                state_shapes=serving.state_shapes)
+                                state_shapes=serving.state_shapes,
+                                recurrent=cfg.recurrent_state)
         self._chunk_prefill = serving.chunk_prefill
         self._decode = serving.decode
         self._pack_chunk = serving.pack_chunk
         self._pack_decode = serving.pack_decode
         # the model's programs keep the newest tokens on the device
-        # (PagedServing.ahead): the tick is _tick_ahead's, and _ahead
-        # the decode it has dispatched for the next one
-        self._dispatch_ahead = serving.ahead
+        # (PagedServing.ahead: its chunks are told what to seat): the tick
+        # is _tick_ahead's, and _ahead the decode it has dispatched for
+        # the next one. Not under speculation, whose tick reads the
+        # host's tokens (the pair its chunks seat is then never read)
+        self._seats_newest = serving.ahead
+        self._dispatch_ahead = serving.ahead and not self.speculative
         self._ahead = None
         from simple_distributed_machine_learning_tpu.models.gpt import (
             SEAT_NONE,
@@ -424,10 +433,11 @@ class InferenceEngine:
     def _place_tp(self, mesh) -> None:
         """Shard the serving state for the TP programs: the K/V pool
         buffers split over their head axis (per-chip KV drops by ``tp``),
-        the dense stage weights sliced into the Megatron serving layout
+        the per-slot state (newest tokens and keys) replicated, the dense
+        stage weights sliced into the Megatron serving layout
         (``pack_tp_serve_params``) with block shards on the model axis and
         embed/head replicated. One placement at construction; donation
-        keeps the pool buffers sharded across ticks."""
+        keeps the pool buffers where they were placed across ticks."""
         import jax
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
@@ -450,6 +460,8 @@ class InferenceEngine:
         stacked, rep = pack_tp_serve_params(self.params, self.tp)
         blk_sh = NamedSharding(mesh, P(MODEL_AXIS))
         rep_sh = NamedSharding(mesh, P())
+        self.pool.state = jax.tree.map(
+            lambda leaf: jax.device_put(leaf, rep_sh), self.pool.state)
         self.params = (
             [jax.tree.map(lambda leaf: jax.device_put(leaf, blk_sh), bp)
              for bp in stacked],
@@ -648,8 +660,9 @@ class InferenceEngine:
                       prefix_declined=(self.pool.prefix_declined_total
                                        - declined))
         chunk = int(bool(self._prefilling))
+        ahead = 0
         if self._dispatch_ahead:
-            emitted, decode_active = self._tick_ahead()
+            emitted, decode_active, ahead = self._tick_ahead()
         else:
             emitted = self._prefill_tick()
             # occupancy the batched decode actually RUNS at — sampled
@@ -676,7 +689,7 @@ class InferenceEngine:
                 if self.flight is not None:
                     self.flight.snap(self, self._tick_count, emitted)
         sp.set(chunk=chunk, decoding=decode_active, emitted=emitted,
-               queue=self.scheduler.queue_depth,
+               ahead=ahead, queue=self.scheduler.queue_depth,
                state_slots=self._state_slots(),
                kv_blocks=self.pool.blocks_in_use)
         return emitted
@@ -698,12 +711,15 @@ class InferenceEngine:
         tick. ``live - predicted`` is the drift gauge: exactly 0 without
         prefix sharing, ≤ 0 with it (sharing only shrinks the truth), and
         > 0 only if the pool leaks blocks the model says no live sequence
-        can be pinning."""
+        can be pinning. A slot of the decode already dispatched for the
+        next tick (:meth:`_tick_ahead`) has that position's row allocated:
+        it counts one more than the host has emitted."""
+        in_flight = self._ahead[0] if self._ahead else ()
         rows = []
         for s in self.pool.active_slots():
             r = self.requests[self.pool.occupant(s)]
             n = (r.prefill_pos if r.prefill_pos is not None
-                 else int(self.pool.positions[s]))
+                 else int(self.pool.positions[s]) + (r.rid in in_flight))
             if n > 0:
                 rows.append(n)
         if self.pool.recurrent:
@@ -905,7 +921,7 @@ class InferenceEngine:
             t_start = self._now = self._clock()
             self._ensure_writable_range(r.slot, p0, c)
             seat = ()
-            if self._dispatch_ahead:
+            if self._seats_newest:
                 # what the chunk leaves as the slot's newest token on the
                 # device: nothing mid-prompt, its own sample, or a resumed
                 # request's stored one (as _prefill_emit seats the host's)
@@ -915,8 +931,8 @@ class InferenceEngine:
             args = (
                 seq[None, p0:p0 + c], np.int32(p0),
                 self.pool.device_table(r.slot),
-                # a model with recurrent state is told whose rows these are
-                *((np.int32(r.slot),) if self.pool.recurrent else ()), *seat,
+                # a model with per-slot state is told whose rows these are
+                *((np.int32(r.slot),) if self.pool.has_state else ()), *seat,
                 r.key_data, np.float32(r.temperature),
                 np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
                 np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
@@ -995,12 +1011,12 @@ class InferenceEngine:
     def _run_paged(self, program, pack, *args):
         """Call one of the model's two paged programs (its host-side
         arguments through the model's ``pack``, where it has one) and take
-        the donated pool buffers back — and the recurrent state buffers
+        the donated pool buffers back — and the per-slot state buffers
         that ride beside them, where the model has any."""
         pool = self.pool
         if pack is not None:
             args = pack(*args)      # the model takes them as one transfer
-        if pool.recurrent:
+        if pool.has_state:
             pool.kc, pool.vc, pool.state, tok, kd = program(
                 self.params, pool.kc, pool.vc, pool.state, *args)
         else:
@@ -1048,9 +1064,10 @@ class InferenceEngine:
                 toks[s] = self.pool.last_token[s]
             bank_args = self._bank_args(self._adapter_inputs(active))
             live = ()
-            if self.pool.recurrent:
-                # the slots whose recurrent state this tick advances; the
-                # others' (mid-prefill, free) must come back unchanged
+            if self.pool.has_state:
+                # the slots whose state this tick advances; the others'
+                # (mid-prefill, seated and not yet decoding, free) must
+                # come back unchanged
                 live = (np.zeros(S, bool),)
                 live[0][active] = True
         with tracing.span("engine.decode.dispatch"):
@@ -1070,7 +1087,9 @@ class InferenceEngine:
         emits, admits and prepares. A request that can end on a token
         (``eos_id``) makes the next tick's slots unknowable: that tick
         dispatches its own decode, in the same order. Returns the tokens
-        emitted and the slots that decoded."""
+        emitted, the slots that decoded, and 1 where their decode had been
+        dispatched by the tick before (else 0: the ``engine.tick`` span's
+        ``ahead``)."""
         ahead, self._ahead = self._ahead, None
         if ahead is None:
             seats = [(s, int(self.pool.positions[s]))
@@ -1093,7 +1112,7 @@ class InferenceEngine:
         emitted = self._emit_decoded(*dec) if dec else 0
         if chunk is not None:
             emitted += self._prefill_finish(chunk)
-        return emitted, len(dec[0]) if dec else 0
+        return emitted, len(dec[0]) if dec else 0, int(ahead is not None)
 
     def _seats_ahead(self, decoding, chunk) -> list[tuple[int, int]] | None:
         """``(slot, position)`` of the NEXT tick's decode while this

@@ -17,20 +17,24 @@ into a shared block copies it first. A sequence's memory footprint is
 sequence uses it or not, so concurrency is a function of the tokens
 actually resident.
 
-Recurrent state (``state_shapes``): a model with state-space layers
-(``models/jamba.py``) keeps, beside the K/V blocks of its few attention
-layers, a fixed-size recurrent buffer per slot and layer — no blocks, no
-length, nothing to share. :class:`PagedKVPool` holds those buffers
-(``pool.state``, one ``[n_slots, ...]`` array per leaf, indexed by slot)
-under the same slot discipline. Such state summarises a whole prefix in
-place, so a pool that has it matches and registers NO prompt prefix (a
-shared block would come without the state that belongs to it): every bind
-starts at position 0, where the model's prefill zeroes the slot's rows —
-a bound slot never sees its last occupant's state. Requests that WOULD
-have matched are counted (``prefix_declined_total``). A model may keep
-every slot's newest token and sampling key among these buffers too
-(``models/gpt.py::PagedServing.ahead``); ``last_token`` here then trails
-the device by the tick in flight.
+Per-slot state (``state_shapes``): device buffers beside the blocks
+(``pool.state``, one ``[n_slots, ...]`` array per leaf, indexed by slot,
+donated through the model's programs with the blocks). Two things live
+there, and they are two facts. Every model of this package keeps each
+slot's newest token and sampling key there
+(``models/gpt.py::PagedServing.ahead``; ``last_token`` here then trails the
+device by the tick in flight): a token and a key are a request's own, a
+chunk seats them, and nothing about the blocks changes. ``recurrent`` says
+the other thing: a model with state-space layers (``models/jamba.py``)
+also keeps, beside the K/V blocks of its few attention layers, a
+fixed-size recurrent buffer per slot and layer — no blocks, no length,
+nothing to share. Such state summarises a whole prefix in place, so a
+``recurrent`` pool matches and registers NO prompt prefix (a shared block
+would come without the state that belongs to it), has no host tier and no
+tensor-parallel placement: every bind starts at position 0, where the
+model's prefill zeroes the slot's rows — a bound slot never sees its last
+occupant's state. Requests that WOULD have matched are counted
+(``prefix_declined_total``).
 
 The slot free list is invariant-guarded: acquiring an occupied slot or
 releasing a free one raises instead of silently corrupting a neighbor's
@@ -200,7 +204,8 @@ class PagedKVPool:
                  max_len: int, head_dim: int, cache_dtype=None,
                  block_size: int = 16, n_blocks: int | None = None,
                  tp: int = 1, host_cache_blocks: int = 0,
-                 prefetch_ticks: int = 1, state_shapes=()) -> None:
+                 prefetch_ticks: int = 1, state_shapes=(),
+                 recurrent: bool = False) -> None:
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if max_len < 2:
@@ -216,11 +221,14 @@ class PagedKVPool:
         self.tp = _check_tp(n_heads, tp)
         import jax
 
-        # per-slot recurrent buffers beside the blocks (module docstring,
-        # "Recurrent state"): one array per leaf, not one over the layers,
-        # so a layer's update never copies its neighbours
+        # per-slot buffers beside the blocks (module docstring, "Per-slot
+        # state"): one array per leaf, not one over the layers, so a
+        # layer's update never copies its neighbours. ``recurrent``: some
+        # of them summarise the slot's whole prefix (the model's
+        # ``cfg.recurrent_state``)
         leaves = jax.tree.leaves(state_shapes)
-        self.recurrent = bool(leaves)
+        self.has_state = bool(leaves)
+        self.recurrent = bool(recurrent)
         if self.recurrent and (host_cache_blocks or self.tp > 1):
             raise ValueError(
                 "recurrent state has no host offload tier and no "

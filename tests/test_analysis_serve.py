@@ -163,11 +163,14 @@ def _paged_decode_args(stages, tables_hi, pos_hi, S=2, ml=16, bs=4):
         for s in stages]
     kc = (jax.ShapeDtypeStruct((nb + 1, bs, CFG.d_model),
                                np.float32),) * CFG.n_layers
-    return (params, kc, kc,
-            spec((S,), np.int32, 0, CFG.vocab - 1),
+    # every slot's newest token and key ride beside the pool (the
+    # programs' ``state``); ``live`` says whose the step writes back
+    state = ((spec((S,), np.int32, 0, CFG.vocab - 1),
+              jax.ShapeDtypeStruct((S, 2), np.uint32)),)
+    return (params, kc, kc, state,
             spec((S,), np.int32, 0, pos_hi),
             spec((S, -(-ml // bs)), np.int32, 0, tables_hi),
-            jax.ShapeDtypeStruct((S, 2), np.uint32),
+            jax.ShapeDtypeStruct((S,), np.bool_),
             jax.ShapeDtypeStruct((S,), np.float32),
             spec((S,), np.int32, 0, CFG.vocab),
             jax.ShapeDtypeStruct((S,), np.float32)), nb
@@ -256,8 +259,10 @@ def test_no_contracts_at_all_still_runs_bounds(stages):
     # vacuously-clean hole, not a clean proof
     step = make_paged_decode_step(stages, CFG, 16, 4)
     args, _ = _paged_decode_args(stages, tables_hi=None, pos_hi=15)
-    plain = [jax.ShapeDtypeStruct(a.sds.shape, a.sds.dtype)
-             if hasattr(a, "sds") else a for a in args]
+    plain = jax.tree.map(
+        lambda a: (jax.ShapeDtypeStruct(a.sds.shape, a.sds.dtype)
+                   if hasattr(a, "sds") else a),
+        list(args), is_leaf=lambda a: hasattr(a, "sds"))
     report = analyze(step, *plain)
     assert any(f.rule == "scatter-bounds.unproven-promise"
                for f in report.findings), report.format()
@@ -432,6 +437,9 @@ def test_predicted_resident_bytes_match_gauge(stages, block_size, n_reqs,
                       block_size=block_size)
     for _ in range(n_reqs + 2):      # prefills (one per tick) + decodes
         engine.step()
+        # the next tick's decode is already dispatched (the engine's tick
+        # ahead): its slots have that position's row allocated
+        in_flight = engine._ahead[0] if engine._ahead else ()
         rows = []
         for h in handles:
             if h.state != "active":
@@ -439,7 +447,8 @@ def test_predicted_resident_bytes_match_gauge(stages, block_size, n_reqs,
             if h.prefill_pos is not None:        # mid-prefill
                 rows.append(h.prefill_pos)
             else:
-                rows.append(int(h.prompt.shape[0]) + len(h.tokens) - 1)
+                rows.append(int(h.prompt.shape[0]) + len(h.tokens) - 1
+                            + (h.rid in in_flight))
         predicted = predict_kv_bytes_resident(sspec,
                                               [r for r in rows if r > 0])
         assert predicted == engine.pool.stats()["kv_bytes_resident"], (

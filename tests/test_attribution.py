@@ -177,7 +177,7 @@ def test_attribution_reconciles_across_every_serving_path():
     chunked prefill — every fold reconciles (drift within float
     rounding), with the per-scenario request counts pinned."""
     expected_requests = {
-        "overload-shed": 11,          # queue-heavy: only completions fold
+        "overload-shed": 9,           # queue-heavy: only completions fold
         "crash-serve": 16,
         "offload-churn": 24,          # host-prefetch gate path
         "handoff-replica-loss": 16,   # fleet handoff path
@@ -218,11 +218,11 @@ def test_crash_serve_autopsy_pinned_with_recovered_rid():
 def test_overload_shed_autopsy_pinned():
     rep = run_scenario("overload-shed", _model(), CFG, trace=True)
     att = rep["attribution"]
-    assert att["requests"] == 11
+    assert att["requests"] == 9
     top = att["top_slow"][0]
-    assert top["rid"] == 2 and top["cls"] == "batch"
-    assert top["ttft_ms"] == 351.149
-    assert top["components_ms"] == {"queue": 333.15, "prefill": 18.0}
+    assert top["rid"] == 26 and top["cls"] == "interactive"
+    assert top["ttft_ms"] == 69.143
+    assert top["components_ms"] == {"queue": 61.144, "prefill": 8.0}
 
 
 def test_attribution_block_deterministic():

@@ -217,16 +217,19 @@ def _gpt_programs():
     serving = cfg.paged_serving([types.SimpleNamespace(params=params)], ml,
                                 bs, "bfloat16", kernel="fused")
     pool = (_sd((nb + 1, bs, d), jnp.bfloat16),) * L
+    # every slot's newest token and key, donated with the pool
+    state = jax.tree.map(lambda sd: _sd((S, *sd.shape), sd.dtype),
+                         serving.state_shapes)
     i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
-    return pool, {
+    return pool, state, {
         "decode": (serving.decode, (
-            [params], pool, pool, _sd((S,), i32), _sd((S,), i32),
-            _sd((S, ml // bs), i32), _sd((S, 2), u32), _sd((S,), f32),
+            [params], pool, pool, state, _sd((S,), i32),
+            _sd((S, ml // bs), i32), _sd((S,), jnp.bool_), _sd((S,), f32),
             _sd((S,), i32), _sd((S,), f32))),
         "chunk": (serving.chunk_prefill, (
-            [params], pool, pool, _sd((1, c), i32), _sd((), i32),
-            _sd((ml // bs,), i32), _sd((2,), u32), _sd((), f32),
-            _sd((), i32), _sd((), f32)))}
+            [params], pool, pool, state, _sd((1, c), i32), _sd((), i32),
+            _sd((ml // bs,), i32), _sd((), i32), _sd((), i32),
+            _sd((2,), u32), _sd((), f32), _sd((), i32), _sd((), f32)))}
 
 
 def _hybrid_programs():
@@ -255,7 +258,9 @@ def _hybrid_programs():
     host, = pack_decode_inputs(z, z, np.zeros((S, ml // bs), np.int32), z,
                                None, z.astype(np.float32), z,
                                z.astype(np.float32))
-    return pool, {"hybrid-decode": (serving.decode, (
+    # its state is mostly the recurrent buffers, which this test leaves
+    # alone: no pair to hold to the byte here
+    return pool, (), {"hybrid-decode": (serving.decode, (
         [params], pool, pool, state, _sd(host.shape, host.dtype)))}
 
 
@@ -276,7 +281,9 @@ def test_serve_programs_leave_the_pool_where_it_is(one_chip, mosaic,
     in place and hands them to the kernel untouched: nothing as large as
     one layer's K buffer is copied, sliced, transposed or padded, every
     pool byte is aliased input to output, and the temporaries are smaller
-    than one layer's K buffer.
+    than one layer's K buffer. GPT's two programs hold every slot's newest
+    token and key beside the pool (``PagedServing.ahead``): every byte of
+    that pair is aliased too.
 
     What the compiler may still do on its own: stage a buffer in its on-chip
     memory around a kernel call and write it back (an asynchronous
@@ -285,8 +292,8 @@ def test_serve_programs_leave_the_pool_where_it_is(one_chip, mosaic,
     (two of 72 at 36 layers; none at 1,024 blocks; sandbox compile, PR 29).
     That is no re-layout of the pool and is left to it; a ``copy-start``
     that stays in one memory space is refused like a ``copy``."""
-    pool, programs = (_hybrid_programs() if program == "hybrid-decode"
-                      else _gpt_programs())
+    pool, pair, programs = (_hybrid_programs() if program == "hybrid-decode"
+                            else _gpt_programs())
     fn, args = programs[program]
     compiled = fn.lower(*_on_chip(args, one_chip)).compile()
     layer = math.prod(pool[0].shape) * pool[0].dtype.itemsize
@@ -304,7 +311,10 @@ def test_serve_programs_leave_the_pool_where_it_is(one_chip, mosaic,
         moved.append(line.strip()[:200])
     assert not moved, moved
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * len(pool) * layer
+    pair_bytes = sum(math.prod(sd.shape) * sd.dtype.itemsize
+                     for sd in jax.tree.leaves(pair))
+    assert pair_bytes == (192 if pair else 0)
+    assert mem.alias_size_in_bytes >= 2 * len(pool) * layer + pair_bytes
     assert mem.temp_size_in_bytes < layer, (mem.temp_size_in_bytes, layer)
 
 

@@ -329,9 +329,9 @@ def test_affinity_routes_to_prefix_holder(tmp_path):
 def test_diurnal_autoscale_trajectory_pinned():
     """The fleet-autoscale-diurnal scenario walks the whole autoscaler
     state machine in one virtual-clock run, and the trajectory is EXACT:
-    scale-out to 3 at the first peak (ticks 30/36), drain-then-retire
-    back to 1 in the trough (tick 61), scale-out again at the second
-    peak (ticks 76/78)."""
+    scale-out to 3 at the first peak (ticks 33/39), drain-then-retire
+    back to 1 in the trough (tick 68), scale-out again at the second
+    peak (ticks 85/87)."""
     stages, _ = _model()
     report = run_scenario("fleet-autoscale-diurnal", stages, CFG)
     assert report["slo_ok"] is True
@@ -339,12 +339,12 @@ def test_diurnal_autoscale_trajectory_pinned():
     log = [(e["event"], e["replica"], e["tick"], e["alive"])
            for e in report["fleet"]["replica_log"]]
     assert log == [
-        ("scale-out", 1, 30, 2),
-        ("scale-out", 2, 36, 3),
-        ("retire", 2, 61, 2),
-        ("retire", 1, 61, 1),
-        ("scale-out", 3, 76, 2),
-        ("scale-out", 4, 78, 3),
+        ("scale-out", 1, 33, 2),
+        ("scale-out", 2, 39, 3),
+        ("retire", 2, 68, 2),
+        ("retire", 1, 68, 1),
+        ("scale-out", 3, 85, 2),
+        ("scale-out", 4, 87, 3),
     ]
     assert report["fleet"]["scale_outs"] == 4
     assert report["fleet"]["retired"] == 2

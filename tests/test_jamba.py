@@ -467,9 +467,9 @@ def test_mechanisms_built_for_kv_blocks_alone_are_refused_by_name(
     assert name in str(e.value) and "recurrent state" in str(e.value)
 
 
-def test_gpt_engine_has_no_state_and_reports_zero(stages):
-    """The first model of the interface: empty recurrent shapes, programs
-    and names as they were."""
+def test_gpt_engine_has_no_recurrent_state_and_reports_zero(stages):
+    """The first model of the interface: its only state is every slot's
+    newest token and key, nothing recurrent; program names as they were."""
     from simple_distributed_machine_learning_tpu.models.gpt import (
         GPTConfig,
         make_gpt_stages,
@@ -478,7 +478,9 @@ def test_gpt_engine_has_no_state_and_reports_zero(stages):
     gstages = make_gpt_stages(jax.random.key(0), cfg, 1)[0]
     eng = InferenceEngine(gstages, cfg, n_slots=2, block_size=4,
                           prefill_chunk=4)
-    assert eng.pool.state == () and not eng.pool.recurrent
+    assert [leaf.shape for leaf in jax.tree.leaves(eng.pool.state)] \
+        == [(2,), (2, 2)]
+    assert eng.pool.has_state and not eng.pool.recurrent
     assert eng._decode.__name__ == "step_paged_decode"
     assert eng._chunk_prefill.__name__ == "chunk_paged_prefill"
     mark = len(tracing.current().spans())

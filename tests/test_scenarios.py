@@ -342,16 +342,16 @@ def test_overload_shed_protects_interactive_vs_fcfs_baseline():
     rep = run_scenario("overload-shed", stages, CFG)
     assert rep["slo_ok"] and rep["supervised"]
     assert rep["completed"] + rep["shed"] == rep["n_requests"] == 36
-    assert rep["completed"] == 11 and rep["shed"] == 25
-    assert rep["shed_by_reason"] == {"backpressure": 5, "class": 18,
-                                     "deadline": 2}
+    assert rep["completed"] == 9 and rep["shed"] == 27
+    assert rep["shed_by_reason"] == {"backpressure": 6, "class": 18,
+                                     "deadline": 3}
     # the 18 class sheds prove the best-effort lockout ENGAGED mid-burst;
     # the final gauge reads 0 because the hysteresis correctly lifts the
     # mode once the backlog drains (the latch regression's pin)
     assert rep["degraded"] == 0
     att = rep["slo"]["interactive"]
     assert att["ttft_attainment"] == 1.0 and att["ok"]
-    assert att["ttft_ms_p95"] == 75.651
+    assert att["ttft_ms_p95"] == 69.143
 
     base = run_scenario("overload-shed", stages, CFG, scheduler="fcfs",
                         supervised=False)
@@ -359,7 +359,7 @@ def test_overload_shed_protects_interactive_vs_fcfs_baseline():
     assert base["all_completed"] and base["shed"] == 0   # nothing enforced
     f_att = base["slo"]["interactive"]
     assert f_att["ttft_attainment"] == 0.0 and not f_att["ok"]
-    assert f_att["ttft_ms_p95"] == 995.326               # ~10x the target
+    assert f_att["ttft_ms_p95"] == 1021.326              # ~10x the target
     # the pinned gap: shedding is what buys the attainment
     assert att["ttft_ms_p95"] * 10 < f_att["ttft_ms_p95"]
 

@@ -198,7 +198,12 @@ def test_freed_slot_reusable_next_tick():
     assert r2.state == "queued"
     eng.step()                    # tick 2: r2 boards the freed slot
     assert r2.state == "active" and r2.slot is not None
-    assert len(r2.tokens) == 2    # prefill token + one decode tick
+    # its prefill token; its first decode is already dispatched and is
+    # read in the next tick (the tick ahead: the chunk runs after the
+    # decode, and the slot it seats decodes from the tick after)
+    assert len(r2.tokens) == 1 and eng._ahead is not None
+    eng.step()
+    assert len(r2.tokens) == 2
     eng.drain()
     assert r2.state == DONE and len(r2.tokens) == 5
 

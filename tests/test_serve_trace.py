@@ -480,10 +480,10 @@ def test_report_json_schema_pinned(tmp_path, capsys):
         "epochs", "last_epoch", "sentinel", "journals", "timelines",
         "traces", "postmortems"}
     assert [(r["tick"], r["to"]) for r in doc["slo_alerts"]] == [
-        (37, "pending"), (38, "firing"), (49, "resolved"),
-        (50, "inactive")]
+        (32, "pending"), (33, "firing"), (56, "resolved"),
+        (57, "inactive")]
     att = doc["attribution"]["overload-shed"]
-    assert att["requests"] == 11 and att["top_slow"][0]["rid"] == 2
+    assert att["requests"] == 9 and att["top_slow"][0]["rid"] == 26
     # the text renderer shows the same two blocks: alert transitions and
     # the top-K slow-request autopsy table
     assert report.main(["--dir", d]) == 0
