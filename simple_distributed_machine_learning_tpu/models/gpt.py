@@ -979,7 +979,26 @@ class PagedServing(NamedTuple):
     (seat that token and the key it was handed: a resumed request's).
     Nothing a decode needs then waits for the host to read the last one,
     and the engine dispatches a tick's decode before it reads the previous
-    tick's tokens (``serve/engine.py::_tick_ahead``)."""
+    tick's tokens (``serve/engine.py::_tick_ahead``).
+
+    ``block``: how many positions a slot's step works on. 1 (GPT, the
+    hybrid): a step reads one token, writes one K/V row and emits one
+    token. ``block > 1`` (``models/sdar.py``, generation by diffusion over
+    blocks): a step is one FORWARD of the slot's block of ``block``
+    positions, which either denoises (fixes some of its still-masked
+    tokens, writes nothing that lasts, emits nothing) or commits (writes
+    the block's K/V rows for good and emits its tokens). The block in
+    progress rides ``state_shapes`` before the newest pair; the chunk's
+    ``seat`` is ``[1 + block]`` (how many tokens of the prompt's remainder
+    open the block, or :data:`SEAT_NONE`, then those tokens); the decode
+    takes ``steps [S]`` (each slot's denoising steps) after ``live`` and
+    returns ``[S, 2 * block + 5]`` int32 for its tokens, which
+    ``unpack_rows(rows, block)`` reads: every slot's block, the forward
+    that fixed each position, whether the forward committed, and the
+    tick's counters. ``block_forwards(block, steps, masked)``: the
+    denoising forwards a block with ``masked`` open positions takes under
+    a request's ``steps``; the host foresees every slot's phase from it
+    (``models/sdar.py::denoise_forwards``, ``unpack_block_rows``)."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -989,6 +1008,9 @@ class PagedServing(NamedTuple):
     pack_chunk: Callable | None = None
     pack_decode: Callable | None = None
     ahead: bool = False
+    block: int = 1
+    block_forwards: Callable | None = None
+    unpack_rows: Callable | None = None
 
 
 # a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)

@@ -170,3 +170,20 @@ def gated_mlp(params: dict, x: jax.Array) -> jax.Array:
     mid = jax.nn.silu(matmul_acc32(x, params["gate"])) * matmul_acc32(
         x, params["up"])
     return matmul_acc32(mid, params["down"])
+
+
+def rotary(x: jax.Array, positions: jax.Array,
+           theta: float = 10000.0) -> jax.Array:
+    """Rotary position embedding over the whole head, rotate-half
+    convention (the two halves of a head are the rotation's pairs): ``x
+    [..., T, H, dh]`` at ``positions [..., T]`` becomes ``x * cos + (-x2,
+    x1) * sin`` with angle ``positions * theta ** (-2i / dh)`` for pair
+    ``i``. Float32; no scaling of the frequencies."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions[..., None].astype(jnp.float32) * inv    # [..., T, dh/2]
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)

@@ -1,5 +1,13 @@
 """Expert parallelism: mixture-of-experts FFN with all-to-all dispatch.
 
+This is the TRAINER's expert layer: a static capacity per expert, tokens past
+it dropped, two-matrix experts. The SERVING path has its own,
+``ops/moe_experts.py`` (dropless: every routed token is computed, by a
+grouped product over the experts that got rows, SwiGLU experts): with a
+capacity a request's tokens would depend on who else is in the batch, which
+a server cannot offer. No flag chooses between them: a model's training
+stage builds this one, its serve programs call the other.
+
 Not owed for reference parity (SURVEY §2.2: the reference has no MoE), but a
 first-class parallelism strategy of this framework, alongside pipeline
 (``pipeline.py``), tensor (``tensor.py``) and sequence (``sequence.py``)

@@ -6,10 +6,11 @@ cache's shape and its two compiled programs from the model
 (``cfg.paged_serving(...)`` -> ``models/gpt.py::PagedServing``) and nowhere
 else: GPT (``models/gpt.py``) is attention in every block and nothing
 else; a model with state-space layers (``models/jamba.py``) also has a
-recurrent buffer per slot. Either keeps per-slot buffers in the pool
-(``pool.state``: every slot's newest token and key, and the recurrent
-buffers where there are any), which ride beside the K/V blocks through
-both programs.
+recurrent buffer per slot; a model that generates by diffusion over blocks
+(``models/sdar.py``, ``PagedServing.block > 1``) keeps each slot's block in
+progress. Each keeps per-slot buffers in the pool (``pool.state``: every
+slot's newest token and key, and the recurrent buffers or the block where
+there are any), which ride beside the K/V blocks through both programs.
 
 - ``submit(prompt, ...) -> Request`` enqueues one sequence with its own
   sampling params and seeded key stream, and returns the live handle
@@ -42,6 +43,14 @@ the same; the slot a chunk seats decodes from the tick after. Speculative
 decoding keeps the plain tick (chunk, then :meth:`InferenceEngine.
 _spec_tick`): its draft and its token budget need the host's tokens every
 tick.
+
+Block steps (``PagedServing.block = B > 1``): a decoding slot's tick is one
+forward of its block of ``B`` positions. A denoising forward emits nothing
+and leaves nothing that lasts; a committing forward emits up to ``B`` tokens
+at once, all stamped when the tick is read, and advances the slot by ``B``.
+The last prefill chunk emits no token: it seats the first block. Which
+phase a slot is in follows from counts the host keeps (``pool.block_fwd`` /
+``block_total``: the static schedule), so the dispatch ahead holds.
 
 Correctness anchor: a request's tokens are bit-exact vs decoding it alone
 via ``make_cached_decoder`` with the same seed (tests/test_serve.py) —
@@ -79,6 +88,9 @@ from simple_distributed_machine_learning_tpu.telemetry import tracing
 # anything > 1 disables top-p
 _NO_TOP_K = 0
 _NO_TOP_P = 2.0
+# a block tick's counters (``PagedServing.unpack_rows``) where no decode ran
+_NO_BLOCK_STATS = dict.fromkeys(
+    ("forwards", "commits", "experts_hit", "expert_rows_max"), 0)
 
 
 class DrainTimeout(RuntimeError):
@@ -154,6 +166,35 @@ def _refuse_for_recurrent_state(*, host_cache_blocks, draft_stages,
                 f"state: {reason}")
 
 
+def _refuse_for_block_steps(*, host_cache_blocks, draft_stages,
+                            lint) -> None:
+    """A model whose step works on a block of positions
+    (``PagedServing.block > 1``) serves through the paged pool, chunked
+    prefill and the tick dispatched ahead; what was built for one token a
+    step is refused by name, as :func:`_refuse_for_recurrent_state` does
+    (``mesh``, ``adapters`` and a quantized ``cache_dtype`` reach the
+    model's own ``paged_serving``, which refuses them in the same words)."""
+    why = {
+        "host_cache_blocks": (
+            bool(host_cache_blocks),
+            "the host offload tier demotes and uploads prefix blocks of "
+            "any fill, and a row here is valid only with its whole block"),
+        "draft_stages (speculative decoding)": (
+            draft_stages is not None,
+            "a tick already decides several positions at once, by the "
+            "model's own confidence and not by a draft's proposals"),
+        "lint=True": (
+            bool(lint),
+            "the analyzer's program registry (analysis/programs.py) builds "
+            "GPT's programs"),
+    }
+    for name, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{name} is not available with a model that generates by "
+                f"diffusion over blocks: {reason}")
+
+
 class InferenceEngine:
     """Continuous-batching serving over a single-device model build.
 
@@ -225,6 +266,16 @@ class InferenceEngine:
     ``draft_stages`` (speculation), ``adapters``, ``mesh`` (tensor
     parallelism), ``lint=True`` (the analyzer's registry builds GPT's
     programs) and a quantized ``cache_dtype``.
+
+    Block steps: a ``cfg`` whose ``paged_serving`` gives ``block = B > 1``
+    (generation by diffusion over blocks) serves through the same pool,
+    chunked prefill and dispatch ahead; ``submit(..., denoising_steps=)``
+    sets a request's steps (1..B, default ``cfg.denoising_steps``).
+    ``prefill_chunk``, ``block_size`` and ``max_len`` must be multiples of
+    ``B``. Prefix sharing is KEPT, at whole pool blocks (a row depends on
+    the tokens up to the end of its block of ``B``). A request preempted or
+    restored mid-block starts that block again from masks; its committed
+    tokens stay. Refused by name: the same six options.
     """
 
     def __init__(self, stages, cfg, *, params=None, n_slots: int = 4,
@@ -290,6 +341,21 @@ class InferenceEngine:
             stages, self.max_len, block_size, cache_dtype, mesh=mesh,
             kernel=attn_kernel, adapters=adp)
         self._n_layers = serving.kv_layers
+        # positions a slot's step works on (PagedServing.block)
+        self._block = int(serving.block)
+        if self._block > 1:
+            _refuse_for_block_steps(
+                host_cache_blocks=host_cache_blocks,
+                draft_stages=draft_stages, lint=lint)
+            if prefill_chunk is not None and prefill_chunk % self._block:
+                raise ValueError(
+                    f"prefill_chunk={prefill_chunk} must be a multiple of "
+                    f"the model's block of {self._block} positions: a chunk "
+                    f"holds whole blocks")
+        self._block_forwards = serving.block_forwards
+        self._unpack_block = serving.unpack_rows
+        # what the last block tick's program counted (engine.tick's attrs)
+        self._block_stats = _NO_BLOCK_STATS
         self.pool = PagedKVPool(self._n_layers, n_slots, serving.kv_heads,
                                 self.max_len, serving.head_dim, cache_dtype,
                                 block_size=block_size, n_blocks=n_blocks,
@@ -297,7 +363,8 @@ class InferenceEngine:
                                 host_cache_blocks=host_cache_blocks,
                                 prefetch_ticks=prefetch_ticks,
                                 state_shapes=serving.state_shapes,
-                                recurrent=cfg.recurrent_state)
+                                recurrent=cfg.recurrent_state,
+                                step_rows=self._block)
         self._chunk_prefill = serving.chunk_prefill
         self._decode = serving.decode
         self._pack_chunk = serving.pack_chunk
@@ -480,7 +547,8 @@ class InferenceEngine:
                cls: str | None = None, priority: int = 0,
                ttft_deadline_s: float | None = None,
                deadline_s: float | None = None,
-               adapter: str | None = None) -> Request:
+               adapter: str | None = None,
+               denoising_steps: int | None = None) -> Request:
         """Enqueue one request; returns its live handle immediately.
 
         ``arrival_time`` backdates ``submit_time`` to when the request
@@ -506,9 +574,15 @@ class InferenceEngine:
                 if v is not None and v <= 0:
                     raise ValueError(f"{name} must be > 0, got {v}")
             self._check_adapter(adapter)
+            steps = self._check_denoising_steps(denoising_steps)
             rid = self._next_rid
             self._next_rid += 1
             sp.set(rid=rid)
+            if self._block > 1:
+                # blocks the request will generate, the one its prompt's
+                # remainder opens included
+                sp.set(blocks=-(-(len(prompt) % self._block
+                                  + max_new_tokens) // self._block))
             seed = rid if seed is None else seed
             r = Request(rid=rid, prompt=prompt,
                         max_new_tokens=max_new_tokens,
@@ -516,7 +590,8 @@ class InferenceEngine:
                         eos_id=eos_id, seed=seed, on_token=on_token,
                         cls=cls, priority=priority,
                         ttft_deadline_s=ttft_deadline_s,
-                        deadline_s=deadline_s, adapter=adapter)
+                        deadline_s=deadline_s, adapter=adapter,
+                        block=self._block, denoising_steps=steps)
             if self._adapters is not None:
                 # the version-qualified prefix-cache namespace (refreshed at
                 # the admission gate — the probe and the decode must agree on
@@ -541,6 +616,22 @@ class InferenceEngine:
             if self.trace is not None:
                 self.trace.on_submit(r, r.submit_time)
             return r
+
+    def _check_denoising_steps(self, steps: int | None) -> int:
+        """A request's denoising steps: the model's default, 0 for a model
+        whose step is one token."""
+        if self._block == 1:
+            if steps is not None:
+                raise ValueError(
+                    "denoising_steps is for a model that generates by "
+                    "diffusion over blocks; this one emits a token a step")
+            return 0
+        steps = self.cfg.denoising_steps if steps is None else int(steps)
+        if not 1 <= steps <= self._block:
+            raise ValueError(
+                f"denoising_steps must be in [1, {self._block}] (the "
+                f"block's length), got {steps}")
+        return steps
 
     # -- adapter plumbing --------------------------------------------------
 
@@ -692,6 +783,10 @@ class InferenceEngine:
                ahead=ahead, queue=self.scheduler.queue_depth,
                state_slots=self._state_slots(),
                kv_blocks=self.pool.blocks_in_use)
+        if self._block > 1:
+            # what the tick's decode run counted (0 where it ran none)
+            sp.set(**(self._block_stats if decode_active
+                      else _NO_BLOCK_STATS))
         return emitted
 
     def _state_slots(self) -> int:
@@ -718,11 +813,17 @@ class InferenceEngine:
         rows = []
         for s in self.pool.active_slots():
             r = self.requests[self.pool.occupant(s)]
-            n = (r.prefill_pos if r.prefill_pos is not None
-                 else int(self.pool.positions[s]) + (r.rid in in_flight))
+            if r.prefill_pos is not None:
+                n = r.prefill_pos
+            elif self._block > 1:
+                # a block's rows are reserved from its first forward on
+                n = int(self.pool.positions[s]) + self._block * (
+                    r.rid in in_flight or self.pool.block_fwd[s] > 0)
+            else:
+                n = int(self.pool.positions[s]) + (r.rid in in_flight)
             if n > 0:
                 rows.append(n)
-        if self.pool.recurrent:
+        if self.pool.recurrent or self._block > 1:
             # the analyzer's model is GPT's (one head count, every layer
             # attends); here the pool's own block bytes make the prediction
             return (self.pool.bytes_resident(),
@@ -837,6 +938,9 @@ class InferenceEngine:
                          request.temperature, request.top_k, request.top_p,
                          self.cfg.vocab, self.max_len)
         self._check_adapter(getattr(request, "adapter", None))
+        request.block = self._block
+        request.denoising_steps = self._check_denoising_steps(
+            request.denoising_steps or None)
         request.state = QUEUED
         request.slot = None
         request.prefill_pos = None
@@ -913,6 +1017,8 @@ class InferenceEngine:
             return None
         r = self.requests[self._prefilling[0]]
         seq = r.resume_seq           # == r.prompt unless resuming preempted
+        if self._block > 1:
+            seq, opening = self._block_prefill_seq(seq)
         plen = int(seq.shape[0])
         p0 = r.prefill_pos
         c = (plen - p0 if self.prefill_chunk is None
@@ -921,7 +1027,14 @@ class InferenceEngine:
             t_start = self._now = self._clock()
             self._ensure_writable_range(r.slot, p0, c)
             seat = ()
-            if self._seats_newest:
+            if self._block > 1:
+                # the last chunk seats the slot's first block: the
+                # sequence's remainder fixed, the rest masked
+                seat = (np.concatenate([
+                    [self._seat_none if p0 + c < plen else len(opening)],
+                    opening, np.zeros(self._block - len(opening), np.int32)
+                ]).astype(np.int32),)
+            elif self._seats_newest:
                 # what the chunk leaves as the slot's newest token on the
                 # device: nothing mid-prompt, its own sample, or a resumed
                 # request's stored one (as _prefill_emit seats the host's)
@@ -971,6 +1084,16 @@ class InferenceEngine:
         # publish the sequence's blocks BEFORE any same-tick retirement so
         # even a 1-token request leaves its prefix reusable (cached blocks
         # survive end_seq as reclaimable)
+        if self._block > 1:
+            # no token yet: the slot's first block is seated (on the
+            # device by the chunk), masked but for the sequence's remainder
+            full = r.resume_seq
+            n_open = len(full) % self._block
+            self.pool.register_prefix(r.slot, full[:len(full) - n_open])
+            self._seat_block(r, len(full) - n_open, n_open)
+            if r.tokens and self.trace is not None:
+                self.trace.on_resume(r, now)
+            return 0
         self.pool.register_prefix(r.slot, seq)
         if self.speculative:
             # the draft prefills the WHOLE sequence in one shot at the
@@ -1008,6 +1131,27 @@ class InferenceEngine:
             self.pool.seat(r.slot, plen, tok)
         return 1
 
+    def _block_prefill_seq(self, seq):
+        """What a block-step model's chunks run over, and the tokens that
+        open its first block: ``seq``'s whole blocks and its remainder. A
+        sequence shorter than one block runs its chunk over that block
+        itself, masks and all (rows the first forward overwrites): a slot
+        is seated by a chunk."""
+        B = self._block
+        whole = len(seq) - len(seq) % B
+        opening = seq[whole:]
+        if whole:
+            return seq[:whole], opening
+        return np.concatenate([opening, np.full(
+            B - len(opening), self.cfg.mask_id, np.int32)]), opening
+
+    def _seat_block(self, r: Request, position: int, n_open: int) -> None:
+        """``r``'s slot starts a block at ``position`` with ``n_open``
+        tokens already fixed: the forwards it takes follow from the
+        request's schedule."""
+        self.pool.seat_block(r.slot, position, 1 + self._block_forwards(
+            self._block, r.denoising_steps, self._block - n_open))
+
     def _run_paged(self, program, pack, *args):
         """Call one of the model's two paged programs (its host-side
         arguments through the model's ``pack``, where it has one) and take
@@ -1037,8 +1181,14 @@ class InferenceEngine:
     def _decode_tick(self, active: list[int]) -> int:
         if not active:
             return 0
-        return self._emit_decoded(*self._decode_dispatch(
+        return self._emit_tick(*self._decode_dispatch(
             [(s, int(self.pool.positions[s])) for s in active]))
+
+    def _emit_tick(self, active: list[int], out, kd2) -> int:
+        """Read one decode back and account it: a token a slot, or (block
+        steps) a forward a slot."""
+        emit = self._emit_block if self._block > 1 else self._emit_decoded
+        return emit(active, out, kd2)
 
     def _decode_dispatch(self, seats: list[tuple[int, int]]):
         """Launch one decode over ``seats``, ``(slot, position)`` of every
@@ -1057,8 +1207,11 @@ class InferenceEngine:
                              PagedKVPool.TRASH, np.int32)
             for s, p in seats:
                 # on-demand block allocation as this position advances (and
-                # copy-on-write if the write block is still shared)
-                self._ensure_writable_range(s, p, 1)
+                # copy-on-write if the write block is still shared); a
+                # block's rows are reserved before its first forward
+                if (self._block == 1 or len(self.pool.tables[s])
+                        * self.pool.block_size < p + self._block):
+                    self._ensure_writable_range(s, p, self._block)
                 tables[s] = self.pool.device_table(s)
                 pos[s] = p
                 toks[s] = self.pool.last_token[s]
@@ -1070,6 +1223,12 @@ class InferenceEngine:
                 # come back unchanged
                 live = (np.zeros(S, bool),)
                 live[0][active] = True
+            if self._block > 1:
+                steps = np.ones(S, np.int32)
+                for s in active:
+                    steps[s] = self.requests[
+                        self.pool.occupant(s)].denoising_steps
+                live += (steps,)
         with tracing.span("engine.decode.dispatch"):
             toks2, kd2 = self._run_paged(
                 self._decode, self._pack_decode, toks, pos, tables, *live,
@@ -1109,7 +1268,7 @@ class InferenceEngine:
         if seats:
             self._ahead = ([self.pool.occupant(s) for s, _ in seats],
                            self._decode_dispatch(seats))
-        emitted = self._emit_decoded(*dec) if dec else 0
+        emitted = self._emit_tick(*dec) if dec else 0
         if chunk is not None:
             emitted += self._prefill_finish(chunk)
         return emitted, len(dec[0]) if dec else 0, int(ahead is not None)
@@ -1118,6 +1277,8 @@ class InferenceEngine:
         """``(slot, position)`` of the NEXT tick's decode while this
         tick's (over ``decoding``) and its ``chunk`` are in flight, or
         ``None`` where a token not yet read could end a request."""
+        if self._block > 1:
+            return self._block_seats_ahead(decoding, chunk)
         seats = []
         for s in decoding:
             r = self.requests[self.pool.occupant(s)]
@@ -1132,6 +1293,31 @@ class InferenceEngine:
                 if r.eos_id is not None and not r.tokens:
                     return None
                 seats.append((r.slot, len(seq)))
+        return sorted(seats)
+
+    def _block_seats_ahead(self, decoding, chunk):
+        """:meth:`_seats_ahead` for block steps: ``(slot, block start)``.
+        Under the static schedule the host's counts say which phase every
+        slot's forward in flight is in: a denoising one keeps the slot on
+        its block, a committing one moves it on by a block, or ends the
+        request where the block reaches its budget; only a commit of a
+        request with ``eos_id`` could end one unforeseen."""
+        B = self._block
+        seats = []
+        for s in decoding:
+            r = self.requests[self.pool.occupant(s)]
+            p = int(self.pool.positions[s])
+            if self.pool.block_fwd[s] + 1 < self.pool.block_total[s]:
+                seats.append((s, p))
+                continue
+            if r.eos_id is not None:
+                return None
+            if p + B < len(r.prompt) + r.max_new_tokens:
+                seats.append((s, p + B))
+        if chunk is not None:
+            r, seq, p0, c = chunk[:4]
+            if p0 + c == len(seq):
+                seats.append((r.slot, len(r.resume_seq) // B * B))
         return sorted(seats)
 
     def _ensure_writable_range(self, slot: int, p0: int, n: int) -> None:
@@ -1311,6 +1497,67 @@ class InferenceEngine:
                     self._finish(r, reason, now)
                 else:
                     self.pool.advance(s, tok)
+        return emitted
+
+    def _emit_block(self, active: list[int], rows, kd2) -> int:
+        """:meth:`_emit_decoded` for block steps: a slot whose forward
+        denoised counts it; one whose forward committed emits its block's
+        tokens in order (past the prompt's remainder, cut at
+        ``max_new_tokens``, ended by ``eos_id``), all stamped now, and
+        moves on by a block."""
+        B = self._block
+        with tracing.span("engine.decode.wait"):
+            rows = np.asarray(rows)              # host sync: tick endpoint
+            kd2 = np.asarray(kd2)
+        with tracing.span("engine.decode.emit"):
+            now = self._now = self._clock()
+            toks, order, committed, self._block_stats = self._unpack_block(
+                rows, B)
+            emitted = 0
+            for s in active:
+                r = self.requests[self.pool.occupant(s)]
+                r.key_data = kd2[s]
+                self.pool.block_fwd[s] += 1
+                if committed[s] != (self.pool.block_fwd[s]
+                                    == self.pool.block_total[s]):
+                    raise RuntimeError(
+                        f"slot {s}: forward {self.pool.block_fwd[s]} of "
+                        f"{self.pool.block_total[s]} committed="
+                        f"{bool(committed[s])} — the host's schedule and "
+                        f"the device's block state disagree")
+                if not committed[s]:
+                    continue
+                p = int(self.pool.positions[s])
+                r.blocks.append((p, toks[s].tolist(), order[s].tolist()))
+                first = r.first_token_time is None
+                if first:
+                    r.first_token_time = now
+                    if self.metrics is not None:
+                        self.metrics.on_first_token(r.ttft_s, cls=r.cls)
+                    if self.trace is not None:
+                        self.trace.on_first_token(r, now)
+                dt = now - self._last_emit.get(r.rid, now)
+                self._last_emit[r.rid] = now
+                n_emit, finish = 0, None
+                for tok in toks[s, max(len(r.prompt) - p, 0):]:
+                    n_emit += 1
+                    r.emit(int(tok))
+                    finish = r.finished_by(int(tok))
+                    if finish is not None:
+                        break
+                if self.metrics is not None:
+                    # the gap since the last block, spread over this one's
+                    # tokens (as _emit_spec does); a request's first token
+                    # is its TTFT's
+                    for _ in range(n_emit - first):
+                        self.metrics.on_token(dt / n_emit, cls=r.cls)
+                if self.trace is not None:
+                    self.trace.on_tick_tokens(r, now, n_emit)
+                emitted += n_emit
+                if finish is not None:
+                    self._finish(r, finish, now)
+                else:
+                    self._seat_block(r, p + B, 0)
         return emitted
 
     def _finish(self, r: Request, reason: str, now: float) -> None:

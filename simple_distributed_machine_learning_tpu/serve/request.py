@@ -64,6 +64,11 @@ class Request:
     # recovery/migration, and the prefix-cache namespace key, because K/V
     # computed under one adapter is wrong for every other.
     adapter: str | None = None
+    # generation by diffusion over blocks (a model whose
+    # ``PagedServing.block`` is > 1; set by the engine): the block's
+    # length and this request's denoising steps (1..block)
+    block: int = 1
+    denoising_steps: int = 0
 
     # -- lifecycle (engine-owned) -----------------------------------------
     state: str = QUEUED
@@ -72,6 +77,10 @@ class Request:
     # is admitted but not yet decoding (None once seated)
     prefill_pos: int | None = None
     tokens: list[int] = dataclasses.field(default_factory=list)
+    # ``block > 1``: every committed block as it was served, ``(start
+    # position, its tokens, the forward that fixed each: 0 for the prompt's
+    # remainder)`` — what rebuilds each denoising forward's input
+    blocks: list[tuple] = dataclasses.field(default_factory=list)
     key_data: np.ndarray | None = None  # live PRNG key data (uint32 [2])
     # speculative decoding: the draft model's SEPARATE key stream (set by
     # the engine when a draft is configured; fold_in(key(seed), 1), so
@@ -110,17 +119,21 @@ class Request:
         """The token sequence (re-)admission must have K/V for: the prompt,
         plus — after a preemption — every emitted token except the newest
         (whose K/V the next decode step writes; it rides in ``last_token``).
-        Fresh requests: exactly the prompt."""
+        Fresh requests: exactly the prompt. With ``block > 1`` every
+        emitted token belongs to a committed block and needs its K/V: the
+        block in progress starts again from masks."""
         if not self.tokens:
             return self.prompt
-        return np.concatenate(
-            [self.prompt, np.asarray(self.tokens[:-1], np.int32)])
+        kept = self.tokens if self.block > 1 else self.tokens[:-1]
+        return np.concatenate([self.prompt, np.asarray(kept, np.int32)])
 
     @property
     def resume_max_new(self) -> int:
         """Remaining new-token budget paired with :attr:`resume_seq` so the
         pool's worst-case row bound (``len(seq) + budget - 1``) stays exactly
         ``prompt_len + max_new_tokens - 1`` across preemptions."""
+        if self.block > 1:
+            return self.max_new_tokens - len(self.tokens)
         return self.max_new_tokens - max(0, len(self.tokens) - 1)
 
     @property

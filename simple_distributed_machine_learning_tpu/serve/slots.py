@@ -36,6 +36,15 @@ model's prefill zeroes the slot's rows — a bound slot never sees its last
 occupant's state. Requests that WOULD have matched are counted
 (``prefix_declined_total``).
 
+A model whose step works on a BLOCK of positions (``step_rows > 1``,
+``models/gpt.py::PagedServing.block``: generation by diffusion over blocks)
+writes whole blocks of ``step_rows`` rows: a sequence's budget is its
+length rounded up to one, the host keeps for each slot how many forwards
+its block in progress has had and how many it takes (``block_fwd`` /
+``block_total``), and a K/V row depends on the tokens up to the END of its
+block, so only prefixes of whole pool blocks (a multiple of ``step_rows``)
+are registered and matched.
+
 The slot free list is invariant-guarded: acquiring an occupied slot or
 releasing a free one raises instead of silently corrupting a neighbor's
 cache, and the same discipline covers blocks — no double allocation, no
@@ -205,7 +214,7 @@ class PagedKVPool:
                  block_size: int = 16, n_blocks: int | None = None,
                  tp: int = 1, host_cache_blocks: int = 0,
                  prefetch_ticks: int = 1, state_shapes=(),
-                 recurrent: bool = False) -> None:
+                 recurrent: bool = False, step_rows: int = 1) -> None:
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if max_len < 2:
@@ -215,6 +224,11 @@ class PagedKVPool:
         self.max_len = max_len
         self.positions = np.zeros(n_slots, np.int32)
         self.last_token = np.zeros(n_slots, np.int32)
+        # positions a slot's step works on (module docstring); with more
+        # than one: the forwards the slot's block has had and takes
+        self.step_rows = int(step_rows)
+        self.block_fwd = np.zeros(n_slots, np.int32)
+        self.block_total = np.zeros(n_slots, np.int32)
         self._occupant: list[int | None] = [None] * n_slots
         self._free: list[int] = list(range(n_slots))[::-1]   # pop() -> slot 0
         self.prefix_declined_total = 0
@@ -412,6 +426,19 @@ class PagedKVPool:
         self.positions[slot] += 1
         self.last_token[slot] = int(next_token)
 
+    def seat_block(self, slot: int, position: int, forwards: int) -> None:
+        """``slot``'s next block starts at ``position`` and takes
+        ``forwards`` forwards, the commit included (``step_rows > 1``):
+        after the prefill, and after every commit."""
+        if position % self.step_rows or not (
+                0 <= position <= self.max_len - self.step_rows):
+            raise ValueError(
+                f"block start {position} is no multiple of "
+                f"{self.step_rows} inside [0, {self.max_len})")
+        self.positions[slot] = position
+        self.block_fwd[slot] = 0
+        self.block_total[slot] = forwards
+
     # -- capacity ----------------------------------------------------------
 
     @property
@@ -433,12 +460,15 @@ class PagedKVPool:
     def bytes_resident(self) -> int:
         return self.blocks_in_use * self.bytes_per_block
 
-    @staticmethod
-    def _rows_needed(prompt_len: int, max_new_tokens: int) -> int:
+    def _rows_needed(self, prompt_len: int, max_new_tokens: int) -> int:
         # positions written: prefill [0, prompt_len) + one decode write per
         # consumed token — the final emitted token is never consumed, so the
         # highest written position is prompt_len + max_new - 2
-        return prompt_len + max_new_tokens - 1
+        if self.step_rows == 1:
+            return prompt_len + max_new_tokens - 1
+        # whole blocks, the last one to its end
+        return -(-(prompt_len + max_new_tokens) // self.step_rows
+                 ) * self.step_rows
 
     def blocks_for(self, rows: int) -> int:
         return math.ceil(rows / self.block_size)
@@ -698,6 +728,11 @@ class PagedKVPool:
         prompt = np.asarray(prompt, np.int32)
         cap = int(prompt.shape[0]) - 1
         bs = self.block_size
+        # a row of a block-step model is its whole block's: only whole pool
+        # blocks of the prompt's whole steps can be another prompt's too
+        whole = self.step_rows > 1
+        if whole:
+            cap = int(prompt.shape[0]) // self.step_rows * self.step_rows - 1
         chain: list[tuple[int, int]] = []
         shared = 0
         j = 0
@@ -705,7 +740,8 @@ class PagedKVPool:
             hit = None
             # the longest key covering block j that still prefixes prompt:
             # full block first, then partial fills from longest down
-            for length in range(min(cap, (j + 1) * bs), j * bs, -1):
+            for length in range(min(cap, (j + 1) * bs),
+                                (j + 1) * bs - 1 if whole else j * bs, -1):
                 entry = self._prefix.get(ns + prompt[:length].tobytes())
                 if entry is not None:
                     hit = (entry[0], length - j * bs)
@@ -748,7 +784,10 @@ class PagedKVPool:
             return
         table = self.tables[slot]
         plen = int(prompt.shape[0])
-        for j in range(self.blocks_for(plen)):
+        # a block-step model publishes whole pool blocks only (_probe_prefix)
+        n_blocks = (plen // bs if self.step_rows > 1
+                    else self.blocks_for(plen))
+        for j in range(n_blocks):
             fill = min(plen - j * bs, bs)
             key = ns + prompt[:j * bs + fill].tobytes()
             if key in self._prefix:

@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from simple_distributed_machine_learning_tpu.ops import (
     flash_attention as fa,
+    moe_experts as me,
     paged_attention as pa,
     selective_scan as ss,
 )
@@ -63,6 +64,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(pa, "_interpret", lambda: False)
     monkeypatch.setattr(ss, "_interpret", lambda: False)
+    monkeypatch.setattr(me, "_interpret", lambda: False)
 
 
 def _compile(fn, one_chip, *shapes, kernels):
@@ -264,6 +266,39 @@ def _hybrid_programs():
         [params], pool, pool, state, _sd(host.shape, host.dtype)))}
 
 
+def _block_programs():
+    """``sdar-30b-a3b.serve-diffuse-closed``'s block tick at 2 of its 7
+    layers and 16 of its 128 experts (8 a token), the published widths
+    otherwise: hidden 2048, 32 query heads over 4 K/V heads of 128, experts
+    of 768, 64 slots, blocks of 16, bf16 pool, fused kernel (the vocabulary
+    cut to 512: the head is not what is looked at)."""
+    from simple_distributed_machine_learning_tpu.models.sdar import (
+        SdarConfig,
+        make_sdar_stages,
+        pack_decode_inputs,
+    )
+    import numpy as np
+    S, ml, bs, nb = 64, 1024, 16, 4096
+    cfg = SdarConfig(vocab=512, seq_len=ml, d_model=2048, n_heads=32,
+                     n_kv_heads=4, head_dim=128, n_layers=2, n_experts=16,
+                     top_k=8, d_expert=768, mask_id=511,
+                     param_dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda k: make_sdar_stages(k, cfg)[0][0].params, jax.random.key(0))
+    serving = cfg.paged_serving([types.SimpleNamespace(params=params)], ml,
+                                bs, "bfloat16", kernel="fused")
+    assert serving.block == 4
+    pool = (_sd((nb + 1, bs, 4 * 128), jnp.bfloat16),) * serving.kv_layers
+    state = jax.tree.map(lambda sd: _sd((S, *sd.shape), sd.dtype),
+                         serving.state_shapes)
+    z = np.zeros(S, np.int32)
+    host, = pack_decode_inputs(z, z, np.zeros((S, ml // bs), np.int32), z, z,
+                               None, z.astype(np.float32), z,
+                               z.astype(np.float32))
+    return pool, (), {"block-decode": (serving.decode, (
+        [params], pool, pool, state, _sd(host.shape, host.dtype)))}
+
+
 def _bytes(shape_text: str) -> int:
     """The largest array a result type names, ``bf16[513,16,1280]{...}`` or
     a tuple of such, in bytes."""
@@ -274,7 +309,8 @@ def _bytes(shape_text: str) -> int:
     return best
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk", "hybrid-decode"])
+@pytest.mark.parametrize("program", ["decode", "chunk", "hybrid-decode",
+                                     "block-decode"])
 def test_serve_programs_leave_the_pool_where_it_is(one_chip, mosaic,
                                                    program):
     """The compiled entry computation writes the donated per-layer buffers
@@ -292,8 +328,9 @@ def test_serve_programs_leave_the_pool_where_it_is(one_chip, mosaic,
     (two of 72 at 36 layers; none at 1,024 blocks; sandbox compile, PR 29).
     That is no re-layout of the pool and is left to it; a ``copy-start``
     that stays in one memory space is refused like a ``copy``."""
-    pool, pair, programs = (_hybrid_programs() if program == "hybrid-decode"
-                            else _gpt_programs())
+    pool, pair, programs = {"hybrid-decode": _hybrid_programs,
+                            "block-decode": _block_programs}.get(
+                                program, _gpt_programs)()
     fn, args = programs[program]
     compiled = fn.lower(*_on_chip(args, one_chip)).compile()
     layer = math.prod(pool[0].shape) * pool[0].dtype.itemsize
@@ -336,6 +373,26 @@ def test_selective_scan_compiles_for_v5e(one_chip, mosaic, n, n_tok):
     assert re.search(_kernel_pattern("serve-reason-closed",
                                      "selective_scan"), line.strip())
     assert not re.search(_kernel_pattern("serve-reason-closed",
+                                         "paged_attention"), line.strip())
+
+
+# -- grouped expert products: the sparse serve programs' kernel ---------------
+
+
+@pytest.mark.parametrize("m,k,n", [(2048, 2048, 768), (2048, 768, 2048),
+                                   (512, 2048, 768), (1536, 768, 2048)])
+def test_expert_products_compile_for_v5e(one_chip, mosaic, m, k, n):
+    """The published widths (128 experts, hidden 2048, expert width 768) at
+    the rows ``sdar-30b-a3b.serve-diffuse-closed`` runs: 8 pairs a row of a
+    tick's 256 rows or of a 64- to 256-token chunk; the gate / up shape and
+    the down shape, each expert's matrix one block."""
+    bf16 = jnp.bfloat16
+    (line,) = _compile(
+        me.grouped_matmul, one_chip, ((m, k), bf16), ((128, k, n), bf16),
+        ((128,), jnp.int32), kernels=["moe_experts"])
+    assert re.search(_kernel_pattern("serve-diffuse-closed", "moe_experts"),
+                     line.strip())
+    assert not re.search(_kernel_pattern("serve-diffuse-closed",
                                          "paged_attention"), line.strip())
 
 
