@@ -123,6 +123,7 @@ class _Env:
         self.iv: dict[int, Interval] = {}
         self.concrete: dict[int, np.ndarray] = {}
         self.parts: dict[int, list[tuple[int, Interval]]] = {}
+        self.producer: dict[int, Any] = {}      # var -> the eqn that made it
 
     def read(self, atom) -> Interval:
         if hasattr(atom, "val"):            # Literal (has .aval too)
@@ -272,6 +273,7 @@ class BoundsWalker:
     def __init__(self, emit: Callable[..., None]):
         self._emit = emit
         self._mute = 0
+        self._kernels: list[str] = []   # the pallas_calls being walked
 
     # -- body walk --------------------------------------------------------
 
@@ -290,6 +292,7 @@ class BoundsWalker:
             outs = self._eqn(eqn, env)
             for var, iv in zip(eqn.outvars, outs):
                 env.iv[id(var)] = iv
+                env.producer[id(var)] = eqn
         return [env.read(v) for v in jaxpr.outvars]
 
     def _sub_env(self, sub_closed_or_open, in_ivs: list[Interval]) -> _Env:
@@ -447,6 +450,14 @@ class BoundsWalker:
             # Pallas ref read (SMEM scalar-prefetch deref in index maps):
             # values drawn from the ref carry the ref's content interval
             return [a] * n
+        if prim == "dma_start":
+            # a kernel's own copy (operands in pl.ANY): its source window
+            # is proven like a BlockSpec index map (analysis/kernels.py)
+            from simple_distributed_machine_learning_tpu.analysis import (
+                kernels,
+            )
+            kernels.check_dma_start(self, eqn, env)
+            return []
         if prim == "pallas_call":
             # open the kernel box: index-map bounds proofs, write-race
             # detection, tiling/dtype lint (analysis/kernels.py)

@@ -405,16 +405,16 @@ def _paged_kernel_report(table_hi_slack: int, H: int,
 
 
 def kernel_oob_index_map() -> Report:
-    """The fused kernel's K/V index map fed a block-table contract that can
-    reach one past the pool: the BlockSpec would stream a window outside
-    the backing buffer."""
+    """The fused kernel's own block copies fed a block-table contract that
+    can reach one past the pool: a ``dma_start`` would read a window
+    outside the backing buffer (``kernel-oob.dma-source``)."""
     return _paged_kernel_report(1, H=2, dh=8, bs=4,
                                 name="fixture:kernel_oob_index_map")
 
 
 def kernel_clean_paged() -> Report:
-    """The same kernel under the slots.py table invariant — every index
-    map proves in bounds (must be fully clean)."""
+    """The same kernel under the slots.py table invariant — every copy
+    and index map proves in bounds (must be fully clean)."""
     return _paged_kernel_report(0, H=2, dh=8, bs=4,
                                 name="fixture:kernel_clean_paged")
 

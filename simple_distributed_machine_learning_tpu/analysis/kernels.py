@@ -18,6 +18,11 @@ comments become proofs:
   positions < max_len). A block index that can escape
   ``[0, ceil(dim/block)-1]`` is an out-of-bounds HBM window — the
   trash-block-0 and fetch-elision-clamp disciplines, machine-checked.
+  An operand handed over unblocked (``memory_space=pl.ANY``) has no index
+  map: the kernel copies from it itself, and the body is walked with the
+  same seeds, every ``dma_start`` that reads such an operand held to the
+  operand's shape (``kernel-oob.dma-source`` ERROR /
+  ``kernel-unproven.dma-source`` WARNING, which names the kernel).
 - **grid write races** (``kernel-race.parallel-overwrite`` ERROR /
   ``kernel-race.unproven-map`` WARNING): each output element must be
   written by at most one cell of every ``parallel`` grid axis. The output
@@ -32,7 +37,10 @@ comments become proofs:
   (f32 ``(8,128)``, bf16 ``(16,128)``, int8/fp8 ``(32,128)``); a block
   whose natural layout pads >= 4x while the transposed layout would pad
   less than half that is the known small-head-dim hazard (dh in the lane
-  slot) — fix the layout, don't eat the copy.
+  slot) — fix the layout, don't eat the copy. An unblocked operand is
+  judged by one row of its leading axis as the caller holds it: there the
+  padding is the caller's own (``ops/paged_attention.py::_whole_lanes``),
+  a copy of the pool every call.
 - **dtype lint** (``kernel-dtype-drift.low-precision-scratch`` WARNING):
   sub-f32 floating scratch in a kernel that carries state across grid
   iterations loses the online-softmax accumulation precision the dense
@@ -41,7 +49,9 @@ comments become proofs:
 :func:`kernel_hbm_costs` additionally derives HBM traffic rows from the
 kernels themselves (block bytes x the grid trips each index map actually
 depends on), tagged ``kernel.kv_stream`` for table-indexed streams and
-``kernel.io`` for the rest. ``programs.lint_serve`` reconciles the
+``kernel.io`` for the rest; what a kernel copies itself from unblocked
+operands is the call's declared ``cost_estimate.bytes_accessed`` less the
+blocks the walker can see. ``programs.lint_serve`` reconciles the
 kv_stream bytes against the hand-built ``HBMCost`` tick model
 (``decode.kv_gather`` et al.) EXACTLY — the analyzer's claim that the
 fused kernel deletes the 2x ``kv_attn_reread`` pass is computed from the
@@ -137,6 +147,20 @@ def _bm_parts(bm):
 
 def _index_map_jaxpr(bm):
     return getattr(bm, "index_map_jaxpr", None)
+
+
+def _unblocked(aval) -> bool:
+    """A ref the kernel reads by copies of its own (``pl.ANY`` / HBM):
+    Pallas pipelines no block of it."""
+    return str(getattr(aval, "memory_space", None)) in ("any", "hbm")
+
+
+def _kernel_copies(bm) -> bool:
+    return _unblocked(getattr(bm, "transformed_block_aval", None))
+
+
+def _kernel_name(eqn) -> str:
+    return str(eqn.params.get("name") or "pallas_call")
 
 
 # -- index-map evaluation over the interval lattice ------------------------
@@ -273,6 +297,14 @@ def check_pallas_call(walker, eqn, ins, env):
         block, shape, dtype = parts
         is_out = i >= n_in
         what = (f"output {i - n_in}" if is_out else f"input {i}")
+        if _kernel_copies(bm):
+            # no block and no index map (the body walk proves the copies);
+            # what the kernel moves at a time is one row of the leading
+            # axis, and the tiling lint judges it as the caller holds it
+            held = (shape if is_out
+                    else _held_shape(eqn.invars[n_sp + i], env))
+            _tile_lint((1,) + held[1:], dtype, what, src, emit)
+            continue
 
         # (1) index-map bounds proof
         comps = _eval_index_map(walker, closed, grid, sp_ivs)
@@ -354,31 +386,7 @@ def check_pallas_call(walker, eqn, ins, env):
                              "dimension_semantics so Mosaic serializes it "
                              "for an accumulate discipline"))
 
-        # (3) tiling lint: Mosaic pads the trailing two dims to the
-        # dtype's minimum tile; compare against the transposed layout
-        if len(block) >= 2:
-            sub, lane = block[-2], block[-1]
-            if sub > 0 and lane > 0:
-                st, lt = _min_tile(dtype)
-                waste = (_roundup(sub, st) * _roundup(lane, lt)) / (sub * lane)
-                waste_t = (_roundup(lane, st) * _roundup(sub, lt)) / (sub * lane)
-                if waste >= _WASTE_FLAG and waste >= _WASTE_RATIO * waste_t:
-                    emit(Finding(
-                        rule="kernel-tile.pad-waste",
-                        severity=Severity.WARNING,
-                        message=(f"pallas_call {what} block {block} "
-                                 f"({dtype.name}) pads to the "
-                                 f"({st},{lt}) minimum tile at {waste:.0f}x "
-                                 f"its size — transposing the trailing "
-                                 f"dims would pad only {waste_t:.0f}x (the "
-                                 f"small-head-dim-in-the-lane-slot "
-                                 f"hazard)"),
-                        where=src,
-                        hint="swap the trailing block dims (pack the "
-                             "small dim into sublanes, the long one into "
-                             "lanes), or widen the row: the paged pool "
-                             "keeps all heads of a position in one row "
-                             "(serve/slots.py::PagedKVPool)"))
+        _tile_lint(block, dtype, what, src, emit)
 
     # (4) dtype lint: sub-f32 floating scratch accumulators
     body = eqn.params.get("jaxpr")
@@ -389,7 +397,11 @@ def check_pallas_call(walker, eqn, ins, env):
             dt = getattr(aval, "dtype", None)
             if dt is None:
                 continue
-            if np.dtype(dt).kind == "f" and is_low_precision(dt):
+            try:
+                dt = np.dtype(dt)
+            except TypeError:
+                continue                # a semaphore
+            if dt.kind == "f" and is_low_precision(dt):
                 emit(Finding(
                     rule="kernel-dtype-drift.low-precision-scratch",
                     severity=Severity.WARNING,
@@ -404,7 +416,116 @@ def check_pallas_call(walker, eqn, ins, env):
                     hint="allocate the accumulator/l/m scratch as "
                          "pltpu.VMEM(..., jnp.float32) and cast only on "
                          "the final store"))
+
+    # (5) the kernel's own copies: walk the body, scalar-prefetch refs
+    # seeded from the caller's contracts; ``check_dma_start`` does the rest
+    if body_jaxpr is not None and any(_kernel_copies(bm) for bm in bms):
+        ivs = sp_ivs + [TOP] * (len(body_jaxpr.invars) - len(sp_ivs))
+        walker._kernels.append(_kernel_name(eqn))
+        try:
+            walker._walk(body_jaxpr, walker._sub_env(body, ivs))
+        finally:
+            walker._kernels.pop()
     return [TOP] * n
+
+
+def _tile_lint(block, dtype, what, src, emit) -> None:
+    """(3) tiling lint: Mosaic pads the trailing two dims to the dtype's
+    minimum tile; compare against the transposed layout."""
+    if len(block) < 2:
+        return
+    sub, lane = block[-2], block[-1]
+    if sub <= 0 or lane <= 0:
+        return
+    st, lt = _min_tile(dtype)
+    waste = (_roundup(sub, st) * _roundup(lane, lt)) / (sub * lane)
+    waste_t = (_roundup(lane, st) * _roundup(sub, lt)) / (sub * lane)
+    if waste >= _WASTE_FLAG and waste >= _WASTE_RATIO * waste_t:
+        emit(Finding(
+            rule="kernel-tile.pad-waste",
+            severity=Severity.WARNING,
+            message=(f"pallas_call {what} block {block} "
+                     f"({dtype.name}) pads to the "
+                     f"({st},{lt}) minimum tile at {waste:.0f}x "
+                     f"its size — transposing the trailing "
+                     f"dims would pad only {waste_t:.0f}x (the "
+                     f"small-head-dim-in-the-lane-slot "
+                     f"hazard)"),
+            where=src,
+            hint="swap the trailing block dims (pack the "
+                 "small dim into sublanes, the long one into "
+                 "lanes), or widen the row: the paged pool "
+                 "keeps all heads of a position in one row "
+                 "(serve/slots.py::PagedKVPool)"))
+
+
+def _held_shape(var, env) -> tuple[int, ...]:
+    """A kernel input's shape as the caller holds it: before the rows were
+    padded to whole lane tiles on the way in, where they were."""
+    made = env.producer.get(id(var))
+    if made is not None and made.primitive.name == "pad":
+        var = made.invars[0]
+    return tuple(int(d) for d in var.aval.shape)
+
+
+def check_dma_start(walker, eqn, env) -> None:
+    """BoundsWalker transfer function for a kernel's ``dma_start``: where
+    the source is an unblocked operand, every indexed dimension's interval
+    must lie inside the operand (the block-table contract reaches the copy
+    through the ``get`` on the scalar-prefetch ref)."""
+    import jax
+
+    from simple_distributed_machine_learning_tpu.analysis.bounds import (
+        Interval,
+        _index_verdict,
+    )
+    if walker._mute > 0:
+        return
+    src_ref, src_tf = jax.tree_util.tree_unflatten(
+        eqn.params["tree"], eqn.invars)[:2]
+    aval = src_ref.aval
+    if not _unblocked(aval) or not src_tf:
+        return                          # not the pool, or all of it
+    name = walker._kernels[-1] if walker._kernels else "pallas_call"
+    where = source_line(eqn)
+    shape = tuple(int(d) for d in aval.shape)
+    idx = getattr(src_tf[0], "indices", None) if len(src_tf) == 1 else None
+    if idx is None or tuple(src_tf[0].shape) != shape:
+        walker._emit(Finding(
+            rule="kernel-unproven.dma-source", severity=Severity.WARNING,
+            message=(f"{name}: a copy reads its unblocked operand {shape} "
+                     f"through a view the rule does not follow — nothing "
+                     f"proven"),
+            where=where,
+            hint="index the operand with one .at[...] of scalars and "
+                 "pl.ds slices"))
+        return
+    for d, (ix, dim) in enumerate(zip(idx, shape)):
+        size = int(getattr(ix, "size", 1))
+        start = getattr(ix, "start", ix)
+        iv = (Interval(start, start) if isinstance(start, int)
+              else env.read(start))
+        verdict = _index_verdict(iv, dim - size)
+        if verdict == "ok":
+            continue
+        oob = verdict == "oob"
+        lo = "-inf" if iv.lo == -_INF else int(iv.lo)
+        hi = "inf" if iv.hi == _INF else int(iv.hi)
+        walker._emit(Finding(
+            rule=("kernel-oob.dma-source" if oob
+                  else "kernel-unproven.dma-source"),
+            severity=Severity.ERROR if oob else Severity.WARNING,
+            message=(f"{name}: a copy of {size} along dimension {d} of "
+                     f"its unblocked operand {shape} starts in [{lo}, "
+                     f"{hi}], only [0, {dim - size}] is addressable — "
+                     + ("the kernel would copy a window outside the "
+                        "buffer" if oob else
+                        "the copy is only as safe as the undeclared "
+                        "operand feeding it")),
+            where=where,
+            hint="clamp the index in the kernel, or tighten the declared "
+                 "spec(...) contract on the scalar-prefetch operand "
+                 "feeding it (block tables <= n_blocks)"))
 
 
 def _roundup(x: int, q: int) -> int:
@@ -460,26 +581,36 @@ def kernel_hbm_costs(closed_jaxpr, program: str = "") -> list[HBMCost]:
                 calls += 1
                 grid = _grid(gm)
                 n_sp, n_in, n_out, _ = _counts(eqn, gm)
+                seen = 0
+                copies = False
                 for i, bm in enumerate(getattr(gm, "block_mappings", ())
                                        or ()):
                     parts = _bm_parts(bm)
                     closed = _index_map_jaxpr(bm)
                     if parts is None or closed is None:
                         continue
+                    if _kernel_copies(bm):
+                        copies = True
+                        continue
                     block, _shape, dtype = parts
                     deps = frozenset().union(
                         *_dep_axes(closed, len(grid))) \
                         if grid else frozenset()
-                    t = trips
+                    nbytes = int(np.prod(block)) * dtype.itemsize
                     for g in deps:
                         if g < len(grid):
-                            t *= grid[g]
-                    nbytes = int(np.prod(block)) * dtype.itemsize * t
+                            nbytes *= grid[g]
+                    seen += nbytes
                     if i < n_in and _uses_scalar_prefetch(closed,
                                                           len(grid)):
-                        kv += nbytes
+                        kv += nbytes * trips
                     else:
-                        io += nbytes
+                        io += nbytes * trips
+                # what the kernel copies itself no BlockSpec shows: the
+                # call declares all it moves, the blocks seen are the rest
+                cost = eqn.params.get("cost_estimate")
+                if copies and cost is not None:
+                    kv += max(0, int(cost.bytes_accessed) - seen) * trips
                 continue
             mult = 1
             if eqn.primitive.name == "scan":
@@ -493,8 +624,9 @@ def kernel_hbm_costs(closed_jaxpr, program: str = "") -> list[HBMCost]:
     rows = [HBMCost(
         op="kernel.kv_stream", program=program, bytes_per_tick=kv,
         note=f"{calls} pallas_call(s): table-indexed K/V blocks x the "
-             f"grid trips their index maps depend on — derived from the "
-             f"kernels' own BlockSpecs")]
+             f"grid trips their index maps depend on, from the kernels' "
+             f"own BlockSpecs; for operands a kernel copies itself, its "
+             f"declared bytes less the blocks seen")]
     if io:
         rows.append(HBMCost(
             op="kernel.io", program=program, bytes_per_tick=io,

@@ -6,29 +6,32 @@ dance every tick: gather each slot's physical K/V blocks into a dense
 ``[S, H, span, dh]`` row buffer (one full HBM read of resident K/V plus a
 full write of the gathered copy), then dense masked attention over that
 buffer (a second full read). This module fuses the two into ONE Pallas
-kernel pass, following the grid/online-softmax structure of
-``ops/flash_attention.py``:
+kernel pass (the structure of JAX's own paged-attention kernel):
 
-- **block-table-indexed gather**: the per-slot block table and query
-  positions ride in as scalar-prefetch operands
-  (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index maps
-  dereference ``tables[s, kb]`` directly — each physical block streams from
-  HBM into VMEM exactly once per tick, already in sequence order, and no
-  gathered dense copy ever exists;
-- **online softmax** (flash style): the k-block grid axis is innermost and
-  carries ``(acc, l, m)`` scratch across iterations, so the ``[K, span]``
-  score matrix is never materialized and VMEM holds O(H·K·dh + H·bs·dh);
-- **past-the-end fetch elision**: k-blocks wholly past the newest query
-  position are predicated off with ``pl.when``, and the index map clamps
-  their block id at the last needed one — an unchanged index between
-  iterations means Mosaic's pipeline issues no HBM copy (the
-  ``_diag_kv_index`` trick from the causal kernel, applied to the
-  position mask instead of the diagonal);
+- **a slot's own blocks, copied by the kernel**: the grid is one cell a
+  slot. The per-slot block table and query positions ride in as
+  scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``), the pool's
+  buffers go in unblocked (``memory_space=pl.ANY``), and the cell loops
+  over the slot's live SPANS only, ``newest position // span + 1`` trips:
+  each trip's blocks are copied by ``pltpu.make_async_copy`` through
+  ``tables[s, b]`` into one contiguous double-buffered VMEM span, the next
+  span's copies started before the current one is waited for, and under a
+  slot's last span the NEXT slot's first one. A seat that sits a tick out
+  (position 0 of an all-trash table) costs one trip, a 300-position slot
+  two (the grid before PR 33 stepped through 16 cells for either, at two
+  thirds of a microsecond a skipped cell), and no gathered dense copy ever
+  exists;
+- **online softmax** (flash style): ``(m, l, acc)`` are the loop's carries,
+  written to the output once, so the ``[K, max_len]`` score matrix is never
+  materialized;
+- **nothing past a slot's length**: a span's blocks past the newest query
+  position are fetched as the newest block again and removed by the
+  position mask; a table entry past it is never read;
 - **fused dequantization**: int8/fp8 K/V blocks carry per-row (position x
-  head) f32 scales; the kernel multiplies them back in VMEM right after the
-  block load, so a quantized pool pays the narrow dtype's HBM bytes without
-  a separate dequantize pass (the whole point of quantizing: the decode
-  tick is memory-bound on exactly this stream);
+  head) f32 scales, copied beside them; the kernel multiplies them back in
+  VMEM right after the load, so a quantized pool pays the narrow dtype's
+  HBM bytes without a separate dequantize pass (the whole point of
+  quantizing: the decode tick is memory-bound on exactly this stream);
 - **f32 score/accumulator math**: K/V tiles are upcast (or dequantized) to
   f32 before the dots, matching the dense path's einsum promotion — which
   is what keeps greedy decode through this kernel TOKEN-bit-exact against
@@ -63,160 +66,231 @@ from simple_distributed_machine_learning_tpu.ops.flash_attention import (
 )
 
 
-#: K/V blocks a grid cell reads (each through an operand of its own, so
-#: their fetches are in flight together) and attends as ONE span of
-#: ``n * bs`` positions. What bounds the kernel is neither bytes nor
-#: fetches but the dependent chain of a cell (scores, row maximum,
-#: exponential, weighted sum, the scratch's read-modify-write): about a
-#: microsecond whatever the block holds (``PERF.md``, PR 29), so a cell takes
-#: as many blocks as divide the table and fit the kernel's fast memory
-_BLOCKS_PER_CELL = 4
+#: blocks of one span at most, and the fast memory the two double-buffered
+#: span buffers (K and V, two halves each) may take. Measured on the chip
+#: (``PERF.md`` section 6, PR 33): the grid this loop replaced, ``(slots,
+#: max_len / 4 blocks)``, paid 0.66-0.70 us for every cell it SKIPPED and
+#: 1.17-1.84 us for a live one, so a call was mostly its grid; here a
+#: further span of 16 blocks costs a slot 0.4-0.6 us, against 3-7 us a slot
+#: costs before its first span, so a span is as long as the table and the
+#: buffers allow (16 beat 8 and 4 at all three cells' shapes)
+_SPAN_BLOCKS = 16
 _KV_VMEM_BYTES = 4 << 20
 
 
+def _span_blocks(bs: int, block_bytes: int, n_table: int, itemsize: int):
+    """Blocks of one span, from what the call can see. A block lands at row
+    ``g * bs`` of the span buffer, which Mosaic takes only where a block is
+    whole sublane tiles of the POOL's dtype (8 rows of 4 bytes, 16 of 2, 32
+    of 1); where it is not, a span is one block. Otherwise the largest
+    power of two up to ``_SPAN_BLOCKS`` that the table holds and whose four
+    buffers fit ``_KV_VMEM_BYTES``."""
+    if bs % (32 // itemsize):
+        return 1
+    n = _SPAN_BLOCKS
+    while n > 1 and (n > n_table or 4 * n * block_bytes > _KV_VMEM_BYTES):
+        n //= 2
+    return n
+
+
+def _whole_lanes(a):
+    """``a`` with its last axis zero-padded to whole lane tiles."""
+    pad = -a.shape[-1] % _LANES
+    return lax.pad(a, jnp.zeros((), a.dtype), [(0, 0, 0)] * (a.ndim - 1)
+                   + [(0, pad, 0)]) if pad else a
+
+
 def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
-                       n_q: int, scale: float, quant: bool, n_sub: int):
-    """One (slot, span of ``n_sub`` k-blocks) grid cell; the k axis is
-    innermost and carries the online-softmax state.
+                       n_q: int, scale: float, quant: bool, n_sub: int,
+                       nested: bool = False):
+    """One slot: a loop over the slot's live spans of ``n_sub`` blocks.
 
-    ``q_ref``: [1, H, K, dh] (this slot's queries, all heads); then
-    ``n_sub`` K refs and ``n_sub`` V refs, each [1, H, bs, dh] — the
-    PHYSICAL blocks the index maps dereferenced through the slot's table,
-    consecutive logical blocks of the sequence; with ``quant``, as many
-    ``ks``/``vs`` refs [1, H, bs], the per-row dequant scales of the same
-    blocks; ``o_ref``: [1, H, K, dh] f32. Scratch: ``acc`` [H, K, dh] f32
-    and the lane-broadcast ``l``/``m`` [H, K, _LANES] f32
-    (flash_attention's scratch idiom). ``H`` and ``dh`` are the CALL's: the
-    wrapper hands a rows-in-lanes pool over as one stream (``H = 1``) whose
-    ``dh`` is the whole row."""
-    k_refs, v_refs = rest[:n_sub], rest[n_sub:2 * n_sub]
-    rest = rest[2 * n_sub:]
-    ks_refs = vs_refs = (None,) * n_sub
-    if quant:
-        ks_refs, vs_refs = rest[:n_sub], rest[n_sub:2 * n_sub]
-        rest = rest[2 * n_sub:]
-    o_ref, acc_scr, l_scr, m_scr = rest
+    ``q_ref``: [1, H, R, dh], this slot's query rows, all heads: row ``r``
+    stands at position ``qpos[s, r % n_q]``; ``k_hbm`` / ``v_hbm``: the
+    pool's buffers where they lie, [n_blocks+1, H, bs, dh]; with ``quant``
+    the scale planes ``ks_hbm`` / ``vs_hbm`` [n_blocks+1, H, bs in whole
+    lane tiles]; ``o_ref``: [1, H, R, dh] f32. Scratch, all of it kept from
+    one slot to the next: the double-buffered spans ``kbuf`` / ``vbuf`` [2,
+    H, n_sub * bs, dh] in the pool's dtype (with ``quant`` ``ksbuf`` /
+    ``vsbuf`` [2, n_sub, H, lanes] f32), the DMA semaphores [streams, 2,
+    n_sub], and ``first`` (SMEM [1]): the buffer half that holds this
+    slot's first span. ``H`` and ``dh`` are the CALL's: the wrapper hands a
+    rows-in-lanes pool over as one stream (``H = 1``) whose ``dh`` is the
+    whole row."""
+    n_streams = 4 if quant else 2
+    hbm, rest = rest[:n_streams], rest[n_streams:]
+    o_ref, rest = rest[0], rest[1:]
+    bufs, (sem, first) = rest[:n_streams], rest[n_streams:]
     s_idx = pl.program_id(0)
-    kb = pl.program_id(1)
-    n_kb = pl.num_programs(1)
     span = n_sub * bs
+    trips = lax.div(qpos_ref[s_idx, n_q - 1], span) + 1  # newest position
 
-    @pl.when(kb == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    def copies(slot, it, half, wait=False):
+        """Start (or, with ``wait``, wait for) the DMAs of ``slot``'s span
+        ``it`` into buffer half ``half``: block ``g`` of every stream,
+        through the slot's table. A span's blocks past the slot's newest
+        one fetch that one again (the position mask removes them; a table
+        entry past it is never read). A descriptor that only waits names a
+        fixed block. The blocks are a loop, not ``n_sub`` copies of its
+        body: a program traces this three times over."""
+        last_blk = 0 if wait else lax.div(qpos_ref[slot, n_q - 1], bs)
 
-    def rows(refs, scale_refs):
-        """The cell's K or V rows, [H, span, dh] f32 (dequantized)."""
-        parts = []
-        for ref, sc in zip(refs, scale_refs):
-            x = ref[0].astype(jnp.float32)                # [H, bs, dh]
-            parts.append(x if sc is None else x * sc[0][..., None])
+        def block(g, _):
+            blk = 0 if wait else tables_ref[
+                slot, lax.min(it * n_sub + g, last_blk)]
+            at = pl.ds(pl.multiple_of(g * bs, bs), bs)
+            for w, (ref, buf) in enumerate(zip(hbm, bufs)):
+                dst = buf.at[half, :, at] if w < 2 else buf.at[half, g]
+                c = pltpu.make_async_copy(ref.at[blk], dst,
+                                          sem.at[w, half, g])
+                if wait:
+                    c.wait()
+                else:
+                    c.start()
+
+        lax.fori_loop(0, n_sub, block, None)
+
+    def rows(buf, sc, half):
+        """The span's K or V rows, [H, span, dh] f32 (dequantized)."""
+        x = buf[half].astype(jnp.float32)
+        if sc is None:
+            return x
+        parts = [x[:, g * bs:(g + 1) * bs] * sc[half, g, :, :bs][..., None]
+                 for g in range(n_sub)]
         return parts[0] if n_sub == 1 else jnp.concatenate(parts, axis=1)
 
-    # spans wholly past the newest query position contribute nothing —
-    # skip (their fetches are elided by the index-map clamp below, which
-    # also hands a live span's own past-the-end blocks the last live one
-    # again: the position mask removes them)
-    @pl.when(kb * span <= qpos_ref[s_idx, n_q - 1])
-    def _compute():
-        # per-query positions of this slot (K is static and small)
-        qp = jnp.stack([qpos_ref[s_idx, j] for j in range(n_q)])
-        q = q_ref[0].astype(jnp.float32)                  # [H, K, dh]
-        k = rows(k_refs, ks_refs)                         # [H, span, dh]
-        v = rows(v_refs, vs_refs)
-        # scores in f32 — the dense path's einsum promotion, so the fused
-        # logits track the gather-then-dense ones to ulps
-        s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,)))) * scale
-        kpos = kb * span + lax.broadcasted_iota(jnp.int32, (1, n_q, span), 2)
-        mask = kpos <= qp[None, :, None]                  # [1, K, span]
-        s = jnp.where(mask, s, NEG_INF)                   # [H, K, span]
-        m_prev = m_scr[..., 0]                            # [H, K]
-        l_prev = l_scr[..., 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=2))
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        acc_scr[...] = (acc_scr[...] * corr[..., None]
-                        + lax.dot_general(p, v,
-                                          (((2,), (1,)), ((0,), (0,)))))
-        l_scr[...] = jnp.broadcast_to(
-            (l_prev * corr + p.sum(axis=2))[..., None], l_scr.shape)
-        m_scr[...] = jnp.broadcast_to(m_new[..., None], m_scr.shape)
+    def slot():
+        @pl.when(s_idx == 0)
+        def _first():           # nobody before the first slot fetched for it
+            first[0] = 0
+            copies(0, 0, 0)
 
-    @pl.when(kb == n_kb - 1)
-    def _finalize():
-        l = l_scr[..., 0]
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l, 1e-30)[..., None]).astype(o_ref.dtype)
+        q = q_ref[0].astype(jnp.float32)                  # [H, R, dh]
+        H, R, dh = q.shape
+        # the rows' positions, in the sublanes as the scores' rows are
+        qp = jnp.full((1, R, 1), qpos_ref[s_idx, 0], jnp.int32)
+        row = lax.rem(lax.broadcasted_iota(jnp.int32, (1, R, 1), 1), n_q)
+        for j in range(1, n_q):
+            qp = jnp.where(row == j, qpos_ref[s_idx, j], qp)
+        half0 = first[0]
+        more = s_idx + 1 < pl.num_programs(0)
+
+        def trip(it, carry):
+            """Span ``it``: start what comes after it, wait for it, fold it
+            into the online-softmax state ``(m, l, acc)``: [H, R, 1] twice
+            and [H, R, dh], float32."""
+            m_prev, l_prev, acc = carry
+            half = lax.rem(half0 + it, 2)
+            # in flight meanwhile: the slot's next span, and under its last
+            # span the NEXT slot's first one, so that a call waits for a
+            # copy it has not overlapped once, not once a slot
+            mine = it + 1 < trips
+
+            @pl.when(mine | more)
+            def _next():
+                copies(jnp.where(mine, s_idx, s_idx + 1),
+                       jnp.where(mine, it + 1, 0), 1 - half)
+
+            copies(s_idx, it, half, wait=True)
+            k = rows(bufs[0], bufs[2] if quant else None, half)
+            v = rows(bufs[1], bufs[3] if quant else None, half)
+            # scores in f32 — the dense path's einsum promotion, so the
+            # fused logits track the gather-then-dense ones to ulps
+            s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,)))) * scale
+            kpos = it * span + lax.broadcasted_iota(
+                jnp.int32, (1, R, span), 2)
+            mask = kpos <= qp                             # [1, R, span]
+            s = jnp.where(mask, s, NEG_INF)               # [H, R, span]
+            m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            acc = acc * corr + lax.dot_general(
+                p, v, (((2,), (1,)), ((0,), (0,))))
+            return (m_new, l_prev * corr + p.sum(axis=2, keepdims=True),
+                    acc)
+
+        _, l, acc = lax.fori_loop(0, trips, trip, (
+            jnp.full((H, R, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, R, 1), jnp.float32),
+            jnp.zeros((H, R, dh), jnp.float32)))
+        first[0] = lax.rem(half0 + trips, 2)    # where the next slot starts
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    # ``nested``: the interpreter under ``shard_map`` alone. It holds the
+    # kernel's top-level equations to the mesh axes the pool varies over,
+    # which a kernel body (traced with that typing off) cannot state; what
+    # lies inside a branch it leaves alone. Every slot has a first span
+    (pl.when(trips > 0)(slot) if nested else slot())
 
 
 def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
                    interpret=None):
     """The Pallas call over head-major operands: ``q`` [S, H, K, dh],
     ``kc``/``vc`` [n_blocks+1, H, bs, dh], scales [n_blocks+1, H, bs] or
-    None, ``qpos`` [S, K] (non-decreasing along K). Returns f32
-    [S, H, K, dh]."""
+    None, ``qpos`` [S, n_q]: query row ``r`` of a head stands at position
+    ``qpos[s, r % n_q]`` (``K`` a multiple of ``n_q``), and the last
+    column is the slot's newest position. Returns f32 [S, H, K, dh]."""
     if interpret is None:
         interpret = _interpret()
-    S, H, K, dh = q.shape
     NB = tables.shape[1]
     quant = kscale is not None
-    # the pool goes in once per block of a cell (the same buffer under
-    # another index map): logical block kb * n_sub + g through operand g.
-    # One block a cell where the blocks are not whole f32 sublane tiles
-    # (their rows could not be joined in place)
+    # the kernel copies a block itself, and Mosaic copies whole lane tiles
+    # of the source only: a stream whose rows are not whole tiles (a toy
+    # width; every scale plane, whose row is a block's positions) is
+    # padded with zeros, which a score and a row's output add exactly
+    streams = [kc, vc] + ([kscale, vscale] if quant else [])
+    held = sum(math.prod(a.shape[1:]) * a.dtype.itemsize for a in streams)
+    dh_call = q.shape[-1]
+    q, *streams = (_whole_lanes(a) for a in (q, *streams))
+    kc, vc = streams[:2]
+    S, H, K, dh = q.shape
+    # what the call moves at most: the query block in and the output block
+    # back, and every slot's whole table span of every stream once, as the
+    # pool holds it. The analyzer reads the K/V stream of operands it
+    # cannot see blocked from this (analysis/kernels.py::kernel_hbm_costs)
+    moved = q.size * (q.dtype.itemsize + 4) + S * NB * held
     block_bytes = H * bs * dh * kc.dtype.itemsize
-    n_sub = next(g for g in (_BLOCKS_PER_CELL, 2, 1) if g == 1 or (
-        NB % g == 0 and bs % 8 == 0
-        and 4 * g * block_bytes <= _KV_VMEM_BYTES))
+    n_sub = _span_blocks(bs, block_bytes, NB, kc.dtype.itemsize)
+    span = n_sub * bs
 
-    def _block(g):
-        def index(s, kb, tables_ref, qpos_ref):
-            # past-the-end fetch elision: clamp at the newest query's block
-            # so skipped blocks revisit it (no HBM copy when unchanged)
-            last = qpos_ref[s, K - 1] // bs
-            return tables_ref[s, jnp.minimum(kb * n_sub + g, last)]
-
-        return index
-
-    def _q_idx(s, kb, tables_ref, qpos_ref):
+    def _q_idx(s, tables_ref, qpos_ref):
         return (s, 0, 0, 0)
 
-    def _specs(block, tail):
-        return [pl.BlockSpec(block, lambda *a, i=_block(g): (i(*a), *tail))
-                for g in range(n_sub)]
-
+    # the pool's buffers stay where they are: the kernel copies what a
+    # slot has, block by block
     in_specs = ([pl.BlockSpec((1, H, K, dh), _q_idx)]
-                + 2 * _specs((1, H, bs, dh), (0, 0, 0)))
-    operands = [q] + [kc] * n_sub + [vc] * n_sub
+                + [pl.BlockSpec(memory_space=pl.ANY)] * len(streams))
+    scratch = [pltpu.VMEM((2, H, span, dh), kc.dtype)] * 2
     if quant:
-        in_specs += 2 * _specs((1, H, bs), (0, 0))
-        operands += [kscale] * n_sub + [vscale] * n_sub
+        scratch += [pltpu.VMEM((2, n_sub, *streams[2].shape[1:]),
+                               kscale.dtype)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((len(streams), 2, n_sub)),
+                pltpu.SMEM((1,), jnp.int32)]
 
     vma = _vma_of(q, kc, vc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, NB // n_sub),
+        grid=(S,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, H, K, dh), _q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((H, K, dh), jnp.float32),
-            pltpu.VMEM((H, K, _LANES), jnp.float32),
-            pltpu.VMEM((H, K, _LANES), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, bs=bs, n_q=K, scale=scale,
-                          quant=quant, n_sub=n_sub),
+        functools.partial(_paged_attn_kernel, bs=bs, n_q=qpos.shape[1],
+                          scale=scale, quant=quant, n_sub=n_sub,
+                          nested=bool(interpret and vma)),
         grid_spec=grid_spec,
         out_shape=_struct((S, H, K, dh), jnp.float32, vma),
-        # slots are independent; the k axis carries scratch state
-        compiler_params=_compiler_params("parallel", "arbitrary"),
+        # in order: a slot fetches its successor's first span
+        compiler_params=_compiler_params("arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * S * H * K * NB * bs * dh,
+            transcendentals=S * H * K * NB * bs,
+            bytes_accessed=moved),
         interpret=interpret,
         name="paged_attention",
-    )(tables.astype(jnp.int32), qpos.astype(jnp.int32), *operands)
+    )(tables.astype(jnp.int32), qpos.astype(jnp.int32), q,
+      *streams)[..., :dh_call]
 
 
 def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
@@ -282,16 +356,15 @@ def _paged_attention(q, kc, vc, tables, qpos, kscale, vscale, *, bs,
     scale = 1.0 / math.sqrt(dh)
     rows = (H // kvh) * n_q
     # head h = kv * group + g: its queries are rows g * n_q + k of K/V head
-    # kv; each group's last row holds the newest position, which is all the
-    # kernel asks of their order
+    # kv, and row r of any head stands at qpos[s, r % n_q]: what the kernel
+    # asks of their order
     q = q.reshape(S, kvh, rows, dh)
     if quant and kvh > 1:
         def heads_first(a):
             return jnp.swapaxes(a.reshape(n_phys, bs, kvh, -1), 1, 2)
 
         out = _attend_blocks(
-            q, heads_first(kc), heads_first(vc), tables,
-            jnp.tile(qpos, (1, H // kvh)), bs, scale,
+            q, heads_first(kc), heads_first(vc), tables, qpos, bs, scale,
             jnp.swapaxes(kscale, 1, 2), jnp.swapaxes(vscale, 1, 2),
             interpret)
         return out.reshape(S, H, n_q, dh)
@@ -300,7 +373,7 @@ def _paged_attention(q, kc, vc, tables, qpos, kscale, vscale, *, bs,
         q = q[:, :, :, None, :] * own             # [S, KVH, rows, KVH, dh]
     out = _attend_blocks(
         q.reshape(S, 1, kvh * rows, width), kc[:, None], vc[:, None],
-        tables, jnp.tile(qpos, (1, H)), bs, scale,
+        tables, qpos, bs, scale,
         kscale.reshape(n_phys, 1, bs) if quant else None,
         vscale.reshape(n_phys, 1, bs) if quant else None, interpret)
     out = out.reshape(S, kvh, rows, kvh, dh)

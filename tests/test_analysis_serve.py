@@ -860,6 +860,66 @@ def test_kernel_scalar_prefetch_contract_seeds_the_proof():
                for f in escaping.findings), escaping.format()
 
 
+def test_a_kernels_own_copies_are_proven_from_the_table_contract():
+    """``paged_attention`` takes the pool unblocked and copies a slot's
+    blocks itself: no index map to evaluate, so the body is walked and
+    every ``dma_start`` that reads the pool is held to its shape. With the
+    table's contract the copies prove clean; without it the kernel is
+    reported BY NAME as unproven, never silently ok; a contract that
+    admits a block past the pool is an ERROR."""
+    from simple_distributed_machine_learning_tpu.ops.paged_attention import (
+        paged_attention,
+    )
+    S, H, dh, bs, NB, n_blocks = 2, 2, 64, 8, 3, 5
+
+    def attend(q, kc, vc, tables, qpos):
+        return paged_attention(q, kc, vc, tables, qpos, block_size=bs)
+
+    q = jax.ShapeDtypeStruct((S, H, 1, dh), np.float32)
+    kv = jax.ShapeDtypeStruct((n_blocks + 1, bs, H * dh), np.float32)
+    qpos = spec((S, 1), np.int32, 0, NB * bs - 1)
+    proven = analyze(attend, q, kv, kv,
+                     spec((S, NB), np.int32, 0, n_blocks), qpos)
+    assert not [f for f in proven.findings
+                if f.family.startswith("kernel-")], proven.format()
+    unproven = analyze(attend, q, kv, kv,
+                       jax.ShapeDtypeStruct((S, NB), np.int32), qpos)
+    found = [f for f in unproven.findings
+             if f.rule == "kernel-unproven.dma-source"]
+    assert found and all("paged_attention" in f.message for f in found), (
+        unproven.format())
+    assert not unproven.errors
+    escaping = analyze(attend, q, kv, kv,
+                       spec((S, NB), np.int32, 0, n_blocks + 1), qpos)
+    assert any(f.rule == "kernel-oob.dma-source"
+               for f in escaping.errors), escaping.format()
+
+
+def test_a_kernels_declared_bytes_are_its_kv_stream():
+    """What a kernel copies itself no BlockSpec shows: ``kernel_hbm_costs``
+    takes the stream from the call's declared ``cost_estimate`` less the
+    blocks it can see, which for ``paged_attention`` is every slot's whole
+    table span of K and V, once."""
+    from simple_distributed_machine_learning_tpu.analysis.kernels import (
+        kernel_hbm_costs,
+    )
+    from simple_distributed_machine_learning_tpu.ops.paged_attention import (
+        paged_attention,
+    )
+    S, H, dh, bs, NB, n_phys = 3, 2, 64, 8, 4, 9
+    jaxpr = jax.make_jaxpr(lambda *a: paged_attention(*a, block_size=bs))(
+        jax.ShapeDtypeStruct((S, H, 1, dh), np.float32),
+        jax.ShapeDtypeStruct((n_phys, bs, H * dh), "bfloat16"),
+        jax.ShapeDtypeStruct((n_phys, bs, H * dh), "bfloat16"),
+        jax.ShapeDtypeStruct((S, NB), np.int32),
+        jax.ShapeDtypeStruct((S, 1), np.int32))
+    rows = {h.op: h.bytes_per_tick for h in kernel_hbm_costs(jaxpr)}
+    assert rows["kernel.kv_stream"] == S * NB * bs * H * dh * 2 * 2
+    # the query block in, the output block back: a head's row is the whole
+    # pool row wide (its own lanes of a zeroed row)
+    assert rows["kernel.io"] == 2 * S * H * (H * dh) * 4
+
+
 def test_kernel_narrowing_cast_drops_the_proof():
     """An i32->i8 cast inside the index map forgets the interval when the
     grid axis provably overflows int8 (wrap semantics): the proof must

@@ -170,24 +170,45 @@ def _kernel_pattern(mix: str, kernel: str) -> str:
         return json.load(f)["kernels"][kernel]
 
 
-def test_multi_query_paged_attention_compiles_at_the_hybrid_cells_shape(
-        one_chip, mosaic):
-    """``jamba2-3b.serve-reason-closed``: 128 slots, 20 query heads riding
-    the pool's ONE K/V head of 128, bfloat16 blocks of 16 (the 20 heads are
-    20 query rows of the head's block stream: the case where the pool's row
-    is one head wide)."""
-    slots, heads, dh, bs, nb = 128, 20, 128, 16, 64
-    shapes = [((slots, heads, 1, dh), jnp.float32),
-              ((8193, bs, dh), jnp.bfloat16),
-              ((8193, bs, dh), jnp.bfloat16),
-              ((slots, nb), jnp.int32), ((slots, 1), jnp.int32)]
+# the three serve cells' decode calls as they are: slots, query heads,
+# queries a slot, head dim, K/V heads in a pool row, blocks in the pool (the
+# trash block included), the traffic file whose pattern finds the kernel
+_CELLS = {
+    "gpt2-large.serve-closed": (16, 20, 1, 64, 20, 513, "serve-closed"),
+    "jamba2-3b.serve-reason-closed": (128, 20, 1, 128, 1, 8193,
+                                      "serve-reason-closed"),
+    "sdar-30b-a3b.serve-diffuse-closed": (64, 32, 4, 128, 4, 4097,
+                                          "serve-diffuse-closed"),
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELLS))
+def test_paged_attention_compiles_at_the_serve_cells_real_shapes(
+        one_chip, mosaic, cell):
+    """Every serve cell's call at its REAL size (``max_len`` 1,024 in
+    bfloat16 blocks of 16): 20 K/V heads of 64 in a 1,280-lane row under
+    one query each; 20 query heads riding the hybrid's ONE K/V head of 128
+    (the case where the pool's row is one head wide); 4 K/V heads under 32
+    query heads with the 4 queries of a block a slot. Mosaic's tiling and
+    fast-memory limits for the double-buffered spans (16 blocks each here)
+    are what interpret mode cannot see."""
+    slots, heads, k, dh, kvh, n_phys, mix = _CELLS[cell]
+    bs, nb = 16, 64
+    shapes = [((slots, heads, k, dh), jnp.float32),
+              ((n_phys, bs, kvh * dh), jnp.bfloat16),
+              ((n_phys, bs, kvh * dh), jnp.bfloat16),
+              ((slots, nb), jnp.int32), ((slots, k), jnp.int32)]
+    assert pa._span_blocks(bs, bs * kvh * dh * 2, nb, 2) == 16
     (line,) = _compile(lambda q, kc, vc, t, p: pa.paged_attention(
         q, kc, vc, t, p, block_size=bs), one_chip, *shapes,
         kernels=["paged_attention"])
-    assert re.search(_kernel_pattern("serve-reason-closed",
-                                     "paged_attention"), line.strip())
-    assert not re.search(_kernel_pattern("serve-reason-closed",
-                                         "selective_scan"), line.strip())
+    assert re.search(_kernel_pattern(mix, "paged_attention"), line.strip())
+    for other in ("selective_scan", "moe_experts"):
+        with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                               "bench_cells", "traffic",
+                               mix + ".json")) as f:
+            pattern = json.load(f)["kernels"].get(other)
+        assert not (pattern and re.search(pattern, line.strip()))
 
 
 # -- the serve programs: the pool stays where it is ----------------------------

@@ -121,9 +121,10 @@ def test_paged_flash_decode_matches_dense_gather():
 @pytest.mark.parametrize("bs,NB", [(4, 6), (8, 4), (8, 6)])
 def test_paged_attention_verify_variant_matches_dense_gather(bs, NB):
     """The K-token variant: per-query masks at qpos = pos + j. Blocks of 8
-    are whole sublane tiles, so a grid cell attends a span of 4 (``NB`` 4)
-    or 2 (``NB`` 6) of them at once; a span's blocks past a slot's newest
-    position are fetched as its last live block and masked."""
+    float32 rows are whole sublane tiles, so a trip attends a span of 4 of
+    them at once (the largest power of two either table holds); a span's
+    blocks past a slot's newest position are fetched as its last live
+    block and masked."""
     kq, kc, vc, tables, pos = _toy_pool(jax.random.key(1), bs=bs, NB=NB)
     K = 4
     q = jax.random.normal(kq, (3, 2, K, 16))
@@ -167,6 +168,85 @@ def test_paged_attention_fused_dequant_matches_dequantized_rows(H):
     rtol, atol = attn_tol(jnp.int8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                rtol=rtol, atol=atol)
+
+
+# the three served families' calls, scaled down: (query heads, K/V heads in
+# a pool row, queries a slot, head dim). A row is 128 lanes in each
+_FAMILIES = {
+    "rows-in-lanes": (4, 4, 1, 32),     # GPT: every head a K/V head
+    "one-kv-head": (5, 1, 1, 128),      # the hybrid: multi-query
+    "grouped-4-queries": (8, 2, 4, 64),  # block diffusion: a block a slot
+}
+
+
+@pytest.mark.parametrize("pool", ["bfloat16", "int8"])
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_ragged_slots_in_one_call_match_dense_gather(family, pool):
+    """The loop over a slot's own spans, five slots of one call: a seat at
+    position 0 of an all-trash table (one trip), a slot that ends on a
+    span's last position, one a position past it (a second trip for one
+    row), one at ``max_len - 1``, and one whose table is a permutation
+    with a block repeated. A span is 128 positions here (8 bfloat16 blocks
+    of 16, 4 int8 blocks of 32: whole sublane tiles) in a table of 192."""
+    H, KVH, K, dh = _FAMILIES[family]
+    quant = pool == "int8"
+    bs = 32 if quant else 16
+    NB, n_phys, span = 192 // bs, 40, 128
+    kq, kk, kv = jax.random.split(jax.random.key(11), 3)
+    kc = jax.random.normal(kk, (n_phys, KVH, bs, dh))
+    vc = jax.random.normal(kv, (n_phys, KVH, bs, dh))
+    last = np.array([0, span - 1, span, NB * bs - 1, 100], np.int32)
+    rng = np.random.default_rng(3)
+    tables = np.zeros((5, NB), np.int32)
+    for s in (1, 2, 3):
+        live = last[s] // bs + 1
+        tables[s, :live] = rng.permutation(np.arange(1, n_phys))[:live]
+    live = last[4] // bs + 1
+    tables[4, :live] = rng.permutation(np.arange(1, n_phys))[:live]
+    tables[4, live - 1] = tables[4, 0]          # a block referenced twice
+    q = jax.random.normal(kq, (5, H, K, dh))
+    qpos = np.repeat(last[:, None], K, axis=1)  # a block's rows: one position
+    if quant:
+        kd, ks = _quantize_rows(kc, jnp.int8)
+        vd, vs = _quantize_rows(vc, jnp.int8)
+        kw = dict(kscale=_rows(ks), vscale=_rows(vs))
+        kc = kd.astype(jnp.float32) * ks[..., None]
+        vc = vd.astype(jnp.float32) * vs[..., None]
+    else:
+        kd = kc = kc.astype(jnp.bfloat16)
+        vd = vc = vc.astype(jnp.bfloat16)
+        kw = {}
+    out = jax.jit(lambda q, k, v, t, p, **kw: paged_attention(
+        q, k, v, t, p, block_size=bs, **kw))(
+        q, _rows(kd), _rows(vd), jnp.asarray(tables), jnp.asarray(qpos),
+        **kw)
+    group = H // KVH
+    ref = _dense_paged_reference(q, jnp.repeat(kc, group, axis=1),
+                                 jnp.repeat(vc, group, axis=1), tables, qpos)
+    assert out.shape == q.shape and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_a_span_is_chosen_from_what_the_call_can_see():
+    """No knob: whole sublane tiles of the pool's dtype join into a span
+    (as many blocks as the table holds and the buffers fit), anything else
+    is attended a block at a time."""
+    from simple_distributed_machine_learning_tpu.ops.paged_attention import (
+        _span_blocks,
+    )
+    # the three cells: 40 KB, 4 KB and 16 KB bfloat16 blocks, tables of 64
+    assert _span_blocks(16, 16 * 1280 * 2, 64, 2) == 16
+    assert _span_blocks(16, 16 * 128 * 2, 64, 2) == 16
+    assert _span_blocks(16, 16 * 512 * 2, 64, 2) == 16
+    assert _span_blocks(16, 16 * 1280 * 2, 12, 2) == 8      # a short table
+    assert _span_blocks(16, 1 << 20, 64, 2) == 1            # no room for two
+    assert _span_blocks(128, 128 * 2048 * 2, 8, 2) == 2
+    # not whole tiles: 4 rows of anything, 16 rows of one byte
+    assert _span_blocks(4, 4 * 1024 * 2, 128, 2) == 1
+    assert _span_blocks(16, 16 * 1024, 32, 1) == 1
+    assert _span_blocks(32, 32 * 1024, 32, 1) == 16
+    assert _span_blocks(8, 8 * 1024 * 4, 64, 4) == 16
 
 
 def test_quantize_roundtrip_error_bound():
@@ -266,8 +346,8 @@ def test_engine_greedy_fused_bit_exact_vs_dense_path(stages, stages_dh64,
     storage dtype (f32/bf16 bit-exact vs their own dense path; the int8
     pool vs ITS dense path, quantization identical on both sides), and
     with four heads of 64 in one pool row (and blocks of 8, which the
-    kernel attends four to a grid cell) as with two of 16 (blocks of 4,
-    one to a cell)."""
+    f32 pool's kernel attends four to a span) as with two of 16 (blocks of
+    4, one to a span)."""
     st, cfg, bs = ((stages, CFG, 4) if model == "dh16"
                    else (stages_dh64, CFG_DH64, 8))
     prompts = _prompts()
