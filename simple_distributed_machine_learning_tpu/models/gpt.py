@@ -992,13 +992,22 @@ class PagedServing(NamedTuple):
     ``seat`` is ``[1 + block]`` (how many tokens of the prompt's remainder
     open the block, or :data:`SEAT_NONE`, then those tokens); the decode
     takes ``steps [S]`` (each slot's denoising steps) after ``live`` and
-    returns ``[S, 2 * block + 5]`` int32 for its tokens, which
+    returns ``[S, 2 * block + 1]`` int32 for its tokens, which
     ``unpack_rows(rows, block)`` reads: every slot's block, the forward
-    that fixed each position, whether the forward committed, and the
-    tick's counters. ``block_forwards(block, steps, masked)``: the
-    denoising forwards a block with ``masked`` open positions takes under
-    a request's ``steps``; the host foresees every slot's phase from it
-    (``models/sdar.py::denoise_forwards``, ``unpack_block_rows``)."""
+    that fixed each position and whether the forward committed.
+    ``block_forwards(block, steps, masked)``: the denoising forwards a
+    block with ``masked`` open positions takes under a request's
+    ``steps``; the host foresees every slot's phase from it
+    (``models/sdar.py::denoise_forwards``, ``unpack_block_rows``).
+
+    ``counters``: names of int32 counts a decode run makes of itself (the
+    experts it hit, the forwards it ran). A program that names ``n`` hands
+    its tokens as ``[S, w + n]`` int32 instead of ``[S]`` (``w = 1``) or
+    ``[S, w]``: the last ``n`` columns hold the counts, the same in every
+    row. They ride the read-back the engine makes of the tokens anyway (a
+    tick late under ``ahead``; a second transfer would cost 0.13 ms) and
+    become attributes of that tick's ``engine.tick`` span, 0 where a tick
+    ran no decode."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -1011,6 +1020,7 @@ class PagedServing(NamedTuple):
     block: int = 1
     block_forwards: Callable | None = None
     unpack_rows: Callable | None = None
+    counters: tuple = ()
 
 
 # a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)

@@ -336,10 +336,12 @@ def _tied_logits(params_or_trees, h, cfg: JambaConfig):
 # -- serving: the two paged programs ------------------------------------------
 
 
-def _validate_hybrid_build(stages, cfg: JambaConfig, max_len: int,
-                           block_size: int, cache_dtype, mesh,
-                           adapters: bool) -> None:
-    caller = "JambaConfig.paged_serving"
+def _validate_hybrid_build(stages, cfg, max_len: int, block_size: int,
+                           cache_dtype, mesh, adapters: bool,
+                           caller: str = "JambaConfig.paged_serving",
+                           maker: str = "make_jamba_stages") -> None:
+    """What any family with recurrent state refuses of ``paged_serving``'s
+    arguments, by name (``models/nemotron_h.py`` calls it too)."""
     for name, asked, reason in (
             ("mesh (tensor-parallel serving)", mesh is not None,
              "the scan's channels and the state buffers have no sharded "
@@ -355,8 +357,8 @@ def _validate_hybrid_build(stages, cfg: JambaConfig, max_len: int,
                 f"state: {reason}")
     if len(stages) != 1 or "embed" not in stages[0].params:
         raise ValueError(
-            f"{caller} needs make_jamba_stages' one stage (the head is "
-            f"tied to the embedding), got {len(stages)} stages")
+            f"{caller} needs {maker}' one stage (it has no pipeline "
+            f"build), got {len(stages)} stages")
     table = stages[0].params["embed"]["tok"]
     if table.shape != (cfg.vocab, cfg.d_model) or len(
             stages[0].params["blocks"]) != cfg.n_layers:

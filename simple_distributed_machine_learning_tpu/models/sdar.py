@@ -150,7 +150,7 @@ class SdarConfig:
                 lambda: _build_block_denoise_step(self, block_size, kernel)),
             pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
             ahead=True, block=blk, block_forwards=denoise_forwards,
-            unpack_rows=unpack_block_rows)
+            unpack_rows=unpack_block_rows, counters=BLOCK_COUNTERS)
 
 
 def denoise_schedule(block: int, steps: int) -> list[int]:
@@ -495,19 +495,19 @@ def _sample_block(logits, key_data, temps, top_ks, top_ps):
     return toks, picked - lse, keys
 
 
+#: what a block tick counts of itself (``PagedServing.counters``): slots
+#: that ran a block forward, slots whose block was committed, (layer,
+#: expert) pairs that got a row, the most rows one expert got
+BLOCK_COUNTERS = ("forwards", "commits", "experts_hit", "expert_rows_max")
+
+
 def unpack_block_rows(rows: np.ndarray, block: int):
-    """What the engine reads back of a block tick (``PagedServing.block``):
-    ``(tokens [S, B], order [S, B], committed [S], counters)``. ``order``:
-    the forward (1-based) that fixed each position, 0 for the prompt's
-    remainder. ``counters``: ``forwards`` (slots that ran a block forward),
-    ``commits`` (slots whose block was committed), ``experts_hit`` ((layer,
-    expert) pairs that got a row) and ``expert_rows_max`` (the most rows
-    one expert got)."""
-    c = rows[0, 2 * block + 1:]
+    """What the engine reads back of a block tick (``PagedServing.block``),
+    its counters taken off: ``(tokens [S, B], order [S, B], committed
+    [S])``. ``order``: the forward (1-based) that fixed each position, 0
+    for the prompt's remainder."""
     return (rows[:, :block], rows[:, block:2 * block],
-            rows[:, 2 * block] != 0,
-            {"forwards": int(c[0]), "commits": int(c[1]),
-             "experts_hit": int(c[2]), "expert_rows_max": int(c[3])})
+            rows[:, 2 * block] != 0)
 
 
 def _build_block_denoise_step(cfg: SdarConfig, bs: int, kernel: str):

@@ -83,8 +83,9 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
                    group_sizes: jax.Array) -> jax.Array:
     """``out[r] = lhs[r] @ rhs[g]`` for every row ``r`` of group ``g``,
     float32. ``lhs [m, K]``: the groups' rows laid end to end in group
-    order (``group_sizes [E]`` int32, summing to ``m``); ``rhs [E, K, N]``.
-    Operands in ``rhs``'s dtype."""
+    order (``group_sizes [E]`` int32, summing to ``m`` or less: rows past
+    the last group are multiplied by nothing and come back unwritten);
+    ``rhs [E, K, N]``. Operands in ``rhs``'s dtype."""
     return _grouped_matmul(lhs.astype(rhs.dtype), rhs,
                            group_sizes.astype(jnp.int32),
                            interpret=_interpret())
@@ -98,9 +99,10 @@ def _grouped_matmul(lhs, rhs, group_sizes, *, interpret):
     padded = -(-m // tm) * tm
     if padded != m:             # rows past the last group: never kept
         lhs = jnp.pad(lhs, ((0, padded - m), (0, 0)))
-    tn = n
-    while k * tn * rhs.dtype.itemsize > _RHS_BLOCK_BYTES and tn % 256 == 0:
-        tn //= 2
+    # the widest lane-multiple divisor of n whose block keeps to its budget
+    tn = max((t for t in range(128, n + 1, 128)
+              if n % t == 0 and k * t * rhs.dtype.itemsize
+              <= _RHS_BLOCK_BYTES), default=n)
     offsets, gids, tids, count = _visits(group_sizes, padded, tm)
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm),
@@ -123,27 +125,93 @@ def _grouped_matmul(lhs, rhs, group_sizes, *, interpret):
     return out[:m]
 
 
-def dropless_experts(params: dict, x: jax.Array, top_k: int):
-    """The sparse feed-forward part over rows ``x [T, d]`` (float32,
-    already normed): ``g = softmax(x W_r)`` in float32 over the ``E``
-    experts, the ``top_k`` largest renormalised to sum 1, and ``sum_e w_e *
-    (silu(x Wg_e) * (x Wu_e)) Wd_e`` over them. ``params``: ``router [d,
-    E]``, ``gate`` / ``up`` ``[E, d, f]``, ``down [E, f, d]``. No capacity:
-    every routed pair is computed. Returns ``(y [T, d] float32, rows [E]
-    int32)``, the second how many rows each expert got."""
-    n_tok = x.shape[0]
-    n_experts = params["router"].shape[1]
-    probs = jax.nn.softmax(matmul_acc32(x, params["router"]), axis=-1)
-    w, ids = jax.lax.top_k(probs, top_k)                    # [T, k]
-    w = w / w.sum(-1, keepdims=True)
-    flat = ids.reshape(-1)
-    order = jnp.argsort(flat, stable=True)                  # pairs by expert
-    sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
-    rows = x.astype(params["gate"].dtype)[order // top_k]   # [k T, d]
+# -- routing rules: scores [T, E] -> the chosen experts' (weights, ids) [T, k] --
+
+
+def softmax_top_k(scores: jax.Array, top_k: int):
+    """``g = softmax(scores)`` over the ``E`` experts, the ``top_k`` largest
+    renormalised to sum 1."""
+    w, ids = jax.lax.top_k(jax.nn.softmax(scores, axis=-1), top_k)
+    return w / w.sum(-1, keepdims=True), ids
+
+
+def sigmoid_top_k(bias: jax.Array, scale: float):
+    """The rule ``s = sigmoid(scores)``; chosen: the ``top_k`` largest of
+    ``s + bias`` (``bias [E]`` steers the choice alone); ``w_e = scale * s_e
+    / (sum of s over the chosen + 1e-20)``."""
+    def route(scores, top_k):
+        s = jax.nn.sigmoid(scores)
+        _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        return scale * w / (w.sum(-1, keepdims=True) + 1e-20), ids
+
+    return route
+
+
+# -- expert bodies: (params, rows [m, K] sorted by expert, sizes) -> [m, K] ----
+
+
+def swiglu_experts(params: dict, rows: jax.Array, sizes: jax.Array):
+    """Three matrices an expert: ``(silu(x Wg_e) * (x Wu_e)) Wd_e`` with
+    ``gate`` / ``up [E, K, f]`` and ``down [E, f, K]``."""
     mid = jax.nn.silu(grouped_matmul(rows, params["gate"], sizes)) * (
         grouped_matmul(rows, params["up"], sizes))
-    out = grouped_matmul(mid, params["down"], sizes)        # [k T, d]
+    return grouped_matmul(mid, params["down"], sizes)
+
+
+def relu2_experts(params: dict, rows: jax.Array, sizes: jax.Array):
+    """Two matrices an expert: ``relu(x W1_e)^2 W2_e`` with ``w1 [E, K, f]``
+    and ``w2 [E, f, K]``."""
+    mid = jax.nn.relu(grouped_matmul(rows, params["w1"], sizes))
+    return grouped_matmul(mid * mid, params["w2"], sizes)
+
+
+def dropless_experts(params: dict, x: jax.Array, top_k: int, *,
+                     route=softmax_top_k, experts=swiglu_experts,
+                     held: tuple[int, int] | None = None,
+                     rows: jax.Array | None = None):
+    """The sparse feed-forward part over ``x [T, d]`` (float32, already
+    normed): ``route(x W_r, top_k)`` in float32 over ALL the ``E`` experts
+    of ``params["router"] [d, E]`` gives each token its ``top_k`` experts
+    and their weights; ``experts(params, rows, sizes)`` multiplies the
+    (token, expert) pairs, sorted by expert, by their own expert's
+    matrices; the result is ``sum_e w_e * E_e(row)``. The experts read
+    ``rows [T, K]`` where given (a mixture in a latent space hands its
+    down-projected rows), else ``x``. No capacity: every routed pair of a
+    held expert is computed.
+
+    ``held = (first, n)``: the expert matrices in ``params`` are those of
+    experts ``first .. first + n - 1`` alone (``None``: all ``E``). Routing,
+    the choice and the weights' normaliser are over all ``E`` as they would
+    be anywhere; the sum is over the chosen experts that are held. A pair
+    routed to an absent expert sorts past the held groups, so no tile of it
+    is visited and no weight is read for it; what it would have added is
+    left out.
+
+    Returns ``(y [T, K] float32, sizes [n] int32)``, the second how many
+    rows each HELD expert got."""
+    n_tok = x.shape[0]
+    n_experts = params["router"].shape[1]
+    first, n_held = (0, n_experts) if held is None else held
+    if not 0 <= first <= first + n_held <= n_experts or n_held < 1:
+        raise ValueError(
+            f"dropless_experts: held experts [{first}, {first + n_held}) "
+            f"outside the router's {n_experts}")
+    w, ids = route(matmul_acc32(x, params["router"]), top_k)    # [T, k]
+    flat = ids.reshape(-1)
+    if n_held != n_experts:
+        here = (flat >= first) & (flat < first + n_held)
+        flat = jnp.where(here, flat - first, n_held)
+    order = jnp.argsort(flat, stable=True)                  # pairs by expert
+    sizes = jnp.bincount(flat, length=n_held + (n_held != n_experts))[
+        :n_held].astype(jnp.int32)
+    # operands in the weights' dtype, cast before the gather copies them
+    rows = (x if rows is None else rows).astype(params["router"].dtype)
+    out = experts(params, rows[order // top_k], sizes)      # [k T, K]
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
     out = out[back].reshape(n_tok, top_k, -1)
+    if n_held != n_experts:
+        # rows past the last held group were never written
+        out = jnp.where(here.reshape(n_tok, top_k, 1), out, 0.0)
     return jnp.einsum("tk,tkd->td", w, out), sizes

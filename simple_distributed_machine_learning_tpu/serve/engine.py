@@ -88,9 +88,6 @@ from simple_distributed_machine_learning_tpu.telemetry import tracing
 # anything > 1 disables top-p
 _NO_TOP_K = 0
 _NO_TOP_P = 2.0
-# a block tick's counters (``PagedServing.unpack_rows``) where no decode ran
-_NO_BLOCK_STATS = dict.fromkeys(
-    ("forwards", "commits", "experts_hit", "expert_rows_max"), 0)
 
 
 class DrainTimeout(RuntimeError):
@@ -354,8 +351,10 @@ class InferenceEngine:
                     f"holds whole blocks")
         self._block_forwards = serving.block_forwards
         self._unpack_block = serving.unpack_rows
-        # what the last block tick's program counted (engine.tick's attrs)
-        self._block_stats = _NO_BLOCK_STATS
+        # what a decode run counts of itself (PagedServing.counters): the
+        # names, and the last run's counts (engine.tick's attrs)
+        self._counter_names = tuple(serving.counters)
+        self._counted = dict.fromkeys(self._counter_names, 0)
         self.pool = PagedKVPool(self._n_layers, n_slots, serving.kv_heads,
                                 self.max_len, serving.head_dim, cache_dtype,
                                 block_size=block_size, n_blocks=n_blocks,
@@ -783,10 +782,10 @@ class InferenceEngine:
                ahead=ahead, queue=self.scheduler.queue_depth,
                state_slots=self._state_slots(),
                kv_blocks=self.pool.blocks_in_use)
-        if self._block > 1:
+        if self._counter_names:
             # what the tick's decode run counted (0 where it ran none)
-            sp.set(**(self._block_stats if decode_active
-                      else _NO_BLOCK_STATS))
+            sp.set(**(self._counted if decode_active
+                      else dict.fromkeys(self._counter_names, 0)))
         return emitted
 
     def _state_slots(self) -> int:
@@ -1473,10 +1472,23 @@ class InferenceEngine:
             top_ps[s] = r.top_p if r.top_p is not None else _NO_TOP_P
         return kd, temps, top_ks, top_ps
 
+    def _take_counters(self, rows: np.ndarray) -> np.ndarray:
+        """A decode's tokens as read back, the program's counters
+        (``PagedServing.counters``: the last columns, every row alike)
+        taken off and kept for the tick's span."""
+        n = len(self._counter_names)
+        if not n:
+            return rows
+        self._counted = dict(zip(self._counter_names,
+                                 rows[0, -n:].tolist()))
+        return rows[:, :-n]
+
     def _emit_decoded(self, active: list[int], toks, kd2) -> int:
         with tracing.span("engine.decode.wait"):
             toks = np.asarray(toks)              # host sync: tick endpoint
             kd2 = np.asarray(kd2)
+        if self._counter_names:
+            toks = self._take_counters(toks)[:, 0]
         with tracing.span("engine.decode.emit"):
             now = self._now = self._clock()
             emitted = 0
@@ -1511,8 +1523,8 @@ class InferenceEngine:
             kd2 = np.asarray(kd2)
         with tracing.span("engine.decode.emit"):
             now = self._now = self._clock()
-            toks, order, committed, self._block_stats = self._unpack_block(
-                rows, B)
+            toks, order, committed = self._unpack_block(
+                self._take_counters(rows), B)
             emitted = 0
             for s in active:
                 r = self.requests[self.pool.occupant(s)]

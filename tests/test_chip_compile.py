@@ -179,6 +179,9 @@ _CELLS = {
                                       "serve-reason-closed"),
     "sdar-30b-a3b.serve-diffuse-closed": (64, 32, 4, 128, 4, 4097,
                                           "serve-diffuse-closed"),
+    # max_len 2,048: 128 blocks a slot, not 64
+    "nemotron3-super-120b-a12b.serve-agent-closed": (
+        96, 32, 1, 128, 2, 12289, "serve-agent-closed"),
 }
 
 
@@ -193,7 +196,7 @@ def test_paged_attention_compiles_at_the_serve_cells_real_shapes(
     fast-memory limits for the double-buffered spans (16 blocks each here)
     are what interpret mode cannot see."""
     slots, heads, k, dh, kvh, n_phys, mix = _CELLS[cell]
-    bs, nb = 16, 64
+    bs, nb = 16, (n_phys - 1) // slots
     shapes = [((slots, heads, k, dh), jnp.float32),
               ((n_phys, bs, kvh * dh), jnp.bfloat16),
               ((n_phys, bs, kvh * dh), jnp.bfloat16),
@@ -203,7 +206,7 @@ def test_paged_attention_compiles_at_the_serve_cells_real_shapes(
         q, kc, vc, t, p, block_size=bs), one_chip, *shapes,
         kernels=["paged_attention"])
     assert re.search(_kernel_pattern(mix, "paged_attention"), line.strip())
-    for other in ("selective_scan", "moe_experts"):
+    for other in ("selective_scan", "moe_experts", "selective_scan_grouped"):
         with open(os.path.join(os.path.dirname(__file__), os.pardir,
                                "bench_cells", "traffic",
                                mix + ".json")) as f:
@@ -397,6 +400,26 @@ def test_selective_scan_compiles_for_v5e(one_chip, mosaic, n, n_tok):
                                          "paged_attention"), line.strip())
 
 
+@pytest.mark.parametrize("n,n_tok", [(96, 1), (1, 256), (3, 7)])
+def test_grouped_selective_scan_compiles_for_v5e(one_chip, mosaic, n, n_tok):
+    """The second recurrence at the published widths (128 heads of 64
+    channels, 8 groups, 128 states) at the shapes
+    ``nemotron3-super-120b-a12b.serve-agent-closed`` runs: the decode tick
+    over 96 slots and the one 256-token chunk; and a ragged walk."""
+    di, groups, n_state, f32 = 8192, 8, 128, jnp.float32
+    wide, narrow = ((n, n_tok, di), f32), ((n, n_tok, groups, n_state), f32)
+    (line,) = _compile(
+        lambda x, dt, b, c, a, d, h0: ss.selective_scan(
+            x, dt, None, b, c, a, d, h0),
+        one_chip, wide, wide, narrow, narrow, ((di,), f32), ((di,), f32),
+        ((n, n_state, di), f32), kernels=["selective_scan_grouped"])
+    assert re.search(_kernel_pattern("serve-agent-closed",
+                                     "selective_scan_grouped"), line.strip())
+    # the hybrid cell's pattern must not count this kernel as its own
+    assert not re.search(_kernel_pattern("serve-reason-closed",
+                                         "selective_scan"), line.strip())
+
+
 # -- grouped expert products: the sparse serve programs' kernel ---------------
 
 
@@ -415,6 +438,22 @@ def test_expert_products_compile_for_v5e(one_chip, mosaic, m, k, n):
                      line.strip())
     assert not re.search(_kernel_pattern("serve-diffuse-closed",
                                          "paged_attention"), line.strip())
+
+
+@pytest.mark.parametrize("m,k,n", [(2112, 1024, 2688), (2112, 2688, 1024),
+                                   (5632, 1024, 2688), (5632, 2688, 1024)])
+def test_latent_expert_products_compile_for_v5e(one_chip, mosaic, m, k, n):
+    """The published widths (latent 1,024, expert width 2,688) over the 128
+    experts held, at the rows ``nemotron3-super-120b-a12b.serve-agent-
+    closed`` runs: 22 pairs a row of a tick's 96 rows or of a 256-token
+    chunk (the pairs of absent experts lie past the last group); the first
+    product's matrix in three blocks of 896 columns, the second's in two."""
+    bf16 = jnp.bfloat16
+    (line,) = _compile(
+        me.grouped_matmul, one_chip, ((m, k), bf16), ((128, k, n), bf16),
+        ((128,), jnp.int32), kernels=["moe_experts"])
+    assert re.search(_kernel_pattern("serve-agent-closed", "moe_experts"),
+                     line.strip())
 
 
 # -- flash attention: the train step's kernel -------------------------------

@@ -276,6 +276,97 @@ def test_grouped_experts_match_the_masked_sum(case):
         assert rows.tolist() == [0, 0, 0, n, 0, n, 0, 0]
 
 
+def _latent_params(key, d=64, lat=32, f=48, e=16, held=(0, 16)):
+    kr, kb, k1, k2 = jax.random.split(key, 4)
+    return {"router": jax.random.normal(kr, (d, e)),
+            "bias": 0.3 * jax.random.normal(kb, (e,)),
+            "w1": 0.2 * jax.random.normal(k1, (e, lat, f))[
+                held[0]:held[0] + held[1]],
+            "w2": 0.2 * jax.random.normal(k2, (e, f, lat))[
+                held[0]:held[0] + held[1]]}
+
+
+def _dense_masked_sum(mp, x, rows, gate, body, held):
+    """``sum over the held e of gate[:, e] * E_e(rows)``, every expert
+    computed for every row."""
+    out = 0.0
+    for j in range(held[1]):
+        if body == "swiglu":
+            y = (jax.nn.silu(rows @ mp["gate"][j]) * (rows @ mp["up"][j])
+                 ) @ mp["down"][j]
+        else:
+            y = jnp.square(jax.nn.relu(rows @ mp["w1"][j])) @ mp["w2"][j]
+        out = out + gate[:, held[0] + j, None] * y
+    return out
+
+
+@pytest.mark.parametrize("held", [(0, 16), (4, 8), (12, 4)])
+@pytest.mark.parametrize("rule,body", [("softmax", "swiglu"),
+                                       ("sigmoid", "relu2"),
+                                       ("sigmoid", "swiglu"),
+                                       ("softmax", "relu2")])
+def test_each_routing_rule_and_expert_body_match_a_dense_masked_sum(
+        rule, body, held):
+    """The routing rule and the expert body are arguments: every pairing,
+    over all the experts and over a range held, against a dense sum in
+    which every held expert multiplies every row and a gate (the rule's
+    weight where chosen, 0 elsewhere) masks it. The normaliser is over all
+    the chosen, held or not."""
+    from bench_cells.reference import nemotron_h as latent_ref
+    k = 5
+    x = jax.random.normal(jax.random.key(2), (37, 64))
+    if body == "swiglu":
+        mp = _moe_params(jax.random.key(0), e=16)
+        mp = dict(mp, **{w: mp[w][held[0]:held[0] + held[1]]
+                         for w in ("gate", "up", "down")})
+        rows, experts = None, moe_experts.swiglu_experts
+    else:
+        mp = _latent_params(jax.random.key(0), held=held)
+        rows = jax.random.normal(jax.random.key(3), (37, 32))
+        experts = moe_experts.relu2_experts
+    bias = 0.3 * jax.random.normal(jax.random.key(4), (16,))
+    scores = x @ mp["router"]
+    if rule == "softmax":
+        route = moe_experts.softmax_top_k
+        gate = reference.expert_gates(jax.nn.softmax(scores, -1), k)
+    else:
+        route = moe_experts.sigmoid_top_k(bias, 2.5)
+        gate = latent_ref.expert_gates(scores, bias, k, 2.5)
+    got, sizes = moe_experts.dropless_experts(
+        mp, x, k, route=route, experts=experts, held=held, rows=rows)
+    want = _dense_masked_sum(mp, x, x if rows is None else rows, gate, body,
+                             held)
+    np.testing.assert_allclose(got, want, **F32)
+    # rows per HELD expert, and only the pairs that landed on one
+    assert sizes.shape == (held[1],)
+    assert sizes.tolist() == (gate[:, held[0]:held[0] + held[1]] > 0).sum(
+        0).tolist()
+    assert (int(sizes.sum()) == 37 * k) == (held == (0, 16))
+
+
+def test_pairs_of_absent_experts_read_no_weight():
+    """Every token routed to experts that are not held: no group has a row,
+    the kernel visits nothing (matrices of NaN leave no trace), and the
+    result is exactly zero."""
+    mp = _latent_params(jax.random.key(0), held=(8, 8))
+    mp = dict(mp, w1=jnp.full_like(mp["w1"], jnp.nan),
+              w2=jnp.full_like(mp["w2"], jnp.nan))
+    # experts 0 and 1 first for every token: both absent
+    col = jnp.zeros((64, 16)).at[:, 0].set(1.0).at[:, 1].set(0.5)
+    x = jnp.abs(jax.random.normal(jax.random.key(1), (16, 64)))
+    got, sizes = moe_experts.dropless_experts(
+        dict(mp, router=col), x, 2,
+        route=moe_experts.sigmoid_top_k(jnp.zeros(16), 2.5),
+        experts=moe_experts.relu2_experts, held=(8, 8),
+        rows=jnp.ones((16, 32)))
+    assert sizes.tolist() == [0] * 8
+    assert np.array_equal(np.asarray(got), np.zeros((16, 32), np.float32))
+    _, _, _, n = moe_experts._visits(sizes, 128, 128)
+    assert int(n[0]) == 0
+    with pytest.raises(ValueError, match="held experts"):
+        moe_experts.dropless_experts(mp, x, 2, held=(12, 8))
+
+
 @pytest.mark.parametrize("sizes", [[0, 16, 0, 0], [4, 4, 4, 4],
                                    [100, 0, 50, 150], [1, 0, 0, 4],
                                    [0, 0, 0, 130]])

@@ -378,19 +378,34 @@ def test_degraded_factory_drops_speed_features_and_keeps_the_pool():
         True).pool.cache_dtype == jax.numpy.bfloat16
 
 
-def test_degraded_rebuild_serves_a_model_with_recurrent_state(tmp_path):
-    """A supervised hybrid (state-space + attention) deployment with
+def _hybrid(family):
+    if family == "jamba":
+        from simple_distributed_machine_learning_tpu.models.jamba import (
+            JambaConfig,
+            make_jamba_stages,
+        )
+        cfg = JambaConfig(vocab=97, seq_len=48, d_model=64, n_heads=4,
+                          n_kv_heads=1, d_ff=128, n_layers=4, attn_period=2,
+                          attn_offset=1, expand=4, dt_rank=8)
+        return cfg, make_jamba_stages(jax.random.key(0), cfg)[0]
+    from simple_distributed_machine_learning_tpu.models.nemotron_h import (
+        NemotronHConfig,
+        make_nemotron_h_stages,
+    )
+    cfg = NemotronHConfig(vocab=97, seq_len=48, pattern="ME*E",
+                          experts_held=4, expert_offset=2)
+    return cfg, make_nemotron_h_stages(jax.random.key(0), cfg)[0]
+
+
+@pytest.mark.parametrize("family", ["jamba", "nemotron_h"])
+def test_degraded_rebuild_serves_a_model_with_recurrent_state(tmp_path,
+                                                              family):
+    """A supervised hybrid (state-space + attention; and the family whose
+    layers are one part each, with a share of its experts) deployment with
     ``degrade_after`` set: the degraded rebuild constructs (the fallback
     keeps the paged pool, where recurrent state lives) and every request
     finishes bit-exact with the uncrashed run."""
-    from simple_distributed_machine_learning_tpu.models.jamba import (
-        JambaConfig,
-        make_jamba_stages,
-    )
-    cfg = JambaConfig(vocab=97, seq_len=48, d_model=64, n_heads=4,
-                      n_kv_heads=1, d_ff=128, n_layers=4, attn_period=2,
-                      attn_offset=1, expand=4, dt_rank=8)
-    stages = make_jamba_stages(jax.random.key(0), cfg)[0]
+    cfg, stages = _hybrid(family)
     assert cfg.recurrent_state
 
     def run(name, chaos):
