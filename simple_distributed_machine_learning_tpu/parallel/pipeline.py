@@ -14,7 +14,9 @@ into ONE ``jit``-ed SPMD program:
   per-step time = t(stage0) + 2·t(transfer) + t(stage1), SURVEY §3.3);
 - backward needs no distributed-autograd engine: ``jax.grad`` through
   ``ppermute`` emits the transposed permute, so activation cotangents hop
-  stage ``s+1`` → ``s`` inside the same compiled program;
+  stage ``s+1`` → ``s`` inside the same compiled program; each data shard
+  accumulates its own float32 parameter-row cotangent through the reversed
+  scan, and the row crosses the ``data`` axis once a step, after it;
 - heterogeneous stages (conv front / fc back, as in the reference's
   Network1/Network2 split ``:26-83``) are dispatched with ``lax.switch`` on
   the device's stage index, over the packed stage-sharded parameter buffer
@@ -481,8 +483,14 @@ class Pipeline:
         def per_device(row4d, x_mb, tgt_mb, w_mb, key):
             # row4d: [1, 1, 1, P] this device's (stage, model-shard,
             # expert-shard) param row; x_mb: [M, mb, wire]; tgt_mb/w_mb:
-            # [M, mb(...)] targets and weights
-            row = row4d[0, 0, 0]
+            # [M, mb(...)] targets and weights. The row, replicated over
+            # data, is typed data-varying HERE, once: the transpose of this
+            # cast is the ONE psum over data its gradient needs, on the f32
+            # row cotangent each shard accumulates through the reversed
+            # scan. Left invariant it is cast where a leaf meets an
+            # activation, inside switch and scan, and the transpose
+            # all-reduces every leaf's cotangent at every scan step.
+            row = _pvary_to(row4d[0, 0, 0], (DATA_AXIS,))
             stage = lax.axis_index(STAGE_AXIS)
             mb = x_mb.shape[1]
 
@@ -541,12 +549,12 @@ class Pipeline:
                     # and jax's cond transpose rejects the switch
                     # ("mismatched varying manual axes"). Adding 0*sum(wire)
                     # is value-free but makes every branch's wire cotangent
-                    # at least vary_axes-typed. The anchor sums BOTH the
-                    # wire and the closed-over param row: closure captures
-                    # are hoisted into cond operands, so the row's cotangent
-                    # type needs the same pinning.
+                    # at least vary_axes-typed. The closed-over param row
+                    # needs no such pin (its type is fixed outside the scan),
+                    # and 0*sum(row) would be a pass over the row forward and
+                    # a row-wide add of zeros backward, at every scan step.
                     anchor = _pvary_to(
-                        jnp.float32(0.0) * (jnp.sum(wire) + jnp.sum(row)),
+                        jnp.float32(0.0) * jnp.sum(wire),
                         vary_axes)
                     return (_pvary_to(out, vary_axes) + anchor,
                             _pvary_to(aux, vary_axes) + anchor,
@@ -623,8 +631,10 @@ class Pipeline:
                 return (wire, num_acc, den_acc, aux_acc, logits_acc), None
 
             # the init carry is device-uniform but the loop body makes it
-            # vary over every mesh axis (params vary over stage/model/expert,
-            # data over data, seq-sharded tokens over seq); pcast aligns the
+            # vary over every mesh axis (the row over data/stage/model/
+            # expert, inputs over data, seq-sharded tokens over seq), the
+            # row's cotangent included: it is reduced over data after the
+            # reversed scan, not in it. pcast aligns the
             # carry types for check_vma. The scalar accumulators ride as
             # shape-(1,) arrays: scan-resident rank-0 carries trip the
             # scalar-residual promotion of older jax's shard_map partial

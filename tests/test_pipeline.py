@@ -167,6 +167,42 @@ def test_data_parallel_matches_single_data_rank():
                                rtol=5e-5, atol=5e-5)
 
 
+@pytest.mark.parametrize("seed,parent_gap", [(0, 3.278836e-02),
+                                             (1, 5.882350e-02)])
+def test_data_axis_adds_the_row_gradient_in_f32(seed, parent_gap):
+    """bf16 compute, 2 stages: 2 data shards x 4 microbatches are the same
+    eight 4-sample groups as 1 shard x 8 microbatches, and every group's
+    bf16 leaf cotangents are the same numbers. The row is data-varying
+    before the scan, so each shard adds its four in f32 and the two shards'
+    rows are added in f32: the gradient is the unsharded engine's to f32
+    summation order. While the row was data-invariant each leaf cotangent
+    was added over the shards and ROUNDED to bf16 at every scan step: that
+    engine was 1.5e-3 to 1.9e-3 of the gradient's norm away from the
+    unsharded one, and ``parent_gap`` (commit 3c4690d, this CPU backend)
+    from the fused one-device f32 gradient of the same batch."""
+    dims, batch = [32, 128, 128, 64, 10], 32
+    stages, wire_dim, out_dim, x, targets = _make_problem(
+        jax.random.key(seed), dims, 2, batch)
+    want = jax.grad(lambda ps: _fused_loss(stages, ps, x, targets))(
+        [s.params for s in stages])
+    want = np.asarray(pack_stage_params(want)[0])
+
+    got = {}
+    for n_data, n_micro in ((1, 8), (2, 4)):
+        pipe = Pipeline(stages, make_mesh(n_stages=2, n_data=n_data),
+                        wire_dim, out_dim, n_microbatches=n_micro,
+                        remat=True, compute_dtype=jnp.bfloat16)
+        _, grads = jax.jit(lambda b, p=pipe: p.loss_and_grads(
+            b, x, targets, jax.random.key(3), deterministic=True))(
+                pipe.init_params())
+        got[n_data] = np.asarray(grads)[:, 0, 0]
+    norm = np.linalg.norm(want)
+    assert np.linalg.norm(got[2] - got[1]) / norm < 1e-6
+    gap = {n: np.linalg.norm(g - want) / norm for n, g in got.items()}
+    assert gap[2] <= gap[1] * (1 + 1e-5)
+    assert gap[2] < parent_gap
+
+
 def test_weighted_loss_masks_padding():
     """Zero-weighted padded rows must not dilute the loss: weighted loss over
     a padded batch == unweighted loss over just the valid prefix."""

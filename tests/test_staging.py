@@ -1,5 +1,6 @@
 """Stage packing and wire codec round-trips."""
 
+import collections
 import re
 
 import jax
@@ -107,30 +108,93 @@ def test_unpack_backward_writes_the_row_once(n_leaves):
     assert _row_wide_eqns(jaxpr, width) == ["concatenate"]
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_gpipe_engine_has_no_row_wide_pad(remat):
-    """The same on the compiled engine: the unpack sits inside the switch
-    branch inside the GPipe scan (under jax.checkpoint with remat), and the
-    reversed scan must not pad a leaf's cotangent to the row's width."""
+def _compiled_mlp_engine(n_data, n_micro, batch, **pipeline_kw):
+    """The 2-stage GPipe engine's ``loss_and_grads`` over small MLP stages
+    of four leaves and more, compiled: ``(step, buf, key, mesh)``."""
     from simple_distributed_machine_learning_tpu import make_mesh
     from simple_distributed_machine_learning_tpu.models import make_mlp_stages
     from simple_distributed_machine_learning_tpu.parallel import Pipeline
 
     stages, wire_dim, out_dim = make_mlp_stages(
         jax.random.key(0), [9, 23, 17, 11, 5], n_stages=2)
-    mesh = make_mesh(n_stages=2, n_data=1)
-    pipe = Pipeline(stages, mesh, wire_dim, out_dim, n_microbatches=2,
-                    remat=remat)
+    mesh = make_mesh(n_stages=2, n_data=n_data)
+    pipe = Pipeline(stages, mesh, wire_dim, out_dim, n_microbatches=n_micro,
+                    **pipeline_kw)
     buf = pipe.init_params()
-    width = buf.shape[-1]
     assert all(len(m.sizes) >= 4 for m in pipe.metas)
-    x = jax.random.normal(jax.random.key(1), (4, 9))
-    y = jax.random.randint(jax.random.key(2), (4,), 0, 5)
+    x = jax.random.normal(jax.random.key(1), (batch, 9))
+    y = jax.random.randint(jax.random.key(2), (batch,), 0, 5)
     key = jax.random.key(3)
     step = jax.jit(lambda b, k: pipe.loss_and_grads(b, x, y, k)).lower(
         buf, key).compile()
+    return step, buf, key, mesh
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_gpipe_engine_has_no_row_wide_pad(remat):
+    """The same on the compiled engine: the unpack sits inside the switch
+    branch inside the GPipe scan (under jax.checkpoint with remat), and the
+    reversed scan must not pad a leaf's cotangent to the row's width."""
+    step, buf, key, _ = _compiled_mlp_engine(1, 2, 4, remat=remat)
+    width = buf.shape[-1]
     pads = re.findall(
         rf"^.*= f32\[(?:1,1,1,)?{width}\]\S* pad\(.*$", step.as_text(), re.M)
     assert not pads, pads[:3]
+    loss, grads = step(buf, key)
+    assert np.isfinite(float(loss)) and grads.shape == buf.shape
+
+
+AllReduce = collections.namedtuple(
+    "AllReduce", "computation in_entry shapes groups")
+
+
+def _all_reduces(hlo_text):
+    """Every ``all-reduce`` of a compiled module's text: the computation it
+    sits in, whether that is the entry, its result shapes as written, and
+    its replica groups as sorted tuples."""
+    found, comp, entry = [], None, False
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp, entry = head.group(2), bool(head.group(1))
+            continue
+        op = re.match(r"^\s*(?:ROOT )?\S+ = (.*?) all-reduce(?:-start)?\(", line)
+        if not op:
+            continue
+        groups = re.search(r"replica_groups=\{(\{[\d,{} ]*\})\}", line)
+        assert groups, line      # an iota-form group list would need expanding
+        found.append(AllReduce(comp, entry, op.group(1), sorted(
+            tuple(int(i) for i in g.split(","))
+            for g in re.findall(r"\{([\d, ]+)\}", groups.group(1)))))
+    return found
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_gpipe_engine_reduces_the_row_over_data_once(remat):
+    """On the compiled 2-stage x 2-data engine (bf16 compute) the parameter
+    row is typed data-varying before the scan, so the one reduction its
+    gradient needs over the data axis is ONE all-reduce of the accumulated
+    f32 row after the reversed scan. An invariant row met data-varying
+    activations inside the switch inside the scan, and the transpose put an
+    all-reduce of every leaf's cotangent into each branch of the backward
+    loop: the whole stage's gradient crossed the data axis once a scan step."""
+    step, buf, key, mesh = _compiled_mlp_engine(
+        2, 4, 16, remat=remat, compute_dtype=jnp.bfloat16)
+    width = buf.shape[-1]
+    # a device's place in the program is its place in the mesh's grid
+    # [data, stage]: the data peers of stage s are s and n_stages + s
+    data_peers = [(0, 2), (1, 3)]
+    assert [[d.id for d in r] for r in mesh.devices[:, :, 0, 0, 0]] == [
+        [0, 1], [2, 3]]
+    over_data = [r for r in _all_reduces(step.as_text())
+                 if r.groups == data_peers]
+    inside = [r for r in over_data if not r.in_entry]
+    assert not inside, inside[:3]
+    row_wide = [r for r in over_data if re.fullmatch(
+        rf"f32\[(?:1,1,1,)?{width}\]\S*", r.shapes)]
+    assert len(row_wide) == 1, over_data
+    # every other reduction over the data axis is a scalar (the loss's)
+    assert all(re.fullmatch(r"\(?f32\[\]\S*(?:, f32\[\]\S*)*\)?", r.shapes)
+               for r in over_data if r not in row_wide), over_data
     loss, grads = step(buf, key)
     assert np.isfinite(float(loss)) and grads.shape == buf.shape
