@@ -108,6 +108,14 @@ class DrainTimeout(RuntimeError):
         self.unfinished = unfinished
 
 
+def _ready(wait, awaited) -> None:
+    """Tell a ``*.wait`` span, as it begins, whether the bytes it reads
+    back were already there (``ready`` 1: the wait is a copy; 0: the host
+    goes to sleep on the device). A disabled recorder asks nothing."""
+    if wait is not tracing.NO_SPAN:
+        wait.set(ready=int(awaited.is_ready()))
+
+
 @functools.cache
 def _host_device():
     """The host's own CPU device, or ``None`` where JAX is held to another
@@ -376,6 +384,10 @@ class InferenceEngine:
         self._seats_newest = serving.ahead
         self._dispatch_ahead = serving.ahead and not self.speculative
         self._ahead = None
+        # program runs launched in the engine's life (decode, chunk,
+        # speculative and block programs alike): a run's number is on its
+        # ``*.dispatch`` span and on the ``*.wait`` span that reads it
+        self._runs = 0
         from simple_distributed_machine_learning_tpu.models.gpt import (
             SEAT_NONE,
             SEAT_SAMPLE,
@@ -714,9 +726,15 @@ class InferenceEngine:
         """
         if not self.busy:
             return 0
-        # the tick by phase (telemetry/tracing.py): every stretch of the
-        # tick lies in one child span, so that host time has a cause
-        with tracing.span("engine.tick", tick=self._tick_count + 1) as sp:
+        # the tick by phase (telemetry/tracing.py): its work lies in child
+        # spans, so that host time has a cause. Left in none, each a walk
+        # over the slots at most: the fault-injection probe, who decodes
+        # now and next (``_decoding_slots``, ``_seats_ahead``) and the
+        # attributes set at the end (``_state_slots``). A stall there is
+        # the tick's own (its ``cpu_ns`` / ``runq_ns`` less its waits'),
+        # between the two children around it
+        with tracing.span("engine.tick", sched=True,
+                          tick=self._tick_count + 1) as sp:
             return self._tick(sp)
 
     def _tick(self, sp) -> int:
@@ -727,6 +745,7 @@ class InferenceEngine:
         # no-op without an installed plan
         maybe_fire("serve.tick", step=self._tick_count)
         self._tick_count += 1
+        runs = self._runs
         with tracing.span("engine.admit") as admit:
             # host-tier upload progress FIRST: blocks completing this
             # tick register before admission probes the prefix registry,
@@ -780,7 +799,7 @@ class InferenceEngine:
                     self.flight.snap(self, self._tick_count, emitted)
         sp.set(chunk=chunk, decoding=decode_active, emitted=emitted,
                ahead=ahead, queue=self.scheduler.queue_depth,
-               state_slots=self._state_slots(),
+               runs=self._runs - runs, state_slots=self._state_slots(),
                kv_blocks=self.pool.blocks_in_use)
         if self._counter_names:
             # what the tick's decode run counted (0 where it ran none)
@@ -1049,14 +1068,18 @@ class InferenceEngine:
                 np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
                 np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
                 *self._bank_args(np.int32(getattr(r, "_adapter_row", 0))))
-        with tracing.span("engine.prefill.dispatch", rid=r.rid):
+        run = self._next_run()
+        with tracing.span("engine.prefill.dispatch", rid=r.rid, run=run,
+                          program="chunk"):
             tok, kd = self._run_paged(self._chunk_prefill,
                                       self._pack_chunk, *args)
-        return r, seq, p0, c, t_start, tok, kd
+        return r, seq, p0, c, t_start, tok, kd, run
 
     def _prefill_finish(self, chunk) -> int:
-        r, seq, p0, c, t_start, tok, kd = chunk
-        with tracing.span("engine.prefill.wait", rid=r.rid):
+        r, seq, p0, c, t_start, tok, kd, run = chunk
+        with tracing.span("engine.prefill.wait", sched=True, rid=r.rid,
+                          run=run) as wait:
+            _ready(wait, tok)
             tok = int(np.asarray(tok))     # host sync: honest chunk timing
         with tracing.span("engine.prefill.emit", rid=r.rid):
             return self._prefill_emit(r, seq, p0, c, t_start, tok, kd)
@@ -1151,6 +1174,10 @@ class InferenceEngine:
         self.pool.seat_block(r.slot, position, 1 + self._block_forwards(
             self._block, r.denoising_steps, self._block - n_open))
 
+    def _next_run(self) -> int:
+        self._runs += 1
+        return self._runs
+
     def _run_paged(self, program, pack, *args):
         """Call one of the model's two paged programs (its host-side
         arguments through the model's ``pack``, where it has one) and take
@@ -1183,16 +1210,16 @@ class InferenceEngine:
         return self._emit_tick(*self._decode_dispatch(
             [(s, int(self.pool.positions[s])) for s in active]))
 
-    def _emit_tick(self, active: list[int], out, kd2) -> int:
-        """Read one decode back and account it: a token a slot, or (block
-        steps) a forward a slot."""
+    def _emit_tick(self, active: list[int], out, kd2, run: int) -> int:
+        """Read one decode back (run ``run``) and account it: a token a
+        slot, or (block steps) a forward a slot."""
         emit = self._emit_block if self._block > 1 else self._emit_decoded
-        return emit(active, out, kd2)
+        return emit(active, out, kd2, run)
 
     def _decode_dispatch(self, seats: list[tuple[int, int]]):
         """Launch one decode over ``seats``, ``(slot, position)`` of every
-        slot that takes part: ``(slots, tokens, key_data)`` as
-        :meth:`_emit_decoded` takes them, the last two still on the
+        slot that takes part: ``(slots, tokens, key_data, run)`` as
+        :meth:`_emit_decoded` takes them, tokens and keys still on the
         device."""
         S = self.pool.n_slots
         active = [s for s, _ in seats]
@@ -1228,11 +1255,13 @@ class InferenceEngine:
                     steps[s] = self.requests[
                         self.pool.occupant(s)].denoising_steps
                 live += (steps,)
-        with tracing.span("engine.decode.dispatch"):
+        run = self._next_run()
+        with tracing.span("engine.decode.dispatch", run=run,
+                          program="decode"):
             toks2, kd2 = self._run_paged(
                 self._decode, self._pack_decode, toks, pos, tables, *live,
                 kd, temps, top_ks, top_ps, *bank_args)
-        return active, toks2, kd2
+        return active, toks2, kd2, run
 
     def _tick_ahead(self) -> tuple[int, int]:
         """The paged tick of a model whose programs keep the newest tokens
@@ -1257,11 +1286,11 @@ class InferenceEngine:
             # a request preempted or cancelled since the dispatch has left
             # its slot: its token is dropped (a resumed one is sampled
             # again from the key the host kept)
-            rids, (slots, toks, kd) = ahead
+            rids, (slots, toks, kd, run) = ahead
             held = [s for s, rid in zip(slots, rids)
                     if self.pool.occupant(s) == rid
                     and self.requests[rid].prefill_pos is None]
-            dec = (held, toks, kd) if held else None
+            dec = (held, toks, kd, run) if held else None
         chunk = self._prefill_dispatch()
         seats = self._seats_ahead(dec[0] if dec else (), chunk)
         if seats:
@@ -1381,7 +1410,9 @@ class InferenceEngine:
             # base model (a wrong proposal costs acceptance rate, never
             # correctness — the adapted verify rows decide every emission)
             bank_args = self._bank_args(self._adapter_inputs(active))
-        with tracing.span("engine.decode.dispatch"):
+        run = self._next_run()
+        with tracing.span("engine.decode.dispatch", run=run,
+                          program="decode"):
             if self._spec_fused is not None:
                 dkc, dvc, kc, vc, otoks, nacc, kd2, dkd2 = self._spec_fused(
                     self._draft_params, self._dkc, self._dvc, self.params,
@@ -1401,16 +1432,18 @@ class InferenceEngine:
                     top_ps, *bank_args)
             self._dkc, self._dvc = dkc, dvc
             self.pool.kc, self.pool.vc = kc, vc
-        return self._emit_spec(active, otoks, nacc, kd2, dkd2, valid)
+        return self._emit_spec(active, otoks, nacc, kd2, dkd2, valid, run)
 
     def _emit_spec(self, active: list[int], otoks, nacc, kd2, dkd2,
-                   valid) -> int:
+                   valid, run: int) -> int:
         """Host-side tail of a speculative tick: emit each slot's accepted
         tokens in order (truncating at EOS — later positions' K/V is
         already written but gets overwritten before it can be attended),
         advance positions by the count actually emitted, and feed the
         proposed/accepted counters."""
-        with tracing.span("engine.decode.wait"):
+        with tracing.span("engine.decode.wait", sched=True,
+                          run=run) as wait:
+            _ready(wait, otoks)
             otoks = np.asarray(otoks)            # host sync: tick endpoint
             nacc = np.asarray(nacc)
             kd2 = np.asarray(kd2)
@@ -1483,8 +1516,10 @@ class InferenceEngine:
                                  rows[0, -n:].tolist()))
         return rows[:, :-n]
 
-    def _emit_decoded(self, active: list[int], toks, kd2) -> int:
-        with tracing.span("engine.decode.wait"):
+    def _emit_decoded(self, active: list[int], toks, kd2, run: int) -> int:
+        with tracing.span("engine.decode.wait", sched=True,
+                          run=run) as wait:
+            _ready(wait, toks)
             toks = np.asarray(toks)              # host sync: tick endpoint
             kd2 = np.asarray(kd2)
         if self._counter_names:
@@ -1511,14 +1546,16 @@ class InferenceEngine:
                     self.pool.advance(s, tok)
         return emitted
 
-    def _emit_block(self, active: list[int], rows, kd2) -> int:
+    def _emit_block(self, active: list[int], rows, kd2, run: int) -> int:
         """:meth:`_emit_decoded` for block steps: a slot whose forward
         denoised counts it; one whose forward committed emits its block's
         tokens in order (past the prompt's remainder, cut at
         ``max_new_tokens``, ended by ``eos_id``), all stamped now, and
         moves on by a block."""
         B = self._block
-        with tracing.span("engine.decode.wait"):
+        with tracing.span("engine.decode.wait", sched=True,
+                          run=run) as wait:
+            _ready(wait, rows)
             rows = np.asarray(rows)              # host sync: tick endpoint
             kd2 = np.asarray(kd2)
         with tracing.span("engine.decode.emit"):

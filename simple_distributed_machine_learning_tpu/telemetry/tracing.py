@@ -17,6 +17,33 @@ same spans lie on its host plane beside the device lines. ``tracer.enabled =
 False`` is the operator's switch: :func:`span` then returns one shared no-op
 object, one attribute test a site.
 
+``span(name, sched=True, ...)`` also records what the kernel's scheduler did
+to the calling thread in the span, as up to four attributes read as it
+opens and as it closes (a span so opened takes 8 us against a plain one's
+2.5 on a plain Linux host, 17 against 3.2 where a reading is a sandboxed
+system call of 6 us: ``PERF.md`` section 6, PR 37; that time is the span's
+own where it has a parent, whose children then still cover it, and lies
+just outside its two stamps where it has none):
+``cpu_ns`` (``time.thread_time_ns()``: CPU time of this thread), ``nvcsw`` /
+``nivcsw`` (voluntary / involuntary context switches,
+``getrusage(RUSAGE_THREAD)``) and ``runq_ns`` (nanoseconds the thread was
+runnable and waited for a CPU: the second field of
+``/proc/thread-self/schedstat``, through one descriptor a thread kept open).
+A reading this kernel does not give is left out and never asked for again:
+``runq_ns`` where the file cannot be opened, the two switch counts where a
+sleep does not move them (asked once a process, 1 ms; a sandboxed kernel
+counts none). ``cpu_ns`` is as fine as the kernel's clock: a span without
+a parent may read its length and a reading's own time more, and where the
+clock moves in steps of 10 ms it tells only a long span busy from asleep.
+A long span then says of itself which it was: asleep and never woken
+(``cpu_ns`` near 0, one voluntary switch, ``runq_ns`` near 0: the
+runtime's or the device's),
+runnable without a CPU (``runq_ns`` near its length, or ``nivcsw`` > 0: the
+machine's, a CPU quota for one), or busy (``cpu_ns`` near its length: the
+program's own work). They are exported with the other attributes as the
+event's ``args`` in ``trace.json``. The serve engine opens ``engine.tick``
+and its ``*.wait`` spans so; a disabled recorder reads none of it.
+
 Two kinds of stall nobody called for are recorded where they happen, once a
 process: JAX's trace, lower and backend-compile events (``jax.trace``,
 ``jax.lower``, ``jax.compile``, from its monitoring listener) and the
@@ -43,6 +70,7 @@ import gc
 import itertools
 import json
 import os
+import resource
 import threading
 import time
 
@@ -60,7 +88,10 @@ _JAX_EVENTS = {
 }
 
 _ids = itertools.count(1)
-_open = threading.local()      # .stack: the ids of this thread's open spans
+# .stack: the ids of this thread's open spans; .schedstat: its open
+# /proc/thread-self/schedstat (None where that cannot be opened)
+_open = threading.local()
+SCHEDSTAT = "/proc/thread-self/schedstat"
 
 
 def _stack() -> list[int]:
@@ -107,6 +138,9 @@ class Span:
 
     def __exit__(self, *exc) -> None:
         self.end_ns = time.perf_counter_ns()
+        self._close(exc)
+
+    def _close(self, exc) -> None:
         self._annotation.__exit__(*exc)
         self._annotation = None
         _stack().pop()
@@ -115,6 +149,74 @@ class Span:
     @property
     def seconds(self) -> float:
         return (self.end_ns - self.start_ns) * 1e-9
+
+
+_SCHED = ("cpu_ns", "nvcsw", "nivcsw", "runq_ns")
+_rusage_counts = None     # does getrusage count this kernel's switches?
+
+
+def _rusage_live() -> bool:
+    """Whether ``getrusage(RUSAGE_THREAD)`` counts context switches here:
+    asked once a process, across one sleep of 1 ms."""
+    global _rusage_counts
+    if _rusage_counts is None:
+        before = resource.getrusage(resource.RUSAGE_THREAD).ru_nvcsw
+        time.sleep(0.001)
+        after = resource.getrusage(resource.RUSAGE_THREAD).ru_nvcsw
+        _rusage_counts = after > before
+    return _rusage_counts
+
+
+def _sched_now() -> tuple:
+    """The calling thread's readings in the order of ``_SCHED``: its CPU
+    time (ns), its voluntary and involuntary context switches, its wait
+    for a CPU (ns); ``None`` for one this kernel does not give."""
+    try:
+        stat = _open.schedstat
+    except AttributeError:
+        # the link resolves to the opening thread's file: one a thread
+        try:
+            stat = open(SCHEDSTAT, "rb", buffering=0)
+        except OSError:
+            stat = None
+        _open.schedstat = stat
+    nv = niv = None
+    if _rusage_live():
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        nv, niv = ru.ru_nvcsw, ru.ru_nivcsw
+    runq = (None if stat is None
+            else int(os.pread(stat.fileno(), 64, 0).split()[1]))
+    return time.thread_time_ns(), nv, niv, runq
+
+
+class SchedSpan(Span):
+    """A span that also records what the scheduler did to its thread
+    (``span(name, sched=True)``). What the readings cost has to be some
+    span's time: a span with a parent reads just inside its own two
+    stamps, so that the parent's children still cover the parent; one
+    without reads just outside them, its time being nobody else's."""
+
+    __slots__ = ("_sched",)
+
+    def __enter__(self) -> "SchedSpan":
+        if not _stack():
+            self._sched = _sched_now()
+        super().__enter__()
+        if self.parent is not None:
+            self._sched = _sched_now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.parent is not None:
+            now = _sched_now()
+            self.end_ns = time.perf_counter_ns()
+        else:
+            self.end_ns = time.perf_counter_ns()
+            now = _sched_now()
+        for key, before, after in zip(_SCHED, self._sched, now):
+            if after is not None:
+                self.attrs[key] = after - before
+        self._close(exc)
 
 
 class _NoSpan:
@@ -179,12 +281,13 @@ class Tracer:
 
     # -- spans -------------------------------------------------------------
 
-    def span(self, name: str, **attrs):
+    def span(self, name: str, sched: bool = False, **attrs):
         """``with tracer.span("step", epoch=3) as sp: ...`` — one interval;
-        ``sp.set(...)`` adds attributes before it closes."""
+        ``sp.set(...)`` adds attributes before it closes; ``sched=True``
+        adds what the scheduler did to the thread (:class:`SchedSpan`)."""
         if not self.enabled:
             return NO_SPAN
-        return Span(self, name, attrs)
+        return (SchedSpan if sched else Span)(self, name, attrs)
 
     def _record(self, sp: Span) -> None:
         with self._lock:
@@ -309,16 +412,17 @@ def install(tracer: Tracer) -> Tracer:
     return previous
 
 
-def span(name: str, **attrs):
+def span(name: str, sched: bool = False, **attrs):
     """A span in the process's recorder, or the shared no-op while it is
     disabled."""
-    return _current.span(name, **attrs)
+    return _current.span(name, sched, **attrs)
 
 
 def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
     name = _JAX_EVENTS.get(event)
     duration_ns = int(duration_secs * 1e9)
-    if name is not None and duration_ns >= STALL_FLOOR_NS:
+    if (name is not None and duration_ns >= STALL_FLOOR_NS
+            and _current.enabled):
         end = time.perf_counter_ns()
         _current.record(name, end - duration_ns, end)
 
@@ -329,7 +433,8 @@ _gc_started_ns = 0
 def _on_gc(phase: str, info: dict) -> None:
     global _gc_started_ns
     if phase == "start":
-        _gc_started_ns = time.perf_counter_ns()
+        # a disabled recorder reads no clock, here as in span()
+        _gc_started_ns = time.perf_counter_ns() if _current.enabled else 0
     elif _gc_started_ns:
         end = time.perf_counter_ns()
         if end - _gc_started_ns >= STALL_FLOOR_NS:
