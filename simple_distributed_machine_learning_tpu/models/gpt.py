@@ -1222,6 +1222,35 @@ def _sample_dyn(row: jax.Array, key_data: jax.Array, temperature: jax.Array,
     return tok.astype(jnp.int32), kd
 
 
+def _sample_slots(rows: jax.Array, key_data: jax.Array, temps: jax.Array,
+                  top_ks: jax.Array, top_ps: jax.Array
+                  ) -> tuple[jax.Array, jax.Array]:
+    """:func:`_sample_dyn` over the rows ``[S, V]`` with each row's own
+    ``key_data [S, 2]`` and params ``[S]`` -> ``(tokens int32 [S],
+    key_data [S, 2])`` — unless every row is greedy: ``_sample_dyn`` sorts
+    each row twice for its top-k / top-p filters whatever the temperature,
+    and over a vocabulary those sorts cost as much as the rest of a decode
+    tick. A greedy row's result is the same either way (its ``argmax``, its
+    key unchanged), so one sampled row takes ``vmap`` of ``_sample_dyn``
+    for every row and an all-greedy batch the ``argmax`` alone. The ONE
+    row-batch sampler of the serve programs: GPT's below, the hybrid's
+    (``models/jamba.py``) and the pattern family's
+    (``models/nemotron_h.py``) import it; ``models/sdar.py::_sample_block``
+    keeps a ``cond`` of its own, whose sampled branch folds the position
+    into the key."""
+    return jax.lax.cond(
+        jnp.any(temps > 0),
+        lambda: jax.vmap(_sample_dyn)(rows, key_data, temps, top_ks, top_ps),
+        lambda: (jnp.argmax(rows, axis=-1).astype(jnp.int32), key_data))
+
+
+def _sample_slot(row, key_data, temperature, top_k, top_p):
+    """:func:`_sample_slots` for ONE row ``[V]`` with scalar params."""
+    tok, kd = _sample_slots(row[None], key_data[None], temperature[None],
+                            top_k[None], top_p[None])
+    return tok[0], kd[0]
+
+
 def _check_sampling_args(temperature, top_k, top_p, vocab=None):
     if (top_k is not None or top_p is not None) and temperature <= 0.0:
         raise ValueError("top_k/top_p filtering needs temperature > 0 "
@@ -1481,7 +1510,7 @@ def _build_slot_prefill(H):
                 vc, v.astype(vc.dtype)[None], (li, slot, 0, 0, 0))
             h = _dense_attn_tail(bp, h, causal_attention_core(q, k_, v))
         row = _head_logprobs(head, h[:, -1])[0]           # [V]
-        tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
+        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
         return kc, vc, tok, kd
 
     return prefill
@@ -1627,7 +1656,7 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     another request prefilled) and the chunk's own freshly written rows.
     The engine interleaves these chunks with decode ticks so a long prompt
     never stalls in-flight requests; the last chunk's final position feeds
-    the head and samples the request's first token (:func:`_sample_dyn` —
+    the head and samples the request's first token (:func:`_sample_slot` —
     the engine discards the sampled token and key for non-final chunks, so
     the request's key stream advances exactly once, at the same point as
     its solo decode).
@@ -1715,7 +1744,7 @@ def _build_paged_prefill_chunk(H, bs, dh, adapters=False):
         kc, vc, row = _paged_chunk_fwd(blocks, embed, head, kc, vc,
                                        tokens, p0, table, H, bs, dh,
                                        _dense_attn_tail, ab_at)
-        tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
+        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
         pair, = state
         return (kc, vc, (_seat_newest(pair, slot, seat, tok, kd, key_data),),
                 tok, kd)
@@ -1752,7 +1781,7 @@ def _build_paged_prefill_chunk_tp(cfg, bs, dh, mesh, adapters=False):
                                        tokens, p0, table, H_loc, bs, dh,
                                        tail, ab_at)
         row = _close_rows(row)
-        tok, kd = _sample_dyn(row, key_data, temperature, top_k, top_p)
+        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
         pair, = state
         return (kc, vc, (_seat_newest(pair, slot, seat, tok, kd, key_data),),
                 tok, kd)
@@ -1798,8 +1827,9 @@ def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
     K/V via a per-slot scatter into physical block ``tables[s, pos // bs]``
     at offset ``pos % bs``, attends the row assembled from its block table
     (:func:`_paged_gather`) masked to ``<= pos``, and samples with its own
-    params and key stream (``vmap`` of :func:`_sample_dyn` — loop
-    semantics, per-slot draws equal the unbatched calls). Values for live
+    params and key stream (:func:`_sample_slots`: ``vmap`` of
+    :func:`_sample_dyn` — loop semantics, per-slot draws equal the
+    unbatched calls — or, every slot greedy, the ``argmax``). Values for live
     positions are the cached decoder's (same numbers, different storage)
     and the mask removes everything else: the bit-exactness anchor
     continuous batching rests on.
@@ -1886,8 +1916,7 @@ def _build_paged_decode_step(H, bs, dh, kernel="dense", adapters=False):
         kc, vc, rows = _paged_decode_fwd(blocks, embed, head, kc, vc, toks,
                                          pos, tables, H, bs, dh,
                                          _dense_attn_tail, kernel, ab_at)
-        toks2, kd2 = jax.vmap(_sample_dyn)(rows, key_data, temps,
-                                           top_ks, top_ps)
+        toks2, kd2 = _sample_slots(rows, key_data, temps, top_ks, top_ps)
         return kc, vc, (_feed_newest(pair, live, toks2, kd2),), toks2, kd2
 
     if adapters:
@@ -1923,8 +1952,7 @@ def _build_paged_decode_step_tp(cfg, bs, dh, mesh, kernel="dense",
                                          pos, tables, H_loc, bs, dh, tail,
                                          kernel, ab_at)
         rows = _close_rows(rows)
-        toks2, kd2 = jax.vmap(_sample_dyn)(rows, key_data, temps,
-                                           top_ks, top_ps)
+        toks2, kd2 = _sample_slots(rows, key_data, temps, top_ks, top_ps)
         return kc, vc, (_feed_newest(pair, live, toks2, kd2),), toks2, kd2
 
     if adapters:
@@ -2191,8 +2219,7 @@ def _build_slot_propose(H, K, ml):
             p = jnp.minimum(pos + j, ml - 1)
             kc, vc, rows = _slot_decode_fwd(blocks, embed, head, kc, vc,
                                             tok, p, H)
-            nxt, kd = jax.vmap(_sample_dyn)(rows, kd, temps, top_ks,
-                                            top_ps)
+            nxt, kd = _sample_slots(rows, kd, temps, top_ks, top_ps)
             return (kc, vc, nxt, kd), (nxt, rows)
 
         (kc, vc, _, kd2), (drafts, rows) = jax.lax.scan(

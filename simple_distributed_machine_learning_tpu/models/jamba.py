@@ -56,7 +56,8 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     _paged_attend,
     _paged_gather,
     _paged_scatter,
-    _sample_dyn,
+    _sample_slot,
+    _sample_slots,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -427,18 +428,6 @@ def _unpack_chunk(host):
             host[2], f32(6))
 
 
-def _sample(rows, key_data, temps, top_ks, top_ps):
-    """:func:`_sample_dyn` over the rows ``[S, V]`` — unless every row is
-    greedy: ``_sample_dyn`` sorts each row twice for its top-k / top-p
-    filters whatever the temperature, and over 65,536 logits those sorts
-    cost as much as the rest of a decode tick. A greedy row's result is the
-    same either way (its ``argmax``, its key unchanged)."""
-    return jax.lax.cond(
-        jnp.any(temps > 0),
-        lambda: jax.vmap(_sample_dyn)(rows, key_data, temps, top_ks, top_ps),
-        lambda: (jnp.argmax(rows, axis=-1).astype(jnp.int32), key_data))
-
-
 def _slot_pair(ssm, tail, slot, fresh):
     """``slot``'s recurrent pair ``([1, S, Di], [1, d_conv - 1, Di])`` as a
     chunk starts from it: zeros when the chunk is the sequence's first."""
@@ -510,14 +499,13 @@ def _build_hybrid_prefill_chunk(cfg: JambaConfig, bs: int):
          top_p) = _unpack_chunk(host)
         kc, vc, layers, row = _hybrid_chunk_fwd(
             params, kc, vc, tuple(layers), tokens, p0, table, slot, cfg, bs)
-        tok, kd = _sample(row[None], key_data[None], temperature[None],
-                          top_k[None], top_p[None])
+        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
         own = seat == SEAT_SAMPLE
         newest = newest.at[slot].set(jnp.where(
-            seat == SEAT_NONE, newest[slot], jnp.where(own, tok[0], seat)))
+            seat == SEAT_NONE, newest[slot], jnp.where(own, tok, seat)))
         keys = keys.at[slot].set(jnp.where(
-            seat == SEAT_NONE, keys[slot], jnp.where(own, kd[0], key_data)))
-        return kc, vc, (*layers, (newest, keys)), tok[0], kd[0]
+            seat == SEAT_NONE, keys[slot], jnp.where(own, kd, key_data)))
+        return kc, vc, (*layers, (newest, keys)), tok, kd
 
     return chunk_hybrid_prefill
 
@@ -584,7 +572,7 @@ def _build_hybrid_decode_step(cfg: JambaConfig, bs: int, kernel: str):
         kc, vc, layers, rows = _hybrid_decode_fwd(
             params, kc, vc, tuple(layers), toks, pos, tables, live, cfg, bs,
             kernel)
-        toks2, kd2 = _sample(rows, key_data, temps, top_ks, top_ps)
+        toks2, kd2 = _sample_slots(rows, key_data, temps, top_ks, top_ps)
         newest = (jnp.where(live, toks2, toks),
                   jnp.where(live[:, None], kd2, key_data))
         return kc, vc, (*layers, newest), toks2, kd2

@@ -70,12 +70,13 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     _paged_attend,
     _paged_gather,
     _paged_scatter,
+    _sample_slot,
+    _sample_slots,
     _seat_newest,
 )
 from simple_distributed_machine_learning_tpu.models.jamba import (
     _grouped_attention,
     _qkv,
-    _sample,
     _slot_pair,
     _unpack_chunk,
     _unpack_decode,
@@ -452,10 +453,9 @@ def _build_pattern_prefill_chunk(cfg: NemotronHConfig, bs: int):
          top_p) = _unpack_chunk(host)
         kc, vc, layers, row = _pattern_chunk_fwd(
             params, kc, vc, tuple(layers), tokens, p0, table, slot, cfg, bs)
-        tok, kd = _sample(row[None], key_data[None], temperature[None],
-                          top_k[None], top_p[None])
-        newest = _seat_newest(newest, slot, seat, tok[0], kd[0], key_data)
-        return kc, vc, (*layers, newest), tok[0], kd[0]
+        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
+        newest = _seat_newest(newest, slot, seat, tok, kd, key_data)
+        return kc, vc, (*layers, newest), tok, kd
 
     return chunk_pattern_prefill
 
@@ -527,7 +527,7 @@ def _build_pattern_decode_step(cfg: NemotronHConfig, bs: int, kernel: str):
         kc, vc, layers, logits, expert_rows = _pattern_decode_fwd(
             params, kc, vc, tuple(layers), toks, pos, tables, live, cfg, bs,
             kernel)
-        toks2, kd2 = _sample(logits, key_data, temps, top_ks, top_ps)
+        toks2, kd2 = _sample_slots(logits, key_data, temps, top_ks, top_ps)
         counters = jnp.stack([(expert_rows > 0).sum(), expert_rows.sum(),
                               expert_rows.max()]).astype(jnp.int32)
         rows = jnp.concatenate([

@@ -471,7 +471,9 @@ def _sample_block(logits, key_data, temps, top_ks, top_ps):
     log-probability the model gives it: ``(tokens [S, B], logp [S, B],
     next keys [S, 2])``. Greedy rows take the largest and consume no
     randomness; when every slot is greedy the vocabulary-wide sorts of
-    ``_sample_dyn`` are skipped (``models/jamba.py::_sample``)."""
+    ``_sample_dyn`` are skipped (as ``models/gpt.py::_sample_slots``
+    does for the three other families; the sampled branch here folds each
+    position into the key, which that one does not)."""
     blk = logits.shape[1]
     lse = jax.nn.logsumexp(logits, axis=-1)
 

@@ -363,6 +363,10 @@ class InferenceEngine:
         # names, and the last run's counts (engine.tick's attrs)
         self._counter_names = tuple(serving.counters)
         self._counted = dict.fromkeys(self._counter_names, 0)
+        # of the slots of the decode this tick read, those that sample
+        # (temperature > 0): where 0 the programs' sampler took its argmax
+        # branch and sorted nothing (models/gpt.py::_sample_slots)
+        self._sampling = 0
         self.pool = PagedKVPool(self._n_layers, n_slots, serving.kv_heads,
                                 self.max_len, serving.head_dim, cache_dtype,
                                 block_size=block_size, n_blocks=n_blocks,
@@ -800,7 +804,8 @@ class InferenceEngine:
         sp.set(chunk=chunk, decoding=decode_active, emitted=emitted,
                ahead=ahead, queue=self.scheduler.queue_depth,
                runs=self._runs - runs, state_slots=self._state_slots(),
-               kv_blocks=self.pool.blocks_in_use)
+               kv_blocks=self.pool.blocks_in_use,
+               sampling=self._sampling if decode_active else 0)
         if self._counter_names:
             # what the tick's decode run counted (0 where it ran none)
             sp.set(**(self._counted if decode_active
@@ -1210,17 +1215,21 @@ class InferenceEngine:
         return self._emit_tick(*self._decode_dispatch(
             [(s, int(self.pool.positions[s])) for s in active]))
 
-    def _emit_tick(self, active: list[int], out, kd2, run: int) -> int:
-        """Read one decode back (run ``run``) and account it: a token a
-        slot, or (block steps) a forward a slot."""
+    def _emit_tick(self, active: list[int], out, kd2, run: int,
+                   sampling: int) -> int:
+        """Read one decode back (run ``run``, ``sampling`` of whose slots
+        sample) and account it: a token a slot, or (block steps) a forward
+        a slot."""
+        self._sampling = sampling
         emit = self._emit_block if self._block > 1 else self._emit_decoded
         return emit(active, out, kd2, run)
 
     def _decode_dispatch(self, seats: list[tuple[int, int]]):
         """Launch one decode over ``seats``, ``(slot, position)`` of every
-        slot that takes part: ``(slots, tokens, key_data, run)`` as
-        :meth:`_emit_decoded` takes them, tokens and keys still on the
-        device."""
+        slot that takes part: ``(slots, tokens, key_data, run, sampling)``
+        as :meth:`_emit_tick` takes them, tokens and keys still on the
+        device, ``sampling`` the slots among them whose temperature is
+        above 0."""
         S = self.pool.n_slots
         active = [s for s, _ in seats]
         with tracing.span("engine.decode.prepare"):
@@ -1261,7 +1270,7 @@ class InferenceEngine:
             toks2, kd2 = self._run_paged(
                 self._decode, self._pack_decode, toks, pos, tables, *live,
                 kd, temps, top_ks, top_ps, *bank_args)
-        return active, toks2, kd2, run
+        return active, toks2, kd2, run, int(np.count_nonzero(temps > 0))
 
     def _tick_ahead(self) -> tuple[int, int]:
         """The paged tick of a model whose programs keep the newest tokens
@@ -1286,11 +1295,11 @@ class InferenceEngine:
             # a request preempted or cancelled since the dispatch has left
             # its slot: its token is dropped (a resumed one is sampled
             # again from the key the host kept)
-            rids, (slots, toks, kd, run) = ahead
+            rids, (slots, *flight) = ahead
             held = [s for s, rid in zip(slots, rids)
                     if self.pool.occupant(s) == rid
                     and self.requests[rid].prefill_pos is None]
-            dec = (held, toks, kd, run) if held else None
+            dec = (held, *flight) if held else None
         chunk = self._prefill_dispatch()
         seats = self._seats_ahead(dec[0] if dec else (), chunk)
         if seats:
@@ -1388,6 +1397,7 @@ class InferenceEngine:
         S, K = self.pool.n_slots, self.spec_k
         with tracing.span("engine.decode.prepare"):
             kd, temps, top_ks, top_ps = self._sampling_inputs(active)
+            self._sampling = int(np.count_nonzero(temps > 0))
             toks = np.zeros(S, np.int32)
             pos = np.zeros(S, np.int32)
             valid = np.zeros(S, np.int32)

@@ -97,6 +97,14 @@ def _engine(**kw):
         "n_slots": 3, "block_size": BS, "prefill_chunk": 5, **kw})
 
 
+def _tp2_engine():
+    from simple_distributed_machine_learning_tpu.parallel.mesh import (
+        make_mesh,
+    )
+    return _engine(cfg=dataclasses.replace(CFG, n_tensor_parallel=2),
+                   mesh=make_mesh(n_stages=1, n_data=1, n_model=2))
+
+
 def _run(eng, specs, **extra):
     """Drive ``specs`` to the end: ``(handles, tokens emitted per tick,
     ticks that left a decode dispatched for the next one)``."""
@@ -140,11 +148,7 @@ def test_served_tokens_are_the_solo_decoders(build):
             weights[name] = lora.merge_adapter(params, w)
         specs = _mix((None, "t1", "t2"))
     elif build == "tp2":
-        from simple_distributed_machine_learning_tpu.parallel.mesh import (
-            make_mesh,
-        )
-        eng = _engine(cfg=dataclasses.replace(CFG, n_tensor_parallel=2),
-                      mesh=make_mesh(n_stages=1, n_data=1, n_model=2))
+        eng = _tp2_engine()
         assert eng.pool.tp == 2
     elif build == "int8":
         eng = _engine(cache_dtype="int8")
@@ -171,6 +175,36 @@ def test_served_tokens_are_the_solo_decoders(build):
     assert len(ticks) == len(per_tick) and ahead >= 10
     assert all(decoded) and sum(decoded) == ahead
     assert all(t["ahead"] == 0 for t in ticks if not t["decoding"])
+
+
+@pytest.mark.parametrize("build", ["single", "tp2"])
+@pytest.mark.parametrize("kind", ["greedy", "sampled"])
+def test_a_batch_of_one_kind_serves_the_solo_decodes_too(kind, build):
+    """The programs' sampler takes one of two branches by whether any slot
+    samples (``models/gpt.py::_sample_slots``): a run whose every decode is
+    all-greedy (the branch that sorts nothing) and one whose every decode
+    samples in every live slot serve each request's solo decode all the
+    same, and the tick's ``sampling`` says which branch its decode took."""
+    _, params = _model()
+    specs = _mix()
+    for i, s in enumerate(specs):
+        for k in ("temperature", "top_k", "top_p"):
+            s.pop(k, None)
+        if kind == "sampled":
+            s.update(temperature=0.6 + 0.2 * i, top_k=(None, 12)[i % 2],
+                     top_p=(0.9, None, None)[i % 3])
+    eng = _tp2_engine() if build == "tp2" else _engine()
+    mark = len(tracing.current().spans())
+    handles, _, ahead = _run(eng, specs)
+    assert [h.tokens for h in handles] == [_solo(params, s) for s in specs]
+    for h, s in zip(handles, specs):
+        splits = s["max_new_tokens"] if kind == "sampled" else 0
+        assert [int(w) for w in h.key_data] == _key_after(s["seed"], splits)
+    ticks = [sp.attrs for sp in tracing.current().spans()[mark:]
+             if sp.name == "engine.tick"]
+    assert ahead >= 10 and sum(t["decoding"] > 0 for t in ticks) >= 10
+    assert all(t["sampling"] == (t["decoding"] if kind == "sampled" else 0)
+               for t in ticks)
 
 
 def test_next_decode_is_dispatched_before_this_ones_tokens_are_read():

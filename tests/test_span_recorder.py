@@ -464,6 +464,45 @@ def test_one_tick_span_per_busy_step_and_none_for_an_idle_one(stages,
     assert sum(s.attrs["boarded"] for s in by["engine.admit"]) == 4
 
 
+@pytest.mark.parametrize("temps", [(0.0, 0.0, 0.0, 0.0),
+                                   (0.0, 0.9, 0.0, 1.2),
+                                   (0.7, 0.9, 1.1, 0.5)],
+                         ids=["none", "some", "all"])
+@pytest.mark.parametrize("tick", ["ahead", "speculative"])
+def test_tick_counts_the_decoding_slots_that_sample(stages, tracer, tick,
+                                                    temps):
+    """``sampling``: of the slots of the tick's decode, those whose
+    temperature is above 0 (where it is 0 the programs' sampler sorted
+    nothing, ``models/gpt.py::_sample_slots``); witnessed from outside by
+    which requests got a token past their first in the tick."""
+    kw = {}
+    if tick == "speculative":
+        draft_cfg = dataclasses.replace(CFG, n_layers=1)
+        kw = dict(draft_cfg=draft_cfg, spec_k=3, draft_stages=make_gpt_stages(
+            jax.random.key(9), draft_cfg, 1)[0])
+    eng = _engine(stages, **kw)
+    assert eng._dispatch_ahead == (tick == "ahead")
+    did, decoded = [], []
+    for i, t in enumerate(temps):
+        eng.submit(_prompt(6 + 3 * i, i), 5, temperature=t, seed=i,
+                   on_token=lambda r, _t: len(r.tokens) > 1 and decoded.append(
+                       (len(did), r.rid, r.temperature > 0)))
+    while eng.busy:
+        did.append(eng.step())
+    ticks = _by_name(tracer)["engine.tick"]
+    assert len(ticks) == len(did)
+    for n, t in enumerate(ticks):
+        slots = {(rid, hot) for k, rid, hot in decoded if k == n}
+        assert t.attrs["decoding"] == len(slots)
+        assert t.attrs["sampling"] == sum(hot for _, hot in slots)
+    seen = {t.attrs["sampling"] for t in ticks if t.attrs["decoding"]}
+    hot = sum(t > 0 for t in temps)
+    assert {0: seen == {0}, 2: 1 in seen and max(seen) <= 2,
+            4: 0 not in seen}[hot]
+    assert all(t.attrs["sampling"] == 0 for t in ticks
+               if not t.attrs["decoding"])
+
+
 def test_children_cover_the_tick(stages, tracer):
     """At least 95 % of a tick lies inside its children. A client's callback
     that takes 2 ms (it runs inside the ``*.emit`` spans) makes a CPU tick
