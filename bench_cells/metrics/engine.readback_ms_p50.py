@@ -1,7 +1,11 @@
 """Median over the traced runs that were waited for of the wait's end less
 the later of the wait's start and the run's end on the device: how long the
 tokens take from the device to the host thread once both are ready for
-them. The largest, with its run, tick and ``ready``, is printed on stderr."""
+them. The largest, with its run, tick and ``ready``, is printed on stderr.
+The reading carries in full however far the device's stamps lie before the
+host's clock in this trace (1.0-1.2 ms, 2.0 in a machine's first traced
+process: ``PERF.md``, Open question 15); the ``host`` reader's stderr line
+says how far."""
 
 import sys
 

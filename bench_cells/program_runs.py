@@ -10,14 +10,34 @@ tokens carries the same ``run``. A run whose tokens were dropped (its slots
 preempted or cancelled since the dispatch) has a dispatch and no wait.
 
 The device's half is device 0's ``XLA Modules`` line: the k-th traced run of
-a program is the k-th dispatch of that program, counted from the last one
-that began no later than the first traced run did. Everything is put on the
-trace's clock by ``program_spans.align``; how far a single traced tick's own
-offset lies from that median is the join's error, and the slack every
-comparison of the two clocks is given. A program whose spans carry no
-``run`` (one older than the numbering) gives every reader nothing; a wait
-that names no earlier dispatch of its program, or a trace that cannot be
-paired, is an error like a kernel that is not found.
+a program is the (``at`` + k)-th dispatch of that program, and ``at``, the
+shift, is the unknown. Two orders hold for the right shift and for no other:
+a run starts on the device after its dispatch span began, and ends before
+the ``*.wait`` that read it ended. The device plane's stamps do not sit on
+the host's clock to the millisecond (``program_spans.align`` aligns HOST
+spans; the device's lay 1-2 ms early in the traces caught), so the right
+shift may break an order by that skew on the few pairs whose run started
+or was read at once, while a wrong shift breaks one of them on EVERY pair
+by the better part of a program run's length. So every shift that fits
+(``0 <= at``, ``at + traced <= dispatches``) is scored by the seconds its
+pairs break the two orders, summed (a run nobody waited for binds on its
+start alone), and the least is taken where it is clearly least: under
+``CLEAR`` (a tenth) of the runner-up's, or, where one shift alone fits, of
+the time the paired runs took on the device, which is the size of what a
+wrong shift breaks. No single device stamp against a single host stamp
+decides anything.
+
+Where no shift is clearly least, where the trace holds more runs than the
+spans dispatches, where more than ``CUT`` dispatches of the stretch never
+ran, or where the device's waits do not add up to its idle, the trace is
+UNPAIRED: one line on stderr that starts ``bench_cells: unpaired:`` says
+why, :func:`join` gives ``None`` as for an untraced run, and the three
+readers of the device's half report nothing (``engine.tick_max_wait_pct``
+reads host stamps alone and still does). That costs four diagnostics,
+never the run's result. A program whose spans carry no ``run`` (one older
+than the numbering) gives every reader nothing; a wait that names no
+earlier dispatch of its program says the PROGRAM is wrong and is an error
+like a kernel that is not found.
 """
 
 from __future__ import annotations
@@ -38,8 +58,11 @@ PROGRAMS = {
 _DISPATCH = {d: p for p, (_, d, _) in PROGRAMS.items()}
 _WAIT = {w: p for p, (_, _, w) in PROGRAMS.items()}
 CUT = 2        # runs a stretch's end may cut: dispatched inside, run after
-SLACK_S = 50e-6     # between two host stamps, beside the join's own error
-LATE_S = 1e-3       # a device stamp against a host stamp that all but meets it
+CLEAR = 0.1    # the least score is taken under this share of the runner-up's
+
+
+class Unpaired(Exception):
+    """The trace and the spans cannot be paired; the text says why."""
 
 
 @dataclasses.dataclass
@@ -81,13 +104,23 @@ def span_runs(spans) -> dict[int, Run]:
 
 
 @dataclasses.dataclass
+class Shift:
+    """How one program's traced runs were laid on its dispatches."""
+    at: int                          # the first traced run's dispatch
+    score: float                     # s its pairs break the two orders by
+    runner_up: float | None          # the next shift's; None: one fitted
+    tried: int                       # shifts that fitted
+
+
+@dataclasses.dataclass
 class Joined:
     runs: dict[int, Run]
     offset: float                    # perf_counter -> the trace's clock, s
     error: float                     # a tick's own offset from it, at most
     ticks: list                      # the traced ticks, the trace's clock
     unrun: list                      # dispatched in the stretch, run after
-    waits: dict | None = None        # what device_waits found, once asked
+    shifts: dict[str, Shift]         # program -> how its runs were paired
+    waits: dict | None = None        # what device_waits found
 
     def on_trace(self, ns: int) -> float:
         return ns * 1e-9 + self.offset
@@ -97,6 +130,17 @@ class Joined:
         """The runs the trace holds, in the order the device ran them."""
         return sorted((r for r in self.runs.values() if r.start is not None),
                       key=lambda r: r.start)
+
+    @property
+    def skew(self) -> float | None:
+        """The smallest ``wait.end - run.end`` over the traced runs whose
+        wait slept on the device (``ready`` 0): the read-back at its
+        quickest plus however far the device's stamps lie before the
+        host's clock (less for stamps that lie after it)."""
+        return min((self.on_trace(r.wait.end_ns) - r.end
+                    for r in self.runs.values() if r.start is not None
+                    and r.wait is not None and not r.wait.attrs.get("ready")),
+                   default=None)
 
 
 def window_runs(run):
@@ -114,16 +158,80 @@ _joined = None      # the last (trace, records, recorder) joined, and the join
 
 def join(run):
     """The window's runs, those of the traced stretch with their time on
-    the device, or ``None`` where there is nothing to read (an untraced
-    run included). Every reader of a run asks; the trace is parsed, sorted
-    and paired for the first."""
+    the device, or ``None`` where there is nothing to read: an untraced
+    run, or a trace that cannot be paired (which says so once on stderr).
+    Every reader of a run asks; the trace is parsed, sorted and paired, or
+    found unpaired, for the first."""
     global _joined
     if run["trace"] is None:
         return None
     of = (run["trace"], run["records"], program_spans.recorder())
     if _joined is None or any(a is not b for a, b in zip(_joined[0], of)):
-        _joined = (of, _join(run))
+        try:
+            j = _join(run)
+        except Unpaired as why:
+            _say(f"bench_cells: unpaired: {why}")
+            j = None
+        _joined = (of, j)
     return _joined[1]
+
+
+def _pairs(begun, ended, traced, at: int):
+    """Per pair of the k-th traced run with the (``at`` + k)-th dispatch:
+    the seconds the run starts BEFORE its dispatch began and the seconds
+    it ends AFTER its wait ended (negative where it keeps the order)."""
+    for k, ev in enumerate(traced):
+        yield begun[at + k] - ev.start, ev.end - ended[at + k]
+
+
+def _breaks(begun, ended, traced, at: int, stop: float) -> float:
+    """A shift's score: the seconds its pairs break the two orders by,
+    summed, and given up once past ``stop``."""
+    total = 0.0
+    for early, late in _pairs(begun, ended, traced, at):
+        total += max(early, 0.0) + max(late, 0.0)
+        if total > stop:
+            break
+    return total
+
+
+def find_shift(program, pattern, asked, begun, ended, traced) -> Shift:
+    """The shift at which ``traced`` (a program's runs on the device, in
+    order) lie on ``asked`` (its dispatches, in order, begun at ``begun``,
+    their waits ended at ``ended``, ``inf`` where nobody waited): every
+    shift that fits is scored (:func:`_breaks`) and the least taken where
+    it is under ``CLEAR`` of the runner-up's."""
+    fits = len(asked) - len(traced) + 1
+    if fits < 1:
+        raise Unpaired(
+            f"the trace holds {len(traced)} runs of {pattern!r} and the "
+            f"spans {len(asked)} {program} dispatches: no shift fits")
+    inf = float("inf")
+    best, second = (inf, -1), (inf, -1)
+    for at in range(fits):
+        got = (_breaks(begun, ended, traced, at, second[0]), at)
+        if got < best:
+            best, second = got, best
+        elif got < second:
+            second = got
+    (score, at), lone = best, fits == 1
+    bar = sum(ev.end - ev.start for ev in traced) if lone else second[0]
+    if traced and not score < CLEAR * bar:
+        k, (early, late) = max(enumerate(_pairs(begun, ended, traced, at)),
+                               key=lambda pair: max(pair[1]))
+        r, ev = asked[at + k], traced[k]
+        raise Unpaired(
+            f"the {len(traced)} traced runs of {pattern!r} on the "
+            f"{len(asked)} {program} dispatches break the two orders by "
+            f"{score:.6f} s at shift {at}, the least of {fits} tried, and by "
+            + (f"{second[0]:.6f} s at shift {second[1]}, the runner-up"
+               if not lone else f"no other (the runs took {bar:.6f} s)")
+            + f": not under {CLEAR} of it; at shift {at} {program} run "
+            f"{r.run} would lie on the device at {ev.start:.6f}.."
+            f"{ev.end:.6f} s, {1e3 * max(early, late):.3f} ms "
+            + ("after the wait that read it ended" if late > early else
+               "before its dispatch began"))
+    return Shift(at, score, None if lone else second[0], fits)
 
 
 def _join(run):
@@ -140,8 +248,7 @@ def _join(run):
                 for k, e in enumerate(steps))
     ticks = [(ts + offset, te + offset)
              for ts, te, _ in records["ticks"][first:last]]
-    j = Joined(runs, offset, error, ticks, [])
-    slack = error + SLACK_S
+    j = Joined(runs, offset, error, ticks, [], {})
     lo, hi = ticks[0][0], ticks[-1][1]
     dev = trace.devices[0]
     for program, (key, _, _) in PROGRAMS.items():
@@ -151,35 +258,23 @@ def _join(run):
         asked = sorted((r for r in runs.values() if r.program == program),
                        key=lambda r: r.dispatch.start_ns)
         begun = [j.on_trace(r.dispatch.start_ns) for r in asked]
-        at = 0
-        if traced:
-            at = bisect.bisect_right(begun, traced[0].start + slack) - 1
-            if at < 0 or at + len(traced) > len(asked):
-                raise SystemExit(
-                    f"bench_cells: the trace holds {len(traced)} runs of "
-                    f"{pattern!r} and the spans {len(asked)} {program} "
-                    f"dispatches, {max(at, 0)} of them before the first "
-                    f"traced run: they cannot be paired")
-        for r, ev in zip(asked[at:], traced):
-            late = (r.wait is not None
-                    and ev.end > j.on_trace(r.wait.end_ns) + LATE_S)
-            if j.on_trace(r.dispatch.start_ns) > ev.start + slack or late:
-                raise SystemExit(
-                    f"bench_cells: {program} run {r.run} would lie on the "
-                    f"device at {ev.start:.6f}..{ev.end:.6f} s, "
-                    + ("after the wait that read it ended" if late else
-                       "before its dispatch began")
-                    + ": the trace cannot be paired")
+        ended = [float("inf") if r.wait is None
+                 else j.on_trace(r.wait.end_ns) for r in asked]
+        shift = find_shift(program, pattern, asked, begun, ended, traced)
+        j.shifts[program] = shift
+        for r, ev in zip(asked[shift.at:], traced):
             r.start, r.end = ev.start, ev.end
-        cut = [r for r, b in zip(asked[at + len(traced):],
-                                 begun[at + len(traced):]) if lo <= b <= hi]
+        after = shift.at + len(traced)
+        cut = [r for r, b in zip(asked[after:], begun[after:])
+               if lo <= b <= hi]
         if len(cut) > CUT:
-            raise SystemExit(
-                f"bench_cells: {len(cut)} {program} dispatches of the "
-                f"traced stretch have no run of {pattern!r} in the trace "
-                f"({len(traced)} runs); at most {CUT} may be cut at its end")
+            raise Unpaired(
+                f"{len(cut)} {program} dispatches of the traced stretch "
+                f"have no run of {pattern!r} in the trace ({len(traced)} "
+                f"runs); at most {CUT} may be cut at its end")
         j.unrun.extend(cut)
     j.unrun.sort(key=lambda r: r.dispatch.start_ns)
+    j.waits = device_waits(j, trace)
     return j
 
 
@@ -189,9 +284,8 @@ def device_waits(j: Joined, trace) -> dict[str, float]:
     of the next, split where the next run's dispatch span began: before it
     the device had nothing asked of it (``host``), after it the call, the
     runtime and the chip (``launch``). With ``idle``, their sum as the
-    device's operations alone give it; the two must agree within 2 %."""
-    if j.waits is not None:
-        return j.waits
+    device's operations alone give it; where the two do not agree within
+    2 % the trace is unpaired."""
     lo, hi = j.ticks[0][0], j.ticks[-1][1]
     dev = trace.devices[0]
     idle = program_spans._overlaps(
@@ -211,31 +305,39 @@ def device_waits(j: Joined, trace) -> dict[str, float]:
         out["launch"] += max(b - max(a, asked), 0.0)
     parts = out["host"] + out["launch"] + out["inside"]
     if abs(parts - out["idle"]) > 0.02 * out["idle"] + 1e-9:
-        raise SystemExit(
-            f"bench_cells: the device's waits for the host {out['host']:.6f}"
-            f" s and for the launch {out['launch']:.6f} s and its idle "
-            f"inside runs {out['inside']:.6f} s do not add up to its idle "
-            f"inside the traced ticks, {out['idle']:.6f} s")
-    j.waits = out
+        raise Unpaired(
+            f"the device's waits for the host {out['host']:.6f} s and for "
+            f"the launch {out['launch']:.6f} s and its idle inside runs "
+            f"{out['inside']:.6f} s do not add up to its idle inside the "
+            f"traced ticks, {out['idle']:.6f} s")
     return out
 
 
 def read_device_wait(run, part: str):
     """What the two ``engine.device_wait_*_ms_per_tick`` readers are: the
     mean a traced tick of ``part`` (``host`` / ``launch``), in ms; the
-    ``host`` reader says on stderr what the three parts were."""
+    ``host`` reader says on stderr what the three parts were and how the
+    runs were paired."""
     j = join(run)
     if j is None:
         return None
-    w = device_waits(j, run["trace"])
+    w = j.waits
     n = len(j.ticks)
     if part == "host":
+        skew = j.skew
         _say(f"device 0 idle inside the {n} traced ticks: "
              f"{w['idle']:.6f} s = waiting for the host {w['host']:.6f} + "
              f"for the launch {w['launch']:.6f} + inside program runs "
              f"{w['inside']:.6f}; {len(j.traced)} runs paired, "
              f"{len(j.unrun)} cut at the end; the join's error (a traced "
-             f"tick's own offset from the median) {1e6 * j.error:.1f} us")
+             f"tick's own offset from the median) {1e6 * j.error:.1f} us; "
+             + "; ".join(
+                 f"{p} shift {s.at} of {s.tried} breaks the orders by "
+                 f"{s.score:.6f} s, the runner-up by "
+                 + ("-" if s.runner_up is None else f"{s.runner_up:.6f}")
+                 for p, s in j.shifts.items())
+             + "; the smallest wait.end - run.end over waits that slept "
+             + ("-" if skew is None else f"{1e3 * skew:.3f} ms"))
     return 1e3 * w[part] / n
 
 

@@ -76,13 +76,13 @@ def test_nothing_where_a_tick_carries_no_such_attribute(monkeypatch):
 def test_manifest_entry_lists_both_serve_cells():
     entry, = (m for m in manifest.load_manifest()["per_layer"]
               if m["name"] == NAME)
-    assert entry == {
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "serve engine",
-        "moves": "serve_tokens_per_s",
-        "workloads": ["gpt2-large.serve-closed",
-                      "jamba2-3b.serve-reason-closed"]}
-    assert manifest.load_manifest()["per_layer"][-1] == entry
+        "moves": "serve_tokens_per_s"}
+    # by membership: every serving cell a later PR adds lists it too
+    assert {"gpt2-large.serve-closed",
+            "jamba2-3b.serve-reason-closed"} <= set(entry["workloads"])
 
 
 def test_toy_serve_cell_runs_ahead_nearly_every_tick():

@@ -90,14 +90,14 @@ def test_the_cell_its_files_and_its_readers_are_found(bench):
         assert callable(manifest.load_reader(name))
     per_layer = _by_name(bench["per_layer"])
     for name in NEW:
-        assert per_layer[name]["workloads"] == [CELL], name
+        assert CELL in per_layer[name]["workloads"], name
         assert per_layer[name]["layer"] in ("kernels", "model programs")
     assert per_layer["moe.held_experts_hit_pct"]["better"] == "lower"
     assert per_layer["moe.rows_per_held_expert"]["moves"] == \
         "serve_tokens_per_s"
     cells = [w["name"] for w in bench["workloads"]]
     assert CELL in cells
-    assert per_layer["entry.trace_lower_s"]["workloads"] == cells
+    assert set(per_layer["entry.trace_lower_s"]["workloads"]) == set(cells)
     w = _by_name(bench["workloads"])[CELL]
     c = _by_name(bench["configs"])[CONFIG]
     assert len(w["why"]) <= 200 and len(c["why"]) <= 200
@@ -285,7 +285,7 @@ def test_seeded_weights_have_the_programs_layout_and_count():
 # -- the runner at toy size ----------------------------------------------------
 
 
-def toy_cell(limits=LIMITS, arch=TOY):
+def toy_cell(limits=LIMITS, arch=TOY, requests=6):
     real = manifest.load_cell(CELL)
     mix = copy.deepcopy(real.traffic)
     mix.update(
@@ -296,7 +296,7 @@ def toy_cell(limits=LIMITS, arch=TOY):
         prompt_lengths={"min": 8, "max": 24, "multiple_of": 8,
                         "weight": "inverse_length"},
         answer_lengths={"law": "log_uniform", "min": 3, "max": 8})
-    mix["check"] = {"requests": 6, "limits": limits}
+    mix["check"] = {"requests": requests, "limits": limits}
     return manifest.Cell(CELL, 1, real.config_name, real.traffic_name,
                          dict(real.config, nemotron_h_config=arch), mix,
                          real.end_to_end, real.per_layer)
@@ -337,7 +337,10 @@ def test_state_left_by_the_last_occupant_is_not_correct(monkeypatch):
     monkeypatch.setattr(nemotron_h, "_slot_pair", kept)
     # a width of its own: the programs are memoized by configuration, and
     # the broken pair must neither find the sound one nor be found later
-    result = _run(toy_cell(arch=dict(TOY, d_shared=80)))
+    # and a sample of 24: which six of the finished requests a window's
+    # luck drew decided the reading (1.2e-3 to 6.3e-3, and once in four
+    # under the limit); over 24 it reads 1.1e-3 to 3.9e-3 (my CPU runs, PR 41)
+    result = _run(toy_cell(arch=dict(TOY, d_shared=80), requests=24))
     assert result["correct"] is False
     assert result["compared"]["gap_mean"]["value"] > 5 * LIMITS["gap_mean"]
 

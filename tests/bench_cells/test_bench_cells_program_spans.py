@@ -363,6 +363,7 @@ def program_names():
     """What the trace calls each program a traffic file looks for: the
     module name of its lowering (CPU, toy size)."""
     from simple_distributed_machine_learning_tpu.models.gpt import (
+        SEAT_SAMPLE,
         GPTConfig,
         make_gpt_stages,
     )
@@ -382,19 +383,20 @@ def program_names():
     stages, wire_dim, out_shape = make_gpt_stages(jax.random.key(0), cfg, 1)
     eng = InferenceEngine(stages, cfg, n_slots=2, block_size=4,
                           prefill_chunk=4, attn_kernel="fused")
-    S, nb = 2, eng.pool.blocks_per_seq
-    scalars = (np.zeros(2, np.uint32), np.float32(0), np.int32(0),
-               np.float32(2))
+    # the signature since PR 31: the state (every slot's newest token and
+    # key) after the pool; the decode told which slots are live, the chunk
+    # its slot and what to seat
+    S, nb, pool = 2, eng.pool.blocks_per_seq, eng.pool
     decode = _module_name(
-        eng._decode, eng.params, eng.pool.kc, eng.pool.vc,
-        np.zeros(S, np.int32), np.zeros(S, np.int32),
-        np.zeros((S, nb), np.int32), np.zeros((S, 2), np.uint32),
-        np.zeros(S, np.float32), np.zeros(S, np.int32),
-        np.full(S, 2.0, np.float32))
+        eng._decode, eng.params, pool.kc, pool.vc, pool.state,
+        np.zeros(S, np.int32), np.zeros((S, nb), np.int32),
+        np.zeros(S, bool), np.zeros(S, np.float32),
+        np.zeros(S, np.int32), np.full(S, 2.0, np.float32))
     chunk = _module_name(
-        eng._chunk_prefill, eng.params, eng.pool.kc, eng.pool.vc,
-        np.zeros((1, 4), np.int32), np.int32(0), eng.pool.device_table(0),
-        *scalars)
+        eng._chunk_prefill, eng.params, pool.kc, pool.vc, pool.state,
+        np.zeros((1, 4), np.int32), np.int32(0), pool.device_table(0),
+        np.int32(0), np.int32(SEAT_SAMPLE), np.zeros(2, np.uint32),
+        np.float32(0), np.int32(0), np.float32(2))
     pipe = Pipeline(stages, make_mesh(n_stages=1, n_data=1), wire_dim,
                     out_shape)
     opt = adamw(1e-3)

@@ -58,7 +58,7 @@ def serve_cell(limits=SERVE_LIMITS):
         engine={"n_slots": 4, "max_len": 64, "block_size": 4, "n_blocks": 40,
                 "prefill_chunk": 8, "attn_kernel": "fused",
                 "cache_dtype": "bfloat16"},
-        clients=4, round_size=8, rounds=40,
+        clients=4, round_size=8, rounds=120,
         prompt_lengths={"min": 4, "max": 24, "multiple_of": 4,
                         "weight": "inverse_length"},
         answer_lengths={"law": "log_uniform", "min": 3, "max": 8})

@@ -80,7 +80,7 @@ def test_the_cell_its_files_and_its_readers_are_found(bench):
     e2e = {m["name"] for m in cell.end_to_end}
     assert e2e == {"serve_tokens_per_s", "tpot_p95_ms", "setup_s"}
     layer = {m["name"] for m in cell.per_layer}
-    assert layer == set(NEW) | {
+    assert layer >= set(NEW) | {
         "entry.trace_lower_s", "engine.tick_ms_p50", "engine.ttft_p50_ms",
         "model.decode_device_ms", "device.idle_pct.serve",
         "engine.host_ms_per_tick", "engine.host_admit_ms",
@@ -102,7 +102,7 @@ def test_new_metric_entry_by_name(bench, name):
     unit, better, layer, moves = NEW[name]
     assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
         unit, better, layer, moves)
-    assert m["workloads"] == [CELL]
+    assert CELL in m["workloads"]
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
                       "workloads"}
     # the end-to-end metric it moves is one the cell reports
@@ -111,7 +111,7 @@ def test_new_metric_entry_by_name(bench, name):
 
 def test_every_cell_still_lists_the_trace_lower_metric(bench):
     lower = _entry(bench["per_layer"], "entry.trace_lower_s")
-    assert lower["workloads"] == [w["name"] for w in bench["workloads"]]
+    assert set(lower["workloads"]) == {w["name"] for w in bench["workloads"]}
     w = _entry(bench["workloads"], CELL)
     assert len(w["why"]) <= 200 and w["chips"] == 1
     c = _entry(bench["configs"], "sdar-30b-a3b")
