@@ -172,18 +172,29 @@ def gated_mlp(params: dict, x: jax.Array) -> jax.Array:
     return matmul_acc32(mid, params["down"])
 
 
-def rotary(x: jax.Array, positions: jax.Array,
-           theta: float = 10000.0) -> jax.Array:
-    """Rotary position embedding over the whole head, rotate-half
-    convention (the two halves of a head are the rotation's pairs): ``x
-    [..., T, H, dh]`` at ``positions [..., T]`` becomes ``x * cos + (-x2,
-    x1) * sin`` with angle ``positions * theta ** (-2i / dh)`` for pair
-    ``i``. Float32; no scaling of the frequencies."""
-    half = x.shape[-1] // 2
+def rotary(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+           rotated: int | None = None) -> jax.Array:
+    """Rotary position embedding over the first ``rotated`` lanes of every
+    head (the whole head where ``None``; a model with a
+    ``partial_rotary_factor`` rotates that share and passes the rest
+    through), rotate-half convention (the two halves of the rotated lanes
+    are the rotation's pairs): ``x [..., T, H, dh]`` at ``positions [...,
+    T]`` becomes ``x * cos + (-x2, x1) * sin`` there, with angle ``positions
+    * theta ** (-2i / rotated)`` for pair ``i``. Float32; no scaling of the
+    frequencies."""
+    dh = x.shape[-1]
+    rotated = dh if rotated is None else rotated
+    if not 2 <= rotated <= dh or rotated % 2:
+        raise ValueError(
+            f"rotary: rotated={rotated} must be an even number of the "
+            f"head's {dh} lanes")
+    half = rotated // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions[..., None].astype(jnp.float32) * inv    # [..., T, dh/2]
+    ang = positions[..., None].astype(jnp.float32) * inv    # [..., T, r/2]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x = x.astype(jnp.float32)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
+    x1, x2 = x[..., :half], x[..., half:rotated]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rotated < dh:
+        parts.append(x[..., rotated:])
+    return jnp.concatenate(parts, axis=-1)

@@ -148,6 +148,21 @@ def sigmoid_top_k(bias: jax.Array, scale: float):
     return route
 
 
+def softmax_top_1(bias: jax.Array):
+    """The rule ``p = softmax(scores)``; chosen: the ONE largest of ``p +
+    bias`` (``bias [E]`` steers the choice alone); its weight is ``p_e``
+    itself: with one expert a token there is nothing to renormalise."""
+    def route(scores, top_k):
+        if top_k != 1:
+            raise ValueError(f"softmax_top_1 routes one expert a token, "
+                             f"got top_k={top_k}")
+        p = jax.nn.softmax(scores, axis=-1)
+        ids = jnp.argmax(p + bias.astype(jnp.float32), axis=-1)[:, None]
+        return jnp.take_along_axis(p, ids, axis=-1), ids.astype(jnp.int32)
+
+    return route
+
+
 # -- expert bodies: (params, rows [m, K] sorted by expert, sizes) -> [m, K] ----
 
 
@@ -169,16 +184,22 @@ def relu2_experts(params: dict, rows: jax.Array, sizes: jax.Array):
 def dropless_experts(params: dict, x: jax.Array, top_k: int, *,
                      route=softmax_top_k, experts=swiglu_experts,
                      held: tuple[int, int] | None = None,
-                     rows: jax.Array | None = None):
+                     rows: jax.Array | None = None,
+                     scores: jax.Array | None = None):
     """The sparse feed-forward part over ``x [T, d]`` (float32, already
     normed): ``route(x W_r, top_k)`` in float32 over ALL the ``E`` experts
     of ``params["router"] [d, E]`` gives each token its ``top_k`` experts
-    and their weights; ``experts(params, rows, sizes)`` multiplies the
+    and their weights (``scores [T, E]`` float32, where given, stand in
+    for ``x W_r``: a router that is more than one matrix, or that carries
+    state from layer to layer, is the model's to run, and ``params`` then
+    needs no ``"router"``); ``experts(params, rows, sizes)`` multiplies the
     (token, expert) pairs, sorted by expert, by their own expert's
     matrices; the result is ``sum_e w_e * E_e(row)``. The experts read
     ``rows [T, K]`` where given (a mixture in a latent space hands its
-    down-projected rows), else ``x``. No capacity: every routed pair of a
-    held expert is computed.
+    down-projected rows), else ``x``; beside a router matrix they are cast
+    to its dtype here, beside ready ``scores`` the caller hands them in the
+    dtype the experts read. No capacity: every routed pair of a held expert
+    is computed.
 
     ``held = (first, n)``: the expert matrices in ``params`` are those of
     experts ``first .. first + n - 1`` alone (``None``: all ``E``). Routing,
@@ -191,13 +212,18 @@ def dropless_experts(params: dict, x: jax.Array, top_k: int, *,
     Returns ``(y [T, K] float32, sizes [n] int32)``, the second how many
     rows each HELD expert got."""
     n_tok = x.shape[0]
-    n_experts = params["router"].shape[1]
+    rows = x if rows is None else rows
+    if scores is None:
+        scores = matmul_acc32(x, params["router"])
+        # operands in the weights' dtype, cast before the gather copies them
+        rows = rows.astype(params["router"].dtype)
+    n_experts = scores.shape[1]
     first, n_held = (0, n_experts) if held is None else held
     if not 0 <= first <= first + n_held <= n_experts or n_held < 1:
         raise ValueError(
             f"dropless_experts: held experts [{first}, {first + n_held}) "
             f"outside the router's {n_experts}")
-    w, ids = route(matmul_acc32(x, params["router"]), top_k)    # [T, k]
+    w, ids = route(scores, top_k)                           # [T, k]
     flat = ids.reshape(-1)
     if n_held != n_experts:
         here = (flat >= first) & (flat < first + n_held)
@@ -205,8 +231,6 @@ def dropless_experts(params: dict, x: jax.Array, top_k: int, *,
     order = jnp.argsort(flat, stable=True)                  # pairs by expert
     sizes = jnp.bincount(flat, length=n_held + (n_held != n_experts))[
         :n_held].astype(jnp.int32)
-    # operands in the weights' dtype, cast before the gather copies them
-    rows = (x if rows is None else rows).astype(params["router"].dtype)
     out = experts(params, rows[order // top_k], sizes)      # [k T, K]
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))

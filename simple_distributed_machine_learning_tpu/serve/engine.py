@@ -367,6 +367,9 @@ class InferenceEngine:
         # (temperature > 0): where 0 the programs' sampler took its argmax
         # branch and sorted nothing (models/gpt.py::_sample_slots)
         self._sampling = 0
+        # the summed lengths of those slots: the K/V positions a layer of
+        # that decode read
+        self._kv_positions = 0
         self.pool = PagedKVPool(self._n_layers, n_slots, serving.kv_heads,
                                 self.max_len, serving.head_dim, cache_dtype,
                                 block_size=block_size, n_blocks=n_blocks,
@@ -805,7 +808,8 @@ class InferenceEngine:
                ahead=ahead, queue=self.scheduler.queue_depth,
                runs=self._runs - runs, state_slots=self._state_slots(),
                kv_blocks=self.pool.blocks_in_use,
-               sampling=self._sampling if decode_active else 0)
+               sampling=self._sampling if decode_active else 0,
+               kv_positions=self._kv_positions if decode_active else 0)
         if self._counter_names:
             # what the tick's decode run counted (0 where it ran none)
             sp.set(**(self._counted if decode_active
@@ -1216,20 +1220,22 @@ class InferenceEngine:
             [(s, int(self.pool.positions[s])) for s in active]))
 
     def _emit_tick(self, active: list[int], out, kd2, run: int,
-                   sampling: int) -> int:
+                   sampling: int, kv_positions: int) -> int:
         """Read one decode back (run ``run``, ``sampling`` of whose slots
-        sample) and account it: a token a slot, or (block steps) a forward
-        a slot."""
+        sample, over ``kv_positions`` cached positions) and account it: a
+        token a slot, or (block steps) a forward a slot."""
         self._sampling = sampling
+        self._kv_positions = kv_positions
         emit = self._emit_block if self._block > 1 else self._emit_decoded
         return emit(active, out, kd2, run)
 
     def _decode_dispatch(self, seats: list[tuple[int, int]]):
         """Launch one decode over ``seats``, ``(slot, position)`` of every
-        slot that takes part: ``(slots, tokens, key_data, run, sampling)``
-        as :meth:`_emit_tick` takes them, tokens and keys still on the
-        device, ``sampling`` the slots among them whose temperature is
-        above 0."""
+        slot that takes part: ``(slots, tokens, key_data, run, sampling,
+        kv_positions)`` as :meth:`_emit_tick` takes them, tokens and keys
+        still on the device, ``sampling`` the slots among them whose
+        temperature is above 0, ``kv_positions`` their lengths summed, the
+        rows this step writes included."""
         S = self.pool.n_slots
         active = [s for s, _ in seats]
         with tracing.span("engine.decode.prepare"):
@@ -1270,7 +1276,8 @@ class InferenceEngine:
             toks2, kd2 = self._run_paged(
                 self._decode, self._pack_decode, toks, pos, tables, *live,
                 kd, temps, top_ks, top_ps, *bank_args)
-        return active, toks2, kd2, run, int(np.count_nonzero(temps > 0))
+        return (active, toks2, kd2, run, int(np.count_nonzero(temps > 0)),
+                sum(p + self._block for _, p in seats))
 
     def _tick_ahead(self) -> tuple[int, int]:
         """The paged tick of a model whose programs keep the newest tokens
@@ -1398,6 +1405,8 @@ class InferenceEngine:
         with tracing.span("engine.decode.prepare"):
             kd, temps, top_ks, top_ps = self._sampling_inputs(active)
             self._sampling = int(np.count_nonzero(temps > 0))
+            self._kv_positions = int(sum(
+                self.pool.positions[s] + 1 for s in active))
             toks = np.zeros(S, np.int32)
             pos = np.zeros(S, np.int32)
             valid = np.zeros(S, np.int32)

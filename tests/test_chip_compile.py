@@ -182,6 +182,10 @@ _CELLS = {
     # max_len 2,048: 128 blocks a slot, not 64
     "nemotron3-super-120b-a12b.serve-agent-closed": (
         96, 32, 1, 128, 2, 12289, "serve-agent-closed"),
+    # max_len 8,192: 512 blocks a slot, 32 spans of 16; the pool's row is
+    # the latent's 2 K/V heads of 128 under 8 query heads
+    "zaya1-8b.serve-context-closed": (24, 8, 1, 128, 2, 12289,
+                                      "serve-context-closed"),
 }
 
 
@@ -454,6 +458,92 @@ def test_latent_expert_products_compile_for_v5e(one_chip, mosaic, m, k, n):
         ((128,), jnp.int32), kernels=["moe_experts"])
     assert re.search(_kernel_pattern("serve-agent-closed", "moe_experts"),
                      line.strip())
+
+
+@pytest.mark.parametrize("m,k,n", [(24, 2048, 2048), (512, 2048, 2048)])
+def test_top1_expert_products_compile_for_v5e(one_chip, mosaic, m, k, n):
+    """The published widths (16 experts of 2048 x 2048) at the rows
+    ``zaya1-8b.serve-context-closed`` runs: ONE pair a row of a tick's 24
+    rows (a row tile of 24, 1.9 rows a hit expert) or of a 512-token chunk;
+    gate, up and down are the same shape, each expert's matrix in two
+    blocks of 1,024 columns, 4 MiB each: the largest block the layer
+    fetches in any cell."""
+    bf16 = jnp.bfloat16
+    (line,) = _compile(
+        me.grouped_matmul, one_chip, ((m, k), bf16), ((16, k, n), bf16),
+        ((16,), jnp.int32), kernels=["moe_experts"])
+    assert re.search(_kernel_pattern("serve-context-closed", "moe_experts"),
+                     line.strip())
+    assert not re.search(_kernel_pattern("serve-context-closed",
+                                         "paged_attention"), line.strip())
+
+
+def _cca_programs():
+    """``zaya1-8b.serve-context-closed``'s two programs at 2 of its 20
+    layers and everything else as the cell runs it: hidden 2048, 8 query
+    heads over 2 K/V heads of 128 in the latent, 16 experts of 2048, the
+    router's 256, the whole vocabulary of 262,272 under the tied head, 24
+    slots of 8,192 positions, 12,288 bf16 blocks of 16, chunks of 512, the
+    fused kernel."""
+    from simple_distributed_machine_learning_tpu.models.zaya import (
+        ZayaConfig,
+        make_zaya_stages,
+        pack_chunk_inputs,
+        pack_decode_inputs,
+    )
+    import numpy as np
+    S, ml, bs, nb, c = 24, 8192, 16, 12288, 512
+    cfg = ZayaConfig(vocab=262272, seq_len=ml, d_model=2048, n_layers=2,
+                     n_heads=8, n_kv_heads=2, head_dim=128, n_experts=16,
+                     d_expert=2048, d_router=256, param_dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda k: make_zaya_stages(k, cfg)[0][0].params, jax.random.key(0))
+    serving = cfg.paged_serving([types.SimpleNamespace(params=params)], ml,
+                                bs, "bfloat16", kernel="fused")
+    pool = (_sd((nb + 1, bs, cfg.d_kv), jnp.bfloat16),) * serving.kv_layers
+    state = jax.tree.map(lambda sd: _sd((S, *sd.shape), sd.dtype),
+                         serving.state_shapes)
+    z = np.zeros(S, np.int32)
+    host, = pack_decode_inputs(z, z, np.zeros((S, ml // bs), np.int32), z,
+                               None, z.astype(np.float32), z,
+                               z.astype(np.float32))
+    tokens, chost = pack_chunk_inputs(
+        np.zeros((1, c), np.int32), 0, np.zeros(ml // bs, np.int32), 0, -1,
+        np.zeros(2, np.uint32), 0.0, 0, 1.0)
+    return pool, {
+        "cca-decode": (serving.decode, (
+            [params], pool, pool, state, _sd(host.shape, host.dtype))),
+        "cca-chunk": (serving.chunk_prefill, (
+            [params], pool, pool, state, _sd(tokens.shape, tokens.dtype),
+            _sd(chost.shape, chost.dtype)))}
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("cca-decode", {"paged_attention", "moe_experts"}),
+    ("cca-chunk", {"moe_experts"})])
+def test_cca_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
+                                                      program, kernels):
+    """The decode step and the prefill chunk of the fifth family, handed to
+    the chip's compiler whole: a slot of 8,192 positions (512 table
+    entries), rows of 262,272 logits under the sampler, the 4 MiB expert
+    blocks at a row tile of 24. The pool and the per-slot state are
+    donated: every byte of both is aliased input to output, and what the
+    program holds beside its arguments stays under two layers' K buffers
+    (a chunk's scores over 8,192 cached positions are 134 MB)."""
+    pool, programs = _cca_programs()
+    fn, args = programs[program]
+    compiled = fn.lower(*_on_chip(args, one_chip)).compile()
+    found = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert found == kernels
+    layer = math.prod(pool[0].shape) * pool[0].dtype.itemsize
+    state_bytes = sum(math.prod(sd.shape) * sd.dtype.itemsize
+                      for sd in jax.tree.leaves(args[3]))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * len(pool) * layer + state_bytes
+    assert mem.temp_size_in_bytes < 2 * layer, (mem.temp_size_in_bytes,
+                                                  layer)
 
 
 # -- flash attention: the train step's kernel -------------------------------
