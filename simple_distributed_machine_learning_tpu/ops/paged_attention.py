@@ -21,6 +21,15 @@ kernel pass (the structure of JAX's own paged-attention kernel):
   two (the grid before PR 33 stepped through 16 cells for either, at two
   thirds of a microsecond a skipped cell), and no gathered dense copy ever
   exists;
+- **copies the scalar core can issue fast**: on the chip a trip is mostly
+  the issuing of its block copies and the waiting for them, not the bytes
+  and not the two products (``PERF.md`` section 6, PR 43: on a 256-lane
+  row 0.87 of a span's 1.10 us, the products 0.19). So a stream's blocks
+  of a span all signal ONE semaphore and the span is waited for ONCE a
+  stream, through a descriptor over the whole buffer half (a DMA
+  semaphore counts bytes); the block loop is traced once and lowered
+  unrolled; and the starts sit under a branch a buffer half, so that
+  every destination offset is static;
 - **online softmax** (flash style): ``(m, l, acc)`` are the loop's carries,
   written to the output once, so the ``[K, max_len]`` score matrix is never
   materialized;
@@ -36,7 +45,17 @@ kernel pass (the structure of JAX's own paged-attention kernel):
   f32 before the dots, matching the dense path's einsum promotion — which
   is what keeps greedy decode through this kernel TOKEN-bit-exact against
   the gather-then-dense path (logits agree to accumulation-order ulps;
-  tests/test_paged_attention.py pins both).
+  tests/test_paged_attention.py pins both). What a float32 product IS
+  depends on who runs it: the CPU interpreter multiplies in float32; on
+  the chip Mosaic, like XLA for the dense path's einsums, multiplies
+  float32 operands at default precision in ONE bfloat16 pass (measured,
+  PR 43: casting ``q`` and ``p`` to bfloat16 and leaving K/V unwidened
+  gives the kernel's output bit for bit, at the same speed). So there
+  are no extra passes over K and V to save, and carrying ``q`` and ``p``
+  as three stacked bfloat16 terms (float32 to the last bit in one
+  product) RAISES the chip's precision at 6-48 % more kernel time: not
+  built. Mask, scale, ``exp`` and the softmax state are float32
+  everywhere.
 
 On non-TPU backends the same kernel runs in Pallas interpret mode
 (``flash_attention._interpret``), so the serving engine's ``kernel="fused"``
@@ -112,11 +131,11 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
     lane tiles]; ``o_ref``: [1, H, R, dh] f32. Scratch, all of it kept from
     one slot to the next: the double-buffered spans ``kbuf`` / ``vbuf`` [2,
     H, n_sub * bs, dh] in the pool's dtype (with ``quant`` ``ksbuf`` /
-    ``vsbuf`` [2, n_sub, H, lanes] f32), the DMA semaphores [streams, 2,
-    n_sub], and ``first`` (SMEM [1]): the buffer half that holds this
-    slot's first span. ``H`` and ``dh`` are the CALL's: the wrapper hands a
-    rows-in-lanes pool over as one stream (``H = 1``) whose ``dh`` is the
-    whole row."""
+    ``vsbuf`` [2, n_sub, H, lanes] f32), the DMA semaphores [streams, 2]
+    (one a stream and half: a span's blocks all signal it), and ``first``
+    (SMEM [1]): the buffer half that holds this slot's first span. ``H``
+    and ``dh`` are the CALL's: the wrapper hands a rows-in-lanes pool over
+    as one stream (``H = 1``) whose ``dh`` is the whole row."""
     n_streams = 4 if quant else 2
     hbm, rest = rest[:n_streams], rest[n_streams:]
     o_ref, rest = rest[0], rest[1:]
@@ -125,30 +144,36 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
     span = n_sub * bs
     trips = lax.div(qpos_ref[s_idx, n_q - 1], span) + 1  # newest position
 
-    def copies(slot, it, half, wait=False):
-        """Start (or, with ``wait``, wait for) the DMAs of ``slot``'s span
-        ``it`` into buffer half ``half``: block ``g`` of every stream,
-        through the slot's table. A span's blocks past the slot's newest
-        one fetch that one again (the position mask removes them; a table
-        entry past it is never read). A descriptor that only waits names a
-        fixed block. The blocks are a loop, not ``n_sub`` copies of its
-        body: a program traces this three times over."""
-        last_blk = 0 if wait else lax.div(qpos_ref[slot, n_q - 1], bs)
+    def start(slot, it, half: int):
+        """Start the DMAs of ``slot``'s span ``it`` into buffer half
+        ``half``: block ``g`` of every stream, through the slot's table. A
+        span's blocks past the slot's newest one fetch that one again (the
+        position mask removes them; a table entry past it is never read).
+        The kernel is bound by how fast the scalar core issues these (a
+        descriptor of 8-40 KB each; ``PERF.md`` section 6, PR 43), so the
+        half is a Python int (every destination offset static) and the
+        blocks are a loop traced ONCE and lowered unrolled: ``n_sub``
+        copies of its body at trace time cost 2.2 s of a program's set-up
+        (PR 33), the rolled loop a quarter of a call."""
+        last_blk = lax.div(qpos_ref[slot, n_q - 1], bs)
 
         def block(g, _):
-            blk = 0 if wait else tables_ref[
-                slot, lax.min(it * n_sub + g, last_blk)]
+            blk = tables_ref[slot, lax.min(it * n_sub + g, last_blk)]
             at = pl.ds(pl.multiple_of(g * bs, bs), bs)
             for w, (ref, buf) in enumerate(zip(hbm, bufs)):
                 dst = buf.at[half, :, at] if w < 2 else buf.at[half, g]
-                c = pltpu.make_async_copy(ref.at[blk], dst,
-                                          sem.at[w, half, g])
-                if wait:
-                    c.wait()
-                else:
-                    c.start()
+                pltpu.make_async_copy(ref.at[blk], dst,
+                                      sem.at[w, half]).start()
 
-        lax.fori_loop(0, n_sub, block, None)
+        lax.fori_loop(0, n_sub, block, None, unroll=True)
+
+    def wait(half):
+        """Wait for the span in buffer half ``half``: a stream's ``n_sub``
+        block copies signal ONE semaphore, and one wait a stream, its
+        descriptor the whole half, takes the span's bytes off it."""
+        for w, buf in enumerate(bufs):
+            pltpu.make_async_copy(buf.at[half], buf.at[half],
+                                  sem.at[w, half]).wait()
 
     def rows(buf, sc, half):
         """The span's K or V rows, [H, span, dh] f32 (dequantized)."""
@@ -163,7 +188,7 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
         @pl.when(s_idx == 0)
         def _first():           # nobody before the first slot fetched for it
             first[0] = 0
-            copies(0, 0, 0)
+            start(0, 0, 0)
 
         q = q_ref[0].astype(jnp.float32)                  # [H, R, dh]
         H, R, dh = q.shape
@@ -186,12 +211,13 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
             # copy it has not overlapped once, not once a slot
             mine = it + 1 < trips
 
-            @pl.when(mine | more)
-            def _next():
-                copies(jnp.where(mine, s_idx, s_idx + 1),
-                       jnp.where(mine, it + 1, 0), 1 - half)
+            for h in (0, 1):        # a branch a half: static offsets
+                @pl.when((mine | more) & (half == h))
+                def _next(h=h):
+                    start(jnp.where(mine, s_idx, s_idx + 1),
+                          jnp.where(mine, it + 1, 0), 1 - h)
 
-            copies(s_idx, it, half, wait=True)
+            wait(half)
             k = rows(bufs[0], bufs[2] if quant else None, half)
             v = rows(bufs[1], bufs[3] if quant else None, half)
             # scores in f32 — the dense path's einsum promotion, so the
@@ -264,7 +290,7 @@ def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
     if quant:
         scratch += [pltpu.VMEM((2, n_sub, *streams[2].shape[1:]),
                                kscale.dtype)] * 2
-    scratch += [pltpu.SemaphoreType.DMA((len(streams), 2, n_sub)),
+    scratch += [pltpu.SemaphoreType.DMA((len(streams), 2)),
                 pltpu.SMEM((1,), jnp.int32)]
 
     vma = _vma_of(q, kc, vc)

@@ -39,6 +39,7 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
 )
 from simple_distributed_machine_learning_tpu.ops.paged_attention import (
     _attend_blocks,
+    _span_blocks,
     paged_attention,
     paged_flash_decode,
 )
@@ -170,29 +171,33 @@ def test_paged_attention_fused_dequant_matches_dequantized_rows(H):
                                rtol=rtol, atol=atol)
 
 
-# the three served families' calls, scaled down: (query heads, K/V heads in
-# a pool row, queries a slot, head dim). A row is 128 lanes in each
+# the served families' calls, scaled down: (query heads, K/V heads in a pool
+# row, queries a slot, head dim, positions a table holds). A row is 128
+# lanes in the first three
 _FAMILIES = {
-    "rows-in-lanes": (4, 4, 1, 32),     # GPT: every head a K/V head
-    "one-kv-head": (5, 1, 1, 128),      # the hybrid: multi-query
-    "grouped-4-queries": (8, 2, 4, 64),  # block diffusion: a block a slot
+    "rows-in-lanes": (4, 4, 1, 32, 192),     # GPT: every head a K/V head
+    "one-kv-head": (5, 1, 1, 128, 192),      # the hybrid: multi-query
+    "grouped-4-queries": (8, 2, 4, 64, 192),  # block diffusion: a block a slot
+    # the latent cache: 4 query heads to each of 2 K/V heads of 128 in a
+    # 256-lane row, a table of several spans
+    "long-narrow": (8, 2, 1, 128, 1024),
 }
 
 
-@pytest.mark.parametrize("pool", ["bfloat16", "int8"])
-@pytest.mark.parametrize("family", list(_FAMILIES))
-def test_ragged_slots_in_one_call_match_dense_gather(family, pool):
-    """The loop over a slot's own spans, five slots of one call: a seat at
+def _ragged_call(family, quant, seed=11):
+    """Five slots of one call over a pool of ``family``'s shape: a seat at
     position 0 of an all-trash table (one trip), a slot that ends on a
-    span's last position, one a position past it (a second trip for one
-    row), one at ``max_len - 1``, and one whose table is a permutation
-    with a block repeated. A span is 128 positions here (8 bfloat16 blocks
-    of 16, 4 int8 blocks of 32: whole sublane tiles) in a table of 192."""
-    H, KVH, K, dh = _FAMILIES[family]
-    quant = pool == "int8"
+    span's last position, one a position past it (a further trip for one
+    row), one at ``max_len - 1``, and one whose table is a permutation with
+    a block repeated. Returns ``(q, kc, vc, tables, qpos, bs)``, the pool
+    head-major float32 ``[n, KVH, bs, dh]``."""
+    H, KVH, K, dh, positions = _FAMILIES[family]
     bs = 32 if quant else 16
-    NB, n_phys, span = 192 // bs, 40, 128
-    kq, kk, kv = jax.random.split(jax.random.key(11), 3)
+    NB = positions // bs
+    n_phys = max(40, 2 * NB)
+    span = bs * _span_blocks(bs, KVH * bs * dh * (1 if quant else 2), NB,
+                             1 if quant else 2)
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
     kc = jax.random.normal(kk, (n_phys, KVH, bs, dh))
     vc = jax.random.normal(kv, (n_phys, KVH, bs, dh))
     last = np.array([0, span - 1, span, NB * bs - 1, 100], np.int32)
@@ -206,6 +211,19 @@ def test_ragged_slots_in_one_call_match_dense_gather(family, pool):
     tables[4, live - 1] = tables[4, 0]          # a block referenced twice
     q = jax.random.normal(kq, (5, H, K, dh))
     qpos = np.repeat(last[:, None], K, axis=1)  # a block's rows: one position
+    return q, kc, vc, tables, qpos, bs
+
+
+@pytest.mark.parametrize("pool", ["bfloat16", "int8"])
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_ragged_slots_in_one_call_match_dense_gather(family, pool):
+    """The loop over a slot's own spans: :func:`_ragged_call`'s five slots
+    of one call. A span is 128 positions (8 bfloat16 blocks of 16, 4 int8
+    blocks of 32: whole sublane tiles) in a table of 192, and for the long
+    narrow cache 256 and 512 in a table of 1,024."""
+    H, KVH = _FAMILIES[family][:2]
+    quant = pool == "int8"
+    q, kc, vc, tables, qpos, bs = _ragged_call(family, quant)
     if quant:
         kd, ks = _quantize_rows(kc, jnp.int8)
         vd, vs = _quantize_rows(vc, jnp.int8)
@@ -232,9 +250,6 @@ def test_a_span_is_chosen_from_what_the_call_can_see():
     """No knob: whole sublane tiles of the pool's dtype join into a span
     (as many blocks as the table holds and the buffers fit), anything else
     is attended a block at a time."""
-    from simple_distributed_machine_learning_tpu.ops.paged_attention import (
-        _span_blocks,
-    )
     # the three cells: 40 KB, 4 KB and 16 KB bfloat16 blocks, tables of 64
     assert _span_blocks(16, 16 * 1280 * 2, 64, 2) == 16
     assert _span_blocks(16, 16 * 128 * 2, 64, 2) == 16
@@ -247,6 +262,100 @@ def test_a_span_is_chosen_from_what_the_call_can_see():
     assert _span_blocks(16, 16 * 1024, 32, 1) == 1
     assert _span_blocks(32, 32 * 1024, 32, 1) == 16
     assert _span_blocks(8, 8 * 1024 * 4, 64, 4) == 16
+
+
+def _dense_reference_f64(q, kc, vc, tables, qpos):
+    """:func:`_dense_paged_reference` in numpy float64, grouped queries
+    included (query head ``h`` reads K/V head ``h // group``)."""
+    q, kc, vc = (np.asarray(a, np.float64) for a in (q, kc, vc))
+    S, H, K, dh = q.shape
+    KVH, bs = kc.shape[1], kc.shape[2]
+    span = tables.shape[1] * bs
+    out = np.zeros(q.shape)
+    for s in range(S):
+        k = np.moveaxis(kc[tables[s]], 0, 1).reshape(KVH, span, dh)
+        v = np.moveaxis(vc[tables[s]], 0, 1).reshape(KVH, span, dh)
+        for h in range(H):
+            sc = q[s, h] @ k[h // (H // KVH)].T / math.sqrt(dh)
+            sc = np.where(np.arange(span)[None] <= qpos[s][:, None], sc,
+                          -np.inf)
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            out[s, h] = (p / p.sum(-1, keepdims=True)) @ v[h // (H // KVH)]
+    return out
+
+
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_bfloat16_pool_is_float32_attention_over_its_values(family):
+    """The five ragged slots over a bfloat16 pool (an idle seat among
+    them) against the float64 dense reference at the float32 tolerance,
+    and against the same call over a float32 pool holding the same
+    values: widening a bfloat16 row changes nothing, so the two are one
+    sum."""
+    q, kc, vc, tables, qpos, bs = _ragged_call(family, False, seed=12)
+    kc, vc = kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16)
+    call = jax.jit(lambda *a: paged_attention(*a, block_size=bs))
+    t, p = jnp.asarray(tables), jnp.asarray(qpos)
+    out = call(q, _rows(kc), _rows(vc), t, p)
+    ref = _dense_reference_f64(q, kc, vc, tables, qpos)
+    rtol, atol = attn_tol(jnp.float32)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=rtol, atol=atol)
+    wide = call(q, _rows(kc.astype(jnp.float32)),
+                _rows(vc.astype(jnp.float32)), t, p)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(wide),
+                               rtol=1e-6, atol=1e-6)
+
+
+def _kernel_eqns(jaxpr, inside=()):
+    """``(primitive name, the loops and branches it lies in)`` of every
+    equation of a jaxpr, those of its nested jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e, inside
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_eqns(sub, inside + (e,))
+
+
+@pytest.mark.parametrize("pool,bs", [("bfloat16", 16), ("float32", 8),
+                                     ("int8", 32)])
+def test_a_span_is_one_wait_a_stream_and_its_copies_lowered_unrolled(pool,
+                                                                     bs):
+    """What the chip showed binds the kernel is the scalar core issuing
+    copies: a span's blocks are started in a loop traced once and lowered
+    unrolled, at three sites (the call's first span, and the next span
+    into either buffer half, the half static), and waited for ONCE a
+    stream, through the one semaphore a stream and half they all signal."""
+    quant = pool == "int8"
+    sd = jax.ShapeDtypeStruct
+    args = [sd((3, 8, 1, 128), jnp.float32),
+            sd((40, bs, 256), jnp.dtype(pool)),
+            sd((40, bs, 256), jnp.dtype(pool)),
+            sd((3, 512 // bs), jnp.int32), sd((3, 1), jnp.int32)]
+    if quant:
+        args += [sd((40, bs, 2), jnp.float32)] * 2
+
+    def fn(q, kc, vc, t, p, *scales):
+        kw = dict(kscale=scales[0], vscale=scales[1]) if quant else {}
+        return paged_attention(q, kc, vc, t, p, block_size=bs, **kw)
+
+    eqns = list(_kernel_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    (call,) = [e for e, _ in eqns if e.primitive.name == "pallas_call"]
+    streams = 4 if quant else 2
+    # the semaphores: one a stream and half
+    sems = [v.aval for v in call.params["jaxpr"].invars
+            if "sem" in str(v.aval).lower()]
+    assert [tuple(a.shape) for a in sems] == [(streams, 2)]
+    waits = [inside for e, inside in eqns if e.primitive.name == "dma_wait"]
+    assert len(waits) == streams            # once a stream, in the trip
+    assert all(not any(i.primitive.name == "scan" for i in inside)
+               for inside in waits)
+    starts = [inside for e, inside in eqns
+              if e.primitive.name == "dma_start"]
+    assert len(starts) == 3 * streams
+    for inside in starts:
+        (loop,) = [i for i in inside if i.primitive.name == "scan"]
+        assert loop.params["length"] == loop.params["unroll"] == 16
 
 
 def test_quantize_roundtrip_error_bound():
