@@ -1007,7 +1007,16 @@ class PagedServing(NamedTuple):
     row. They ride the read-back the engine makes of the tokens anyway (a
     tick late under ``ahead``; a second transfer would cost 0.13 ms) and
     become attributes of that tick's ``engine.tick`` span, 0 where a tick
-    ran no decode."""
+    ran no decode.
+
+    ``windows``: each K/V layer's KIND, one entry a layer: ``None`` a full
+    layer (it attends every earlier position), else the window in positions
+    (``models/cohere2.py``). Layers of one window value are a GROUP of the
+    pool, with buffers sized by the window, a ring table a slot and blocks
+    handed back behind the window (``serve/slots.py``, "Layer kinds"); the
+    programs are then handed every group's table side by side
+    (``PagedKVPool.device_table``: the full group's ``ceil(max_len /
+    block)`` entries, then each ring). ``()``: every layer is full."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -1021,6 +1030,7 @@ class PagedServing(NamedTuple):
     block_forwards: Callable | None = None
     unpack_rows: Callable | None = None
     counters: tuple = ()
+    windows: tuple = ()
 
 
 # a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)

@@ -107,10 +107,13 @@ def layer_norm_init(d: int, dtype=jnp.float32) -> dict:
 
 
 def layer_norm(params: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
-    """LayerNorm over the trailing feature axis."""
+    """LayerNorm over the trailing feature axis. ``params["bias"]`` may be
+    absent (a bias-free norm, ``models/cohere2.py``): the result is then
+    ``(x - mean) / sqrt(var + eps) * scale`` and nothing is added."""
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * params["scale"] + params["bias"]
+    y = (x - mu) * jax.lax.rsqrt(var + eps) * params["scale"]
+    return y + params["bias"] if "bias" in params else y
 
 
 def embedding_init(key: jax.Array, vocab: int, d: int,
@@ -173,15 +176,17 @@ def gated_mlp(params: dict, x: jax.Array) -> jax.Array:
 
 
 def rotary(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-           rotated: int | None = None) -> jax.Array:
+           rotated: int | None = None, interleaved: bool = False) -> jax.Array:
     """Rotary position embedding over the first ``rotated`` lanes of every
     head (the whole head where ``None``; a model with a
     ``partial_rotary_factor`` rotates that share and passes the rest
     through), rotate-half convention (the two halves of the rotated lanes
     are the rotation's pairs): ``x [..., T, H, dh]`` at ``positions [...,
     T]`` becomes ``x * cos + (-x2, x1) * sin`` there, with angle ``positions
-    * theta ** (-2i / rotated)`` for pair ``i``. Float32; no scaling of the
-    frequencies."""
+    * theta ** (-2i / rotated)`` for pair ``i``. ``interleaved``
+    (``rope_gptj``): pair ``i`` is the NEIGHBOURING lanes ``(2i, 2i + 1)``
+    instead of ``(i, i + rotated / 2)``, same angles. Float32; no scaling of
+    the frequencies."""
     dh = x.shape[-1]
     rotated = dh if rotated is None else rotated
     if not 2 <= rotated <= dh or rotated % 2:
@@ -193,8 +198,14 @@ def rotary(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
     ang = positions[..., None].astype(jnp.float32) * inv    # [..., T, r/2]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x = x.astype(jnp.float32)
-    x1, x2 = x[..., :half], x[..., half:rotated]
-    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if interleaved:
+        pairs = x[..., :rotated].reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        parts = [jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(*x.shape[:-1], rotated)]
+    else:
+        x1, x2 = x[..., :half], x[..., half:rotated]
+        parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
     if rotated < dh:
         parts.append(x[..., rotated:])
     return jnp.concatenate(parts, axis=-1)

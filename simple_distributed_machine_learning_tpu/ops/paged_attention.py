@@ -36,6 +36,18 @@ kernel pass (the structure of JAX's own paged-attention kernel):
 - **nothing past a slot's length**: a span's blocks past the newest query
   position are fetched as the newest block again and removed by the
   position mask; a table entry past it is never read;
+- **a window layer's walk** (``window=``): a layer whose queries look
+  back ``window`` positions and no further (query ``t`` attends keys ``j``
+  with ``t - window < j <= t``) starts at the span that holds the oldest
+  query's ``qpos - window + 1``, and the mask adds ``qpos - j < window``.
+  Its table is a RING (``serve/slots.py``, "Layer kinds"): logical block
+  ``j`` lives at entry ``j % NB``, and an entry whose block lies wholly
+  behind the window has been handed back to the pool (it reads TRASH, or
+  already names the block of ``j + NB``). So the first span's blocks
+  before the first live one fetch THAT one again, as the blocks past the
+  newest fetch the newest: no block behind the window is ever fetched,
+  and the position mask, which counts logical positions, removes what the
+  stand-ins hold. With ``window=None`` none of this is traced;
 - **fused dequantization**: int8/fp8 K/V blocks carry per-row (position x
   head) f32 scales, copied beside them; the kernel multiplies them back in
   VMEM right after the load, so a quantized pool pays the narrow dtype's
@@ -121,7 +133,8 @@ def _whole_lanes(a):
 
 def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
                        n_q: int, scale: float, quant: bool, n_sub: int,
-                       nested: bool = False):
+                       nested: bool = False, window: int | None = None,
+                       ring: int = 0):
     """One slot: a loop over the slot's live spans of ``n_sub`` blocks.
 
     ``q_ref``: [1, H, R, dh], this slot's query rows, all heads: row ``r``
@@ -135,7 +148,9 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
     (one a stream and half: a span's blocks all signal it), and ``first``
     (SMEM [1]): the buffer half that holds this slot's first span. ``H``
     and ``dh`` are the CALL's: the wrapper hands a rows-in-lanes pool over
-    as one stream (``H = 1``) whose ``dh`` is the whole row."""
+    as one stream (``H = 1``) whose ``dh`` is the whole row. ``window``: the
+    layer's window in positions (module docstring), its table a ring of
+    ``ring`` entries; every line it adds is under ``window is not None``."""
     n_streams = 4 if quant else 2
     hbm, rest = rest[:n_streams], rest[n_streams:]
     o_ref, rest = rest[0], rest[1:]
@@ -143,6 +158,15 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
     s_idx = pl.program_id(0)
     span = n_sub * bs
     trips = lax.div(qpos_ref[s_idx, n_q - 1], span) + 1  # newest position
+
+    def live_from(slot):
+        """The first position ``slot``'s oldest query still sees."""
+        return lax.max(qpos_ref[slot, 0] - (window - 1), 0)
+
+    def trip0(slot):
+        """The span ``slot``'s walk starts at: 0, or (a window layer) the
+        one that holds the oldest query's ``qpos - window + 1``."""
+        return 0 if window is None else lax.div(live_from(slot), span)
 
     def start(slot, it, half: int):
         """Start the DMAs of ``slot``'s span ``it`` into buffer half
@@ -157,8 +181,15 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
         (PR 33), the rolled loop a quarter of a call."""
         last_blk = lax.div(qpos_ref[slot, n_q - 1], bs)
 
+        if window is not None:
+            first_blk = lax.div(live_from(slot), bs)
+
         def block(g, _):
-            blk = tables_ref[slot, lax.min(it * n_sub + g, last_blk)]
+            if window is None:
+                blk = tables_ref[slot, lax.min(it * n_sub + g, last_blk)]
+            else:
+                blk = tables_ref[slot, lax.rem(lax.max(lax.min(
+                    it * n_sub + g, last_blk), first_blk), ring)]
             at = pl.ds(pl.multiple_of(g * bs, bs), bs)
             for w, (ref, buf) in enumerate(zip(hbm, bufs)):
                 dst = buf.at[half, :, at] if w < 2 else buf.at[half, g]
@@ -188,7 +219,7 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
         @pl.when(s_idx == 0)
         def _first():           # nobody before the first slot fetched for it
             first[0] = 0
-            start(0, 0, 0)
+            start(0, trip0(0), 0)
 
         q = q_ref[0].astype(jnp.float32)                  # [H, R, dh]
         H, R, dh = q.shape
@@ -199,13 +230,15 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
             qp = jnp.where(row == j, qpos_ref[s_idx, j], qp)
         half0 = first[0]
         more = s_idx + 1 < pl.num_programs(0)
+        it0 = trip0(s_idx)
+        since = lambda it: it if window is None else it - it0  # noqa: E731
 
         def trip(it, carry):
             """Span ``it``: start what comes after it, wait for it, fold it
             into the online-softmax state ``(m, l, acc)``: [H, R, 1] twice
             and [H, R, dh], float32."""
             m_prev, l_prev, acc = carry
-            half = lax.rem(half0 + it, 2)
+            half = lax.rem(half0 + since(it), 2)
             # in flight meanwhile: the slot's next span, and under its last
             # span the NEXT slot's first one, so that a call waits for a
             # copy it has not overlapped once, not once a slot
@@ -215,7 +248,10 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
                 @pl.when((mine | more) & (half == h))
                 def _next(h=h):
                     start(jnp.where(mine, s_idx, s_idx + 1),
-                          jnp.where(mine, it + 1, 0), 1 - h)
+                          jnp.where(mine, it + 1, 0 if window is None else
+                                    trip0(lax.min(s_idx + 1,
+                                                  pl.num_programs(0) - 1))),
+                          1 - h)
 
             wait(half)
             k = rows(bufs[0], bufs[2] if quant else None, half)
@@ -226,6 +262,8 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
             kpos = it * span + lax.broadcasted_iota(
                 jnp.int32, (1, R, span), 2)
             mask = kpos <= qp                             # [1, R, span]
+            if window is not None:
+                mask &= qp - kpos < window
             s = jnp.where(mask, s, NEG_INF)               # [H, R, span]
             m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
             p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
@@ -235,11 +273,11 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
             return (m_new, l_prev * corr + p.sum(axis=2, keepdims=True),
                     acc)
 
-        _, l, acc = lax.fori_loop(0, trips, trip, (
+        _, l, acc = lax.fori_loop(it0, trips, trip, (
             jnp.full((H, R, 1), NEG_INF, jnp.float32),
             jnp.zeros((H, R, 1), jnp.float32),
             jnp.zeros((H, R, dh), jnp.float32)))
-        first[0] = lax.rem(half0 + trips, 2)    # where the next slot starts
+        first[0] = lax.rem(half0 + since(trips), 2)  # the next slot's start
         o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
     # ``nested``: the interpreter under ``shard_map`` alone. It holds the
@@ -250,12 +288,13 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
 
 
 def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
-                   interpret=None):
+                   interpret=None, window=None):
     """The Pallas call over head-major operands: ``q`` [S, H, K, dh],
     ``kc``/``vc`` [n_blocks+1, H, bs, dh], scales [n_blocks+1, H, bs] or
     None, ``qpos`` [S, n_q]: query row ``r`` of a head stands at position
     ``qpos[s, r % n_q]`` (``K`` a multiple of ``n_q``), and the last
-    column is the slot's newest position. Returns f32 [S, H, K, dh]."""
+    column is the slot's newest position. ``window``: ``tables`` is a
+    window layer's ring (module docstring). Returns f32 [S, H, K, dh]."""
     if interpret is None:
         interpret = _interpret()
     NB = tables.shape[1]
@@ -301,10 +340,11 @@ def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
         out_specs=pl.BlockSpec((1, H, K, dh), _q_idx),
         scratch_shapes=scratch,
     )
+    windowed = {} if window is None else {"window": window, "ring": NB}
     return pl.pallas_call(
         functools.partial(_paged_attn_kernel, bs=bs, n_q=qpos.shape[1],
                           scale=scale, quant=quant, n_sub=n_sub,
-                          nested=bool(interpret and vma)),
+                          nested=bool(interpret and vma), **windowed),
         grid_spec=grid_spec,
         out_shape=_struct((S, H, K, dh), jnp.float32, vma),
         # in order: a slot fetches its successor's first span
@@ -322,7 +362,8 @@ def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
 def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
                     tables: jax.Array, qpos: jax.Array, *,
                     block_size: int, kscale: jax.Array | None = None,
-                    vscale: jax.Array | None = None) -> jax.Array:
+                    vscale: jax.Array | None = None,
+                    window: int | None = None) -> jax.Array:
     """Fused paged attention over one layer's physical block pool.
 
     ``q``: [S, H, K, dh] queries (K = 1 for the flash-decode tick, the
@@ -334,6 +375,15 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     ``pos + j`` plan). With a quantized pool pass ``kscale``/``vscale``
     [n_blocks+1, bs, KVH] — the per-(position, head) f32 dequant scales —
     and int8/fp8 ``kc``/``vc``.
+
+    ``window``: the layer attends ``window`` positions back and no further
+    (query ``t`` sees keys ``t - window < j <= t``), and ``tables`` is its
+    group's RING (``serve/slots.py::PagedKVPool.device_table(slot, g)``):
+    logical block ``j`` at entry ``j % NB``. An entry whose block lies
+    wholly behind the oldest query's window is a RELEASED block's: it may
+    read TRASH or already name a newer block, and the kernel never looks
+    it up (module docstring). ``NB * block_size`` must cover ``window`` and
+    the ``K`` query positions; plain pools only (no scale planes).
 
     The pool is handed to the kernel as it lies: ONE K/V stream whose row
     is the whole ``KVH*dh`` lanes, the ``H`` query heads its group rows.
@@ -360,13 +410,21 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     the same shapes, and traces and lowers the kernel once for all of them
     (36 lowerings of it were 6 s of ``gpt2-large.serve-closed``'s set-up).
     """
-    return _paged_attention(q, kc, vc, tables, qpos, kscale, vscale,
-                            bs=int(block_size), interpret=_interpret())
+    if window is None:
+        return _paged_attention(q, kc, vc, tables, qpos, kscale, vscale,
+                                bs=int(block_size), interpret=_interpret())
+    if kscale is not None or vscale is not None:
+        raise ValueError("a window layer's pool carries no scale planes")
+    if window < 1:
+        raise ValueError(f"window must be >= 1 position, got {window}")
+    return _paged_attention(q, kc, vc, tables, qpos, None, None,
+                            bs=int(block_size), interpret=_interpret(),
+                            window=int(window))
 
 
-@functools.partial(jax.jit, static_argnames=("bs", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bs", "interpret", "window"))
 def _paged_attention(q, kc, vc, tables, qpos, kscale, vscale, *, bs,
-                     interpret):
+                     interpret, window=None):
     S, H, n_q, dh = q.shape
     if kc.ndim != 3 or kc.shape[1] != bs:
         raise ValueError(f"kc must be [n_blocks+1, {bs}, KVH*dh], got "
@@ -401,7 +459,7 @@ def _paged_attention(q, kc, vc, tables, qpos, kscale, vscale, *, bs,
         q.reshape(S, 1, kvh * rows, width), kc[:, None], vc[:, None],
         tables, qpos, bs, scale,
         kscale.reshape(n_phys, 1, bs) if quant else None,
-        vscale.reshape(n_phys, 1, bs) if quant else None, interpret)
+        vscale.reshape(n_phys, 1, bs) if quant else None, interpret, window)
     out = out.reshape(S, kvh, rows, kvh, dh)
     if kvh > 1:
         out = jnp.moveaxis(jnp.diagonal(out, axis1=1, axis2=3), -1, 1)

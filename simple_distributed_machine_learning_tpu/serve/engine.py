@@ -200,6 +200,39 @@ def _refuse_for_block_steps(*, host_cache_blocks, draft_stages,
                 f"diffusion over blocks: {reason}")
 
 
+def _refuse_for_window_layers(*, host_cache_blocks, draft_stages, lint,
+                              adapters) -> None:
+    """A model with window layers beside full ones
+    (``PagedServing.windows``) serves through the paged pool's groups
+    (``serve/slots.py``, "Layer kinds"); what was built for blocks that live
+    as long as their request is refused by name, as
+    :func:`_refuse_for_recurrent_state` does (``mesh`` and a quantized
+    ``cache_dtype`` reach the model's own ``paged_serving`` and the pool,
+    which refuse them in the same words)."""
+    why = {
+        "host_cache_blocks": (
+            bool(host_cache_blocks),
+            "the host offload tier demotes prefix blocks, and a window "
+            "layer has handed its share of a prefix back"),
+        "draft_stages (speculative decoding)": (
+            draft_stages is not None,
+            "a rejected draft token's window blocks may already have been "
+            "handed back behind it"),
+        "adapters": (
+            adapters is not None,
+            "the LoRA bank rides GPT's wq / wv (models/lora.py)"),
+        "lint=True": (
+            bool(lint),
+            "the analyzer's program registry (analysis/programs.py) builds "
+            "GPT's programs"),
+    }
+    for name, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{name} is not available with a model that has window "
+                f"layers: {reason}")
+
+
 class InferenceEngine:
     """Continuous-batching serving over a single-device model build.
 
@@ -281,12 +314,28 @@ class InferenceEngine:
     the tokens up to the end of its block of ``B``). A request preempted or
     restored mid-block starts that block again from masks; its committed
     tokens stay. Refused by name: the same six options.
+
+    A model whose attention layers are of several KINDS
+    (``PagedServing.windows``, ``models/cohere2.py``: window layers beside
+    full ones) is served by the pool's groups (``serve/slots.py``, "Layer
+    kinds"): ``n_window_blocks`` is a window group's block count (default:
+    every slot can hold its window, a prefill chunk and one block more),
+    the programs are handed every group's table side by side
+    (``pool.device_table``), the blocks of a program's rows are asked for
+    with the oldest of them (``ensure_writable(oldest=)``: what lies behind
+    that query's window is handed back first), and every ``engine.tick``
+    span carries ``kv_window_positions``, ``kv_window_blocks`` and
+    ``kv_full_blocks``, ``engine.admit`` ``window_released``. Refused by
+    name ("window layers"): the host tier, drafts, adapters, ``lint=True``
+    here, ``mesh`` and a quantized ``cache_dtype`` by the model and the
+    pool; no prefix is shared.
     """
 
     def __init__(self, stages, cfg, *, params=None, n_slots: int = 4,
                  max_len: int | None = None, cache_dtype=None,
                  block_size: int = 16,
                  n_blocks: int | None = None, prefill_chunk: int | None = None,
+                 n_window_blocks: int | None = None,
                  host_cache_blocks: int = 0, prefetch_ticks: int = 1,
                  attn_kernel: str = "dense",
                  metrics: ServeMetrics | None = None,
@@ -346,6 +395,10 @@ class InferenceEngine:
             stages, self.max_len, block_size, cache_dtype, mesh=mesh,
             kernel=attn_kernel, adapters=adp)
         self._n_layers = serving.kv_layers
+        if any(w is not None for w in serving.windows):
+            _refuse_for_window_layers(
+                host_cache_blocks=host_cache_blocks,
+                draft_stages=draft_stages, lint=lint, adapters=adapters)
         # positions a slot's step works on (PagedServing.block)
         self._block = int(serving.block)
         if self._block > 1:
@@ -370,6 +423,9 @@ class InferenceEngine:
         # the summed lengths of those slots: the K/V positions a layer of
         # that decode read
         self._kv_positions = 0
+        # and, of those, what a window layer read: each slot's length or
+        # the window, whichever is less (0 without a window group)
+        self._kv_window_positions = 0
         self.pool = PagedKVPool(self._n_layers, n_slots, serving.kv_heads,
                                 self.max_len, serving.head_dim, cache_dtype,
                                 block_size=block_size, n_blocks=n_blocks,
@@ -378,7 +434,15 @@ class InferenceEngine:
                                 prefetch_ticks=prefetch_ticks,
                                 state_shapes=serving.state_shapes,
                                 recurrent=cfg.recurrent_state,
-                                step_rows=self._block)
+                                step_rows=self._block,
+                                windows=serving.windows,
+                                n_window_blocks=n_window_blocks,
+                                chunk_rows=prefill_chunk)
+        # the pool's narrowest window group, where it has one: what the
+        # tick's window counts are of
+        self._window_group = (self.pool.window_groups[0]
+                              if self.pool.windowed else None)
+        self._window_released = 0
         self._chunk_prefill = serving.chunk_prefill
         self._decode = serving.decode
         self._pack_chunk = serving.pack_chunk
@@ -775,6 +839,13 @@ class InferenceEngine:
             admit.set(boarded=self._admit(),
                       prefix_declined=(self.pool.prefix_declined_total
                                        - declined))
+            if self._window_group is not None:
+                # blocks handed back behind a window since the last tick's
+                # admission (the hand-backs happen where a program's rows
+                # are allocated: counted here, a tick late)
+                released = self.pool.window_released_total
+                admit.set(window_released=released - self._window_released)
+                self._window_released = released
         chunk = int(bool(self._prefilling))
         ahead = 0
         if self._dispatch_ahead:
@@ -810,6 +881,13 @@ class InferenceEngine:
                kv_blocks=self.pool.blocks_in_use,
                sampling=self._sampling if decode_active else 0,
                kv_positions=self._kv_positions if decode_active else 0)
+        if self._window_group is not None:
+            # what ONE window layer and ONE full layer hold at the tick's
+            # end, and what the window layer's decode read
+            sp.set(kv_window_positions=(self._kv_window_positions
+                                        if decode_active else 0),
+                   kv_window_blocks=self._window_group.blocks_in_use,
+                   kv_full_blocks=self.pool.blocks_in_use)
         if self._counter_names:
             # what the tick's decode run counted (0 where it ran none)
             sp.set(**(self._counted if decode_active
@@ -850,12 +928,13 @@ class InferenceEngine:
                 n = int(self.pool.positions[s]) + (r.rid in in_flight)
             if n > 0:
                 rows.append(n)
-        if self.pool.recurrent or self._block > 1:
+        if self.pool.recurrent or self._block > 1 or self.pool.windowed:
             # the analyzer's model is GPT's (one head count, every layer
             # attends); here the pool's own block bytes make the prediction
+            # (a window group's: the most its ring holds, so the drift is
+            # at most 0 there)
             return (self.pool.bytes_resident(),
-                    sum(self.pool.blocks_for(n) for n in rows)
-                    * self.pool.bytes_per_block)
+                    sum(self.pool.bytes_for_rows(n) for n in rows))
         if self._predict is None:
             from simple_distributed_machine_learning_tpu.analysis.programs import (  # noqa: E501
                 engine_spec,
@@ -1220,22 +1299,28 @@ class InferenceEngine:
             [(s, int(self.pool.positions[s])) for s in active]))
 
     def _emit_tick(self, active: list[int], out, kd2, run: int,
-                   sampling: int, kv_positions: int) -> int:
+                   sampling: int, kv_positions: int,
+                   kv_window_positions: int) -> int:
         """Read one decode back (run ``run``, ``sampling`` of whose slots
-        sample, over ``kv_positions`` cached positions) and account it: a
-        token a slot, or (block steps) a forward a slot."""
+        sample, over ``kv_positions`` cached positions,
+        ``kv_window_positions`` of them inside a window layer's window)
+        and account it: a token a slot, or (block steps) a forward a
+        slot."""
         self._sampling = sampling
         self._kv_positions = kv_positions
+        self._kv_window_positions = kv_window_positions
         emit = self._emit_block if self._block > 1 else self._emit_decoded
         return emit(active, out, kd2, run)
 
     def _decode_dispatch(self, seats: list[tuple[int, int]]):
         """Launch one decode over ``seats``, ``(slot, position)`` of every
         slot that takes part: ``(slots, tokens, key_data, run, sampling,
-        kv_positions)`` as :meth:`_emit_tick` takes them, tokens and keys
-        still on the device, ``sampling`` the slots among them whose
-        temperature is above 0, ``kv_positions`` their lengths summed, the
-        rows this step writes included."""
+        kv_positions, kv_window_positions)`` as :meth:`_emit_tick` takes
+        them, tokens and keys still on the device, ``sampling`` the slots
+        among them whose temperature is above 0, ``kv_positions`` their
+        lengths summed, the rows this step writes included, and
+        ``kv_window_positions`` the same sum with each length cut to the
+        pool's window (0 without a window group)."""
         S = self.pool.n_slots
         active = [s for s, _ in seats]
         with tracing.span("engine.decode.prepare"):
@@ -1244,7 +1329,7 @@ class InferenceEngine:
             # garbage write lands in the trash block no table references
             pos = np.zeros(S, np.int32)
             toks = np.zeros(S, np.int32)
-            tables = np.full((S, self.pool.blocks_per_seq),
+            tables = np.full((S, self.pool.table_width),
                              PagedKVPool.TRASH, np.int32)
             for s, p in seats:
                 # on-demand block allocation as this position advances (and
@@ -1276,8 +1361,11 @@ class InferenceEngine:
             toks2, kd2 = self._run_paged(
                 self._decode, self._pack_decode, toks, pos, tables, *live,
                 kd, temps, top_ks, top_ps, *bank_args)
+        window = (self._window_group.window if self._window_group is not None
+                  else 0)
         return (active, toks2, kd2, run, int(np.count_nonzero(temps > 0)),
-                sum(p + self._block for _, p in seats))
+                sum(p + self._block for _, p in seats),
+                sum(min(p + self._block, window) for _, p in seats))
 
     def _tick_ahead(self) -> tuple[int, int]:
         """The paged tick of a model whose programs keep the newest tokens
@@ -1369,7 +1457,10 @@ class InferenceEngine:
         ``[p0, p0+n)`` of ``slot``'s sequence; runs the device block copy
         the pool asks for."""
         for p in range(p0, p0 + n):
-            cp = self.pool.ensure_writable(slot, p)
+            # ``oldest``: the first of these rows is the oldest query of the
+            # program that writes them (a window group hands back what lies
+            # behind its window, serve/slots.py)
+            cp = self.pool.ensure_writable(slot, p, oldest=p0)
             if cp is not None:
                 src, dst = cp
                 self.pool.kc, self.pool.vc = self._copy_block(

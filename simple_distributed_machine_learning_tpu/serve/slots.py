@@ -45,6 +45,27 @@ its block in progress has had and how many it takes (``block_fwd`` /
 block, so only prefixes of whole pool blocks (a multiple of ``step_rows``)
 are registered and matched.
 
+Layer kinds (``windows``, ``models/gpt.py::PagedServing.windows``): a model
+whose attention layers do not all look back equally far has layers of
+several KINDS in one pool. Layers of one window value are a GROUP; the
+layers that attend every earlier position are the FULL group, and it is
+everything said above (a pool without windows has that group alone and is
+the pool as it always was). A window group (``PagedKVPool._WindowGroup``)
+has its own block count (``n_window_blocks``: its layers' buffers are sized
+by the window, never by ``max_len``), its own free list, its own reservation
+at admission (``min(rows, window + chunk_rows)`` positions and a block) and
+per slot a table of its own, a RING: logical block ``j`` of the sequence
+lives at entry ``j % ring``, ``ring`` the blocks that ``window +
+chunk_rows`` positions can touch. A position ``p`` is dead in the group once
+``p <= q - window`` for the OLDEST query ``q`` still to run (a chunk's
+first row, a decode's one row); a block whose positions are all dead is
+handed back (its ring entry reads TRASH) before the slot's next allocation
+in the group, so that no program is handed a block that lies wholly behind
+its window. A donor's window blocks are gone when a second request could
+share them, so such a pool registers and matches no prefix (counted, as
+for recurrent state), and has no host tier, no quantized dtype, no
+tensor-parallel placement and no block steps.
+
 The slot free list is invariant-guarded: acquiring an occupied slot or
 releasing a free one raises instead of silently corrupting a neighbor's
 cache, and the same discipline covers blocks — no double allocation, no
@@ -209,12 +230,104 @@ class PagedKVPool:
 
     TRASH = 0   # physical block 0: the garbage sink for non-decoding slots
 
+    class _WindowGroup:
+        """The layers of one window value in a :class:`PagedKVPool` (module
+        docstring, "Layer kinds"): which of the pool's buffers they are, their
+        blocks (``1 .. n_blocks``; block 0 is the group's trash block too),
+        and per slot the ring table, the first live and the next logical
+        block, and what is left of the admission's reservation."""
+
+        def __init__(self, window: int, layers: tuple, ring: int,
+                     n_blocks: int, n_slots: int,
+                     bytes_per_block: int) -> None:
+            self.window, self.layers, self.ring = window, layers, ring
+            self.n_blocks = n_blocks
+            self.bytes_per_block = bytes_per_block
+            self.free: list[int] = list(range(1, n_blocks + 1))[::-1]
+            self.tables = np.full((n_slots, ring), PagedKVPool.TRASH, np.int32)
+            # logical blocks [first, next) of a slot's sequence hold a block
+            self.first = np.zeros(n_slots, np.int64)
+            self.next = np.zeros(n_slots, np.int64)
+            self.resv = np.zeros(n_slots, np.int64)
+            self.reserved = 0
+            self.released_total = 0
+
+        @property
+        def blocks_in_use(self) -> int:
+            return self.n_blocks - len(self.free)
+
+        @property
+        def blocks_available(self) -> int:
+            return len(self.free) - self.reserved
+
+        def budget(self, rows: int, block_size: int) -> int:
+            """The most blocks a sequence of ``rows`` positions holds at
+            once."""
+            return min(math.ceil(rows / block_size), self.ring)
+
+        def begin(self, slot: int, budget: int) -> None:
+            if budget > self.blocks_available:
+                raise RuntimeError(
+                    f"begin_seq short of window blocks (need {budget}, have "
+                    f"{self.blocks_available}) — the scheduler must check "
+                    f"can_admit first")
+            self.resv[slot] = budget
+            self.reserved += budget
+
+        def _hand_back(self, slot: int, upto: int) -> int:
+            """Free ``slot``'s logical blocks before ``upto``; how many."""
+            first = int(self.first[slot])
+            for j in range(first, upto):
+                e = j % self.ring
+                self.free.append(int(self.tables[slot, e]))
+                self.tables[slot, e] = PagedKVPool.TRASH
+            self.first[slot] = max(first, upto)
+            return max(0, upto - first)
+
+        def end(self, slot: int) -> None:
+            self._hand_back(slot, int(self.next[slot]))
+            self.first[slot] = self.next[slot] = 0
+            self.reserved -= int(self.resv[slot])
+            self.resv[slot] = 0
+
+        def ensure(self, slot: int, position: int, oldest: int,
+                   block_size: int) -> None:
+            """Hand back ``slot``'s blocks that lie wholly behind the window of
+            the query at ``oldest``, then give ``position``'s block a home."""
+            n = self._hand_back(slot, min(
+                (oldest - self.window + 1) // block_size,
+                int(self.next[slot])))
+            # what is handed back may be allocated again: the budget is the
+            # most the sequence holds at once
+            self.resv[slot] += n
+            self.reserved += n
+            self.released_total += n
+            j = position // block_size
+            if j > self.next[slot]:     # pragma: no cover - guard
+                raise RuntimeError(
+                    f"slot {slot} write at position {position} skips logical "
+                    f"block {self.next[slot]} — positions must advance "
+                    f"contiguously")
+            if j < self.next[slot]:
+                return
+            if self.resv[slot] <= 0 or j - self.first[slot] >= self.ring:
+                raise RuntimeError(     # pragma: no cover - guard
+                    f"slot {slot} allocates past its window reservation: the "
+                    f"queries of one program reach back over more than "
+                    f"window + chunk_rows positions")
+            self.tables[slot, j % self.ring] = self.free.pop()
+            self.next[slot] = j + 1
+            self.resv[slot] -= 1
+            self.reserved -= 1
+
     def __init__(self, n_layers: int, n_slots: int, n_heads: int,
                  max_len: int, head_dim: int, cache_dtype=None,
                  block_size: int = 16, n_blocks: int | None = None,
                  tp: int = 1, host_cache_blocks: int = 0,
                  prefetch_ticks: int = 1, state_shapes=(),
-                 recurrent: bool = False, step_rows: int = 1) -> None:
+                 recurrent: bool = False, step_rows: int = 1,
+                 windows: tuple = (), n_window_blocks: int | None = None,
+                 chunk_rows: int | None = None) -> None:
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if max_len < 2:
@@ -284,12 +397,49 @@ class PagedKVPool:
         cd = _cache_dtype(cache_dtype)
         self.cache_dtype = cd
         self.quantized = _is_quantized_dtype(cache_dtype)
+        # layer kinds (module docstring): ``windows[li]`` is layer li's
+        # window in positions, None (or no entry) a full layer
+        windows = tuple(windows) or (None,) * n_layers
+        if len(windows) != n_layers or any(
+                w is not None and w < 1 for w in windows):
+            raise ValueError(
+                f"windows must name each of the {n_layers} K/V layers: "
+                f"None (full) or a window of >= 1 positions, got {windows}")
+        self.windows = windows
+        self.windowed = any(w is not None for w in windows)
+        # neither kind of pool registers or matches a prompt prefix
+        self._no_prefix = self.recurrent or self.windowed
+        if self.windowed:
+            self._refuse_for_window_layers(host_cache_blocks, cache_dtype,
+                                           self.tp, self.step_rows)
+        # the most positions one program writes, so the most its queries
+        # reach back beyond a window: a prefill chunk's rows
+        chunk_rows = max_len if chunk_rows is None else min(chunk_rows,
+                                                            max_len)
+        self.window_groups: list[PagedKVPool._WindowGroup] = []
+        for w in sorted({w for w in windows if w is not None}):
+            layers = tuple(li for li, x in enumerate(windows) if x == w)
+            ring = min(self.blocks_per_seq,
+                       math.ceil((w + chunk_rows) / block_size) + 1)
+            n = n_slots * ring if n_window_blocks is None else n_window_blocks
+            if n < ring:
+                raise ValueError(
+                    f"n_window_blocks={n} cannot hold even one sequence's "
+                    f"window ({ring} blocks of {block_size} for window {w} "
+                    f"and chunks of {chunk_rows})")
+            self.window_groups.append(self._WindowGroup(
+                w, layers, ring, n, n_slots, kv_block_bytes(
+                    len(layers), n_heads, block_size, head_dim, cd)))
+        n_full = sum(w is None for w in windows)
+
         # +1: physical block 0 is the trash block, never allocated. One
         # buffer a layer, a position's heads in one row: a layer's write
-        # touches no other layer, and no program re-lays a buffer out
-        shape = (n_blocks + 1, block_size, n_heads * head_dim)
-
-        def layer():
+        # touches no other layer, and no program re-lays a buffer out. A
+        # window layer's buffer has its group's blocks, not the pool's
+        def layer(li: int):
+            g = self._group_of(li)
+            shape = ((n_blocks if g is None else g.n_blocks) + 1, block_size,
+                     n_heads * head_dim)
             if not self.quantized:
                 return jnp.zeros(shape, cd)
             # narrow block data + per-(position, head) f32 scale planes as
@@ -299,8 +449,8 @@ class PagedKVPool:
             return QuantKV(jnp.zeros(shape, cd),
                            jnp.zeros((*shape[:2], n_heads), jnp.float32))
 
-        self.kc = tuple(layer() for _ in range(n_layers))
-        self.vc = tuple(layer() for _ in range(n_layers))
+        self.kc = tuple(layer(li) for li in range(n_layers))
+        self.vc = tuple(layer(li) for li in range(n_layers))
         self.state = jax.tree.map(
             lambda sd: jnp.zeros((n_slots, *sd.shape), sd.dtype),
             state_shapes)
@@ -316,7 +466,8 @@ class PagedKVPool:
         # the gauge tracks what one chip actually pins, which is the number
         # TP sharding exists to shrink — and what the analyzer's
         # predict_kv_bytes_resident must agree with per shard
-        self.bytes_per_block = kv_block_bytes(n_layers, n_heads // self.tp,
+        # (the FULL group's layers: a window group bills its own)
+        self.bytes_per_block = kv_block_bytes(n_full, n_heads // self.tp,
                                               block_size, head_dim, cd)
         # block bookkeeping (host-side, authoritative)
         self.ref = np.zeros(n_blocks + 1, np.int64)
@@ -373,6 +524,50 @@ class PagedKVPool:
                 make_paged_block_write,
             )
             self._write_block = make_paged_block_write()
+
+    # -- layer kinds -------------------------------------------------------
+
+    @staticmethod
+    def _refuse_for_window_layers(host_cache_blocks, cache_dtype, tp,
+                                  step_rows) -> None:
+        """What a pool with a window group refuses, by name (module
+        docstring, "Layer kinds")."""
+        from simple_distributed_machine_learning_tpu.models.gpt import (
+            _is_quantized_dtype,
+        )
+        for name, asked, reason in (
+                ("host_cache_blocks", bool(host_cache_blocks),
+                 "the host offload tier demotes prefix blocks, and a window "
+                 "layer has handed its share of a prefix back"),
+                ("a quantized cache_dtype", _is_quantized_dtype(cache_dtype),
+                 "the window walk of ops/paged_attention.py has no scale "
+                 "planes: use float32 or bfloat16"),
+                ("tp > 1", tp > 1,
+                 "the groups' buffers have no sharded placement"),
+                ("step_rows > 1 (block steps)", step_rows > 1,
+                 "a block in progress is rewritten, and a window group "
+                 "hands blocks back by the oldest query alone")):
+            if asked:
+                raise ValueError(
+                    f"{name} is not available with a pool that has window "
+                    f"layers: {reason}")
+
+    def _group_of(self, layer: int):
+        """Layer ``layer``'s window group, ``None`` for a full layer."""
+        w = self.windows[layer]
+        return None if w is None else next(
+            g for g in self.window_groups if g.window == w)
+
+    @property
+    def table_width(self) -> int:
+        """Entries of :meth:`device_table`'s row: the full group's table,
+        then each window group's ring."""
+        return self.blocks_per_seq + sum(g.ring for g in self.window_groups)
+
+    @property
+    def window_released_total(self) -> int:
+        """Blocks handed back behind a window in the pool's life."""
+        return sum(g.released_total for g in self.window_groups)
 
     # -- occupancy accounting ---------------------------------------------
 
@@ -458,7 +653,16 @@ class PagedKVPool:
         return len(self._free_blocks) + len(self._lru) - self._reserved
 
     def bytes_resident(self) -> int:
-        return self.blocks_in_use * self.bytes_per_block
+        return self.blocks_in_use * self.bytes_per_block + sum(
+            g.blocks_in_use * g.bytes_per_block for g in self.window_groups)
+
+    def bytes_for_rows(self, rows: int) -> int:
+        """The most bytes a sequence of ``rows`` written positions pins:
+        its blocks in the full group and, in a window group, no more than
+        the ring."""
+        n = self.blocks_for(rows)
+        return n * self.bytes_per_block + sum(
+            min(n, g.ring) * g.bytes_per_block for g in self.window_groups)
 
     def _rows_needed(self, prompt_len: int, max_new_tokens: int) -> int:
         # positions written: prefill [0, prompt_len) + one decode write per
@@ -504,10 +708,15 @@ class PagedKVPool:
         _shared_len, chain = self._probe_cached(request)
         n_shared_full = sum(1 for _, fill in chain if fill == self.block_size)
         n_shared_reclaimable = sum(1 for b, _ in chain if self.ref[b] == 0)
-        budget = self.blocks_for(
-            self._rows_needed(int(np.asarray(_bind_seq_of(request)).shape[0]),
-                              _bind_budget_of(request))) - n_shared_full
-        return max(0, budget - (self.blocks_available - n_shared_reclaimable))
+        rows = self._rows_needed(
+            int(np.asarray(_bind_seq_of(request)).shape[0]),
+            _bind_budget_of(request))
+        budget = self.blocks_for(rows) - n_shared_full
+        # each window group is asked for its own worst case
+        return max(0, budget - (self.blocks_available - n_shared_reclaimable)
+                   ) + sum(max(0, g.budget(rows, self.block_size)
+                               - g.blocks_available)
+                           for g in self.window_groups)
 
     def freeable_blocks(self, slot: int) -> int:
         """Blocks GUARANTEED back into availability-for-an-admission if
@@ -524,7 +733,9 @@ class PagedKVPool:
         nothing)."""
         return int(self._resv[slot]) + sum(
             1 for b in self.tables[slot]
-            if self.ref[b] == 1 and not self._cached.get(b))
+            if self.ref[b] == 1 and not self._cached.get(b)) + sum(
+            int(g.resv[slot] + g.next[slot] - g.first[slot])
+            for g in self.window_groups)
 
     def begin_seq(self, slot: int, prompt: np.ndarray,
                   max_new_tokens: int, ns: bytes = b"") -> int:
@@ -541,7 +752,7 @@ class PagedKVPool:
                 f"reservation — the previous sequence was never ended")
         prompt = np.asarray(prompt)
         self._slot_ns[slot] = ns
-        if self.recurrent and self._first_block_key(ns, prompt) \
+        if self._no_prefix and self._first_block_key(ns, prompt) \
                 in self._would_be:
             self.prefix_declined_total += 1
         shared_len, chain = self._probe_prefix(prompt, ns)
@@ -549,9 +760,10 @@ class PagedKVPool:
             self._ref_block(block)
             self.tables[slot].append(block)
         n_shared_full = sum(1 for _, fill in chain if fill == self.block_size)
-        budget = self.blocks_for(
-            self._rows_needed(int(prompt.shape[0]), max_new_tokens)
-        ) - n_shared_full
+        rows = self._rows_needed(int(prompt.shape[0]), max_new_tokens)
+        budget = self.blocks_for(rows) - n_shared_full
+        for g in self.window_groups:
+            g.begin(slot, g.budget(rows, self.block_size))
         if budget > self.blocks_available:
             raise RuntimeError(
                 f"begin_seq short of blocks (need {budget}, have "
@@ -594,15 +806,22 @@ class PagedKVPool:
         self._slot_ns[slot] = b""
         self._reserved -= int(self._resv[slot])
         self._resv[slot] = 0
+        for g in self.window_groups:
+            g.end(slot)
 
     # -- write-path allocation + copy-on-write -----------------------------
 
-    def ensure_writable(self, slot: int, position: int
+    def ensure_writable(self, slot: int, position: int,
+                        oldest: int | None = None
                         ) -> tuple[int, int] | None:
         """Make ``position``'s block privately writable by ``slot``'s
         sequence, allocating on demand as positions advance. Returns a
         ``(src, dst)`` physical pair when copy-on-write fired — the CALLER
         must copy the device block rows before writing — else ``None``.
+        ``oldest``: the position of the oldest query of the program that
+        will write ``position`` (a chunk's first row; ``position`` itself
+        where not given): a window group hands back what lies behind that
+        query's window before it allocates.
 
         In-place writes into a singly-referenced block drop any registered
         prefix whose covered rows extend past the write offset (the write
@@ -611,6 +830,9 @@ class PagedKVPool:
         if not 0 <= position < self.max_len:
             raise ValueError(f"position {position} outside [0, "
                              f"{self.max_len})")
+        for g in self.window_groups:
+            g.ensure(slot, position, position if oldest is None else oldest,
+                     self.block_size)
         table = self.tables[slot]
         j = position // self.block_size
         if j > len(table):          # pragma: no cover - guard
@@ -723,8 +945,10 @@ class PagedKVPool:
         ``ns`` (capped at ``prompt_len - 1`` so at least one position is
         always recomputed). Returns ``(shared_len, [(block, fill), ...])``
         without mutating."""
-        if self.recurrent:
-            return 0, []        # a block without its state is no prefix
+        if self._no_prefix:
+            # a block without its state, or without the window layers'
+            # share of the same positions, is no prefix
+            return 0, []
         prompt = np.asarray(prompt, np.int32)
         cap = int(prompt.shape[0]) - 1
         bs = self.block_size
@@ -772,7 +996,7 @@ class PagedKVPool:
         prompt = np.asarray(prompt, np.int32)
         ns = self._slot_ns[slot]
         bs = self.block_size
-        if self.recurrent:
+        if self._no_prefix:
             # nothing is published; only remembered, to count the matches
             # this pool has to decline
             key = self._first_block_key(ns, prompt)
@@ -1017,13 +1241,25 @@ class PagedKVPool:
 
     # -- tick inputs -------------------------------------------------------
 
-    def device_table(self, slot: int) -> np.ndarray:
+    def device_table(self, slot: int, group: int | None = None
+                     ) -> np.ndarray:
         """This slot's block table padded to the static program width with
-        trash entries (masked out by position in the compiled step)."""
+        trash entries (masked out by position in the compiled step).
+        ``group``: 0 the full group's table (logical block ``j`` at entry
+        ``j``), ``g >= 1`` window group ``g - 1``'s ring (logical block
+        ``j`` at entry ``j % ring``, TRASH where the block is not yet
+        written or already handed back); ``None``: all of them side by
+        side, ``table_width`` entries, which is the full group's table
+        alone in a pool without windows."""
+        if group:
+            return self.window_groups[group - 1].tables[slot].copy()
         t = np.full(self.blocks_per_seq, self.TRASH, np.int32)
         table = self.tables[slot]
         t[:len(table)] = table
-        return t
+        if group == 0 or not self.window_groups:
+            return t
+        return np.concatenate([t, *(g.tables[slot]
+                                    for g in self.window_groups)])
 
     def stats(self) -> dict:
         s = {
@@ -1040,6 +1276,15 @@ class PagedKVPool:
             s.update({
                 "state_bytes_resident":
                     self.n_active * self.state_bytes_per_slot,
+                "prefix_declined_total": self.prefix_declined_total,
+            })
+        if self.windowed:
+            s.update({
+                "window_blocks_total": [g.n_blocks
+                                        for g in self.window_groups],
+                "window_blocks_in_use": [g.blocks_in_use
+                                         for g in self.window_groups],
+                "window_released_total": self.window_released_total,
                 "prefix_declined_total": self.prefix_declined_total,
             })
         if self.host_cache_blocks:

@@ -546,6 +546,78 @@ def test_cca_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
                                                   layer)
 
 
+def _window_programs():
+    """``command-a-plus-05-2026.serve-mixed-closed``'s two programs as the
+    cell runs them: four layers (window, window, window, full), hidden
+    4096, 128 query heads over 8 K/V heads of 128, window 4,096, 16 held
+    experts of 128 beside four shared ones of 4096, 32,768 held rows under
+    the tied head, 16 slots of 32,768 positions, 32,768 full and 4,624
+    window bf16 blocks of 16, chunks of 512, the fused kernel."""
+    from simple_distributed_machine_learning_tpu.models.cohere2 import (
+        Cohere2Config,
+        make_cohere2_stages,
+        pack_chunk_inputs,
+        pack_decode_inputs,
+    )
+    import numpy as np
+    S, ml, bs, nb, nwb, c, ring = 16, 32768, 16, 32768, 4624, 512, 289
+    cfg = Cohere2Config(vocab=32768, seq_len=ml, d_model=4096, n_layers=4,
+                        n_heads=128, n_kv_heads=8, head_dim=128, window=4096,
+                        n_experts=128, top_k=8, experts_held=16, n_shared=4,
+                        d_expert=4096, param_dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda k: make_cohere2_stages(k, cfg)[0][0].params, jax.random.key(0))
+    serving = cfg.paged_serving([types.SimpleNamespace(params=params)], ml,
+                                bs, "bfloat16", kernel="fused")
+    pool = tuple(_sd(((nb if w is None else nwb) + 1, bs, cfg.d_kv),
+                     jnp.bfloat16) for w in serving.windows)
+    state = jax.tree.map(lambda sd: _sd((S, *sd.shape), sd.dtype),
+                         serving.state_shapes)
+    width = ml // bs + ring
+    z = np.zeros(S, np.int32)
+    host, = pack_decode_inputs(z, z, np.zeros((S, width), np.int32), z,
+                               None, z.astype(np.float32), z,
+                               z.astype(np.float32))
+    tokens, chost = pack_chunk_inputs(
+        np.zeros((1, c), np.int32), 0, np.zeros(width, np.int32), 0, -1,
+        np.zeros(2, np.uint32), 0.0, 0, 1.0)
+    return pool, {
+        "window-decode": (serving.decode, (
+            [params], pool, pool, state, _sd(host.shape, host.dtype))),
+        "window-chunk": (serving.chunk_prefill, (
+            [params], pool, pool, state, _sd(tokens.shape, tokens.dtype),
+            _sd(chost.shape, chost.dtype)))}
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("window-decode", {"paged_attention", "moe_experts"}),
+    ("window-chunk", {"moe_experts"})])
+def test_window_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
+                                                         program, kernels):
+    """The decode step and the prefill chunk of the sixth family, handed to
+    the chip's compiler whole: the kernel over a full layer's table of
+    2,048 entries and a window layer's ring of 289 at 16 query heads to a
+    K/V head (a query row block of 128 rows by 1,024 lanes), the 32 MiB
+    expert matrices, rows of 32,768 logits under the sampler. The pool and
+    the newest pair are donated: every byte is aliased input to output.
+    The chunk attends 512 positions a step over the live positions alone,
+    so what it holds beside its arguments stays under 1.5 GB (scores of
+    128 heads x 512 rows x 32,768 positions would be 8.6 GB)."""
+    pool, programs = _window_programs()
+    fn, args = programs[program]
+    compiled = fn.lower(*_on_chip(args, one_chip)).compile()
+    found = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert found == kernels
+    held = sum(math.prod(b.shape) * b.dtype.itemsize for b in pool)
+    state_bytes = sum(math.prod(sd.shape) * sd.dtype.itemsize
+                      for sd in jax.tree.leaves(args[3]))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * held + state_bytes
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+
+
 # -- flash attention: the train step's kernel -------------------------------
 
 _FLASH = [((8, 16, 512, 64), "bfloat16"),      # chip_smoke's train step

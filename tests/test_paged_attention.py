@@ -840,3 +840,166 @@ def test_query_heads_must_be_a_multiple_of_the_pools_heads():
     with pytest.raises(ValueError, match="do not divide"):
         paged_attention(q, _rows(kc), _rows(vc), jnp.asarray(tables),
                         jnp.asarray(pos[:, None]), block_size=4)
+
+
+# -- a window layer's walk (PR 44) ------------------------
+
+
+def _ring_call(seed, S, H, KVH, dh, bs, window, ring, K=1, extra=0):
+    """Slots of one call over a window layer's RING: slot ``s`` has written
+    positions ``0 .. last[s]`` of its own sequence into blocks of a pool of
+    ``1 + S * ring`` blocks (logical block ``j`` at entry ``j % ring``; a
+    block that lies wholly behind the oldest query's window has been handed
+    back: its entry reads TRASH, or already names the block of ``j +
+    ring``), and the whole sequence's rows for the dense reference."""
+    rng = np.random.default_rng(seed)
+    n_phys = 1 + S * ring
+    kc = np.zeros((n_phys, bs, KVH * dh), np.float32)
+    vc = np.zeros_like(kc)
+    # lengths below, at and many times the window, a whole ring and more
+    last = np.array(([0, window - 1, window, 3 * window + 1,
+                      ring * bs + window // 2, 9 * ring * bs + 5] * S)[:S],
+                    np.int64) + extra
+    n_seq = int(last.max()) + 1
+    keys = rng.standard_normal((S, n_seq, KVH * dh)).astype(np.float32)
+    vals = rng.standard_normal((S, n_seq, KVH * dh)).astype(np.float32)
+    tables = np.zeros((S, ring), np.int32)
+    qpos = np.stack([last - (K - 1 - j) for j in range(K)], axis=1)
+    qpos = np.maximum(qpos, 0).astype(np.int32)
+    for s in range(S):
+        first_live = max(int(qpos[s, 0]) - window + 1, 0) // bs
+        for j in range(first_live, int(last[s]) // bs + 1):
+            blk = 1 + s * ring + j % ring
+            tables[s, j % ring] = blk
+            n = min(bs, int(last[s]) + 1 - j * bs)
+            kc[blk, :n] = keys[s, j * bs:j * bs + n]
+            vc[blk, :n] = vals[s, j * bs:j * bs + n]
+    q = rng.standard_normal((S, H, K, dh)).astype(np.float32)
+    return q, kc, vc, tables, qpos, keys, vals
+
+
+def _dense_window_reference(q, keys, vals, qpos, window, KVH):
+    """Masked attention over each slot's whole sequence, the window a mask
+    built from positions: query ``t`` sees ``t - window < j <= t``."""
+    S, H, K, dh = q.shape
+    n_seq = keys.shape[1]
+    k = keys.reshape(S, n_seq, KVH, dh)
+    v = vals.reshape(S, n_seq, KVH, dh)
+    group = H // KVH
+    out = np.zeros((S, H, K, dh), np.float64)
+    for s in range(S):
+        back = qpos[s][:, None] - np.arange(n_seq)[None, :]
+        seen = (back >= 0) & (back < window)
+        for h in range(H):
+            sc = q[s, h].astype(np.float64) @ k[s, :, h // group].T.astype(
+                np.float64) / math.sqrt(dh)
+            sc = np.where(seen, sc, -np.inf)
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            out[s, h] = (p / p.sum(-1, keepdims=True)) @ v[s, :, h // group]
+    return out
+
+
+@pytest.mark.parametrize("H,KVH,dh,bs,window,ring,K", [
+    (4, 2, 16, 4, 8, 5, 1),        # the toy family of tests/test_cohere2.py
+    (4, 2, 16, 4, 8, 5, 3),        # several query rows: the oldest's window
+    (32, 2, 8, 16, 64, 6, 1),      # 16 query heads to a K/V head
+    (16, 1, 8, 8, 40, 7, 1),       # a window that is no whole blocks
+])
+def test_window_walk_matches_the_dense_mask_over_a_ring(H, KVH, dh, bs,
+                                                        window, ring, K):
+    """``window=``: slots below, at and many rings past the window in one
+    call, each against attention over its WHOLE sequence under the
+    position mask; what lies behind the window is not in the pool at all
+    (its entries read TRASH or a newer block), so a kernel that looked any
+    of it up would read zeros or the wrong rows."""
+    q, kc, vc, tables, qpos, keys, vals = _ring_call(
+        5, 6, H, KVH, dh, bs, window, ring, K)
+    out = paged_attention(jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+                          jnp.asarray(tables), jnp.asarray(qpos),
+                          block_size=bs, window=window)
+    want = _dense_window_reference(q, keys, vals, qpos, window, KVH)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+    # the window's edge: one key more and the comparison fails
+    wider = _dense_window_reference(q, keys, vals, qpos, window + 1, KVH)
+    assert np.abs(wider[3:] - want[3:]).max() > 1e-3
+
+
+def test_window_walk_over_a_full_layers_table_of_2048_entries():
+    """A table as long as the cell's full layer has (2,048 entries of 16,
+    here a window layer whose ring is the whole table: ``window + chunk``
+    past ``max_len``), 16 query heads to a K/V head and 8 K/V heads, a slot
+    30,000 positions deep: the walk starts 1,800 blocks in, and ``window=
+    None`` over the same table reads every position (the full layer)."""
+    H, KVH, dh, bs, NB, window = 128, 8, 8, 16, 2048, 4096
+    rng = np.random.default_rng(9)
+    last = np.array([29_999, 100, 4_095], np.int32)
+    S = len(last)
+    n_phys = 1 + int(sum(p // bs + 1 for p in last))
+    kc = rng.standard_normal((n_phys, bs, KVH * dh)).astype(np.float32)
+    vc = rng.standard_normal((n_phys, bs, KVH * dh)).astype(np.float32)
+    tables = np.zeros((S, NB), np.int32)
+    keys = np.zeros((S, int(last.max()) + 1, KVH * dh), np.float32)
+    vals = np.zeros_like(keys)
+    at = 1
+    for s in range(S):
+        n = int(last[s]) // bs + 1
+        tables[s, :n] = np.arange(at, at + n)
+        keys[s, :n * bs] = kc[at:at + n].reshape(n * bs, -1)[
+            :keys.shape[1]] if n * bs <= keys.shape[1] else kc[
+                at:at + n].reshape(n * bs, -1)[:keys.shape[1]]
+        vals[s, :min(n * bs, vals.shape[1])] = vc[at:at + n].reshape(
+            n * bs, -1)[:vals.shape[1]]
+        at += n
+    q = rng.standard_normal((S, H, 1, dh)).astype(np.float32)
+    qpos = last[:, None]
+    for w in (window, None):
+        out = paged_attention(jnp.asarray(q), jnp.asarray(kc),
+                              jnp.asarray(vc), jnp.asarray(tables),
+                              jnp.asarray(qpos), block_size=bs, window=w)
+        want = _dense_window_reference(q, keys, vals, qpos, w or 10 ** 9,
+                                       KVH)
+        np.testing.assert_allclose(np.asarray(out), want, rtol=3e-5,
+                                   atol=3e-5)
+
+
+#: sha256 of ``str(jax.make_jaxpr(...))`` of the kernel at two cells' decode
+#: shapes, made on the PARENT commit (2681516, PR 43) by the same lines as
+#: the test below: what ``window=None`` has to trace, operation for operation
+_PARENT_JAXPR = {
+    # gpt2-large.serve-closed: 16 slots, 20 heads of 64, tables of 64
+    (16, 20, 20, 64, 64, 513):
+        "df8aaecb2ac48f57c07a21d3b90d6676de46ffc4dc26fef8480aaf4f1fe24698",
+    # zaya1-8b.serve-context-closed: 24 slots, 8 heads over 2 of 128, 512
+    (24, 8, 2, 128, 512, 12289):
+        "8e165732e7aa69a58dba5d80505cc2d534dc2dc8097d2210935f69eadaf78d5f",
+}
+
+
+@pytest.mark.parametrize("shape", list(_PARENT_JAXPR))
+def test_without_a_window_the_kernel_traces_what_the_parent_traced(shape):
+    """The five serve cells' ``setup_s`` and ``tpot_p95_ms`` ride on this
+    kernel: with ``window=None`` its jaxpr (the Pallas call's body
+    included) is the parent commit's to the letter, at the shapes of the
+    GPT cell and of the long narrow cache. And with a window it is not."""
+    import hashlib
+    import re
+
+    from simple_distributed_machine_learning_tpu.ops import (
+        paged_attention as pa,
+    )
+    S, H, KVH, dh, NB, n_phys = shape
+    sd = jax.ShapeDtypeStruct
+    args = (sd((S, H, 1, dh), jnp.float32),
+            sd((n_phys, 16, KVH * dh), jnp.bfloat16),
+            sd((n_phys, 16, KVH * dh), jnp.bfloat16),
+            sd((S, NB), jnp.int32), sd((S, 1), jnp.int32))
+
+    def text(**kw):
+        fn = lambda q, k, v, t, p: pa._paged_attention.__wrapped__(  # noqa: E731
+            q, k, v, t, p, None, None, bs=16, interpret=False, **kw)
+        return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+
+    plain = text()
+    assert hashlib.sha256(plain.encode()).hexdigest() == _PARENT_JAXPR[shape]
+    assert text(window=None) == plain
+    assert text(window=64) != plain

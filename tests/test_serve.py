@@ -1066,3 +1066,66 @@ def test_bench_spec_beats_plain_2x():
     assert row["ticks_spec"] < row["ticks_plain"]
     for k in ("wall_tokens_per_sec_spec", "wall_tokens_per_sec_plain"):
         assert row[k] > 0
+
+
+def test_a_pool_without_windows_is_the_pool_it_always_was():
+    """``PagedKVPool(windows=)`` (PR 44: layer kinds): without the
+    argument, with ``()`` and with every layer named full the pool is one
+    group and, through a request's whole life (admission, allocation on
+    demand, a shared prefix and its copy-on-write, the end), field for
+    field and allocation for allocation the same; the numbers below are
+    the parent commit's, by hand."""
+    import types
+
+    from simple_distributed_machine_learning_tpu.serve.slots import (
+        kv_block_bytes,
+    )
+
+    def drive(**kw):
+        pool = PagedKVPool(2, 2, 2, 16, 4, block_size=4, n_blocks=6, **kw)
+        log = []
+        req = types.SimpleNamespace(prompt=np.arange(6, dtype=np.int32),
+                                    max_new_tokens=3)
+        log.append(pool.can_admit(req))
+        s = pool.acquire(7)
+        log.append(pool.begin_seq(s, req.prompt, 3))
+        for p in range(6):
+            log.append(pool.ensure_writable(s, p))
+        pool.register_prefix(s, req.prompt)
+        log.append((list(pool.tables[s]), pool.device_table(s).tolist(),
+                    pool.blocks_in_use, pool.blocks_available,
+                    int(pool._resv[s]), pool.bytes_resident()))
+        s2 = pool.acquire(8)
+        log.append(pool.begin_seq(s2, req.prompt, 3))
+        log.append(pool.ensure_writable(s2, 5, oldest=5))
+        log.append((list(pool.tables[s2]), pool.ref.tolist()))
+        pool.end_seq(s)
+        pool.end_seq(s2)
+        log.append((sorted(pool._free_blocks), pool.blocks_cached,
+                    pool.stats()))
+        return pool, log
+
+    plain, want = drive()
+    assert want[0] is True and want[1] == 0 and want[2:8] == [None] * 6
+    assert want[8] == ([1, 2], [1, 2, 0, 0], 2, 4, 0, 2 * 512)
+    # the second request shares the first block and takes a tail of its own
+    assert want[9] == 4 and want[10] is None
+    assert want[11] == ([1, 3], [0, 2, 1, 1, 0, 0, 0])
+    assert want[12] == ([3, 4, 5, 6], 2, {
+        "blocks_total": 6, "blocks_in_use": 0, "blocks_cached": 2,
+        "blocks_free": 4, "kv_bytes_resident": 0,
+        "prefix_hit_blocks_total": 1, "cow_copies_total": 0,
+        "evictions_total": 0})
+    assert not plain.windowed and plain.window_groups == []
+    assert plain.table_width == plain.blocks_per_seq == 4
+    assert plain.bytes_per_block == kv_block_bytes(2, 2, 4, 4) == 512
+    assert [k.shape for k in plain.kc] == [(7, 4, 8)] * 2
+    for kw in ({"windows": ()}, {"windows": (None, None)},
+               {"windows": (None, None), "n_window_blocks": 3,
+                "chunk_rows": 2}):
+        pool, got = drive(**kw)
+        assert got == want
+        assert [k.shape for k in pool.kc] == [(7, 4, 8)] * 2
+        assert pool.bytes_per_block == 512 and not pool.window_groups
+    with pytest.raises(ValueError, match="windows must name each"):
+        PagedKVPool(2, 2, 2, 16, 4, windows=(None,))
