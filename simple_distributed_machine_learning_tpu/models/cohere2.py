@@ -47,7 +47,34 @@ Precision as the other served families': matmul operands in the weights'
 dtype with float32 accumulation; the residual stream, the norm, the
 router's sigmoid and the attention in float32.
 
-Serving: the paged pool keeps the two layer kinds apart
+Serving: the two programs read the tree :func:`serve_params` makes of the
+stage's (``PagedServing.serve_params``; the engine applies it once, where it
+takes its parameters). It differs in a WINDOW layer's ``attn`` alone: in
+place of ``wq`` / ``wk`` it holds ``wq_halves`` / ``wk_halves``, the same
+columns with every head's lanes in the order even lanes first, then odd
+(``[0, 2, .., dh - 2, 1, 3, .., dh - 1]``; heads stay where they are). On
+that order the neighbouring pair ``(2i, 2i + 1)`` is the pair ``(i, i + dh /
+2)``, which ``rotary``'s default (rotate-half) form rotates by the same
+angle: ``q`` and ``k`` leave their products in the lane order their rotation
+reads. Why: ``rotary(..., interleaved=True)`` meets the pairs through a
+``[.., dh / 2, 2]`` view of a head, and the chip's compiler answered that
+view on the WEIGHT's side, reshaping the whole query matrix to ``[heads, dh
+/ 2, 2, d]`` in every window layer of every program run. Scores do not move
+(one permutation of both operands of a head's dot product); a window layer's
+K rows in the pool are the published rows in the HELD lane order
+(``serve/slots.py``, "Layer kinds"); V rows, ``wv``, ``wo``, the full layers
+and everything after attention are the stage's own. Either path refuses the
+other's tree by leaf name (:func:`_qkv`). In EVERY layer the programs also
+keep the two products whole behind an ``optimization_barrier`` until they
+are cut into heads: fused with that cut, a product came out heads-major over
+a transposed copy of its matrix, the full layer's too. Together that was 3.3
+ms of a 14.9 ms decode run and 3.65 of a 33.6 ms chunk run at hidden 4096
+and 128 heads of 128 (``PERF.md`` section 6, PR 45);
+``tests/test_chip_compile.py`` holds the compiled programs to "nothing but a
+product reads either matrix". The whole-sequence path (:func:`full_logits`,
+``Stage.apply``) reads the stage's tree and keeps the neighbouring-lane form.
+
+The paged pool keeps the two layer kinds apart
 (``PagedServing.windows``; ``serve/slots.py``, "Layer kinds"). A window
 layer's buffer holds ``window + chunk`` positions a slot, its table is a
 ring, and what lies behind a slot's window is handed back. The decode
@@ -215,7 +242,8 @@ class Cohere2Config:
                 lambda: _build_window_decode_step(self, block_size, nb_full,
                                                   kernel)),
             pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
-            ahead=True, counters=EXPERT_COUNTERS, windows=self.windows)
+            ahead=True, counters=EXPERT_COUNTERS, windows=self.windows,
+            serve_params=functools.partial(serve_params, cfg=self))
 
 
 def _validate_build(stages, cfg: Cohere2Config, max_len: int,
@@ -305,18 +333,39 @@ def _norm(weight, h, cfg: Cohere2Config):
     return layer_norm({"scale": weight.astype(jnp.float32)}, h, cfg.ln_eps)
 
 
-def _qkv(ap: dict, u, positions, window, cfg: Cohere2Config):
+def _qkv(ap: dict, u, positions, window, cfg: Cohere2Config,
+         held: bool = False):
     """``q [N, L, H, dh]``, ``k`` / ``v [N, L, KV, dh]``, float32, of
     normed ``u [N, L, d]`` at ``positions [N, L]``: rotary on ``q`` and
-    ``k`` in a window layer, no position in them at all in a full one."""
+    ``k`` in a window layer, no position in them at all in a full one.
+    ``held``: ``ap`` is a layer of the serving programs' tree
+    (:func:`serve_params`): a window layer's ``q`` and ``k`` come out of
+    ``wq_halves`` / ``wk_halves`` with every head's even lanes first, and
+    the rotation's pairs are the head's two halves."""
     n, n_tok, _ = u.shape
     dh = cfg.head_dim
-    q = matmul_acc32(u, ap["wq"]).reshape(n, n_tok, cfg.n_heads, dh)
-    k = matmul_acc32(u, ap["wk"]).reshape(n, n_tok, cfg.n_kv_heads, dh)
+    halves = held and window is not None
+    wq, wk = ("wq_halves", "wk_halves") if halves else ("wq", "wk")
+    if wq not in ap or wk not in ap:
+        raise ValueError(
+            f"a {'window' if window is not None else 'full'} layer's attn "
+            f"holds {sorted(ap)} where {wq!r} and {wk!r} are read: the "
+            f"serving programs take the tree of cohere2.serve_params "
+            f"(PagedServing.serve_params), full_logits the stage's own")
+    q, k = matmul_acc32(u, ap[wq]), matmul_acc32(u, ap[wk])
+    if held:
+        # the two products WHOLE, before they are cut into heads: fused with
+        # that cut, the chip's compiler lays a product's output out heads-
+        # major and transposes the whole matrix to feed it, in every layer
+        # of every run (0.41 ms for W_q, the full layer's too), then copies
+        # the few rows back two operations later (PERF.md section 6, PR 45)
+        q, k = jax.lax.optimization_barrier((q, k))
+    q = q.reshape(n, n_tok, cfg.n_heads, dh)
+    k = k.reshape(n, n_tok, cfg.n_kv_heads, dh)
     v = matmul_acc32(u, ap["wv"]).reshape(n, n_tok, cfg.n_kv_heads, dh)
     if window is not None:
-        q = rotary(q, positions, cfg.rope_theta, interleaved=True)
-        k = rotary(k, positions, cfg.rope_theta, interleaved=True)
+        q = rotary(q, positions, cfg.rope_theta, interleaved=not held)
+        k = rotary(k, positions, cfg.rope_theta, interleaved=not held)
     return q, k, v
 
 
@@ -375,6 +424,33 @@ def full_logits(params: dict, tokens, cfg: Cohere2Config):
 
 
 # -- serving: the two paged programs ------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames="dh")
+def _even_lanes_first(w, dh: int):
+    """``w [d, heads * dh]`` with every head's columns in the order ``[0, 2,
+    .., dh - 2, 1, 3, .., dh - 1]``: one transpose, no gather."""
+    d = w.shape[0]
+    return jnp.swapaxes(w.reshape(d, -1, dh // 2, 2), 2, 3).reshape(d, -1)
+
+
+def serve_params(params: list, cfg: Cohere2Config) -> list:
+    """The tree the two serving programs read (``PagedServing.
+    serve_params``), of the stages' ``params``: the same leaves, the very
+    arrays, but in a window layer's ``attn`` ``wq`` / ``wk`` give way to
+    ``wq_halves`` / ``wk_halves`` (the module's docstring, "Serving")."""
+    def layer(bp, window):
+        if window is None:
+            return bp
+        ap = dict(bp["attn"])
+        for name in ("wq", "wk"):
+            ap[name + "_halves"] = _even_lanes_first(ap.pop(name),
+                                                     dh=cfg.head_dim)
+        return {**bp, "attn": ap}
+
+    stage, = params                 # one stage: make_cohere2_stages
+    return [{**stage, "blocks": [layer(bp, w) for bp, w in zip(
+        stage["blocks"], cfg.windows)]}]
 
 
 def _group_tables(tables, windows, nb_full: int):
@@ -493,7 +569,7 @@ def _window_chunk_fwd(params, kc, vc, tokens, p0, table,
     tables = _group_tables(table[None], cfg.windows, nb_full)
     for li, (bp, window) in enumerate(zip(blocks, cfg.windows)):
         u = _norm(bp["norm"], h, cfg)
-        q, k, v = _qkv(bp["attn"], u, idx, window, cfg)
+        q, k, v = _qkv(bp["attn"], u, idx, window, cfg, held=True)
         phys, off = _entry(tables[li], idx // bs, window)[0], idx[0] % bs
         kc = _paged_scatter(kc, li, phys, off, k[0])
         vc = _paged_scatter(vc, li, phys, off, v[0])
@@ -539,7 +615,7 @@ def _window_decode_fwd(params, kc, vc, toks, pos, tables, live,
     rows = []
     for li, (bp, window) in enumerate(zip(blocks, cfg.windows)):
         u = _norm(bp["norm"], h, cfg)
-        q, k, v = _qkv(bp["attn"], u, qpos, window, cfg)
+        q, k, v = _qkv(bp["attn"], u, qpos, window, cfg, held=True)
         phys = _entry(tables[li], qpos // bs, window)[:, 0]
         kc = _paged_scatter(kc, li, phys, off, k[:, 0])
         vc = _paged_scatter(vc, li, phys, off, v[:, 0])

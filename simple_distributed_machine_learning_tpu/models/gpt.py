@@ -969,6 +969,18 @@ class PagedServing(NamedTuple):
     chip run, PR 28); a model may take them as one array, or leave behind
     what its program does not read.
 
+    ``serve_params``: where given, the two programs read not the stages'
+    parameter trees (``[s.params for s in stages]``, or the engine's
+    ``params=``) but what this function makes of that list, and the engine
+    calls it ONCE, where it takes its parameters: a layout of the same
+    weights that only the programs need (``models/cohere2.py`` holds a
+    window layer's query and key projections in the lane order their
+    rotation reads). Programs and layout travel together: whoever calls
+    ``chunk_prefill`` or ``decode`` by hand hands them
+    ``serve_params(params)``, and a program refuses by name a tree that did
+    not pass through it. ``None`` (every other family): the programs read
+    the stages' trees themselves and the engine keeps the list it was given.
+
     ``ahead``: the programs keep every slot's newest token and sampling key
     on the device, in the LAST pair of ``state_shapes``
     (:data:`NEWEST_PAIR`). The decode reads its ``toks`` and ``key_data``
@@ -1031,6 +1043,7 @@ class PagedServing(NamedTuple):
     unpack_rows: Callable | None = None
     counters: tuple = ()
     windows: tuple = ()
+    serve_params: Callable | None = None
 
 
 # a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)

@@ -239,7 +239,9 @@ class InferenceEngine:
     ``stages``/``cfg``: a ``make_gpt_stages`` build (dense-MLP, unsharded —
     the ``make_cached_decoder`` restrictions). ``params`` overrides the
     stages' init weights (e.g. checkpoint-restored trees from
-    ``Pipeline.unpack``). ``max_len`` caps each slot's prompt+generation
+    ``Pipeline.unpack``); where the model's programs read a layout of their
+    own (``PagedServing.serve_params``) the engine makes it of either, once,
+    and ``self.params`` is that. ``max_len`` caps each slot's prompt+generation
     budget (defaults to ``cfg.seq_len``); ``cache_dtype`` is the pool's
     storage dtype (bf16 halves pool memory, the ``_cache_dtype`` rule).
 
@@ -394,6 +396,10 @@ class InferenceEngine:
         serving = cfg.paged_serving(
             stages, self.max_len, block_size, cache_dtype, mesh=mesh,
             kernel=attn_kernel, adapters=adp)
+        # the layout the programs read, where it is not the stages' own
+        # (PagedServing.serve_params): made once, here
+        if serving.serve_params is not None:
+            self.params = serving.serve_params(self.params)
         self._n_layers = serving.kv_layers
         if any(w is not None for w in serving.windows):
             _refuse_for_window_layers(

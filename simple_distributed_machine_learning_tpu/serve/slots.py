@@ -64,7 +64,13 @@ in the group, so that no program is handed a block that lies wholly behind
 its window. A donor's window blocks are gone when a second request could
 share them, so such a pool registers and matches no prefix (counted, as
 for recurrent state), and has no host tier, no quantized dtype, no
-tensor-parallel placement and no block steps.
+tensor-parallel placement and no block steps. What a row HOLDS is the
+programs' affair, and the one model with window layers does not store the
+published lanes there: ``models/cohere2.py``'s programs write a window
+group's K rows with every head's lanes in the order their rotation reads
+(even lanes first, then odd: its docstring, "Serving"), while V rows and
+the full group's K rows are the published ones. The programs are the only
+readers of a row; whoever reads the pool by hand has to know.
 
 The slot free list is invariant-guarded: acquiring an occupied slot or
 releasing a free one raises instead of silently corrupting a neighbor's

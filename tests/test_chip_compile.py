@@ -569,6 +569,8 @@ def _window_programs():
         lambda k: make_cohere2_stages(k, cfg)[0][0].params, jax.random.key(0))
     serving = cfg.paged_serving([types.SimpleNamespace(params=params)], ml,
                                 bs, "bfloat16", kernel="fused")
+    # the tree the programs read (PagedServing.serve_params), as shapes
+    params, = jax.eval_shape(serving.serve_params, [params])
     pool = tuple(_sd(((nb if w is None else nwb) + 1, bs, cfg.d_kv),
                      jnp.bfloat16) for w in serving.windows)
     state = jax.tree.map(lambda sd: _sd((S, *sd.shape), sd.dtype),
@@ -589,6 +591,18 @@ def _window_programs():
             _sd(chost.shape, chost.dtype)))}
 
 
+_WINDOW_COMPILED = {}
+
+
+def _window_compiled(program, one_chip):
+    """One compile a program for the tests below (25 s each)."""
+    if program not in _WINDOW_COMPILED:
+        fn, args = _window_programs()[1][program]
+        _WINDOW_COMPILED[program] = fn.lower(
+            *_on_chip(args, one_chip)).compile()
+    return _WINDOW_COMPILED[program]
+
+
 @pytest.mark.parametrize("program,kernels", [
     ("window-decode", {"paged_attention", "moe_experts"}),
     ("window-chunk", {"moe_experts"})])
@@ -604,8 +618,8 @@ def test_window_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
     so what it holds beside its arguments stays under 1.5 GB (scores of
     128 heads x 512 rows x 32,768 positions would be 8.6 GB)."""
     pool, programs = _window_programs()
-    fn, args = programs[program]
-    compiled = fn.lower(*_on_chip(args, one_chip)).compile()
+    _, args = programs[program]
+    compiled = _window_compiled(program, one_chip)
     found = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln}
@@ -616,6 +630,31 @@ def test_window_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * held + state_bytes
     assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["window-decode", "window-chunk"])
+def test_window_programs_leave_the_projections_where_they_lie(
+        one_chip, mosaic, program):
+    """Nothing but a product reads a layer's query or key matrix: the
+    compiled text holds no operation that MOVES an array of either one's
+    size (``bf16[4096, 16384]`` is 134 MB, ``bf16[4096, 1024]`` 8.4 MB) in
+    whatever shape: no copy, transpose, reshape, slice, pad, concatenate or
+    convert. Until PR 45 every window layer's run transposed ``W_q``,
+    reshaped it to ``[128, 64, 2, 4096]`` (the neighbouring-lane rotary's
+    view, answered on the weight), and the full layer's transposed it
+    too: 3.3 ms of a 14.9 ms decode run on the
+    chip (``PERF.md`` section 6, PR 45). The compiler's own prefetches of a
+    matrix into faster memory (``copy-start``, ``slice-start``) are not
+    passes of the program's and are let be."""
+    moved = []
+    sizes = {4096 * 16384, 4096 * 1024}
+    for ln in _window_compiled(program, one_chip).as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = bf16\[([\d,]+)\]\S* "
+                     r"(copy|transpose|reshape|slice|dynamic-slice|pad|"
+                     r"concatenate|convert)\(", ln)
+        if m and math.prod(int(n) for n in m.group(2).split(",")) in sizes:
+            moved.append(f"{m.group(3)} {m.group(1)} bf16[{m.group(2)}]")
+    assert not moved, moved
 
 
 # -- flash attention: the train step's kernel -------------------------------

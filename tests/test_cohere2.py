@@ -112,6 +112,11 @@ def _prompt(seed, n):
         np.int32)
 
 
+def _lanes_held(dh=CFG.head_dim):
+    """A head's lanes as the serving programs hold them: even, then odd."""
+    return np.concatenate([np.arange(0, dh, 2), np.arange(1, dh, 2)])
+
+
 # -- the stage ------------------------
 
 
@@ -148,6 +153,43 @@ def test_a_lower_precision_or_a_window_run_as_full_fails_the_tolerances(
         assert np.abs(other - want).max() > 50 * F32["atol"]
         with pytest.raises(AssertionError):
             np.testing.assert_allclose(other, want, **F32)
+
+
+def _qkv_of_pr44(ap, u, positions, window, cfg):
+    """``models/cohere2.py::_qkv`` as it stood before the serving programs
+    got a tree of their own (PR 45)."""
+    from simple_distributed_machine_learning_tpu.ops.layers import (
+        matmul_acc32,
+    )
+    n, n_tok, _ = u.shape
+    dh = cfg.head_dim
+    q = matmul_acc32(u, ap["wq"]).reshape(n, n_tok, cfg.n_heads, dh)
+    k = matmul_acc32(u, ap["wk"]).reshape(n, n_tok, cfg.n_kv_heads, dh)
+    v = matmul_acc32(u, ap["wv"]).reshape(n, n_tok, cfg.n_kv_heads, dh)
+    if window is not None:
+        q = rotary(q, positions, cfg.rope_theta, interleaved=True)
+        k = rotary(k, positions, cfg.rope_theta, interleaved=True)
+    return q, k, v
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_full_logits_on_the_stages_tree_is_bit_for_bit_what_it_was(
+        dtype, monkeypatch):
+    """The whole-sequence path (``full_logits``, ``Stage.apply``) reads the
+    stage's own tree and keeps the neighbouring-lane rotary: the layout the
+    serving programs read is theirs alone."""
+    cfg = dataclasses.replace(CFG, param_dtype=dtype)
+    stage = _stages(cfg)[0]
+    toks = jnp.asarray(np.stack([_prompt(1, 40), _prompt(2, 40)]))
+    now = np.asarray(cohere2.full_logits(stage.params, toks, cfg))
+    applied = np.asarray(stage.apply(stage.params, jnp.pad(
+        toks, ((0, 0), (0, cfg.seq_len - 40))), None, True))
+    monkeypatch.setattr(cohere2, "_qkv", _qkv_of_pr44)
+    np.testing.assert_array_equal(
+        now, np.asarray(cohere2.full_logits(stage.params, toks, cfg)))
+    np.testing.assert_array_equal(applied, np.asarray(stage.apply(
+        stage.params, jnp.pad(toks, ((0, 0), (0, cfg.seq_len - 40))), None,
+        True)))
 
 
 def test_more_than_one_stage_is_refused():
@@ -238,6 +280,34 @@ def test_interleaved_rotary_pairs_neighbouring_lanes_by_hand():
     np.testing.assert_allclose(
         np.asarray(rotary(y, pos, theta, interleaved=True)),
         np.asarray(back), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("dh", [16, 128])
+def test_default_rotary_over_even_lanes_first_is_the_neighbouring_form(dh):
+    """What the serving programs lean on (``cohere2.serve_params``): with a
+    head's lanes in the order ``[0, 2, .., dh - 2, 1, 3, .., dh - 1]`` the
+    neighbouring pair ``(2i, 2i + 1)`` is the pair ``(i, i + dh / 2)``, so
+    the default ``rotary`` over the permuted lanes is ``rotary(...,
+    interleaved=True)`` over the published ones, permuted the same way: by
+    hand with Python floats at one position, and bit for bit over random
+    heads (each lane is the same two products and their sum)."""
+    theta, t = 50000.0, 7
+    order = _lanes_held(dh)
+    x = np.linspace(-1.0, 2.0, dh).astype(np.float32)
+    want = np.empty(dh)
+    for i in range(dh // 2):
+        a = t * theta ** (-2 * i / dh)
+        want[2 * i] = x[2 * i] * np.cos(a) - x[2 * i + 1] * np.sin(a)
+        want[2 * i + 1] = x[2 * i + 1] * np.cos(a) + x[2 * i] * np.sin(a)
+    got = rotary(jnp.asarray(x[order])[None, None], jnp.asarray([t]),
+                 theta)[0, 0]
+    np.testing.assert_allclose(np.asarray(got), want[order], rtol=1e-5,
+                               atol=1e-5)
+    y = jax.random.normal(jax.random.key(dh), (2, 5, 3, dh))
+    pos = jnp.asarray([[0, 1, 4, 4095, 32767], [3, 9, 27, 81, 243]])
+    np.testing.assert_array_equal(
+        np.asarray(rotary(y[..., order], pos, theta)),
+        np.asarray(rotary(y, pos, theta, interleaved=True)[..., order]))
 
 
 def test_layer_norm_without_a_bias_is_the_formula():
@@ -605,6 +675,151 @@ def test_the_real_programs_serve_what_the_twins_serve(stages):
     got = [eng.submit(p, 14) for p in prompts]
     eng.drain()
     assert [h.tokens for h in got] == [h.tokens for h in want]
+
+
+# -- the tree the serving programs read ------------------------
+
+
+def test_serve_params_moves_a_window_layers_two_projections_and_no_more(
+        stages):
+    """``cohere2.serve_params``: every leaf of the stage's tree is the very
+    array, but a window layer's ``wq`` / ``wk``, which give way to
+    ``wq_halves`` / ``wk_halves``: the same columns, every head's even lanes
+    first."""
+    params = stages[0].params
+    held, = cohere2.serve_params([params], CFG)
+    order = _lanes_held()
+    for bp, hp, window in zip(params["blocks"], held["blocks"], CFG.windows):
+        if window is None:
+            assert hp is bp
+            continue
+        assert sorted(hp["attn"]) == ["wk_halves", "wo", "wq_halves", "wv"]
+        for name, heads in (("wq", CFG.n_heads), ("wk", CFG.n_kv_heads)):
+            cols = (np.arange(heads)[:, None] * CFG.head_dim + order).ravel()
+            np.testing.assert_array_equal(
+                np.asarray(hp["attn"][name + "_halves"]),
+                np.asarray(bp["attn"][name])[:, cols])
+        assert all(hp["attn"][k] is bp["attn"][k] for k in ("wv", "wo"))
+        assert all(hp[k] is bp[k] for k in ("norm", "moe", "shared"))
+    assert held["embed"] is params["embed"] and held["head"] is params["head"]
+
+
+def test_a_window_layers_k_rows_lie_in_the_held_lane_order(stages,
+                                                           monkeypatch):
+    """What the pool holds after a prompt's chunks and a few decode steps,
+    read by hand through the slot's tables: a window layer's K rows are the
+    published rows (``full_logits``' own ``k`` of the same sequence) with
+    every head's even lanes first, a full layer's K rows and EVERY V row
+    the published rows themselves (``serve/slots.py``, "Layer kinds")."""
+    eng = _engine(stages)
+    h = eng.submit(_prompt(6, 7), 6)
+    while len(h.tokens) < 2:
+        eng.step()
+    # 8 rows lie in the pool for sure (the prompt, the first token): under
+    # the window of 8, nothing handed back, no ring wrapped
+    seq = np.concatenate([_prompt(6, 7), np.asarray(h.tokens[:1], np.int32)])
+    seen, real = [], cohere2._qkv
+
+    def spy(*a, **kw):
+        seen.append(real(*a, **kw))
+        return seen[-1]
+
+    monkeypatch.setattr(cohere2, "_qkv", spy)
+    cohere2.full_logits(stages[0].params, jnp.asarray(seq)[None], CFG)
+    order = _lanes_held()
+    for li, ((_, k, v), window) in enumerate(zip(seen, CFG.windows)):
+        table = eng.pool.device_table(h.slot, 0 if window is None else 1)
+        at = (np.arange(len(seq)) // BS) % len(table), np.arange(len(seq)) % BS
+        rows_k = np.asarray(eng.pool.kc[li])[table[at[0]], at[1]]
+        rows_v = np.asarray(eng.pool.vc[li])[table[at[0]], at[1]]
+        k, v = np.asarray(k[0]), np.asarray(v[0])       # [T, KV, dh]
+        np.testing.assert_allclose(
+            rows_k, (k if window is None else k[..., order]).reshape(
+                len(seq), -1), **F32)
+        np.testing.assert_allclose(rows_v, v.reshape(len(seq), -1), **F32)
+        if window is not None:          # and the published order is not it
+            assert np.abs(rows_k - k.reshape(len(seq), -1)).max() > 0.1
+
+
+def test_a_tree_that_is_not_the_programs_own_is_refused_by_name(stages):
+    """The programs and their layout travel together
+    (``PagedServing.serve_params``): the stage's tree handed to the serving
+    programs, or the programs' tree to ``full_logits``, stops at the first
+    window layer with both leaf names in the message, and serves nothing."""
+    eng = _engine(stages)
+    assert eng.params is not None and "wq_halves" in (
+        eng.params[0]["blocks"][0]["attn"])
+    eng.params = [stages[0].params]
+    h = eng.submit(_prompt(3, 5), 2)
+    with pytest.raises(ValueError, match="'wq_halves'.*serve_params"):
+        eng.step()
+    assert not h.tokens
+    held = cohere2.serve_params([stages[0].params], CFG)
+    with pytest.raises(ValueError, match="'wq' and 'wk'.*serve_params"):
+        cohere2.full_logits(held[0], jnp.asarray(_prompt(3, 5))[None], CFG)
+
+
+def _toy(family):
+    """``(cfg, stages, engine keywords)`` of each served family's toy."""
+    from simple_distributed_machine_learning_tpu.models import (
+        gpt,
+        jamba,
+        nemotron_h,
+        sdar,
+        zaya,
+    )
+    key = jax.random.key(0)
+    kw = dict(n_slots=2, max_len=48, block_size=4, prefill_chunk=4)
+    if family == "gpt":
+        cfg = gpt.GPTConfig(vocab=64, seq_len=48, d_model=32, n_heads=2,
+                            n_layers=2)
+        return cfg, gpt.make_gpt_stages(key, cfg, 2)[0], kw
+    if family == "jamba":
+        cfg = jamba.JambaConfig(vocab=97, seq_len=48, d_model=64, expand=4)
+        return cfg, jamba.make_jamba_stages(key, cfg)[0], kw
+    if family == "sdar":
+        cfg = sdar.SdarConfig()
+        return cfg, sdar.make_sdar_stages(key, cfg)[0], dict(
+            n_slots=2, max_len=64, block_size=8, prefill_chunk=8)
+    if family == "nemotron_h":
+        cfg = nemotron_h.NemotronHConfig(pattern="ME*E", n_experts=8,
+                                         experts_held=4, expert_offset=4)
+        return cfg, nemotron_h.make_nemotron_h_stages(key, cfg)[0], kw
+    if family == "zaya":
+        cfg = zaya.ZayaConfig(vocab=97, seq_len=48)
+        return cfg, zaya.make_zaya_stages(key, cfg)[0], kw
+    return CFG, make_cohere2_stages(key, CFG)[0], dict(kw, max_len=ML)
+
+
+@pytest.mark.parametrize("family", ["gpt", "jamba", "sdar", "nemotron_h",
+                                    "zaya", "cohere2"])
+def test_the_engine_holds_the_stages_tree_or_the_programs_own(family):
+    """``PagedServing.serve_params``: ``None`` for five families, whose
+    engine holds the very trees the stages hold (no copy, no program run at
+    construction) and a ``params=`` list as it was given; Cohere2's engine
+    holds what ``serve_params`` makes of either, once: the full layer, the
+    embedding and the head the same objects, a window layer's two
+    projections alone its own."""
+    cfg, stages, kw = _toy(family)
+    serving = cfg.paged_serving(stages, kw["max_len"], kw["block_size"])
+    eng = InferenceEngine(stages, cfg, **kw)
+    given = [s.params for s in stages]
+    over = InferenceEngine(stages, cfg, params=given, **kw)
+    if family != "cohere2":
+        assert serving.serve_params is None
+        assert len(eng.params) == len(stages) and all(
+            a is s.params for a, s in zip(eng.params, stages))
+        assert over.params is given
+        return
+    own = lambda path: path[-1].key in ("wq_halves", "wk_halves")  # noqa: E731
+    for e in (eng, over):
+        kept = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(
+            e.params) if not own(path)]
+        theirs = {id(leaf) for leaf in jax.tree.leaves(given)}
+        assert all(id(leaf) in theirs for leaf in kept)
+        assert len(jax.tree.leaves(e.params)) == len(theirs) == len(kept) + 6
+        assert e.params[0]["blocks"][3] is given[0]["blocks"][3]
+    assert eng.stages is stages and stages[0].params is given[0]
 
 
 # -- the tick's counts ------------------------
