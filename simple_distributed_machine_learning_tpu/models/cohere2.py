@@ -81,10 +81,16 @@ ring, and what lies behind a slot's window is handed back. The decode
 (``jit_step_window_decode``) calls ``ops/paged_attention.py`` once a layer
 with that layer's buffer, table and window. The chunk
 (``jit_chunk_window_prefill``) writes its rows, then attends over the
-slot's LIVE positions alone, :data:`_ATTEND_ROWS` at a time with a running
-maximum and sum (:func:`_span_attention`): no array of ``heads x chunk x
-max_len`` exists, and a block that lies wholly behind a window is never
-gathered. Host inputs, sampling and seats are ``models/jamba.py``'s. The
+slot's LIVE positions alone, a step of pool blocks at a time with a running
+maximum and sum: no array of ``heads x chunk x max_len`` exists, and a block
+that lies wholly behind a window is never gathered. That walk lives beside
+the pool's other helpers since PR 46, ``models/gpt.py::_span_attention``
+(with ``_entry``, a ring's ``block % NB``), imported by name like
+``_paged_scatter``: the long-context family's chunk (``models/zaya.py``)
+attends through it too, so it takes the K/V head count and not this
+family's config, and both programs here trace what they traced when it lay
+in this module (``tests/test_cohere2.py``). Host inputs, sampling and seats
+are ``models/jamba.py``'s. The
 decode program also counts what its expert layers did
 (``PagedServing.counters``), over the LIVE slots' rows: a slot that sits a
 tick out is routed to no held expert (:func:`_ffn`), so it reads no
@@ -105,6 +111,7 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     NEWEST_PAIR,
     PagedServing,
     _check_attn_kernel,
+    _entry,
     _feed_newest,
     _is_quantized_dtype,
     _memo_build,
@@ -113,6 +120,7 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     _sample_slot,
     _sample_slots,
     _seat_newest,
+    _span_attention,
 )
 from simple_distributed_machine_learning_tpu.models.jamba import (
     _grouped_attention,
@@ -142,13 +150,6 @@ from simple_distributed_machine_learning_tpu.parallel.pipeline import Stage
 
 #: what a decode run counts over its expert layers (``PagedServing.counters``)
 EXPERT_COUNTERS = ("experts_hit", "expert_rows", "expert_rows_max")
-
-#: cached positions one step of :func:`_span_attention` gathers and scores:
-#: whole pool blocks, ``heads x chunk x _ATTEND_ROWS`` float32 scores a step
-_ATTEND_ROWS = 512
-
-_NEG = -1e30        # a masked score: finite, so an empty step changes nothing
-
 
 @dataclasses.dataclass(frozen=True)
 class Cohere2Config:
@@ -471,96 +472,13 @@ def _group_tables(tables, windows, nb_full: int):
     return out
 
 
-def _entry(table, block, window):
-    """The physical block of logical block(s) ``block`` through a layer's
-    ``table [N, NB]`` (``block [N, ...]``): entry ``block``, or in a window
-    layer's ring ``block % NB``."""
-    if window is not None:
-        block = block % table.shape[-1]
-    flat = jnp.take_along_axis(table, block.reshape(block.shape[0], -1),
-                               axis=1)
-    return flat.reshape(block.shape)
-
-
-def _span_attention(q, kbuf, vbuf, table, qpos, window, cfg: Cohere2Config,
-                    bs: int):
-    """Softmax attention of ``q [N, L, H, dh]`` at positions ``qpos [N, L]``
-    (non-decreasing along ``L``) over ONE layer's pool buffers ``kbuf`` /
-    ``vbuf [n_blocks + 1, bs, KV dh]`` through that layer's ``table [N,
-    NB]``, over the live positions alone: steps of :data:`_ATTEND_ROWS`
-    positions from the one that holds the oldest query's first visible key
-    to the one that holds the newest query, a running maximum and sum
-    between them (``ops/paged_attention.py``'s walk in ``jax.numpy``, for a
-    chunk's many query rows). A step's blocks before the first live one or
-    past the newest fetch that one instead, and the position mask removes
-    them: no block wholly behind a window is gathered. Returns ``[N, L, H
-    dh]`` float32."""
-    f32 = jnp.float32
-    n, lq, _, dh = q.shape
-    kv = cfg.n_kv_heads
-    g = cfg.n_heads // kv
-    # operands in the POOL's dtype, sums in float32, as every matmul here
-    # reads its weights: a bfloat16 pool's rows go to the matrix unit as
-    # they lie (what the chip's one-pass float32 product makes of them
-    # anyway, ops/paged_attention.py), a float32 pool keeps float32. A K/V
-    # head's group of query heads are ROWS of one product, [N, KV, g L, dh]
-    # against [N, KV, R, dh]: the scores' lanes are the step's positions
-    q = jnp.moveaxis(q.reshape(n, lq, kv, g, dh) / math.sqrt(dh), 1, 3)
-    q = q.reshape(n, kv, g * lq, dh).astype(kbuf.dtype)
-    rowpos = jnp.tile(qpos, (1, g))[:, None, :, None]        # [N, 1, g L, 1]
-    blocks = max(1, min(table.shape[-1], _ATTEND_ROWS // bs))
-    rows = blocks * bs
-    oldest = qpos[:, 0] if window is None else jnp.maximum(
-        qpos[:, 0] - (window - 1), 0)
-    first_blk = (0 * oldest if window is None else oldest // bs)[:, None]
-    last_blk = (qpos[:, -1] // bs)[:, None]
-    batched = ((0, 1), (0, 1))
-
-    def step(i, carry):
-        m_prev, l_prev, acc = carry
-        want = i * blocks + jnp.arange(blocks)[None, :]          # [1, G]
-        phys = _entry(table, jnp.clip(want, first_blk, last_blk), window)
-        k = jnp.swapaxes(kbuf[phys].reshape(n, rows, kv, dh), 1, 2)
-        v = jnp.swapaxes(vbuf[phys].reshape(n, rows, kv, dh), 1, 2)
-        back = rowpos - (i * rows + jnp.arange(rows))    # [N, 1, g L, R]
-        mask = back >= 0
-        if window is not None:
-            mask &= back < window
-        scores = lambda q: jax.lax.dot_general(  # noqa: E731
-            q, k, (((3,), (3,)), batched), preferred_element_type=f32)
-        m_new = jnp.maximum(m_prev, jnp.where(mask, scores(q), _NEG).max(
-            axis=-1, keepdims=True))
-        # the scores a second time, behind a barrier that keeps the compiler
-        # from sharing the first product: each product then keeps its
-        # epilogue (the row maximum; exp and the cast) in its own fusion
-        # and the float32 scores of a step, heads x chunk x step x 4 bytes,
-        # are never written out (they were 800 MB of a step's traffic and
-        # two thirds of its time on the chip: PERF.md section 6, PR 44)
-        p = jnp.where(mask, jnp.exp(
-            scores(jax.lax.optimization_barrier(q)) - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        return (m_new, l_prev * corr + p.sum(axis=-1, keepdims=True),
-                acc * corr + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((3,), (2,)), batched),
-                    preferred_element_type=f32))
-
-    lo = (jnp.min(oldest) // rows if window is not None else 0)
-    hi = jnp.max(qpos[:, -1]) // rows + 1
-    _, l, acc = jax.lax.fori_loop(lo, hi, step, (
-        jnp.full((n, kv, g * lq, 1), _NEG, f32),
-        jnp.zeros((n, kv, g * lq, 1), f32),
-        jnp.zeros((n, kv, g * lq, dh), f32)))
-    out = (acc / jnp.maximum(l, 1e-30)).reshape(n, kv, g, lq, dh)
-    return jnp.moveaxis(out, 3, 1).reshape(n, lq, cfg.n_heads * dh)
-
-
 def _window_chunk_fwd(params, kc, vc, tokens, p0, table,
                       cfg: Cohere2Config, bs: int, nb_full: int):
     """One request's prompt positions ``[p0, p0 + c)`` through every layer:
     each layer's K/V rows are scattered into the slot's blocks of its KIND
     (a window layer's through its ring), then the chunk attends over the
-    slot's live positions in that layer (:func:`_span_attention`). Returns
-    the last position's logits ``[V]``."""
+    slot's live positions in that layer (``models/gpt.py::
+    _span_attention``). Returns the last position's logits ``[V]``."""
     embed, blocks, head = _merged_stage_trees(params)
     c = tokens.shape[1]
     h = embedding_lookup(embed["tok"], tokens.astype(jnp.int32)).astype(
@@ -573,8 +491,8 @@ def _window_chunk_fwd(params, kc, vc, tokens, p0, table,
         phys, off = _entry(tables[li], idx // bs, window)[0], idx[0] % bs
         kc = _paged_scatter(kc, li, phys, off, k[0])
         vc = _paged_scatter(vc, li, phys, off, v[0])
-        a = _span_attention(q, kc[li], vc[li], tables[li], idx, window, cfg,
-                            bs)
+        a = _span_attention(q, kc[li], vc[li], tables[li], idx, window,
+                            cfg.n_kv_heads, bs)
         y, _ = _ffn(bp, u, cfg)
         h = h + matmul_acc32(a, bp["attn"]["wo"]) + y
     return kc, vc, _head_logits(embed, head, h[:, -1], cfg)[0]
@@ -626,7 +544,7 @@ def _window_decode_fwd(params, kc, vc, toks, pos, tables, live,
             a = jnp.swapaxes(a, 1, 2).reshape(a.shape[0], 1, -1)
         else:
             a = _span_attention(q, kc[li], vc[li], tables[li], qpos, window,
-                                cfg, bs)
+                                cfg.n_kv_heads, bs)
         y, r = _ffn(bp, u, cfg, live)
         rows.append(r)
         h = h + matmul_acc32(a, bp["attn"]["wo"]) + y

@@ -1636,6 +1636,97 @@ def _paged_gather(kc, li, table, n_heads):
     return jnp.moveaxis(rows, -3, -2)             # [..., H, span, dh]
 
 
+#: cached positions one step of :func:`_span_attention` gathers and scores:
+#: whole pool blocks, ``heads x chunk x _ATTEND_ROWS`` float32 scores a step
+_ATTEND_ROWS = 512
+
+_NEG = -1e30        # a masked score: finite, so an empty step changes nothing
+
+
+def _entry(table, block, window):
+    """The physical block of logical block(s) ``block`` through a layer's
+    ``table [N, NB]`` (``block [N, ...]``): entry ``block``, or in a window
+    layer's ring ``block % NB``."""
+    if window is not None:
+        block = block % table.shape[-1]
+    flat = jnp.take_along_axis(table, block.reshape(block.shape[0], -1),
+                               axis=1)
+    return flat.reshape(block.shape)
+
+
+def _span_attention(q, kbuf, vbuf, table, qpos, window, kv: int, bs: int):
+    """Softmax attention of ``q [N, L, H, dh]`` at positions ``qpos [N, L]``
+    (non-decreasing along ``L``) over ONE layer's pool buffers ``kbuf`` /
+    ``vbuf [n_blocks + 1, bs, KV dh]`` (``kv`` K/V heads a row, each read by
+    its ``H / kv`` query heads) through that layer's ``table [N, NB]``,
+    over the live positions alone: steps of :data:`_ATTEND_ROWS`
+    positions from the one that holds the oldest query's first visible key
+    to the one that holds the newest query, a running maximum and sum
+    between them (``ops/paged_attention.py``'s walk in ``jax.numpy``, for a
+    chunk's many query rows). A step's blocks before the first live one or
+    past the newest fetch that one instead, and the position mask removes
+    them: no block wholly behind a window, and none past the newest query's,
+    is gathered, whatever the table holds there. Returns ``[N, L, H dh]``
+    float32. The prefill chunks of ``models/cohere2.py`` (a window or a full
+    layer) and of ``models/zaya.py`` (``window=None``) attend through it."""
+    f32 = jnp.float32
+    n, lq, heads, dh = q.shape
+    g = heads // kv
+    # operands in the POOL's dtype, sums in float32, as every matmul here
+    # reads its weights: a bfloat16 pool's rows go to the matrix unit as
+    # they lie (what the chip's one-pass float32 product makes of them
+    # anyway, ops/paged_attention.py), a float32 pool keeps float32. A K/V
+    # head's group of query heads are ROWS of one product, [N, KV, g L, dh]
+    # against [N, KV, R, dh]: the scores' lanes are the step's positions
+    q = jnp.moveaxis(q.reshape(n, lq, kv, g, dh) / math.sqrt(dh), 1, 3)
+    q = q.reshape(n, kv, g * lq, dh).astype(kbuf.dtype)
+    rowpos = jnp.tile(qpos, (1, g))[:, None, :, None]        # [N, 1, g L, 1]
+    blocks = max(1, min(table.shape[-1], _ATTEND_ROWS // bs))
+    rows = blocks * bs
+    oldest = qpos[:, 0] if window is None else jnp.maximum(
+        qpos[:, 0] - (window - 1), 0)
+    first_blk = (0 * oldest if window is None else oldest // bs)[:, None]
+    last_blk = (qpos[:, -1] // bs)[:, None]
+    batched = ((0, 1), (0, 1))
+
+    def step(i, carry):
+        m_prev, l_prev, acc = carry
+        want = i * blocks + jnp.arange(blocks)[None, :]          # [1, G]
+        phys = _entry(table, jnp.clip(want, first_blk, last_blk), window)
+        k = jnp.swapaxes(kbuf[phys].reshape(n, rows, kv, dh), 1, 2)
+        v = jnp.swapaxes(vbuf[phys].reshape(n, rows, kv, dh), 1, 2)
+        back = rowpos - (i * rows + jnp.arange(rows))    # [N, 1, g L, R]
+        mask = back >= 0
+        if window is not None:
+            mask &= back < window
+        scores = lambda q: jax.lax.dot_general(  # noqa: E731
+            q, k, (((3,), (3,)), batched), preferred_element_type=f32)
+        m_new = jnp.maximum(m_prev, jnp.where(mask, scores(q), _NEG).max(
+            axis=-1, keepdims=True))
+        # the scores a second time, behind a barrier that keeps the compiler
+        # from sharing the first product: each product then keeps its
+        # epilogue (the row maximum; exp and the cast) in its own fusion
+        # and the float32 scores of a step, heads x chunk x step x 4 bytes,
+        # are never written out (they were 800 MB of a step's traffic and
+        # two thirds of its time on the chip: PERF.md section 6, PR 44)
+        p = jnp.where(mask, jnp.exp(
+            scores(jax.lax.optimization_barrier(q)) - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        return (m_new, l_prev * corr + p.sum(axis=-1, keepdims=True),
+                acc * corr + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((3,), (2,)), batched),
+                    preferred_element_type=f32))
+
+    lo = (jnp.min(oldest) // rows if window is not None else 0)
+    hi = jnp.max(qpos[:, -1]) // rows + 1
+    _, l, acc = jax.lax.fori_loop(lo, hi, step, (
+        jnp.full((n, kv, g * lq, 1), _NEG, f32),
+        jnp.zeros((n, kv, g * lq, 1), f32),
+        jnp.zeros((n, kv, g * lq, dh), f32)))
+    out = (acc / jnp.maximum(l, 1e-30)).reshape(n, kv, g, lq, dh)
+    return jnp.moveaxis(out, 3, 1).reshape(n, lq, heads * dh)
+
+
 def _paged_attend(kc, vc, li, q, tables, qpos, bs):
     """The FUSED attention path: one Pallas pass over layer ``li``'s
     physical blocks (gather + mask + online-softmax attention, dequant

@@ -56,8 +56,18 @@ slot), so the keys it writes to the pool do not depend on where the prompt
 was cut. :meth:`ZayaConfig.paged_serving` hands ``serve/engine.py`` that
 layout and the two programs (``jit_chunk_cca_prefill``,
 ``jit_step_cca_decode``); host inputs, sampling and seats are
-``models/jamba.py``'s. The decode program also counts what its expert
-layers did (``PagedServing.counters``). Training this family is not built.
+``models/jamba.py``'s. The chunk attends through ``models/gpt.py::
+_span_attention`` (the walk ``models/cohere2.py``'s chunk makes, with no
+window): over the slot's LIVE positions, a step of pool blocks at a time
+with a running maximum and sum, operands in the pool's dtype. It holds no
+table-wide score array (until PR 46 every layer gathered the whole table
+and wrote ``heads x chunk x max_len`` float32 scores whatever the slot
+held: a quarter of the decode-and-chunk tick at ``max_len`` 8,192,
+``PERF.md`` section 6) and fetches no block past the chunk's last position.
+The whole-sequence path (:func:`full_logits`) and the decode program's
+``kernel="dense"`` keep ``_grouped_attention``. The decode program also
+counts what its expert layers did (``PagedServing.counters``). Training this
+family is not built.
 """
 
 from __future__ import annotations
@@ -82,6 +92,7 @@ from simple_distributed_machine_learning_tpu.models.gpt import (
     _sample_slot,
     _sample_slots,
     _seat_newest,
+    _span_attention,
 )
 from simple_distributed_machine_learning_tpu.models.jamba import (
     _grouped_attention,
@@ -417,7 +428,9 @@ def _cca_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
     layer has BOTH kinds of state: the convolutions and the value shift
     start from the slot's tails (zeros when ``p0 == 0``, so a slot never
     sees its last occupant's), the keys they give are scattered into the
-    slot's blocks, and the chunk attends over those. Returns the last
+    slot's blocks, and the chunk attends over the slot's LIVE positions, a
+    step of blocks at a time (``models/gpt.py::_span_attention``): what the
+    table holds past ``p0 + c`` is never fetched. Returns the last
     position's logits ``[V]``."""
     embed, blocks, head = _merged_stage_trees(params)
     c = tokens.shape[1]
@@ -425,8 +438,6 @@ def _cca_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
         jnp.float32)
     idx = p0 + jnp.arange(c)
     phys, off = table[idx // bs], idx % bs
-    span = table.shape[0] * bs
-    seen = (jnp.arange(span)[None, :] <= idx[:, None])[None]   # [1, c, span]
     state = list(state)
     carried = None
     for li, bp in enumerate(blocks):
@@ -440,13 +451,9 @@ def _cca_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
                           for t, new in zip(state[li], tails))
         kc = _paged_scatter(kc, li, phys, off, k[0])
         vc = _paged_scatter(vc, li, phys, off, v[0])
-        # [KV, span, dh] -> [1, span, KV, dh]
-        krow = jnp.swapaxes(
-            _paged_gather(kc, li, table, cfg.n_kv_heads), 0, 1)[None]
-        vrow = jnp.swapaxes(
-            _paged_gather(vc, li, table, cfg.n_kv_heads), 0, 1)[None]
-        h = _merge(ap, h, matmul_acc32(
-            _grouped_attention(q, krow, vrow, seen, cfg), ap["wo"]))
+        a = _span_attention(q, kc[li], vc[li], table[None], idx[None], None,
+                            cfg.n_kv_heads, bs)
+        h = _merge(ap, h, matmul_acc32(a, ap["wo"]))
         y, carried, _ = _top1_experts(
             ep, rms_norm(ep["norm"], h, cfg.rms_eps), carried, cfg)
         h = _merge(ep, h, y)

@@ -43,7 +43,7 @@ import pytest
 
 from bench_cells.reference import cohere2 as reference
 
-from simple_distributed_machine_learning_tpu.models import cohere2
+from simple_distributed_machine_learning_tpu.models import cohere2, gpt
 from simple_distributed_machine_learning_tpu.models.cohere2 import (
     EXPERT_COUNTERS,
     Cohere2Config,
@@ -478,12 +478,73 @@ def _served_rows(logits, n_prompt, n_new):
     return logits[n_prompt - 1:n_prompt - 1 + n_new]
 
 
+#: sha256 of ``str(jax.make_jaxpr(...))`` of the family's two programs, made
+#: on the PARENT commit (0eac28c, PR 45), where ``_span_attention`` lay in
+#: ``models/cohere2.py`` and took the config, by the same lines as the test
+#: below: (size, kernel, program) -> what the program has to trace
+_PARENT_JAXPR = {
+    ("toy", "dense", "decode"):
+        "1a4564248431bca5499fccde8223a0764899540cb67c51f1e15c35e6e2f7af3e",
+    ("toy", "fused", "decode"):
+        "38f0e44b94b2d01c7dd16481ff4ed24234b8edf6c730f9c3c9d696663c29ffbc",
+    ("toy", "fused", "chunk"):
+        "e9d79fb10d00895f1db583428bbc8e9b4d16f35a43f7313ff734ba6b298df4b6",
+    # command-a-plus-05-2026.serve-mixed-closed's shapes
+    ("cell", "dense", "decode"):
+        "65a6c67da72a608f99944fcbdbecf489029bc6fcd9c6d557c2c52e5e0e3cf10e",
+    ("cell", "fused", "decode"):
+        "89c6bc7d203b76c857b1159843cf540b33ba24511c299b22e171e14adac4d1a7",
+    ("cell", "fused", "chunk"):
+        "2c75de672f0469747d159ad236870ce2f3adb0ebdec4276862ceda57dbdda95a",
+}
+
+#: the toy's build in ``test_chip_compile._window_programs``'s terms (slots,
+#: max_len, block, full blocks, window blocks, chunk); the cell's are its own
+_TOY_SIZES = (2, ML, BS, 2 * NB_FULL, 10, CHUNK)
+
+
+@pytest.mark.parametrize("size,kernel,program", list(_PARENT_JAXPR))
+def test_the_window_programs_trace_what_they_traced_before_the_move(
+        monkeypatch, size, kernel, program):
+    """``command-a-plus-05-2026.serve-mixed-closed`` rides on these two
+    programs: with the chunk's attention lifted to ``models/gpt.py::
+    _span_attention`` (PR 46: the long-context family's chunk calls it too,
+    and it takes the K/V head count in place of the config) each program's
+    jaxpr is the parent commit's to the letter, at the toy's size and at
+    the cell's shapes (shapes alone: nothing is allocated). The decode
+    program calls it under ``kernel="dense"``; a step of another length is
+    another trace."""
+    import hashlib
+    import re
+
+    from test_chip_compile import _window_programs
+
+    bs = BS if size == "toy" else 16
+
+    def text():
+        kw = dict(cfg=CFG, sizes=_TOY_SIZES,
+                  pool_dtype="float32") if size == "toy" else {}
+        fn, args = _window_programs(kernel=kernel, **kw)[1][
+            "window-" + program]
+        return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+
+    # programs built anew each time: a memoized one keeps its first trace
+    monkeypatch.setattr(gpt, "_DECODE_BUILD_CACHE", {})
+    plain = text()
+    assert hashlib.sha256(plain.encode()).hexdigest() == _PARENT_JAXPR[
+        size, kernel, program]
+    if (kernel, program) != ("fused", "decode"):
+        monkeypatch.setattr(gpt, "_ATTEND_ROWS", bs)
+        monkeypatch.setattr(gpt, "_DECODE_BUILD_CACHE", {})
+        assert text() != plain
+
+
 @pytest.fixture()
 def small_steps(monkeypatch):
     """The chunk's attention in steps of 8 positions (two blocks), so that
     the toy's 40 positions are five steps and the walk is seen to start
     behind the window, not at 0."""
-    monkeypatch.setattr(cohere2, "_ATTEND_ROWS", 8)
+    monkeypatch.setattr(gpt, "_ATTEND_ROWS", 8)
     _twins.cache_clear()
     yield
     _twins.cache_clear()

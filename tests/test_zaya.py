@@ -35,7 +35,7 @@ import pytest
 
 from bench_cells.reference import zaya as reference
 
-from simple_distributed_machine_learning_tpu.models import zaya
+from simple_distributed_machine_learning_tpu.models import gpt, zaya
 from simple_distributed_machine_learning_tpu.models.gpt import (
     SEAT_NONE,
     SEAT_SAMPLE,
@@ -496,6 +496,87 @@ def test_released_slot_bound_again_starts_from_zeros(stages):
     h_fresh, = _run(fresh, [fresh.eng.submit(second, 5)])
     assert h_used.tokens == h_fresh.tokens
     assert np.array_equal(used.logits_of(h_used), fresh.logits_of(h_fresh))
+
+
+@pytest.fixture()
+def small_steps(monkeypatch):
+    """The chunk's attention in steps of 8 positions (two blocks), so that
+    the toy's table of 48 is six steps (``tests/test_cohere2.py``'s fixture
+    of the name)."""
+    monkeypatch.setattr(gpt, "_ATTEND_ROWS", 8)
+    _twins.cache_clear()
+    yield
+    _twins.cache_clear()
+
+
+@pytest.mark.parametrize("dtype,tol,mean", [("float32", F32, 2e-5),
+                                            ("bfloat16", BF16, 0.01)])
+def test_a_chunk_walks_the_live_steps_of_its_table_alone(small_steps, dtype,
+                                                         tol, mean):
+    """A table of six steps and a prompt of 13 that ends inside the second:
+    chunks of 5, 5 and 3 through the chunk program, each one's logits
+    against the reference's full forward, over a pool of ``dtype`` in which
+    ONLY the slot's four live blocks are numbers. Everything else is NaN:
+    the trash block and the blocks the table's later entries name (what a
+    longer occupant left there). A probability of zero times a NaN row is
+    NaN, so the walk must never fetch a dead block; the dense path, which
+    gathers the whole table and masks after the product, hands the NaN on
+    (the decode program's ``kernel="dense"`` over the same pool)."""
+    cfg = dataclasses.replace(CFG, param_dtype=dtype)
+    stages = _stages(cfg)
+    params = stages[0].params
+    seq, live, ml = _prompt(11, 13), 4, CFG.seq_len
+    table = jnp.asarray(1 + np.arange(ml // BS), jnp.int32)
+    pool = tuple(jnp.full((ml // BS + 1, BS, cfg.d_kv), jnp.nan,
+                          jnp.dtype(dtype)).at[1:1 + live].set(7.0)
+                 for _ in range(cfg.n_layers))
+    serving = cfg.paged_serving(stages, ml, BS, dtype)
+    *state, _ = jax.tree.map(lambda sd: jnp.zeros((1, *sd.shape), sd.dtype),
+                             tuple(serving.state_shapes))
+    chunk = jax.jit(functools.partial(zaya._cca_chunk_fwd, cfg=cfg, bs=BS))
+    kc, vc, state = pool, pool, tuple(state)
+    want = _ref_logits(params, seq, cfg)
+    for p0, c in ((0, 5), (5, 5), (10, 3)):
+        kc, vc, state, row = chunk([params], kc, vc, state,
+                                   jnp.asarray(seq[None, p0:p0 + c]), p0,
+                                   table, 0)
+        np.testing.assert_allclose(np.asarray(row), want[p0 + c - 1], **tol)
+        assert np.abs(np.asarray(row) - want[p0 + c - 1]).mean() < mean
+    # the rows the chunks wrote are numbers, nothing else became one
+    for buf in kc + vc:
+        held = np.isfinite(np.asarray(buf, np.float32)).all(axis=(1, 2))
+        assert held.tolist() == [False] + [True] * live + [False] * (
+            ml // BS - live)
+    step = jax.jit(functools.partial(zaya._cca_decode_fwd, cfg=cfg, bs=BS,
+                                     kernel="dense"))
+    *_, rows, _ = step([params], kc, vc, state, jnp.asarray(seq[-1:]),
+                       jnp.asarray([len(seq)]), table[None],
+                       jnp.asarray([True]))
+    assert np.isnan(np.asarray(rows)).all()
+
+
+def test_a_slot_bound_again_reads_no_row_its_longer_occupant_left(
+        stages, small_steps):
+    """One slot: a request of 36 positions fills nine blocks and leaves;
+    every block of the pool is then set to NaN (none is referenced), and a
+    prompt of 12 in three chunks of one whole block, which ends inside the
+    table's second step of six, must serve the logits a fresh pool serves,
+    bit for bit: it fetches its own three blocks and no other."""
+    second = _prompt(13, 12)
+    used = Tap(stages, n_slots=1, prefill_chunk=BS)
+    _run(used, [used.eng.submit(_prompt(12, 30), 6)])
+    pool = used.eng.pool
+    pool.kc, pool.vc = (tuple(jnp.full_like(b, jnp.nan) for b in bufs)
+                        for bufs in (pool.kc, pool.vc))
+    used.rows.clear()
+    h_used, = _run(used, [used.eng.submit(second, 1)])
+    fresh = Tap(stages, n_slots=1, prefill_chunk=BS)
+    h_fresh, = _run(fresh, [fresh.eng.submit(second, 1)])
+    got = used.logits_of(h_used)
+    assert np.isfinite(got).all() and len(used.rows) == 3
+    assert np.array_equal(got, fresh.logits_of(h_fresh))
+    np.testing.assert_allclose(
+        got[0], _ref_logits(stages[0].params, second)[-1], **F32)
 
 
 def _engine(stages, **kw):
