@@ -53,9 +53,9 @@ from simple_distributed_machine_learning_tpu.analysis.programs import (
 )
 from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
-    QuantKV,
     make_gpt_stages,
 )
+from simple_distributed_machine_learning_tpu.models.serving import QuantKV
 from simple_distributed_machine_learning_tpu.ops.paged_attention import (
     paged_attention,
 )

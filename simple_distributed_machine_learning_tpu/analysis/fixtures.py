@@ -253,9 +253,11 @@ def _cow_tick_report(threaded: bool, name: str) -> Report:
 
     from simple_distributed_machine_learning_tpu.analysis import spec
     from simple_distributed_machine_learning_tpu.models.gpt import (
-        SEAT_NONE,
         make_paged_block_copy,
         make_paged_prefill_chunk,
+    )
+    from simple_distributed_machine_learning_tpu.models.serving import (
+        SEAT_NONE,
     )
     cfg, stages = _tiny_serve()
     ml, bs, n_blocks = 12, 4, 6

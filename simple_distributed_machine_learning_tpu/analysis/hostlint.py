@@ -1,20 +1,20 @@
 """Host-side AST lint: the ``_DECODE_BUILD_CACHE`` discipline.
 
 The jaxpr rules see compiled programs; this pass sees the PYTHON that
-builds them. The discipline (models/gpt.py, PR 6): every decode-path
+builds them. The discipline (models/serving.py, PR 6): every decode-path
 builder is memoized on its static config in ``_DECODE_BUILD_CACHE``, so a
 fleet of engines (and a test suite full of them) shares one traced +
 compiled program per config. Three ways the discipline rots, all cheap to
 catch with ``ast`` and expensive to catch in production:
 
 - ``hostlint.unmemoized-builder`` — a decode builder in ``models/gpt.py``
-  whose body no longer routes through ``_memo_build`` (a refactor dropped
+  whose body no longer routes through ``memo_build`` (a refactor dropped
   the memo; every engine recompiles);
 - ``hostlint.builder-bypass`` — a call site anywhere outside
   ``models/gpt.py`` invoking a private ``_build_*`` helper directly,
   skipping the memo the public ``make_*`` wraps around it;
-- ``hostlint.cache-poke`` — code outside ``models/gpt.py`` touching
-  ``_DECODE_BUILD_CACHE`` itself (clearing or seeding it from a distance);
+- ``hostlint.cache-poke`` — code outside ``models/serving.py`` and ``gpt.py``
+  touching ``_DECODE_BUILD_CACHE`` itself (clearing or seeding it from afar);
 - ``hostlint.raw-jit-in-serve`` — a ``jax.jit`` created inside ``serve/``:
   the serving layer's contract is that every compiled program comes from
   the memoized gpt builders, so a stray jit there is an unmemoized program
@@ -211,15 +211,15 @@ def lint_builder_definitions(gpt_path: str = GPT_PATH) -> list[Finding]:
                 where=_where(gpt_path, tree),
                 hint="update DECODE_BUILDER_NAMES alongside the builder"))
             continue
-        if not any(_call_name(c) == "_memo_build" for c in _calls_in(fn)):
+        if not any(_call_name(c) == "memo_build" for c in _calls_in(fn)):
             findings.append(Finding(
                 rule="hostlint.unmemoized-builder", severity=Severity.ERROR,
                 message=(f"decode builder '{name}' no longer routes its "
-                         f"build through _memo_build — every engine and "
+                         f"build through memo_build — every engine and "
                          f"test constructing it re-traces and re-compiles "
                          f"an identical program"),
                 where=_where(gpt_path, fn),
-                hint="wrap the build in _memo_build(key, build) keyed on "
+                hint="wrap the build in memo_build(key, build) keyed on "
                      "the static config (see the sibling builders)"))
     return findings
 
@@ -244,9 +244,9 @@ def _lint_call_sites(path: str, allow_jit: bool,
                 == "_DECODE_BUILD_CACHE"):
             findings.append(Finding(
                 rule="hostlint.cache-poke", severity=Severity.ERROR,
-                message="_DECODE_BUILD_CACHE touched outside models/gpt.py "
-                        "— the memo's invariants (keying, shared "
-                        "executables) belong to its owner",
+                message="_DECODE_BUILD_CACHE touched outside "
+                        "models/serving.py — the memo's invariants (keying, "
+                        "shared executables) belong to its owner",
                 where=_where(path, node, repo),
                 hint="go through the public make_* builders"))
         if isinstance(node, ast.Call):
@@ -460,15 +460,16 @@ def lint_metric_catalog(metric_files=None,
 
 def lint_repo(repo: str = _REPO) -> Report:
     """The whole hostlint suite: builder definitions in models/gpt.py;
-    cache-poke and builder-bypass EVERYWHERE outside the cache's owner —
-    the whole package, repo-root scripts (bench.py) and tests/ — because
-    "code outside models/gpt.py touching _DECODE_BUILD_CACHE" is the
-    documented rule, and a poke from cli.py or bench.py rots the memo
-    just as surely as one from serve/; raw-jit additionally in serve/
-    (every other layer creates jits legitimately)."""
+    cache-poke and builder-bypass EVERYWHERE outside the memo's home
+    (models/serving.py) and the builders' (models/gpt.py) — the whole
+    package, repo-root scripts (bench.py) and tests/ — because a poke from
+    cli.py or bench.py rots the memo just as surely as one from serve/;
+    raw-jit additionally in serve/ (every other layer creates jits
+    legitimately)."""
     pkg = os.path.join(repo,
                        "simple_distributed_machine_learning_tpu")
     gpt = os.path.abspath(os.path.join(pkg, "models", "gpt.py"))
+    owners = (gpt, os.path.abspath(os.path.join(pkg, "models", "serving.py")))
     findings = lint_builder_definitions(gpt)
     findings.extend(lint_journal_grammar(repo=repo))
     findings.extend(lint_metric_catalog(repo=repo))
@@ -490,7 +491,7 @@ def lint_repo(repo: str = _REPO) -> Report:
                  if f.endswith(".py"))
     for path in paths:
         ap = os.path.abspath(path)
-        if ap == gpt:
+        if ap in owners:
             continue
         findings.extend(_lint_call_sites(
             path, allow_jit=not ap.startswith(serve_dir), repo=repo,

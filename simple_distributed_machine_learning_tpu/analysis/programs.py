@@ -186,8 +186,8 @@ def check_builder_memo(name: str, build: Callable[[], Any]) -> list[Finding]:
                  f"identical static config — every engine (and every test) "
                  f"constructing it pays a fresh trace + XLA compile"),
         where=name,
-        hint="route the build through models.gpt._DECODE_BUILD_CACHE "
-             "(_memo_build) keyed on the static config")]
+        hint="route the build through models.serving._DECODE_BUILD_CACHE "
+             "(memo_build) keyed on the static config")]
 
 
 def _retrace_finding(name: str, axis: str, sspec: ServeSpec) -> list[Finding]:
@@ -227,13 +227,13 @@ def _cache_sds(n_layers, n_phys, bs, n_heads, head_dim, cache_dtype):
     (``serve/slots.py::PagedKVPool``)."""
     import numpy as np
 
-    from simple_distributed_machine_learning_tpu.models.gpt import (
+    from simple_distributed_machine_learning_tpu.models.serving import (
         QuantKV,
-        _cache_dtype,
-        _is_quantized_dtype,
+        is_quantized_dtype,
+        storage_dtype,
     )
-    layer = _sds((n_phys, bs, n_heads * head_dim), _cache_dtype(cache_dtype))
-    if _is_quantized_dtype(cache_dtype):
+    layer = _sds((n_phys, bs, n_heads * head_dim), storage_dtype(cache_dtype))
+    if is_quantized_dtype(cache_dtype):
         layer = QuantKV(layer, _sds((n_phys, bs, n_heads), np.float32))
     return (layer,) * n_layers
 
@@ -252,12 +252,14 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
     import numpy as np
 
     from simple_distributed_machine_learning_tpu.models.gpt import (
-        SEAT_NONE,
         make_cached_decoder,
         make_paged_block_copy,
         make_paged_decode_step,
         make_paged_prefill_chunk,
         pack_tp_serve_params,
+    )
+    from simple_distributed_machine_learning_tpu.models.serving import (
+        SEAT_NONE,
     )
 
     cfg = sspec.cfg
@@ -297,8 +299,8 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
     # the solo anchor decodes contiguous rows: a quantized serving dtype
     # widens to f32 there (quantized pools are judged against it at
     # pinned tolerance, not bit-exactness)
-    from simple_distributed_machine_learning_tpu.models.gpt import (
-        _is_quantized_dtype as _is_q,
+    from simple_distributed_machine_learning_tpu.models.serving import (
+        is_quantized_dtype as _is_q,
     )
     anchor_cd = None if _is_q(sspec.cache_dtype) else sspec.cache_dtype
     findings += check_builder_memo(
@@ -373,7 +375,7 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
                                      "chunk (= whole-prompt) length", sspec)
 
     # every slot's newest token and key, which both programs keep on the
-    # device beside the pool (PagedServing.ahead): the chunk seats its
+    # device beside the pool (PagedServing): the chunk seats its
     # slot's (``seat``: SEAT_NONE, SEAT_SAMPLE or a token), the decode
     # reads its inputs there and writes the ``live`` slots' back
     state = ((toks, kdS),)
@@ -437,21 +439,23 @@ def build_registry(stages, sspec: ServeSpec, mesh=None, draft_stages=None
 
     if speculative:
         from simple_distributed_machine_learning_tpu.models.gpt import (
-            _cache_dtype,
-            _is_quantized_dtype,
             make_paged_verify_step,
             make_slot_prefill,
             make_slot_propose,
+        )
+        from simple_distributed_machine_learning_tpu.models.serving import (
+            is_quantized_dtype,
+            storage_dtype,
         )
         # the draft's programs over its own slot rows (one max_len row a
         # slot; a quantized TARGET dtype falls back to f32 for the draft:
         # the engine's rule — trace the programs it actually runs)
         dcfg = sspec.draft_cfg
         dL = sum(len(p["blocks"]) for p in (s.params for s in draft_stages))
-        draft_cd = (None if _is_quantized_dtype(sspec.cache_dtype)
+        draft_cd = (None if is_quantized_dtype(sspec.cache_dtype)
                     else sspec.cache_dtype)
         dkc = _sds((dL, S, dcfg.n_heads, ml,
-                    dcfg.d_model // dcfg.n_heads), _cache_dtype(draft_cd))
+                    dcfg.d_model // dcfg.n_heads), storage_dtype(draft_cd))
         dparams = abstractify([s.params for s in draft_stages])
         draft_prefill = make_slot_prefill(draft_stages, dcfg, ml, draft_cd)
         findings += check_builder_memo(
@@ -538,13 +542,13 @@ def degraded_spec(sspec: ServeSpec) -> ServeSpec:
     (:func:`default_registry_reports`) lints the exact layout a
     chaos-stressed supervisor will rebuild into — a fallback that only
     exists on the worst day must be proven clean on every PR."""
-    from simple_distributed_machine_learning_tpu.models.gpt import (
-        _is_quantized_dtype,
+    from simple_distributed_machine_learning_tpu.models.serving import (
+        is_quantized_dtype,
     )
     return dataclasses.replace(
         sspec, cfg=cfg_dense(sspec.cfg), spec_k=0, draft_cfg=None,
         attn_kernel="dense", host_cache_blocks=0, prefetch_ticks=1,
-        cache_dtype=(None if _is_quantized_dtype(sspec.cache_dtype)
+        cache_dtype=(None if is_quantized_dtype(sspec.cache_dtype)
                      else sspec.cache_dtype))
 
 
@@ -666,13 +670,13 @@ def hbm_tick_costs(sspec: ServeSpec, n_layers: int | None = None
             note="per hot-swap / first admission: one donated bank-row "
                  "rewrite (serve_adapter_swaps_total advances by 1)"))
     if K >= 2 and sspec.draft_cfg is not None:
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            _is_quantized_dtype,
+        from simple_distributed_machine_learning_tpu.models.serving import (
+            is_quantized_dtype,
         )
         dcfg = sspec.draft_cfg
         # the draft keeps one max_len row a slot; a quantized TARGET dtype
         # falls back to f32 for the draft (the engine's rule)
-        draft_cd = (None if _is_quantized_dtype(sspec.cache_dtype)
+        draft_cd = (None if is_quantized_dtype(sspec.cache_dtype)
                     else sspec.cache_dtype)
         drow = kv_block_bytes(1, dcfg.n_heads, 1,
                               dcfg.d_model // dcfg.n_heads, draft_cd)
@@ -963,7 +967,7 @@ def engine_spec(engine, prompt_lens: tuple | None = None) -> ServeSpec:
         block_size=pool.block_size, n_blocks=pool.n_blocks,
         prefill_chunk=engine.prefill_chunk,
         # the storage dtype (a quantized pool's is its narrow one, which
-        # round-trips through _cache_dtype)
+        # round-trips through storage_dtype)
         cache_dtype=pool.cache_dtype, prompt_lens=prompt_lens,
         spec_k=engine.spec_k if engine.speculative else 0,
         draft_cfg=engine.draft_cfg,

@@ -35,12 +35,14 @@ from jax import lax
 
 from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
-    _cache_dtype,
-    _dense_block_prefill,
-    _dense_block_step,
-    _head_logprobs,
-    _merged_stage_trees,
-    _validate_decode_build,
+    dense_block_prefill,
+    dense_block_step,
+    head_logprobs,
+    validate_decode_build,
+)
+from simple_distributed_machine_learning_tpu.models.serving import (
+    merged_stage_trees,
+    storage_dtype,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -63,18 +65,18 @@ def make_beam_decoder(stages, cfg: GPTConfig, prompt_len: int, n_new: int,
     if eos_id is not None and not 0 <= eos_id < cfg.vocab:
         raise ValueError(
             f"eos_id={eos_id} outside [0, vocab={cfg.vocab})")
-    total = _validate_decode_build(stages, cfg, prompt_len, n_new,
-                                   "make_beam_decoder")
+    total = validate_decode_build(stages, cfg, prompt_len, n_new,
+                                  "make_beam_decoder")
     K = beam_size
     H, d = cfg.n_heads, cfg.d_model
     dh = d // H
     V = cfg.vocab
-    cd = _cache_dtype(cache_dtype)
+    cd = storage_dtype(cache_dtype)
 
     @jax.jit
     def decode(params, prompt, key):
         del key                                  # beam search is deterministic
-        embed, blocks, head = _merged_stage_trees(params)
+        embed, blocks, head = merged_stage_trees(params)
         b = prompt.shape[0]
         L = len(blocks)
 
@@ -84,9 +86,9 @@ def make_beam_decoder(stages, cfg: GPTConfig, prompt_len: int, n_new: int,
         ids = prompt.astype(jnp.int32)
         h = embedding_lookup(embed["tok"], ids) + embed["pos"][:prompt_len]
         for li, bp in enumerate(blocks):
-            h, kc, vc = _dense_block_prefill(bp, h, li, kc, vc,
-                                             prompt_len, H)
-        row = _head_logprobs(head, h[:, -1])                     # [B, V]
+            h, kc, vc = dense_block_prefill(bp, h, li, kc, vc,
+                                            prompt_len, H)
+        row = head_logprobs(head, h[:, -1])                     # [B, V]
 
         # ---- beam init: top-K first tokens; caches tile to B*K rows
         # (beam-major within each sequence: row index = b*K + k)
@@ -111,9 +113,9 @@ def make_beam_decoder(stages, cfg: GPTConfig, prompt_len: int, n_new: int,
             h = (embedding_lookup(embed["tok"],
                                   tok_in.reshape(b * K)[:, None]) + pos)
             for li, bp in enumerate(blocks):
-                h, kc, vc = _dense_block_step(bp, h, li, kc, vc, pos_i,
-                                              total, H)
-            row = _head_logprobs(head, h[:, 0]).reshape(b, K, V)
+                h, kc, vc = dense_block_step(bp, h, li, kc, vc, pos_i,
+                                             total, H)
+            row = head_logprobs(head, h[:, 0]).reshape(b, K, V)
             if eos_id is not None:
                 # finished beams: only continuation is EOS at log-prob 0 —
                 # the beam rides the rest of the scan on its frozen score

@@ -84,14 +84,12 @@ with that layer's buffer, table and window. The chunk
 slot's LIVE positions alone, a step of pool blocks at a time with a running
 maximum and sum: no array of ``heads x chunk x max_len`` exists, and a block
 that lies wholly behind a window is never gathered. That walk lives beside
-the pool's other helpers since PR 46, ``models/gpt.py::_span_attention``
-(with ``_entry``, a ring's ``block % NB``), imported by name like
-``_paged_scatter``: the long-context family's chunk (``models/zaya.py``)
-attends through it too, so it takes the K/V head count and not this
-family's config, and both programs here trace what they traced when it lay
-in this module (``tests/test_cohere2.py``). Host inputs, sampling and seats
-are ``models/jamba.py``'s. The
-decode program also counts what its expert layers did
+the pool's other helpers, ``models/serving.py::span_attention`` (with
+``entry``, a ring's ``block % NB``): the long-context family's chunk
+(``models/zaya.py``) attends through it too, so it takes the K/V head count
+and not this family's config, and both programs here trace what they traced
+when it lay in this module (``tests/test_cohere2.py``). Host inputs,
+sampling and seats are ``models/serving.py``'s too. The decode program also counts what its expert layers did
 (``PagedServing.counters``), over the LIVE slots' rows: a slot that sits a
 tick out is routed to no held expert (:func:`_ffn`), so it reads no
 expert's weights and its stale token's routing is nobody's count. Training
@@ -107,28 +105,26 @@ import math
 import jax
 import jax.numpy as jnp
 
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     NEWEST_PAIR,
     PagedServing,
-    _check_attn_kernel,
-    _entry,
-    _feed_newest,
-    _is_quantized_dtype,
-    _memo_build,
-    _merged_stage_trees,
-    _paged_scatter,
-    _sample_slot,
-    _sample_slots,
-    _seat_newest,
-    _span_attention,
-)
-from simple_distributed_machine_learning_tpu.models.jamba import (
-    _grouped_attention,
-    _unpack_chunk,
-    _unpack_decode,
-    _validate_hybrid_build,
+    check_attn_kernel,
+    entry,
+    feed_newest,
+    grouped_attention,
+    is_quantized_dtype,
+    memo_build,
+    merged_stage_trees,
     pack_chunk_inputs,
     pack_decode_inputs,
+    paged_scatter,
+    sample_slot,
+    sample_slots,
+    seat_newest,
+    span_attention,
+    unpack_chunk,
+    unpack_decode,
+    validate_hybrid_build,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -223,27 +219,27 @@ class Cohere2Config:
     def paged_serving(self, stages, max_len: int, block_size: int,
                       cache_dtype=None, mesh=None, kernel: str = "dense",
                       adapters: bool = False) -> PagedServing:
-        """The engine's model interface (``models/gpt.py::PagedServing``):
+        """The engine's model interface (``models/serving.py::PagedServing``):
         the paged pool holds every layer's K/V rows, each layer of its kind
-        (``windows``), and every slot its newest token and sampling key
-        (``ahead``): no recurrent state."""
+        (``windows``), and every slot its newest token and sampling key:
+        no recurrent state."""
         _validate_build(stages, self, max_len, block_size, cache_dtype, mesh,
                         adapters)
-        _check_attn_kernel(kernel, "Cohere2Config.paged_serving")
+        check_attn_kernel(kernel, "Cohere2Config.paged_serving")
         nb_full = math.ceil(max_len / block_size)
         return PagedServing(
             kv_layers=self.n_layers, kv_heads=self.n_kv_heads,
             head_dim=self.head_dim, state_shapes=(NEWEST_PAIR,),
-            chunk_prefill=_memo_build(
+            chunk_prefill=memo_build(
                 ("window_chunk", self, block_size, nb_full),
                 lambda: _build_window_prefill_chunk(self, block_size,
                                                     nb_full)),
-            decode=_memo_build(
+            decode=memo_build(
                 ("window_decode", self, block_size, nb_full, kernel),
                 lambda: _build_window_decode_step(self, block_size, nb_full,
                                                   kernel)),
             pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
-            ahead=True, counters=EXPERT_COUNTERS, windows=self.windows,
+            counters=EXPERT_COUNTERS, windows=self.windows,
             serve_params=functools.partial(serve_params, cfg=self))
 
 
@@ -257,7 +253,7 @@ def _validate_build(stages, cfg: Cohere2Config, max_len: int,
              "placement"),
             ("adapters", adapters,
              "the LoRA bank rides GPT's wq / wv (models/lora.py)"),
-            ("a quantized cache_dtype", _is_quantized_dtype(cache_dtype),
+            ("a quantized cache_dtype", is_quantized_dtype(cache_dtype),
              "the window walk of ops/paged_attention.py has no scale "
              "planes: use float32 or bfloat16")):
         if asked:
@@ -266,9 +262,9 @@ def _validate_build(stages, cfg: Cohere2Config, max_len: int,
                 f"layers: {reason}")
     # the stage, its shapes and the slot's length: the checks every family
     # with one tied stage shares (none of its refusals can fire here)
-    _validate_hybrid_build(stages, cfg, max_len, block_size, None, None,
-                           False, caller="Cohere2Config.paged_serving",
-                           maker="make_cohere2_stages")
+    validate_hybrid_build(stages, cfg, max_len, block_size, None, None,
+                          False, caller="Cohere2Config.paged_serving",
+                          maker="make_cohere2_stages")
 
 
 # -- parameters ---------------------------------------------------------------
@@ -419,7 +415,7 @@ def full_logits(params: dict, tokens, cfg: Cohere2Config):
         u = _norm(bp["norm"], h, cfg)
         q, k, v = _qkv(bp["attn"], u, positions, window, cfg)
         y, _ = _ffn(bp, u, cfg)
-        h = h + matmul_acc32(_grouped_attention(q, k, v, mask[None], cfg),
+        h = h + matmul_acc32(grouped_attention(q, k, v, mask[None], cfg),
                              bp["attn"]["wo"]) + y
     return _head_logits(params["embed"], params["head"], h, cfg)
 
@@ -477,9 +473,9 @@ def _window_chunk_fwd(params, kc, vc, tokens, p0, table,
     """One request's prompt positions ``[p0, p0 + c)`` through every layer:
     each layer's K/V rows are scattered into the slot's blocks of its KIND
     (a window layer's through its ring), then the chunk attends over the
-    slot's live positions in that layer (``models/gpt.py::
-    _span_attention``). Returns the last position's logits ``[V]``."""
-    embed, blocks, head = _merged_stage_trees(params)
+    slot's live positions in that layer (``models/serving.py::
+    span_attention``). Returns the last position's logits ``[V]``."""
+    embed, blocks, head = merged_stage_trees(params)
     c = tokens.shape[1]
     h = embedding_lookup(embed["tok"], tokens.astype(jnp.int32)).astype(
         jnp.float32)
@@ -488,11 +484,11 @@ def _window_chunk_fwd(params, kc, vc, tokens, p0, table,
     for li, (bp, window) in enumerate(zip(blocks, cfg.windows)):
         u = _norm(bp["norm"], h, cfg)
         q, k, v = _qkv(bp["attn"], u, idx, window, cfg, held=True)
-        phys, off = _entry(tables[li], idx // bs, window)[0], idx[0] % bs
-        kc = _paged_scatter(kc, li, phys, off, k[0])
-        vc = _paged_scatter(vc, li, phys, off, v[0])
-        a = _span_attention(q, kc[li], vc[li], tables[li], idx, window,
-                            cfg.n_kv_heads, bs)
+        phys, off = entry(tables[li], idx // bs, window)[0], idx[0] % bs
+        kc = paged_scatter(kc, li, phys, off, k[0])
+        vc = paged_scatter(vc, li, phys, off, v[0])
+        a = span_attention(q, kc[li], vc[li], tables[li], idx, window,
+                           cfg.n_kv_heads, bs)
         y, _ = _ffn(bp, u, cfg)
         h = h + matmul_acc32(a, bp["attn"]["wo"]) + y
     return kc, vc, _head_logits(embed, head, h[:, -1], cfg)[0]
@@ -507,11 +503,11 @@ def _build_window_prefill_chunk(cfg: Cohere2Config, bs: int, nb_full: int):
     def chunk_window_prefill(params, kc, vc, state, tokens, host):
         newest, = state
         (p0, table, slot, seat, key_data, temperature, top_k,
-         top_p) = _unpack_chunk(host)
+         top_p) = unpack_chunk(host)
         kc, vc, row = _window_chunk_fwd(params, kc, vc, tokens, p0, table,
                                         cfg, bs, nb_full)
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
-        newest = _seat_newest(newest, slot, seat, tok, kd, key_data)
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
+        newest = seat_newest(newest, slot, seat, tok, kd, key_data)
         return kc, vc, (newest,), tok, kd
 
     return chunk_window_prefill
@@ -525,7 +521,7 @@ def _window_decode_fwd(params, kc, vc, toks, pos, tables, live,
     trash block and are routed to no held expert). Returns logits ``[S,
     V]`` and, per layer, the rows each held expert got ``[n_layers,
     experts_held]``."""
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     h = embedding_lookup(embed["tok"], toks[:, None]).astype(jnp.float32)
     qpos = pos[:, None]
     off = pos % bs
@@ -534,17 +530,17 @@ def _window_decode_fwd(params, kc, vc, toks, pos, tables, live,
     for li, (bp, window) in enumerate(zip(blocks, cfg.windows)):
         u = _norm(bp["norm"], h, cfg)
         q, k, v = _qkv(bp["attn"], u, qpos, window, cfg, held=True)
-        phys = _entry(tables[li], qpos // bs, window)[:, 0]
-        kc = _paged_scatter(kc, li, phys, off, k[:, 0])
-        vc = _paged_scatter(vc, li, phys, off, v[:, 0])
+        phys = entry(tables[li], qpos // bs, window)[:, 0]
+        kc = paged_scatter(kc, li, phys, off, k[:, 0])
+        vc = paged_scatter(vc, li, phys, off, v[:, 0])
         if kernel == "fused":
             a = paged_attention(jnp.swapaxes(q, 1, 2), kc[li], vc[li],
                                 tables[li], qpos, block_size=bs,
                                 window=window)                # [S, H, 1, dh]
             a = jnp.swapaxes(a, 1, 2).reshape(a.shape[0], 1, -1)
         else:
-            a = _span_attention(q, kc[li], vc[li], tables[li], qpos, window,
-                                cfg.n_kv_heads, bs)
+            a = span_attention(q, kc[li], vc[li], tables[li], qpos, window,
+                               cfg.n_kv_heads, bs)
         y, r = _ffn(bp, u, cfg, live)
         rows.append(r)
         h = h + matmul_acc32(a, bp["attn"]["wo"]) + y
@@ -564,15 +560,15 @@ def _build_window_decode_step(cfg: Cohere2Config, bs: int, nb_full: int,
     def step_window_decode(params, kc, vc, state, host):
         newest, = state
         toks, key_data = newest
-        pos, tables, live, temps, top_ks, top_ps = _unpack_decode(host)
+        pos, tables, live, temps, top_ks, top_ps = unpack_decode(host)
         kc, vc, logits, expert_rows = _window_decode_fwd(
             params, kc, vc, toks, pos, tables, live, cfg, bs, nb_full, kernel)
-        toks2, kd2 = _sample_slots(logits, key_data, temps, top_ks, top_ps)
+        toks2, kd2 = sample_slots(logits, key_data, temps, top_ks, top_ps)
         counters = jnp.stack([(expert_rows > 0).sum(), expert_rows.sum(),
                               expert_rows.max()]).astype(jnp.int32)
         rows = jnp.concatenate([
             toks2[:, None],
             jnp.broadcast_to(counters, (toks2.shape[0], 3))], axis=1)
-        return (kc, vc, (_feed_newest(newest, live, toks2, kd2),), rows, kd2)
+        return (kc, vc, (feed_newest(newest, live, toks2, kd2),), rows, kd2)
 
     return step_window_decode

@@ -21,7 +21,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +42,27 @@ from simple_distributed_machine_learning_tpu.ops.layers import (
     linear_init,
 )
 from simple_distributed_machine_learning_tpu.models.lora import lora_delta
+from simple_distributed_machine_learning_tpu.models.serving import (
+    NEWEST_PAIR,
+    # tests/bench_cells/test_bench_cells_ahead.py and
+    # test_bench_cells_program_spans.py import this name from here
+    SEAT_SAMPLE,
+    PagedServing,
+    check_attn_kernel,
+    check_cache_quantization,
+    feed_newest,
+    filter_top_dyn,
+    is_quantized_dtype,
+    memo_build,
+    merged_stage_trees,
+    paged_attend,
+    paged_gather,
+    paged_scatter,
+    sample_slot,
+    sample_slots,
+    seat_newest,
+    storage_dtype,
+)
 from simple_distributed_machine_learning_tpu.ops.losses import log_softmax
 from simple_distributed_machine_learning_tpu.parallel.pipeline import Stage
 
@@ -176,11 +196,11 @@ class GPTConfig:
 
     def paged_serving(self, stages, max_len: int, block_size: int,
                       cache_dtype=None, mesh=None, kernel: str = "dense",
-                      adapters: bool = False) -> "PagedServing":
+                      adapters: bool = False) -> PagedServing:
         """The engine's model interface (:class:`PagedServing`): every
         block is an attention layer with ``n_heads`` K/V heads, and beside
         its blocks a slot has its newest token and sampling key and nothing
-        else (``ahead``: the programs feed them back on the device)."""
+        else (the programs feed them back on the device)."""
         return PagedServing(
             kv_layers=sum(len(s.params["blocks"]) for s in stages),
             kv_heads=self.n_heads, head_dim=self.d_model // self.n_heads,
@@ -191,7 +211,7 @@ class GPTConfig:
             decode=make_paged_decode_step(
                 stages, self, max_len, block_size, cache_dtype, mesh=mesh,
                 kernel=kernel, adapters=adapters),
-            pack_decode=_leave_host_pair_behind, ahead=True)
+            pack_decode=_leave_host_pair_behind)
 
 
 def _block_init(key: jax.Array, cfg: GPTConfig) -> dict:
@@ -661,106 +681,6 @@ def _dense_attn_tail(bp, h, a):
     return h + linear(bp["mlp_out"], jax.nn.gelu(linear(bp["mlp_in"], hn2)))
 
 
-def _cache_dtype(cache_dtype):
-    """K/V cache storage dtype (None = f32). bf16 HALVES decode memory — the
-    cache is the dominant inference allocation at L x B x H x total x dh x 2
-    buffers — at ~1e-3 relative logit error (attention math still
-    accumulates in f32 via einsum promotion). The one copy of the rule for
-    every decoder (cached, beam, pipeline-parallel).
-
-    QUANTIZED storage (``int8``, and the fp8 formats where the jnp build
-    has them) quarters/halves-again the paged pool's block bytes: blocks
-    store narrow-dtype rows plus one f32 scale per (position, head) row —
-    a :class:`QuantKV` pytree instead of a bare array — with quantize
-    fused into every scatter and dequantize into every gather/kernel
-    (:func:`_quantize_rows` / :func:`_paged_gather`). Quantization is a
-    PAGED-pool feature: the speculative draft's slot rows and the solo
-    cached decoder (the parity anchor) carry no scale planes and reject it
-    (:func:`_check_cache_quantization`)."""
-    return jnp.float32 if cache_dtype is None else jnp.dtype(cache_dtype)
-
-
-# fp8 availability is build-dependent on the 0.4.x line; int8 always exists
-_QUANT_QMAX = {"int8": 127.0}
-for _fp8_name, _fp8_qmax in (("float8_e4m3fn", 448.0),
-                             ("float8_e5m2", 57344.0)):
-    if hasattr(jnp, _fp8_name):
-        _QUANT_QMAX[_fp8_name] = _fp8_qmax
-
-
-def _is_quantized_dtype(cache_dtype) -> bool:
-    """Whether ``cache_dtype`` selects the quantized (data + scales) K/V
-    block format — the one predicate pool construction, byte accounting
-    and program tracing all branch on."""
-    return (cache_dtype is not None
-            and jnp.dtype(cache_dtype).name in _QUANT_QMAX)
-
-
-class QuantKV(NamedTuple):
-    """One layer's quantized K or V pool buffer: narrow-dtype block
-    ``data`` (``[n_blocks+1, bs, H*dh]``, a position's heads side by side
-    like the plain buffer's) plus the per-row f32 dequant ``scale`` plane
-    (``[n_blocks+1, bs, H]`` — one scale per written position per head, so
-    incremental decode writes never re-quantize a block's existing rows).
-    A NamedTuple so jax treats the pair as ONE pytree buffer: jit
-    donation, device_put sharding and tree_map'd block copies all flow
-    through unchanged engine/pool code."""
-    data: jax.Array
-    scale: jax.Array
-
-    @property
-    def dtype(self):
-        """The storage dtype — what ``engine_spec``/``ServeSpec`` record
-        as the deployment's cache_dtype."""
-        return self.data.dtype
-
-    @property
-    def nbytes(self) -> int:
-        return self.data.nbytes + self.scale.nbytes
-
-    @property
-    def shape(self):
-        return self.data.shape
-
-
-def _quantize_rows(rows: jax.Array, dtype) -> tuple[jax.Array, jax.Array]:
-    """Quantize K/V rows ``[..., dh]`` to ``dtype`` with one f32 scale per
-    row: ``scale = amax(|row|) / qmax`` (floored so all-zero rows stay
-    finite), data = ``round(row / scale)`` for int8, the plain cast for
-    fp8 (whose format rounds itself). Dequantization is exactly
-    ``data * scale`` — the round trip's relative error is bounded by
-    ~``1/(2*qmax)`` per element (tests/test_paged_attention.py pins it)."""
-    dtype = jnp.dtype(dtype)
-    qmax = _QUANT_QMAX[dtype.name]
-    rows = rows.astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(rows), axis=-1) / qmax, 1e-8)
-    q = rows / scale[..., None]
-    if dtype.name == "int8":
-        q = jnp.clip(jnp.round(q), -127.0, 127.0)
-    return q.astype(dtype), scale.astype(jnp.float32)
-
-
-def _check_cache_quantization(cache_dtype, caller: str,
-                              paged: bool) -> None:
-    """Quantized caches are paged-pool-only (the solo cached decoder is the
-    bit-exactness anchor the quantized pool's pinned tolerance is judged
-    against, and the draft's slot rows have no scale planes); unknown
-    narrow dtypes fail loudly here instead of as a shape error
-    mid-trace."""
-    if cache_dtype is None:
-        return
-    name = jnp.dtype(cache_dtype).name
-    if name in ("float8_e4m3fn", "float8_e5m2") and name not in _QUANT_QMAX:
-        raise ValueError(
-            f"{caller}: cache_dtype={name} is not available in this jnp "
-            f"build — use int8 (always available) or a wider dtype")
-    if _is_quantized_dtype(cache_dtype) and not paged:
-        raise ValueError(
-            f"{caller}: quantized cache_dtype={name} is a paged-pool "
-            f"feature (per-block scales live beside physical blocks); "
-            f"the cached decoder and the draft's slot rows take f32/bf16")
-
-
 # -- tensor-parallel serving ------------------------------------------------
 #
 # The serving builders below accept a GPTConfig with n_tensor_parallel > 1:
@@ -782,7 +702,7 @@ def pack_tp_serve_params(params_list, tp: int):
     axis ``tp``, placed ``P('model')`` by the engine); embed and head are
     replicated. The slices are exactly :func:`_slice_tp_block`'s, so a TP
     engine serves the identical model."""
-    embed, blocks, head = _merged_stage_trees(params_list)
+    embed, blocks, head = merged_stage_trees(params_list)
     stacked = [jax.tree.map(lambda *ls: jnp.stack(ls),
                             *[_slice_tp_block(bp, m, tp) for m in range(tp)])
                for bp in blocks]
@@ -922,166 +842,6 @@ def _validate_tp_serve(cfg: GPTConfig, mesh, caller: str):
     return mesh
 
 
-# Built decode-path programs, keyed by their STATIC config. Every function
-# cached here closes over shape scalars only — params (and therefore the
-# stages' weights and layer count) arrive as traced ARGUMENTS — so two
-# builds with the same key return one shared jitted callable and its
-# compiled executables. Build-time validation still runs per call (it
-# checks the CALLER's stages); only the trace/compile work is shared.
-# This is what keeps a fleet of serving engines (and a test suite full of
-# them) from recompiling identical programs per instance.
-_DECODE_BUILD_CACHE: dict = {}
-
-
-def _memo_build(key: tuple, build):
-    fn = _DECODE_BUILD_CACHE.get(key)
-    if fn is None:
-        fn = _DECODE_BUILD_CACHE[key] = build()
-    return fn
-
-
-class PagedServing(NamedTuple):
-    """What a model hands ``serve/engine.py`` for the paged layout
-    (``cfg.paged_serving(stages, max_len, block_size, cache_dtype, mesh=,
-    kernel=, adapters=)``): the cache's layout and the two programs.
-
-    The pool holds ``kv_layers x kv_heads x head_dim`` K/V rows a position
-    (the CACHE's head count: a grouped-query model's is smaller than its
-    query heads'). ``state_shapes`` is a pytree of per-slot
-    ``jax.ShapeDtypeStruct``: device buffers the pool keeps beside the
-    blocks, one ``[n_slots, *shape]`` array per leaf (a state-space
-    layer's recurrent pair, every slot's newest token and key). With it
-    empty the programs are ``chunk_prefill(params, kc, vc, tokens [1, c],
-    p0, table, key_data, temperature, top_k, top_p) -> (kc, vc, token,
-    key_data)`` and ``decode(params, kc, vc, toks, pos, tables, key_data,
-    temps, top_ks, top_ps) -> (kc, vc, tokens, key_data)``; with state they
-    take ``state`` after ``vc`` (donated like the pool) and return it after
-    ``vc``, the chunk takes ``slot`` after ``table`` and the decode
-    ``live [S]`` after ``tables``. Both of this package's models keep
-    state: what differs is whether any of it is RECURRENT
-    (``cfg.recurrent_state``: a summary of the whole prefix, which rules
-    out prefix sharing and more, ``serve/slots.py``).
-
-    ``pack_chunk`` / ``pack_decode``: where given, the engine hands them a
-    program's host-side arguments (everything after the buffers) and calls
-    the program with what they return instead. Every numpy argument of a
-    call is a transfer of its own, about 0.13 ms each on a v5e's host (my
-    chip run, PR 28); a model may take them as one array, or leave behind
-    what its program does not read.
-
-    ``serve_params``: where given, the two programs read not the stages'
-    parameter trees (``[s.params for s in stages]``, or the engine's
-    ``params=``) but what this function makes of that list, and the engine
-    calls it ONCE, where it takes its parameters: a layout of the same
-    weights that only the programs need (``models/cohere2.py`` holds a
-    window layer's query and key projections in the lane order their
-    rotation reads). Programs and layout travel together: whoever calls
-    ``chunk_prefill`` or ``decode`` by hand hands them
-    ``serve_params(params)``, and a program refuses by name a tree that did
-    not pass through it. ``None`` (every other family): the programs read
-    the stages' trees themselves and the engine keeps the list it was given.
-
-    ``ahead``: the programs keep every slot's newest token and sampling key
-    on the device, in the LAST pair of ``state_shapes``
-    (:data:`NEWEST_PAIR`). The decode reads its ``toks`` and ``key_data``
-    there (the host's copies are not looked at) and writes the live
-    slots' new ones back; the chunk takes ``seat`` after ``slot``:
-    :data:`SEAT_NONE` (a mid-prompt chunk: the slot's pair stays),
-    :data:`SEAT_SAMPLE` (seat its own sample and advanced key) or a token
-    (seat that token and the key it was handed: a resumed request's).
-    Nothing a decode needs then waits for the host to read the last one,
-    and the engine dispatches a tick's decode before it reads the previous
-    tick's tokens (``serve/engine.py::_tick_ahead``).
-
-    ``block``: how many positions a slot's step works on. 1 (GPT, the
-    hybrid): a step reads one token, writes one K/V row and emits one
-    token. ``block > 1`` (``models/sdar.py``, generation by diffusion over
-    blocks): a step is one FORWARD of the slot's block of ``block``
-    positions, which either denoises (fixes some of its still-masked
-    tokens, writes nothing that lasts, emits nothing) or commits (writes
-    the block's K/V rows for good and emits its tokens). The block in
-    progress rides ``state_shapes`` before the newest pair; the chunk's
-    ``seat`` is ``[1 + block]`` (how many tokens of the prompt's remainder
-    open the block, or :data:`SEAT_NONE`, then those tokens); the decode
-    takes ``steps [S]`` (each slot's denoising steps) after ``live`` and
-    returns ``[S, 2 * block + 1]`` int32 for its tokens, which
-    ``unpack_rows(rows, block)`` reads: every slot's block, the forward
-    that fixed each position and whether the forward committed.
-    ``block_forwards(block, steps, masked)``: the denoising forwards a
-    block with ``masked`` open positions takes under a request's
-    ``steps``; the host foresees every slot's phase from it
-    (``models/sdar.py::denoise_forwards``, ``unpack_block_rows``).
-
-    ``counters``: names of int32 counts a decode run makes of itself (the
-    experts it hit, the forwards it ran). A program that names ``n`` hands
-    its tokens as ``[S, w + n]`` int32 instead of ``[S]`` (``w = 1``) or
-    ``[S, w]``: the last ``n`` columns hold the counts, the same in every
-    row. They ride the read-back the engine makes of the tokens anyway (a
-    tick late under ``ahead``; a second transfer would cost 0.13 ms) and
-    become attributes of that tick's ``engine.tick`` span, 0 where a tick
-    ran no decode.
-
-    ``windows``: each K/V layer's KIND, one entry a layer: ``None`` a full
-    layer (it attends every earlier position), else the window in positions
-    (``models/cohere2.py``). Layers of one window value are a GROUP of the
-    pool, with buffers sized by the window, a ring table a slot and blocks
-    handed back behind the window (``serve/slots.py``, "Layer kinds"); the
-    programs are then handed every group's table side by side
-    (``PagedKVPool.device_table``: the full group's ``ceil(max_len /
-    block)`` entries, then each ring). ``()``: every layer is full."""
-    kv_layers: int
-    kv_heads: int
-    head_dim: int
-    state_shapes: tuple
-    chunk_prefill: Callable
-    decode: Callable
-    pack_chunk: Callable | None = None
-    pack_decode: Callable | None = None
-    ahead: bool = False
-    block: int = 1
-    block_forwards: Callable | None = None
-    unpack_rows: Callable | None = None
-    counters: tuple = ()
-    windows: tuple = ()
-    serve_params: Callable | None = None
-
-
-# a chunk's ``seat`` where it is no token (PagedServing, ``ahead``)
-SEAT_NONE = -2
-SEAT_SAMPLE = -1
-# one slot's newest token and sampling key data (PagedServing, ``ahead``)
-NEWEST_PAIR = (jax.ShapeDtypeStruct((), jnp.int32),
-               jax.ShapeDtypeStruct((2,), jnp.uint32))
-
-
-def _seat_newest(pair, slot, seat, tok, kd, key_data):
-    """The pair ``([S] int32, [S, 2] uint32)`` after a prefill chunk of
-    ``slot`` (``PagedServing.ahead``): left as it was (:data:`SEAT_NONE`),
-    or the slot's row set to the chunk's own sample ``tok`` and advanced
-    key ``kd`` (:data:`SEAT_SAMPLE`), or to the token ``seat`` and the key
-    the chunk was handed."""
-    newest, keys = pair
-    own = seat == SEAT_SAMPLE
-    # the clamp changes no value (a negative seat is a code and takes
-    # another branch): it lets the analyzer's bounds pass prove that what
-    # the next decode looks up is a token
-    newest = newest.at[slot].set(jnp.where(
-        seat == SEAT_NONE, newest[slot],
-        jnp.where(own, tok, jnp.maximum(seat, 0))))
-    keys = keys.at[slot].set(jnp.where(
-        seat == SEAT_NONE, keys[slot], jnp.where(own, kd, key_data)))
-    return newest, keys
-
-
-def _feed_newest(pair, live, toks2, kd2):
-    """The pair after a decode step: the ``live`` slots' rows are the
-    step's samples and keys, the others' as they were (a slot between its
-    chunk and its first decode must find what the chunk seated)."""
-    toks, key_data = pair
-    return (jnp.where(live, toks2, toks),
-            jnp.where(live[:, None], kd2, key_data))
-
-
 def _leave_host_pair_behind(toks, pos, tables, live, key_data, *rest):
     """GPT's ``PagedServing.pack_decode``: the engine's host copies of the
     tokens and keys stay behind, the decode reads the device's."""
@@ -1089,7 +849,7 @@ def _leave_host_pair_behind(toks, pos, tables, live, key_data, *rest):
     return (pos, tables, live, *rest)
 
 
-def _dense_block_prefill(bp, h, li, kc, vc, prompt_len, n_heads):
+def dense_block_prefill(bp, h, li, kc, vc, prompt_len, n_heads):
     """One block over the whole prompt [b, T0, d], recording cache row
     ``li`` for positions [0, prompt_len). K/V are cast to the cache's dtype
     (a bf16 cache halves decode memory; reads promote back in the einsum)."""
@@ -1099,7 +859,7 @@ def _dense_block_prefill(bp, h, li, kc, vc, prompt_len, n_heads):
     return _dense_attn_tail(bp, h, causal_attention_core(q, k, v)), kc, vc
 
 
-def _dense_block_step(bp, h, li, kc, vc, i, total, n_heads):
+def dense_block_step(bp, h, li, kc, vc, i, total, n_heads):
     """One block on ONE token [b, 1, d] against cache row ``li``; writes K/V
     at position ``i`` (cast to the cache's dtype). Same scale expression as
     causal_attention_core (divide by sqrt(dh)) so prefill and step compile
@@ -1118,7 +878,7 @@ def _dense_block_step(bp, h, li, kc, vc, i, total, n_heads):
     return _dense_attn_tail(bp, h, a), kc, vc
 
 
-def _validate_decode_build(stages, cfg, prompt_len, n_new, caller):
+def validate_decode_build(stages, cfg, prompt_len, n_new, caller):
     """Shared decoder-build validation (cached + pipeline-parallel): dense
     blocks only, sane lengths, and cfg matching the stages' ACTUAL build
     shapes (a mismatched cfg would otherwise silently clamp pos-table
@@ -1146,7 +906,7 @@ def _validate_decode_build(stages, cfg, prompt_len, n_new, caller):
 
 def _check_embed_matches(stages, cfg: GPTConfig) -> None:
     """The one copy of the cfg-vs-build shape check every decoder-style
-    builder runs (cached/beam via :func:`_validate_decode_build`, the
+    builder runs (cached/beam via :func:`validate_decode_build`, the
     serving slot ops via :func:`_validate_slot_build`): a mismatched cfg
     would otherwise silently clamp pos-table slices past the real seq_len
     instead of raising."""
@@ -1161,24 +921,12 @@ def _check_embed_matches(stages, cfg: GPTConfig) -> None:
             f"the stages were built with")
 
 
-def _merged_stage_trees(params_list):
-    """Re-join per-stage param trees into ``(embed, blocks, head)`` — the
-    one copy shared by every single-device decoder (cached, beam)."""
-    embed = head = None
-    blocks = []
-    for p in params_list:
-        blocks.extend(p["blocks"])
-        embed = p.get("embed", embed)
-        head = p.get("head", head)
-    return embed, blocks, head
-
-
-def _head_logprobs(head, h_last):
+def head_logprobs(head, h_last):
     """[B, d] final hidden -> [B, V] log-probs (ln_f + untied head)."""
     return log_softmax(linear(head["out"], layer_norm(head["ln_f"], h_last)))
 
 
-def _sample_from(row, ks, temperature, top_k, top_p):
+def sample_from(row, ks, temperature, top_k, top_p):
     """Scale/filter/categorical core on a PRE-SPLIT subkey ``ks`` (argmax
     when temperature == 0) — the ONE copy of the sampling math, shared by
     every decoder (cached, recompute, pipeline-parallel)."""
@@ -1192,89 +940,17 @@ def _sample_row(row, k, temperature, top_k, top_p):
     """One decode step on [B, V] log-probs -> ``(tokens, next_key)``.
 
     The ONE copy of the split discipline (exactly one split per sampled
-    token) over :func:`_sample_from` — the single-device decoders call it,
+    token) over :func:`sample_from` — the single-device decoders call it,
     which is what keeps their key streams (and therefore their sampled
     tokens) exactly identical; the pipeline decoder performs the same split
-    itself (uniformly on every device) and calls :func:`_sample_from`."""
+    itself (uniformly on every device) and calls :func:`sample_from`."""
     if temperature > 0.0:
         k, ks = jax.random.split(k)
-        return _sample_from(row, ks, temperature, top_k, top_p), k
+        return sample_from(row, ks, temperature, top_k, top_p), k
     return jnp.argmax(row, axis=-1), k
 
 
-def _filter_top_dyn(scaled: jax.Array, top_k: jax.Array,
-                    top_p: jax.Array) -> jax.Array:
-    """Traced-argument counterpart of :func:`_filter_top` on ONE row [V] —
-    the serving engine's decode tick samples every slot in a single compiled
-    program, so each request's top-k/top-p knobs arrive as device scalars.
-    ``top_k == 0`` disables top-k; ``top_p > 1`` disables top-p. When a
-    filter IS enabled the math mirrors the static version step for step
-    (same k-th-largest threshold, same exclusive-cumsum rule, top-k before
-    top-p with the second sort on the top-k-filtered row), so a served
-    request's filtered distribution matches its solo decode bit for bit."""
-    V = scaled.shape[-1]
-    srt = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)        # descending
-    kth = jnp.take(srt, jnp.clip(top_k, 1, V) - 1, axis=-1)
-    scaled = jnp.where((top_k >= 1) & (scaled < kth), -jnp.inf, scaled)
-    srt = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)        # post-top-k
-    p = jax.nn.softmax(srt, axis=-1)
-    exclusive = jnp.cumsum(p, axis=-1) - p
-    keep = exclusive < top_p                                  # top-1 always
-    thresh = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1)
-    return jnp.where((top_p <= 1.0) & (scaled < thresh), -jnp.inf, scaled)
-
-
-def _sample_dyn(row: jax.Array, key_data: jax.Array, temperature: jax.Array,
-                top_k: jax.Array, top_p: jax.Array
-                ) -> tuple[jax.Array, jax.Array]:
-    """One decode step on ONE row [V] with TRACED sampling params ->
-    ``(token, next_key_data)``. Mirrors :func:`_sample_row`'s key-split
-    discipline exactly — greedy (``temperature == 0``) consumes no
-    randomness, sampling splits once per token — so a served request's key
-    stream (and therefore its tokens) match its solo decode bit for bit.
-    Keys travel as raw uint32 key data so per-slot selection can use
-    ``jnp.where`` (typed key arrays reject it); ``vmap`` over slots is the
-    loop semantics, so per-slot draws equal the unbatched calls."""
-    k = jax.random.wrap_key_data(key_data)
-    nk, ks = jax.random.split(k)
-    safe_t = jnp.where(temperature > 0, temperature, jnp.float32(1.0))
-    filtered = _filter_top_dyn(row / safe_t, top_k, top_p)
-    samp = jax.random.categorical(ks, filtered, axis=-1)
-    tok = jnp.where(temperature > 0, samp, jnp.argmax(row, axis=-1))
-    kd = jnp.where(temperature > 0, jax.random.key_data(nk), key_data)
-    return tok.astype(jnp.int32), kd
-
-
-def _sample_slots(rows: jax.Array, key_data: jax.Array, temps: jax.Array,
-                  top_ks: jax.Array, top_ps: jax.Array
-                  ) -> tuple[jax.Array, jax.Array]:
-    """:func:`_sample_dyn` over the rows ``[S, V]`` with each row's own
-    ``key_data [S, 2]`` and params ``[S]`` -> ``(tokens int32 [S],
-    key_data [S, 2])`` — unless every row is greedy: ``_sample_dyn`` sorts
-    each row twice for its top-k / top-p filters whatever the temperature,
-    and over a vocabulary those sorts cost as much as the rest of a decode
-    tick. A greedy row's result is the same either way (its ``argmax``, its
-    key unchanged), so one sampled row takes ``vmap`` of ``_sample_dyn``
-    for every row and an all-greedy batch the ``argmax`` alone. The ONE
-    row-batch sampler of the serve programs: GPT's below, the hybrid's
-    (``models/jamba.py``) and the pattern family's
-    (``models/nemotron_h.py``) import it; ``models/sdar.py::_sample_block``
-    keeps a ``cond`` of its own, whose sampled branch folds the position
-    into the key."""
-    return jax.lax.cond(
-        jnp.any(temps > 0),
-        lambda: jax.vmap(_sample_dyn)(rows, key_data, temps, top_ks, top_ps),
-        lambda: (jnp.argmax(rows, axis=-1).astype(jnp.int32), key_data))
-
-
-def _sample_slot(row, key_data, temperature, top_k, top_p):
-    """:func:`_sample_slots` for ONE row ``[V]`` with scalar params."""
-    tok, kd = _sample_slots(row[None], key_data[None], temperature[None],
-                            top_k[None], top_p[None])
-    return tok[0], kd[0]
-
-
-def _check_sampling_args(temperature, top_k, top_p, vocab=None):
+def check_sampling_args(temperature, top_k, top_p, vocab=None):
     if (top_k is not None or top_p is not None) and temperature <= 0.0:
         raise ValueError("top_k/top_p filtering needs temperature > 0 "
                          "(greedy decoding ignores the filtered tail)")
@@ -1372,17 +1048,17 @@ def make_cached_decoder(stages, cfg: GPTConfig, prompt_len: int, n_new: int,
         raise ValueError(
             "cached decode is single-device; rebuild the stages with n_seq=1 "
             "(same weights) as make_decoder requires too")
-    _check_sampling_args(temperature, top_k, top_p, cfg.vocab)
-    _check_cache_quantization(cache_dtype, "make_cached_decoder",
-                              paged=False)
-    total = _validate_decode_build(stages, cfg, prompt_len, n_new,
-                                   "make_cached_decoder")
+    check_sampling_args(temperature, top_k, top_p, cfg.vocab)
+    check_cache_quantization(cache_dtype, "make_cached_decoder",
+                             paged=False)
+    total = validate_decode_build(stages, cfg, prompt_len, n_new,
+                                  "make_cached_decoder")
     H, d = cfg.n_heads, cfg.d_model
     dh = d // H
-    cd = _cache_dtype(cache_dtype)
+    cd = storage_dtype(cache_dtype)
     key_ = ("cached_decoder", cfg, prompt_len, n_new, temperature, top_k,
             top_p, jnp.dtype(cd).name)
-    return _memo_build(key_, lambda: _build_cached_decoder(
+    return memo_build(key_, lambda: _build_cached_decoder(
         total, prompt_len, n_new, H, dh, cd, temperature, top_k, top_p))
 
 
@@ -1390,8 +1066,8 @@ def _build_cached_decoder(total, prompt_len, n_new, H, dh, cd,
                           temperature, top_k, top_p):
     from jax import lax
 
-    _merged = _merged_stage_trees
-    _head_row = _head_logprobs
+    _merged = merged_stage_trees
+    _head_row = head_logprobs
 
     def _pick(row, k):
         return _sample_row(row, k, temperature, top_k, top_p)
@@ -1409,7 +1085,7 @@ def _build_cached_decoder(total, prompt_len, n_new, H, dh, cd,
         ids = prompt.astype(jnp.int32)
         h = embedding_lookup(embed["tok"], ids) + embed["pos"][:prompt_len]
         for li, bp in enumerate(blocks):
-            h, kc, vc = _dense_block_prefill(bp, h, li, kc, vc, prompt_len, H)
+            h, kc, vc = dense_block_prefill(bp, h, li, kc, vc, prompt_len, H)
         row = _head_row(head, h[:, -1])
         tok, key = _pick(row, key)          # token for position prompt_len
 
@@ -1421,7 +1097,7 @@ def _build_cached_decoder(total, prompt_len, n_new, H, dh, cd,
             pos = lax.dynamic_slice_in_dim(embed["pos"], i, 1, 0)
             h = embedding_lookup(embed["tok"], tok[:, None]) + pos   # [B,1,d]
             for li, bp in enumerate(blocks):
-                h, kc, vc = _dense_block_step(bp, h, li, kc, vc, i, total, H)
+                h, kc, vc = dense_block_step(bp, h, li, kc, vc, i, total, H)
             row = _head_row(head, h[:, 0])
             nxt, k = _pick(row, k)
             return (kc, vc, nxt, k), tok
@@ -1448,7 +1124,7 @@ def _validate_slot_build(stages, cfg: GPTConfig, max_len: int,
     slices, not the whole model), ``max_len`` within the position
     table, and no quantized cache dtype (the draft's slot rows carry no
     scale planes; the paged validator re-allows quantization)."""
-    _check_cache_quantization(cache_dtype, caller, paged=False)
+    check_cache_quantization(cache_dtype, caller, paged=False)
     if cfg.n_experts > 0:
         raise ValueError(
             f"{caller} supports dense-MLP blocks only — MoE capacity is a "
@@ -1498,13 +1174,13 @@ def make_slot_prefill(stages, cfg: GPTConfig, max_len: int,
     solo decoder's prefill shapes and math — shared :func:`_dense_qkv` /
     ``causal_attention_core`` / :func:`_dense_attn_tail`), writes each
     layer's K/V rows into row ``slot`` at positions ``[0, T0)``, and
-    samples a token with the given params and key (:func:`_sample_dyn`'s
+    samples a token with the given params and key (:func:`sample_dyn`'s
     sentinels: ``top_k=0`` / ``top_p=2.0`` disable; the engine discards
     both, only the cache write matters to a draft). Retraces per distinct
     prompt length (the prompt shape is static).
 
     ``kc``/``vc``: the draft's buffers, ``[L, n_slots, H, max_len, dh]`` in
-    the :func:`_cache_dtype` storage dtype. They are DONATED — the engine
+    the :func:`storage_dtype` storage dtype. They are DONATED — the engine
     threads the returned buffers back, and donation lets XLA update the
     slot row in place instead of copying the buffer per call. Single-device
     like :func:`make_slot_propose`: a tensor-parallel ``cfg`` is refused.
@@ -1513,15 +1189,15 @@ def make_slot_prefill(stages, cfg: GPTConfig, max_len: int,
                          cache_dtype)
     _refuse_tp_draft(cfg, "make_slot_prefill")
     H = cfg.n_heads
-    return _memo_build(("slot_prefill", cfg, max_len),
-                       lambda: _build_slot_prefill(H))
+    return memo_build(("slot_prefill", cfg, max_len),
+                      lambda: _build_slot_prefill(H))
 
 
 def _build_slot_prefill(H):
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def prefill(params, kc, vc, prompt, slot, key_data, temperature,
                 top_k, top_p):
-        embed, blocks, head = _merged_stage_trees(params)
+        embed, blocks, head = merged_stage_trees(params)
         t0 = prompt.shape[1]
         ids = prompt.astype(jnp.int32)
         h = embedding_lookup(embed["tok"], ids) + embed["pos"][:t0]
@@ -1532,8 +1208,8 @@ def _build_slot_prefill(H):
             vc = jax.lax.dynamic_update_slice(
                 vc, v.astype(vc.dtype)[None], (li, slot, 0, 0, 0))
             h = _dense_attn_tail(bp, h, causal_attention_core(q, k_, v))
-        row = _head_logprobs(head, h[:, -1])[0]           # [V]
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
+        row = head_logprobs(head, h[:, -1])[0]           # [V]
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
         return kc, vc, tok, kd
 
     return prefill
@@ -1543,7 +1219,7 @@ def _dense_block_step_slots(bp, h, li, kc, vc, pos, n_heads):
     """One block of the DRAFT on one token per SLOT (``h``: [S, 1, d])
     against its cache row ``li``; each slot writes its new K/V at its OWN
     position (``pos``: [S]) and attends ``[0, pos]``. Per-slot math is
-    exactly :func:`_dense_block_step`'s (same scale expression, same
+    exactly :func:`dense_block_step`'s (same scale expression, same
     einsums, same masked-row softmax), and every slot's output depends only
     on its own cache row."""
     q, knew, vnew = _dense_qkv(bp, h, n_heads)            # [S, H, 1, dh]
@@ -1574,7 +1250,7 @@ def _slot_decode_fwd(blocks, embed, head, kc, vc, toks, pos, H):
     h = embedding_lookup(embed["tok"], toks[:, None]) + pe
     for li, bp in enumerate(blocks):
         h, kc, vc = _dense_block_step_slots(bp, h, li, kc, vc, pos, H)
-    return kc, vc, _head_logprobs(head, h[:, 0])           # rows: [S, V]
+    return kc, vc, head_logprobs(head, h[:, 0])           # rows: [S, V]
 
 
 def _validate_paged_build(stages, cfg: GPTConfig, max_len: int,
@@ -1584,174 +1260,9 @@ def _validate_paged_build(stages, cfg: GPTConfig, max_len: int,
     Quantized cache dtypes are allowed HERE (the paged pool carries the
     per-block scale planes) — only their availability is checked."""
     _validate_slot_build(stages, cfg, max_len, caller)
-    _check_cache_quantization(cache_dtype, caller, paged=True)
+    check_cache_quantization(cache_dtype, caller, paged=True)
     if block_size < 1:
         raise ValueError(f"{caller} needs block_size >= 1, got {block_size}")
-
-
-def _paged_scatter(kc, li, phys, off, rows):
-    """Land K/V ``rows`` (``[..., H, dh]``, aligned with the ``phys``/
-    ``off`` index arrays ``[...]``) in layer ``li``'s buffer of a paged
-    pool — the ONE scatter every paged program uses. ``kc`` is the pool's
-    tuple of per-layer buffers ``[n_blocks+1, bs, H*dh]``: a position's
-    heads lie side by side in one row, the two indexed axes lead and are
-    adjacent, so the write is one contiguous row a position and XLA keeps
-    it in place on the donated buffer. Plain buffers cast to the storage
-    dtype; :class:`QuantKV` buffers quantize each head's row and land its
-    scale in the matching plane, so a quantized pool never holds a
-    half-updated (data, scale) pair."""
-    buf = kc[li]
-    if isinstance(buf, QuantKV):
-        qd, sc = _quantize_rows(rows, buf.data.dtype)
-        new = QuantKV(
-            buf.data.at[phys, off].set(qd.reshape(*qd.shape[:-2], -1)),
-            buf.scale.at[phys, off].set(sc))
-    else:
-        new = buf.at[phys, off].set(
-            rows.reshape(*rows.shape[:-2], -1).astype(buf.dtype))
-    return kc[:li] + (new,) + kc[li + 1:]
-
-
-def _paged_gather(kc, li, table, n_heads):
-    """Layer ``li``'s K or V rows of a sequence, assembled from the paged
-    pool for the dense-math attention path. ``table``: logical->physical
-    block ids, ``[NB]`` (one sequence) or ``[S, NB]`` (one per slot);
-    ``n_heads``: the heads in a pool row. Returns ``[..., H, NB*bs, dh]``
-    with position ``p`` of the sequence at flattened row index ``p`` —
-    EXACTLY a contiguous cache row's order (the cached decoder's), so the
-    attention math downstream is unchanged and the trailing garbage rows
-    (trash-block entries past the allocated span) are removed by the same
-    position mask that hides not-yet-written rows. :class:`QuantKV`
-    buffers dequantize
-    (``data * scale``, f32) so the downstream einsums see ordinary rows."""
-    buf = kc[li]
-    quant = isinstance(buf, QuantKV)
-    rows = (buf.data if quant else buf)[table]    # [..., NB, bs, H*dh]
-    lead = rows.shape[:-3]
-    span = rows.shape[-3] * rows.shape[-2]
-    rows = rows.reshape(*lead, span, n_heads, -1)
-    if quant:
-        sc = buf.scale[table].reshape(*lead, span, n_heads)
-        rows = rows.astype(jnp.float32) * sc[..., None]
-    return jnp.moveaxis(rows, -3, -2)             # [..., H, span, dh]
-
-
-#: cached positions one step of :func:`_span_attention` gathers and scores:
-#: whole pool blocks, ``heads x chunk x _ATTEND_ROWS`` float32 scores a step
-_ATTEND_ROWS = 512
-
-_NEG = -1e30        # a masked score: finite, so an empty step changes nothing
-
-
-def _entry(table, block, window):
-    """The physical block of logical block(s) ``block`` through a layer's
-    ``table [N, NB]`` (``block [N, ...]``): entry ``block``, or in a window
-    layer's ring ``block % NB``."""
-    if window is not None:
-        block = block % table.shape[-1]
-    flat = jnp.take_along_axis(table, block.reshape(block.shape[0], -1),
-                               axis=1)
-    return flat.reshape(block.shape)
-
-
-def _span_attention(q, kbuf, vbuf, table, qpos, window, kv: int, bs: int):
-    """Softmax attention of ``q [N, L, H, dh]`` at positions ``qpos [N, L]``
-    (non-decreasing along ``L``) over ONE layer's pool buffers ``kbuf`` /
-    ``vbuf [n_blocks + 1, bs, KV dh]`` (``kv`` K/V heads a row, each read by
-    its ``H / kv`` query heads) through that layer's ``table [N, NB]``,
-    over the live positions alone: steps of :data:`_ATTEND_ROWS`
-    positions from the one that holds the oldest query's first visible key
-    to the one that holds the newest query, a running maximum and sum
-    between them (``ops/paged_attention.py``'s walk in ``jax.numpy``, for a
-    chunk's many query rows). A step's blocks before the first live one or
-    past the newest fetch that one instead, and the position mask removes
-    them: no block wholly behind a window, and none past the newest query's,
-    is gathered, whatever the table holds there. Returns ``[N, L, H dh]``
-    float32. The prefill chunks of ``models/cohere2.py`` (a window or a full
-    layer) and of ``models/zaya.py`` (``window=None``) attend through it."""
-    f32 = jnp.float32
-    n, lq, heads, dh = q.shape
-    g = heads // kv
-    # operands in the POOL's dtype, sums in float32, as every matmul here
-    # reads its weights: a bfloat16 pool's rows go to the matrix unit as
-    # they lie (what the chip's one-pass float32 product makes of them
-    # anyway, ops/paged_attention.py), a float32 pool keeps float32. A K/V
-    # head's group of query heads are ROWS of one product, [N, KV, g L, dh]
-    # against [N, KV, R, dh]: the scores' lanes are the step's positions
-    q = jnp.moveaxis(q.reshape(n, lq, kv, g, dh) / math.sqrt(dh), 1, 3)
-    q = q.reshape(n, kv, g * lq, dh).astype(kbuf.dtype)
-    rowpos = jnp.tile(qpos, (1, g))[:, None, :, None]        # [N, 1, g L, 1]
-    blocks = max(1, min(table.shape[-1], _ATTEND_ROWS // bs))
-    rows = blocks * bs
-    oldest = qpos[:, 0] if window is None else jnp.maximum(
-        qpos[:, 0] - (window - 1), 0)
-    first_blk = (0 * oldest if window is None else oldest // bs)[:, None]
-    last_blk = (qpos[:, -1] // bs)[:, None]
-    batched = ((0, 1), (0, 1))
-
-    def step(i, carry):
-        m_prev, l_prev, acc = carry
-        want = i * blocks + jnp.arange(blocks)[None, :]          # [1, G]
-        phys = _entry(table, jnp.clip(want, first_blk, last_blk), window)
-        k = jnp.swapaxes(kbuf[phys].reshape(n, rows, kv, dh), 1, 2)
-        v = jnp.swapaxes(vbuf[phys].reshape(n, rows, kv, dh), 1, 2)
-        back = rowpos - (i * rows + jnp.arange(rows))    # [N, 1, g L, R]
-        mask = back >= 0
-        if window is not None:
-            mask &= back < window
-        scores = lambda q: jax.lax.dot_general(  # noqa: E731
-            q, k, (((3,), (3,)), batched), preferred_element_type=f32)
-        m_new = jnp.maximum(m_prev, jnp.where(mask, scores(q), _NEG).max(
-            axis=-1, keepdims=True))
-        # the scores a second time, behind a barrier that keeps the compiler
-        # from sharing the first product: each product then keeps its
-        # epilogue (the row maximum; exp and the cast) in its own fusion
-        # and the float32 scores of a step, heads x chunk x step x 4 bytes,
-        # are never written out (they were 800 MB of a step's traffic and
-        # two thirds of its time on the chip: PERF.md section 6, PR 44)
-        p = jnp.where(mask, jnp.exp(
-            scores(jax.lax.optimization_barrier(q)) - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        return (m_new, l_prev * corr + p.sum(axis=-1, keepdims=True),
-                acc * corr + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((3,), (2,)), batched),
-                    preferred_element_type=f32))
-
-    lo = (jnp.min(oldest) // rows if window is not None else 0)
-    hi = jnp.max(qpos[:, -1]) // rows + 1
-    _, l, acc = jax.lax.fori_loop(lo, hi, step, (
-        jnp.full((n, kv, g * lq, 1), _NEG, f32),
-        jnp.zeros((n, kv, g * lq, 1), f32),
-        jnp.zeros((n, kv, g * lq, dh), f32)))
-    out = (acc / jnp.maximum(l, 1e-30)).reshape(n, kv, g, lq, dh)
-    return jnp.moveaxis(out, 3, 1).reshape(n, lq, heads * dh)
-
-
-def _paged_attend(kc, vc, li, q, tables, qpos, bs):
-    """The FUSED attention path: one Pallas pass over layer ``li``'s
-    physical blocks (gather + mask + online-softmax attention, dequant
-    fused for :class:`QuantKV` pools) — see ``ops/paged_attention.py``.
-    The layer's buffer goes over whole, as the pool holds it. ``q``:
-    [S, H, K, dh]; ``qpos``: [S, K]. Returns f32 [S, H, K, dh], exactly
-    the dense-math path's masked attention output."""
-    from simple_distributed_machine_learning_tpu.ops.paged_attention import (
-        paged_attention,
-    )
-    k, v = kc[li], vc[li]
-    if isinstance(k, QuantKV):
-        return paged_attention(q, k.data, v.data, tables, qpos,
-                               block_size=bs, kscale=k.scale,
-                               vscale=v.scale)
-    return paged_attention(q, k, v, tables, qpos, block_size=bs)
-
-
-def _check_attn_kernel(kernel: str, caller: str) -> str:
-    if kernel not in ("dense", "fused"):
-        raise ValueError(
-            f"{caller}: kernel must be 'dense' (gather-then-dense "
-            f"attention, the parity anchor) or 'fused' (the Pallas "
-            f"paged-attention kernel), got {kernel!r}")
-    return kernel
 
 
 def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
@@ -1770,17 +1281,17 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     another request prefilled) and the chunk's own freshly written rows.
     The engine interleaves these chunks with decode ticks so a long prompt
     never stalls in-flight requests; the last chunk's final position feeds
-    the head and samples the request's first token (:func:`_sample_slot` —
+    the head and samples the request's first token (:func:`sample_slot` —
     the engine discards the sampled token and key for non-final chunks, so
     the request's key stream advances exactly once, at the same point as
     its solo decode).
 
     ``state`` is ``((newest [S] int32, keys [S, 2] uint32),)``, every
     slot's newest token and sampling key (:data:`NEWEST_PAIR`,
-    ``PagedServing.ahead``), and ``seat`` says what this chunk leaves there
+    ``PagedServing``), and ``seat`` says what this chunk leaves there
     for ``slot``: nothing (:data:`SEAT_NONE`, a mid-prompt chunk), its own
     sample and advanced key (:data:`SEAT_SAMPLE`), or a resumed request's
-    stored token with the key handed in (:func:`_seat_newest`). The decode
+    stored token with the key handed in (:func:`seat_newest`). The decode
     step reads its inputs there, so the slot's first decode waits for no
     host.
 
@@ -1817,9 +1328,9 @@ def make_paged_prefill_chunk(stages, cfg: GPTConfig, max_len: int,
     dh = cfg.d_model // H
     key_ = ("paged_chunk", cfg, max_len, block_size, mesh, adapters)
     if cfg.n_tensor_parallel > 1:
-        return _memo_build(key_, lambda: _build_paged_prefill_chunk_tp(
+        return memo_build(key_, lambda: _build_paged_prefill_chunk_tp(
             cfg, bs, dh, mesh, adapters))
-    return _memo_build(key_, lambda: _build_paged_prefill_chunk(
+    return memo_build(key_, lambda: _build_paged_prefill_chunk(
         H, bs, dh, adapters))
 
 
@@ -1839,28 +1350,28 @@ def _paged_chunk_fwd(blocks, embed, head, kc, vc, tokens, p0, table, H, bs,
     for li, bp in enumerate(blocks):
         q, k_, v = _dense_qkv(bp, h, H,           # [1, H, c, dh]
                               None if ab_at is None else ab_at(li))
-        kc = _paged_scatter(kc, li, phys, off, k_[0].swapaxes(0, 1))
-        vc = _paged_scatter(vc, li, phys, off, v[0].swapaxes(0, 1))
-        krow = _paged_gather(kc, li, table, H)    # [H, span, dh]
-        vrow = _paged_gather(vc, li, table, H)
+        kc = paged_scatter(kc, li, phys, off, k_[0].swapaxes(0, 1))
+        vc = paged_scatter(vc, li, phys, off, v[0].swapaxes(0, 1))
+        krow = paged_gather(kc, li, table, H)    # [H, span, dh]
+        vrow = paged_gather(vc, li, table, H)
         scores = jnp.einsum("bhqd,hkd->bhqk", q, krow) / math.sqrt(dh)
         scores = jnp.where(live, scores, -jnp.inf)
         a = jnp.einsum("bhqk,hkd->bhqd",
                        jax.nn.softmax(scores, axis=-1), vrow)
         h = tail(bp, h, a)
-    return kc, vc, _head_logprobs(head, h[:, -1])[0]    # row: [V]
+    return kc, vc, head_logprobs(head, h[:, -1])[0]    # row: [V]
 
 
 def _build_paged_prefill_chunk(H, bs, dh, adapters=False):
     def run(params, kc, vc, state, tokens, p0, table, slot, seat, key_data,
             temperature, top_k, top_p, ab_at=None):
-        embed, blocks, head = _merged_stage_trees(params)
+        embed, blocks, head = merged_stage_trees(params)
         kc, vc, row = _paged_chunk_fwd(blocks, embed, head, kc, vc,
                                        tokens, p0, table, H, bs, dh,
                                        _dense_attn_tail, ab_at)
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
         pair, = state
-        return (kc, vc, (_seat_newest(pair, slot, seat, tok, kd, key_data),),
+        return (kc, vc, (seat_newest(pair, slot, seat, tok, kd, key_data),),
                 tok, kd)
 
     if adapters:
@@ -1895,9 +1406,9 @@ def _build_paged_prefill_chunk_tp(cfg, bs, dh, mesh, adapters=False):
                                        tokens, p0, table, H_loc, bs, dh,
                                        tail, ab_at)
         row = _close_rows(row)
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
         pair, = state
-        return (kc, vc, (_seat_newest(pair, slot, seat, tok, kd, key_data),),
+        return (kc, vc, (seat_newest(pair, slot, seat, tok, kd, key_data),),
                 tok, kd)
 
     if adapters:
@@ -1934,15 +1445,15 @@ def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
     Every slot's input token and sampling key are ``state``'s pair
     ``((newest [S] int32, keys [S, 2] uint32),)``, where the chunk that
     finished the slot's prompt seated them and where the ``live`` slots'
-    new ones go back (:func:`_feed_newest`, ``PagedServing.ahead``): the
+    new ones go back (:func:`feed_newest`, ``PagedServing``): the
     next step needs nothing from the host that this one computes, and the
     engine launches it before it has read this one's tokens.
     Each slot consumes its carried token at its own position, lands its new
     K/V via a per-slot scatter into physical block ``tables[s, pos // bs]``
     at offset ``pos % bs``, attends the row assembled from its block table
-    (:func:`_paged_gather`) masked to ``<= pos``, and samples with its own
-    params and key stream (:func:`_sample_slots`: ``vmap`` of
-    :func:`_sample_dyn` — loop semantics, per-slot draws equal the
+    (:func:`paged_gather`) masked to ``<= pos``, and samples with its own
+    params and key stream (:func:`sample_slots`: ``vmap`` of
+    :func:`sample_dyn` — loop semantics, per-slot draws equal the
     unbatched calls — or, every slot greedy, the ``argmax``). Values for live
     positions are the cached decoder's (same numbers, different storage)
     and the mask removes everything else: the bit-exactness anchor
@@ -1972,15 +1483,15 @@ def make_paged_decode_step(stages, cfg: GPTConfig, max_len: int,
     _validate_paged_build(stages, cfg, max_len, block_size,
                           "make_paged_decode_step", cache_dtype)
     mesh = _validate_tp_serve(cfg, mesh, "make_paged_decode_step")
-    _check_attn_kernel(kernel, "make_paged_decode_step")
+    check_attn_kernel(kernel, "make_paged_decode_step")
     H, bs = cfg.n_heads, block_size
     dh = cfg.d_model // H
     key_ = ("paged_decode", cfg, max_len, block_size, mesh, kernel,
             adapters)
     if cfg.n_tensor_parallel > 1:
-        return _memo_build(key_, lambda: _build_paged_decode_step_tp(
+        return memo_build(key_, lambda: _build_paged_decode_step_tp(
             cfg, bs, dh, mesh, kernel, adapters))
-    return _memo_build(key_, lambda: _build_paged_decode_step(
+    return memo_build(key_, lambda: _build_paged_decode_step(
         H, bs, dh, kernel, adapters))
 
 
@@ -1991,7 +1502,7 @@ def _paged_decode_fwd(blocks, embed, head, kc, vc, toks, pos, tables, H, bs,
     attention path: ``"dense"`` gathers each slot's table span into a
     dense row buffer and runs masked softmax-attention einsums over it
     (two passes over resident K/V); ``"fused"`` runs the one-pass Pallas
-    flash-decode kernel (:func:`_paged_attend`). Scatter (and quantize,
+    flash-decode kernel (:func:`paged_attend`). Scatter (and quantize,
     for :class:`QuantKV` pools) happens before either path attends, so
     the new token's row is visible at its own position in both."""
     pe = jnp.take(embed["pos"], pos, axis=0)[:, None]     # [S, 1, d]
@@ -2005,20 +1516,20 @@ def _paged_decode_fwd(blocks, embed, head, kc, vc, toks, pos, tables, H, bs,
     for li, bp in enumerate(blocks):
         q, knew, vnew = _dense_qkv(bp, h, H,              # [S, H, 1, dh]
                                    None if ab_at is None else ab_at(li))
-        kc = _paged_scatter(kc, li, phys, off, knew[:, :, 0, :])
-        vc = _paged_scatter(vc, li, phys, off, vnew[:, :, 0, :])
+        kc = paged_scatter(kc, li, phys, off, knew[:, :, 0, :])
+        vc = paged_scatter(vc, li, phys, off, vnew[:, :, 0, :])
         if kernel == "fused":
-            a = _paged_attend(kc, vc, li, q, tables, pos[:, None], bs)
+            a = paged_attend(kc, vc, li, q, tables, pos[:, None], bs)
         else:
-            krow = _paged_gather(kc, li, tables, H)       # [S,H,span,dh]
-            vrow = _paged_gather(vc, li, tables, H)
+            krow = paged_gather(kc, li, tables, H)       # [S,H,span,dh]
+            vrow = paged_gather(vc, li, tables, H)
             scores = (jnp.einsum("bhqd,bhkd->bhqk", q, krow)
                       / math.sqrt(dh))
             scores = jnp.where(live, scores, -jnp.inf)
             a = jnp.einsum("bhqk,bhkd->bhqd",
                            jax.nn.softmax(scores, axis=-1), vrow)
         h = tail(bp, h, a)
-    return kc, vc, _head_logprobs(head, h[:, 0])          # rows: [S, V]
+    return kc, vc, head_logprobs(head, h[:, 0])          # rows: [S, V]
 
 
 def _build_paged_decode_step(H, bs, dh, kernel="dense", adapters=False):
@@ -2026,12 +1537,12 @@ def _build_paged_decode_step(H, bs, dh, kernel="dense", adapters=False):
             ab_at=None):
         pair, = state
         toks, key_data = pair
-        embed, blocks, head = _merged_stage_trees(params)
+        embed, blocks, head = merged_stage_trees(params)
         kc, vc, rows = _paged_decode_fwd(blocks, embed, head, kc, vc, toks,
                                          pos, tables, H, bs, dh,
                                          _dense_attn_tail, kernel, ab_at)
-        toks2, kd2 = _sample_slots(rows, key_data, temps, top_ks, top_ps)
-        return kc, vc, (_feed_newest(pair, live, toks2, kd2),), toks2, kd2
+        toks2, kd2 = sample_slots(rows, key_data, temps, top_ks, top_ps)
+        return kc, vc, (feed_newest(pair, live, toks2, kd2),), toks2, kd2
 
     if adapters:
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
@@ -2066,8 +1577,8 @@ def _build_paged_decode_step_tp(cfg, bs, dh, mesh, kernel="dense",
                                          pos, tables, H_loc, bs, dh, tail,
                                          kernel, ab_at)
         rows = _close_rows(rows)
-        toks2, kd2 = _sample_slots(rows, key_data, temps, top_ks, top_ps)
-        return kc, vc, (_feed_newest(pair, live, toks2, kd2),), toks2, kd2
+        toks2, kd2 = sample_slots(rows, key_data, temps, top_ks, top_ps)
+        return kc, vc, (feed_newest(pair, live, toks2, kd2),), toks2, kd2
 
     if adapters:
         def body(params, kc, vc, state, pos, tables, live, temps, top_ks,
@@ -2110,7 +1621,7 @@ def make_paged_block_copy():
 
         return copy
 
-    return _memo_build(("paged_block_copy",), build)
+    return memo_build(("paged_block_copy",), build)
 
 
 def make_paged_block_write():
@@ -2130,7 +1641,7 @@ def make_paged_block_write():
 
         return write
 
-    return _memo_build(("paged_block_write",), build)
+    return memo_build(("paged_block_write",), build)
 
 
 def make_adapter_bank_update():
@@ -2149,7 +1660,7 @@ def make_adapter_bank_update():
 
         return update
 
-    return _memo_build(("adapter_bank_update",), build)
+    return memo_build(("adapter_bank_update",), build)
 
 
 # -- speculative decoding ---------------------------------------------------
@@ -2218,13 +1729,13 @@ def _spec_accept_sampled(rows, drafts, draft_rows, valid_n, key_data,
     def samp_step(carry, j):
         kd, alive = carry
         k = jax.random.wrap_key_data(kd)
-        nk, ks = jax.random.split(k)               # _sample_dyn's split
-        pt_log = _filter_top_dyn(rows[j] / safe_t, top_k, top_p)
+        nk, ks = jax.random.split(k)               # sample_dyn's split
+        pt_log = filter_top_dyn(rows[j] / safe_t, top_k, top_p)
         pt = jax.nn.softmax(pt_log)
         jj = jnp.minimum(j, K - 2)
         d = drafts[jj]
-        qt = jax.nn.softmax(_filter_top_dyn(draft_rows[jj] / safe_t,
-                                            top_k, top_p))
+        qt = jax.nn.softmax(filter_top_dyn(draft_rows[jj] / safe_t,
+                                           top_k, top_p))
         accept = (jax.random.uniform(ks)
                   < jnp.minimum(pt[d] / jnp.maximum(qt[d], 1e-30), 1.0))
         # rejection: one more split funds the residual draw; an empty
@@ -2318,22 +1829,22 @@ def make_slot_propose(stages, cfg: GPTConfig, max_len: int, spec_k: int,
     _refuse_tp_draft(cfg, "make_slot_propose")
     H = cfg.n_heads
     key_ = ("slot_propose", cfg, max_len, spec_k)
-    return _memo_build(key_, lambda: _build_slot_propose(H, spec_k,
-                                                         max_len))
+    return memo_build(key_, lambda: _build_slot_propose(H, spec_k,
+                                                        max_len))
 
 
 def _build_slot_propose(H, K, ml):
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def propose(params, kc, vc, toks, pos, key_data, temps, top_ks,
                 top_ps):
-        embed, blocks, head = _merged_stage_trees(params)
+        embed, blocks, head = merged_stage_trees(params)
 
         def step(carry, j):
             kc, vc, tok, kd = carry
             p = jnp.minimum(pos + j, ml - 1)
             kc, vc, rows = _slot_decode_fwd(blocks, embed, head, kc, vc,
                                             tok, p, H)
-            nxt, kd = _sample_slots(rows, kd, temps, top_ks, top_ps)
+            nxt, kd = sample_slots(rows, kd, temps, top_ks, top_ps)
             return (kc, vc, nxt, kd), (nxt, rows)
 
         (kc, vc, _, kd2), (drafts, rows) = jax.lax.scan(
@@ -2362,20 +1873,20 @@ def _paged_verify_fwd(blocks, embed, head, kc, vc, xs, qpos, wphys, woff,
     for li, bp in enumerate(blocks):
         q, knew, vnew = _dense_qkv(                          # [S, H, K, dh]
             bp, h, H, None if ab_at is None else ab_at(li))
-        kc = _paged_scatter(kc, li, wphys, woff, knew.swapaxes(1, 2))
-        vc = _paged_scatter(vc, li, wphys, woff, vnew.swapaxes(1, 2))
+        kc = paged_scatter(kc, li, wphys, woff, knew.swapaxes(1, 2))
+        vc = paged_scatter(vc, li, wphys, woff, vnew.swapaxes(1, 2))
         if kernel == "fused":
-            a = _paged_attend(kc, vc, li, q, tables, qpos, bs)
+            a = paged_attend(kc, vc, li, q, tables, qpos, bs)
         else:
-            krow = _paged_gather(kc, li, tables, H)          # [S,H,span,dh]
-            vrow = _paged_gather(vc, li, tables, H)
+            krow = paged_gather(kc, li, tables, H)          # [S,H,span,dh]
+            vrow = paged_gather(vc, li, tables, H)
             scores = (jnp.einsum("bhqd,bhkd->bhqk", q, krow)
                       / math.sqrt(dh))
             scores = jnp.where(live, scores, -jnp.inf)
             a = jnp.einsum("bhqk,bhkd->bhqd",
                            jax.nn.softmax(scores, axis=-1), vrow)
         h = tail(bp, h, a)
-    return kc, vc, _head_logprobs(head, h)                   # [S, K, V]
+    return kc, vc, head_logprobs(head, h)                   # [S, K, V]
 
 
 def make_paged_verify_step(stages, cfg: GPTConfig, max_len: int,
@@ -2409,15 +1920,15 @@ def make_paged_verify_step(stages, cfg: GPTConfig, max_len: int,
                           "make_paged_verify_step", cache_dtype)
     _check_spec_k(spec_k, "make_paged_verify_step")
     mesh = _validate_tp_serve(cfg, mesh, "make_paged_verify_step")
-    _check_attn_kernel(kernel, "make_paged_verify_step")
+    check_attn_kernel(kernel, "make_paged_verify_step")
     H, bs = cfg.n_heads, block_size
     dh = cfg.d_model // H
     key_ = ("paged_verify", cfg, max_len, block_size, spec_k, mesh, kernel,
             adapters)
     if cfg.n_tensor_parallel > 1:
-        return _memo_build(key_, lambda: _build_paged_verify_step_tp(
+        return memo_build(key_, lambda: _build_paged_verify_step_tp(
             cfg, spec_k, max_len, bs, dh, mesh, kernel, adapters))
-    return _memo_build(key_, lambda: _build_paged_verify_step(
+    return memo_build(key_, lambda: _build_paged_verify_step(
         H, spec_k, max_len, bs, dh, kernel, adapters))
 
 
@@ -2438,7 +1949,7 @@ def _build_paged_verify_step(H, K, ml, bs, dh, kernel="dense",
                              adapters=False):
     def run(params, kc, vc, toks, pos, drafts, draft_rows, valid_n,
             tables, key_data, temps, top_ks, top_ps, ab_at=None):
-        embed, blocks, head = _merged_stage_trees(params)
+        embed, blocks, head = merged_stage_trees(params)
         xs = jnp.concatenate([toks[:, None], drafts[:, :-1]], axis=1)
         qpos, wphys, woff = _paged_verify_routing(pos, valid_n, tables, K,
                                                   bs, ml)
@@ -2546,7 +2057,7 @@ def make_paged_spec_tick(stages, cfg: GPTConfig, draft_stages,
     # the draft's slot rows carry no scales: a quantized TARGET dtype falls
     # back to f32 for the draft (the engine builds its draft buffers with
     # the same rule)
-    draft_cd = None if _is_quantized_dtype(cache_dtype) else cache_dtype
+    draft_cd = None if is_quantized_dtype(cache_dtype) else cache_dtype
     propose = make_slot_propose(draft_stages, draft_cfg, max_len, spec_k,
                                 draft_cd)
     verify = make_paged_verify_step(stages, cfg, max_len, block_size,
@@ -2582,14 +2093,14 @@ def make_paged_spec_tick(stages, cfg: GPTConfig, draft_stages,
 
         return tick
 
-    return _memo_build(("paged_spec_tick", cfg, draft_cfg, max_len,
-                        block_size, spec_k, kernel, adapters), build)
+    return memo_build(("paged_spec_tick", cfg, draft_cfg, max_len,
+                       block_size, spec_k, kernel, adapters), build)
 
 
 # The memoized decode-path builders, by name — the single list the
 # analyzer's program registry and host-side AST lint key off
 # (analysis/programs.py enumerates these as compiled entry points;
-# analysis/hostlint.py checks each definition routes through _memo_build
+# analysis/hostlint.py checks each definition routes through memo_build
 # and that no call site bypasses it).
 DECODE_BUILDERS = {
     "make_cached_decoder": make_cached_decoder,
@@ -2658,7 +2169,7 @@ def make_decoder(stages, prompt_len: int, n_new: int,
             "token is conditioned on the prompt's last position")
     # vocab-bound validation of top_k happens at trace time in _filter_top
     # against the actual row width — no reach into the param layout here
-    _check_sampling_args(temperature, top_k, top_p)
+    check_sampling_args(temperature, top_k, top_p)
     # the stages are traced at a fixed sequence length (stage 0's in_shape);
     # decode inside that static buffer
     seq_len = int(stages[0].in_shape[0])

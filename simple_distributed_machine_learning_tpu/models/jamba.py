@@ -42,22 +42,31 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
+    NEWEST_PAIR,
     SEAT_NONE,
     SEAT_SAMPLE,
     PagedServing,
-    _cache_dtype,
-    _check_attn_kernel,
-    _is_quantized_dtype,
-    _memo_build,
-    _merged_stage_trees,
-    _paged_attend,
-    _paged_gather,
-    _paged_scatter,
-    _sample_slot,
-    _sample_slots,
+    check_attn_kernel,
+    grouped_attention,
+    memo_build,
+    merged_stage_trees,
+    pack_chunk_inputs,
+    pack_decode_inputs,
+    paged_attend,
+    paged_gather,
+    paged_scatter,
+    qkv,
+    sample_slot,
+    sample_slots,
+    # tests/bench_cells/test_bench_cells_jamba.py patches this name here
+    slot_pair as _slot_pair,
+    storage_dtype,
+    tied_logits,
+    unpack_chunk,
+    unpack_decode,
+    validate_hybrid_build,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -129,15 +138,15 @@ class JambaConfig:
     def paged_serving(self, stages, max_len: int, block_size: int,
                       cache_dtype=None, mesh=None, kernel: str = "dense",
                       adapters: bool = False) -> PagedServing:
-        """The engine's model interface (``models/gpt.py::PagedServing``):
+        """The engine's model interface (``models/serving.py::PagedServing``):
         the paged pool holds the attention layers' K/V heads only, and every
         slot has one recurrent pair per Mamba layer and, last, its newest
-        token and sampling key (``ahead``: the programs feed them back on
-        the device)."""
-        _validate_hybrid_build(stages, self, max_len, block_size,
-                               cache_dtype, mesh, adapters)
-        _check_attn_kernel(kernel, "JambaConfig.paged_serving")
-        cd = _cache_dtype(cache_dtype)
+        token and sampling key (the programs feed them back on the
+        device)."""
+        validate_hybrid_build(stages, self, max_len, block_size,
+                              cache_dtype, mesh, adapters)
+        check_attn_kernel(kernel, "JambaConfig.paged_serving")
+        cd = storage_dtype(cache_dtype)
         pair = (jax.ShapeDtypeStruct((self.d_state, self.d_inner),
                                      jnp.float32),
                 jax.ShapeDtypeStruct((self.d_conv - 1, self.d_inner), cd))
@@ -145,16 +154,14 @@ class JambaConfig:
             kv_layers=self.n_attn_layers, kv_heads=self.n_kv_heads,
             head_dim=self.head_dim,
             state_shapes=(pair,) * (self.n_layers - self.n_attn_layers) + (
-                (jax.ShapeDtypeStruct((), jnp.int32),
-                 jax.ShapeDtypeStruct((2,), jnp.uint32)),),
-            chunk_prefill=_memo_build(
+                NEWEST_PAIR,),
+            chunk_prefill=memo_build(
                 ("hybrid_chunk", self, block_size),
                 lambda: _build_hybrid_prefill_chunk(self, block_size)),
-            decode=_memo_build(
+            decode=memo_build(
                 ("hybrid_decode", self, block_size, kernel),
                 lambda: _build_hybrid_decode_step(self, block_size, kernel)),
-            pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
-            ahead=True)
+            pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs)
 
 
 # -- parameters ---------------------------------------------------------------
@@ -277,30 +284,6 @@ def _mamba_mixer(mp: dict, u, tail, h0, cfg: JambaConfig, live=None):
     return matmul_acc32(y, mp["out_proj"]), h, new_tail
 
 
-def _qkv(ap: dict, u, cfg: JambaConfig):
-    """``q [N, L, H, dh]``, ``k`` / ``v [N, L, KV, dh]``, float32."""
-    n, n_tok, _ = u.shape
-    dh = cfg.head_dim
-    return (matmul_acc32(u, ap["wq"]).reshape(n, n_tok, cfg.n_heads, dh),
-            matmul_acc32(u, ap["wk"]).reshape(n, n_tok, cfg.n_kv_heads, dh),
-            matmul_acc32(u, ap["wv"]).reshape(n, n_tok, cfg.n_kv_heads, dh))
-
-
-def _grouped_attention(q, k, v, mask, cfg: JambaConfig):
-    """Softmax attention of ``q [N, Lq, H, dh]`` over ``k`` / ``v [N, Lk,
-    KV, dh]`` where ``mask [N or 1, Lq, Lk]`` allows: every group of
-    ``H / KV`` query heads reads its one K/V head, never a repeated copy.
-    Returns ``[N, Lq, H * dh]``."""
-    n, lq, _, dh = q.shape
-    kv = cfg.n_kv_heads
-    q = q.reshape(n, lq, kv, cfg.n_heads // kv, dh)
-    scores = jnp.einsum("nqkgd,npkd->nkgqp", q, k.astype(jnp.float32))
-    scores = jnp.where(mask[:, None, None], scores / math.sqrt(dh), -jnp.inf)
-    a = jnp.einsum("nkgqp,npkd->nqkgd", jax.nn.softmax(scores, axis=-1),
-                   v.astype(jnp.float32))
-    return a.reshape(n, lq, cfg.n_heads * dh)
-
-
 def full_logits(params: dict, tokens, cfg: JambaConfig):
     """Logits ``[B, T, V]`` of whole sequences ``tokens [B, T]`` from empty
     state: the stage's forward (no cache, every token at once)."""
@@ -311,8 +294,8 @@ def full_logits(params: dict, tokens, cfg: JambaConfig):
     for bp in params["blocks"]:
         u = rms_norm(bp["norm_in"], h, cfg.rms_eps)
         if "attn" in bp:
-            q, k, v = _qkv(bp["attn"], u, cfg)
-            h = h + matmul_acc32(_grouped_attention(q, k, v, causal, cfg),
+            q, k, v = qkv(bp["attn"], u, cfg)
+            h = h + matmul_acc32(grouped_attention(q, k, v, causal, cfg),
                                  bp["attn"]["wo"])
         else:
             out, _, _ = _mamba_mixer(
@@ -321,120 +304,10 @@ def full_logits(params: dict, tokens, cfg: JambaConfig):
                 jnp.zeros((bsz, cfg.d_state, cfg.d_inner), f32), cfg)
             h = h + out
         h = h + gated_mlp(bp["mlp"], rms_norm(bp["norm_ff"], h, cfg.rms_eps))
-    return _tied_logits(params, h, cfg)
-
-
-def _tied_logits(params_or_trees, h, cfg: JambaConfig):
-    """Final norm, then the embedding matrix itself as the head."""
-    table = params_or_trees["embed"]["tok"]
-    hn = rms_norm(params_or_trees["head"]["norm_f"], h, cfg.rms_eps)
-    return jax.lax.dot_general(
-        hn.astype(table.dtype), table,
-        (((hn.ndim - 1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    return tied_logits(params, h, cfg)
 
 
 # -- serving: the two paged programs ------------------------------------------
-
-
-def _validate_hybrid_build(stages, cfg, max_len: int, block_size: int,
-                           cache_dtype, mesh, adapters: bool,
-                           caller: str = "JambaConfig.paged_serving",
-                           maker: str = "make_jamba_stages") -> None:
-    """What any family with recurrent state refuses of ``paged_serving``'s
-    arguments, by name (``models/nemotron_h.py`` calls it too)."""
-    for name, asked, reason in (
-            ("mesh (tensor-parallel serving)", mesh is not None,
-             "the scan's channels and the state buffers have no sharded "
-             "placement"),
-            ("adapters", adapters,
-             "the LoRA bank rides GPT's wq / wv (models/lora.py)"),
-            ("a quantized cache_dtype", _is_quantized_dtype(cache_dtype),
-             "K/V blocks would carry scale planes, the recurrent state has "
-             "no such format: use float32 or bfloat16")):
-        if asked:
-            raise ValueError(
-                f"{name} is not available with a model that has recurrent "
-                f"state: {reason}")
-    if len(stages) != 1 or "embed" not in stages[0].params:
-        raise ValueError(
-            f"{caller} needs {maker}' one stage (it has no pipeline "
-            f"build), got {len(stages)} stages")
-    table = stages[0].params["embed"]["tok"]
-    if table.shape != (cfg.vocab, cfg.d_model) or len(
-            stages[0].params["blocks"]) != cfg.n_layers:
-        raise ValueError(
-            f"cfg (vocab={cfg.vocab}, d_model={cfg.d_model}, "
-            f"n_layers={cfg.n_layers}) does not match the stage's build "
-            f"(embedding {table.shape}, "
-            f"{len(stages[0].params['blocks'])} layers)")
-    if not 2 <= max_len <= cfg.seq_len:
-        raise ValueError(
-            f"slot max_len={max_len} outside [2, seq_len={cfg.seq_len}]")
-    if block_size < 1:
-        raise ValueError(f"{caller} needs block_size >= 1, got {block_size}")
-
-
-# -- host inputs as one array -------------------------------------------------
-#
-# A program's host-side arguments travel as ONE int32 array (float32 and
-# uint32 values by their bits): eight small numpy arguments are eight
-# transfers, a millisecond of every launch on a v5e's host.
-
-_DECODE_COLS = 5    # a slot's columns before its block table
-_CHUNK_COLS = 8     # a chunk's scalars before its block table
-
-
-def _bits(a, dtype=np.float32) -> np.ndarray:
-    return np.asarray(a, dtype).view(np.int32)
-
-
-def pack_decode_inputs(toks, pos, tables, live, key_data, temps, top_ks,
-                       top_ps) -> tuple[np.ndarray]:
-    """``[S, 5 + NB]`` int32: a slot's position, live flag, top-k,
-    temperature and top-p bits, then its block table. ``toks`` and
-    ``key_data``, the engine's host copies, stay behind: the program reads
-    the newest token and key of every slot from its state
-    (``PagedServing.ahead``)."""
-    del toks, key_data
-    cols = [pos, live, top_ks, _bits(temps), _bits(top_ps)]
-    return (np.concatenate([np.stack(cols, axis=1).astype(np.int32),
-                            np.asarray(tables, np.int32)], axis=1),)
-
-
-def _unpack_decode(host):
-    f32 = lambda c: jax.lax.bitcast_convert_type(host[:, c], jnp.float32)  # noqa: E731
-    return (host[:, 0], host[:, _DECODE_COLS:], host[:, 1] != 0, f32(3),
-            host[:, 2], f32(4))
-
-
-def pack_chunk_inputs(tokens, p0, table, slot, seat, key_data, temperature,
-                      top_k, top_p) -> tuple[np.ndarray, np.ndarray]:
-    """``(tokens [1, c], [8 + NB] int32)``: position, slot, top-k, two key
-    words, temperature and top-p bits and ``seat``, then the block table.
-    The tokens stay an argument of their own: their length is the one
-    shape the program is traced for."""
-    head = [p0, slot, top_k, *_bits(key_data, np.uint32), _bits(temperature),
-            _bits(top_p), seat]
-    return (np.asarray(tokens, np.int32),
-            np.concatenate([np.asarray(head, np.int32),
-                            np.asarray(table, np.int32)]))
-
-
-def _unpack_chunk(host):
-    f32 = lambda c: jax.lax.bitcast_convert_type(host[c], jnp.float32)  # noqa: E731
-    kd = jax.lax.bitcast_convert_type(host[3:5], jnp.uint32)
-    return (host[0], host[_CHUNK_COLS:], host[1], host[7], kd, f32(5),
-            host[2], f32(6))
-
-
-def _slot_pair(ssm, tail, slot, fresh):
-    """``slot``'s recurrent pair ``([1, S, Di], [1, d_conv - 1, Di])`` as a
-    chunk starts from it: zeros when the chunk is the sequence's first."""
-    h0 = jax.lax.dynamic_slice_in_dim(ssm, slot, 1, 0)
-    t0 = jax.lax.dynamic_slice_in_dim(tail, slot, 1, 0)
-    return (jnp.where(fresh, jnp.zeros_like(h0), h0),
-            jnp.where(fresh, jnp.zeros_like(t0), t0))
 
 
 def _hybrid_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
@@ -446,7 +319,7 @@ def _hybrid_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
     never sees its last occupant's state. Returns the last position's
     logits ``[V]``."""
     f32 = jnp.float32
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     c = tokens.shape[1]
     h = embedding_lookup(embed["tok"], tokens.astype(jnp.int32)).astype(f32)
     idx = p0 + jnp.arange(c)
@@ -459,16 +332,16 @@ def _hybrid_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
     for bp in blocks:
         u = rms_norm(bp["norm_in"], h, cfg.rms_eps)
         if "attn" in bp:
-            q, k, v = _qkv(bp["attn"], u, cfg)
-            kc = _paged_scatter(kc, ai, phys, off, k[0])
-            vc = _paged_scatter(vc, ai, phys, off, v[0])
+            q, k, v = qkv(bp["attn"], u, cfg)
+            kc = paged_scatter(kc, ai, phys, off, k[0])
+            vc = paged_scatter(vc, ai, phys, off, v[0])
             # [KV, span, dh] -> [1, span, KV, dh]
             krow = jnp.swapaxes(
-                _paged_gather(kc, ai, table, cfg.n_kv_heads), 0, 1)[None]
+                paged_gather(kc, ai, table, cfg.n_kv_heads), 0, 1)[None]
             vrow = jnp.swapaxes(
-                _paged_gather(vc, ai, table, cfg.n_kv_heads), 0, 1)[None]
+                paged_gather(vc, ai, table, cfg.n_kv_heads), 0, 1)[None]
             h = h + matmul_acc32(
-                _grouped_attention(q, krow, vrow, seen, cfg),
+                grouped_attention(q, krow, vrow, seen, cfg),
                 bp["attn"]["wo"])
             ai += 1
         else:
@@ -481,7 +354,7 @@ def _hybrid_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
             h = h + out
             mi += 1
         h = h + gated_mlp(bp["mlp"], rms_norm(bp["norm_ff"], h, cfg.rms_eps))
-    logits = _tied_logits({"embed": embed, "head": head}, h[:, -1], cfg)
+    logits = tied_logits({"embed": embed, "head": head}, h[:, -1], cfg)
     return kc, vc, tuple(state), logits[0]
 
 
@@ -490,16 +363,16 @@ def _build_hybrid_prefill_chunk(cfg: JambaConfig, bs: int):
     state, token, key_data)`` with ``host = pack_chunk_inputs(tokens, p0,
     table [NB], slot, seat, key_data, temperature, top_k, top_p)[1]``; pool
     and state buffers are donated. ``seat`` says what becomes the slot's
-    newest token and key in the state's last pair (``PagedServing.ahead``).
+    newest token and key in the state's last pair (``PagedServing``).
     Retraces per chunk length, like GPT's."""
     @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
     def chunk_hybrid_prefill(params, kc, vc, state, tokens, host):
         *layers, (newest, keys) = state
         (p0, table, slot, seat, key_data, temperature, top_k,
-         top_p) = _unpack_chunk(host)
+         top_p) = unpack_chunk(host)
         kc, vc, layers, row = _hybrid_chunk_fwd(
             params, kc, vc, tuple(layers), tokens, p0, table, slot, cfg, bs)
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
         own = seat == SEAT_SAMPLE
         newest = newest.at[slot].set(jnp.where(
             seat == SEAT_NONE, newest[slot], jnp.where(own, tok, seat)))
@@ -518,7 +391,7 @@ def _hybrid_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
     is returned unchanged — a slot in the middle of its prefill must find
     its state as its last chunk left it. Returns logits ``[S, V]``."""
     f32 = jnp.float32
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     h = embedding_lookup(embed["tok"], toks[:, None]).astype(f32)  # [S, 1, d]
     phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
@@ -529,20 +402,20 @@ def _hybrid_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
     for bp in blocks:
         u = rms_norm(bp["norm_in"], h, cfg.rms_eps)
         if "attn" in bp:
-            q, k, v = _qkv(bp["attn"], u, cfg)
-            kc = _paged_scatter(kc, ai, phys, off, k[:, 0])
-            vc = _paged_scatter(vc, ai, phys, off, v[:, 0])
+            q, k, v = qkv(bp["attn"], u, cfg)
+            kc = paged_scatter(kc, ai, phys, off, k[:, 0])
+            vc = paged_scatter(vc, ai, phys, off, v[:, 0])
             if kernel == "fused":
-                a = _paged_attend(kc, vc, ai, jnp.swapaxes(q, 1, 2), tables,
-                                  pos[:, None], bs)           # [S, H, 1, dh]
+                a = paged_attend(kc, vc, ai, jnp.swapaxes(q, 1, 2), tables,
+                                 pos[:, None], bs)           # [S, H, 1, dh]
                 a = jnp.swapaxes(a, 1, 2).reshape(a.shape[0], 1, -1)
             else:
                 # [S, KV, span, dh] -> [S, span, KV, dh]
                 krow = jnp.swapaxes(
-                    _paged_gather(kc, ai, tables, cfg.n_kv_heads), 1, 2)
+                    paged_gather(kc, ai, tables, cfg.n_kv_heads), 1, 2)
                 vrow = jnp.swapaxes(
-                    _paged_gather(vc, ai, tables, cfg.n_kv_heads), 1, 2)
-                a = _grouped_attention(q, krow, vrow, seen, cfg)
+                    paged_gather(vc, ai, tables, cfg.n_kv_heads), 1, 2)
+                a = grouped_attention(q, krow, vrow, seen, cfg)
             h = h + matmul_acc32(a, bp["attn"]["wo"])
             ai += 1
         else:
@@ -553,7 +426,7 @@ def _hybrid_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
             h = h + out
             mi += 1
         h = h + gated_mlp(bp["mlp"], rms_norm(bp["norm_ff"], h, cfg.rms_eps))
-    logits = _tied_logits({"embed": embed, "head": head}, h[:, 0], cfg)
+    logits = tied_logits({"embed": embed, "head": head}, h[:, 0], cfg)
     return kc, vc, tuple(state), logits
 
 
@@ -563,16 +436,16 @@ def _build_hybrid_decode_step(cfg: JambaConfig, bs: int, kernel: str):
     tables [S, NB], live [S], key_data [S, 2], temps, top_ks, top_ps)``;
     pool and state buffers are donated. Every slot's input token and key
     are the state's last pair, where the live slots' new ones go back
-    (``PagedServing.ahead``): the next step needs nothing from the host
+    (``PagedServing``): the next step needs nothing from the host
     that this one computes."""
     @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
     def step_hybrid_decode(params, kc, vc, state, host):
         *layers, (toks, key_data) = state
-        pos, tables, live, temps, top_ks, top_ps = _unpack_decode(host)
+        pos, tables, live, temps, top_ks, top_ps = unpack_decode(host)
         kc, vc, layers, rows = _hybrid_decode_fwd(
             params, kc, vc, tuple(layers), toks, pos, tables, live, cfg, bs,
             kernel)
-        toks2, kd2 = _sample_slots(rows, key_data, temps, top_ks, top_ps)
+        toks2, kd2 = sample_slots(rows, key_data, temps, top_ks, top_ps)
         newest = (jnp.where(live, toks2, toks),
                   jnp.where(live[:, None], kd2, key_data))
         return kc, vc, (*layers, newest), toks2, kd2

@@ -43,7 +43,7 @@ d_conv - 1 pre-convolution inputs [d_conv - 1, d_inner + 2 G S])``.
 :meth:`NemotronHConfig.paged_serving` hands ``serve/engine.py`` that layout
 and the two programs (``jit_chunk_pattern_prefill``,
 ``jit_step_pattern_decode``); host inputs, sampling and seats are
-``models/jamba.py``'s. The decode program also counts what its expert
+``models/serving.py``'s. The decode program also counts what its expert
 layers did (``PagedServing.counters``). Training this family is not built
 (the scan kernels have no backward rule), nor is the published
 multi-token-prediction layer (a draft head: the model's logits do not
@@ -59,30 +59,29 @@ import math
 import jax
 import jax.numpy as jnp
 
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     NEWEST_PAIR,
     PagedServing,
-    _cache_dtype,
-    _check_attn_kernel,
-    _feed_newest,
-    _memo_build,
-    _merged_stage_trees,
-    _paged_attend,
-    _paged_gather,
-    _paged_scatter,
-    _sample_slot,
-    _sample_slots,
-    _seat_newest,
-)
-from simple_distributed_machine_learning_tpu.models.jamba import (
-    _grouped_attention,
-    _qkv,
-    _slot_pair,
-    _unpack_chunk,
-    _unpack_decode,
-    _validate_hybrid_build,
+    check_attn_kernel,
+    feed_newest,
+    grouped_attention,
+    memo_build,
+    merged_stage_trees,
     pack_chunk_inputs,
     pack_decode_inputs,
+    paged_attend,
+    paged_gather,
+    paged_scatter,
+    qkv,
+    sample_slot,
+    sample_slots,
+    seat_newest,
+    # tests/bench_cells/test_bench_cells_nemotron_h.py patches this name here
+    slot_pair as _slot_pair,
+    storage_dtype,
+    unpack_chunk,
+    unpack_decode,
+    validate_hybrid_build,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -187,36 +186,36 @@ class NemotronHConfig:
     def paged_serving(self, stages, max_len: int, block_size: int,
                       cache_dtype=None, mesh=None, kernel: str = "dense",
                       adapters: bool = False) -> PagedServing:
-        """The engine's model interface (``models/gpt.py::PagedServing``):
+        """The engine's model interface (``models/serving.py::PagedServing``):
         the paged pool holds the attention layers' K/V heads only, and every
         slot has one recurrent pair per Mamba layer and, last, its newest
-        token and sampling key (``ahead``)."""
-        _validate_hybrid_build(stages, self, max_len, block_size,
-                               cache_dtype, mesh, adapters,
-                               caller="NemotronHConfig.paged_serving",
-                               maker="make_nemotron_h_stages")
+        token and sampling key."""
+        validate_hybrid_build(stages, self, max_len, block_size,
+                              cache_dtype, mesh, adapters,
+                              caller="NemotronHConfig.paged_serving",
+                              maker="make_nemotron_h_stages")
         if "*" not in self.pattern:
             raise ValueError(
                 f"NemotronHConfig.paged_serving: pattern {self.pattern!r} "
                 f"has no attention layer, and a paged pool without a K/V "
                 f"layer is not built")
-        _check_attn_kernel(kernel, "NemotronHConfig.paged_serving")
+        check_attn_kernel(kernel, "NemotronHConfig.paged_serving")
         pair = (jax.ShapeDtypeStruct((self.d_state, self.d_inner),
                                      jnp.float32),
                 jax.ShapeDtypeStruct((self.d_conv - 1, self.d_conv_channels),
-                                     _cache_dtype(cache_dtype)))
+                                     storage_dtype(cache_dtype)))
         return PagedServing(
             kv_layers=self.pattern.count("*"), kv_heads=self.n_kv_heads,
             head_dim=self.head_dim,
             state_shapes=(pair,) * self.pattern.count("M") + (NEWEST_PAIR,),
-            chunk_prefill=_memo_build(
+            chunk_prefill=memo_build(
                 ("pattern_chunk", self, block_size),
                 lambda: _build_pattern_prefill_chunk(self, block_size)),
-            decode=_memo_build(
+            decode=memo_build(
                 ("pattern_decode", self, block_size, kernel),
                 lambda: _build_pattern_decode_step(self, block_size, kernel)),
             pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
-            ahead=True, counters=EXPERT_COUNTERS)
+            counters=EXPERT_COUNTERS)
 
 
 # -- parameters ---------------------------------------------------------------
@@ -378,8 +377,8 @@ def full_logits(params: dict, tokens, cfg: NemotronHConfig):
     for bp in params["blocks"]:
         u = rms_norm(bp["norm"], h, cfg.rms_eps)
         if "attn" in bp:
-            q, k, v = _qkv(bp["attn"], u, cfg)
-            out = matmul_acc32(_grouped_attention(q, k, v, causal, cfg),
+            q, k, v = qkv(bp["attn"], u, cfg)
+            out = matmul_acc32(grouped_attention(q, k, v, causal, cfg),
                                bp["attn"]["wo"])
         elif "mamba" in bp:
             out, _, _ = _mamba2_mixer(
@@ -403,7 +402,7 @@ def _pattern_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
     carry the slot's recurrent pair from the previous chunk (zeros when
     ``p0 == 0``). Returns the last position's logits ``[V]``."""
     f32 = jnp.float32
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     c = tokens.shape[1]
     h = embedding_lookup(embed["tok"], tokens.astype(jnp.int32)).astype(f32)
     idx = p0 + jnp.arange(c)
@@ -416,15 +415,15 @@ def _pattern_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
     for bp in blocks:
         u = rms_norm(bp["norm"], h, cfg.rms_eps)
         if "attn" in bp:
-            q, k, v = _qkv(bp["attn"], u, cfg)
-            kc = _paged_scatter(kc, ai, phys, off, k[0])
-            vc = _paged_scatter(vc, ai, phys, off, v[0])
+            q, k, v = qkv(bp["attn"], u, cfg)
+            kc = paged_scatter(kc, ai, phys, off, k[0])
+            vc = paged_scatter(vc, ai, phys, off, v[0])
             # [KV, span, dh] -> [1, span, KV, dh]
             krow = jnp.swapaxes(
-                _paged_gather(kc, ai, table, cfg.n_kv_heads), 0, 1)[None]
+                paged_gather(kc, ai, table, cfg.n_kv_heads), 0, 1)[None]
             vrow = jnp.swapaxes(
-                _paged_gather(vc, ai, table, cfg.n_kv_heads), 0, 1)[None]
-            out = matmul_acc32(_grouped_attention(q, krow, vrow, seen, cfg),
+                paged_gather(vc, ai, table, cfg.n_kv_heads), 0, 1)[None]
+            out = matmul_acc32(grouped_attention(q, krow, vrow, seen, cfg),
                                bp["attn"]["wo"])
             ai += 1
         elif "mamba" in bp:
@@ -450,11 +449,11 @@ def _build_pattern_prefill_chunk(cfg: NemotronHConfig, bs: int):
     def chunk_pattern_prefill(params, kc, vc, state, tokens, host):
         *layers, newest = state
         (p0, table, slot, seat, key_data, temperature, top_k,
-         top_p) = _unpack_chunk(host)
+         top_p) = unpack_chunk(host)
         kc, vc, layers, row = _pattern_chunk_fwd(
             params, kc, vc, tuple(layers), tokens, p0, table, slot, cfg, bs)
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
-        newest = _seat_newest(newest, slot, seat, tok, kd, key_data)
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
+        newest = seat_newest(newest, slot, seat, tok, kd, key_data)
         return kc, vc, (*layers, newest), tok, kd
 
     return chunk_pattern_prefill
@@ -469,7 +468,7 @@ def _pattern_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
     ``[n_E, experts_held]`` (every slot's row counts: the run computes
     them all)."""
     f32 = jnp.float32
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     h = embedding_lookup(embed["tok"], toks[:, None]).astype(f32)  # [S, 1, d]
     phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
@@ -481,20 +480,20 @@ def _pattern_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
     for bp in blocks:
         u = rms_norm(bp["norm"], h, cfg.rms_eps)
         if "attn" in bp:
-            q, k, v = _qkv(bp["attn"], u, cfg)
-            kc = _paged_scatter(kc, ai, phys, off, k[:, 0])
-            vc = _paged_scatter(vc, ai, phys, off, v[:, 0])
+            q, k, v = qkv(bp["attn"], u, cfg)
+            kc = paged_scatter(kc, ai, phys, off, k[:, 0])
+            vc = paged_scatter(vc, ai, phys, off, v[:, 0])
             if kernel == "fused":
-                a = _paged_attend(kc, vc, ai, jnp.swapaxes(q, 1, 2), tables,
-                                  pos[:, None], bs)           # [S, H, 1, dh]
+                a = paged_attend(kc, vc, ai, jnp.swapaxes(q, 1, 2), tables,
+                                 pos[:, None], bs)           # [S, H, 1, dh]
                 a = jnp.swapaxes(a, 1, 2).reshape(a.shape[0], 1, -1)
             else:
                 # [S, KV, span, dh] -> [S, span, KV, dh]
                 krow = jnp.swapaxes(
-                    _paged_gather(kc, ai, tables, cfg.n_kv_heads), 1, 2)
+                    paged_gather(kc, ai, tables, cfg.n_kv_heads), 1, 2)
                 vrow = jnp.swapaxes(
-                    _paged_gather(vc, ai, tables, cfg.n_kv_heads), 1, 2)
-                a = _grouped_attention(q, krow, vrow, seen, cfg)
+                    paged_gather(vc, ai, tables, cfg.n_kv_heads), 1, 2)
+                a = grouped_attention(q, krow, vrow, seen, cfg)
             out = matmul_acc32(a, bp["attn"]["wo"])
             ai += 1
         elif "mamba" in bp:
@@ -523,17 +522,17 @@ def _build_pattern_decode_step(cfg: NemotronHConfig, bs: int, kernel: str):
     def step_pattern_decode(params, kc, vc, state, host):
         *layers, newest = state
         toks, key_data = newest
-        pos, tables, live, temps, top_ks, top_ps = _unpack_decode(host)
+        pos, tables, live, temps, top_ks, top_ps = unpack_decode(host)
         kc, vc, layers, logits, expert_rows = _pattern_decode_fwd(
             params, kc, vc, tuple(layers), toks, pos, tables, live, cfg, bs,
             kernel)
-        toks2, kd2 = _sample_slots(logits, key_data, temps, top_ks, top_ps)
+        toks2, kd2 = sample_slots(logits, key_data, temps, top_ks, top_ps)
         counters = jnp.stack([(expert_rows > 0).sum(), expert_rows.sum(),
                               expert_rows.max()]).astype(jnp.int32)
         rows = jnp.concatenate([
             toks2[:, None],
             jnp.broadcast_to(counters, (toks2.shape[0], 3))], axis=1)
-        return (kc, vc, (*layers, _feed_newest(newest, live, toks2, kd2)),
+        return (kc, vc, (*layers, feed_newest(newest, live, toks2, kd2)),
                 rows, kd2)
 
     return step_pattern_decode

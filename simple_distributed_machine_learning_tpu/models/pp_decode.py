@@ -35,12 +35,14 @@ from jax.sharding import PartitionSpec as P
 
 from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
-    _cache_dtype,
-    _check_sampling_args,
-    _dense_block_prefill,
-    _dense_block_step,
-    _sample_from,
-    _validate_decode_build,
+    check_sampling_args,
+    dense_block_prefill,
+    dense_block_step,
+    sample_from,
+    validate_decode_build,
+)
+from simple_distributed_machine_learning_tpu.models.serving import (
+    storage_dtype,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -74,9 +76,9 @@ def make_pp_decoder(pipe, cfg: GPTConfig, prompt_len: int, n_new: int,
         raise ValueError(
             "make_pp_decoder shards over stage (x data) only — rebuild "
             "without seq/model/expert axes for decoding")
-    _check_sampling_args(temperature, top_k, top_p, cfg.vocab)
-    total = _validate_decode_build(pipe.stages, cfg, prompt_len, n_new,
-                                   "make_pp_decoder")
+    check_sampling_args(temperature, top_k, top_p, cfg.vocab)
+    total = validate_decode_build(pipe.stages, cfg, prompt_len, n_new,
+                                  "make_pp_decoder")
 
     S = pipe.n_stages
     metas = list(pipe.metas)
@@ -102,13 +104,13 @@ def make_pp_decoder(pipe, cfg: GPTConfig, prompt_len: int, n_new: int,
     def _pick(row, ks):
         """ks: the per-token subkey (split uniformly on every device, so
         the stream matches make_cached_decoder's exactly); the sampling
-        math itself is gpt.py's shared _sample_from."""
-        return _sample_from(row, ks, temperature, top_k, top_p)
+        math itself is gpt.py's shared sample_from."""
+        return sample_from(row, ks, temperature, top_k, top_p)
 
     fwd = [(i, (i + 1) % S) for i in range(S)]
 
     # cache_dtype: as make_cached_decoder (bf16 halves each stage's cache)
-    cd = _cache_dtype(cache_dtype)
+    cd = storage_dtype(cache_dtype)
 
     def per_device(row4d, prompt, key):
         row = row4d[0, 0, 0]
@@ -132,9 +134,9 @@ def make_pp_decoder(pipe, cfg: GPTConfig, prompt_len: int, n_new: int,
                 else:
                     h = wire[:, :-1].reshape(b, prompt_len, d)
                 for li in range(n_blocks[s]):
-                    h, kc, vc = _dense_block_prefill(params["blocks"][li],
-                                                     h, li, kc, vc,
-                                                     prompt_len, H)
+                    h, kc, vc = dense_block_prefill(params["blocks"][li],
+                                                    h, li, kc, vc,
+                                                    prompt_len, H)
                 tok = jnp.zeros((b,), jnp.float32)
                 if s == S - 1:
                     tok = _pick(_head_row(params, h[:, -1]), ks).astype(
@@ -191,8 +193,8 @@ def make_pp_decoder(pipe, cfg: GPTConfig, prompt_len: int, n_new: int,
                 else:
                     h = wire[:, :-1].reshape(b, 1, d)
                 for li in range(n_blocks[s]):
-                    h, kc, vc = _dense_block_step(params["blocks"][li], h,
-                                                  li, kc, vc, i, total, H)
+                    h, kc, vc = dense_block_step(params["blocks"][li], h,
+                                                 li, kc, vc, i, total, H)
                 tok_out = jnp.zeros((b,), jnp.float32)
                 if s == S - 1:
                     tok_out = _pick(_head_row(params, h[:, 0]), ks).astype(

@@ -50,22 +50,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     NEWEST_PAIR,
     SEAT_NONE,
     PagedServing,
-    _check_attn_kernel,
-    _is_quantized_dtype,
-    _memo_build,
-    _merged_stage_trees,
-    _paged_attend,
-    _paged_gather,
-    _paged_scatter,
-    _sample_dyn,
-)
-from simple_distributed_machine_learning_tpu.models.jamba import (
-    _bits,
-    _grouped_attention,
+    bits,
+    check_attn_kernel,
+    grouped_attention,
+    is_quantized_dtype,
+    memo_build,
+    merged_stage_trees,
+    # tests/bench_cells/test_bench_cells_sdar.py patches this name here
+    paged_attend as _paged_attend,
+    paged_gather,
+    paged_scatter,
+    sample_dyn,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -127,14 +126,14 @@ class SdarConfig:
     def paged_serving(self, stages, max_len: int, block_size: int,
                       cache_dtype=None, mesh=None, kernel: str = "dense",
                       adapters: bool = False) -> PagedServing:
-        """The engine's model interface (``models/gpt.py::PagedServing``)
+        """The engine's model interface (``models/serving.py::PagedServing``)
         with ``block = block_length``: per slot the block in progress (its
         tokens, the forward that fixed each, the forwards it has had) and,
         last, the newest pair (the last committed token and the sampling
         key)."""
         _validate_block_build(stages, self, max_len, block_size, cache_dtype,
                               mesh, adapters)
-        _check_attn_kernel(kernel, "SdarConfig.paged_serving")
+        check_attn_kernel(kernel, "SdarConfig.paged_serving")
         blk = self.block_length
         row = jax.ShapeDtypeStruct((blk,), jnp.int32)
         return PagedServing(
@@ -142,14 +141,14 @@ class SdarConfig:
             head_dim=self.head_dim,
             state_shapes=((row, row, jax.ShapeDtypeStruct((), jnp.int32)),
                           NEWEST_PAIR),
-            chunk_prefill=_memo_build(
+            chunk_prefill=memo_build(
                 ("block_chunk", self, block_size),
                 lambda: _build_block_prefill_chunk(self, block_size)),
-            decode=_memo_build(
+            decode=memo_build(
                 ("block_denoise", self, block_size, kernel),
                 lambda: _build_block_denoise_step(self, block_size, kernel)),
             pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
-            ahead=True, block=blk, block_forwards=denoise_forwards,
+            block=blk, block_forwards=denoise_forwards,
             unpack_rows=unpack_block_rows, counters=BLOCK_COUNTERS)
 
 
@@ -273,7 +272,7 @@ def full_logits(params: dict, tokens, cfg: SdarConfig,
     for bp in params["blocks"]:
         q, k, v = _qkv(bp["attn"], rms_norm(bp["norm_in"], h, cfg.rms_eps),
                        pos, cfg)
-        h = h + matmul_acc32(_grouped_attention(q, k, v, seen, cfg),
+        h = h + matmul_acc32(grouped_attention(q, k, v, seen, cfg),
                              bp["attn"]["wo"])
         h, _ = _experts(bp, h, cfg)
     return _head_logits(params["head"], h, cfg)
@@ -291,7 +290,7 @@ def _validate_block_build(stages, cfg: SdarConfig, max_len: int,
              "the experts and the block's state have no sharded placement"),
             ("adapters", adapters,
              "the LoRA bank rides GPT's wq / wv (models/lora.py)"),
-            ("a quantized cache_dtype", _is_quantized_dtype(cache_dtype),
+            ("a quantized cache_dtype", is_quantized_dtype(cache_dtype),
              "a denoising forward rewrites its block's rows every step, "
              "and the rows-in-lanes kernel path takes no per-head scales: "
              "use float32 or bfloat16")):
@@ -325,7 +324,7 @@ def _validate_block_build(stages, cfg: SdarConfig, max_len: int,
             f"rows lie in one pool block")
 
 
-# -- host inputs as one array (models/jamba.py) --------------------------------
+# -- host inputs as one array (models/serving.py) ------------------------------
 
 _DECODE_COLS = 6    # a slot's columns before its block table
 
@@ -337,7 +336,7 @@ def pack_decode_inputs(toks, pos, tables, live, steps, key_data, temps,
     host's ``toks`` and ``key_data`` stay behind: the block and the key are
     the program's state."""
     del toks, key_data
-    cols = [pos, live, steps, top_ks, _bits(temps), _bits(top_ps)]
+    cols = [pos, live, steps, top_ks, bits(temps), bits(top_ps)]
     return (np.concatenate([np.stack(cols, axis=1).astype(np.int32),
                             np.asarray(tables, np.int32)], axis=1),)
 
@@ -355,7 +354,7 @@ def pack_chunk_inputs(tokens, p0, table, slot, seat, key_data, temperature,
     and those tokens), then the block table. A chunk samples nothing: the
     sampling parameters stay behind."""
     del temperature, top_k, top_p
-    head = [p0, slot, *_bits(key_data, np.uint32)]
+    head = [p0, slot, *bits(key_data, np.uint32)]
     return (np.asarray(tokens, np.int32),
             np.concatenate([np.asarray(head, np.int32),
                             np.asarray(seat, np.int32),
@@ -369,7 +368,7 @@ def _block_chunk_fwd(params, kc, vc, tokens, p0, table, cfg: SdarConfig,
     chunk's K/V into the slot's blocks and attends over them as GPT's chunk
     does. No logits: a prefill yields no token."""
     f32 = jnp.float32
-    embed, blocks, _ = _merged_stage_trees(params)
+    embed, blocks, _ = merged_stage_trees(params)
     c = tokens.shape[1]
     h = embedding_lookup(embed["tok"], tokens.astype(jnp.int32)).astype(f32)
     idx = p0 + jnp.arange(c)
@@ -379,14 +378,14 @@ def _block_chunk_fwd(params, kc, vc, tokens, p0, table, cfg: SdarConfig,
     for li, bp in enumerate(blocks):
         q, k, v = _qkv(bp["attn"], rms_norm(bp["norm_in"], h, cfg.rms_eps),
                        idx[None], cfg)
-        kc = _paged_scatter(kc, li, phys, off, k[0])
-        vc = _paged_scatter(vc, li, phys, off, v[0])
+        kc = paged_scatter(kc, li, phys, off, k[0])
+        vc = paged_scatter(vc, li, phys, off, v[0])
         # [KV, span, dh] -> [1, span, KV, dh]
         krow = jnp.swapaxes(
-            _paged_gather(kc, li, table, cfg.n_kv_heads), 0, 1)[None]
+            paged_gather(kc, li, table, cfg.n_kv_heads), 0, 1)[None]
         vrow = jnp.swapaxes(
-            _paged_gather(vc, li, table, cfg.n_kv_heads), 0, 1)[None]
-        h = h + matmul_acc32(_grouped_attention(q, krow, vrow, seen, cfg),
+            paged_gather(vc, li, table, cfg.n_kv_heads), 0, 1)[None]
+        h = h + matmul_acc32(grouped_attention(q, krow, vrow, seen, cfg),
                              bp["attn"]["wo"])
         h, _ = _experts(bp, h, cfg)
     return kc, vc
@@ -434,7 +433,7 @@ def _block_fwd(params, kc, vc, btok, pos, tables, cfg: SdarConfig, bs: int,
     all-trash table. Returns logits ``[S, B, V]`` and, per layer, the rows
     each expert got ``[L, E]``."""
     f32 = jnp.float32
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     blk = cfg.block_length
     h = embedding_lookup(embed["tok"], btok).astype(f32)       # [S, B, d]
     idx = pos[:, None] + jnp.arange(blk)                       # [S, B]
@@ -447,8 +446,8 @@ def _block_fwd(params, kc, vc, btok, pos, tables, cfg: SdarConfig, bs: int,
     for li, bp in enumerate(blocks):
         q, k, v = _qkv(bp["attn"], rms_norm(bp["norm_in"], h, cfg.rms_eps),
                        idx, cfg)
-        kc = _paged_scatter(kc, li, phys, off, k)
-        vc = _paged_scatter(vc, li, phys, off, v)
+        kc = paged_scatter(kc, li, phys, off, k)
+        vc = paged_scatter(vc, li, phys, off, v)
         if kernel == "fused":
             a = _paged_attend(kc, vc, li, jnp.swapaxes(q, 1, 2), tables,
                               qpos, bs)                    # [S, H, B, dh]
@@ -456,10 +455,10 @@ def _block_fwd(params, kc, vc, btok, pos, tables, cfg: SdarConfig, bs: int,
         else:
             # [S, KV, span, dh] -> [S, span, KV, dh]
             krow = jnp.swapaxes(
-                _paged_gather(kc, li, tables, cfg.n_kv_heads), 1, 2)
+                paged_gather(kc, li, tables, cfg.n_kv_heads), 1, 2)
             vrow = jnp.swapaxes(
-                _paged_gather(vc, li, tables, cfg.n_kv_heads), 1, 2)
-            a = _grouped_attention(q, krow, vrow, seen, cfg)
+                paged_gather(vc, li, tables, cfg.n_kv_heads), 1, 2)
+            a = grouped_attention(q, krow, vrow, seen, cfg)
         h = h + matmul_acc32(a, bp["attn"]["wo"])
         h, r = _experts(bp, h, cfg)
         rows.append(r)
@@ -471,7 +470,7 @@ def _sample_block(logits, key_data, temps, top_ks, top_ps):
     log-probability the model gives it: ``(tokens [S, B], logp [S, B],
     next keys [S, 2])``. Greedy rows take the largest and consume no
     randomness; when every slot is greedy the vocabulary-wide sorts of
-    ``_sample_dyn`` are skipped (as ``models/gpt.py::_sample_slots``
+    ``sample_dyn`` are skipped (as ``models/serving.py::sample_slots``
     does for the three other families; the sampled branch here folds each
     position into the key, which that one does not)."""
     blk = logits.shape[1]
@@ -485,7 +484,7 @@ def _sample_block(logits, key_data, temps, top_ks, top_ps):
             key = jax.random.wrap_key_data(kd)
             each = jax.vmap(lambda b: jax.random.key_data(
                 jax.random.fold_in(key, b)))(jnp.arange(blk))
-            toks, _ = jax.vmap(_sample_dyn, (0, 0, None, None, None))(
+            toks, _ = jax.vmap(sample_dyn, (0, 0, None, None, None))(
                 rows, each, t, k, p)
             nxt = jax.random.key_data(jax.random.split(key)[0])
             return toks, jnp.where(t > 0, nxt, kd)

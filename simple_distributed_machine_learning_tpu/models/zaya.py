@@ -56,8 +56,8 @@ slot), so the keys it writes to the pool do not depend on where the prompt
 was cut. :meth:`ZayaConfig.paged_serving` hands ``serve/engine.py`` that
 layout and the two programs (``jit_chunk_cca_prefill``,
 ``jit_step_cca_decode``); host inputs, sampling and seats are
-``models/jamba.py``'s. The chunk attends through ``models/gpt.py::
-_span_attention`` (the walk ``models/cohere2.py``'s chunk makes, with no
+``models/serving.py``'s. The chunk attends through ``models/serving.py::
+span_attention`` (the walk ``models/cohere2.py``'s chunk makes, with no
 window): over the slot's LIVE positions, a step of pool blocks at a time
 with a running maximum and sum, operands in the pool's dtype. It holds no
 table-wide score array (until PR 46 every layer gathered the whole table
@@ -65,7 +65,7 @@ and wrote ``heads x chunk x max_len`` float32 scores whatever the slot
 held: a quarter of the decode-and-chunk tick at ``max_len`` 8,192,
 ``PERF.md`` section 6) and fetches no block past the chunk's last position.
 The whole-sequence path (:func:`full_logits`) and the decode program's
-``kernel="dense"`` keep ``_grouped_attention``. The decode program also
+``kernel="dense"`` keep ``grouped_attention``. The decode program also
 counts what its expert layers did (``PagedServing.counters``). Training this
 family is not built.
 """
@@ -79,29 +79,27 @@ import math
 import jax
 import jax.numpy as jnp
 
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     NEWEST_PAIR,
     PagedServing,
-    _check_attn_kernel,
-    _feed_newest,
-    _memo_build,
-    _merged_stage_trees,
-    _paged_attend,
-    _paged_gather,
-    _paged_scatter,
-    _sample_slot,
-    _sample_slots,
-    _seat_newest,
-    _span_attention,
-)
-from simple_distributed_machine_learning_tpu.models.jamba import (
-    _grouped_attention,
-    _tied_logits,
-    _unpack_chunk,
-    _unpack_decode,
-    _validate_hybrid_build,
+    check_attn_kernel,
+    feed_newest,
+    grouped_attention,
+    memo_build,
+    merged_stage_trees,
     pack_chunk_inputs,
     pack_decode_inputs,
+    paged_attend,
+    paged_gather,
+    paged_scatter,
+    sample_slot,
+    sample_slots,
+    seat_newest,
+    span_attention,
+    tied_logits,
+    unpack_chunk,
+    unpack_decode,
+    validate_hybrid_build,
 )
 from simple_distributed_machine_learning_tpu.ops.layers import (
     embedding_lookup,
@@ -188,16 +186,15 @@ class ZayaConfig:
     def paged_serving(self, stages, max_len: int, block_size: int,
                       cache_dtype=None, mesh=None, kernel: str = "dense",
                       adapters: bool = False) -> PagedServing:
-        """The engine's model interface (``models/gpt.py::PagedServing``):
+        """The engine's model interface (``models/serving.py::PagedServing``):
         the paged pool holds every layer's latent K/V rows, and every slot
         has per layer the convolutions' two tails and the shifted value's
-        half, float32, and last its newest token and sampling key
-        (``ahead``)."""
-        _validate_hybrid_build(stages, self, max_len, block_size,
-                               cache_dtype, mesh, adapters,
-                               caller="ZayaConfig.paged_serving",
-                               maker="make_zaya_stages")
-        _check_attn_kernel(kernel, "ZayaConfig.paged_serving")
+        half, float32, and last its newest token and sampling key."""
+        validate_hybrid_build(stages, self, max_len, block_size,
+                              cache_dtype, mesh, adapters,
+                              caller="ZayaConfig.paged_serving",
+                              maker="make_zaya_stages")
+        check_attn_kernel(kernel, "ZayaConfig.paged_serving")
         f32 = jnp.float32
         layer = (jax.ShapeDtypeStruct((self.conv0 - 1, self.d_conv), f32),
                  jax.ShapeDtypeStruct((self.conv1 - 1, self.d_conv), f32),
@@ -206,14 +203,14 @@ class ZayaConfig:
             kv_layers=self.n_layers, kv_heads=self.n_kv_heads,
             head_dim=self.head_dim,
             state_shapes=(layer,) * self.n_layers + (NEWEST_PAIR,),
-            chunk_prefill=_memo_build(
+            chunk_prefill=memo_build(
                 ("cca_chunk", self, block_size),
                 lambda: _build_cca_prefill_chunk(self, block_size)),
-            decode=_memo_build(
+            decode=memo_build(
                 ("cca_decode", self, block_size, kernel),
                 lambda: _build_cca_decode_step(self, block_size, kernel)),
             pack_chunk=pack_chunk_inputs, pack_decode=pack_decode_inputs,
-            ahead=True, counters=EXPERT_COUNTERS)
+            counters=EXPERT_COUNTERS)
 
 
 # -- parameters ---------------------------------------------------------------
@@ -411,11 +408,11 @@ def full_logits(params: dict, tokens, cfg: ZayaConfig):
         q, k, v, _ = _cca_mix(ap, rms_norm(ap["norm"], h, cfg.rms_eps),
                               _zero_tails(cfg, bsz), positions, cfg)
         h = _merge(ap, h, matmul_acc32(
-            _grouped_attention(q, k, v, causal, cfg), ap["wo"]))
+            grouped_attention(q, k, v, causal, cfg), ap["wo"]))
         y, carried, _ = _top1_experts(
             ep, rms_norm(ep["norm"], h, cfg.rms_eps), carried, cfg)
         h = _merge(ep, h, y)
-    return _tied_logits(params, h, cfg)
+    return tied_logits(params, h, cfg)
 
 
 # -- serving: the two paged programs ------------------------------------------
@@ -429,10 +426,10 @@ def _cca_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
     start from the slot's tails (zeros when ``p0 == 0``, so a slot never
     sees its last occupant's), the keys they give are scattered into the
     slot's blocks, and the chunk attends over the slot's LIVE positions, a
-    step of blocks at a time (``models/gpt.py::_span_attention``): what the
-    table holds past ``p0 + c`` is never fetched. Returns the last
+    step of blocks at a time (``models/serving.py::span_attention``): what
+    the table holds past ``p0 + c`` is never fetched. Returns the last
     position's logits ``[V]``."""
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     c = tokens.shape[1]
     h = embedding_lookup(embed["tok"], tokens.astype(jnp.int32)).astype(
         jnp.float32)
@@ -449,15 +446,15 @@ def _cca_chunk_fwd(params, kc, vc, state, tokens, p0, table, slot,
                                   tails, idx[None], cfg)
         state[li] = tuple(jax.lax.dynamic_update_slice_in_dim(t, new, slot, 0)
                           for t, new in zip(state[li], tails))
-        kc = _paged_scatter(kc, li, phys, off, k[0])
-        vc = _paged_scatter(vc, li, phys, off, v[0])
-        a = _span_attention(q, kc[li], vc[li], table[None], idx[None], None,
-                            cfg.n_kv_heads, bs)
+        kc = paged_scatter(kc, li, phys, off, k[0])
+        vc = paged_scatter(vc, li, phys, off, v[0])
+        a = span_attention(q, kc[li], vc[li], table[None], idx[None], None,
+                           cfg.n_kv_heads, bs)
         h = _merge(ap, h, matmul_acc32(a, ap["wo"]))
         y, carried, _ = _top1_experts(
             ep, rms_norm(ep["norm"], h, cfg.rms_eps), carried, cfg)
         h = _merge(ep, h, y)
-    logits = _tied_logits({"embed": embed, "head": head}, h[:, -1], cfg)
+    logits = tied_logits({"embed": embed, "head": head}, h[:, -1], cfg)
     return kc, vc, tuple(state), logits[0]
 
 
@@ -470,11 +467,11 @@ def _build_cca_prefill_chunk(cfg: ZayaConfig, bs: int):
     def chunk_cca_prefill(params, kc, vc, state, tokens, host):
         *layers, newest = state
         (p0, table, slot, seat, key_data, temperature, top_k,
-         top_p) = _unpack_chunk(host)
+         top_p) = unpack_chunk(host)
         kc, vc, layers, row = _cca_chunk_fwd(
             params, kc, vc, tuple(layers), tokens, p0, table, slot, cfg, bs)
-        tok, kd = _sample_slot(row, key_data, temperature, top_k, top_p)
-        newest = _seat_newest(newest, slot, seat, tok, kd, key_data)
+        tok, kd = sample_slot(row, key_data, temperature, top_k, top_p)
+        newest = seat_newest(newest, slot, seat, tok, kd, key_data)
         return kc, vc, (*layers, newest), tok, kd
 
     return chunk_cca_prefill
@@ -487,7 +484,7 @@ def _cca_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
     all-trash table; their tails come back unchanged). Returns logits ``[S,
     V]`` and, per layer, the rows each expert got ``[n_layers, E]`` (every
     slot's row counts: the run computes them all)."""
-    embed, blocks, head = _merged_stage_trees(params)
+    embed, blocks, head = merged_stage_trees(params)
     h = embedding_lookup(embed["tok"], toks[:, None]).astype(jnp.float32)
     phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
@@ -503,25 +500,25 @@ def _cca_decode_fwd(params, kc, vc, state, toks, pos, tables, live,
         state[li] = tuple(
             jnp.where(live.reshape(-1, *[1] * (t.ndim - 1)), new, t)
             for t, new in zip(state[li], tails))
-        kc = _paged_scatter(kc, li, phys, off, k[:, 0])
-        vc = _paged_scatter(vc, li, phys, off, v[:, 0])
+        kc = paged_scatter(kc, li, phys, off, k[:, 0])
+        vc = paged_scatter(vc, li, phys, off, v[:, 0])
         if kernel == "fused":
-            a = _paged_attend(kc, vc, li, jnp.swapaxes(q, 1, 2), tables,
-                              pos[:, None], bs)               # [S, H, 1, dh]
+            a = paged_attend(kc, vc, li, jnp.swapaxes(q, 1, 2), tables,
+                             pos[:, None], bs)               # [S, H, 1, dh]
             a = jnp.swapaxes(a, 1, 2).reshape(a.shape[0], 1, -1)
         else:
             # [S, KV, span, dh] -> [S, span, KV, dh]
             krow = jnp.swapaxes(
-                _paged_gather(kc, li, tables, cfg.n_kv_heads), 1, 2)
+                paged_gather(kc, li, tables, cfg.n_kv_heads), 1, 2)
             vrow = jnp.swapaxes(
-                _paged_gather(vc, li, tables, cfg.n_kv_heads), 1, 2)
-            a = _grouped_attention(q, krow, vrow, seen, cfg)
+                paged_gather(vc, li, tables, cfg.n_kv_heads), 1, 2)
+            a = grouped_attention(q, krow, vrow, seen, cfg)
         h = _merge(ap, h, matmul_acc32(a, ap["wo"]))
         y, carried, r = _top1_experts(
             ep, rms_norm(ep["norm"], h, cfg.rms_eps), carried, cfg)
         rows.append(r)
         h = _merge(ep, h, y)
-    logits = _tied_logits({"embed": embed, "head": head}, h[:, 0], cfg)
+    logits = tied_logits({"embed": embed, "head": head}, h[:, 0], cfg)
     return kc, vc, tuple(state), logits, jnp.stack(rows)
 
 
@@ -536,17 +533,17 @@ def _build_cca_decode_step(cfg: ZayaConfig, bs: int, kernel: str):
     def step_cca_decode(params, kc, vc, state, host):
         *layers, newest = state
         toks, key_data = newest
-        pos, tables, live, temps, top_ks, top_ps = _unpack_decode(host)
+        pos, tables, live, temps, top_ks, top_ps = unpack_decode(host)
         kc, vc, layers, logits, expert_rows = _cca_decode_fwd(
             params, kc, vc, tuple(layers), toks, pos, tables, live, cfg, bs,
             kernel)
-        toks2, kd2 = _sample_slots(logits, key_data, temps, top_ks, top_ps)
+        toks2, kd2 = sample_slots(logits, key_data, temps, top_ks, top_ps)
         counters = jnp.stack([(expert_rows > 0).sum(),
                               expert_rows.max()]).astype(jnp.int32)
         rows = jnp.concatenate([
             toks2[:, None],
             jnp.broadcast_to(counters, (toks2.shape[0], 2))], axis=1)
-        return (kc, vc, (*layers, _feed_newest(newest, live, toks2, kd2)),
+        return (kc, vc, (*layers, feed_newest(newest, live, toks2, kd2)),
                 rows, kd2)
 
     return step_cca_decode

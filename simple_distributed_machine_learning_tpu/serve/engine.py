@@ -3,8 +3,8 @@
 :class:`InferenceEngine` is the serving API over a model's serving
 programs, the paged KV-cache pool and the FCFS scheduler. It takes the
 cache's shape and its two compiled programs from the model
-(``cfg.paged_serving(...)`` -> ``models/gpt.py::PagedServing``) and nowhere
-else: GPT (``models/gpt.py``) is attention in every block and nothing
+(``cfg.paged_serving(...)`` -> ``models/serving.py::PagedServing``) and
+nowhere else: GPT (``models/gpt.py``) is attention in every block and nothing
 else; a model with state-space layers (``models/jamba.py``) also has a
 recurrent buffer per slot; a model that generates by diffusion over blocks
 (``models/sdar.py``, ``PagedServing.block > 1``) keeps each slot's block in
@@ -34,9 +34,9 @@ Device state is exactly the pool's buffers; everything else (positions,
 block tables, request lifecycle, and the host's copy of last tokens and
 key streams) is host-side numpy assembled into each tick's inputs — the
 scheduler stays plain Python while every FLOP runs inside the compiled
-programs. A model whose programs keep every slot's newest token and key on
-the device (``PagedServing.ahead``: both of this package's) gets the tick
-of :meth:`InferenceEngine._tick_ahead`: decode, then the chunk, and the
+programs. Every model's programs keep every slot's newest token and key on
+the device (``models/serving.py::PagedServing``), so the tick is
+:meth:`InferenceEngine._tick_ahead`'s: decode, then the chunk, and the
 NEXT tick's decode launched before this tick's tokens are read, so the
 device works through the host's share of the tick. A request's tokens are
 the same; the slot a chunk seats decodes from the tick after. Speculative
@@ -84,8 +84,8 @@ from simple_distributed_machine_learning_tpu.serve.scheduler import (
 from simple_distributed_machine_learning_tpu.serve.slots import PagedKVPool
 from simple_distributed_machine_learning_tpu.telemetry import tracing
 
-# sampling-param sentinels (models/gpt.py::_sample_dyn): 0 disables top-k,
-# anything > 1 disables top-p
+# sampling-param sentinels (models/serving.py::sample_dyn): 0 disables
+# top-k, anything > 1 disables top-p
 _NO_TOP_K = 0
 _NO_TOP_P = 2.0
 
@@ -143,94 +143,56 @@ def _seed_key_data(seed: int, fold: int | None = None) -> np.ndarray:
         return np.asarray(jax.random.key_data(key))
 
 
-def _refuse_for_recurrent_state(*, host_cache_blocks, draft_stages,
-                                lint) -> None:
-    """A model with per-slot recurrent state serves through the paged pool
-    and nothing that was built for K/V blocks alone: each such mechanism is
-    refused by name rather than half-done. (``mesh``, ``adapters`` and a
-    quantized ``cache_dtype`` reach the model's own ``paged_serving``, which
-    refuses them in the same words.)"""
-    why = {
-        "host_cache_blocks": (
-            bool(host_cache_blocks),
-            "the host offload tier demotes prefix BLOCKS, and a block "
-            "without the recurrent state that goes with it is no prefix"),
-        "draft_stages (speculative decoding)": (
-            draft_stages is not None,
-            "a rejected draft token cannot be taken back out of the "
-            "recurrent state without a snapshot of it"),
-        "lint=True": (
-            bool(lint),
-            "the analyzer's program registry (analysis/programs.py) builds "
-            "GPT's programs"),
-    }
-    for name, (asked, reason) in why.items():
-        if asked:
-            raise ValueError(
-                f"{name} is not available with a model that has recurrent "
-                f"state: {reason}")
+# What was built for K/V blocks alone, for blocks that live as long as their
+# request or for one token a step is refused by name where the model declares
+# another trait, rather than half-done: (trait, option) -> reason, the trait
+# in the words that end "a model that ...". (``mesh``, a quantized
+# ``cache_dtype`` and, but for window layers, ``adapters`` reach the model's
+# own ``paged_serving`` and the pool, which refuse them in the same words.)
+_RECURRENT = "has recurrent state"
+_BLOCK_STEPS = "generates by diffusion over blocks"
+_WINDOWS = "has window layers"
+_HOST, _DRAFT = "host_cache_blocks", "draft_stages (speculative decoding)"
+_ADAPTERS, _LINT = "adapters", "lint=True"
+_GPT_REGISTRY = ("the analyzer's program registry (analysis/programs.py) "
+                 "builds GPT's programs")
+_REFUSALS = {
+    (_RECURRENT, _HOST):
+        "the host offload tier demotes prefix BLOCKS, and a block without "
+        "the recurrent state that goes with it is no prefix",
+    (_RECURRENT, _DRAFT):
+        "a rejected draft token cannot be taken back out of the recurrent "
+        "state without a snapshot of it",
+    (_RECURRENT, _LINT): _GPT_REGISTRY,
+    (_BLOCK_STEPS, _HOST):
+        "the host offload tier demotes and uploads prefix blocks of any "
+        "fill, and a row here is valid only with its whole block",
+    (_BLOCK_STEPS, _DRAFT):
+        "a tick already decides several positions at once, by the model's "
+        "own confidence and not by a draft's proposals",
+    (_BLOCK_STEPS, _LINT): _GPT_REGISTRY,
+    (_WINDOWS, _HOST):
+        "the host offload tier demotes prefix blocks, and a window layer "
+        "has handed its share of a prefix back",
+    (_WINDOWS, _DRAFT):
+        "a rejected draft token's window blocks may already have been "
+        "handed back behind it",
+    (_WINDOWS, _ADAPTERS):
+        "the LoRA bank rides GPT's wq / wv (models/lora.py)",
+    (_WINDOWS, _LINT): _GPT_REGISTRY,
+}
 
 
-def _refuse_for_block_steps(*, host_cache_blocks, draft_stages,
-                            lint) -> None:
-    """A model whose step works on a block of positions
-    (``PagedServing.block > 1``) serves through the paged pool, chunked
-    prefill and the tick dispatched ahead; what was built for one token a
-    step is refused by name, as :func:`_refuse_for_recurrent_state` does
-    (``mesh``, ``adapters`` and a quantized ``cache_dtype`` reach the
-    model's own ``paged_serving``, which refuses them in the same words)."""
-    why = {
-        "host_cache_blocks": (
-            bool(host_cache_blocks),
-            "the host offload tier demotes and uploads prefix blocks of "
-            "any fill, and a row here is valid only with its whole block"),
-        "draft_stages (speculative decoding)": (
-            draft_stages is not None,
-            "a tick already decides several positions at once, by the "
-            "model's own confidence and not by a draft's proposals"),
-        "lint=True": (
-            bool(lint),
-            "the analyzer's program registry (analysis/programs.py) builds "
-            "GPT's programs"),
-    }
-    for name, (asked, reason) in why.items():
-        if asked:
-            raise ValueError(
-                f"{name} is not available with a model that generates by "
-                f"diffusion over blocks: {reason}")
-
-
-def _refuse_for_window_layers(*, host_cache_blocks, draft_stages, lint,
-                              adapters) -> None:
-    """A model with window layers beside full ones
-    (``PagedServing.windows``) serves through the paged pool's groups
-    (``serve/slots.py``, "Layer kinds"); what was built for blocks that live
-    as long as their request is refused by name, as
-    :func:`_refuse_for_recurrent_state` does (``mesh`` and a quantized
-    ``cache_dtype`` reach the model's own ``paged_serving`` and the pool,
-    which refuse them in the same words)."""
-    why = {
-        "host_cache_blocks": (
-            bool(host_cache_blocks),
-            "the host offload tier demotes prefix blocks, and a window "
-            "layer has handed its share of a prefix back"),
-        "draft_stages (speculative decoding)": (
-            draft_stages is not None,
-            "a rejected draft token's window blocks may already have been "
-            "handed back behind it"),
-        "adapters": (
-            adapters is not None,
-            "the LoRA bank rides GPT's wq / wv (models/lora.py)"),
-        "lint=True": (
-            bool(lint),
-            "the analyzer's program registry (analysis/programs.py) builds "
-            "GPT's programs"),
-    }
-    for name, (asked, reason) in why.items():
-        if asked:
-            raise ValueError(
-                f"{name} is not available with a model that has window "
-                f"layers: {reason}")
+def _refuse(declared: dict, asked: dict) -> None:
+    """Raise for the first option of ``asked`` (name -> whether it was
+    asked for) that :data:`_REFUSALS` holds against a trait the model has
+    (``declared``: trait -> whether it has it), in the order given."""
+    for trait, has in declared.items():
+        for option, on in asked.items():
+            if has and on and (trait, option) in _REFUSALS:
+                raise ValueError(
+                    f"{option} is not available with a model that {trait}: "
+                    f"{_REFUSALS[trait, option]}")
 
 
 class InferenceEngine:
@@ -243,7 +205,7 @@ class InferenceEngine:
     own (``PagedServing.serve_params``) the engine makes it of either, once,
     and ``self.params`` is that. ``max_len`` caps each slot's prompt+generation
     budget (defaults to ``cfg.seq_len``); ``cache_dtype`` is the pool's
-    storage dtype (bf16 halves pool memory, the ``_cache_dtype`` rule).
+    storage dtype (bf16 halves pool memory, the ``storage_dtype`` rule).
 
     Pool knobs: ``block_size`` positions per K/V block; ``n_blocks`` pool
     capacity (default ``n_slots * ceil(max_len/block_size)``: every slot
@@ -256,7 +218,7 @@ class InferenceEngine:
     HBM pass, ``ops/paged_attention.py``; greedy token streams stay
     bit-exact vs ``"dense"``). A QUANTIZED ``cache_dtype`` (``"int8"``, or
     fp8 where the jnp build has it) stores paged blocks narrow with
-    per-row f32 scales (``models/gpt.py::QuantKV``) — roughly 3.6x more
+    per-row f32 scales (``models/serving.py::QuantKV``): roughly 3.6x more
     resident requests per byte than f32 at pinned-tolerance logits, with
     dequantize fused into both attention paths. ``host_cache_blocks > 0``
     enables the LRU host-RAM offload tier (evicted prefix blocks demote to
@@ -372,10 +334,10 @@ class InferenceEngine:
                 f"AdapterStore has {adapters.n_rows} bank rows but this "
                 f"engine needs n_slots + 1 = {n_slots + 1} (base row + one "
                 f"per slot — the never-refuse sizing)")
-        if cfg.recurrent_state:
-            _refuse_for_recurrent_state(
-                host_cache_blocks=host_cache_blocks,
-                draft_stages=draft_stages, lint=lint)
+        asked = {_HOST: bool(host_cache_blocks),
+                 _DRAFT: draft_stages is not None,
+                 _ADAPTERS: adapters is not None, _LINT: bool(lint)}
+        _refuse({_RECURRENT: cfg.recurrent_state}, asked)
         self._adapters = adapters
         self.cfg = cfg
         self.stages = stages       # kept for the analyzer's program registry
@@ -391,7 +353,7 @@ class InferenceEngine:
         self.draft_stages = draft_stages   # for the analyzer's registry
         self.draft_cfg = draft_cfg
         adp = adapters is not None
-        # the model's cache layout and its two programs (models/gpt.py
+        # the model's cache layout and its two programs (models/serving.py
         # ::PagedServing); everything below the pool is the model's
         serving = cfg.paged_serving(
             stages, self.max_len, block_size, cache_dtype, mesh=mesh,
@@ -401,16 +363,11 @@ class InferenceEngine:
         if serving.serve_params is not None:
             self.params = serving.serve_params(self.params)
         self._n_layers = serving.kv_layers
-        if any(w is not None for w in serving.windows):
-            _refuse_for_window_layers(
-                host_cache_blocks=host_cache_blocks,
-                draft_stages=draft_stages, lint=lint, adapters=adapters)
         # positions a slot's step works on (PagedServing.block)
         self._block = int(serving.block)
+        _refuse({_WINDOWS: any(w is not None for w in serving.windows),
+                 _BLOCK_STEPS: self._block > 1}, asked)
         if self._block > 1:
-            _refuse_for_block_steps(
-                host_cache_blocks=host_cache_blocks,
-                draft_stages=draft_stages, lint=lint)
             if prefill_chunk is not None and prefill_chunk % self._block:
                 raise ValueError(
                     f"prefill_chunk={prefill_chunk} must be a multiple of "
@@ -424,7 +381,7 @@ class InferenceEngine:
         self._counted = dict.fromkeys(self._counter_names, 0)
         # of the slots of the decode this tick read, those that sample
         # (temperature > 0): where 0 the programs' sampler took its argmax
-        # branch and sorted nothing (models/gpt.py::_sample_slots)
+        # branch and sorted nothing (models/serving.py::sample_slots)
         self._sampling = 0
         # the summed lengths of those slots: the K/V positions a layer of
         # that decode read
@@ -454,21 +411,23 @@ class InferenceEngine:
         self._pack_chunk = serving.pack_chunk
         self._pack_decode = serving.pack_decode
         # the model's programs keep the newest tokens on the device
-        # (PagedServing.ahead: its chunks are told what to seat): the tick
-        # is _tick_ahead's, and _ahead the decode it has dispatched for
-        # the next one. Not under speculation, whose tick reads the
-        # host's tokens (the pair its chunks seat is then never read)
-        self._seats_newest = serving.ahead
-        self._dispatch_ahead = serving.ahead and not self.speculative
+        # (PagedServing: its chunks are told what to seat): the tick is
+        # _tick_ahead's, and _ahead the decode it has dispatched for the
+        # next one. Not under speculation, whose tick reads the host's
+        # tokens (the pair its chunks seat is then never read)
+        self._dispatch_ahead = not self.speculative
         self._ahead = None
         # program runs launched in the engine's life (decode, chunk,
         # speculative and block programs alike): a run's number is on its
         # ``*.dispatch`` span and on the ``*.wait`` span that reads it
         self._runs = 0
         from simple_distributed_machine_learning_tpu.models.gpt import (
+            make_paged_block_copy,
+        )
+        from simple_distributed_machine_learning_tpu.models.serving import (
             SEAT_NONE,
             SEAT_SAMPLE,
-            make_paged_block_copy,
+            is_quantized_dtype,
         )
         self._copy_block = make_paged_block_copy()
         self._seat_none, self._seat_sample = SEAT_NONE, SEAT_SAMPLE
@@ -482,13 +441,12 @@ class InferenceEngine:
             # draft cache is small by design, and its rows feed proposals
             # only (acceptance always re-scores on the target)
             from simple_distributed_machine_learning_tpu.models.gpt import (
-                _is_quantized_dtype,
                 make_paged_spec_tick,
                 make_paged_verify_step,
                 make_slot_prefill,
                 make_slot_propose,
             )
-            self._draft_cache_dtype = (None if _is_quantized_dtype(
+            self._draft_cache_dtype = (None if is_quantized_dtype(
                 cache_dtype) else cache_dtype)
             self._draft_prefill = make_slot_prefill(
                 draft_stages, draft_cfg, self.max_len,
@@ -574,13 +532,13 @@ class InferenceEngine:
         section)."""
         import jax.numpy as jnp
 
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            _cache_dtype,
+        from simple_distributed_machine_learning_tpu.models.serving import (
+            storage_dtype,
         )
         dcfg = self.draft_cfg
         dL = sum(len(p["blocks"]) for p in self._draft_params)
         ddh = dcfg.d_model // dcfg.n_heads
-        cd = _cache_dtype(self._draft_cache_dtype)
+        cd = storage_dtype(self._draft_cache_dtype)
         shape = (dL, n_slots, dcfg.n_heads, self.max_len, ddh)
         self._dkc = jnp.zeros(shape, cd)
         self._dvc = jnp.zeros(shape, cd)
@@ -1138,26 +1096,25 @@ class InferenceEngine:
         with tracing.span("engine.prefill.prepare", rid=r.rid, p0=p0, n=c):
             t_start = self._now = self._clock()
             self._ensure_writable_range(r.slot, p0, c)
-            seat = ()
             if self._block > 1:
                 # the last chunk seats the slot's first block: the
                 # sequence's remainder fixed, the rest masked
-                seat = (np.concatenate([
+                seat = np.concatenate([
                     [self._seat_none if p0 + c < plen else len(opening)],
                     opening, np.zeros(self._block - len(opening), np.int32)
-                ]).astype(np.int32),)
-            elif self._seats_newest:
+                ]).astype(np.int32)
+            else:
                 # what the chunk leaves as the slot's newest token on the
                 # device: nothing mid-prompt, its own sample, or a resumed
                 # request's stored one (as _prefill_emit seats the host's)
-                seat = (np.int32(
+                seat = np.int32(
                     self._seat_none if p0 + c < plen else
-                    r.tokens[-1] if r.tokens else self._seat_sample),)
+                    r.tokens[-1] if r.tokens else self._seat_sample)
             args = (
                 seq[None, p0:p0 + c], np.int32(p0),
                 self.pool.device_table(r.slot),
-                # a model with per-slot state is told whose rows these are
-                *((np.int32(r.slot),) if self.pool.has_state else ()), *seat,
+                # the chunk is told whose rows of the state these are
+                np.int32(r.slot), seat,
                 r.key_data, np.float32(r.temperature),
                 np.int32(r.top_k if r.top_k is not None else _NO_TOP_K),
                 np.float32(r.top_p if r.top_p is not None else _NO_TOP_P),
@@ -1275,17 +1232,13 @@ class InferenceEngine:
     def _run_paged(self, program, pack, *args):
         """Call one of the model's two paged programs (its host-side
         arguments through the model's ``pack``, where it has one) and take
-        the donated pool buffers back — and the per-slot state buffers
-        that ride beside them, where the model has any."""
+        the donated pool buffers back, and the per-slot state buffers
+        that ride beside them."""
         pool = self.pool
         if pack is not None:
             args = pack(*args)      # the model takes them as one transfer
-        if pool.has_state:
-            pool.kc, pool.vc, pool.state, tok, kd = program(
-                self.params, pool.kc, pool.vc, pool.state, *args)
-        else:
-            pool.kc, pool.vc, tok, kd = program(
-                self.params, pool.kc, pool.vc, *args)
+        pool.kc, pool.vc, pool.state, tok, kd = program(
+            self.params, pool.kc, pool.vc, pool.state, *args)
         # start the read-back now: the copies are queued behind the program,
         # and the ``*.wait`` that follows finds the bytes on the host
         tok.copy_to_host_async()
@@ -1348,13 +1301,11 @@ class InferenceEngine:
                 pos[s] = p
                 toks[s] = self.pool.last_token[s]
             bank_args = self._bank_args(self._adapter_inputs(active))
-            live = ()
-            if self.pool.has_state:
-                # the slots whose state this tick advances; the others'
-                # (mid-prefill, seated and not yet decoding, free) must
-                # come back unchanged
-                live = (np.zeros(S, bool),)
-                live[0][active] = True
+            # the slots whose state this tick advances; the others'
+            # (mid-prefill, seated and not yet decoding, free) must come
+            # back unchanged
+            live = (np.zeros(S, bool),)
+            live[0][active] = True
             if self._block > 1:
                 steps = np.ones(S, np.int32)
                 for s in active:
@@ -1374,9 +1325,9 @@ class InferenceEngine:
                 sum(min(p + self._block, window) for _, p in seats))
 
     def _tick_ahead(self) -> tuple[int, int]:
-        """The paged tick of a model whose programs keep the newest tokens
-        on the device (``PagedServing.ahead``): the decode FIRST, then the
-        prefill chunk, whose slot decodes from the next tick on. In that
+        """The paged tick over programs that keep the newest tokens on the
+        device (``models/serving.py::PagedServing``): the decode FIRST, then
+        the prefill chunk, whose slot decodes from the next tick on. In that
         order the next tick's decode needs nothing this tick has yet to
         read (its tokens are on the device, and who takes part follows
         from lengths the host knows), so it is dispatched before this

@@ -169,7 +169,7 @@ def validate_request(prompt: np.ndarray, max_new_tokens: int,
                      temperature: float, top_k: int | None,
                      top_p: float | None, vocab: int, max_len: int) -> None:
     """Submit-time validation: length/prompt bounds here, sampling args
-    delegated to the one-shot decoders' ``_check_sampling_args`` — one
+    delegated to the one-shot decoders' ``check_sampling_args`` — one
     source of truth, so a request the engine accepts is exactly one
     ``make_cached_decoder`` accepts."""
     prompt = np.asarray(prompt)
@@ -187,6 +187,6 @@ def validate_request(prompt: np.ndarray, max_new_tokens: int,
         raise ValueError(
             f"prompt tokens outside [0, vocab={vocab})")
     from simple_distributed_machine_learning_tpu.models.gpt import (
-        _check_sampling_args,
+        check_sampling_args,
     )
-    _check_sampling_args(temperature, top_k, top_p, vocab)
+    check_sampling_args(temperature, top_k, top_p, vocab)

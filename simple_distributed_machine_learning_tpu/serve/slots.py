@@ -22,7 +22,7 @@ Per-slot state (``state_shapes``): device buffers beside the blocks
 donated through the model's programs with the blocks). Two things live
 there, and they are two facts. Every model of this package keeps each
 slot's newest token and sampling key there
-(``models/gpt.py::PagedServing.ahead``; ``last_token`` here then trails the
+(``models/serving.py::PagedServing``; ``last_token`` here then trails the
 device by the tick in flight): a token and a key are a request's own, a
 chunk seats them, and nothing about the blocks changes. ``recurrent`` says
 the other thing: a model with state-space layers (``models/jamba.py``)
@@ -37,16 +37,16 @@ occupant's state. Requests that WOULD have matched are counted
 (``prefix_declined_total``).
 
 A model whose step works on a BLOCK of positions (``step_rows > 1``,
-``models/gpt.py::PagedServing.block``: generation by diffusion over blocks)
-writes whole blocks of ``step_rows`` rows: a sequence's budget is its
-length rounded up to one, the host keeps for each slot how many forwards
+``models/serving.py::PagedServing.block``: generation by diffusion over
+blocks) writes whole blocks of ``step_rows`` rows: a sequence's budget is
+its length rounded up to one, the host keeps for each slot how many forwards
 its block in progress has had and how many it takes (``block_fwd`` /
 ``block_total``), and a K/V row depends on the tokens up to the END of its
 block, so only prefixes of whole pool blocks (a multiple of ``step_rows``)
 are registered and matched.
 
-Layer kinds (``windows``, ``models/gpt.py::PagedServing.windows``): a model
-whose attention layers do not all look back equally far has layers of
+Layer kinds (``windows``, ``models/serving.py::PagedServing.windows``): a
+model whose attention layers do not all look back equally far has layers of
 several KINDS in one pool. Layers of one window value are a GROUP; the
 layers that attend every earlier position are the FULL group, and it is
 everything said above (a pool without windows has that group alone and is
@@ -122,21 +122,21 @@ def kv_block_bytes(n_layers: int, n_heads: int, block_size: int,
     (``analysis/programs.py``) predicts against it — the cross-check in
     tests/test_analysis_serve.py holds because both sides share this.
 
-    QUANTIZED dtypes (int8/fp8, ``models/gpt.py::_is_quantized_dtype``)
+    QUANTIZED dtypes (int8/fp8, ``models/serving.py::is_quantized_dtype``)
     add the per-block scale planes to the bill: one f32 scale per
     (position, head) row, for K and for V — the honest block footprint,
     so a fixed-byte pool sizing (``n_blocks_for_bytes``) and the
     resident-bytes gauge can never claim the scale planes are free."""
     import jax.numpy as jnp
 
-    from simple_distributed_machine_learning_tpu.models.gpt import (
-        _cache_dtype,
-        _is_quantized_dtype,
+    from simple_distributed_machine_learning_tpu.models.serving import (
+        is_quantized_dtype,
+        storage_dtype,
     )
-    cd = _cache_dtype(cache_dtype)
+    cd = storage_dtype(cache_dtype)
     bytes_ = (2 * n_layers * n_heads * block_size * head_dim
               * jnp.dtype(cd).itemsize)
-    if _is_quantized_dtype(cache_dtype):
+    if is_quantized_dtype(cache_dtype):
         bytes_ += 2 * n_layers * n_heads * block_size * 4   # f32 scales
     return int(bytes_)
 
@@ -393,16 +393,16 @@ class PagedKVPool:
         self.n_blocks = n_blocks
         import jax.numpy as jnp
 
-        from simple_distributed_machine_learning_tpu.models.gpt import (
+        from simple_distributed_machine_learning_tpu.models.serving import (
             QuantKV,
-            _cache_dtype,
-            _check_cache_quantization,
-            _is_quantized_dtype,
+            check_cache_quantization,
+            is_quantized_dtype,
+            storage_dtype,
         )
-        _check_cache_quantization(cache_dtype, "PagedKVPool", paged=True)
-        cd = _cache_dtype(cache_dtype)
+        check_cache_quantization(cache_dtype, "PagedKVPool", paged=True)
+        cd = storage_dtype(cache_dtype)
         self.cache_dtype = cd
-        self.quantized = _is_quantized_dtype(cache_dtype)
+        self.quantized = is_quantized_dtype(cache_dtype)
         # layer kinds (module docstring): ``windows[li]`` is layer li's
         # window in positions, None (or no entry) a full layer
         windows = tuple(windows) or (None,) * n_layers
@@ -449,7 +449,7 @@ class PagedKVPool:
             if not self.quantized:
                 return jnp.zeros(shape, cd)
             # narrow block data + per-(position, head) f32 scale planes as
-            # ONE pytree buffer per layer (models/gpt.py::QuantKV): every
+            # ONE pytree buffer per layer (models/serving.py::QuantKV): every
             # compiled step, the CoW copy, donation and TP placement
             # thread the pair together
             return QuantKV(jnp.zeros(shape, cd),
@@ -538,14 +538,14 @@ class PagedKVPool:
                                   step_rows) -> None:
         """What a pool with a window group refuses, by name (module
         docstring, "Layer kinds")."""
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            _is_quantized_dtype,
+        from simple_distributed_machine_learning_tpu.models.serving import (
+            is_quantized_dtype,
         )
         for name, asked, reason in (
                 ("host_cache_blocks", bool(host_cache_blocks),
                  "the host offload tier demotes prefix blocks, and a window "
                  "layer has handed its share of a prefix back"),
-                ("a quantized cache_dtype", _is_quantized_dtype(cache_dtype),
+                ("a quantized cache_dtype", is_quantized_dtype(cache_dtype),
                  "the window walk of ops/paged_attention.py has no scale "
                  "planes: use float32 or bfloat16"),
                 ("tp > 1", tp > 1,

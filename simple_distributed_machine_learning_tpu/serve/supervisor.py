@@ -210,10 +210,10 @@ def engine_factory(stages, cfg, *, metrics=None, clock=time.monotonic,
         dkw = {k: v for k, v in kw.items()
                if k not in ("attn_kernel", "host_cache_blocks",
                             "prefetch_ticks")}
-        from simple_distributed_machine_learning_tpu.models.gpt import (
-            _is_quantized_dtype,
+        from simple_distributed_machine_learning_tpu.models.serving import (
+            is_quantized_dtype,
         )
-        if _is_quantized_dtype(dkw.get("cache_dtype")):
+        if is_quantized_dtype(dkw.get("cache_dtype")):
             # the fallback widens a quantized pool to f32 — same rule
             # degraded_spec mirrors for the lint gate
             dkw["cache_dtype"] = None

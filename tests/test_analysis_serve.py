@@ -722,30 +722,82 @@ def test_degraded_spec_of_a_fused_paged_spec_lints_clean(stages):
 
 def test_engine_imports_only_what_speculation_and_cow_need():
     """``serve/engine.py`` reaches a target model's programs through
-    ``cfg.paged_serving()`` alone. What it still imports from
-    ``models/gpt.py`` by name is pinned here: the seat constants and the
-    block copy (copy-on-write), the speculative verify / tick / draft
-    builders, the TP weight packing and two dtype helpers. A decode or
-    prefill builder for the target cannot come back unnoticed."""
+    ``cfg.paged_serving()`` alone. What it still imports from ``models/`` by
+    name is pinned here: from ``models/serving.py`` the seat constants and
+    two dtype helpers, from ``models/gpt.py`` the block copy
+    (copy-on-write), the speculative verify / tick / draft builders and
+    the TP weight packing. A decode or prefill builder for the target
+    cannot come back unnoticed."""
     import simple_distributed_machine_learning_tpu.serve.engine as engine
     with open(engine.__file__) as f:
         tree = ast.parse(f.read())
-    names, modules = set(), set()
+    names = {}
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom) and ".models" in (
                 node.module or ""):
-            modules.add(node.module.rsplit(".", 1)[1])
-            names |= {a.name for a in node.names}
-    assert modules == {"gpt"}
+            names.setdefault(node.module.rsplit(".", 1)[1], set()).update(
+                a.name for a in node.names)
     assert names == {
-        "SEAT_NONE", "SEAT_SAMPLE", "make_paged_block_copy",
-        "make_paged_verify_step", "make_paged_spec_tick",
-        "make_slot_prefill", "make_slot_propose",
-        "pack_tp_serve_params", "_cache_dtype", "_is_quantized_dtype"}
-    builders = names & set(DECODE_BUILDERS)
+        "serving": {"SEAT_NONE", "SEAT_SAMPLE", "storage_dtype",
+                    "is_quantized_dtype"},
+        "gpt": {"make_paged_block_copy", "make_paged_verify_step",
+                "make_paged_spec_tick", "make_slot_prefill",
+                "make_slot_propose", "pack_tp_serve_params"}}
+    builders = names["gpt"] & set(DECODE_BUILDERS)
     assert builders == {"make_paged_block_copy", "make_paged_verify_step",
                         "make_paged_spec_tick", "make_slot_prefill",
                         "make_slot_propose"}
+
+
+def _package_imports():
+    """``(importing module, imported module, name)`` of every ``from ...
+    import name`` inside the package, modules as dotted paths below it
+    (``models.gpt``, ``serve.engine``), by ``ast``."""
+    import simple_distributed_machine_learning_tpu as package
+    root = os.path.dirname(package.__file__)
+    prefix = package.__name__ + "."
+    found = []
+    for folder, _dirs, files in sorted(os.walk(root)):
+        for fname in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(folder, fname)
+            here = os.path.relpath(path, root)[:-3].replace(os.sep, ".")
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.ImportFrom) and (
+                        node.module or "").startswith(prefix):
+                    there = node.module[len(prefix):]
+                    found += [(here, there, a.name) for a in node.names]
+    return found
+
+
+@pytest.mark.parametrize("rule", ["no-private-name", "families-serving-only",
+                                  "serving-imports-no-family"])
+def test_direction_of_imports_around_the_serving_contract(rule):
+    """What a paged serving program is made of lives in
+    ``models/serving.py``, and the imports say so: (a) nothing in the
+    package imports an underscored name from a module of ``models/`` (a
+    name two modules need is an interface and loses the underscore; the
+    three names ``tests/bench_cells/`` patches are imported public and
+    bound private), (b) the five families after GPT import from
+    ``models/`` nothing but ``models.serving``, (c) ``models/serving.py``
+    imports no family and nothing from ``serve/``."""
+    imports = _package_imports()
+    assert len(imports) > 500        # the walk found the package
+    if rule == "no-private-name":
+        assert [(here, there, name) for here, there, name in imports
+                if there.startswith("models.") and name.startswith("_")] == []
+    elif rule == "families-serving-only":
+        for family in ("jamba", "sdar", "nemotron_h", "zaya", "cohere2"):
+            took = {there for here, there, _ in imports
+                    if here == f"models.{family}"
+                    and (there + ".").startswith("models.")}
+            assert took == {"models.serving"}, family
+    else:
+        took = {there for here, there, _ in imports
+                if here == "models.serving"}
+        assert took and not [t for t in took if t.startswith(
+            ("models.", "serve.")) or t in ("models", "serve")], took
 
 
 def test_default_registry_includes_clean_degraded_entry():

@@ -109,7 +109,7 @@ def _eqns(jaxpr):
 
 @pytest.mark.parametrize("dh,bs,k,pool", _PAGED)
 def test_paged_attention_compiles_for_v5e(one_chip, mosaic, dh, bs, k, pool):
-    """The shapes and dtypes ``models/gpt.py::_paged_attend`` passes: f32
+    """The shapes and dtypes ``models/serving.py::paged_attend`` passes: f32
     queries (serving computes in f32), one layer's pool buffer
     ``[n_blocks+1, bs, H*dh]`` in the cache dtype, and for a quantized pool
     the ``QuantKV`` scale plane ``[n_blocks+1, bs, H]`` in f32."""
@@ -346,7 +346,7 @@ def test_serve_programs_leave_the_pool_where_it_is(one_chip, mosaic,
     one layer's K buffer is copied, sliced, transposed or padded, every
     pool byte is aliased input to output, and the temporaries are smaller
     than one layer's K buffer. GPT's two programs hold every slot's newest
-    token and key beside the pool (``PagedServing.ahead``): every byte of
+    token and key beside the pool (``PagedServing``): every byte of
     that pair is aliased too.
 
     What the compiler may still do on its own: stage a buffer in its on-chip
@@ -530,7 +530,7 @@ def test_cca_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
     donated: every byte of both is aliased input to output, and what the
     program holds beside its arguments stays under two layers' K buffers,
     the chunk's under ONE: it attends over the slot's live positions a step
-    of blocks at a time (``models/gpt.py::_span_attention``), so no
+    of blocks at a time (``models/serving.py::span_attention``), so no
     instruction of its compiled text has the table's 8,192 positions beside
     the chunk's 512 rows in one array (until PR 46 a layer's scores were
     ``f32[1,2,4,512,8192]``, 134 MB written, masked and read back)."""

@@ -43,13 +43,16 @@ import pytest
 
 from bench_cells.reference import cohere2 as reference
 
-from simple_distributed_machine_learning_tpu.models import cohere2, gpt
+from simple_distributed_machine_learning_tpu.models import cohere2
+from simple_distributed_machine_learning_tpu.models import (
+    serving as serving_module,
+)
 from simple_distributed_machine_learning_tpu.models.cohere2 import (
     EXPERT_COUNTERS,
     Cohere2Config,
     make_cohere2_stages,
 )
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     SEAT_NONE,
     SEAT_SAMPLE,
 )
@@ -212,7 +215,7 @@ def test_cache_layout_two_kinds_of_layer_in_one_pool(stages):
     assert serving.windows == (8, 8, 8, None) == CFG.windows
     assert (serving.kv_layers, serving.kv_heads, serving.head_dim) == (4, 2,
                                                                        16)
-    assert serving.ahead and serving.counters == EXPERT_COUNTERS
+    assert serving.counters == EXPERT_COUNTERS
     assert len(serving.state_shapes) == 1 and not CFG.recurrent_state
     eng = _engine(stages)
     ring = -(-(WINDOW + CHUNK) // BS) + 1            # 5 blocks of 4
@@ -479,7 +482,7 @@ def _served_rows(logits, n_prompt, n_new):
 
 
 #: sha256 of ``str(jax.make_jaxpr(...))`` of the family's two programs, made
-#: on the PARENT commit (0eac28c, PR 45), where ``_span_attention`` lay in
+#: on the PARENT commit (0eac28c, PR 45), where ``span_attention`` lay in
 #: ``models/cohere2.py`` and took the config, by the same lines as the test
 #: below: (size, kernel, program) -> what the program has to trace
 _PARENT_JAXPR = {
@@ -507,8 +510,8 @@ _TOY_SIZES = (2, ML, BS, 2 * NB_FULL, 10, CHUNK)
 def test_the_window_programs_trace_what_they_traced_before_the_move(
         monkeypatch, size, kernel, program):
     """``command-a-plus-05-2026.serve-mixed-closed`` rides on these two
-    programs: with the chunk's attention lifted to ``models/gpt.py::
-    _span_attention`` (PR 46: the long-context family's chunk calls it too,
+    programs: with the chunk's attention lifted to ``models/serving.py::
+    span_attention`` (PR 46: the long-context family's chunk calls it too,
     and it takes the K/V head count in place of the config) each program's
     jaxpr is the parent commit's to the letter, at the toy's size and at
     the cell's shapes (shapes alone: nothing is allocated). The decode
@@ -529,13 +532,13 @@ def test_the_window_programs_trace_what_they_traced_before_the_move(
         return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
 
     # programs built anew each time: a memoized one keeps its first trace
-    monkeypatch.setattr(gpt, "_DECODE_BUILD_CACHE", {})
+    monkeypatch.setattr(serving_module, "_DECODE_BUILD_CACHE", {})
     plain = text()
     assert hashlib.sha256(plain.encode()).hexdigest() == _PARENT_JAXPR[
         size, kernel, program]
     if (kernel, program) != ("fused", "decode"):
-        monkeypatch.setattr(gpt, "_ATTEND_ROWS", bs)
-        monkeypatch.setattr(gpt, "_DECODE_BUILD_CACHE", {})
+        monkeypatch.setattr(serving_module, "ATTEND_ROWS", bs)
+        monkeypatch.setattr(serving_module, "_DECODE_BUILD_CACHE", {})
         assert text() != plain
 
 
@@ -544,7 +547,7 @@ def small_steps(monkeypatch):
     """The chunk's attention in steps of 8 positions (two blocks), so that
     the toy's 40 positions are five steps and the walk is seen to start
     behind the window, not at 0."""
-    monkeypatch.setattr(gpt, "_ATTEND_ROWS", 8)
+    monkeypatch.setattr(serving_module, "ATTEND_ROWS", 8)
     _twins.cache_clear()
     yield
     _twins.cache_clear()
@@ -1003,7 +1006,7 @@ def test_a_windowed_pool_shares_no_prefix_and_counts_what_it_declines(
 def test_degraded_rebuild_serves_the_family(tmp_path):
     """A supervised deployment with ``degrade_after`` set: the degraded
     rebuild constructs (the fallback keeps the paged pool and its groups
-    and takes the dense kernel, ``_span_attention``) and every request
+    and takes the dense kernel, ``span_attention``) and every request
     finishes bit-exact with the uncrashed run."""
     from simple_distributed_machine_learning_tpu.resilience import faults
     from simple_distributed_machine_learning_tpu.serve.request import DONE

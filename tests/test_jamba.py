@@ -32,7 +32,7 @@ import pytest
 from bench_cells.reference import jamba as reference
 
 from simple_distributed_machine_learning_tpu.models import jamba
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     SEAT_NONE,
     SEAT_SAMPLE,
 )
@@ -115,7 +115,6 @@ def test_layer_order_and_cache_layout_follow_the_config():
     # a pair per Mamba layer, then every slot's newest token and key
     assert [tuple(s.shape for s in pair) for pair in serving.state_shapes] \
         == [((16, 256), (3, 256))] * 2 + [((), (2,))]
-    assert serving.ahead
 
 
 # -- the engine, with the logits it sampled from taken out --------------------
@@ -138,7 +137,7 @@ class Tap:
     also hand out the logits they chose from (greedy: ``argmax``), and the
     recurrent state of every slot after each call. Like the real ones they
     keep every slot's newest token in the state's last pair and take no
-    token from the host (``PagedServing.ahead``)."""
+    token from the host (``models/serving.py::PagedServing``)."""
 
     def __init__(self, stages, kernel="fused", **kw):
         kw = {"n_slots": 2, "max_len": 48, "block_size": BS,
@@ -411,7 +410,7 @@ def test_host_inputs_survive_the_trip_as_one_array():
     assert host.shape == (n_slots, 5 + nb) and host.dtype == np.int32
     # the tokens and the keys stay behind: the program has its own
     sent = [a for i, a in enumerate(args) if i not in (0, 4)]
-    for got, want in zip(jax.jit(jamba._unpack_decode)(host), sent):
+    for got, want in zip(jax.jit(jamba.unpack_decode)(host), sent):
         assert got.dtype == want.dtype and np.array_equal(got, want)
     chunk = (rng.integers(0, 97, (1, 6)).astype(np.int32), np.int32(12),
              rng.integers(0, 30, nb).astype(np.int32), np.int32(3),
@@ -419,7 +418,7 @@ def test_host_inputs_survive_the_trip_as_one_array():
              np.float32(0.7), np.int32(0), np.float32(2.0))
     tokens, host = jamba.pack_chunk_inputs(*chunk)
     assert np.array_equal(tokens, chunk[0]) and host.shape == (8 + nb,)
-    for got, want in zip(jax.jit(jamba._unpack_chunk)(host), chunk[1:]):
+    for got, want in zip(jax.jit(jamba.unpack_chunk)(host), chunk[1:]):
         assert got.dtype == want.dtype and np.array_equal(got, want)
 
 

@@ -35,7 +35,7 @@ import pytest
 from bench_cells.reference import nemotron_h as reference
 
 from simple_distributed_machine_learning_tpu.models import nemotron_h
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models.serving import (
     SEAT_NONE,
     SEAT_SAMPLE,
 )
@@ -139,7 +139,7 @@ def test_layer_kinds_and_cache_layout_follow_the_pattern():
     # then every slot's newest token and key
     assert [tuple(s.shape for s in pair) for pair in serving.state_shapes] \
         == [((16, 256), (3, 256 + 2 * 2 * 16))] * 2 + [((), (2,))]
-    assert serving.ahead and serving.block == 1
+    assert serving.block == 1
     assert serving.counters == EXPERT_COUNTERS
     # the published model's stage: 11 layers, 5 + 5 + 1
     real = NemotronHConfig(

@@ -31,11 +31,13 @@ from tolerances import attn_tol
 
 from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
-    QuantKV,
-    _paged_scatter,
-    _quantize_rows,
     make_gpt_stages,
     make_paged_block_copy,
+)
+from simple_distributed_machine_learning_tpu.models.serving import (
+    QuantKV,
+    paged_scatter,
+    quantize_rows,
 )
 from simple_distributed_machine_learning_tpu.ops.paged_attention import (
     _attend_blocks,
@@ -152,8 +154,8 @@ def test_paged_attention_fused_dequant_matches_dequantized_rows(H):
     q = jax.random.normal(kq, (3, H, K, 16))
     qpos = np.minimum(pos[:, None] + np.arange(K)[None, :],
                       23).astype(np.int32)
-    kd, ks = _quantize_rows(kc, jnp.int8)
-    vd, vs = _quantize_rows(vc, jnp.int8)
+    kd, ks = quantize_rows(kc, jnp.int8)
+    vd, vs = quantize_rows(vc, jnp.int8)
     out = jax.jit(lambda *a: paged_attention(
         *a[:5], block_size=4, kscale=a[5], vscale=a[6]))(
         q, _rows(kd), _rows(vd), jnp.asarray(tables), jnp.asarray(qpos),
@@ -225,8 +227,8 @@ def test_ragged_slots_in_one_call_match_dense_gather(family, pool):
     quant = pool == "int8"
     q, kc, vc, tables, qpos, bs = _ragged_call(family, quant)
     if quant:
-        kd, ks = _quantize_rows(kc, jnp.int8)
-        vd, vs = _quantize_rows(vc, jnp.int8)
+        kd, ks = quantize_rows(kc, jnp.int8)
+        vd, vs = quantize_rows(vc, jnp.int8)
         kw = dict(kscale=_rows(ks), vscale=_rows(vs))
         kc = kd.astype(jnp.float32) * ks[..., None]
         vc = vd.astype(jnp.float32) * vs[..., None]
@@ -362,7 +364,7 @@ def test_quantize_roundtrip_error_bound():
     """|x - dequant(quant(x))| <= amax_row / (2 * qmax) elementwise — the
     per-row scale scheme's analytic bound (int8 qmax = 127)."""
     x = jax.random.normal(jax.random.key(3), (5, 4, 8, 32)) * 3.0
-    qd, sc = _quantize_rows(x, jnp.int8)
+    qd, sc = quantize_rows(x, jnp.int8)
     assert qd.dtype == jnp.int8 and sc.dtype == jnp.float32
     deq = qd.astype(jnp.float32) * sc[..., None]
     amax = np.max(np.abs(np.asarray(x)), axis=-1, keepdims=True)
@@ -370,7 +372,7 @@ def test_quantize_roundtrip_error_bound():
     assert np.all(np.abs(np.asarray(deq - x)) <= bound)
     # all-zero rows stay finite and decode to zero
     z = jnp.zeros((2, 4))
-    zd, zs = _quantize_rows(z, jnp.int8)
+    zd, zs = quantize_rows(z, jnp.int8)
     assert np.all(np.asarray(zd) == 0) and np.all(np.isfinite(zs))
 
 
@@ -548,7 +550,7 @@ def test_block_copy_then_divergent_write_on_the_per_layer_pool(quant):
         rows = data + offset
         if not quant:
             return tuple(r.reshape(NB + 1, bs, H * dh) for r in rows)
-        qd, sc = _quantize_rows(rows, jnp.int8)
+        qd, sc = quantize_rows(rows, jnp.int8)
         return tuple(QuantKV(d.reshape(NB + 1, bs, H * dh), s_)
                      for d, s_ in zip(qd, sc))
 
@@ -566,7 +568,7 @@ def test_block_copy_then_divergent_write_on_the_per_layer_pool(quant):
     # the divergent write: layer 0, block 1, offset 2
     new = jnp.full((1, H, dh), -7.0)
     k1 = host(kc)
-    kc = jax.jit(lambda c: _paged_scatter(
+    kc = jax.jit(lambda c: paged_scatter(
         c, 0, jnp.array([1]), jnp.array([2]), new))(kc)
     k2 = host(kc)
     for layer, (g, w) in enumerate(zip(k2, k1)):
@@ -684,7 +686,7 @@ def test_fp8_cache_roundtrip_and_engine(stages):
     """fp8 (e4m3) where available: round-trip inside the pinned fp8
     tolerance and engine greedy parity fused-vs-dense."""
     x = jax.random.normal(jax.random.key(9), (4, 8, 16))
-    qd, sc = _quantize_rows(x, jnp.float8_e4m3fn)
+    qd, sc = quantize_rows(x, jnp.float8_e4m3fn)
     deq = np.asarray(qd.astype(jnp.float32) * sc[..., None])
     rtol, atol = attn_tol(jnp.float8_e4m3fn)
     np.testing.assert_allclose(deq, np.asarray(x), rtol=rtol, atol=atol)
@@ -725,8 +727,8 @@ def test_rows_in_lanes_call_matches_head_major_call(dh):
 
 def test_quantized_rows_pool_matches_head_major_call():
     key, kc, vc, tables, pos = _toy_pool(jax.random.key(4), dh=4)
-    kq, ks = _quantize_rows(kc, jnp.int8)
-    vq, vs = _quantize_rows(vc, jnp.int8)
+    kq, ks = quantize_rows(kc, jnp.int8)
+    vq, vs = quantize_rows(vc, jnp.int8)
     S, H = tables.shape[0], kc.shape[1]
     q = jax.random.normal(key, (S, H, 1, 4))
     nat = _head_major_call(q, kq, vq, tables, pos[:, None], ks, vs)

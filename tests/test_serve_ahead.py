@@ -2,7 +2,7 @@
 _tick_ahead``; ``tests/test_jamba.py`` holds the hybrid's twins).
 
 GPT's two paged programs keep every slot's newest token and sampling key on
-the device beside the pool (``models/gpt.py::PagedServing.ahead``), so the
+the device beside the pool (``models/serving.py::PagedServing``), so the
 engine launches tick N+1's decode before it reads tick N's tokens. Nothing a
 request receives may move for it: every build of the programs serves the
 tokens of the solo cached decoder, whatever rides in the neighbouring slots,
@@ -181,8 +181,8 @@ def test_served_tokens_are_the_solo_decoders(build):
 @pytest.mark.parametrize("kind", ["greedy", "sampled"])
 def test_a_batch_of_one_kind_serves_the_solo_decodes_too(kind, build):
     """The programs' sampler takes one of two branches by whether any slot
-    samples (``models/gpt.py::_sample_slots``): a run whose every decode is
-    all-greedy (the branch that sorts nothing) and one whose every decode
+    samples (``models/serving.py::sample_slots``): a run whose every decode
+    is all-greedy (the branch that sorts nothing) and one whose every decode
     samples in every live slot serve each request's solo decode all the
     same, and the tick's ``sampling`` says which branch its decode took."""
     _, params = _model()

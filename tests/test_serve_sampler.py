@@ -1,10 +1,10 @@
-"""The ONE row-batch sampler of the serve programs (``models/gpt.py::
-_sample_slots``): ``vmap`` of ``_sample_dyn`` behind a batch-level
+"""The ONE row-batch sampler of the serve programs (``models/serving.py::
+sample_slots``): ``vmap`` of ``sample_dyn`` behind a batch-level
 ``lax.cond``, so that a tick whose slots are all greedy sorts nothing.
 
 What it must not move: any token or key of any program, for any batch. The
-programs of before called ``jax.vmap(_sample_dyn)`` (a row batch) and
-``_sample_dyn`` (one row) directly; here every GPT serve program is built
+programs of before called ``jax.vmap(sample_dyn)`` (a row batch) and
+``sample_dyn`` (one row) directly; here every GPT serve program is built
 both ways and the two builds' outputs are compared bit for bit over a
 whole serve, all-greedy, all-sampled and mixed, with a slot left free
 (temperature 0, as the engine hands inactive slots in). And what it must
@@ -27,6 +27,7 @@ from simple_distributed_machine_learning_tpu.models import (
     lora,
     nemotron_h,
     sdar,
+    serving,
 )
 from simple_distributed_machine_learning_tpu.models.gpt import (
     GPTConfig,
@@ -64,9 +65,9 @@ def _prompt(n, seed):
 def _as_before(patch):
     """The programs' sampling as the parent commit wrote it, and a memo of
     their builds that holds none built otherwise."""
-    patch.setattr(gpt, "_DECODE_BUILD_CACHE", {})
-    patch.setattr(gpt, "_sample_slots", jax.vmap(gpt._sample_dyn))
-    patch.setattr(gpt, "_sample_slot", gpt._sample_dyn)
+    patch.setattr(serving, "_DECODE_BUILD_CACHE", {})
+    patch.setattr(gpt, "sample_slots", jax.vmap(serving.sample_dyn))
+    patch.setattr(gpt, "sample_slot", serving.sample_dyn)
 
 
 def _host(tree):
@@ -152,7 +153,7 @@ def _both_ways(variant, mix):
 def test_paged_program_gives_what_vmap_of_sample_dyn_gave(program, variant,
                                                           mix):
     """Every run of the program over a whole serve: state pair, tokens and
-    keys bit for bit those of the build that calls ``_sample_dyn`` without
+    keys bit for bit those of the build that calls ``sample_dyn`` without
     the ``cond``; and the batches were of the kind the mix says, with the
     free slot at temperature 0."""
     now, before = _both_ways(variant, mix)
@@ -238,15 +239,15 @@ def test_draft_program_gives_what_vmap_of_sample_dyn_gave(program, mix):
 def test_the_comparison_would_see_a_sampler_that_moved_a_key():
     """The comparison reads what it says it reads: a sampler that advances
     a greedy row's key shows in the decode runs' outputs."""
-    real = gpt._sample_slots
+    real = serving.sample_slots
 
     def moved(*args):
         toks, kd = real(*args)
         return toks, kd + jnp.uint32(1)
 
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(gpt, "_DECODE_BUILD_CACHE", {})
-        patch.setattr(gpt, "_sample_slots", moved)
+        patch.setattr(serving, "_DECODE_BUILD_CACHE", {})
+        patch.setattr(gpt, "sample_slots", moved)
         off = _serve("plain", "greedy")
     now, _ = _both_ways("plain", "greedy")
     assert not any(_same(a[2], b[2])
@@ -323,10 +324,10 @@ def test_the_reading_of_the_text_finds_a_sort_that_always_runs():
     rows = jnp.zeros((N_SLOTS, CFG.vocab))
     args = (rows, jnp.zeros((N_SLOTS, 2), jnp.uint32), jnp.zeros(N_SLOTS),
             jnp.zeros(N_SLOTS, jnp.int32), jnp.full(N_SLOTS, 2.0))
-    always = jax.jit(jax.vmap(gpt._sample_dyn)).lower(*args).as_text()
+    always = jax.jit(jax.vmap(serving.sample_dyn)).lower(*args).as_text()
     total, bare = _unguarded_sorts(always)
     assert total == bare >= 1       # one outlined function, called twice
-    behind = jax.jit(gpt._sample_slots).lower(*args).as_text()
+    behind = jax.jit(serving.sample_slots).lower(*args).as_text()
     assert _unguarded_sorts(behind) == (total, 0)
 
 
@@ -335,8 +336,8 @@ def test_the_reading_of_the_text_finds_a_sort_that_always_runs():
 
 @pytest.mark.parametrize("module", [jamba, nemotron_h])
 def test_the_other_families_call_gpts_sampler(module):
-    assert module._sample_slots is gpt._sample_slots
-    assert module._sample_slot is gpt._sample_slot
+    assert module.sample_slots is gpt.sample_slots is serving.sample_slots
+    assert module.sample_slot is gpt.sample_slot is serving.sample_slot
     own = [n for n, f in vars(module).items()
            if "sample" in n and callable(f)
            and getattr(f, "__module__", None) == module.__name__]
@@ -344,6 +345,6 @@ def test_the_other_families_call_gpts_sampler(module):
 
 
 def test_the_block_family_keeps_its_own_cond_and_says_where_the_other_is():
-    assert not hasattr(sdar, "_sample_slots")
-    assert "models/gpt.py::_sample_slots" in " ".join(
+    assert not hasattr(sdar, "sample_slots")
+    assert "models/serving.py::sample_slots" in " ".join(
         sdar._sample_block.__doc__.split())

@@ -473,7 +473,7 @@ def test_tick_counts_the_decoding_slots_that_sample(stages, tracer, tick,
                                                     temps):
     """``sampling``: of the slots of the tick's decode, those whose
     temperature is above 0 (where it is 0 the programs' sampler sorted
-    nothing, ``models/gpt.py::_sample_slots``); witnessed from outside by
+    nothing, ``models/serving.py::sample_slots``); witnessed from outside by
     which requests got a token past their first in the tick."""
     kw = {}
     if tick == "speculative":

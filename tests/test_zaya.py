@@ -35,8 +35,11 @@ import pytest
 
 from bench_cells.reference import zaya as reference
 
-from simple_distributed_machine_learning_tpu.models import gpt, zaya
-from simple_distributed_machine_learning_tpu.models.gpt import (
+from simple_distributed_machine_learning_tpu.models import (
+    serving as serving_module,
+)
+from simple_distributed_machine_learning_tpu.models import zaya
+from simple_distributed_machine_learning_tpu.models.serving import (
     SEAT_NONE,
     SEAT_SAMPLE,
 )
@@ -184,7 +187,7 @@ def test_cache_layout_two_kinds_of_state_for_every_layer():
         == [((1, c), (1, c), (16,))] * 3 + [((), (2,))]
     assert all(s.dtype == jnp.float32 for leaf in serving.state_shapes[:-1]
                for s in leaf)
-    assert serving.ahead and serving.block == 1
+    assert serving.block == 1
     assert serving.counters == EXPERT_COUNTERS
     assert CFG.recurrent_state
     # the published model's widths
@@ -503,7 +506,7 @@ def small_steps(monkeypatch):
     """The chunk's attention in steps of 8 positions (two blocks), so that
     the toy's table of 48 is six steps (``tests/test_cohere2.py``'s fixture
     of the name)."""
-    monkeypatch.setattr(gpt, "_ATTEND_ROWS", 8)
+    monkeypatch.setattr(serving_module, "ATTEND_ROWS", 8)
     _twins.cache_clear()
     yield
     _twins.cache_clear()
