@@ -692,7 +692,10 @@ def hbm_tick_costs(sspec: ServeSpec, n_layers: int | None = None
 
 
 def predict_kv_bytes_resident(sspec: ServeSpec, rows_per_seq,
-                              n_layers: int | None = None) -> int:
+                              n_layers: int | None = None,
+                              kv_heads: int | None = None,
+                              head_dim: int | None = None,
+                              streams: int = 2) -> int:
     """Model of the pool's ``serve_kv_bytes_resident`` gauge: bytes the
     given live sequences pin, where each entry of ``rows_per_seq`` is one
     sequence's written-row count (``prompt_len + tokens_emitted - 1`` once
@@ -703,15 +706,21 @@ def predict_kv_bytes_resident(sspec: ServeSpec, rows_per_seq,
     and > 0 only if the pool pins blocks the model says it cannot need.
     PER SHARD under TP — the pool's gauge reports per-chip bytes (heads
     split ``tp`` ways), and this model must agree with it EXACTLY
-    (tests/test_analysis_serve.py)."""
+    (tests/test_analysis_serve.py). The pool's row is the CACHE's:
+    ``kv_heads`` heads of ``head_dim`` lanes in ``streams`` buffers a layer
+    (``PagedServing.kv_heads`` / ``head_dim`` / ``value_lanes``), each by
+    default what GPT's config gives (as many heads as the queries', ``d_model
+    / n_heads`` lanes, a key and a value buffer)."""
     from simple_distributed_machine_learning_tpu.serve.slots import (
         kv_block_bytes,
     )
     cfg = sspec.cfg
     L = n_layers if n_layers is not None else cfg.n_layers
-    per_block = kv_block_bytes(L, cfg.n_heads // sspec.tp, sspec.block_size,
-                               cfg.d_model // cfg.n_heads,
-                               sspec.cache_dtype)
+    per_block = kv_block_bytes(
+        L, (cfg.n_heads if kv_heads is None else kv_heads) // sspec.tp,
+        sspec.block_size,
+        cfg.d_model // cfg.n_heads if head_dim is None else head_dim,
+        sspec.cache_dtype, streams)
     blocks = sum(math.ceil(r / sspec.block_size) for r in rows_per_seq)
     return blocks * per_block
 

@@ -5,7 +5,7 @@ The one place that says it: the contract a model hands ``serve/engine.py``
 rule, the pool's rows (scatter, gather, the two attention paths), the ONE
 sampler of the serve programs, and the host arguments packed as one array.
 Every family (``models/gpt.py``, ``jamba.py``, ``sdar.py``,
-``nemotron_h.py``, ``zaya.py``, ``cohere2.py``) builds its two programs from
+``nemotron_h.py``, ``zaya.py``, ``cohere2.py``, ``kimi_linear.py``) builds its two programs from
 these and imports no other family's file; ``serve/`` takes the contract and
 the dtype rule from here. This module imports no family and nothing of
 ``serve/``.
@@ -113,7 +113,18 @@ class PagedServing(NamedTuple):
     handed back behind the window (``serve/slots.py``, "Layer kinds"); the
     programs are then handed every group's table side by side
     (``PagedKVPool.device_table``: the full group's ``ceil(max_len /
-    block)`` entries, then each ring). ``()``: every layer is full."""
+    block)`` entries, then each ring). ``()``: every layer is full.
+
+    ``value_lanes``: ``None``, every K/V layer has a key buffer and a value
+    buffer of ``kv_heads x head_dim`` lanes a position. A number
+    (``models/kimi_linear.py``, an ABSORBED latent cache): a K/V layer holds
+    ONE buffer, a position's row the normed latent and then the key lanes
+    all heads share, and the row's leading ``value_lanes`` lanes are its
+    values. The pool then allocates no value buffer (``PagedKVPool.vc`` is
+    the empty tuple, which the programs are handed, donate and hand back as
+    it is), ``kv_block_bytes`` counts one stream, and the decode attends
+    through ``ops/paged_attention.py``'s one-stream case (``vc=None``). No
+    quantized dtype (a row's scale planes are a head's)."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -128,6 +139,7 @@ class PagedServing(NamedTuple):
     counters: tuple = ()
     windows: tuple = ()
     serve_params: Callable | None = None
+    value_lanes: int | None = None
 
 
 # a chunk's ``seat`` where it is no token (PagedServing)

@@ -48,6 +48,15 @@ kernel pass (the structure of JAX's own paged-attention kernel):
   newest fetch the newest: no block behind the window is ever fetched,
   and the position mask, which counts logical positions, removes what the
   stand-ins hold. With ``window=None`` none of this is traced;
+- **one stream** (``vc=None``, ``v_lanes=``): an ABSORBED latent cache
+  (``models/kimi_linear.py``) holds ONE row a position, the normed latent
+  and then the key lanes all heads share, and the row's leading
+  ``v_lanes`` lanes ARE the values. The kernel then copies one stream (a
+  span's blocks once, where handing the same buffer over as ``kc`` and
+  ``vc`` would copy every block twice and halve what the cache was built
+  to save), takes the values as that lane slice of the span it already
+  holds, and writes ``v_lanes`` lanes a query row; every query head is a
+  row over the one stream. With ``vc`` given none of this is traced;
 - **fused dequantization**: int8/fp8 K/V blocks carry per-row (position x
   head) f32 scales, copied beside them; the kernel multiplies them back in
   VMEM right after the load, so a quantized pool pays the narrow dtype's
@@ -134,7 +143,7 @@ def _whole_lanes(a):
 def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
                        n_q: int, scale: float, quant: bool, n_sub: int,
                        nested: bool = False, window: int | None = None,
-                       ring: int = 0):
+                       ring: int = 0, v_lanes: int | None = None):
     """One slot: a loop over the slot's live spans of ``n_sub`` blocks.
 
     ``q_ref``: [1, H, R, dh], this slot's query rows, all heads: row ``r``
@@ -150,8 +159,11 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
     and ``dh`` are the CALL's: the wrapper hands a rows-in-lanes pool over
     as one stream (``H = 1``) whose ``dh`` is the whole row. ``window``: the
     layer's window in positions (module docstring), its table a ring of
-    ``ring`` entries; every line it adds is under ``window is not None``."""
-    n_streams = 4 if quant else 2
+    ``ring`` entries; every line it adds is under ``window is not None``.
+    ``v_lanes``: ONE stream (module docstring): no ``v_hbm``, no ``vbuf``,
+    the values the leading ``v_lanes`` lanes of the key rows, ``o_ref`` [1,
+    H, R, v_lanes]; plain pools only."""
+    n_streams = 1 if v_lanes is not None else 4 if quant else 2
     hbm, rest = rest[:n_streams], rest[n_streams:]
     o_ref, rest = rest[0], rest[1:]
     bufs, (sem, first) = rest[:n_streams], rest[n_streams:]
@@ -255,7 +267,8 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
 
             wait(half)
             k = rows(bufs[0], bufs[2] if quant else None, half)
-            v = rows(bufs[1], bufs[3] if quant else None, half)
+            v = (k[..., :v_lanes] if v_lanes is not None
+                 else rows(bufs[1], bufs[3] if quant else None, half))
             # scores in f32 — the dense path's einsum promotion, so the
             # fused logits track the gather-then-dense ones to ulps
             s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,)))) * scale
@@ -276,7 +289,8 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
         _, l, acc = lax.fori_loop(it0, trips, trip, (
             jnp.full((H, R, 1), NEG_INF, jnp.float32),
             jnp.zeros((H, R, 1), jnp.float32),
-            jnp.zeros((H, R, dh), jnp.float32)))
+            jnp.zeros((H, R, dh if v_lanes is None else v_lanes),
+                      jnp.float32)))
         first[0] = lax.rem(half0 + since(trips), 2)  # the next slot's start
         o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -288,13 +302,16 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, *rest, bs: int,
 
 
 def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
-                   interpret=None, window=None):
+                   interpret=None, window=None, v_lanes=None):
     """The Pallas call over head-major operands: ``q`` [S, H, K, dh],
     ``kc``/``vc`` [n_blocks+1, H, bs, dh], scales [n_blocks+1, H, bs] or
     None, ``qpos`` [S, n_q]: query row ``r`` of a head stands at position
     ``qpos[s, r % n_q]`` (``K`` a multiple of ``n_q``), and the last
     column is the slot's newest position. ``window``: ``tables`` is a
-    window layer's ring (module docstring). Returns f32 [S, H, K, dh]."""
+    window layer's ring (module docstring). ``v_lanes``: ``vc`` is
+    ``None`` and the values are the leading ``v_lanes`` lanes of ``kc``'s
+    rows (one stream; then f32 [S, H, K, v_lanes] comes back). Returns f32
+    [S, H, K, dh]."""
     if interpret is None:
         interpret = _interpret()
     NB = tables.shape[1]
@@ -303,12 +320,14 @@ def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
     # of the source only: a stream whose rows are not whole tiles (a toy
     # width; every scale plane, whose row is a block's positions) is
     # padded with zeros, which a score and a row's output add exactly
-    streams = [kc, vc] + ([kscale, vscale] if quant else [])
+    n_kv = 2 if v_lanes is None else 1
+    streams = [kc, vc][:n_kv] + ([kscale, vscale] if quant else [])
     held = sum(math.prod(a.shape[1:]) * a.dtype.itemsize for a in streams)
     dh_call = q.shape[-1]
     q, *streams = (_whole_lanes(a) for a in (q, *streams))
-    kc, vc = streams[:2]
+    kc = streams[0]
     S, H, K, dh = q.shape
+    dv = dh if v_lanes is None else v_lanes
     # what the call moves at most: the query block in and the output block
     # back, and every slot's whole table span of every stream once, as the
     # pool holds it. The analyzer reads the K/V stream of operands it
@@ -325,45 +344,49 @@ def _attend_blocks(q, kc, vc, tables, qpos, bs, scale, kscale, vscale,
     # slot has, block by block
     in_specs = ([pl.BlockSpec((1, H, K, dh), _q_idx)]
                 + [pl.BlockSpec(memory_space=pl.ANY)] * len(streams))
-    scratch = [pltpu.VMEM((2, H, span, dh), kc.dtype)] * 2
+    scratch = [pltpu.VMEM((2, H, span, dh), kc.dtype)] * n_kv
     if quant:
         scratch += [pltpu.VMEM((2, n_sub, *streams[2].shape[1:]),
                                kscale.dtype)] * 2
     scratch += [pltpu.SemaphoreType.DMA((len(streams), 2)),
                 pltpu.SMEM((1,), jnp.int32)]
 
-    vma = _vma_of(q, kc, vc)
+    vma = _vma_of(q, *streams[:n_kv])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, K, dh), _q_idx),
+        out_specs=pl.BlockSpec((1, H, K, dv), _q_idx),
         scratch_shapes=scratch,
     )
-    windowed = {} if window is None else {"window": window, "ring": NB}
+    # what only a window layer's or a one-stream call's kernel is told
+    extra = {} if window is None else {"window": window, "ring": NB}
+    if v_lanes is not None:
+        extra["v_lanes"] = v_lanes
     return pl.pallas_call(
         functools.partial(_paged_attn_kernel, bs=bs, n_q=qpos.shape[1],
                           scale=scale, quant=quant, n_sub=n_sub,
-                          nested=bool(interpret and vma), **windowed),
+                          nested=bool(interpret and vma), **extra),
         grid_spec=grid_spec,
-        out_shape=_struct((S, H, K, dh), jnp.float32, vma),
+        out_shape=_struct((S, H, K, dv), jnp.float32, vma),
         # in order: a slot fetches its successor's first span
         compiler_params=_compiler_params("arbitrary"),
         cost_estimate=pl.CostEstimate(
-            flops=4 * S * H * K * NB * bs * dh,
+            flops=2 * S * H * K * NB * bs * (dh + dv),
             transcendentals=S * H * K * NB * bs,
             bytes_accessed=moved),
         interpret=interpret,
         name="paged_attention",
     )(tables.astype(jnp.int32), qpos.astype(jnp.int32), q,
-      *streams)[..., :dh_call]
+      *streams)[..., :dh_call if v_lanes is None else v_lanes]
 
 
-def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
+def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array | None,
                     tables: jax.Array, qpos: jax.Array, *,
                     block_size: int, kscale: jax.Array | None = None,
                     vscale: jax.Array | None = None,
-                    window: int | None = None) -> jax.Array:
+                    window: int | None = None, v_lanes: int | None = None,
+                    scale: float | None = None) -> jax.Array:
     """Fused paged attention over one layer's physical block pool.
 
     ``q``: [S, H, K, dh] queries (K = 1 for the flash-decode tick, the
@@ -401,6 +424,13 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     the call, ``[n_blocks+1, KVH, bs, dh]``, one copy of the layer per
     tick. No benchmark cell runs one (``ROADMAP.md`` D4).
 
+    ``vc=None``: ONE stream (module docstring). ``kc`` [n_blocks+1, bs, D]
+    holds one row a position that every one of the ``H`` query heads reads,
+    ``q`` is [S, H, K, D] (a query laid out in the row's lanes), the values
+    are the row's leading ``v_lanes`` lanes, and ``scale`` multiplies the
+    scores (the row is no head, so its width says nothing about it: both
+    must be given). Returns f32 [S, H, K, v_lanes]. Plain pools, no window.
+
     Returns f32 [S, H, K, dh]: exactly what the dense-math path's masked
     softmax-attention einsum pair produces over the gathered span, with
     rows past each query's position masked out (trash-table entries
@@ -410,6 +440,18 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     the same shapes, and traces and lowers the kernel once for all of them
     (36 lowerings of it were 6 s of ``gpt2-large.serve-closed``'s set-up).
     """
+    if vc is None:
+        if None in (v_lanes, scale) or not (
+                kscale is None and vscale is None and window is None):
+            raise ValueError(
+                "one stream (vc=None) takes v_lanes= and scale=, and neither "
+                "scale planes nor a window")
+        return _paged_attention(q, kc, None, tables, qpos, None, None,
+                                bs=int(block_size), interpret=_interpret(),
+                                v_lanes=int(v_lanes), scale=float(scale))
+    if v_lanes is not None or scale is not None:
+        raise ValueError("v_lanes= and scale= belong to one stream "
+                         "(vc=None)")
     if window is None:
         return _paged_attention(q, kc, vc, tables, qpos, kscale, vscale,
                                 bs=int(block_size), interpret=_interpret())
@@ -422,10 +464,22 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
                             window=int(window))
 
 
-@functools.partial(jax.jit, static_argnames=("bs", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("bs", "interpret", "window",
+                                             "v_lanes", "scale"))
 def _paged_attention(q, kc, vc, tables, qpos, kscale, vscale, *, bs,
-                     interpret, window=None):
+                     interpret, window=None, v_lanes=None, scale=None):
     S, H, n_q, dh = q.shape
+    if vc is None:
+        # one stream: every head a row over the one row a position holds
+        if kc.shape[1:] != (bs, dh) or not 0 < v_lanes <= dh:
+            raise ValueError(
+                f"one stream: kc must be [n_blocks+1, {bs}, {dh}] (the "
+                f"queries' lanes) with 0 < v_lanes <= {dh}, got {kc.shape} "
+                f"and v_lanes={v_lanes}")
+        out = _attend_blocks(q.reshape(S, 1, H * n_q, dh), kc[:, None], None,
+                             tables, qpos, bs, scale, None, None, interpret,
+                             v_lanes=v_lanes)
+        return out.reshape(S, H, n_q, v_lanes)
     if kc.ndim != 3 or kc.shape[1] != bs:
         raise ValueError(f"kc must be [n_blocks+1, {bs}, KVH*dh], got "
                          f"{kc.shape}")

@@ -400,7 +400,8 @@ class InferenceEngine:
                                 step_rows=self._block,
                                 windows=serving.windows,
                                 n_window_blocks=n_window_blocks,
-                                chunk_rows=prefill_chunk)
+                                chunk_rows=prefill_chunk,
+                                value_lanes=serving.value_lanes)
         # the pool's narrowest window group, where it has one: what the
         # tick's window counts are of
         self._window_group = (self.pool.window_groups[0]

@@ -111,10 +111,12 @@ import numpy as np
 
 
 def kv_block_bytes(n_layers: int, n_heads: int, block_size: int,
-                   head_dim: int, cache_dtype=None) -> int:
+                   head_dim: int, cache_dtype=None, streams: int = 2) -> int:
     """Bytes one physical K/V block pins across every layer (K and V).
     ``n_layers`` / ``n_heads`` are the CACHE's: the layers that attend and
     their K/V heads (a grouped-query model's query heads are more).
+    ``streams``: 2, a key and a value buffer a layer; 1 where a layer's one
+    row holds both (``PagedServing.value_lanes``).
 
     The ONE copy of the formula: :class:`PagedKVPool` sizes its
     ``bytes_per_block`` (and therefore the ``serve_kv_bytes_resident``
@@ -134,10 +136,10 @@ def kv_block_bytes(n_layers: int, n_heads: int, block_size: int,
         storage_dtype,
     )
     cd = storage_dtype(cache_dtype)
-    bytes_ = (2 * n_layers * n_heads * block_size * head_dim
+    bytes_ = (streams * n_layers * n_heads * block_size * head_dim
               * jnp.dtype(cd).itemsize)
     if is_quantized_dtype(cache_dtype):
-        bytes_ += 2 * n_layers * n_heads * block_size * 4   # f32 scales
+        bytes_ += streams * n_layers * n_heads * block_size * 4  # f32 scales
     return int(bytes_)
 
 
@@ -333,7 +335,8 @@ class PagedKVPool:
                  prefetch_ticks: int = 1, state_shapes=(),
                  recurrent: bool = False, step_rows: int = 1,
                  windows: tuple = (), n_window_blocks: int | None = None,
-                 chunk_rows: int | None = None) -> None:
+                 chunk_rows: int | None = None,
+                 value_lanes: int | None = None) -> None:
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if max_len < 2:
@@ -403,6 +406,20 @@ class PagedKVPool:
         cd = storage_dtype(cache_dtype)
         self.cache_dtype = cd
         self.quantized = is_quantized_dtype(cache_dtype)
+        # one stream a layer (``PagedServing.value_lanes``): a position's
+        # one row holds its values too, and there is no value buffer
+        self.value_lanes = value_lanes
+        streams = 2 if value_lanes is None else 1
+        if value_lanes is not None and (
+                self.quantized or any(w is not None for w in windows)
+                or host_cache_blocks or self.tp > 1
+                or not 0 < value_lanes <= n_heads * head_dim):
+            raise ValueError(
+                f"a pool without a value buffer (value_lanes={value_lanes} "
+                f"of the row's {n_heads * head_dim}) takes no quantized "
+                f"cache_dtype (QuantKV's scale planes are a head's), no "
+                f"window group, no host tier and no tensor-parallel "
+                f"placement")
         # layer kinds (module docstring): ``windows[li]`` is layer li's
         # window in positions, None (or no entry) a full layer
         windows = tuple(windows) or (None,) * n_layers
@@ -435,7 +452,8 @@ class PagedKVPool:
                     f"and chunks of {chunk_rows})")
             self.window_groups.append(self._WindowGroup(
                 w, layers, ring, n, n_slots, kv_block_bytes(
-                    len(layers), n_heads, block_size, head_dim, cd)))
+                    len(layers), n_heads, block_size, head_dim, cd,
+                    streams)))
         n_full = sum(w is None for w in windows)
 
         # +1: physical block 0 is the trash block, never allocated. One
@@ -456,7 +474,8 @@ class PagedKVPool:
                            jnp.zeros((*shape[:2], n_heads), jnp.float32))
 
         self.kc = tuple(layer(li) for li in range(n_layers))
-        self.vc = tuple(layer(li) for li in range(n_layers))
+        self.vc = (tuple(layer(li) for li in range(n_layers))
+                   if value_lanes is None else ())
         self.state = jax.tree.map(
             lambda sd: jnp.zeros((n_slots, *sd.shape), sd.dtype),
             state_shapes)
@@ -474,7 +493,8 @@ class PagedKVPool:
         # predict_kv_bytes_resident must agree with per shard
         # (the FULL group's layers: a window group bills its own)
         self.bytes_per_block = kv_block_bytes(n_full, n_heads // self.tp,
-                                              block_size, head_dim, cd)
+                                              block_size, head_dim, cd,
+                                              streams)
         # block bookkeeping (host-side, authoritative)
         self.ref = np.zeros(n_blocks + 1, np.int64)
         self._free_blocks: list[int] = list(range(1, n_blocks + 1))[::-1]

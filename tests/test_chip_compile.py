@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from simple_distributed_machine_learning_tpu.ops import (
     flash_attention as fa,
+    kda,
     moe_experts as me,
     paged_attention as pa,
     selective_scan as ss,
@@ -65,6 +66,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(pa, "_interpret", lambda: False)
     monkeypatch.setattr(ss, "_interpret", lambda: False)
     monkeypatch.setattr(me, "_interpret", lambda: False)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
 
 
 def _compile(fn, one_chip, *shapes, kernels):
@@ -670,6 +672,118 @@ def test_window_programs_leave_the_projections_where_they_lie(
         if m and math.prod(int(n) for n in m.group(2).split(",")) in sizes:
             moved.append(f"{m.group(3)} {m.group(1)} bf16[{m.group(2)}]")
     assert not moved, moved
+
+
+# -- the seventh family: the delta rule, one stream, both programs -----------
+
+
+@pytest.mark.parametrize("n,n_tok", [(64, 1), (1, 512), (1, 40)])
+def test_kda_recurrence_compiles_for_v5e(one_chip, mosaic, n, n_tok):
+    """The cell's two calls (a decode tick's 64 slots of one token, a
+    chunk's 512 tokens of one slot) and a ragged walk, at 32 heads of 128 x
+    128 float32 state: the state is aliased in place, and nothing beside
+    the arguments is held."""
+    H, dk = 32, 128
+    f32 = jnp.float32
+    shapes = ([((n, n_tok, H, dk), f32)] * 4 + [((n, n_tok, H), f32),
+                                                ((n, H, dk, dk), f32)])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    live = {"live": jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+            } if n_tok == 1 else {}
+    compiled = jax.jit(kda.kda_recurrence, donate_argnums=(5,)).lower(
+        *args, **live).compile()
+    lines = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(lines) == 1 and "kda_recurrence" in lines[0].split(" = ")[0]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == n * H * dk * dk * 4
+
+
+def test_one_stream_attention_compiles_at_the_cells_real_shape(one_chip,
+                                                               mosaic):
+    """``paged_attention(vc=None)`` as ``kimi-linear-48b-a3b.serve-think-
+    closed`` calls it: 64 slots, 32 query heads over ONE row of 640 lanes
+    (512 latent, 64 shared key lanes, 64 zeros) whose leading 512 are the
+    values, 16,384 bf16 blocks of 16, tables of 256."""
+    fn = lambda q, k, t, p: pa.paged_attention(  # noqa: E731
+        q, k, None, t, p, block_size=16, v_lanes=512, scale=192 ** -0.5)
+    lines = _compile(fn, one_chip, ((64, 32, 1, 640), jnp.float32),
+                     ((16385, 16, 640), jnp.bfloat16),
+                     ((64, 256), jnp.int32), ((64, 1), jnp.int32),
+                     kernels=["paged_attention"])
+    assert "f32[64,1,32,512]" in lines[0].replace(" ", "")
+
+
+def _kda_programs():
+    """``kimi-linear-48b-a3b.serve-think-closed``'s two programs as the cell
+    runs them, but for depth: one period and the dense layer (layers 0-3:
+    KDA with the dense part, two KDA layers and a latent layer with the
+    mixture), hidden 2304, 32 KDA heads of 128 and 32 latent heads over one
+    640-lane row, 16 held experts of 256, 20,480 held rows, 64 slots of
+    4,096, 16,384 bf16 blocks of 16, chunks of 512, the fused kernel."""
+    from simple_distributed_machine_learning_tpu.models.kimi_linear import (
+        KimiLinearConfig,
+        make_kimi_linear_stages,
+        pack_chunk_inputs,
+        pack_decode_inputs,
+    )
+    import numpy as np
+    S, ml, bs, nb, c = 64, 4096, 16, 16384, 512
+    cfg = KimiLinearConfig(
+        vocab=20480, seq_len=ml, d_model=2304, n_layers=4, attn_layers=(3,),
+        n_heads=32, d_nope=128, d_rope=64, d_v=128, d_latent=512,
+        kda_heads=32, kda_head_dim=128, d_conv=4, d_gate=128, n_dense=1,
+        d_ff=9216, n_experts=256, top_k=8, experts_held=16, n_shared=1,
+        d_expert=1024, param_dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda k: make_kimi_linear_stages(k, cfg)[0][0].params,
+        jax.random.key(0))
+    serving = cfg.paged_serving([types.SimpleNamespace(params=params)], ml,
+                                bs, "bfloat16", kernel="fused")
+    pool = tuple(_sd((nb + 1, bs, cfg.d_cache), jnp.bfloat16)
+                 for _ in range(serving.kv_layers))
+    state = jax.tree.map(lambda sd: _sd((S, *sd.shape), sd.dtype),
+                         serving.state_shapes)
+    z = np.zeros(S, np.int32)
+    host, = pack_decode_inputs(z, z, np.zeros((S, ml // bs), np.int32), z,
+                               None, z.astype(np.float32), z,
+                               z.astype(np.float32))
+    tokens, chost = pack_chunk_inputs(
+        np.zeros((1, c), np.int32), 0, np.zeros(ml // bs, np.int32), 0, -1,
+        np.zeros(2, np.uint32), 0.0, 0, 1.0)
+    return pool, {
+        "kda-decode": (serving.decode, (
+            [params], pool, (), state, _sd(host.shape, host.dtype))),
+        "kda-chunk": (serving.chunk_prefill, (
+            [params], pool, (), state, _sd(tokens.shape, tokens.dtype),
+            _sd(chost.shape, chost.dtype)))}
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("kda-decode", {"kda_recurrence", "paged_attention", "moe_experts"}),
+    ("kda-chunk", {"kda_recurrence", "moe_experts"})])
+def test_kda_programs_compile_at_the_cells_real_sizes(one_chip, mosaic,
+                                                      program, kernels):
+    """The decode step and the prefill chunk of the seventh family, handed
+    to the chip's compiler whole at the cell's widths (four of its 27
+    layers, every kind among them). The pool's one stream and the slots'
+    state are donated: every byte of both is aliased input to output, the
+    64 x 2 MiB of every KDA layer's matrix state included, and what a
+    program holds beside its arguments stays under 0.5 GB."""
+    pool, programs = _kda_programs()
+    fn, args = programs[program]
+    compiled = fn.lower(*_on_chip(args, one_chip)).compile()
+    found = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert found == kernels
+    held = sum(math.prod(b.shape) * b.dtype.itemsize for b in pool)
+    state_bytes = sum(math.prod(sd.shape) * sd.dtype.itemsize
+                      for sd in jax.tree.leaves(args[3]))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held + state_bytes
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+
 
 
 # -- flash attention: the train step's kernel -------------------------------

@@ -1005,3 +1005,97 @@ def test_without_a_window_the_kernel_traces_what_the_parent_traced(shape):
     assert hashlib.sha256(plain.encode()).hexdigest() == _PARENT_JAXPR[shape]
     assert text(window=None) == plain
     assert text(window=64) != plain
+
+
+# -- one stream: an absorbed latent cache's row (ISSUE 49) --------------------
+
+
+def _one_stream_call(seed, H, D, v_lanes, bs, NB, last, K=1, dtype=np.float32):
+    """A pool of rows ``[n_phys, bs, D]``, every slot's blocks in a shuffled
+    order, and queries ``[S, H, K, D]`` at the ``K`` newest positions."""
+    rng = np.random.default_rng(seed)
+    last = np.asarray(last, np.int32)
+    S = len(last)
+    n_phys = 1 + S * NB
+    kc = rng.standard_normal((n_phys, bs, D)).astype(dtype)
+    tables = 1 + rng.permutation(S * NB).reshape(S, NB).astype(np.int32)
+    q = rng.standard_normal((S, H, K, D)).astype(np.float32)
+    qpos = last[:, None] - np.arange(K - 1, -1, -1, dtype=np.int32)[None]
+    return q, kc, tables, qpos
+
+
+@pytest.mark.parametrize("H,D,v_lanes,bs,NB,K,pool", [
+    (4, 48, 32, 4, 6, 1, "float32"),       # the toy family's row: 32 + 16
+    (32, 640, 512, 16, 8, 1, "bfloat16"),  # the cell's: 32 heads, 576 in 640
+    (2, 128, 128, 16, 3, 3, "float32"),    # every lane a value; three rows
+])
+def test_one_stream_equals_two_streams_over_the_same_rows(H, D, v_lanes, bs,
+                                                          NB, K, pool):
+    """``vc=None``: the values are the leading ``v_lanes`` lanes of the key
+    rows. The same call with a SECOND buffer that holds those lanes (what a
+    pool with a value buffer would copy beside the keys) gives the same
+    numbers: as multi-query attention of ``H`` heads over one K/V head of
+    ``D`` lanes, whose output's leading ``v_lanes`` lanes are kept."""
+    last = [bs * NB - 1, 0, bs + 1, 2 * bs - 1][:3]
+    q, kc, tables, qpos = _one_stream_call(11, H, D, v_lanes, bs, NB, last, K,
+                                           jnp.dtype(pool))
+    qpos = np.maximum(qpos, 0)
+    scale = 0.37
+    one = paged_attention(jnp.asarray(q), jnp.asarray(kc), None,
+                          jnp.asarray(tables), jnp.asarray(qpos),
+                          block_size=bs, v_lanes=v_lanes, scale=scale)
+    assert one.shape == (len(last), H, K, v_lanes)
+    # two streams: the kernel's own scale is 1 / sqrt(D)
+    vc = jnp.asarray(kc).at[..., v_lanes:].set(0)
+    two = paged_attention(jnp.asarray(q) * (scale * math.sqrt(D)),
+                          jnp.asarray(kc), vc, jnp.asarray(tables),
+                          jnp.asarray(qpos), block_size=bs)[..., :v_lanes]
+    np.testing.assert_allclose(np.asarray(one), np.asarray(two), rtol=2e-5,
+                               atol=2e-5)
+    # and both are the dense softmax over each slot's own positions
+    rows = np.asarray(jnp.asarray(kc).astype(jnp.float32))
+    for s in range(len(last)):
+        seq = rows[tables[s]].reshape(NB * bs, D)
+        for j in range(K):
+            n = int(qpos[s, j]) + 1
+            sc = q[s, :, j] @ seq[:n].T * scale
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            want = (p / p.sum(-1, keepdims=True)) @ seq[:n, :v_lanes]
+            np.testing.assert_allclose(np.asarray(one)[s, :, j], want,
+                                       rtol=3e-5, atol=3e-5)
+
+
+def test_one_stream_copies_one_stream():
+    """The Pallas call of ``vc=None`` has ONE semaphore row and starts and
+    waits for one stream's copies: handing the same buffer over twice (as
+    ``kc`` and ``vc``) has two and copies every block twice."""
+    q, kc, tables, qpos = _one_stream_call(12, 4, 128, 64, 16, 4, [40, 3])
+
+    def counts(fn):
+        eqns = list(_kernel_eqns(jax.make_jaxpr(fn)(q, kc, tables,
+                                                    qpos).jaxpr))
+        (call,) = [e for e, _ in eqns if e.primitive.name == "pallas_call"]
+        sems = [tuple(v.aval.shape) for v in call.params["jaxpr"].invars
+                if "sem" in str(v.aval).lower()]
+        return sems, *(sum(e.primitive.name == name for e, _ in eqns)
+                       for name in ("dma_start", "dma_wait"))
+
+    assert counts(lambda q, k, t, p: paged_attention(
+        q, k, None, t, p, block_size=16, v_lanes=64, scale=1.0)) == (
+            [(1, 2)], 3, 1)
+    assert counts(lambda q, k, t, p: paged_attention(
+        q, k, k, t, p, block_size=16)) == ([(2, 2)], 6, 2)
+
+
+def test_one_stream_refuses_what_it_does_not_take():
+    q, kc, tables, qpos = _one_stream_call(13, 2, 128, 64, 16, 2, [5])
+    with pytest.raises(ValueError, match="takes v_lanes= and scale="):
+        paged_attention(q, kc, None, tables, qpos, block_size=16)
+    with pytest.raises(ValueError, match="takes v_lanes= and scale="):
+        paged_attention(q, kc, None, tables, qpos, block_size=16, v_lanes=64,
+                        scale=1.0, window=8)
+    with pytest.raises(ValueError, match="belong to one stream"):
+        paged_attention(q, kc, kc, tables, qpos, block_size=16, v_lanes=64)
+    with pytest.raises(ValueError, match="one stream: kc must be"):
+        paged_attention(q[..., :64], kc, None, tables, qpos, block_size=16,
+                        v_lanes=64, scale=1.0)

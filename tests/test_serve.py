@@ -1129,3 +1129,92 @@ def test_a_pool_without_windows_is_the_pool_it_always_was():
         assert pool.bytes_per_block == 512 and not pool.window_groups
     with pytest.raises(ValueError, match="windows must name each"):
         PagedKVPool(2, 2, 2, 16, 4, windows=(None,))
+
+
+# -- a pool without a value buffer (PagedServing.value_lanes, ISSUE 49) -------
+
+
+def test_a_pool_without_a_value_buffer_holds_one_stream():
+    """``PagedKVPool(value_lanes=)``: a K/V layer is ONE buffer whose row
+    holds a position's values too. No value buffer is allocated (``vc`` is
+    the empty tuple, which the programs donate and hand back as it is), a
+    block bills one stream, and the block discipline is the pool's own:
+    allocation on demand, the reservation returned, a double free raising."""
+    import types
+
+    import jax
+
+    from simple_distributed_machine_learning_tpu.models.gpt import (
+        make_paged_block_copy,
+    )
+    from simple_distributed_machine_learning_tpu.serve.slots import (
+        kv_block_bytes,
+    )
+    two = PagedKVPool(2, 2, 1, 16, 128, "bfloat16", block_size=4, n_blocks=6)
+    one = PagedKVPool(2, 2, 1, 16, 128, "bfloat16", block_size=4, n_blocks=6,
+                      value_lanes=96, recurrent=True)
+    assert one.vc == () and len(one.kc) == 2 and one.value_lanes == 96
+    assert [k.shape for k in one.kc] == [k.shape for k in two.kc] == [
+        (7, 4, 128)] * 2
+    assert kv_block_bytes(2, 1, 4, 128, "bfloat16") == 2 * two.kc[0][0].nbytes * 2
+    assert kv_block_bytes(2, 1, 4, 128, "bfloat16", streams=1) == 2048
+    assert (one.bytes_per_block, two.bytes_per_block) == (2048, 4096)
+    assert sum(b.nbytes for b in (*one.kc, *one.vc)) * 2 == sum(
+        b.nbytes for b in (*two.kc, *two.vc))
+    req = types.SimpleNamespace(prompt=np.arange(9, dtype=np.int32),
+                                max_new_tokens=8)
+    assert one.can_admit(req)
+    s = one.acquire(0)
+    assert one.begin_seq(s, req.prompt, 8) == 0
+    assert one.blocks_available == 2
+    for p in range(9):
+        assert one.ensure_writable(s, p) is None
+    assert len(one.tables[s]) == 3 and one.bytes_resident() == 3 * 2048
+    used = list(one.tables[s])
+    one.end_seq(s)
+    one.release(s)
+    assert one.blocks_available == 6 and one.bytes_resident() == 0
+    with pytest.raises(RuntimeError, match="double free"):
+        one._unref_block(used[0])
+    # the copy-on-write program and donation take the empty tuple as it is
+    kc, vc = make_paged_block_copy()(one.kc, one.vc, np.int32(2), np.int32(1))
+    assert vc == () and len(kc) == 2
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(one.kc))
+
+
+@pytest.mark.parametrize("kw,match", [
+    ({"cache_dtype": "int8"}, "QuantKV's scale planes"),
+    ({"windows": (8, None)}, "window group"),
+    ({"host_cache_blocks": 2}, "no host tier"),
+    ({"value_lanes": 0}, "without a value buffer"),
+    ({"value_lanes": 129}, "without a value buffer"),
+])
+def test_a_pool_without_a_value_buffer_refuses_by_name(kw, match):
+    kw = {"value_lanes": 96, **kw}
+    with pytest.raises(ValueError, match=match):
+        PagedKVPool(2, 2, 1, 16, 128, block_size=4, **kw)
+
+
+def test_the_analyzers_resident_bytes_take_the_caches_row():
+    """``predict_kv_bytes_resident`` reads the row it is handed (the
+    CACHE's heads, lanes and streams), and by default GPT's."""
+    from simple_distributed_machine_learning_tpu.analysis.programs import (
+        ServeSpec,
+        predict_kv_bytes_resident,
+    )
+    from simple_distributed_machine_learning_tpu.serve.slots import (
+        kv_block_bytes,
+    )
+    sspec = ServeSpec(CFG, n_slots=2, block_size=8, cache_dtype="bfloat16")
+    rows = [9, 16, 17]                      # 2 + 2 + 3 blocks
+    dh = CFG.d_model // CFG.n_heads
+    assert predict_kv_bytes_resident(sspec, rows) == 7 * kv_block_bytes(
+        CFG.n_layers, CFG.n_heads, 8, dh, "bfloat16")
+    # one latent row of 640 lanes in ONE stream over 7 layers
+    assert predict_kv_bytes_resident(
+        sspec, rows, n_layers=7, kv_heads=1, head_dim=640, streams=1
+    ) == 7 * 7 * 8 * 640 * 2
+    pool = PagedKVPool(7, 2, 1, 32, 640, "bfloat16", block_size=8,
+                       value_lanes=512, recurrent=True)
+    assert pool.bytes_for_rows(17) == predict_kv_bytes_resident(
+        sspec, [17], n_layers=7, kv_heads=1, head_dim=640, streams=1)
