@@ -60,8 +60,12 @@ values at the published sizes, and NO value buffer
 ``q'_i . [c^_j ; r_j]``, ``o'_i = softmax . c^_j``, ``o_i = o'_i W^V_i``: the
 same sums in another order, every query head a row over one cached row
 whose leading ``d_latent`` lanes are its own values. The decode
-(``jit_step_kda_decode``) attends through ``ops/paged_attention.py``'s
-one-stream case; the chunk (``jit_chunk_kda_prefill``) writes its rows, then
+(``jit_step_kda_decode``) takes the recurrence's STEP (one token of every
+live slot, the state in and out once) and attends through
+``ops/paged_attention.py``'s one-stream case; the chunk
+(``jit_chunk_kda_prefill``) takes its WALK from the slot's state (64 tokens
+at a time as float32 matrix products, the same recurrence rearranged:
+``ops/kda.py``), writes its rows, then
 attends absorbed too, over the slot's live positions
 (``models/serving.py::span_attention`` with the one buffer as keys and
 values); :func:`full_logits` (no cache) expands keys and values as the
